@@ -33,10 +33,13 @@ __all__ = ["COLLECTIVE_PRIMS", "collective_signature",
            "CollectiveConsistencyPass", "check_stage_consistency",
            "collective_cost_bytes", "scan_trip_counts"]
 
+# ``psum_invariant``/``all_gather_invariant`` are what ``lax.psum``/
+# ``lax.all_gather`` bind inside a ``check_vma=True`` shard_map body
 COLLECTIVE_PRIMS = {
-    "psum", "psum2", "pmax", "pmin", "pmean", "ppermute", "pbroadcast",
-    "all_gather", "all_to_all", "reduce_scatter", "psum_scatter",
-    "pgather", "pshuffle",
+    "psum", "psum2", "psum_invariant", "pmax", "pmin", "pmean",
+    "ppermute", "pbroadcast", "all_gather", "all_gather_invariant",
+    "all_to_all", "reduce_scatter", "psum_scatter", "pgather",
+    "pshuffle",
 }
 
 # eqn params that carry collective SEMANTICS (vs. local tiling detail)
@@ -70,7 +73,7 @@ def collective_signature(jaxpr, include_loops: bool = False
     chunk scanning a different layer count desynchronizes the lockstep
     schedule exactly like a diverging collective would."""
     from ..core.graph_trace import sub_jaxprs
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
 
     sig: List[Tuple] = []
 
@@ -105,8 +108,9 @@ def collective_signature(jaxpr, include_loops: bool = False
 #: ~1). Deliberately topology-free — the planner's comms term is a
 #: RANKING proxy, not a wall-clock model.
 _COLLECTIVE_WIRE_FACTOR = {
-    "psum": 2.0, "psum2": 2.0, "pmax": 2.0, "pmin": 2.0, "pmean": 2.0,
-    "ppermute": 1.0, "pbroadcast": 1.0, "all_gather": 1.0,
+    "psum": 2.0, "psum2": 2.0, "psum_invariant": 2.0, "pmax": 2.0,
+    "pmin": 2.0, "pmean": 2.0, "ppermute": 1.0, "pbroadcast": 1.0,
+    "all_gather": 1.0, "all_gather_invariant": 1.0,
     "all_to_all": 1.0, "reduce_scatter": 1.0, "psum_scatter": 1.0,
     "pgather": 1.0, "pshuffle": 1.0,
 }
@@ -124,7 +128,7 @@ def collective_cost_bytes(jaxpr) -> int:
     bound, stated rather than guessed). One number per graph so the
     planner's comms term and a test can pin it."""
     from ..core.graph_trace import sub_jaxprs
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     from .framework import aval_nbytes
 
     total = 0.0

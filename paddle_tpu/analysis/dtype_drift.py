@@ -11,12 +11,16 @@ the drift is statically visible.
 
 Three rules, each anchored to a concrete failure:
 
-* **wide-dot** (error): a ``dot_general``/``conv`` computing in f32+
-  where an operand's value *originates* from the declared narrow dtype
+* **wide-dot** (error): a ``dot_general``/``conv`` with an f32+
+  OPERAND whose value *originates* from the declared narrow dtype
   (reached the dot through casts/elementwise ops). Deliberate f32
   islands — softmax stats, rms-norm accumulation, rope angles — are
   elementwise/reduction math and never trip this; only a GEMM pulled
   up to f32 does. That is exactly the f32-weight-in-bf16-model bug.
+  Narrow operands with an f32 ACCUMULATOR
+  (``preferred_element_type=f32``) are not drift: that is how the MXU
+  multiplies, and the only accumulator the chip's kernel compiler
+  accepts — the weight stream and the multiply width are unchanged.
 * **const-pollution** (error): a non-scalar f32 constant (a baked-in
   table or weight captured by closure) forcing a bf16 operand's upcast
   in a binary op. Scalar literals (eps, mask values) are exempt — f32
@@ -140,8 +144,15 @@ class DtypeDriftPass(LintPass):
             if (narrow_name is not None and prim in _DOT_PRIMS
                     and eqn.outvars):
                 out_dt = getattr(eqn.outvars[0].aval, "dtype", None)
+                wide_operand = any(
+                    _is_float(a.aval.dtype)
+                    and _width(a.aval.dtype) > _width(narrow)
+                    for a in eqn.invars[:2]
+                    if getattr(getattr(a, "aval", None), "dtype", None)
+                    is not None)
                 if (out_dt is not None and _is_float(out_dt)
                         and _width(out_dt) > _width(narrow)
+                        and wide_operand
                         and narrow_name in in_orig):
                     # declared f32 islands (e.g. the MoE router GEMM,
                     # fp32-by-design for stable softmax) are suppressed

@@ -129,7 +129,7 @@ class GraphTarget:
     host-sync pass applies its output-size budget only there.
     """
     name: str
-    jaxpr: Any                              # jax.core.ClosedJaxpr
+    jaxpr: Any                              # jax.extend.core.ClosedJaxpr
     compute_dtype: Any = None               # declared model dtype
     donated_outputs: Tuple[int, ...] = ()
     slots: int = 1

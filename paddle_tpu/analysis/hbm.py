@@ -66,7 +66,7 @@ class HbmEstimate:
 def _internal_peak(jaxpr) -> int:
     """Peak bytes of values CREATED inside ``jaxpr`` (its invars alias
     buffers that the caller already accounts for)."""
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     last: Dict[Any, int] = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for a in eqn.invars:
@@ -97,7 +97,7 @@ def estimate_hbm_peak(target: GraphTarget, top_k: int = 8
                       ) -> HbmEstimate:
     """Liveness-walk ``target.jaxpr`` and return the per-device peak
     estimate with its top-k live contributors."""
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     closed = target.jaxpr
     jaxpr = closed.jaxpr
     # make_jaxpr over a jitted fn wraps everything in one pjit: inline

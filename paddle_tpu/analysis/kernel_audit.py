@@ -61,7 +61,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from .framework import Finding, Severity
 
@@ -202,7 +202,7 @@ _UNKNOWN = _Unknown()
 _MAX_EAGER_BYTES = 16 * 2 ** 20
 
 #: higher-order primitives we recurse into rather than execute
-_CALL_PRIMS = {"pjit", "closed_call", "core_call", "remat", "remat2",
+_CALL_PRIMS = {"jit", "closed_call", "core_call", "remat", "remat2",
                "custom_jvp_call", "custom_vjp_call",
                "custom_vjp_call_jaxpr"}
 
@@ -314,9 +314,10 @@ def extract_pallas_calls(fn, args) -> List[ExtractedCall]:
 # ---------------------------------------------------------------------------
 
 def _block_dims(bm) -> Tuple[int, ...]:
-    """Block shape with squeezed (Mapped) dims as 1."""
-    return tuple(int(d) if isinstance(d, (int, np.integer)) else 1
-                 for d in bm.block_shape)
+    """Block shape in elements, squeezed dims as 1 (``block_shape``
+    entries are ``pl.Blocked``/``pl.Element`` carrying ``block_size``,
+    or ``pl.Squeezed``)."""
+    return tuple(int(getattr(d, "block_size", 1)) for d in bm.block_shape)
 
 
 def _block_memory_space(bm):
@@ -340,13 +341,13 @@ def vmem_footprint(call: ExtractedCall) -> Dict[str, Any]:
     blocks_bytes = 0
     for bm in gm.block_mappings:
         nbytes = int(np.prod(_block_dims(bm))) * np.dtype(
-            bm.array_shape_dtype.dtype).itemsize
+            bm.array_aval.dtype).itemsize
         pipelined = _is_pipelined_vmem(bm)
         contrib = 2 * nbytes if pipelined else 0
         blocks_bytes += contrib
         blocks.append({"origin": str(bm.origin),
                        "block": list(_block_dims(bm)),
-                       "dtype": str(bm.array_shape_dtype.dtype),
+                       "dtype": str(bm.array_aval.dtype),
                        "bytes": nbytes, "pipelined": pipelined,
                        "vmem_bytes": contrib})
     scratch_bytes = 0
@@ -440,7 +441,7 @@ def _eval_index_map(bm, grid, scalar_values, ctx: str) -> np.ndarray:
         return np.stack(outs, axis=-1)
 
     def one(ij):
-        res = jax_core.eval_jaxpr(dj, consts, *ij, *scalar_args)
+        res = jax.core.eval_jaxpr(dj, consts, *ij, *scalar_args)
         return [jnp.asarray(r, jnp.int32) for r in res[:n_out]]
 
     with jax.ensure_compile_time_eval():
@@ -469,7 +470,7 @@ def _check_ka002(call: ExtractedCall, ctx: str, emit) -> int:
         idx = _eval_index_map(bm, grid, scalars, bctx)
         checked += 1
         bdims = np.array(_block_dims(bm), np.int64)
-        adims = np.array(bm.array_shape_dtype.shape, np.int64)
+        adims = np.array(bm.array_aval.shape, np.int64)
         starts = idx * bdims
         bad_lo = starts < 0
         bad_hi = starts + bdims > adims
@@ -677,7 +678,7 @@ def _is_float(dt) -> bool:
 def _check_ka004(call: ExtractedCall, ctx: str, emit) -> int:
     gm = call.eqn.params["grid_mapping"]
     kjaxpr = call.eqn.params["jaxpr"]
-    low = any(_is_low_precision(bm.array_shape_dtype.dtype)
+    low = any(_is_low_precision(bm.array_aval.dtype)
               for bm in gm.block_mappings)
     if not low:
         return 0
@@ -831,8 +832,10 @@ def audit_config(kind: str, geom: Dict[str, Any],
                  use_cache: bool = True) -> Dict[str, Any]:
     """The flywheel admission verdict for one autotune winner:
     ``{"ok": bool, "rules": [rule, ...], "detail": str}``. Unknown
-    kinds fail closed with rule ``unregistered``; a launch that cannot
-    even trace fails with rule ``build``."""
+    kinds fail closed with rule ``unregistered``; a launch the auditor
+    cannot trace (``KernelAuditError``) fails with rule ``build``. Any
+    other exception is a fault in the auditor or the kernel and
+    propagates — it must not read as "this winner is inadmissible"."""
     key = (kind, tuple(sorted((k, str(v)) for k, v in geom.items())),
            tuple(sorted((k, str(v)) for k, v in (config or {}).items())))
     if use_cache and key in _VERDICT_CACHE:
@@ -845,7 +848,7 @@ def audit_config(kind: str, geom: Dict[str, Any],
         try:
             findings, _, _, _ = audit_kernel(
                 spec.name, geom, config, rules=GATE_RULES)
-        except Exception as e:
+        except KernelAuditError as e:
             verdict = {"ok": False, "rules": ["build"],
                        "detail": f"{type(e).__name__}: {e}"}
         else:

@@ -15,7 +15,7 @@ binding:
   the idiom from outside. Re-using a name (or the same node instance)
   at two operand positions requires the SAME value at both — how
   ``mul(x, x)`` expresses "the square of one thing".
-* ``Lit("name")`` — a scalar ``jax.core.Literal`` operand, captured as
+* ``Lit("name")`` — a scalar ``jax.extend.core.Literal`` operand, captured as
   a Python number (static to the replacement: eps, axis sizes).
 * ``Op(prims, *operands, params=..., commute=...)`` — an equation whose
   primitive is in ``prims``; ``params`` entries are exact values or
@@ -37,7 +37,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from jax._src import core as jax_core
+import jax
+from jax.extend import core as jax_core
 
 from ..core.graph_trace import producer_map, var_use_sites
 
@@ -194,7 +195,7 @@ def _match_node(pat: Pat, atom, producers, st: _State) -> Optional[_State]:
     if isinstance(pat, In):
         aval = getattr(atom, "aval", None)
         if isinstance(atom, jax_core.Literal):
-            aval = jax_core.get_aval(atom.val)
+            aval = jax.typeof(atom.val)
         if not pat.ok(aval):
             return None
         if not _bind(st, pat.name, atom):

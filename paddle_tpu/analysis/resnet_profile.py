@@ -63,7 +63,7 @@ def _sub_jaxpr_fn(level, m):
     Jaxpr whose inputs are the values flowing into the match from
     outside (literals stay inline)."""
     import jax
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     idxs = sorted(m.eqn_idxs)
     eqns = [level.eqns[i] for i in idxs]
     produced = {o for e in eqns for o in e.outvars}
@@ -88,7 +88,7 @@ def _unfused_cost(level, m) -> Dict[str, float]:
     fused-region numbers alongside show what XLA's own fusion already
     recovers when it gets the whole region in one compile."""
     import jax
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     tot = {"flops": 0.0, "bytes": 0.0}
     for i in sorted(m.eqn_idxs):
         eqn = level.eqns[i]
@@ -200,7 +200,7 @@ def profile_resnet(depth: int = 50, image: int = 224, batch: int = 8,
         regions.append(row)
 
     # full-graph A/B: original vs rewritten program, same flat inputs
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     res = rewrite_target(target, rules)
     flat_in = [jax.device_put(_seed_value(a, rng))
                for a in res.closed.in_avals]

@@ -68,7 +68,7 @@ _REBUILDABLE = frozenset({"scan", "pjit", "closed_call", "core_call",
 
 
 def _closed(jaxpr):
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     if isinstance(jaxpr, jax_core.ClosedJaxpr):
         return jaxpr
     return jax_core.ClosedJaxpr(jaxpr, ())
@@ -156,7 +156,7 @@ class _Rewriter:
 
     # -- evaluation --------------------------------------------------
     def run(self, closed, *args) -> List[Any]:
-        from jax._src import core as jax_core
+        from jax.extend import core as jax_core
         closed = _closed(closed)
         jaxpr = closed.jaxpr
         if len(args) != len(jaxpr.invars):
@@ -211,7 +211,7 @@ def _replacement_fits(rule: RewritePass, m: Match) -> bool:
     AND dtype) — a match whose substitute would change the graph's
     types is not a match."""
     import jax
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     try:
         args = []
         for n in rule.arg_names:
@@ -467,7 +467,7 @@ def verify_site(jaxpr, rule: RewritePass, m: Match,
     a one-ulp weight difference — unbounded and graph-dependent, so the
     suite never does that; whole-graph equivalence is only asserted
     bitwise, when every firing rule is bitwise.)"""
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     if isinstance(jaxpr, jax_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     idxs = sorted(m.eqn_idxs)
@@ -550,7 +550,7 @@ def verify_rewrite(res: RewriteResult,
        proves the rewritten callable is re-jittable.
     """
     import jax
-    from jax._src import core as jax_core
+    from jax.extend import core as jax_core
     rules = list(rules) if rules is not None else default_rewrites()
     if not any(res.fired.values()):
         return VerifyOutcome(ok=True, mode="no-op",

@@ -21,7 +21,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Set, Tuple
 
 import jax
-from jax._src import core as jax_core
+from jax.extend import core as jax_core
 
 from .tensor import Tensor
 

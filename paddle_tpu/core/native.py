@@ -2,14 +2,19 @@
 
 The reference's runtime core is native C++ (SURVEY.md §2.1: allocator
 facade, TCPStore, shm transfer). Ours is too: paddle_tpu/csrc/*.cc compiles
-into one libpaddle_tpu_rt.so at first use (g++ -O2 -shared; no network, no
-extra deps) and binds via ctypes. Everything degrades gracefully: if no
-toolchain is available, ``lib()`` returns None and pure-Python fallbacks
-take over (callers must check).
+into one libpaddle_tpu_rt-<hash>.so at first use (g++ -O2 -shared; no
+network, no extra deps) and binds via ctypes. The library is never
+committed (``*.so`` is git-ignored) and its name carries a hash of the
+sources and the compile command, so a library built from other sources
+— one that travelled with a copy of the tree, whatever its mtime — is
+never loaded. Everything degrades gracefully: if no toolchain is
+available, ``lib()`` returns None and pure-Python fallbacks take over
+(callers must check).
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,21 +25,33 @@ _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "csrc")
-_SO = os.path.join(_CSRC, "libpaddle_tpu_rt.so")
 _SOURCES = ["allocator.cc", "shm_ring.cc", "tcp_store.cc"]
+_CXX = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread"]
+
+
+def _so_path() -> str:
+    """Library path keyed on what it is built FROM: source bytes +
+    compile command."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    for s in _SOURCES:
+        with open(os.path.join(_CSRC, s), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_CSRC, f"libpaddle_tpu_rt-{h.hexdigest()[:16]}.so")
 
 
 def _build() -> Optional[str]:
-    srcs = [os.path.join(_CSRC, s) for s in _SOURCES]
-    if os.path.exists(_SO) and all(
-            os.path.getmtime(_SO) >= os.path.getmtime(s) for s in srcs):
-        return _SO
-    cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread",
-           *srcs, "-lrt", "-o", _SO + ".tmp"]
+    so = _so_path()
+    if os.path.exists(so):
+        return so
+    # a temporary name of this process's own: several test workers
+    # build at once, and a shared ".tmp" is overwritten mid-link
+    tmp = f"{so[:-3]}.{os.getpid()}.so.tmp"
+    cmd = [*_CXX, *(os.path.join(_CSRC, s) for s in _SOURCES),
+           "-lrt", "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=240)
-        os.replace(_SO + ".tmp", _SO)
-        return _SO
+        os.replace(tmp, so)
+        return so
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
             FileNotFoundError, OSError) as e:
         err = getattr(e, "stderr", b"")

@@ -36,10 +36,14 @@ def set_device(device: str):
     idx = int(idx) if idx else 0
     cands = [d for d in jax.devices() if d.platform == plat] or \
             ([d for d in jax.local_devices(backend="cpu")] if plat == "cpu" else [])
-    if not cands:
-        # tolerate 'tpu' requests on CPU-only test rigs: fall back
-        cands = jax.devices()
-    _current_device = cands[min(idx, len(cands) - 1)]
+    if idx >= len(cands):
+        # no fallback to "whatever exists" and no clamping: 'tpu:3' on
+        # one chip must not silently mean chip 0 (or the CPU)
+        raise ValueError(
+            f"set_device({device!r}): this process has {len(cands)} "
+            f"{plat!r} device(s); available: "
+            f"{sorted({d.platform for d in jax.devices()})}")
+    _current_device = cands[idx]
     return _current_device
 
 
@@ -81,9 +85,16 @@ class _Place:
 
     def jax_device(self):
         devs = [d for d in jax.devices() if d.platform == self._platform]
-        if not devs:  # fall back to default (e.g. CUDAPlace on a TPU host)
+        if not devs:
+            # API-compat places (CUDAPlace in ported code) name a KIND
+            # of device this build may not have; they resolve to the
+            # default device. The index is never clamped.
             devs = jax.devices()
-        return devs[min(self._id, len(devs) - 1)]
+        if self._id >= len(devs):
+            raise ValueError(
+                f"{self!r}: device index {self._id} out of range "
+                f"({len(devs)} device(s))")
+        return devs[self._id]
 
     def __repr__(self):
         return f"{type(self).__name__}({self._id})"

@@ -27,16 +27,10 @@ from typing import Dict, List, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from ..ops.pallas import on_tpu as _on_tpu
 
 __all__ = ["PagePool", "paged_attention", "write_prompt_pages",
            "write_token_pages", "apply_defrag"]
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 class PagePool:
@@ -267,9 +261,9 @@ def _stats_call(q, k_pages, v_pages, lengths, page_indices,
     dimension_semantics = ("parallel", "arbitrary", "arbitrary")
     in_specs = [
         q_block_spec,
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         None,
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         None,
     ]
     scratch_shapes = (

@@ -240,10 +240,7 @@ def _fused_nr_on(cfg: LlamaConfig, mesh) -> bool:
         return False
     if v in (True, "pallas"):
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _spec_divides(mesh, spec, shape) -> bool:
@@ -395,7 +392,7 @@ def _train_attn_fn(cfg: LlamaConfig, mesh):
     fa = cfg.use_flash_attention
     impl = fa if isinstance(fa, str) else ("auto" if fa else "dense")
     if _tp_heads_shardable(cfg, mesh):
-        from .._compat import shard_map
+        from jax import shard_map
         dp_ax = "dp" if "dp" in mesh.shape else None
         spec = P(dp_ax, None, "tp", None)
         body = lambda ql, kl, vl: _fa(ql, kl, vl, causal=True, impl=impl)

@@ -25,13 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+from . import on_tpu as _on_tpu
 
 
 def _pick_tile(dim: int, cap: int, step: int) -> int:

@@ -25,17 +25,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ..._compat import shard_map
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+from . import on_tpu as _on_tpu
 
 
 # ---------------------------------------------------------------------------

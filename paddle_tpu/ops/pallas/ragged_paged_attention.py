@@ -79,6 +79,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from . import on_tpu as _on_tpu
 
 __all__ = ["ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_paged_attention_packed", "default_kv_tile_pages",
@@ -168,11 +169,20 @@ def default_kv_tile_pages(pages_per_slot: int, page_size: int,
                max(1, DEFAULT_TILE_KV_TOKENS // int(page_size)))
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def _mxu_dot(a, b, dims):
+    """Operand-dtype ``dot_general`` the way the MXU runs it: narrow
+    operands, f32 accumulator, result rounded ONCE to the operand
+    dtype. Mosaic refuses a 16-bit accumulator (``Expected matmul acc
+    to be 32-bit``); rounding the f32 accumulator back keeps the
+    arithmetic of ``_packed_impl``'s operand-dtype dots. The precision
+    is pinned because under a process-wide "highest" Mosaic refuses a
+    bf16 matmul (``Bad lhs type``) and narrow products are exact in
+    f32 anyway. f32 operands keep the plain dot."""
+    if a.dtype == jnp.float32:
+        return jax.lax.dot_general(a, b, dims)
+    return jax.lax.dot_general(
+        a, b, dims, precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32).astype(a.dtype)
 
 
 def _attend(qs, ks, vs, q_len, kv_len, tq: int):
@@ -191,12 +201,7 @@ def _attend(qs, ks, vs, q_len, kv_len, tq: int):
     kmask = jax.lax.broadcasted_iota(jnp.int32, (kv_max, 1), 0) < kv_len
     ks = jnp.where(kmask, ks, 0)
     vs = jnp.where(kmask, vs, 0)
-    # scores dot in the operand dtype, f32 only from the softmax on —
-    # the repo-wide attention convention the dtype-drift pass enforces
-    # (a preferred_element_type=f32 here reads as a silently widened
-    # GEMM on bf16-origin data)
-    s = jax.lax.dot_general(qs, ks,
-                            (((1,), (1,)), ((), ()))).astype(jnp.float32)
+    s = _mxu_dot(qs, ks, (((1,), (1,)), ((), ()))).astype(jnp.float32)
     t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % tq
     k_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     # bottom-right causal: row t sees keys 0 .. (kv_len - q_len) + t;
@@ -207,8 +212,7 @@ def _attend(qs, ks, vs, q_len, kv_len, tq: int):
     p = jnp.exp(s - m)
     p = jnp.where(mask, p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jax.lax.dot_general(p.astype(vs.dtype), vs,
-                            (((1,), (0,)), ((), ())))
+    o = _mxu_dot(p.astype(vs.dtype), vs, (((1,), (0,)), ((), ())))
     # fully-masked rows (padding, empty slots): l == 0 -> emit 0, not NaN
     return (o / jnp.where(l > 0, l, 1.0).astype(o.dtype)).astype(vs.dtype)
 
@@ -236,10 +240,7 @@ def _flash_tile(qs, ks_t, vs_t, k0, q_len, kv_len, tq: int, m, l, acc):
     vmask = (k0 + jax.lax.broadcasted_iota(jnp.int32, (tile_kv, 1), 0)
              < kv_len)
     vs_t = jnp.where(vmask, vs_t, 0)
-    # scores dot in the operand dtype, f32 from the combine on — the
-    # same dtype convention as _attend
-    s = jax.lax.dot_general(qs, ks_t,
-                            (((1,), (1,)), ((), ()))).astype(jnp.float32)
+    s = _mxu_dot(qs, ks_t, (((1,), (1,)), ((), ()))).astype(jnp.float32)
     t = jax.lax.broadcasted_iota(jnp.int32, (gt, tile_kv), 0) % tq
     mask = (t < q_len) & (k_idx <= (kv_len - q_len) + t)
     s = jnp.where(mask, s, _MASK)
@@ -248,7 +249,7 @@ def _flash_tile(qs, ks_t, vs_t, k0, q_len, kv_len, tq: int, m, l, acc):
     p = jnp.where(mask, p, 0.0)
     alpha = jnp.exp(m - m_new)
     l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_new = acc * alpha + jax.lax.dot_general(
+    acc_new = acc * alpha + _mxu_dot(
         p.astype(vs_t.dtype), vs_t,
         (((1,), (0,)), ((), ()))).astype(jnp.float32)
     return m_new, l_new, acc_new
@@ -359,8 +360,8 @@ def _pallas_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq, g,
             grid=(S, Hkv),
             in_specs=[
                 block,
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=block,
             scratch_shapes=[
@@ -377,11 +378,13 @@ def _pallas_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq, g,
                 pltpu.VMEM((pps, page_size, Dh), v_pages.dtype),  # noqa: PT004 — one-shot by design, KA001-audited
                 pltpu.SemaphoreType.DMA((2, pps)),
             ]),
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         out_shape=jax.ShapeDtypeStruct(qs.shape, k_pages.dtype),
         interpret=interpret,
+        # a stable name: how the kernel shows in lowered and compiled
+        # program text (chip_smoke.py) and, later, in a device trace
+        name="ragged_paged_attention",
     )(q_len, kv_len, tables.reshape(-1), qs, k_pages, v_pages)
 
 
@@ -476,8 +479,8 @@ def _pallas_tiled_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq,
             grid=(S, Hkv),
             in_specs=[
                 block,
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=block,
             scratch_shapes=[
@@ -485,11 +488,11 @@ def _pallas_tiled_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq,
                 pltpu.VMEM((2, tile_pages, page_size, Dh), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2, tile_pages)),
             ]),
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         out_shape=jax.ShapeDtypeStruct(qs.shape, k_pages.dtype),
         interpret=interpret,
+        name="ragged_paged_attention_tiled",
     )(q_len, kv_len, tables.reshape(-1), qs, k_pages, v_pages)
 
 

@@ -29,7 +29,7 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from .._compat import shard_map
+from jax import shard_map
 
 _NEG_INF = -1e30
 

@@ -755,7 +755,7 @@ def pipeline_train_async(
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from .._compat import shard_map
+    from jax import shard_map
 
     S, V = int(num_stages), int(virtual_chunks)
     M = x.shape[0]

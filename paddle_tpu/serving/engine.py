@@ -1136,6 +1136,61 @@ class ServingEngine:
         if self.sentinel is not None:
             self.sentinel.arm()
 
+    def _pad_tick_args(self):
+        """The all-padding tick arguments ``warm_programs`` and
+        ``program_texts`` share: ``(pad_meta(T), tabs, zs, samp)``
+        — every packed token is the padding sentinel, every KV write
+        lands on the trash page."""
+        jnp = self._jnp
+        S = self.scheduler.max_batch
+        pps = self.scheduler.pages_per_slot
+        tabs = np.full((S, pps), PagePool.TRASH, np.int32)
+        zs = np.zeros((S,), np.int32)
+        samp = dict(temp=jnp.asarray(np.zeros((S,), np.float32)),
+                    top_p=jnp.asarray(np.ones((S,), np.float32)),
+                    top_k=jnp.asarray(zs),
+                    key=jnp.asarray(np.zeros((S, 2), np.uint32)),
+                    produced=jnp.asarray(zs))
+
+        def pad_meta(T):
+            return dict(
+                tok_slot=jnp.asarray(np.full((T,), S, np.int32)),
+                tok_pos=jnp.asarray(np.zeros((T,), np.int32)),
+                tok_page=jnp.asarray(
+                    np.full((T,), PagePool.TRASH, np.int32)),
+                tok_off=jnp.asarray(np.zeros((T,), np.int32)),
+                tok_qoff=jnp.asarray(np.zeros((T,), np.int32)),
+                q_len=jnp.asarray(zs), kv_len=jnp.asarray(zs),
+                last=jnp.asarray(zs), tables=jnp.asarray(tabs),
+                tail_live=jnp.asarray(np.zeros((S,), bool)),
+                **samp)
+
+        return pad_meta, tabs, zs, samp
+
+    def program_texts(self) -> Dict[str, str]:
+        """Lowered (StableHLO) text of every program ``warm_programs``
+        compiles on a non-speculative engine, traced at the same
+        padding arguments: ``{"tick@<w>": ..., "block": ...}``. Lets a
+        caller check what the programs ARE on this backend (a Pallas
+        kernel shows as a ``tpu_custom_call``) without reaching into
+        the jit objects. Nothing executes and no pool is donated."""
+        jnp = self._jnp
+        S = self.scheduler.max_batch
+        pad_meta, tabs, zs, samp = self._pad_tick_args()
+        out = {}
+        with self._tick_lock:
+            for w in self._w_grid:
+                T = S + w
+                out[f"tick@{w}"] = self._tick_jit.lower(
+                    self._params, jnp.asarray(np.zeros((T,), np.int32)),
+                    pad_meta(T), self._kp, self._vp, tq=w,
+                    decode_tail=0).as_text()
+            out["block"] = self._block_jit.lower(
+                self._params, jnp.asarray(zs), jnp.asarray(zs),
+                jnp.asarray(tabs), self._kp, self._vp,
+                num_steps=self._decode_block, sampling=samp).as_text()
+        return out
+
     def warm_programs(self) -> int:
         """Eagerly compile every tick program the static inventory
         enumerates, via all-padding no-op ticks (every packed token is
@@ -1151,31 +1206,9 @@ class ServingEngine:
         written). Returns the number of jit invocations made."""
         jnp = self._jnp
         S = self.scheduler.max_batch
-        pps = self.scheduler.pages_per_slot
         n = 0
+        pad_meta, tabs, zs, samp = self._pad_tick_args()
         with self._tick_lock:
-            tabs = np.full((S, pps), PagePool.TRASH, np.int32)
-            zs = np.zeros((S,), np.int32)
-            samp = dict(temp=jnp.asarray(np.zeros((S,), np.float32)),
-                        top_p=jnp.asarray(np.ones((S,), np.float32)),
-                        top_k=jnp.asarray(zs),
-                        key=jnp.asarray(np.zeros((S, 2), np.uint32)),
-                        produced=jnp.asarray(zs))
-
-            def pad_meta(T):
-                m = dict(
-                    tok_slot=jnp.asarray(np.full((T,), S, np.int32)),
-                    tok_pos=jnp.asarray(np.zeros((T,), np.int32)),
-                    tok_page=jnp.asarray(
-                        np.full((T,), PagePool.TRASH, np.int32)),
-                    tok_off=jnp.asarray(np.zeros((T,), np.int32)),
-                    tok_qoff=jnp.asarray(np.zeros((T,), np.int32)),
-                    q_len=jnp.asarray(zs), kv_len=jnp.asarray(zs),
-                    last=jnp.asarray(zs), tables=jnp.asarray(tabs),
-                    tail_live=jnp.asarray(np.zeros((S,), bool)),
-                    **samp)
-                return m
-
             def spec_meta(T):
                 m = pad_meta(T)
                 k = self._spec_k
@@ -1432,17 +1465,24 @@ class ServingEngine:
         return bool(done)
 
     def _retire(self, slot: int, state: str) -> None:
-        req = self.scheduler.retire(slot, state)
+        def record(req):
+            # before the handle completes: a caller that exports the
+            # trace (or reads the counters) right after result()
+            # returns must find this request in both
+            self.metrics.inc({COMPLETED: "completed",
+                              CANCELLED: "cancelled",
+                              TIMED_OUT: "timed_out"}[state])
+            # whole-lifecycle span, submit -> retirement, on the slot
+            # track
+            self.tracer.add("request", f"slot{slot}", req.submit_t,
+                            req.finish_t, req=req.id, state=state,
+                            tokens=len(req.tokens))
+
+        self.scheduler.retire(slot, state, before_finish=record)
         self._cur_tok[slot] = 0
         self._produced[slot] = 0
         self._key_data[slot] = 0
         self._samp_cache = None
-        self.metrics.inc({COMPLETED: "completed", CANCELLED: "cancelled",
-                          TIMED_OUT: "timed_out"}[state])
-        # whole-lifecycle span, submit -> retirement, on the slot track
-        self.tracer.add("request", f"slot{slot}", req.submit_t,
-                        req.finish_t, req=req.id, state=state,
-                        tokens=len(req.tokens))
 
     def _emit_toks(self, slot: int, req: Request, toks_row,
                    j0: int, j1: int) -> None:

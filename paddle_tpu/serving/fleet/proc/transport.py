@@ -21,10 +21,11 @@ frames (wire.py schema) into
   deliberate shutdown, because a drained worker exiting is not a
   crash.
 
-Spawn discipline: the worker env (``JAX_PLATFORMS=cpu`` by default) is
-exported around ``Process.start()`` under a module lock so the child
-inherits it even before ``worker_main`` re-asserts it — JAX must never
-see the parent's accelerator from a worker.
+Spawn discipline: the worker env (``spec.process_env()`` —
+``JAX_PLATFORMS=<spec.platform>``) is exported around
+``Process.start()`` under a module lock so the child inherits it even
+before ``worker_main`` re-asserts it — a worker must never reach for a
+chip its parent holds.
 """
 from __future__ import annotations
 
@@ -95,9 +96,9 @@ class WorkerTransport:
         with _spawn_lock:
             # export the worker env around start() so the child
             # inherits it even before worker_main re-asserts it
-            saved = {k: os.environ.get(k) for k in spec.env}
-            os.environ.update(
-                {str(k): str(v) for k, v in spec.env.items()})
+            env = spec.process_env()
+            saved = {k: os.environ.get(k) for k in env}
+            os.environ.update({str(k): str(v) for k, v in env.items()})
             try:
                 self._proc = self._ctx.Process(
                     target=worker_main, args=(spec, self._cmd,

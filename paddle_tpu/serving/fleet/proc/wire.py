@@ -8,9 +8,9 @@ Worker spec (pickled once, at spawn)
     fresh process: config kwargs (dtype as a STRING — jnp dtypes do not
     pickle portably), a params seed (every worker re-derives identical
     weights from ``PRNGKey(params_seed)``, which is what makes
-    re-dispatch after a crash bitwise-safe), engine kwargs, and the env
-    to pin before JAX initializes (``JAX_PLATFORMS=cpu`` by default —
-    workers must never grab the parent's accelerator).
+    re-dispatch after a crash bitwise-safe), engine kwargs, the JAX
+    platform the worker runs on (stated, never defaulted; pinned in
+    the worker's environment before JAX initializes).
 
 Command frames (parent -> worker, on the command queue)
     ``("rpc", seq, op, payload)``   request/reply; the worker answers
@@ -69,6 +69,12 @@ __all__ = ["WorkerSpec", "request_to_wire", "request_from_wire"]
 class WorkerSpec:
     """Everything a spawned worker needs to build its engine.
 
+    ``platform`` is the JAX platform the worker runs on, stated by the
+    caller (no default: a fleet pinned to the CPU by omission looks
+    like a chip run). A chip belongs to one process, so ``"tpu"``
+    workers need a parent that stays off JAX and a host with a chip
+    per worker; the worker refuses to serve on any other platform than
+    the one named here.
     ``cfg_kw`` are ``LlamaConfig`` kwargs with ``dtype`` as a string
     (``"float32"``); ``engine_kw`` are ``ServingEngine`` kwargs.
     ``params_seed`` feeds ``jax.random.PRNGKey`` — every worker in a
@@ -76,11 +82,15 @@ class WorkerSpec:
     the same stream on any replica (greedy/fixed-seed sampling is
     deterministic given identical weights).
     """
+    platform: str
     cfg_kw: dict = field(default_factory=dict)
     params_seed: int = 0
     engine_kw: dict = field(default_factory=dict)
-    env: dict = field(default_factory=lambda: {"JAX_PLATFORMS": "cpu"})
     warm: bool = False
+
+    def process_env(self) -> dict:
+        """What the worker's environment must hold before JAX starts."""
+        return {"JAX_PLATFORMS": self.platform}
 
 
 def request_to_wire(req) -> dict:

@@ -3,8 +3,9 @@
 Spawn-safe by construction: this module imports ONLY stdlib + wire at
 module scope (the spawn child imports it to find :func:`worker_main`
 before anything pins the JAX platform), and :func:`worker_main` sets
-``spec.env`` FIRST — so ``JAX_PLATFORMS=cpu`` (or a real accelerator
-assignment) is in place before JAX initializes any backend. Each worker
+``spec.process_env()`` FIRST — so ``JAX_PLATFORMS=<spec.platform>`` is
+in place before JAX initializes any backend, and the engine is built
+only if that backend is the one the spec names. Each worker
 then owns a full JAX runtime: its own compiled programs, its own page
 pool, its own engine worker thread — the GIL stops at the process
 boundary, which is the whole point of fleet/proc/ over the in-process
@@ -37,20 +38,15 @@ def _build_engine(spec):
     import jax
     import jax.numpy as jnp
 
-    # same persistent compile cache the test conftest uses: workers are
-    # fresh processes, so without this every spawn would pay every XLA
-    # compile from zero (the parent configures jax.config in-process,
-    # which a spawned child does not inherit)
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache",
-                     "paddle_tpu", "xla"))
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.1)
-    except Exception:
-        pass
+    # workers are fresh processes: without the persistent cache every
+    # spawn would pay every XLA compile from zero (the parent's
+    # jax.config is not inherited by a spawned child)
+    from paddle_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    if jax.default_backend() != spec.platform:
+        raise RuntimeError(
+            f"worker was asked for platform {spec.platform!r} and got "
+            f"{jax.default_backend()!r}")
 
     from ...engine import ServingEngine
     from paddle_tpu.models import llama as L
@@ -67,7 +63,8 @@ def _build_engine(spec):
 def worker_main(spec, cmd_q, evt_q) -> None:
     """Process target: build the engine, announce readiness, serve the
     command queue until ``stop`` / shutdown."""
-    os.environ.update({str(k): str(v) for k, v in spec.env.items()})
+    os.environ.update(
+        {str(k): str(v) for k, v in spec.process_env().items()})
     try:
         _run(spec, cmd_q, evt_q)
     except BaseException:

@@ -108,7 +108,8 @@ class Request:
 
     def finish(self, state: str) -> None:
         self.state = state
-        self.finish_t = time.monotonic()
+        if self.finish_t is None:
+            self.finish_t = time.monotonic()
         self.stream.put(_END)
         self.done.set()
 
@@ -362,10 +363,15 @@ class Scheduler:
             admitted.append((slot, req))
         return admitted
 
-    def retire(self, slot: int, state: str) -> Request:
+    def retire(self, slot: int, state: str,
+               before_finish=None) -> Request:
         """Free the slot immediately; private pages return to the pool,
         shared prefix pages are DECREF'd (they stay cached for the next
-        request with the same prefix); mark the request."""
+        request with the same prefix); mark the request.
+        ``before_finish(req)`` runs after ``finish_t`` is stamped and
+        BEFORE the handle completes — whatever it records (the
+        engine's ``request`` span and counter) is visible to a caller
+        the moment ``result()`` returns."""
         req = self.slots[slot]
         assert req is not None
         if req.prefix_nodes:
@@ -378,6 +384,9 @@ class Scheduler:
         self.slots[slot] = None
         self.tables[slot, :] = PagePool.TRASH
         self.lengths[slot] = 0
+        req.finish_t = time.monotonic()
+        if before_finish is not None:
+            before_finish(req)
         req.finish(state)
         return req
 

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from paddle_tpu._compat import shard_map
+from jax import shard_map
 from paddle_tpu.analysis import (TRAIN_GEOMETRIES,
                                  CollectiveConsistencyPass,
                                  DonationAuditPass, DtypeDriftPass,
@@ -106,6 +106,28 @@ def test_dtype_drift_catches_f32_weight_in_bf16_model():
     assert not DtypeDriftPass().run(t2)
 
 
+def test_dtype_drift_f32_accumulator_is_not_a_widened_gemm():
+    """bf16 operands with an f32 accumulator is how the MXU multiplies
+    — and the only accumulator Mosaic accepts (the ragged kernel's
+    ``_mxu_dot``); the drift is an f32 OPERAND of bf16 origin, also
+    when it arrives through an explicit upcast."""
+    def mxu(x, w):
+        return lax.dot_general(
+            x, w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(x.dtype)
+
+    def upcast(x, w):
+        return lax.dot_general(x.astype(jnp.float32), w.astype(jnp.float32),
+                               (((1,), (0,)), ((), ()))).astype(x.dtype)
+
+    args = (sds((4, 8), jnp.bfloat16), sds((8, 8), jnp.bfloat16))
+    ok = trace_graph("mxu", mxu, args, compute_dtype=jnp.bfloat16)
+    assert not DtypeDriftPass().run(ok)
+    bad = trace_graph("upcast", upcast, args, compute_dtype=jnp.bfloat16)
+    errs = _errors(DtypeDriftPass().run(bad))
+    assert errs and "dot_general" in errs[0].message
+
+
 def test_dtype_drift_catches_f32_const_pollution():
     table = jnp.asarray(np.linspace(0, 1, 16, dtype=np.float32))
 
@@ -138,7 +160,7 @@ def test_dtype_drift_scalar_eps_exempt_and_f64_flagged():
                     compute_dtype=jnp.bfloat16)
     assert not DtypeDriftPass().run(t)
 
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         def f64fn(x):
             return x.astype(jnp.float64) * 2.0

@@ -28,6 +28,25 @@ def test_register_after_backend_init_refuses(tmp_path):
         register_custom_device("fakechip", str(fake))
 
 
+def test_set_device_lands_on_the_device_it_names():
+    """No fallback to "whatever exists" and no index clamping: on this
+    CPU rig 'tpu' is an error, not the CPU, and 'cpu:99' is not cpu:0."""
+    from paddle_tpu import framework as fw
+    prev = fw.get_device()
+    try:
+        assert fw.set_device("cpu:1").id == 1
+        assert fw.get_device() == "cpu:1"
+        with pytest.raises(ValueError, match="0 'tpu' device"):
+            fw.set_device("tpu")
+        with pytest.raises(ValueError, match="'cpu' device"):
+            fw.set_device("cpu:99")
+        assert fw.get_device() == "cpu:1"     # a refusal moves nothing
+        with pytest.raises(ValueError, match="out of range"):
+            pt.CUDAPlace(99).jax_device()
+    finally:
+        fw.set_device(prev)
+
+
 def test_register_from_env_empty(monkeypatch):
     monkeypatch.delenv("PADDLE_TPU_CUSTOM_DEVICES", raising=False)
     assert register_custom_devices_from_env() == []

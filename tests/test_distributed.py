@@ -165,7 +165,7 @@ def test_single_process_collectives_identity():
 
 
 def test_functional_collectives_in_shard_map():
-    from paddle_tpu._compat import shard_map
+    from jax import shard_map
     hm = init_hybrid_mesh(dp=8, pp=1, tp=1, set_global=False)
     x = jnp.arange(8.0)
 

@@ -106,7 +106,7 @@ def test_ka003_trips_on_dropped_dma_wait():
         return pl.pallas_call(
             body,
             grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((64, 128), lambda i: (0, 0)),
             scratch_shapes=[pltpu.VMEM((2, 64, 128), jnp.float32),
                             pltpu.SemaphoreType.DMA((2,))],
@@ -131,7 +131,7 @@ def test_ka003_clean_when_wait_present():
         return pl.pallas_call(
             body,
             grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((64, 128), lambda i: (0, 0)),
             scratch_shapes=[pltpu.VMEM((2, 64, 128), jnp.float32),
                             pltpu.SemaphoreType.DMA((2,))],
@@ -278,6 +278,33 @@ def test_load_gate_skips_stale_winner(store):
     assert got is None
     assert any("kernel audit" in str(x.message)
                and "KA002" in str(x.message) for x in w)
+
+
+def test_auditor_fault_is_not_read_as_no_winner(store, monkeypatch):
+    """A fault INSIDE the auditor (an AttributeError after a jax
+    upgrade was the real one) propagates from both gates; swallowed, it
+    made ``lookup`` quietly drop every stored winner."""
+    at.record("fused_rms_norm", {"tile_n": 4},
+              rows=64, d=32, dtype="float32")
+    ka.clear_verdict_cache()
+
+    def broken(*a, **k):
+        raise AttributeError("module 'jax.core' has no attribute 'Literal'")
+
+    monkeypatch.setattr(ka, "audit_kernel", broken)
+    with pytest.raises(AttributeError, match="Literal"):
+        at.lookup("fused_rms_norm", rows=64, d=32, dtype="float32")
+    with pytest.raises(AttributeError, match="Literal"):
+        at.record("fused_rms_norm", {"tile_n": 8}, audit=True,
+                  rows=64, d=32, dtype="float32")
+    # a launch the auditor cannot trace is still a verdict, not a fault
+    monkeypatch.setattr(
+        ka, "audit_kernel",
+        lambda *a, **k: (_ for _ in ()).throw(ka.KernelAuditError("x")))
+    assert ka.audit_config("fused_rms_norm",
+                           {"rows": 64, "d": 32, "dtype": "float32"},
+                           {"tile_n": 4}, use_cache=False)["rules"] == ["build"]
+    ka.clear_verdict_cache()    # later tests audit this geometry for real
 
 
 def test_load_gate_env_escape_hatch(store, monkeypatch):

@@ -61,7 +61,7 @@ CFG_KW = dict(vocab_size=256, hidden_size=64, intermediate_size=128,
               dtype="float32", use_flash_attention=False, remat=False)
 ENGINE_KW = dict(max_batch=4, page_size=4, max_prompt_len=16,
                  max_new_tokens_cap=16)
-SPEC = WorkerSpec(cfg_kw=CFG_KW, params_seed=0, engine_kw=ENGINE_KW,
+SPEC = WorkerSpec("cpu", cfg_kw=CFG_KW, params_seed=0, engine_kw=ENGINE_KW,
                   warm=False)
 CFG = L.LlamaConfig(**{**CFG_KW, "dtype": jnp.float32})
 

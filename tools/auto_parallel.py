@@ -171,4 +171,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main(sys.argv[1:]))

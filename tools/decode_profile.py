@@ -97,8 +97,9 @@ def _span(name, **args):
 
 
 PRESETS = {
-    # bench.py flagship: the 1.72B decode whose 176.7 tok/s (BENCH_r05)
-    # this tool exists to explain
+    # bench.py flagship: the 1.72B decode whose 176.7 tok/s (a removed
+    # record of a machine that is gone; see ROADMAP.md) this tool was
+    # written to explain
     "flagship": dict(vocab_size=32000, hidden_size=4096,
                      intermediate_size=16384, num_hidden_layers=6,
                      num_attention_heads=32, num_key_value_heads=8),
@@ -632,4 +633,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

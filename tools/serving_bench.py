@@ -989,7 +989,11 @@ class Bench:
                          prefill_chunk=a.prefill_chunk or None,
                          admission_window=a.admission_window,
                          check_invariants=a.check_invariants or None)
-        return WorkerSpec(cfg_kw=cfg_kw, params_seed=0,
+        # workers on the CPU, said out loud: this parent has touched
+        # JAX, so on a chip host it HOLDS the chip and a worker that
+        # needed it would fail or hang. --proc measures the process
+        # boundary (IPC, GIL), never the device.
+        return WorkerSpec("cpu", cfg_kw=cfg_kw, params_seed=0,
                           engine_kw=engine_kw, warm=True)
 
     def _fleet_run(self, n, policy, strace, *, paced=True,
@@ -1167,7 +1171,8 @@ class Bench:
         flood_n = self._fleet_run(n, "affinity", ftrace, paced=False,
                                   sequential=False, proc=proc)
         out = {
-            "mode": "fleet", "proc": proc, "replicas": n,
+            "mode": "fleet", "proc": proc,
+            "worker_platform": "cpu" if proc else None, "replicas": n,
             "workload": {
                 "groups": a.fleet_groups,
                 "group_size": a.fleet_group_size,
@@ -1567,7 +1572,10 @@ def main(argv=None):
                     help="fleet mode: run replicas as worker "
                          "PROCESSES (serving.fleet.proc) instead of "
                          "in-process engines — same JSON schema, so "
-                         "the two are directly A/B-able")
+                         "the two are directly A/B-able. Workers run "
+                         "on the CPU (this parent holds whatever chip "
+                         "the host has): --proc prices the process "
+                         "boundary, not the device")
     ap.add_argument("--fleet-groups", type=int, default=8,
                     help="fleet mode: distinct shared-prefix sessions "
                          "(each gets its own system-prompt header)")
@@ -1674,4 +1682,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(sys.argv[1:])

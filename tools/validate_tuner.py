@@ -89,4 +89,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()
