@@ -193,8 +193,12 @@ def moe_ffn(
     S = xs.shape[0]
     capacity = default_capacity(S, E, top_k, capacity_factor)
 
-    logits = xs.astype(jnp.float32) @ gate_w.astype(jnp.float32)  # [S, E]
-    dispatch, combine, aux = top_k_gating(logits, top_k, capacity, key=key)
-    y = moe_expert_compute(xs, dispatch, combine, w_gate, w_up, w_down,
-                           ep_axis=ep_axis, activation=activation)
+    # scopes: metadata that names these operations in a device trace
+    with jax.named_scope("moe.router"):
+        logits = xs.astype(jnp.float32) @ gate_w.astype(jnp.float32)  # [S, E]
+        dispatch, combine, aux = top_k_gating(logits, top_k, capacity,
+                                              key=key)
+    with jax.named_scope("moe.experts"):
+        y = moe_expert_compute(xs, dispatch, combine, w_gate, w_up, w_down,
+                               ep_axis=ep_axis, activation=activation)
     return y.reshape(orig_shape), aux.astype(jnp.float32)

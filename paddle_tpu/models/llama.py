@@ -352,27 +352,34 @@ def _block(lp, h, positions, cfg: LlamaConfig, attn_fn, sp_spec=None,
         rope_fn = lambda q, k: fused_rope(q, k, positions, cfg.rope_theta)
     else:
         rope_fn = lambda q, k: rope(q, k, positions, cfg.rope_theta, Dh)
-    x = norm(h, lp["attn_norm"])
-    q = _mm(x, lp["wq"]).reshape(B, T, H, Dh)
-    k = _mm(x, lp["wk"]).reshape(B, T, Hkv, Dh)
-    v = _mm(x, lp["wv"]).reshape(B, T, Hkv, Dh)
-    q, k = rope_fn(q, k)
+    # named scopes are metadata on the operations (the program is the
+    # same): a device trace then says which line of the model an
+    # operation is
+    with jax.named_scope("attn.qkv_rope"):
+        x = norm(h, lp["attn_norm"])
+        q = _mm(x, lp["wq"]).reshape(B, T, H, Dh)
+        k = _mm(x, lp["wk"]).reshape(B, T, Hkv, Dh)
+        v = _mm(x, lp["wv"]).reshape(B, T, Hkv, Dh)
+        q, k = rope_fn(q, k)
     o = attn_fn(q, k, v)
     # tag for remat policies: lets a save_only_these_names policy keep the
     # kernel output so backward recompute skips the flash forward (the
     # default bench path uses plain per-layer remat, measured faster)
     o = checkpoint_name(o, "attn_out")
-    h = h + _mm(o.reshape(B, T, H * Dh), lp["wo"])
-    if sp_spec is not None:
-        # sequence-parallel residual stream: reduce-scatter the row-parallel
-        # output over tp along the seq dim (sequence_parallel_utils.py:427)
-        h = lax.with_sharding_constraint(h, sp_spec)
+    with jax.named_scope("attn.out"):
+        h = h + _mm(o.reshape(B, T, H * Dh), lp["wo"])
+        if sp_spec is not None:
+            # sequence-parallel residual stream: reduce-scatter the
+            # row-parallel output over tp along the seq dim
+            # (sequence_parallel_utils.py:427)
+            h = lax.with_sharding_constraint(h, sp_spec)
 
-    x = norm(h, lp["mlp_norm"])
-    h = h + _mm(jax.nn.silu(_mm(x, lp["w_gate"])) * _mm(x, lp["w_up"]),
-                lp["w_down"])
-    if sp_spec is not None:
-        h = lax.with_sharding_constraint(h, sp_spec)
+    with jax.named_scope("mlp"):
+        x = norm(h, lp["mlp_norm"])
+        h = h + _mm(jax.nn.silu(_mm(x, lp["w_gate"]))
+                    * _mm(x, lp["w_up"]), lp["w_down"])
+        if sp_spec is not None:
+            h = lax.with_sharding_constraint(h, sp_spec)
     return h
 
 
@@ -864,13 +871,15 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer=None,
         params = state["params"]
         if zero_stage >= 3:
             params = _constrain(params, fwd_pspecs)
-        if use_1f1b:
-            loss, grads = grads_1f1b(params, batch, cfg, mesh)
-        else:
-            loss, grads = jax.value_and_grad(loss_fn)(
-                params, batch, cfg, mesh)
-        updates, opt = optimizer.update(grads, state["opt"], params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("loss"):
+            if use_1f1b:
+                loss, grads = grads_1f1b(params, batch, cfg, mesh)
+            else:
+                loss, grads = jax.value_and_grad(loss_fn)(
+                    params, batch, cfg, mesh)
+        with jax.named_scope("optimizer"):
+            updates, opt = optimizer.update(grads, state["opt"], params)
+            params = optax.apply_updates(params, updates)
         if zero_stage >= 3:
             params = _constrain(params, stored_pspecs)
         return {"params": params, "opt": opt,
@@ -1596,7 +1605,8 @@ def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
     S = meta["q_len"].shape[0]
     tok_slot = meta["tok_slot"]
     tok_qoff = meta["tok_qoff"]
-    h = params["embed"].astype(cfg.dtype)[tokens[None]]        # [1, T, D]
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(cfg.dtype)[tokens[None]]    # [1, T, D]
     positions = meta["tok_pos"][None]
 
     def body(h, xs):
@@ -1605,26 +1615,32 @@ def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
 
         def attn_fn(q, k, v):
             # 1) land the span's KV in the pages (padding -> trash page)
-            kp2 = kp.at[:, meta["tok_page"], meta["tok_off"]].set(
-                k[0].transpose(1, 0, 2).astype(kp.dtype))
-            vp2 = vp.at[:, meta["tok_page"], meta["tok_off"]].set(
-                v[0].transpose(1, 0, 2).astype(vp.dtype))
+            with jax.named_scope("kv_pool.write"):
+                kp2 = kp.at[:, meta["tok_page"], meta["tok_off"]].set(
+                    k[0].transpose(1, 0, 2).astype(kp.dtype))
+                vp2 = vp.at[:, meta["tok_page"], meta["tok_off"]].set(
+                    v[0].transpose(1, 0, 2).astype(vp.dtype))
             cell["kp"], cell["vp"] = kp2, vp2
             # 2) one ragged launch over the pages (span KV included):
             # the packed entry keeps score work proportional to the T
             # real rows off-TPU and scatters to the kernel's slot-major
             # layout on TPU
-            o = ragged_paged_attention_packed(
-                q[0], kp2, vp2, tok_slot, tok_qoff, meta["q_len"],
-                meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl)
+            with jax.named_scope("ragged_attn"):
+                o = ragged_paged_attention_packed(
+                    q[0], kp2, vp2, tok_slot, tok_qoff, meta["q_len"],
+                    meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl)
             return o[None].astype(q.dtype)
 
         h = block_fn(lp, h, positions, cfg, attn_fn)
         return h, (cell["kp"], cell["vp"])
 
-    h, (kp_new, vp_new) = lax.scan(body, h, (params["layers"], k_pages,
-                                             v_pages))
-    h = rms_norm(h[0], params["final_norm"], cfg.rms_norm_eps)  # [T, D]
+    # an operation under bare ``layers`` is the scan's own: the slicing
+    # of a layer's weights and pool pages and their write-back
+    with jax.named_scope("layers"):
+        h, (kp_new, vp_new) = lax.scan(body, h, (params["layers"],
+                                                 k_pages, v_pages))
+    with jax.named_scope("lm_head"):
+        h = rms_norm(h[0], params["final_norm"], cfg.rms_norm_eps)  # [T, D]
     # fused sampling (r16): when the meta carries per-slot sampling
     # state — temp/top_p [S] f32, top_k [S] i32, key [S, 2] u32 raw
     # PRNG keys, produced [S] i32 (the continuation index of the token
@@ -1641,11 +1657,10 @@ def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
                                  meta["top_k"], meta["key"], idx)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    if spec_k:
-        # logits at EVERY span position of every slot — the verify
-        # pass's whole point: one launch prices 1+spec_k predictions
-        h_ver = h[meta["ver_idx"]]                  # [S, 1+spec_k, D]
-        logits_ver = _mm(h_ver, params["lm_head"]).astype(jnp.float32)
+    def verify(logits_ver):
+        """The verify pass's token at every span position and the
+        longest accepted draft prefix, ``(toks [S, 1+spec_k],
+        accept [S])``."""
         if samp:
             # SAMPLED acceptance (spec_k is no longer greedy-only):
             # span position j draws the token for continuation index
@@ -1676,12 +1691,24 @@ def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
                  & (j[None, :] < meta["draft_len"][:, None]))
         accept = jnp.cumprod(match.astype(jnp.int32), axis=1) \
                     .sum(axis=1).astype(jnp.int32)
+        return toks, accept
+
+    if spec_k:
+        # logits at EVERY span position of every slot — the verify
+        # pass's whole point: one launch prices 1+spec_k predictions
+        with jax.named_scope("lm_head"):
+            h_ver = h[meta["ver_idx"]]              # [S, 1+spec_k, D]
+            logits_ver = _mm(h_ver, params["lm_head"]).astype(jnp.float32)
+        with jax.named_scope("sampler"):
+            toks, accept = verify(logits_ver)
         # row 0 == the plain tick's logits for every non-speculating
         # slot (ver_idx[:, 0] = last there)
         return toks, accept, logits_ver[:, 0], kp_new, vp_new
-    h_last = h[meta["last"]]                                    # [S, D]
-    logits = _mm(h_last, params["lm_head"]).astype(jnp.float32)
-    toks = pick(logits, meta["produced"] if samp else None)
+    with jax.named_scope("lm_head"):
+        h_last = h[meta["last"]]                                # [S, D]
+        logits = _mm(h_last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("sampler"):
+        toks = pick(logits, meta["produced"] if samp else None)
     if not decode_tail:
         return toks, logits, kp_new, vp_new
 

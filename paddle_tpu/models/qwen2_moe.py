@@ -300,15 +300,20 @@ def _decode_block(lp, h, positions, cfg: Qwen2MoeConfig, attn_fn):
     B, T, D = h.shape
     H, Hkv, Dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                   cfg.head_dim)
-    x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
-    q = _mm(x, lp["wq"]).reshape(B, T, H, Dh)
-    k = _mm(x, lp["wk"]).reshape(B, T, Hkv, Dh)
-    v = _mm(x, lp["wv"]).reshape(B, T, Hkv, Dh)
-    q, k = rope(q, k, positions, cfg.rope_theta, Dh)
+    # the scopes of models/llama.py _block (metadata only); the MoE FFN
+    # brings ``moe.router`` and ``moe.experts`` (moe_ffn)
+    with jax.named_scope("attn.qkv_rope"):
+        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+        q = _mm(x, lp["wq"]).reshape(B, T, H, Dh)
+        k = _mm(x, lp["wk"]).reshape(B, T, Hkv, Dh)
+        v = _mm(x, lp["wv"]).reshape(B, T, Hkv, Dh)
+        q, k = rope(q, k, positions, cfg.rope_theta, Dh)
     o = attn_fn(q, k, v)
-    h = h + _mm(o.reshape(B, T, H * Dh), lp["wo"])
+    with jax.named_scope("attn.out"):
+        h = h + _mm(o.reshape(B, T, H * Dh), lp["wo"])
 
-    x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("moe.router"):     # the norm that feeds it
+        x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
     nodrop_cf = cfg.num_experts / cfg.num_experts_per_tok
     routed, _ = moe_ffn(
         x, lp["router"], _dense_w(lp["experts"]["w_gate"], cfg.dtype),
@@ -316,11 +321,12 @@ def _decode_block(lp, h, positions, cfg: Qwen2MoeConfig, attn_fn):
         _dense_w(lp["experts"]["w_down"], cfg.dtype),
         top_k=cfg.num_experts_per_tok,
         capacity_factor=nodrop_cf, ep_axis=None)
-    sh = lp["shared"]
-    shared = _mm(jax.nn.silu(_mm(x, sh["w_gate"]))
-                 * _mm(x, sh["w_up"]), sh["w_down"])
-    shared = jax.nn.sigmoid(x @ sh["gate"]) * shared
-    return h + routed + shared
+    with jax.named_scope("moe.shared"):
+        sh = lp["shared"]
+        shared = _mm(jax.nn.silu(_mm(x, sh["w_gate"]))
+                     * _mm(x, sh["w_up"]), sh["w_down"])
+        shared = jax.nn.sigmoid(x @ sh["gate"]) * shared
+        return h + routed + shared
 
 
 def forward_with_cache(params, tokens, cache, pos0, cfg: Qwen2MoeConfig):

@@ -9,7 +9,10 @@ anomaly instead of reconstructed after it.
 
     SpanTracer       — thread-safe bounded-ring span tracer; Chrome-
                        trace/Perfetto export, one track per engine
-                       phase + one per serving slot (tracer.py)
+                       phase + one per serving slot; every span is
+                       also a jax.profiler.TraceAnnotation, so a
+                       profiler session sees it beside the device's
+                       operations (tracer.py)
     FlightRecorder   — last-N-ticks ring + JSON postmortem dumped
                        automatically on KVInvariantError / engine-loop
                        crash (flight.py)
@@ -33,21 +36,4 @@ from .tracer import Span, SpanTracer, current_span  # noqa: F401
 
 __all__ = ["SpanTracer", "Span", "current_span", "FlightRecorder",
            "default_flight_dir", "RecompileSentinel", "RecompileWarning",
-           "COMPILE_EVENT", "RECOMPILES_METRIC", "bridge_record_events"]
-
-
-def bridge_record_events(tracer: SpanTracer, track: str = "profiler"):
-    """Mirror every closing ``profiler.RecordEvent`` span into
-    ``tracer`` on one ``track`` — device-trace annotations and the
-    serving engine's own spans then read in the same Perfetto export.
-    Returns a zero-arg detach callable."""
-    from .. import profiler
-
-    def _sink(name, t0_s, t1_s):
-        tracer.add(name, track, t0_s, t1_s)
-
-    profiler.add_span_sink(_sink)
-
-    def detach():
-        profiler.remove_span_sink(_sink)
-    return detach
+           "COMPILE_EVENT", "RECOMPILES_METRIC"]

@@ -18,8 +18,16 @@ Design constraints, in order:
   test, see docs/OBSERVABILITY.md);
 * **near-free when off** — ``enabled=False`` makes ``span()`` record
   nothing (no clock reads, no ring append); only the thread-local
-  span-name push/pop survives, so the recompile sentinel's "compile
-  during <span>" attribution stays correct with tracing disabled;
+  span-name push/pop and the profiler annotation survive, so the
+  recompile sentinel's "compile during <span>" attribution stays
+  correct with tracing disabled;
+* **one call site, two sinks** — ``span()`` and ``phases()`` also
+  enter a ``jax.profiler.TraceAnnotation`` of the same name and args,
+  whether or not the ring is enabled: a profiler session (the
+  benchmark's traced run) finds the engine's spans on plane
+  ``/host:CPU`` beside the device's operations, on the profiler's own
+  clock, with the args as the event's stats. Outside a session an
+  annotation costs about half a microsecond;
 * **never unbounded** — the ring is a ``deque(maxlen=capacity)``;
   old spans fall off, ``dropped`` counts them. A serving process can
   trace forever and export the recent window on demand (the flight
@@ -41,9 +49,20 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-__all__ = ["Span", "SpanTracer", "current_span"]
+__all__ = ["Span", "SpanTracer", "Phases", "current_span"]
 
 _tls = threading.local()
+_TraceAnnotation = None     # jax.profiler's, imported at first use
+
+
+def _annotate(name: str, args: Optional[dict]):
+    """An ENTERED profiler annotation; the caller exits it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    ann = _TraceAnnotation(name, **args) if args else _TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 def _span_stack() -> list:
@@ -90,19 +109,23 @@ class Span:
 class _StackOnlyCtx:
     """Disabled-tracer span: maintains the thread-local span-name
     stack (so ``current_span()`` — the recompile sentinel's ``during``
-    attribution — keeps working with tracing off) but records nothing:
-    no clock reads, no Span allocation, no ring append."""
+    attribution — keeps working with tracing off) and the profiler
+    annotation, but records nothing: no clock reads, no Span
+    allocation, no ring append."""
 
-    __slots__ = ("_name",)
+    __slots__ = ("_name", "_args", "_ann")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, args):
         self._name = name
+        self._args = args
 
     def __enter__(self):
         _span_stack().append(self._name)
+        self._ann = _annotate(self._name, self._args)
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         st = _span_stack()
         if st and st[-1] == self._name:
             st.pop()
@@ -112,7 +135,7 @@ class _StackOnlyCtx:
 class _SpanCtx:
     """Context manager recording one span on exit."""
 
-    __slots__ = ("_tr", "_name", "_track", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_track", "_args", "_t0", "_ann")
 
     def __init__(self, tr: "SpanTracer", name: str, track: str, args):
         self._tr = tr
@@ -122,17 +145,68 @@ class _SpanCtx:
 
     def __enter__(self):
         _span_stack().append(self._name)
+        self._ann = _annotate(self._name, self._args)
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.monotonic_ns()
+        self._ann.__exit__(*exc)
         st = _span_stack()
         if st and st[-1] == self._name:
             st.pop()
         self._tr._append(Span(self._name, self._track, self._t0, t1,
                               self._args, threading.get_ident()))
         return False
+
+
+class Phases:
+    """Contiguous spans on one track that partition a thread's time
+    (``SpanTracer.phases``): ``enter(name)`` ends the open phase and
+    starts the next at ONE clock read, so the phases of an iteration
+    sum to it exactly. Each phase is also a profiler annotation. The
+    ring gets the closed phases only at ``end(keep=True)`` — an
+    iteration that turns out idle leaves nothing there. Phases stay
+    off the ``current_span()`` stack: a compile is named after the
+    span it interrupted, not after the phase around it."""
+
+    __slots__ = ("_tr", "_track", "_args", "_closed", "_name", "_t0",
+                 "_ann")
+
+    def __init__(self, tr: "SpanTracer", track: str, args):
+        self._tr = tr
+        self._track = track
+        self._args = args
+        self._closed = []           # [(name, t0_ns, t1_ns)]
+        self._name = None
+
+    def stop(self) -> int:
+        """Ends the open phase; returns the boundary
+        (``time.monotonic_ns()``) for the ``enter(..., at=)`` that
+        follows a span opened or closed in between."""
+        t = time.monotonic_ns()
+        if self._name is not None:
+            self._ann.__exit__(None, None, None)
+            self._closed.append((self._name, self._t0, t))
+            self._name = None
+        return t
+
+    def enter(self, name: str, at: Optional[int] = None) -> None:
+        self._t0 = self.stop() if at is None else at
+        self._name = name
+        self._ann = _annotate(name, self._args)
+
+    def end(self, keep: bool = True) -> Dict[str, float]:
+        """Ends the open phase; with ``keep`` the phases go to the ring
+        (when it is enabled). Returns ``{name: seconds}``."""
+        self.stop()
+        tr = self._tr
+        if keep and tr.enabled:
+            tid = threading.get_ident()
+            for name, t0, t1 in self._closed:
+                tr._append(Span(name, self._track, t0, t1, self._args,
+                                tid))
+        return {name: (t1 - t0) / 1e9 for name, t0, t1 in self._closed}
 
 
 class SpanTracer:
@@ -160,12 +234,18 @@ class SpanTracer:
             self._ring.append(span)
 
     def span(self, name: str, track: Optional[str] = None, **args):
-        """Timed context manager; ``track`` defaults to the name.
-        Disabled tracers still publish the span name to
-        ``current_span()`` (sentinel attribution) but record nothing."""
+        """Timed context manager; ``track`` defaults to the name. The
+        span is also a ``jax.profiler.TraceAnnotation(name, **args)``.
+        Disabled tracers still annotate and still publish the span name
+        to ``current_span()`` (sentinel attribution) but record
+        nothing."""
         if not self.enabled:
-            return _StackOnlyCtx(name)
+            return _StackOnlyCtx(name, args or None)
         return _SpanCtx(self, name, track or name, args or None)
+
+    def phases(self, track: str, **args) -> Phases:
+        """A ``Phases`` on ``track``; every phase carries ``args``."""
+        return Phases(self, track, args or None)
 
     def add(self, name: str, track: str, t0_s: float, t1_s: float,
             **args) -> None:

@@ -71,27 +71,6 @@ _records = threading.local()
 _stats_lock = threading.Lock()
 _host_stats = defaultdict(lambda: [0, 0.0])  # name -> [count, total_s]
 
-# span sinks: callables (name, t0_s, t1_s) invoked as each RecordEvent
-# span closes, timestamps on time.monotonic(). The observability span
-# tracer bridges through here (observability.bridge_record_events) so
-# RecordEvent annotations land in Perfetto exports next to the serving
-# engine's own spans. Sink errors are swallowed — a broken exporter
-# must not take down the annotated hot path.
-_span_sinks = []
-
-
-def add_span_sink(fn) -> None:
-    """Register ``fn(name, t0_s, t1_s)`` for every closing RecordEvent
-    span (monotonic-clock seconds)."""
-    _span_sinks.append(fn)
-
-
-def remove_span_sink(fn) -> None:
-    try:
-        _span_sinks.remove(fn)
-    except ValueError:
-        pass
-
 
 class RecordEvent:
     """User annotation span (reference: paddle.profiler.RecordEvent /
@@ -111,22 +90,12 @@ class RecordEvent:
     def end(self):
         if self._ann is not None:
             dt = time.perf_counter() - self._t0
-            # sample the monotonic endpoint NEXT to dt, before the
-            # locked stats update / annotation teardown, so bridged
-            # spans are not translated late under lock contention
-            t1 = time.monotonic()
             with _stats_lock:
                 st = _host_stats[self.name]
                 st[0] += 1
                 st[1] += dt
             self._ann.__exit__(None, None, None)
             self._ann = None
-            if _span_sinks:
-                for fn in list(_span_sinks):
-                    try:
-                        fn(self.name, t1 - dt, t1)
-                    except Exception:
-                        pass
 
     def __enter__(self):
         self.begin()

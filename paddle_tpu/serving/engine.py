@@ -40,8 +40,9 @@ independent (row-wise model math + per-slot page tables).
 
 Tokens stream to callers through per-request iterators
 (``RequestHandle``); ``close()`` drains gracefully. Counters and
-latency histograms live in serving/metrics.py; prefill/decode spans are
-``profiler.RecordEvent``-annotated so they land in device traces.
+latency histograms live in serving/metrics.py; every span of the tick
+path is also a ``jax.profiler.TraceAnnotation`` (observability/
+tracer.py), so ticks and their phases land in device traces.
 
 Runtime observability (ISSUE r13, paddle_tpu/observability/): every
 tick records engine-phase and per-slot lifecycle spans into a bounded
@@ -58,7 +59,6 @@ from __future__ import annotations
 import os
 import threading
 import time
-from functools import partial
 from typing import Dict, Optional
 
 import numpy as np
@@ -69,7 +69,6 @@ import itertools
 
 from ..inference.paged_kv import PagePool, apply_defrag
 from ..observability import FlightRecorder, RecompileSentinel, SpanTracer
-from ..profiler import RecordEvent
 from .locktrace import get_tracer, host_sync, wrap_lock
 from .metrics import ServingMetrics
 from .prefix_cache import ColdTier, PrefixCache, _fp_extend
@@ -150,12 +149,25 @@ def _jit_step_fns(mod, cfg, attn_impl: str, rewrites: bool = False):
     # immediately, and without donation every tick pays a full pool
     # copy — measured 2-3x the whole step time on the CPU mesh at
     # bench shapes
-    tick = jax.jit(_rw(partial(mod.serving_tick, cfg=cfg,
-                               attn_impl=attn_impl)),
-                   donate_argnums=(3, 4),
+    # named wrappers, not bare partials: the function's name is the
+    # HLO module's (``jit_serving_tick``), which is how a profiler
+    # trace tells the tick programs from everything else on the chip
+    def serving_tick(params, tokens, meta, k_pages, v_pages, tq=1,
+                     decode_tail=0, spec_k=0):
+        return mod.serving_tick(params, tokens, meta, k_pages, v_pages,
+                                cfg, tq=tq, decode_tail=decode_tail,
+                                spec_k=spec_k, attn_impl=attn_impl)
+
+    def serving_tick_block(params, tok, lengths, tables, k_pages, v_pages,
+                           num_steps, sampling=None):
+        return mod.serving_tick_block(params, tok, lengths, tables,
+                                      k_pages, v_pages, cfg, num_steps,
+                                      attn_impl=attn_impl,
+                                      sampling=sampling)
+
+    tick = jax.jit(_rw(serving_tick), donate_argnums=(3, 4),
                    static_argnames=("tq", "decode_tail", "spec_k"))
-    blk = jax.jit(_rw(partial(mod.serving_tick_block, cfg=cfg,
-                              attn_impl=attn_impl)), donate_argnums=(4, 5),
+    blk = jax.jit(_rw(serving_tick_block), donate_argnums=(4, 5),
                   static_argnames=("num_steps",))
     _JIT_CACHE[key] = (cfg, tick, blk)
     if len(_JIT_CACHE) > _JIT_CACHE_MAX:
@@ -1167,13 +1179,15 @@ class ServingEngine:
 
         return pad_meta, tabs, zs, samp
 
-    def program_texts(self) -> Dict[str, str]:
+    def program_texts(self, debug_info: bool = False) -> Dict[str, str]:
         """Lowered (StableHLO) text of every program ``warm_programs``
         compiles on a non-speculative engine, traced at the same
         padding arguments: ``{"tick@<w>": ..., "block": ...}``. Lets a
         caller check what the programs ARE on this backend (a Pallas
         kernel shows as a ``tpu_custom_call``) without reaching into
-        the jit objects. Nothing executes and no pool is donated."""
+        the jit objects; with ``debug_info`` every operation carries
+        its ``jax.named_scope`` path. Nothing executes and no pool is
+        donated."""
         jnp = self._jnp
         S = self.scheduler.max_batch
         pad_meta, tabs, zs, samp = self._pad_tick_args()
@@ -1184,11 +1198,12 @@ class ServingEngine:
                 out[f"tick@{w}"] = self._tick_jit.lower(
                     self._params, jnp.asarray(np.zeros((T,), np.int32)),
                     pad_meta(T), self._kp, self._vp, tq=w,
-                    decode_tail=0).as_text()
+                    decode_tail=0).as_text(debug_info=debug_info)
             out["block"] = self._block_jit.lower(
                 self._params, jnp.asarray(zs), jnp.asarray(zs),
                 jnp.asarray(tabs), self._kp, self._vp,
-                num_steps=self._decode_block, sampling=samp).as_text()
+                num_steps=self._decode_block,
+                sampling=samp).as_text(debug_info=debug_info)
         return out
 
     def warm_programs(self) -> int:
@@ -1338,6 +1353,17 @@ class ServingEngine:
             return len(plan)
 
     # ----------------------------------------------------- observability ----
+    def _count_tick(self, rows: int, rows_real: int,
+                    kv_tokens: int) -> dict:
+        """What a tick launches against what it needs, counted where
+        the tick's arrays are built: into the counters (operators) and,
+        returned, into the ``serving.tick`` span's args (the profiler's
+        annotation then carries them for exactly the ticks traced)."""
+        self.metrics.inc("tick_rows", rows)
+        self.metrics.inc("tick_rows_real", rows_real)
+        self.metrics.inc("kv_tokens_attended", kv_tokens)
+        return dict(rows=rows, rows_real=rows_real, kv_tokens=kv_tokens)
+
     def _record_tick(self, t0: float, t1: float, live, spans,
                      admitted: int) -> None:
         """Per-tick evidence (caller holds the tick lock): slot-track
@@ -1347,6 +1373,14 @@ class ServingEngine:
         only ids are used, never slot re-reads."""
         tick = self._tick_no
         self._tick_no += 1
+        for slot, req, start, _ in spans:
+            if start == req.cached_len:
+                # the request's first chunk rode this tick: its wait
+                # in the prefill queue, retroactive on the stamps the
+                # observation uses (the span EQUALS it)
+                self.metrics.observe("prefill_wait_s", t0 - req.admit_t)
+                self.tracer.add("prefill.wait", f"slot{slot}",
+                                req.admit_t, t0, req=req.id)
         if self.tracer.enabled:
             for slot, req in live:
                 self.tracer.add("decode", f"slot{slot}", t0, t1,
@@ -1652,7 +1686,7 @@ class ServingEngine:
         return drafts
 
     # -------------------------------------------------------------- tick ----
-    def _ragged_tick(self, live, spans, tail: int = 0,
+    def _ragged_tick(self, ph, live, spans, tail: int = 0,
                      drafts=None) -> None:
         """ONE serving_tick call covering every live slot's decode token
         plus the collected prompt spans. Geometry is data: the program
@@ -1674,7 +1708,11 @@ class ServingEngine:
         (``spec_k`` mode of ``serving_tick``). Any tick carrying spans
         or drafts on a speculative engine runs the ONE verify program
         for its width — prefill-only ticks included — which is what
-        keeps the per-bucket program count at 1 there."""
+        keeps the per-bucket program count at 1 there.
+
+        ``ph``: the iteration's ``Phases`` (``_loop``), in ``build`` on
+        entry; the tick moves it through ``dispatch`` and ``readback``
+        and leaves it in ``emit``."""
         jnp = self._jnp
         S = self.scheduler.max_batch
         ps = self.pool.page_size
@@ -1775,31 +1813,48 @@ class ServingEngine:
             meta.update(ver_idx=jnp.asarray(ver_idx),
                         draft_tok=jnp.asarray(draft_tok),
                         draft_len=jnp.asarray(draft_len))
+        # what the launch carries against what it needs: T rows and,
+        # per fused tail step, S more; of them the live decoders',
+        # drafts' and spans' tokens; the cache tokens those attend
+        # (tail step j attends j more per tail-live slot)
+        n_tail = int(tail_live.sum())
+        counts = self._count_tick(
+            T + S * tail, int(real.sum()) + n_tail * tail,
+            int(kv_len[q_len > 0].sum())
+            + tail * int(kv_len[tail_live].sum())
+            + n_tail * tail * (tail + 1) // 2)
         t0 = time.perf_counter()
         m0 = time.monotonic()
-        with RecordEvent("serving.tick"), \
-                self.tracer.span("serving.tick", track="engine.decode",
-                                 tick=self._tick_no, width=int(width),
-                                 live=len(live), span_tokens=int(span_tok),
-                                 tail=int(tail), spec=len(spec_rows)):
+        tok_d = jnp.asarray(tok)
+        t_build = ph.stop()
+        with self.tracer.span("serving.tick", track="engine.decode",
+                              tick=self._tick_no, width=int(width),
+                              live=len(live), span_tokens=int(span_tok),
+                              tail=int(tail), spec=len(spec_rows),
+                              **counts):
+            ph.enter("serving.phase.dispatch", at=t_build)
             if spec:
                 toks_d, accept_d, _logits_d, self._kp, self._vp = \
-                    self._tick_jit(self._params, jnp.asarray(tok), meta,
+                    self._tick_jit(self._params, tok_d, meta,
                                    self._kp, self._vp, tq=tq,
                                    decode_tail=0, spec_k=spec)
+                ph.enter("serving.phase.readback")
                 # [S, 1+spec_k] i32 + [S] i32 — the eager pulls
                 toks = np.asarray(toks_d)      # noqa: PT005 - THE sanctioned per-tick verify read-back
                 accept = np.asarray(accept_d)  # noqa: PT005 - rides the same sync
                 host_sync("serving.tick.readback")
             else:
                 toks_d, _logits_d, self._kp, self._vp = self._tick_jit(
-                    self._params, jnp.asarray(tok), meta, self._kp,
+                    self._params, tok_d, meta, self._kp,
                     self._vp, tq=tq, decode_tail=tail)
+                ph.enter("serving.phase.readback")
                 # [S] (tail=0) or [S, 1+tail] i32 — the only eager
                 # pull: sampling happens IN-GRAPH (r16), so no [S, V]
                 # logits row ever crosses to the host
                 toks = np.asarray(toks_d)  # noqa: PT005 - THE sanctioned per-tick token read-back
                 host_sync("serving.tick.readback")
+            t_read = ph.stop()
+        ph.enter("serving.phase.emit", at=t_read)
         m1 = time.monotonic()
         if toks.ndim == 1:
             toks = toks[:, None]
@@ -1855,7 +1910,7 @@ class ServingEngine:
                     self.scheduler.lengths[slot] += tail
                     self._emit_toks(slot, req, toks[slot], 1, 1 + tail)
 
-    def _block_tick(self, live) -> None:
+    def _block_tick(self, ph, live) -> None:
         """Fast path when no prefill work is pending: ``num_steps``
         fused decode ticks in one program — token selection is
         in-graph (argmax for greedy slots, the fused
@@ -1869,19 +1924,30 @@ class ServingEngine:
         discarded sampled tokens burn no key state)."""
         jnp = self._jnp
         k = self._decode_block
+        # S rows a step, the live slots' real; step j attends the
+        # slot's length + j cache tokens
+        lens = self.scheduler.lengths[[slot for slot, _ in live]]
+        counts = self._count_tick(
+            self.scheduler.max_batch * k, len(live) * k,
+            k * int(lens.sum()) + len(live) * k * (k + 1) // 2)
         t0 = time.perf_counter()
-        with RecordEvent("serving.decode_step"), \
-                self.tracer.span("serving.tick", track="engine.decode",
-                                 tick=self._tick_no, kind="block",
-                                 live=len(live), steps=k):
-            toks, self._kp, self._vp = self._block_jit(
-                self._params, jnp.asarray(self._cur_tok),
+        args = (jnp.asarray(self._cur_tok),
                 jnp.asarray(self.scheduler.lengths),
-                jnp.asarray(self.scheduler.tables), self._kp,
-                self._vp, num_steps=k,
-                sampling=self._sampling_arrays())
+                jnp.asarray(self.scheduler.tables))
+        sampling = self._sampling_arrays()
+        t_build = ph.stop()
+        with self.tracer.span("serving.tick", track="engine.decode",
+                              tick=self._tick_no, kind="block",
+                              live=len(live), steps=k, **counts):
+            ph.enter("serving.phase.dispatch", at=t_build)
+            toks, self._kp, self._vp = self._block_jit(
+                self._params, *args, self._kp, self._vp, num_steps=k,
+                sampling=sampling)
+            ph.enter("serving.phase.readback")
             toks = np.asarray(toks)  # noqa: PT005 - sanctioned per-block token read-back ([S, k] i32)
             host_sync("serving.tick.readback")
+            t_read = ph.stop()
+        ph.enter("serving.phase.emit", at=t_read)
         self.metrics.inc("decode_steps", k)
         self.metrics.observe("decode_step_s",
                              (time.perf_counter() - t0) / k)
@@ -1889,7 +1955,7 @@ class ServingEngine:
             self.scheduler.lengths[slot] += k  # block's KV just landed
             self._emit_toks(slot, req, toks[slot], 0, k)
 
-    def _decode_tick(self, live, spans) -> None:
+    def _decode_tick(self, ph, live, spans) -> None:
         """Tick dispatch (r16 — sampling is DATA, so temperature never
         picks a program): the fused block when the tick is pure
         decode, else the ragged one-program tick with the fused decode
@@ -1910,12 +1976,12 @@ class ServingEngine:
         if self._drafter is not None:
             drafts = self._collect_drafts(live)
             if drafts or spans:
-                self._ragged_tick(live, spans, 0, drafts)
+                self._ragged_tick(ph, live, spans, 0, drafts)
                 return
         if not spans and live:
-            self._block_tick(live)
+            self._block_tick(ph, live)
         elif spans:
-            self._ragged_tick(live, spans, self._decode_block - 1)
+            self._ragged_tick(ph, live, spans, self._decode_block - 1)
 
     def _sweep(self, now: float) -> None:
         """Apply cancellations + deadlines to queued and occupied
@@ -1933,11 +1999,20 @@ class ServingEngine:
 
     def _loop(self) -> None:
         try:
+            with self._tick_lock:
+                tick_no = self._tick_no
             while True:
+                # the engine thread's time, cut into five contiguous
+                # phases (track engine.phase; kept only if the
+                # iteration ticks). Waiting for the tick lock is
+                # admission's: it is host time before the next launch.
+                ph = self.tracer.phases("engine.phase", tick=tick_no)
+                ph.enter("serving.phase.admit")
                 with self._tick_lock:
                     now = time.monotonic()
                     self._sweep(now)
                     if self._closing and not self._drain:
+                        ph.end(keep=False)
                         break
                     if self._closing and self._hand_back:
                         # hand-back drain (fleet protocol): admission
@@ -1957,8 +2032,7 @@ class ServingEngine:
                         # _try_reserve sees them as a warm hit
                         self._rewarm_cold()
                     t_adm = time.monotonic()
-                    with RecordEvent("serving.admit"):
-                        admitted = self.scheduler.admit()
+                    admitted = self.scheduler.admit()
                     if admitted:
                         # recorded only when work happened: an idle
                         # engine polls admission every 50ms and must
@@ -1979,6 +2053,7 @@ class ServingEngine:
                                         prompt=int(req.prompt.size),
                                         cached=int(req.cached_len))
                         self._park(slot, req)
+                    ph.enter("serving.phase.build")
                     spans = self._collect_spans()
                     live = self.scheduler.live()
                     self.metrics.observe("batch_occupancy",
@@ -2001,7 +2076,7 @@ class ServingEngine:
                                 "decode_stall_s",
                                 t - self._last_decode_t)
                         t_tick0 = time.monotonic()
-                        self._decode_tick(live, spans)
+                        self._decode_tick(ph, live, spans)
                         t_tick1 = time.monotonic()
                         self._last_decode_t = (time.perf_counter()
                                                if live else None)
@@ -2011,7 +2086,10 @@ class ServingEngine:
                         self._last_decode_t = None
                     if ticked and self._check_invariants:
                         self._audit_or_raise()
+                    phases = ph.end(keep=ticked)
+                    tick_no = self._tick_no     # for the next iteration
                 if ticked:
+                    self._observe_phases(phases)
                     # pace OUTSIDE the tick lock: sleeping inside it
                     # starves defragment() (python locks are unfair)
                     if self._tick_interval:
@@ -2063,6 +2141,17 @@ class ServingEngine:
                     self.prefix_cache.spill = None
                     self.prefix_cache.evict(
                         self.prefix_cache.cached_pages)
+
+    def _observe_phases(self, phases: Dict[str, float]) -> None:
+        """One ticked iteration's phases into their histograms, with
+        ``tick_host_s``: the iteration less its read-back."""
+        host = 0.0
+        for name, dur in phases.items():
+            short = name.rsplit(".", 1)[1]
+            self.metrics.observe(f"phase_{short}_s", dur)
+            if short != "readback":
+                host += dur
+        self.metrics.observe("tick_host_s", host)
 
     def _fail_all(self, e: BaseException) -> None:
         for r in self.scheduler.drop_queued(lambda r: True):

@@ -6,10 +6,10 @@ engine records every observation here; ``snapshot()`` returns a plain
 dict so any exporter (logging, JSON endpoint, test assertion) can
 consume it without a metrics dependency, and ``expose()`` renders the
 same state as dependency-free Prometheus text exposition for a real
-scrape endpoint. Host spans additionally ride ``profiler.RecordEvent``
-and the observability span tracer (engine.py), so prefill/decode ticks
-show up in device traces, ``profiler.host_statistics()`` and Perfetto
-exports.
+scrape endpoint. Host spans ride the observability span tracer
+(engine.py), which writes each one to its ring (Perfetto exports) and
+to the profiler (``jax.profiler.TraceAnnotation``), so ticks and their
+phases show up in device traces.
 """
 from __future__ import annotations
 
@@ -104,7 +104,13 @@ class ServingMetrics:
     the device instead of recomputing prefill; cold_hit_pages — pages
     those rewarm events scattered; cold_spills — pages paged out to
     host at eviction; live cold-tier occupancy — entries/bytes — is a
-    ``cold_tier_*`` gauge, see ``ServingEngine._gauges``).
+    ``cold_tier_*`` gauge, see ``ServingEngine._gauges``), and what a
+    tick launched against what it needed (tick_rows — token rows the
+    tick programs were launched with: packed width, fused steps
+    included; tick_rows_real — those that carried a live decoder's,
+    a draft's or a prompt span's token: the ratio is the share of a
+    launch that was not padding; kv_tokens_attended — cache tokens the
+    real rows attended, the least the attention kernel had to read).
     Labeled counters (``inc_labeled``): the same monotonic semantics
     with a small label set — e.g. ``recompiles{during="serving.tick"}``
     names WHAT a post-warmup compile interrupted. Kept separate from
@@ -123,7 +129,18 @@ class ServingMetrics:
     drafted per speculative verify launch), cold_adopt_s (one
     cold-tier rewarm: host lookup + page alloc + KV scatter + trie
     graft — the latency a re-hit session pays INSTEAD of recomputing
-    its prefill). Histogram summaries report the
+    its prefill), prefill_wait_s (admission -> the tick that carries
+    the request's first prompt chunk: the wait inside the engine's
+    prefill queue, which queue_wait_s cannot see), and the engine
+    thread's time cut into the five contiguous phases of an iteration
+    that ticked — phase_admit_s (sweep, rewarm, admission, parking),
+    phase_build_s (the tick's arrays packed and sent), phase_dispatch_s
+    (the jitted call until it returns), phase_readback_s (the blocking
+    token pull: the device's time, mostly), phase_emit_s (tokens
+    streamed, retirements, the tick's records, the optional audit) —
+    with tick_host_s = the iteration less its read-back, the host
+    time a tick costs (decode_stall_s is a part of it). Histogram
+    summaries report the
     lifetime mean AND the windowed mean/percentiles separately — see
     :class:`Histogram`.
     """
@@ -135,11 +152,14 @@ class ServingMetrics:
                 "prefix_pages_saved", "invariant_violations",
                 "recompiles", "spec_ticks", "draft_tokens",
                 "draft_accepted", "draft_rejected", "handed_back",
-                "cold_hits", "cold_hit_pages", "cold_spills")
+                "cold_hits", "cold_hit_pages", "cold_spills",
+                "tick_rows", "tick_rows_real", "kv_tokens_attended")
     HISTOGRAMS = ("queue_wait_s", "ttft_s", "decode_step_s",
                   "decode_stall_s", "batch_occupancy",
                   "page_utilization", "chunk_queue_depth",
-                  "spec_accept_rate", "cold_adopt_s")
+                  "spec_accept_rate", "cold_adopt_s", "prefill_wait_s",
+                  "phase_admit_s", "phase_build_s", "phase_dispatch_s",
+                  "phase_readback_s", "phase_emit_s", "tick_host_s")
 
     def __init__(self):
         self._lock = wrap_lock(threading.Lock(), "ServingMetrics._lock")
@@ -446,7 +466,10 @@ def merge_exposition(entries, prefix: str = "paddle_serving") -> str:
         for name, v in (gauges or {}).items():
             fam_gauge.setdefault(name, []).append((base, float(v)))
     lines = []
-    for name in sorted(fam_counter):
+    # by the RENDERED family name: ``tick_rows_real_total`` sorts ahead
+    # of ``tick_rows_total`` though ``tick_rows`` sorts ahead of
+    # ``tick_rows_real``
+    for name in sorted(fam_counter, key=lambda n: n + "_total"):
         metric = f"{prefix}_{name}_total"
         lines.append(f"# TYPE {metric} counter")
         for base, v in sorted(fam_counter[name],

@@ -1,7 +1,10 @@
 """Runtime observability layer (ISSUE r13): span tracer round-trip,
 flight-recorder postmortems, the live recompile sentinel, Prometheus
-exposition, thread-safe snapshots, and the profiler RecordEvent /
-host_statistics coverage the module never had.
+exposition, thread-safe snapshots, the profiler RecordEvent /
+host_statistics coverage the module never had, and (PR 26) the tick
+named from the inside: spans as profiler annotations, the engine
+thread's five contiguous phases, the per-tick counts, the prefill
+queue's wait, and the named scopes on the device programs.
 
 Acceptance pins exercised here:
   * exported Perfetto JSON re-parses, spans nest, no negative
@@ -16,6 +19,7 @@ Acceptance pins exercised here:
 """
 import json
 import os
+import re
 import threading
 import time
 import warnings
@@ -28,8 +32,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.models import llama as L
 from paddle_tpu.observability import (FlightRecorder, RecompileWarning,
-                                      SpanTracer, bridge_record_events,
-                                      current_span)
+                                      SpanTracer, current_span)
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.metrics import Histogram, ServingMetrics
 
@@ -174,7 +177,8 @@ def test_tracer_ring_bound_and_disable():
 
 
 # ---------------------------------------------------------------------------
-# profiler satellites: host_statistics / RecordEvent nesting + bridge
+# profiler satellites: host_statistics / RecordEvent nesting; spans and
+# phases as profiler annotations
 # ---------------------------------------------------------------------------
 
 def test_record_event_nesting_host_statistics():
@@ -201,22 +205,80 @@ def test_record_event_nesting_host_statistics():
     assert prof.host_statistics() == {}
 
 
-def test_record_event_bridge_into_tracer():
-    from paddle_tpu import profiler as prof
-    tr = SpanTracer()
-    detach = bridge_record_events(tr)
+def _host_annotations(trace_dir):
+    """``{name: [stats dict]}`` of plane ``/host:CPU`` of the newest
+    xplane under ``trace_dir``."""
+    import glob
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    {"start_ns": ev.start_ns, "dur_ns": ev.duration_ns,
+                     **{k: v for k, v in ev.stats}})
+    return out
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_is_a_profiler_annotation_with_its_args(tmp_path, enabled):
+    """One call site writes ring and profiler: a span (and a phase)
+    lands on plane /host:CPU with its args as the event's stats,
+    whether or not the ring is enabled; a disabled ring still appends
+    nothing."""
+    tr = SpanTracer(enabled=enabled)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        with prof.RecordEvent("annotated"):
-            time.sleep(0.001)
+        with tr.span("obs.test.span", track="t", tick=7, kv_tokens=2 ** 40):
+            ph = tr.phases("t.phase", tick=7)
+            ph.enter("obs.test.phase.a")
+            ph.enter("obs.test.phase.b")
+            durs = ph.end()
     finally:
-        detach()
-    with prof.RecordEvent("after_detach"):
-        pass
-    names = [(s.name, s.track) for s in tr.spans()]
-    assert ("annotated", "profiler") in names
-    assert all(n != "after_detach" for n, _ in names)
-    spans = [s for s in tr.spans() if s.name == "annotated"]
-    assert spans[0].dur_s >= 0.001
+        jax.profiler.stop_trace()
+    ann = _host_annotations(tmp_path)
+    (span,) = ann["obs.test.span"]
+    assert span["tick"] == 7 and span["kv_tokens"] == 2 ** 40
+    (a,), (b,) = ann["obs.test.phase.a"], ann["obs.test.phase.b"]
+    assert a["tick"] == b["tick"] == 7
+    # the phases sit inside the span on the profiler's clock, in order
+    assert span["start_ns"] <= a["start_ns"] <= b["start_ns"]
+    assert (b["start_ns"] + b["dur_ns"]
+            <= span["start_ns"] + span["dur_ns"])
+    assert list(durs) == ["obs.test.phase.a", "obs.test.phase.b"]
+    names = [s.name for s in tr.spans()]
+    if enabled:
+        assert names == ["obs.test.phase.a", "obs.test.phase.b",
+                         "obs.test.span"]
+    else:
+        assert names == []
+
+
+def test_phases_are_contiguous_and_idle_iterations_leave_nothing():
+    tr = SpanTracer()
+    ph = tr.phases("engine.phase", tick=0)
+    ph.enter("a")
+    assert current_span() is None       # phases stay off the stack
+    t = ph.stop()
+    ph.enter("b", at=t)
+    ph.enter("c")
+    durs = ph.end()
+    a, b, c = tr.spans()
+    assert (a.t1, b.t1) == (b.t0, c.t0) and a.t1 == t
+    assert sum(durs.values()) == pytest.approx((c.t1 - a.t0) / 1e9)
+    assert all(s.track == "engine.phase" and s.args == {"tick": 0}
+               for s in (a, b, c))
+    idle = tr.phases("engine.phase", tick=1)
+    idle.enter("a")
+    assert list(idle.end(keep=False)) == ["a"]
+    assert len(tr.spans()) == 3
 
 
 # ---------------------------------------------------------------------------
@@ -308,6 +370,195 @@ def test_engine_snapshot_concurrent_with_loop(params):
             t.join()
     assert not errs
     assert eng.snapshot()["counters"]["completed"] == 12
+
+
+PHASES = ["serving.phase." + p
+          for p in ("admit", "build", "dispatch", "readback", "emit")]
+
+
+def _scripted_run(params, **kw):
+    """Two requests queued under the tick lock, so that both are
+    admitted in one iteration and every tick's make-up is fixed:
+    A = 6 prompt tokens + 3 new, B = 3 + 2, 4 prompt tokens a tick.
+    Tick 0 carries A[0:4]; tick 1 A[4:6] + B[0:2]; tick 2 A's decode
+    token + B[2:3]; tick 3 is the fused block over A and B."""
+    rng = np.random.RandomState(7)
+    a = rng.randint(0, 256, (6,)).astype(np.int32)
+    b = rng.randint(0, 256, (3,)).astype(np.int32)
+    with _engine(params, prefill_chunk=4, prefix_cache=False,
+                 **kw) as eng:
+        with eng._tick_lock:
+            ha, hb = eng.submit(a, 3), eng.submit(b, 2)
+        outs = [ha.result(timeout=300), hb.result(timeout=300)]
+    return eng, (ha, hb), outs
+
+
+@pytest.fixture(scope="module")
+def scripted(params):
+    return _scripted_run(params)
+
+
+def _phases_by_tick(eng):
+    by_tick = {}
+    for s in eng.tracer.spans():
+        if s.track == "engine.phase":
+            by_tick.setdefault(s.args["tick"], []).append(s)
+    return by_tick
+
+
+@pytest.mark.parametrize("kind", ["ragged", "block"])
+def test_five_phases_partition_a_ticked_iteration(scripted, kind):
+    """The engine thread's time in an iteration that ticked is cut into
+    admit, build, dispatch, readback, emit: each ends where the next
+    starts, they sum to the iteration, and the ``serving.tick`` span
+    covers dispatch + read-back — for the ragged tick and for the
+    fused block."""
+    eng, _, _ = scripted
+    ticks = {s.args["tick"]: s for s in eng.tracer.spans()
+             if s.name == "serving.tick"}
+    assert sorted(ticks) == [0, 1, 2, 3]
+    want = {"ragged": [0, 1, 2], "block": [3]}[kind]
+    assert [t for t, s in sorted(ticks.items())
+            if (s.args.get("kind") == "block") == (kind == "block")] == want
+    by_tick = _phases_by_tick(eng)
+    assert sorted(by_tick) == [0, 1, 2, 3]      # idle polls left nothing
+    for t in want:
+        ph = by_tick[t]
+        assert [s.name for s in ph] == PHASES
+        for s0, s1 in zip(ph, ph[1:]):
+            assert s0.t1 == s1.t0
+        assert sum(s.t1 - s.t0 for s in ph) == ph[-1].t1 - ph[0].t0
+        tick = ticks[t]
+        # opens as dispatch starts, closes as the read-back ends
+        assert ph[2].t0 <= tick.t0 <= ph[2].t1
+        assert ph[3].t1 <= tick.t1 <= ph[4].t1
+
+
+def test_tick_host_is_the_iteration_less_its_readback(scripted):
+    eng, _, _ = scripted
+    h = eng.metrics.histograms
+    by_tick = _phases_by_tick(eng)
+    for i, (t, ph) in enumerate(sorted(by_tick.items())):
+        whole = (ph[-1].t1 - ph[0].t0) / 1e9
+        assert (h["tick_host_s"]._vals[i] + h["phase_readback_s"]._vals[i]
+                == pytest.approx(whole, abs=1e-9))
+        for s in ph:
+            short = s.name.rsplit(".", 1)[1]
+            assert h[f"phase_{short}_s"]._vals[i] == pytest.approx(
+                s.dur_s, abs=1e-9)
+    assert h["tick_host_s"]._count == len(by_tick) == 4
+
+
+def test_tick_counts_match_the_hand_computed_run(params, scripted):
+    """rows launched, rows real and cache tokens attended, by hand for
+    the scripted run (S = 4 slots, the one packed width is 4):
+    tick 0: 8 rows, 4 real (A[0:4]), A attends 4;
+    tick 1: 8 rows, 4 real, A attends 6 + B 2;
+    tick 2: 8 rows, 2 real (A decodes, B[2:3]), A attends 7 + B 3;
+    tick 3 (block): 4 rows, 2 real, A attends 8 + B 4.
+    The span args carry each tick's share; a second run repeats them
+    exactly (they are counts, not times)."""
+    eng, _, outs = scripted
+    c = eng.metrics.snapshot()["counters"]
+    assert (c["tick_rows"], c["tick_rows_real"],
+            c["kv_tokens_attended"]) == (28, 12, 34)
+    per_tick = [(s.args["rows"], s.args["rows_real"], s.args["kv_tokens"])
+                for s in eng.tracer.spans() if s.name == "serving.tick"]
+    assert per_tick == [(8, 4, 4), (8, 4, 8), (8, 2, 10), (4, 2, 12)]
+    eng2, _, outs2 = _scripted_run(params, trace=False)
+    c2 = eng2.metrics.snapshot()["counters"]
+    for k in ("tick_rows", "tick_rows_real", "kv_tokens_attended",
+              "decode_steps", "tokens_out"):
+        assert c2[k] == c[k]
+    # a disabled ring changes nothing served and records nothing
+    assert eng2.tracer.spans() == []
+    for o, o2 in zip(outs, outs2):
+        np.testing.assert_array_equal(o, o2)
+
+
+def test_prefill_wait_span_equals_its_observation(scripted):
+    """admission -> the tick that carries the request's first chunk:
+    one observation and one ``prefill.wait`` span a request, on the
+    same stamps; B waits a tick behind A."""
+    eng, (ha, hb), _ = scripted
+    waits = {s.args["req"]: s for s in eng.tracer.spans()
+             if s.name == "prefill.wait"}
+    assert sorted(waits) == sorted([ha.id, hb.id])
+    vals = list(eng.metrics.histograms["prefill_wait_s"]._vals)
+    assert len(vals) == 2
+    for v, req in zip(vals, (ha.id, hb.id)):
+        assert waits[req].dur_s == pytest.approx(v, abs=2e-9)
+        assert waits[req].track.startswith("slot")
+    assert vals[1] > vals[0]
+    first_tick = {s.args["req"]: s for s in reversed(eng.tracer.spans())
+                  if s.name == "prefill.chunk"}
+    for req, w in waits.items():
+        assert w.t1 == pytest.approx(first_tick[req].t0, abs=2)
+
+
+def _train_step_text(debug_info):
+    from paddle_tpu.parallel.mesh import init_hybrid_mesh
+    hm = init_hybrid_mesh(dp=1, pp=1, tp=1, set_global=False)
+    with hm.mesh:
+        step, init = L.make_train_step(CFG, hm.mesh)
+        state = init(jax.random.PRNGKey(0))
+        batch = L.make_batch(CFG, batch_size=2, seq_len=8, mesh=hm.mesh)
+        return {"step": step.lower(state, batch).as_text(
+            debug_info=debug_info)}
+
+
+def _tick_texts(model, debug_info):
+    from paddle_tpu.serving import engine as E
+    E._JIT_CACHE.clear()    # trace anew: the scopes may be patched out
+    if model == "moe":
+        from paddle_tpu.models import qwen2_moe as Q
+        cfg = Q.Qwen2MoeConfig.tiny(dtype=jnp.float32,
+                                    use_flash_attention=False, remat=False)
+        prm = Q.init_params(cfg, jax.random.PRNGKey(1))
+    else:
+        cfg, prm = CFG, L.init_params(CFG, jax.random.PRNGKey(0))
+    with ServingEngine(prm, cfg, max_batch=2, page_size=4,
+                       max_prompt_len=8, max_new_tokens_cap=8,
+                       prefill_chunk=4) as eng:
+        return eng.program_texts(debug_info=debug_info)
+
+
+BLOCK_SCOPES = ["layers", "kv_pool.write", "ragged_attn", "attn.qkv_rope",
+                "attn.out", "lm_head", "sampler", "embed"]
+
+
+@pytest.mark.parametrize("program, scopes, module", [
+    ("dense", BLOCK_SCOPES + ["mlp"], "jit_serving_tick"),
+    ("moe", BLOCK_SCOPES + ["moe.router", "moe.experts", "moe.shared"],
+     "jit_serving_tick"),
+    ("train", ["loss", "optimizer", "attn.qkv_rope", "attn.out", "mlp"],
+     "jit_step_fn"),
+])
+def test_named_scopes_are_metadata_only(monkeypatch, program, scopes,
+                                        module):
+    """The device side has names — every scope is on the lowered
+    program's operations and the module is named after its function
+    (not ``jit__unknown``) — and they are metadata only: with the
+    scopes patched out the lowered programs are the same text, so the
+    tokens they compute are the same."""
+    import contextlib
+    texts = (lambda dbg: _train_step_text(dbg) if program == "train"
+             else _tick_texts(program, dbg))
+    named = texts(True)
+    for name, text in named.items():
+        want = module + ("_block" if name == "block" else "")
+        assert f"module @{want} " in text, (name, text[:200])
+        for scope in scopes:
+            # a path segment of some operation's location (paths inside
+            # a scan body are relative to it in the lowered text)
+            assert re.search(rf'["/]{re.escape(scope)}[/"]', text), \
+                (name, scope)
+    plain = texts(False)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = texts(False)
+    assert bare == plain
+    assert all("kv_pool.write" not in t for t in texts(True).values())
 
 
 # ---------------------------------------------------------------------------
