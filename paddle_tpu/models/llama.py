@@ -1608,37 +1608,53 @@ def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
     with jax.named_scope("embed"):
         h = params["embed"].astype(cfg.dtype)[tokens[None]]    # [1, T, D]
     positions = meta["tok_pos"][None]
+    heads = jnp.arange(k_pages.shape[1], dtype=jnp.int32)[None, :]  # [1, Hkv]
+    tok_page = meta["tok_page"][:, None]                            # [T, 1]
+    tok_off = meta["tok_off"][:, None]
 
-    def body(h, xs):
-        lp, kp, vp = xs
+    def body(carry, xs):
+        h, kp, vp = carry
+        lp, layer = xs
         cell = {}
 
         def attn_fn(q, k, v):
-            # 1) land the span's KV in the pages (padding -> trash page)
+            # 1) land the span's KV in the layer's pages, in place on
+            # the carried pool (padding -> trash page). One scattered
+            # row per (token, kv head), the window the head size alone:
+            # a window over the heads (``kp.at[layer, :, page, off]``)
+            # makes the chip's compiler re-lay the WHOLE pool out,
+            # heads next to the head size, around every use of it
             with jax.named_scope("kv_pool.write"):
-                kp2 = kp.at[:, meta["tok_page"], meta["tok_off"]].set(
-                    k[0].transpose(1, 0, 2).astype(kp.dtype))
-                vp2 = vp.at[:, meta["tok_page"], meta["tok_off"]].set(
-                    v[0].transpose(1, 0, 2).astype(vp.dtype))
+                kp2 = kp.at[layer, heads, tok_page, tok_off].set(
+                    k[0].astype(kp.dtype))
+                vp2 = vp.at[layer, heads, tok_page, tok_off].set(
+                    v[0].astype(vp.dtype))
             cell["kp"], cell["vp"] = kp2, vp2
             # 2) one ragged launch over the pages (span KV included):
             # the packed entry keeps score work proportional to the T
             # real rows off-TPU and scatters to the kernel's slot-major
-            # layout on TPU
+            # layout on TPU; the kernel reads the layer's pages where
+            # they lie in the stacked pool
             with jax.named_scope("ragged_attn"):
                 o = ragged_paged_attention_packed(
                     q[0], kp2, vp2, tok_slot, tok_qoff, meta["q_len"],
-                    meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl)
+                    meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl,
+                    layer=layer)
             return o[None].astype(q.dtype)
 
         h = block_fn(lp, h, positions, cfg, attn_fn)
-        return h, (cell["kp"], cell["vp"])
+        return (h, cell["kp"], cell["vp"]), None
 
-    # an operation under bare ``layers`` is the scan's own: the slicing
-    # of a layer's weights and pool pages and their write-back
+    # the scan CARRIES the stacked pools: the donated parameter is the
+    # loop's initial carry and its final carry is the result, so the
+    # pool is one buffer for the whole tick and a layer touches only
+    # the pages it writes and reads. An operation under bare ``layers``
+    # is the scan's own: the slicing of a layer's weights
     with jax.named_scope("layers"):
-        h, (kp_new, vp_new) = lax.scan(body, h, (params["layers"],
-                                                 k_pages, v_pages))
+        (h, kp_new, vp_new), _ = lax.scan(
+            body, (h, k_pages, v_pages),
+            (params["layers"],
+             jnp.arange(k_pages.shape[0], dtype=jnp.int32)))
     with jax.named_scope("lm_head"):
         h = rms_norm(h[0], params["final_norm"], cfg.rms_norm_eps)  # [T, D]
     # fused sampling (r16): when the meta carries per-slot sampling

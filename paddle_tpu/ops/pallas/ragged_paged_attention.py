@@ -24,6 +24,17 @@ Layout contract:
   written into the pages (the step fn scatters before attending, like
   ``serving_decode_step``), so the kernel is purely paged: no separate
   current-chunk operand, no gathered-prefix concat.
+* ``layer`` (optional): with it the pools are the serving tick's
+  STACKED ones, ``[L, Hkv, total_pages, page_size, Dh]``, and the
+  kernel reads that layer's pages where they lie (its page DMAs start
+  from ``pages[layer, h, page]``; the index is one more
+  scalar-prefetch operand). That is what lets the tick's layer scan
+  CARRY the pools — one buffer from the program's parameter to its
+  result — where slicing a layer out in front of the kernel copied a
+  layer's pages per layer. There is one kernel body per walk: a 4-D
+  pool enters as a one-layer stack read at layer 0. Off the kernel
+  (the dense and packed formulations) the layer is sliced out in
+  front of the same code as ever.
 * ``kv_len[s]`` counts every key visible at the END of slot ``s``'s
   span (context + the span itself); query row ``t`` attends key
   positions ``0 .. kv_len[s]-q_len[s]+t`` — the bottom-right causal
@@ -299,18 +310,20 @@ def _attend_tiled(qs, ks, vs, q_len, kv_len, tq: int, tile_kv: int):
     return _flash_final(m, l, acc, vs.dtype)
 
 
-def _kernel(qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
-            k_scr, v_scr, sems, *, pps: int, page_size: int, tq: int):
+def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
+            o_ref, k_scr, v_scr, sems, *, pps: int, page_size: int,
+            tq: int):
     s = pl.program_id(0)
     h = pl.program_id(1)
+    layer = layer_ref[0]
     qn = qlen_ref[s]
     kn = kvlen_ref[s]
     n_pages = pl.cdiv(kn, page_size)
 
     def dma(p, pages_ref, scr, lane):
         page = tab_ref[s * pps + p]
-        return pltpu.make_async_copy(pages_ref.at[h, page], scr.at[p],
-                                     sems.at[lane, p])
+        return pltpu.make_async_copy(pages_ref.at[layer, h, page],
+                                     scr.at[p], sems.at[lane, p])
 
     # start every valid page's K and V copy, then await them — the
     # copies overlap in flight; a dead slot (qn == 0) moves no bytes
@@ -343,12 +356,15 @@ def _kernel(qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("tq", "g", "interpret"))
-def _pallas_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq, g,
-                 interpret):
-    """qs ``[S, Hkv, G*Tq, Dh]`` pre-scaled; returns the same shape."""
+def _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables, tq,
+                 g, interpret):
+    """qs ``[S, Hkv, G*Tq, Dh]`` pre-scaled; returns the same shape.
+    k_pages/v_pages are the STACKED pools ``[L, Hkv, P, ps, Dh]`` and
+    ``layer`` ``[1]`` i32 picks the layer whose pages the DMAs read:
+    the pools stay in HBM (``pl.ANY``) whole, nothing is sliced out."""
     S, Hkv, GT, Dh = qs.shape
     pps = tables.shape[1]
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[3]
     kernel = functools.partial(_kernel, pps=pps, page_size=page_size,
                                tq=tq)
     block = pl.BlockSpec((None, None, GT, Dh),
@@ -356,7 +372,7 @@ def _pallas_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq, g,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(S, Hkv),
             in_specs=[
                 block,
@@ -385,12 +401,12 @@ def _pallas_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq, g,
         # a stable name: how the kernel shows in lowered and compiled
         # program text (chip_smoke.py) and, later, in a device trace
         name="ragged_paged_attention",
-    )(q_len, kv_len, tables.reshape(-1), qs, k_pages, v_pages)
+    )(layer, q_len, kv_len, tables.reshape(-1), qs, k_pages, v_pages)
 
 
-def _tiled_kernel(qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
-                  o_ref, k_scr, v_scr, sems, *, pps: int, page_size: int,
-                  tq: int, tile_pages: int):
+def _tiled_kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref,
+                  vp_ref, o_ref, k_scr, v_scr, sems, *, pps: int,
+                  page_size: int, tq: int, tile_pages: int):
     """Flash-combine walk: live pages in ``tile_pages``-sized tiles,
     DOUBLE-BUFFERED — tile ``t+1``'s K/V page copies start while tile
     ``t`` computes, so past the first tile the DMA hides under the
@@ -399,6 +415,7 @@ def _tiled_kernel(qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
     in registers/VMEM via the fori_loop."""
     s = pl.program_id(0)
     h = pl.program_id(1)
+    layer = layer_ref[0]
     qn = qlen_ref[s]
     kn = kvlen_ref[s]
     n_pages = pl.cdiv(kn, page_size)
@@ -407,7 +424,7 @@ def _tiled_kernel(qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
 
     def tile_dma(t, buf, p, pages_ref, scr, lane):
         page = tab_ref[s * pps + t * tile_pages + p]
-        return pltpu.make_async_copy(pages_ref.at[h, page],
+        return pltpu.make_async_copy(pages_ref.at[layer, h, page],
                                      scr.at[buf, p],
                                      sems.at[lane, buf, p])
 
@@ -458,14 +475,14 @@ def _tiled_kernel(qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("tq", "g", "tile_pages", "interpret"))
-def _pallas_tiled_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq,
-                       g, tile_pages, interpret):
+def _pallas_tiled_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables,
+                       tq, g, tile_pages, interpret):
     """The tiled walk behind the same slot-major entry contract as
-    ``_pallas_impl``; scratch shapes are the whole VMEM story —
-    O(tile), never O(pps)."""
+    ``_pallas_impl`` (stacked pools + layer index); scratch shapes are
+    the whole VMEM story — O(tile), never O(pps)."""
     S, Hkv, GT, Dh = qs.shape
     pps = tables.shape[1]
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[3]
     tile_pages = min(int(tile_pages), pps)
     kernel = functools.partial(_tiled_kernel, pps=pps,
                                page_size=page_size, tq=tq,
@@ -475,7 +492,7 @@ def _pallas_tiled_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(S, Hkv),
             in_specs=[
                 block,
@@ -493,7 +510,7 @@ def _pallas_tiled_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq,
         out_shape=jax.ShapeDtypeStruct(qs.shape, k_pages.dtype),
         interpret=interpret,
         name="ragged_paged_attention_tiled",
-    )(q_len, kv_len, tables.reshape(-1), qs, k_pages, v_pages)
+    )(layer, q_len, kv_len, tables.reshape(-1), qs, k_pages, v_pages)
 
 
 def _reference_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq, g,
@@ -526,15 +543,29 @@ def _reference_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq, g,
     return jax.vmap(per_slot)(qs, q_len, kv_len, tables)
 
 
+def _layer_pages(pages, layer):
+    """One layer's ``[Hkv, P, ps, Dh]`` pages of a stacked pool, for the
+    formulations that gather from them (the kernel never slices: it
+    DMAs from ``pages_ref.at[layer, h, page]``)."""
+    if layer is None:
+        return pages
+    return jax.lax.dynamic_index_in_dim(pages, layer, 0, keepdims=False)
+
+
 def ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len, tables,
                            sm_scale=None, impl: str = "auto",
-                           kv_tile_pages=None):
+                           kv_tile_pages=None, layer=None):
     """One-launch attention for a mixed ragged batch over paged KV.
 
     q: ``[S, Tq, H, Dh]`` slot-major query spans (see module
     docstring); k_pages/v_pages: ``[Hkv, P, page_size, Dh]``;
     q_len/kv_len: i32 ``[S]``; tables: i32 ``[S, pages_per_slot]``.
     Returns ``[S, Tq, H, Dh]`` in q.dtype.
+
+    layer: None, or an i32 scalar with k_pages/v_pages the STACKED
+    pools ``[L, Hkv, P, page_size, Dh]`` — the serving tick's way in:
+    the kernel reads that layer's pages where they lie, so a layer
+    scan that carries the pools never slices a layer out of them.
 
     impl: "auto" (pallas kernel on TPU, dense-gather reference
     elsewhere), "pallas" (strict — interpreter mode off-TPU), "dense".
@@ -552,7 +583,7 @@ def ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len, tables,
     if impl not in ("auto", "pallas", "dense"):
         raise ValueError(f"impl must be auto|pallas|dense, got {impl!r}")
     S, Tq, H, Dh = q.shape
-    Hkv = k_pages.shape[0]
+    Hkv, _, page_size, _ = k_pages.shape[-4:]
     if H % Hkv:
         raise ValueError(f"H={H} not a multiple of Hkv={Hkv}")
     G = H // Hkv
@@ -578,30 +609,35 @@ def ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len, tables,
             from .. import autotune as at
             win = at.lookup("ragged_paged_attention",
                             pages_per_slot=int(tables.shape[1]),
-                            page_size=int(k_pages.shape[2]),
+                            page_size=int(page_size),
                             head_dim=int(Dh),
                             dtype=str(jnp.dtype(k_pages.dtype)))
             if win is not None and "kv_tile_pages" in win:
                 tile = int(win["kv_tile_pages"])
             else:
-                tile = default_kv_tile_pages(tables.shape[1],
-                                             k_pages.shape[2], Dh,
-                                             k_pages.dtype)
+                tile = default_kv_tile_pages(tables.shape[1], page_size,
+                                             Dh, k_pages.dtype)
         else:
             tile = 0
     tile = int(tile)
     if use_pallas:
+        # one kernel body: a single layer's 4-D pool enters as a
+        # one-layer stack (a bitcast) read at layer 0
+        if layer is None:
+            k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+        layer = jnp.asarray(layer, jnp.int32).reshape(1)
         if tile:
-            out = _pallas_tiled_impl(qs, k_pages, v_pages, q_len,
+            out = _pallas_tiled_impl(qs, k_pages, v_pages, layer, q_len,
                                      kv_len, tables, tq=Tq, g=G,
                                      tile_pages=tile,
                                      interpret=not _on_tpu())
         else:
-            out = _pallas_impl(qs, k_pages, v_pages, q_len, kv_len,
+            out = _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len,
                                tables, tq=Tq, g=G,
                                interpret=not _on_tpu())
     else:
-        out = _reference_impl(qs, k_pages, v_pages, q_len, kv_len,
+        out = _reference_impl(qs, _layer_pages(k_pages, layer),
+                              _layer_pages(v_pages, layer), q_len, kv_len,
                               tables, tq=Tq, g=G, tile_pages=tile)
     out = out.reshape(S, Hkv, G, Tq, Dh).transpose(0, 3, 1, 2, 4)
     return out.reshape(S, Tq, H, Dh).astype(q.dtype)
@@ -669,7 +705,7 @@ def _packed_impl(q, k_pages, v_pages, tok_slot, tok_qoff, q_len, kv_len,
 def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
                                   q_len, kv_len, tables, tq: int,
                                   sm_scale=None, impl: str = "auto",
-                                  kv_tile_pages=None):
+                                  kv_tile_pages=None, layer=None):
     """Packed-layout entry for the serving tick: ``q [T, H, Dh]`` is
     the tick's token stream with per-token owner/offset metadata
     (``tok_slot [T]`` — ``S`` = padding sentinel; ``tok_qoff [T]``).
@@ -682,6 +718,8 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
     ``kv_tile_pages`` rides through to the slot-major walk selection
     (None = geometry auto — the serving tick passes nothing and a
     100k-token table picks the tiled walk by itself on TPU).
+    ``layer`` as in ``ragged_paged_attention``: with it the pools are
+    the stacked ``[L, Hkv, P, page_size, Dh]``.
     """
     if impl not in ("auto", "pallas", "dense", "packed"):
         raise ValueError(
@@ -696,8 +734,9 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
     kv_len = jnp.asarray(kv_len, jnp.int32)
     tables = jnp.asarray(tables, jnp.int32)
     if impl == "packed" or (impl == "auto" and not _on_tpu()):
-        return _packed_impl(q, k_pages, v_pages, tok_slot, tok_qoff,
-                            q_len, kv_len, tables, sm_scale)
+        return _packed_impl(q, _layer_pages(k_pages, layer),
+                            _layer_pages(v_pages, layer), tok_slot,
+                            tok_qoff, q_len, kv_len, tables, sm_scale)
     # slot-major boundary: scatter the stream into the kernel's
     # [S, Tq] layout (row S+1 absorbs padding tokens), run the kernel,
     # gather back (padding reads the zero row)
@@ -705,7 +744,7 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
     qs = qs.at[tok_slot, tok_qoff].set(q)
     o = ragged_paged_attention(qs[:S], k_pages, v_pages, q_len, kv_len,
                                tables, sm_scale=sm_scale, impl=impl,
-                               kv_tile_pages=kv_tile_pages)
+                               kv_tile_pages=kv_tile_pages, layer=layer)
     o = jnp.concatenate([o, jnp.zeros((1,) + o.shape[1:], o.dtype)],
                         axis=0)
     return o[tok_slot, tok_qoff].astype(q.dtype)
@@ -738,19 +777,21 @@ AUDIT_GEOMETRIES = (
 
 def audit_launches(geom, config=None):
     """Zero-execution traceable launches for the kernel auditor: big
-    tensors as ShapeDtypeStructs, scalar-prefetch metadata (q_len,
-    kv_len, tables) concrete so KA002 can evaluate the index maps."""
+    tensors as ShapeDtypeStructs, scalar-prefetch metadata (layer,
+    q_len, kv_len, tables) concrete so KA002 can evaluate the index
+    maps."""
     pps = int(geom["pages_per_slot"])
     ps = int(geom["page_size"])
     dh = int(geom["head_dim"])
     dt = jnp.dtype(geom["dtype"])
     S, Hkv, G, Tq = 4, 2, 2, 8
     qs = jax.ShapeDtypeStruct((S, Hkv, G * Tq, dh), dt)
-    pages = jax.ShapeDtypeStruct((Hkv, S * pps, ps, dh), dt)
+    pages = jax.ShapeDtypeStruct((1, Hkv, S * pps, ps, dh), dt)
+    layer = np.zeros((1,), np.int32)
     q_len = np.full((S,), Tq, np.int32)
     kv_len = np.full((S,), pps * ps, np.int32)
     tables = np.arange(S * pps, dtype=np.int32).reshape(S, pps)
-    args = (qs, pages, pages, q_len, kv_len, tables)
+    args = (qs, pages, pages, layer, q_len, kv_len, tables)
     if config is not None and "kv_tile_pages" in config:
         tile = int(config["kv_tile_pages"])
     else:

@@ -1219,11 +1219,17 @@ class ServingEngine:
         guarantee to cover. Safe any time (serialized against ticks;
         real pages are never read into outputs that matter nor
         written). Returns the number of jit invocations made."""
+        import jax
         jnp = self._jnp
         S = self.scheduler.max_batch
         n = 0
         pad_meta, tabs, zs, samp = self._pad_tick_args()
-        with self._tick_lock:
+        # jit keys its programs on the calling thread's config context:
+        # under a caller's ``jax.default_device(...)`` (thread-local;
+        # the engine thread has none) these calls would warm programs
+        # the engine thread never runs, and its first real ticks would
+        # load every tick program a second time
+        with self._tick_lock, jax.default_device(None):
             def spec_meta(T):
                 m = pad_meta(T)
                 k = self._spec_k

@@ -13,6 +13,8 @@ test file. Compiles happen in the test's own process for the same
 reason.
 """
 import functools
+import math
+import re
 import types
 
 import jax
@@ -53,12 +55,16 @@ def chip(topo):
     jax.config.update("jax_default_matmul_precision", None)
     cc.reset_cache()
 
-    def compile_for_chip(fn, *shapes):
-        args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)
-                for s in shapes]
-        lowered = jax.jit(fn).lower(*args)
+    def compile_for_chip(fn, *shapes, donate=()):
+        """``shapes``: ShapeDtypeStructs, or pytrees of them."""
+        args = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            shapes)
+        lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
+        compiled = lowered.compile()
         text = types.SimpleNamespace(lowered=lowered.as_text(),
-                                     compiled=lowered.compile().as_text())
+                                     compiled=compiled.as_text(),
+                                     memory=compiled.memory_analysis())
         assert "tpu_custom_call" in text.compiled, "no Mosaic kernel"
         return text
 
@@ -72,11 +78,13 @@ def sds(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _ragged_args(tq, pps):
-    pages = sds((HKV, SLOTS * pps + 1, PAGE, DH))
+def _ragged_args(tq, pps, slots=SLOTS, layers=1, pages=None):
+    """The kernels' operands: the STACKED pools and the layer index in
+    front of the geometry (a 4-D pool enters as a one-layer stack)."""
+    pages = sds((layers, HKV, pages or slots * pps + 1, PAGE, DH))
     i32 = functools.partial(sds, dtype=jnp.int32)
-    return (sds((SLOTS, HKV, G * tq, DH)), pages, pages, i32((SLOTS,)),
-            i32((SLOTS,)), i32((SLOTS, pps)))
+    return (sds((slots, HKV, G * tq, DH)), pages, pages, i32((1,)),
+            i32((slots,)), i32((slots,)), i32((slots, pps)))
 
 
 # the engine's packed widths in the smoke: the fused block (tq=1), a
@@ -104,6 +112,131 @@ def test_ragged_tiled(chip, tq):
     fn = functools.partial(R._pallas_tiled_impl, tq=tq, g=G,
                            tile_pages=tile, interpret=False)
     chip(fn, *_ragged_args(tq, pps))
+
+
+# the benchmark's chat cell (benchmark/workloads/mistral7b-serve-chat.json):
+# 32 slots of up to 160 pages over a pool of 3584, 16 layers, 128-row chunks
+CHAT = dict(slots=32, pps=160, pages=3584, layers=16, tq=128)
+
+
+@pytest.mark.parametrize("walk", ["one_shot", "tiled"])
+def test_ragged_layer_indexed_chat_geometry(chip, walk):
+    """What the serving tick launches per layer: the kernel over the
+    stacked pool with the scan's layer index, at the chat cell's
+    geometry, both walks (the tiled one is what a recorded autotune
+    winner or a longer table would select there)."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    tq = CHAT["tq"]
+    if walk == "tiled":
+        fn = functools.partial(
+            R._pallas_tiled_impl, tq=tq, g=G, interpret=False,
+            tile_pages=R.DEFAULT_TILE_KV_TOKENS // PAGE)
+    else:
+        assert R.default_kv_tile_pages(CHAT["pps"], PAGE, DH) == 0
+        fn = functools.partial(R._pallas_impl, tq=tq, g=G, interpret=False)
+    chip(fn, *_ragged_args(tq, CHAT["pps"], CHAT["slots"], CHAT["layers"],
+                           CHAT["pages"]))
+
+
+def _mistral_tick_shapes(tq, layers, pages):
+    """``serving_tick``'s operands at Mistral-7B-v0.3 widths (the
+    Llama-3-8B ones above but for the vocabulary) and the chat cell's
+    slots and table width, cut to ``layers`` layers and ``pages``
+    pages."""
+    from paddle_tpu.models import llama as L
+    cfg = L.LlamaConfig(
+        vocab_size=32768, hidden_size=D, intermediate_size=14336,
+        num_hidden_layers=layers, num_attention_heads=H,
+        num_key_value_heads=HKV, rope_theta=1e6, dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: L.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda a: sds(a.shape), params)
+    S, T = CHAT["slots"], CHAT["slots"] + (tq if tq > 1 else 0)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    f32 = functools.partial(sds, dtype=jnp.float32)
+    meta = dict(tok_slot=i32((T,)), tok_pos=i32((T,)), tok_page=i32((T,)),
+                tok_off=i32((T,)), tok_qoff=i32((T,)), q_len=i32((S,)),
+                kv_len=i32((S,)), last=i32((S,)),
+                tables=i32((S, CHAT["pps"])), temp=f32((S,)),
+                top_p=f32((S,)), top_k=i32((S,)),
+                key=sds((S, 2), jnp.uint32), produced=i32((S,)))
+    pool = sds((layers, HKV, pages, PAGE, DH))
+    return cfg, (params, i32((T,)), meta, pool, pool)
+
+
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
+_HLO_ARRAY = re.compile(r"\b(bf16|f16|f32|s32|u32|s8|u8|pred)\[([\d,]+)\]")
+_ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+             "u8": 1, "pred": 1}
+
+
+def _page_results(compiled_text, nbytes):
+    """(name, opcode, line) of every instruction of the compiled
+    program, fused computations included, whose result holds an array
+    of pages (rows of ``DH``, which no weight has) of ``nbytes`` or
+    more."""
+    out = []
+    for line in compiled_text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m:
+            continue
+        for dtype, dims in _HLO_ARRAY.findall(m.group(2)):
+            dims = [int(d) for d in dims.split(",")]
+            if (dims[-1] == DH
+                    and _ITEMSIZE[dtype] * math.prod(dims) >= nbytes):
+                out.append((m.group(1), m.group(3), line))
+                break
+    return out
+
+
+def _scatters_in_place(compiled_text, line):
+    """Whether a ``fusion`` is the span's scatter taken in place: its
+    computation scatters, and its result shares its operand's buffer."""
+    called = re.search(r"calls=%([\w.\-]+)", line).group(1)
+    body = compiled_text.split(f"\n%{called} (", 1)[1].split("\n}", 1)[0]
+    return (" scatter(" in body
+            and '"aliasing_operands":{"lists":[]}' not in line)
+
+
+@pytest.mark.parametrize("tq", [1, 128])
+def test_serving_tick_holds_the_pool_once(chip, monkeypatch, tq):
+    """The whole tick, compiled for the described chip: the KV pool is
+    ONE buffer from the program's parameter to its result. Nothing but
+    the parameters, the layer loop and its carry (and the span's rows
+    scattered into that carry in place) has a result as large as one
+    layer's K pages: no ``copy``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` of a layer's pages, no relayout in front
+    of the kernel, no whole-pool copy into the donated buffers at the
+    end; and the program's temporaries stay under one pool."""
+    from paddle_tpu.models import llama as L
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    # the packed entry asks the backend, and sees the CPU here
+    monkeypatch.setattr(R, "_on_tpu", lambda: True)
+    # the cell's own pool: a layer's pages (112 MiB) are then larger
+    # than the kernel's slot-major queries (32 x 128 rows, 64 MiB in f32)
+    layers, pages = 2, CHAT["pages"]
+    cfg, shapes = _mistral_tick_shapes(tq, layers, pages)
+
+    def serving_tick(params, tokens, meta, k_pages, v_pages):
+        return L.serving_tick(params, tokens, meta, k_pages, v_pages, cfg,
+                              tq=tq)
+
+    text = chip(serving_tick, *shapes, donate=(3, 4))
+    from chip_smoke import kernels_in
+    assert kernels_in(text.compiled)["ragged_paged_attention"] == 1
+    layer_pages = HKV * pages * PAGE * DH * 2     # bytes of one layer's K
+    carried = {"parameter", "while", "tuple", "get-tuple-element",
+               "bitcast", "scatter"}
+    found = _page_results(text.compiled, layer_pages)
+    moved = [f"{opcode} {name}" for name, opcode, line in found
+             if opcode not in carried
+             and not (opcode == "fusion"
+                      and _scatters_in_place(text.compiled, line))]
+    assert {"parameter", "while"} <= {opcode for _, opcode, _ in found}
+    assert not moved, f"the tick moves a layer's pages or more: {moved}"
+    assert text.memory.alias_size_in_bytes >= 2 * layers * layer_pages
+    assert text.memory.temp_size_in_bytes < layers * layer_pages
 
 
 def test_splash_fwd(chip):

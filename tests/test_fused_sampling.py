@@ -155,15 +155,23 @@ def test_single_step_program_gone_from_inventory(params):
     assert inv["widths"][str(S)] == ["serving_tick_block[k=4]"]
 
 
-def test_warm_programs_sentinel_clean_under_sampled_traffic(params):
+@pytest.mark.parametrize("ambient_device", [False, True])
+def test_warm_programs_sentinel_clean_under_sampled_traffic(
+        params, ambient_device):
     """warm_programs() covers the whole r16 inventory (one compile per
     mixed-width tail variant + the block), and an armed sentinel stays
     clean through mixed greedy+sampled+chunked traffic — the runtime
-    proof that sampling really is data."""
+    proof that sampling really is data. Also when the caller warms
+    inside ``jax.default_device(...)``, as a deployment that names its
+    chip does: that context is the calling thread's, the engine thread
+    has none, and the programs warmed must be the ones IT runs."""
+    import contextlib
     from paddle_tpu.serving import engine as _em
     _em._JIT_CACHE.clear()
-    with _engine(params, recompile_sentinel=True, prefill_chunk=4,
-                 max_batch=2, decode_block_size=2) as eng:
+    ambient = (jax.default_device(jax.devices()[0]) if ambient_device
+               else contextlib.nullcontext())
+    with ambient, _engine(params, recompile_sentinel=True, prefill_chunk=4,
+                          max_batch=2, decode_block_size=2) as eng:
         n = eng.warm_programs()
         # two tail variants per mixed width (decode_block=2) + block
         assert n == 2 * len(eng._w_grid) + 1
