@@ -28,6 +28,7 @@ sys.path.insert(0, os.path.dirname(HERE))
 def serve_seed(cell, seed, seconds, devs):
     import jax
     from harness import serve
+    from harness.common import Checks
     eng, params, model, family = serve.setup(cell, seed, devs)
     try:
         win = serve.run_window(cell, eng, model, seed, seconds, False)
@@ -37,7 +38,7 @@ def serve_seed(cell, seed, seconds, devs):
     _, attempted, failed, _ = serve.end_to_end(win)
     sample = serve.sample_for_check(
         win["records"], seed, int(cell.workload["check_requests"]) - 1)
-    checks = []
+    checks = Checks()
     t0 = time.perf_counter()
     with jax.default_device(devs[0]):
         out = serve.compare_with_reference(
@@ -53,9 +54,8 @@ def serve_seed(cell, seed, seconds, devs):
 def train_seed(cell, seed, devs):
     import jax
     from harness import train
-    from harness.manifest import load_family
-    model = cell.model
-    family = load_family(model["family"])
+    from harness.common import Checks
+    model, family = cell.model, cell.family
     trainer = train.Trainer(cell, model, family, seed, devs)
     first = trainer.first_steps(cell.workload["optimizer"])
     del trainer
@@ -65,7 +65,7 @@ def train_seed(cell, seed, devs):
         ref_s = time.perf_counter() - t0
         low = train.run_reference(cell, model, family, seed,
                                   round_to=family.CONTROL_ROUND_TO)
-    checks_low, checks = [], []
+    checks_low, checks = Checks(), Checks()
     out = {"seed": seed, "reference_s": ref_s,
            "step_ms": [s * 1e3 for s in first["step_s"]],
            "program": train.compare_training(

@@ -5,6 +5,7 @@ from __future__ import annotations
 import json
 import os
 import shutil
+import sys
 
 import jax
 import jax.monitoring
@@ -95,18 +96,42 @@ def device_block(devs, **extra) -> dict:
 
 
 def result_line(*, correct, attempted, failed, metrics, device,
-                breakdown=None) -> str:
+                breakdown=None, compared=None) -> str:
+    """The last line of stdout; ``compared`` (each number compared,
+    beside its limit) comes last in it."""
     out = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    if compared is not None:
+        out["compared"] = compared
     return json.dumps(out)
 
 
-def check(name: str, value: float, limit: float, checks: list) -> None:
+class Checks(list):
+    """A run's comparisons: the list of passes (``all(checks)``), and in
+    ``compared`` each number beside its limit, ``{name: [value,
+    limit]}``, for the result line and the last lines of stderr."""
+
+    def __init__(self):
+        super().__init__()
+        self.compared = {}
+
+    def note(self, name: str, value: float, limit: float, ok: bool):
+        self.append(bool(ok))
+        self.compared[name] = [float(value), float(limit)]
+
+    def to_stderr(self) -> None:
+        for name, (value, limit) in self.compared.items():
+            print(f"[compared] {name} = {value:.6g}  limit {limit:.6g}",
+                  file=sys.stderr)
+        sys.stderr.flush()
+
+
+def check(name: str, value: float, limit: float, checks: Checks) -> None:
     """One number compared, printed beside its limit."""
     ok = bool(np.isfinite(value)) and value <= limit
-    checks.append(ok)
+    checks.note(name, value, limit, ok)
     log(f"[correct] {name} = {value:.6g}  limit {limit:.6g}  "
         f"{'ok' if ok else 'FAIL'}")
 

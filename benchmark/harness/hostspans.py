@@ -35,11 +35,16 @@ PHASE = "serving.phase."
 TICK = "serving.tick"
 # the scopes the program names (models/llama.py, qwen2_moe.py,
 # incubate/moe/functional.py); an operation belongs to the innermost
-# one on its path
+# one on its path. A family whose model step enters scopes of its own
+# declares them (``SCOPES``), and its kernels (``KERNELS``), in its file:
+# ``tables(family)`` adds them to these.
 SCOPES = ("embed", "layers", "attn.qkv_rope", "kv_pool.write",
           "ragged_attn", "attn.out", "mlp", "moe.router", "moe.experts",
           "moe.shared", "lm_head", "sampler", "loss", "optimizer")
-KERNEL = re.compile(r"^ragged_paged_attention")
+# ``{"<scope>.<word>": pattern}``: an operation under ``<scope>`` whose
+# name matches the pattern is the kernel, filed apart from the
+# operations around it
+KERNELS = {"ragged_attn.kernel": r"^ragged_paged_attention"}
 # the stat of a device operation's event metadata that holds its scope
 # path (``jit(serving_tick)/layers/while/body/closed_call/kv_pool.write/
 # scatter:``, the HLO ``op_name``), on a TPU v5e with jax 0.9.0
@@ -125,26 +130,34 @@ def scope_of(path: str, scopes=SCOPES):
     return None
 
 
-def label(name: str, path: str) -> str:
+def tables(family=None):
+    """``(scopes, kernels)``: the harness's own and, added to them,
+    those the family declares."""
+    return (SCOPES + tuple(getattr(family, "SCOPES", ())),
+            {**KERNELS, **getattr(family, "KERNELS", {})})
+
+
+def label(name: str, path: str, scopes=SCOPES, kernels=KERNELS) -> str:
     """What the busy-by-scope table files an operation under: its
-    scope, with the ragged kernel apart from the operations around it.
-    An operation without a scope is one the compiler added and gave no
+    scope, with a kernel apart from the operations around it. An
+    operation without a scope is one the compiler added and gave no
     ``op_name`` (on the chip: the result pools copied whole into the
     donated buffers as the tick program ends, ``copy.141``): it is filed
     under its opcode, ``xla:copy``."""
-    scope = scope_of(path)
+    scope = scope_of(path, scopes)
     if scope is None:
         return "xla:" + name.split(".", 1)[0]
-    if scope == "ragged_attn" and KERNEL.match(name):
-        return "ragged_attn.kernel"
+    for kernel, pattern in kernels.items():
+        if kernel.rsplit(".", 1)[0] == scope and re.match(pattern, name):
+            return kernel
     return scope
 
 
-def self_time_by_label(events, window=None):
+def self_time_by_label(events, window=None, scopes=SCOPES, kernels=KERNELS):
     """``events``: ``[(name, start, end, scope path)]`` of one device's
     operation line. Self time (``trace.self_times``: a ``while`` does not
     count its body again) by ``label``, clipped to ``window``."""
-    evs = [(label(n, p), s, e) for n, s, e, p in events]
+    evs = [(label(n, p, scopes, kernels), s, e) for n, s, e, p in events]
     if window is not None:
         w0, w1 = window
         evs = [(n, max(s, w0), min(e, w1)) for n, s, e in evs
@@ -304,9 +317,10 @@ def load(ctx):
     """The run's xplane, reduced once for all readers of the run (kept
     in ``ctx``; its two tables are logged once): ``idle_by_phase`` over
     the device's window, self time ``by_label`` there and
-    ``tick_by_label`` over the whole ticks inside it, and those ticks'
-    summed ``tick_stats``; all in ns. None where the trace has no device
-    operation."""
+    ``tick_by_label`` over the whole ticks inside it (by the harness's
+    scopes and kernels and those of the cell's family), those ticks'
+    summed ``tick_stats``, all in ns, and the ticked ``phases``. None
+    where the trace has no device operation."""
     if "hostspans" in ctx:
         return ctx["hostspans"]
     try:
@@ -316,6 +330,7 @@ def load(ctx):
         annotations = device = modules = None
     out = None
     if device:
+        scopes, kernels = tables(ctx["cell"].family)
         window = (min(s for _, s, _, _ in device),
                   max(e for _, _, e, _ in device))
         idle = idle_intervals([(n, s, e) for n, s, e, _ in device], window)
@@ -323,9 +338,11 @@ def load(ctx):
         ticks = whole_ticks(annotations, window)
         out = {
             "idle_by_phase": idle_by_phase(idle, phases) if phases else {},
-            "by_label": self_time_by_label(device),
+            "phases": phases,
+            "by_label": self_time_by_label(device, None, scopes, kernels),
             "tick_by_label": (self_time_by_label(
-                device, (ticks[0][0], ticks[-1][1])) if ticks else {}),
+                device, (ticks[0][0], ticks[-1][1]), scopes, kernels)
+                if ticks else {}),
             "tick_stats": {k: stat_sum(ticks, k)
                            for k in ("rows", "rows_real", "kv_tokens")},
         }

@@ -5,7 +5,8 @@ A later PR adds files and entries and edits nothing here: a cell is
 ``workloads/<name>.json``, a configuration the ``file`` its entry
 gives, a traffic mix ``traffic/<traffic>.json``, a per-layer metric
 ``layer_metrics/<name>.py`` with a ``read(ctx)``, a model family
-``families/<family>.py``.
+``families/<family>.py``. Each is looked for under the manifest's own
+first path, then under this benchmark's directory.
 """
 from __future__ import annotations
 
@@ -34,19 +35,6 @@ def load_module(path: str, name: str):
     return mod
 
 
-def load_family(name: str):
-    """``families/<name>.py``, registered so that one family can build
-    on another (``from bench_family_dense_decoder import ...``)."""
-    import sys
-    key = f"bench_family_{name}"
-    if key not in sys.modules:
-        if name != "dense_decoder":
-            load_family("dense_decoder")
-        sys.modules[key] = load_module(
-            os.path.join(BENCH_DIR, "families", f"{name}.py"), key)
-    return sys.modules[key]
-
-
 def find_file(bench_dir: str, *parts: str) -> str:
     """A file of the benchmark by its name: under ``bench_dir`` (the
     manifest's own first path), else under this benchmark's directory
@@ -57,6 +45,19 @@ def find_file(bench_dir: str, *parts: str) -> str:
             return path
     raise SystemExit(f"BENCHMARK.json names {os.path.join(*parts)!r}, "
                      f"which is not under {bench_dir}")
+
+
+def load_family(name: str, bench_dir: str = BENCH_DIR):
+    """``families/<name>.py``, registered so that one family can build
+    on another (``from bench_family_dense_decoder import ...``)."""
+    import sys
+    key = f"bench_family_{name}"
+    if key not in sys.modules:
+        if name != "dense_decoder":
+            load_family("dense_decoder")
+        sys.modules[key] = load_module(
+            find_file(bench_dir, "families", f"{name}.py"), key)
+    return sys.modules[key]
 
 
 def load_reader(name: str, bench_dir: str = BENCH_DIR):
@@ -78,7 +79,8 @@ class Cell:
     """One entry of ``workloads`` with every file it names, loaded."""
 
     def __init__(self, manifest: dict, name: str, root: str = ROOT):
-        bench_dir = os.path.join(root, manifest["paths"][0])
+        bench_dir = os.path.normpath(
+            os.path.join(root, manifest["paths"][0]))
         entry = next((w for w in manifest["workloads"]
                       if w["name"] == name), None)
         if entry is None:
@@ -88,6 +90,7 @@ class Cell:
         cfg_entry = next(c for c in manifest["configs"]
                          if c["name"] == entry["config"])
         self.name = name
+        self.bench_dir = bench_dir
         self.chips = int(entry["chips"])
         self.entry = entry
         self.config = _load_json(os.path.join(root, cfg_entry["file"]))
@@ -116,3 +119,9 @@ class Cell:
                               else m["moves"] in e2e)]
         self.readers = {m["name"]: load_reader(m["name"], bench_dir)
                         for m in self.per_layer}
+
+    @property
+    def family(self):
+        """The configuration's family module, loaded at first use (it
+        imports JAX; building a cell does not)."""
+        return load_family(self.model["family"], self.bench_dir)

@@ -5,11 +5,10 @@ import time
 
 import jax
 
-from . import serve, train
+from . import hostspans, serve, train
 from . import trace as T
-from .common import (check, device_block, log, pctl, result_line,
+from .common import (Checks, check, device_block, log, pctl, result_line,
                      trace_dir)
-from .manifest import load_family
 
 
 def read_layers(cell, ctx: dict) -> dict:
@@ -23,18 +22,22 @@ def read_layers(cell, ctx: dict) -> dict:
     return out
 
 
-def finish(cell, devs, peak: dict, checks: list, attempted: int,
+def finish(cell, devs, peak: dict, checks: Checks, attempted: int,
            failed: int, metrics: dict, ctx: dict | None) -> str:
     """The last line. Untraced (``ctx`` None): the end-to-end metrics.
     Traced: the trace is reduced, every per-layer reader reads ``ctx``
-    and the line carries the per-layer metrics and the breakdown."""
+    and the line carries the per-layer metrics and the breakdown. The
+    numbers compared go to stderr as its last lines, and last into the
+    line."""
     device, breakdown = peak, None
     # the harness measures what the mode can; the manifest says which
     # of it is this cell's end-to-end metrics
     names = {m["name"] for m in cell.end_to_end}
     metrics = {k: v for k, v in metrics.items() if k in names}
     if ctx is not None:
-        red = T.reduce_trace(trace_dir(), len(devs))
+        hs = hostspans.load(ctx)
+        red = T.reduce_trace(trace_dir(), len(devs),
+                             phases=hs["phases"] if hs else None)
         ctx["trace"] = red
         device = {**peak, "busy_s": red["busy_s"],
                   "window_s": red["window_s"]}
@@ -43,9 +46,10 @@ def finish(cell, devs, peak: dict, checks: list, attempted: int,
         log(f"[trace] window {red['window_s']:.3f} s busy "
             f"{red['busy_s']:.3f} s events {red['n_events']}")
         metrics = read_layers(cell, ctx)
+    checks.to_stderr()
     return result_line(correct=all(checks), attempted=attempted,
                        failed=failed, metrics=metrics, device=device,
-                       breakdown=breakdown)
+                       breakdown=breakdown, compared=checks.compared)
 
 
 def run_serve(cell, args, devs, t_process: float) -> str:
@@ -76,7 +80,8 @@ def run_serve(cell, args, devs, t_process: float) -> str:
         log(f"[window] generator lateness ms: p50 {pctl(late, 50):.4f} "
             f"p95 {pctl(late, 95):.4f} max {max(late):.4f}")
     # ------------------------------------------------- correctness ----
-    checks = [failed == 0]
+    checks = Checks()
+    checks.note("failed_requests", failed, 0, failed == 0)
     log(f"[correct] failed requests = {failed}  limit 0  "
         f"{'ok' if failed == 0 else 'FAIL'}")
     check("compiles_in_window", win["compiles_in_window"], 0, checks)
@@ -105,8 +110,7 @@ MODES = {"serve_open": run_serve, "serve_closed": run_serve}
 
 
 def run_train(cell, args, devs, t_process: float) -> str:
-    model = cell.model
-    family = load_family(model["family"])
+    model, family = cell.model, cell.family
     wl = cell.workload
     trainer = train.Trainer(cell, model, family, args.seed, devs)
     kern = train.kernels_in(trainer.text)
@@ -123,14 +127,14 @@ def run_train(cell, args, devs, t_process: float) -> str:
     log(f"[window] {win['steps']} steps of {win['tokens_per_step']} tokens "
         f"in {win['window_s']:.3f} s; loss first {win['losses'][0]:.4f} "
         f"last {win['losses'][-1]:.4f}")
-    checks = []
+    checks = Checks()
     check("non_finite_losses", win["failed"], 0, checks)
     check("compiles_in_window", win["compiles_in_window"], 0, checks)
     need = wl.get("kernels", [])
     missing = [k for k in need if not kern.get(k)]
     log(f"[correct] kernels missing from the compiled step: {missing}  "
         f"limit none  {'ok' if not missing else 'FAIL'}")
-    checks.append(not missing)
+    checks.note("kernels_missing", len(missing), 0, not missing)
     del trainer                 # the program's state goes; then the reference
     t_ref = time.perf_counter()
     with jax.default_device(devs[0]):

@@ -149,10 +149,40 @@ def moe_layer(lp, h, positions, m, round_to=None):
 LAYER_FNS = {"dense": dense_layer, "moe": moe_layer}
 
 
-@partial(jax.jit, static_argnames=("kind", "model", "round_to"))
-def _layer_jit(lp, h, positions, *, kind, model, round_to):
+def layer_groups(params, model: dict, family) -> list:
+    """The model's layers as the reference walks them: ``[(layer_fn,
+    stacked_params, prefix)]`` in order. A family that brings its own
+    layers defines ``reference_layers(params, model)`` returning
+    ``[(layer_fn, stacked_params), ...]``, each ``layer_fn(lp, h,
+    positions, m, round_to) -> h`` in plain float32 ``jax.numpy`` and
+    each stack on a leading layer axis; a third element names where the
+    stack sits in the program's pytree when that is not ``layers`` (two
+    stacks of different shapes cannot share one). A family without it
+    is one stack of the kind its ``REFERENCE_KIND`` names."""
+    own = getattr(family, "reference_layers", None)
+    groups = (own(params, model) if own else
+              [(LAYER_FNS[family.REFERENCE_KIND], params["layers"])])
+    return [(g[0], g[1], g[2] if len(g) > 2 else "layers") for g in groups]
+
+
+def _unstacked(groups, cast=None):
+    """``(layer_fn, prefix, one layer's params)`` for every layer of
+    every group, in order."""
+    for layer_fn, stack, prefix in groups:
+        for i in range(jax.tree_util.tree_leaves(stack)[0].shape[0]):
+            yield layer_fn, prefix, jax.tree_util.tree_map(
+                (lambda a: a[i]) if cast is None
+                else (lambda a: a[i].astype(cast)), stack)
+
+
+def _leaf_name(prefix, path) -> str:
+    return prefix + "." + ".".join(str(getattr(k, "key", k)) for k in path)
+
+
+@partial(jax.jit, static_argnames=("layer_fn", "model", "round_to"))
+def _layer_jit(lp, h, positions, *, layer_fn, model, round_to):
     with jax.default_matmul_precision("highest"):
-        return LAYER_FNS[kind](lp, h, positions, dict(model), round_to)
+        return layer_fn(lp, h, positions, dict(model), round_to)
 
 
 @partial(jax.jit, static_argnames=("eps", "round_to"))
@@ -167,18 +197,15 @@ def _static_model(model: dict) -> tuple:
                         if isinstance(v, (int, float, bool))))
 
 
-def hidden_states(params, tokens, model: dict, kind: str, round_to=None):
+def hidden_states(params, tokens, model: dict, family, round_to=None):
     """Final-layer hidden states ``[T, D]`` (before the last norm) of
-    one sequence, layer by layer."""
+    one sequence, layer by layer over the family's ``layer_groups``."""
     tokens = jnp.asarray(tokens, jnp.int32)
     h = params["embed"][tokens].astype(F32)
     positions = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    layers = params["layers"]
-    n = jax.tree_util.tree_leaves(layers)[0].shape[0]
     static = _static_model(model)
-    for i in range(n):
-        lp = jax.tree_util.tree_map(lambda a: a[i], layers)
-        h = _layer_jit(lp, h, positions, kind=kind, model=static,
+    for layer_fn, _, lp in _unstacked(layer_groups(params, model, family)):
+        h = _layer_jit(lp, h, positions, layer_fn=layer_fn, model=static,
                        round_to=round_to)
     return h
 
@@ -194,7 +221,7 @@ def pad_to(n: int, quantum: int = 256) -> int:
     return -(-n // quantum) * quantum
 
 
-def served_gaps(params, prompt, served, model: dict, kind: str,
+def served_gaps(params, prompt, served, model: dict, family,
                 control_round_to=None, pad_len: int | None = None):
     """Teacher-forced over ``prompt + served``: at every served position
     the gap by which the served token's reference logit lies below the
@@ -213,13 +240,13 @@ def served_gaps(params, prompt, served, model: dict, kind: str,
     padded = np.zeros((max(pad_len or 0, pad_to(seq.size)),), np.int32)
     padded[:seq.size] = seq
     rows = np.arange(p - 1, p - 1 + n)
-    ref = logits_at(params, hidden_states(params, padded, model, kind),
+    ref = logits_at(params, hidden_states(params, padded, model, family),
                     rows, model)
     best = ref.max(-1)
     gaps = np.asarray(best - ref[jnp.arange(n), jnp.asarray(served)])
     if control_round_to is None:
         return gaps, None
-    low = logits_at(params, hidden_states(params, padded, model, kind,
+    low = logits_at(params, hidden_states(params, padded, model, family,
                                           control_round_to),
                     rows, model, control_round_to)
     first = jnp.argmax(low, -1)
@@ -229,7 +256,7 @@ def served_gaps(params, prompt, served, model: dict, kind: str,
 # ------------------------------------------------------------ training ----
 # The plain training reference: float32 forward, backward and AdamW, one
 # layer at a time so that it fits one chip beside nothing else (it runs
-# before the program's state is made). Two full steps and the third
+# once the program's state is freed). Two full steps and the third
 # step's loss: three steps of float32 AdamW state (params, mu, nu at
 # 4 B each) do not fit 16 GB at the depth the program trains, so after
 # step 1 only its gradient is kept (mu1 and nu1 follow from it) and the
@@ -243,18 +270,18 @@ def _batched(layer_fn, lp, h, m, round_to):
     return jax.lax.map(row, h)
 
 
-@partial(jax.jit, static_argnames=("kind", "model", "round_to"))
-def _layer_fwd(lp, h, *, kind, model, round_to):
+@partial(jax.jit, static_argnames=("layer_fn", "model", "round_to"))
+def _layer_fwd(lp, h, *, layer_fn, model, round_to):
     with jax.default_matmul_precision("highest"):
-        return _batched(LAYER_FNS[kind], lp, h, dict(model), round_to)
+        return _batched(layer_fn, lp, h, dict(model), round_to)
 
 
-@partial(jax.jit, static_argnames=("kind", "model", "round_to"))
-def _layer_bwd(lp, h, dh_out, *, kind, model, round_to):
+@partial(jax.jit, static_argnames=("layer_fn", "model", "round_to"))
+def _layer_bwd(lp, h, dh_out, *, layer_fn, model, round_to):
     with jax.default_matmul_precision("highest"):
         _, vjp = jax.vjp(
-            lambda a, b: _batched(LAYER_FNS[kind], a, b, dict(model),
-                                  round_to), lp, h)
+            lambda a, b: _batched(layer_fn, a, b, dict(model), round_to),
+            lp, h)
         return vjp(dh_out)
 
 
@@ -299,21 +326,22 @@ class TrainReference:
     """``params``: the benchmark's own seeded weights (bfloat16 values),
     consumed: cast to float32 leaf by leaf. ``step(tokens, labels)``
     applies AdamW steps 1 and 2; ``loss(tokens, labels)`` is a forward
-    pass. Collects, per stacked leaf name (``layers.wq`` ...), the first
-    gradient's squared norm."""
+    pass. Walks the family's ``layer_groups`` one layer at a time and
+    collects, per stacked leaf name (``layers.wq`` ...: the program's
+    pytree paths), the first gradient's squared norm."""
 
-    def __init__(self, params, model: dict, kind: str, opt: dict,
+    def __init__(self, params, model: dict, family, opt: dict,
                  round_to=None):
-        self.m, self.kind, self.round_to = model, kind, round_to
+        self.m, self.family, self.round_to = model, family, round_to
         self.static = _static_model(model)
         self.opt = tuple(sorted(opt.items()))
-        n = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
-        self.layers = [jax.tree_util.tree_map(
-            lambda a: a[i].astype(F32), params["layers"]) for i in range(n)]
+        # [layer_fn, prefix, this layer's float32 params], in order
+        self.layers = [list(x) for x in _unstacked(
+            layer_groups(params, model, family), F32)]
         self.top = {k: params[k].astype(F32)
                     for k in ("embed", "final_norm", "lm_head")}
         self.t = 0
-        self.g1_layers = [None] * n
+        self.g1_layers = [None] * len(self.layers)
         self.g1_top = {}
         self.grad_sq = {}
 
@@ -321,9 +349,9 @@ class TrainReference:
     def _forward(self, tokens):
         h = self.top["embed"][tokens]
         hs = []
-        for lp in self.layers:
+        for layer_fn, _, lp in self.layers:
             hs.append(h)
-            h = _layer_fwd(lp, h, kind=self.kind, model=self.static,
+            h = _layer_fwd(lp, h, layer_fn=layer_fn, model=self.static,
                            round_to=self.round_to)
         return h, hs
 
@@ -357,15 +385,14 @@ class TrainReference:
             self.g1_top[name] = g if self.t == 1 else None
         del d_fn, d_lm, h
         for i in reversed(range(len(self.layers))):
-            dlp, dh = _layer_bwd(self.layers[i], hs.pop(), dh,
-                                 kind=self.kind, model=self.static,
-                                 round_to=self.round_to)
+            layer_fn, prefix, lp = self.layers[i]
+            dlp, dh = _layer_bwd(lp, hs.pop(), dh, layer_fn=layer_fn,
+                                 model=self.static, round_to=self.round_to)
             prev = self.g1_layers[i]
             for path, g in jax.tree_util.tree_flatten_with_path(dlp)[0]:
-                self._note("layers." + ".".join(
-                    str(getattr(k, "key", k)) for k in path), g)
-            self.layers[i] = jax.tree_util.tree_map(
-                lambda p, g, gp: self._update(p, g, gp), self.layers[i],
+                self._note(_leaf_name(prefix, path), g)
+            self.layers[i][2] = jax.tree_util.tree_map(
+                lambda p, g, gp: self._update(p, g, gp), lp,
                 dlp, dlp if prev is None else prev)
             self.g1_layers[i] = dlp if self.t == 1 else None
         d_emb = jnp.zeros_like(self.top["embed"]).at[tokens].add(dh)
@@ -383,11 +410,16 @@ class TrainReference:
         seeded weights made anew."""
         out = {k: float(np.sqrt(_sq(self.top[k] - params0[k].astype(F32))))
                for k in self.top}
-        for name in self.layers[0]:
-            tot = 0.0
-            for i, lp in enumerate(self.layers):
-                tot += _sq(lp[name] - params0["layers"][name][i].astype(F32))
-            out[f"layers.{name}"] = float(np.sqrt(tot))
+        tot = {}
+        for (_, prefix, lp), (_, _, lp0) in zip(
+                self.layers, _unstacked(layer_groups(params0, self.m,
+                                                     self.family))):
+            for (path, a), b in zip(
+                    jax.tree_util.tree_flatten_with_path(lp)[0],
+                    jax.tree_util.tree_leaves(lp0)):
+                name = _leaf_name(prefix, path)
+                tot[name] = tot.get(name, 0.0) + _sq(a - b.astype(F32))
+        out.update({k: float(np.sqrt(v)) for k, v in tot.items()})
         return out
 
 

@@ -16,7 +16,6 @@ import numpy as np
 
 from . import reference, traffic
 from .common import CompileCounter, check, log, pctl, start_trace
-from .manifest import load_family
 
 # geometry a workload file may set; every policy knob of the engine
 # (decode_block_size, admission_window, prefix_cache, attn_impl,
@@ -209,8 +208,7 @@ def compare_with_reference(params, sample, model, family, limits,
     gaps, low = [], []
     for rec in sample:
         g, c = reference.served_gaps(
-            params, rec.req.prompt, rec.tokens, model,
-            family.REFERENCE_KIND,
+            params, rec.req.prompt, rec.tokens, model, family,
             family.CONTROL_ROUND_TO if control else None, pad_len)
         gaps.append(g)
         if c is not None:
@@ -229,7 +227,7 @@ def compare_with_reference(params, sample, model, family, limits,
               limits["served_logit_gap_mean"], checks)
     else:
         log("[correct] no finished request to compare")
-        checks.append(False)
+        checks.note("requests_compared", 0, 1, False)
     if low:
         allc = np.concatenate(low)
         out.update(control_gap_max=float(allc.max()),
@@ -331,7 +329,7 @@ def end_to_end(win: dict) -> tuple:
 def setup(cell, seed: int, devs):
     """Weights on the device from the seed, the engine, its programs
     warmed. Returns ``(engine, params, model, family)``."""
-    family = load_family(cell.model["family"])
+    family = cell.family
     cfg, mod = family.program_config(cell.model)
     with jax.default_device(devs[0]):
         params = family.make_params(cell.model, seed)

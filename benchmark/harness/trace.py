@@ -4,9 +4,10 @@ and a thin adapter from ``jax.profiler.ProfileData``.
 
 What is sound today: busy/idle by the union of operation intervals,
 time by operation name, exposed collective time by overlap, the top
-operations and the longest gaps. Gaps carry the label ``unattributed``:
-joining them to host spans needs ``TraceAnnotation`` inside the engine
-(PERF.md, "for the tracing issue").
+operations and the longest gaps. A gap carries the name of the host
+phase (``harness/hostspans.py``: the engine thread's
+``serving.phase.*`` annotations, on the same clock) that covers most of
+it, and ``unattributed`` where none does (training has no phases).
 """
 from __future__ import annotations
 
@@ -75,11 +76,23 @@ def self_times(evs):
     return out
 
 
-def reduce_events(events, window=None, top=10, gaps=5):
+def gap_label(gap, phases, prefix="serving.phase.") -> str:
+    """The phase that covers most of ``gap`` ``(start, end)``;
+    ``phases``: ``[(name, start, end, ...)]``."""
+    by = {}
+    for name, s, e, *_ in phases or ():
+        both = min(e, gap[1]) - max(s, gap[0])
+        if both > 0:
+            by[name] = by.get(name, 0) + both
+    return prefix + max(by, key=by.get) if by else "unattributed"
+
+
+def reduce_events(events, window=None, top=10, gaps=5, phases=None):
     """``events``: ``[(name, start_ns, dur_ns)]`` of ONE device's
     operation line. ``window``: ``(start_ns, end_ns)`` or None for the
-    span of the events. Returns seconds throughout; ``by_name_s`` is
-    self time (see ``self_times``)."""
+    span of the events. ``phases``: the host's, to name the gaps by.
+    Returns seconds throughout; ``by_name_s`` is self time (see
+    ``self_times``)."""
     evs = [(short_name(n), s, s + d) for n, s, d in events if d > 0]
     if window is None:
         window = (min(s for _, s, _ in evs), max(e for _, _, e in evs))
@@ -110,7 +123,8 @@ def reduce_events(events, window=None, top=10, gaps=5):
         "collective_exposed_s": exposed * ns,
         "top_ops": [[n, t * ns] for n, t in sorted(
             by_name.items(), key=lambda kv: -kv[1])[:top]],
-        "longest_gaps": [["unattributed", g * ns] for g, _ in idle[:gaps]],
+        "longest_gaps": [[gap_label((at, at + g), phases), g * ns]
+                         for g, at in idle[:gaps]],
         "n_events": len(evs),
     }
 
@@ -169,7 +183,7 @@ def describe(path: str, limit: int = 12) -> list:
     return rows
 
 
-def reduce_trace(trace_dir: str, n_devices: int) -> dict:
+def reduce_trace(trace_dir: str, n_devices: int, phases=None) -> dict:
     """Reduced trace of a run: per-device reductions over one common
     window (first operation start to last operation end on any
     device), ``busy_s`` averaged over the ``n_devices`` used."""
@@ -179,7 +193,8 @@ def reduce_trace(trace_dir: str, n_devices: int) -> dict:
         raise RuntimeError("the trace holds no device operation")
     w0 = min(s for evs in per_dev.values() for _, s, _ in evs)
     w1 = max(s + d for evs in per_dev.values() for _, s, d in evs)
-    devs = [reduce_events(evs, (w0, w1)) for evs in per_dev.values()]
+    devs = [reduce_events(evs, (w0, w1), phases=phases)
+            for evs in per_dev.values()]
     first = devs[0]
     return {**first, "devices": list(per_dev),
             "busy_s": sum(d["busy_s"] for d in devs) / n_devices,

@@ -48,7 +48,7 @@ def run_reference(cell, model, family, seed, round_to=None):
     tr = cell.workload["trainer"]
     B, T, V = tr["batch"], tr["seq_len"], model["vocab_size"]
     params = family.make_params(model, seed)
-    ref = reference.TrainReference(params, model, family.REFERENCE_KIND,
+    ref = reference.TrainReference(params, model, family,
                                    cell.workload["optimizer"], round_to)
     del params
     losses = [ref.step(*host_batch(seed, i, B, T, V))

@@ -46,6 +46,21 @@ def test_gaps_longest_first():
     assert all(n == "unattributed" for n, _ in r["longest_gaps"])
 
 
+def test_a_gap_takes_the_name_of_the_host_phase_that_covers_most_of_it():
+    """``breakdown.idle_gaps``: gaps 350-500, 550-700, 800-1000 against
+    hand-made phases (``hostspans.ticked_phases`` rows)."""
+    phases = [("readback", 340, 420, 7), ("emit", 420, 440, 7),
+              ("admit", 440, 450, 8), ("build", 450, 520, 8),
+              ("dispatch", 600, 640, 8)]
+    r = trace.reduce_events(EVENTS, window=(0, 1000), gaps=3, phases=phases)
+    assert r["longest_gaps"] == [
+        ["unattributed", pytest.approx(200e-9)],        # 800-1000: no phase
+        ["serving.phase.dispatch", pytest.approx(150e-9)],  # 550-700
+        # 350-500: readback 70, emit 20, admit 10, build 50
+        ["serving.phase.readback", pytest.approx(150e-9)]]
+    assert trace.gap_label((0, 10), None) == "unattributed"
+
+
 def test_name_sums_and_top():
     r = trace.reduce_events(EVENTS, window=(0, 1000))
     assert trace.name_sum(r, r"ragged_paged_attention") == pytest.approx(50e-9)
@@ -137,6 +152,81 @@ def test_added_files_are_found_without_an_edit():
     assert "tiny_added_metric" in cell.readers
     assert cell.readers["tiny_added_metric"].read({"late_ms": [1, 3]}) == 3
     assert cell.model["num_hidden_layers"] == 2       # the override
+
+
+def test_added_family_is_found_without_an_edit():
+    """A family that only the test manifest holds, found through
+    ``find_file`` like every other file; the benchmark's own families
+    are still found from there, and its tables reach the trace's."""
+    from harness import hostspans
+    m = manifest.load_manifest(TINY)
+    cell = manifest.Cell(m, "tiny-split-closed", TINY)
+    assert cell.family.__file__ == os.path.join(TINY, "families",
+                                                "tiny_split.py")
+    assert callable(cell.family.reference_layers)
+    dense = manifest.Cell(m, "tiny-serve-closed", TINY).family
+    assert dense.__file__ == os.path.join(BENCH, "families",
+                                          "dense_decoder.py")
+    with pytest.raises(SystemExit):
+        manifest.load_family("no_such_family", TINY)
+    scopes, kernels = hostspans.tables(cell.family)
+    assert scopes[:len(hostspans.SCOPES)] == hostspans.SCOPES
+    assert scopes[-1] == "attn.split_latent"
+    assert kernels == {**hostspans.KERNELS,
+                       "attn.split_latent.kernel": "^split_latent_attention"}
+    assert hostspans.tables(dense) == (hostspans.SCOPES, hostspans.KERNELS)
+
+
+def _tiny_configs():
+    import glob
+    return sorted(glob.glob(os.path.join(TINY, "configs", "*.json")))
+
+
+def test_every_family_has_a_tiny_configuration():
+    """So that the test below runs every family's reference: a PR that
+    adds ``families/<x>.py`` adds ``tests/tiny/configs/<y>.json`` of it."""
+    import glob
+    have = {os.path.basename(p)[:-3] for d in (BENCH, TINY)
+            for p in glob.glob(os.path.join(d, "families", "*.py"))}
+    assert have == {json.load(open(p))["family"] for p in _tiny_configs()}
+
+
+@pytest.mark.parametrize("path", _tiny_configs(),
+                         ids=lambda p: os.path.basename(p)[:-5])
+def test_a_family_s_reference_runs_without_the_program(monkeypatch, path):
+    """No reference code imports ``paddle_tpu``: with the package made
+    unimportable, the family and the reference load anew and run, a
+    forward pass and a training step, at the tiny size."""
+    import importlib
+    for k in [k for k in sys.modules if k.startswith("bench_family_")]:
+        monkeypatch.delitem(sys.modules, k)
+    for k in [k for k in sys.modules if k.split(".")[0] == "paddle_tpu"]:
+        monkeypatch.delitem(sys.modules, k)
+    monkeypatch.setitem(sys.modules, "paddle_tpu", None)
+    with pytest.raises(ImportError):
+        importlib.import_module("paddle_tpu.models")
+    from harness import reference
+    model = json.load(open(path))
+    model["num_hidden_layers"] = 2
+    family = manifest.load_family(model["family"], TINY)
+    toks = np.arange(24, dtype=np.int32) * 5 % model["vocab_size"]
+    h = reference.hidden_states(family.make_params(model, 3), toks, model,
+                                family)
+    logits = np.asarray(reference.logits_at(
+        family.make_params(model, 3), h, np.arange(24), model))
+    assert logits.shape == (24, model["vocab_size"])
+    assert np.isfinite(logits).all()
+    ref = reference.TrainReference(
+        family.make_params(model, 3), model, family,
+        {"lr": 3e-4, "b1": 0.9, "b2": 0.95, "eps": 1e-8,
+         "weight_decay": 0.1})
+    loss = ref.step(toks[None, :-1], toks[None, 1:])
+    assert np.isfinite(loss)
+    change = ref.change_norms(family.make_params(model, 3))
+    assert change.keys() == ref.grad_norms().keys()
+    assert all(np.isfinite(v) and v > 0 for v in change.values())
+    with pytest.raises(ImportError):
+        family.program_config(model)
 
 
 def test_override_outside_reduced_is_refused(tmp_path):

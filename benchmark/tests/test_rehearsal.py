@@ -25,7 +25,7 @@ from harness import manifest, modes, reference  # noqa: E402
 from harness.common import require_devices  # noqa: E402
 
 TINY = os.path.join(HERE, "tiny")
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def run_cell(name, seed=7, seconds=1.5, trace=0):
@@ -66,7 +66,8 @@ def test_serve_closed_last_line():
     assert out["metrics"]["serve_tokens_per_s"]["value"] > 0
 
 
-def test_altered_token_comes_out_not_correct(monkeypatch):
+@pytest.mark.parametrize("name", ["tiny-serve-open", "tiny-split-closed"])
+def test_altered_token_comes_out_not_correct(monkeypatch, name):
     """The timed path broken underneath: every 5th token the engine
     emits is altered where it is produced."""
     from paddle_tpu.serving import engine as E
@@ -80,17 +81,19 @@ def test_altered_token_comes_out_not_correct(monkeypatch):
         return real(self, slot, req, tok)
 
     monkeypatch.setattr(E.ServingEngine, "_emit", emit)
-    _, out = run_cell("tiny-serve-open", seed=11)
+    _, out = run_cell(name, seed=11)
     assert out["correct"] is False and out["failed"] == 0
+    gap, limit = out["compared"]["served_logit_gap_max"]
+    assert gap > limit and list(out)[-1] == "compared"
 
 
-def test_control_fails_the_limits():
+@pytest.mark.parametrize("name", ["tiny-serve-open", "tiny-split-closed"])
+def test_control_fails_the_limits(name):
     """The reference with float8-rounded weights in the program's
     place: the token it puts first lies further below the float32
     reference's best than the cell's limit allows."""
-    cell = manifest.Cell(manifest.load_manifest(TINY), "tiny-serve-open",
-                         TINY)
-    family = manifest.load_family(cell.model["family"])
+    cell = manifest.Cell(manifest.load_manifest(TINY), name, TINY)
+    family = cell.family
     params = family.make_params(cell.model, 5)
     rng = np.random.default_rng(5)
     worst = 0.0
@@ -98,7 +101,7 @@ def test_control_fails_the_limits():
         prompt = rng.integers(0, 256, (40,), dtype=np.int32)
         served = rng.integers(0, 256, (16,), dtype=np.int32)
         _, low = reference.served_gaps(
-            params, prompt, served, cell.model, family.REFERENCE_KIND,
+            params, prompt, served, cell.model, family,
             family.CONTROL_ROUND_TO)
         worst = max(worst, float(low.max()))
     assert worst > 3 * cell.workload["limits"]["served_logit_gap_max"]
@@ -110,14 +113,14 @@ def test_reference_matches_program_forward():
     import jax
     cell = manifest.Cell(manifest.load_manifest(TINY), "tiny-serve-open",
                          TINY)
-    family = manifest.load_family(cell.model["family"])
+    family = cell.family
     params = family.make_params(cell.model, 9)
     cfg, L = family.program_config(cell.model, use_flash_attention=False,
                                    use_fused_norm_rope=False, remat=False)
     toks = np.arange(32, dtype=np.int32) * 7 % 256
     with jax.default_matmul_precision("highest"):
         prog = np.asarray(L.forward(params, toks[None], cfg))[0]
-    h = reference.hidden_states(params, toks, cell.model, "dense")
+    h = reference.hidden_states(params, toks, cell.model, family)
     ref = np.asarray(reference.logits_at(params, h, np.arange(32),
                                          cell.model))
     assert np.abs(prog - ref).max() < 1e-4
@@ -156,12 +159,13 @@ def test_train_control_fails_a_limit():
     """The reference at float8 weights in the program's place: one of
     the compared numbers leaves its limit."""
     from harness import train
+    from harness.common import Checks
     cell = manifest.Cell(manifest.load_manifest(TINY), "tiny-train", TINY)
-    family = manifest.load_family(cell.model["family"])
+    family = cell.family
     ref = train.run_reference(cell, cell.model, family, 4)
     low = train.run_reference(cell, cell.model, family, 4,
                               round_to=family.CONTROL_ROUND_TO)
-    checks = []
+    checks = Checks()
     train.compare_training(low, ref, cell.workload["limits"], checks)
     assert not all(checks)
 
@@ -186,6 +190,47 @@ def test_moe_family_serve_closed_last_line():
     assert out["metrics"]["serve_tokens_per_s"]["value"] > 0
 
 
+def test_added_family_serve_closed_last_line():
+    """A family made only of added files (``tiny/families/tiny_split.py``):
+    its own layer function over a two-group stack decides ``correct``."""
+    cell, out = run_cell("tiny-split-closed", seed=19)
+    assert cell.family.__file__.startswith(TINY)
+    assert not hasattr(cell.family, "REFERENCE_KIND")
+    groups = reference.layer_groups(cell.family.make_params(cell.model, 1),
+                                    cell.model, cell.family)
+    assert [g[1]["wq"].shape[0] for g in groups] == [1, 2]
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["metrics"]["serve_tokens_per_s"]["value"] > 0
+    assert set(out["compared"]) >= {"served_logit_gap_max",
+                                    "served_logit_gap_mean"}
+
+
+def test_added_family_reference_equals_the_built_in_dense_path():
+    """Same weights, serving and training: the family's own layers in
+    two groups against ``LAYER_FNS["dense"]`` over one stack."""
+    from harness import train
+    m = manifest.load_manifest(TINY)
+    split = manifest.Cell(m, "tiny-split-closed", TINY)
+    dense = manifest.load_family("dense_decoder")
+    params = split.family.make_params(split.model, 23)
+    toks = np.arange(48, dtype=np.int32) * 11 % 256
+    for round_to in (None, 3):
+        a = reference.hidden_states(params, toks, split.model, split.family,
+                                    round_to)
+        b = reference.hidden_states(params, toks, split.model, dense,
+                                    round_to)
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-6
+    cell = manifest.Cell(m, "tiny-train", TINY)
+    model = {**cell.model, "num_hidden_layers": 3}
+    a = train.run_reference(cell, model, split.family, 23)
+    b = train.run_reference(cell, model, dense, 23)
+    assert a["grad"].keys() == b["grad"].keys() >= {"layers.wq", "embed"}
+    for what in ("grad", "change"):
+        for k in b[what]:
+            assert a[what][k] == pytest.approx(b[what][k], rel=1e-6), k
+    assert a["losses"] == pytest.approx(b["losses"], abs=1e-6)
+
+
 def _fake_reduced(monkeypatch):
     """The CPU's profile has no TPU plane: stand in for the adapter with
     a hand-made reduction (the reducer itself is tested on events)."""
@@ -196,7 +241,8 @@ def _fake_reduced(monkeypatch):
               ("%all-reduce.4 = all-reduce()", 950_000_000, 20_000_000)]
     red = trace.reduce_events(events, window=(0, 1_000_000_000))
     monkeypatch.setattr(trace, "reduce_trace",
-                        lambda d, n: {**red, "devices": ["fake"]})
+                        lambda d, n, phases=None: {**red,
+                                                   "devices": ["fake"]})
     # an unknown device kind is an error, as it must be; the stand-in
     # trace gets a stand-in peak
     from harness import readers
@@ -215,3 +261,40 @@ def test_traced_run_reports_the_per_layer_metrics(monkeypatch, name):
     want = {m["name"] for m in cell.per_layer}
     assert set(out["metrics"]) <= want and out["metrics"]
     assert not set(out["metrics"]) & {m["name"] for m in cell.end_to_end}
+
+
+def test_traced_run_files_operations_under_the_family_s_own_labels(
+        monkeypatch):
+    """A scope and a kernel that only ``tiny_split`` declares: the traced
+    rehearsal (the device's operation line hand-made, the CPU's profile
+    has none) files an operation under each, the added reader reads
+    them, and another family's cell finds nothing there."""
+    from harness import hostspans
+    _fake_reduced(monkeypatch)
+    body = "jit(serving_tick)/layers/while/body/"
+    device = [
+        ("while.1", 0, 1000, "jit(serving_tick)/layers/while"),
+        ("fusion.2", 0, 100, body + "attn.split_latent/dot_general"),
+        ("split_latent_attention.3", 100, 400,
+         body + "attn.split_latent/pallas_call"),
+        ("ragged_paged_attention.4", 400, 600, body + "ragged_attn/call"),
+        ("fusion.5", 600, 900, body + "mlp/dot_general")]
+    monkeypatch.setattr(hostspans, "read_xplane",
+                        lambda path: ([], device, ["jit_serving_tick"]))
+    seen = {}
+    real = hostspans.load
+
+    def load(ctx):
+        seen[ctx["cell"].name] = real(ctx)
+        return seen[ctx["cell"].name]
+
+    monkeypatch.setattr(hostspans, "load", load)
+    cell, out = run_cell("tiny-split-closed", seed=29, trace=1)
+    by = seen["tiny-split-closed"]["by_label"]
+    assert by == {"attn.split_latent": 100, "attn.split_latent.kernel": 300,
+                  "ragged_attn.kernel": 200, "mlp": 300, "layers": 100}
+    assert out["metrics"]["split_latent_ms_per_tick"]["value"] > 0
+    # the same trace under a family that declares neither
+    run_cell("tiny-serve-closed", seed=29, trace=1)
+    assert seen["tiny-serve-closed"]["by_label"] == {
+        "layers": 500, "ragged_attn.kernel": 200, "mlp": 300}
