@@ -36,8 +36,11 @@ def finish(cell, devs, peak: dict, checks: Checks, attempted: int,
     metrics = {k: v for k, v in metrics.items() if k in names}
     if ctx is not None:
         hs = hostspans.load(ctx)
-        red = T.reduce_trace(trace_dir(), len(devs),
-                             phases=hs["phases"] if hs else None)
+        # the gaps of the breakdown go by the engine thread's phases
+        red = T.reduce_trace(
+            trace_dir(), len(devs),
+            phases=[(hostspans.PHASE + n, s, e)
+                    for n, s, e, _ in hs["phases"]] if hs else None)
         ctx["trace"] = red
         device = {**peak, "busy_s": red["busy_s"],
                   "window_s": red["window_s"]}

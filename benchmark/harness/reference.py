@@ -171,8 +171,7 @@ def _unstacked(groups, cast=None):
     for layer_fn, stack, prefix in groups:
         for i in range(jax.tree_util.tree_leaves(stack)[0].shape[0]):
             yield layer_fn, prefix, jax.tree_util.tree_map(
-                (lambda a: a[i]) if cast is None
-                else (lambda a: a[i].astype(cast)), stack)
+                lambda a: a[i] if cast is None else a[i].astype(cast), stack)
 
 
 def _leaf_name(prefix, path) -> str:

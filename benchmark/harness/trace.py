@@ -15,8 +15,15 @@ import glob
 import os
 import re
 
+# a collective the TPU compiler leaves standing as an operation of its
+# own; ``async-collective-start/done`` bracket one it overlaps. One that
+# it fuses into a compute fusion (``async_collective_fusion``,
+# ``all-reduce-scatter``: on a 2 x 2 mesh the tp weight gathers inside
+# the MLP matmuls) is a ``fusion.N`` like any other and cannot be told
+# apart here.
 COLLECTIVE = re.compile(
-    r"^(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)")
+    r"^(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
+    r"|async-collective)")
 
 
 def union(intervals):
@@ -76,15 +83,15 @@ def self_times(evs):
     return out
 
 
-def gap_label(gap, phases, prefix="serving.phase.") -> str:
+def gap_label(gap, phases) -> str:
     """The phase that covers most of ``gap`` ``(start, end)``;
-    ``phases``: ``[(name, start, end, ...)]``."""
+    ``phases``: ``[(name, start, end)]``."""
     by = {}
-    for name, s, e, *_ in phases or ():
+    for name, s, e in phases or ():
         both = min(e, gap[1]) - max(s, gap[0])
         if both > 0:
             by[name] = by.get(name, 0) + both
-    return prefix + max(by, key=by.get) if by else "unattributed"
+    return max(by, key=by.get) if by else "unattributed"
 
 
 def reduce_events(events, window=None, top=10, gaps=5, phases=None):

@@ -48,10 +48,10 @@ def test_gaps_longest_first():
 
 def test_a_gap_takes_the_name_of_the_host_phase_that_covers_most_of_it():
     """``breakdown.idle_gaps``: gaps 350-500, 550-700, 800-1000 against
-    hand-made phases (``hostspans.ticked_phases`` rows)."""
-    phases = [("readback", 340, 420, 7), ("emit", 420, 440, 7),
-              ("admit", 440, 450, 8), ("build", 450, 520, 8),
-              ("dispatch", 600, 640, 8)]
+    hand-made phases."""
+    phases = [("serving.phase." + n, s, e) for n, s, e in (
+        ("readback", 340, 420), ("emit", 420, 440), ("admit", 440, 450),
+        ("build", 450, 520), ("dispatch", 600, 640))]
     r = trace.reduce_events(EVENTS, window=(0, 1000), gaps=3, phases=phases)
     assert r["longest_gaps"] == [
         ["unattributed", pytest.approx(200e-9)],        # 800-1000: no phase
@@ -83,6 +83,15 @@ def test_a_container_does_not_hide_the_collective_inside_it():
            ("%fusion.4 = fusion()", 500, 500)]
     r = trace.reduce_events(evs, window=(0, 1000))
     assert r["collective_exposed_s"] == pytest.approx(100e-9)
+
+
+def test_async_collective_brackets_count_as_collectives():
+    evs = [("%fusion.1 = fusion()", 0, 100),
+           ("%async-collective-start.2 = ()", 100, 30),
+           ("%fusion.340 = fusion()", 130, 300),     # the gather fused in
+           ("%async-collective-done.2 = ()", 430, 20)]
+    r = trace.reduce_events(evs, window=(0, 500))
+    assert r["collective_exposed_s"] == pytest.approx(50e-9)
 
 
 def test_window_clips_events():
