@@ -37,6 +37,8 @@ def top_k_gating(
     key: Optional[jax.Array] = None,
     second_policy: str = "all",
     normalize_topk: bool = False,
+    score_fn: str = "softmax",
+    select_bias: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Dense top-k gating (GShard).
 
@@ -47,6 +49,10 @@ def top_k_gating(
       key: optional PRNG key; with ``second_policy='random'`` the 2nd+
         expert is kept with probability proportional to its gate value
         (gshard_gate.py random routing).
+      score_fn: ``"softmax"`` over the experts (default) or
+        ``"sigmoid"``, each expert scored on its own.
+      select_bias: optional ``[E]`` added to the scores for the CHOICE
+        of experts only; the combine weights stay the unbiased scores.
 
     Returns:
       (dispatch, combine, aux_loss) with dispatch ``[S, E, C]`` one-hot,
@@ -55,16 +61,27 @@ def top_k_gating(
     """
     S, E = logits.shape
     compute_dtype = jnp.float32
-    raw_gates = jax.nn.softmax(logits.astype(compute_dtype), axis=-1)
+    if score_fn == "softmax":
+        raw_gates = jax.nn.softmax(logits.astype(compute_dtype), axis=-1)
+    elif score_fn == "sigmoid":
+        raw_gates = jax.nn.sigmoid(logits.astype(compute_dtype))
+    else:
+        raise ValueError(f"score_fn must be 'softmax' or 'sigmoid', got "
+                         f"{score_fn!r}")
 
     # iteratively peel off the top-k experts per token
     masks, gate_vals = [], []
     g = raw_gates
+    if select_bias is not None:
+        g = g + select_bias.astype(compute_dtype)[None]
     for i in range(top_k):
         idx = jnp.argmax(g, axis=-1)
         m = jax.nn.one_hot(idx, E, dtype=compute_dtype)      # [S, E]
-        g = g * (1.0 - m)  # peel BEFORE random drop so a dropped expert
-        #                    is never re-picked at the next iteration
+        # peel BEFORE random drop so a dropped expert is never
+        # re-picked at the next iteration (a biased score may be
+        # negative: zero would not keep it out)
+        g = (g * (1.0 - m) if select_bias is None
+             else jnp.where(m > 0, -jnp.inf, g))
         gv = jnp.sum(raw_gates * m, axis=-1)                 # [S]
         if i > 0 and second_policy == "random" and key is not None:
             # keep the i-th expert with prob 2*gate (gshard random routing)
@@ -176,6 +193,9 @@ def moe_ffn(
     key: Optional[jax.Array] = None,
     ep_axis: Optional[str] = None,
     activation=jax.nn.silu,
+    score_fn: str = "softmax",
+    select_bias: Optional[jax.Array] = None,
+    normalize_topk: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Mixture-of-experts SwiGLU FFN over tokens ``x`` ``[..., D]``.
 
@@ -183,6 +203,9 @@ def moe_ffn(
     ``w_down [E, F, D]``. With ``ep_axis`` set and the weights ep-sharded,
     the dispatch/combine einsums below compile to the expert-parallel
     all_to_all (moe_layer.py global_scatter/global_gather equivalent).
+    ``score_fn``, ``select_bias`` and ``normalize_topk`` are the
+    router's, as :func:`top_k_gating` takes them; the defaults give the
+    softmax router with unnormalised weights.
 
     Returns (y, aux_loss) with y shaped like x.
     """
@@ -196,8 +219,9 @@ def moe_ffn(
     # scopes: metadata that names these operations in a device trace
     with jax.named_scope("moe.router"):
         logits = xs.astype(jnp.float32) @ gate_w.astype(jnp.float32)  # [S, E]
-        dispatch, combine, aux = top_k_gating(logits, top_k, capacity,
-                                              key=key)
+        dispatch, combine, aux = top_k_gating(
+            logits, top_k, capacity, key=key, score_fn=score_fn,
+            select_bias=select_bias, normalize_topk=normalize_topk)
     with jax.named_scope("moe.experts"):
         y = moe_expert_compute(xs, dispatch, combine, w_gate, w_up, w_down,
                                ep_axis=ep_axis, activation=activation)

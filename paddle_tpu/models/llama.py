@@ -1269,8 +1269,11 @@ def generate_paged(params, prompt, lengths, cfg: LlamaConfig,
 # decode paths use.
 
 
-def init_serving_pages(cfg, total_pages: int, page_size: int):
-    """Layer-stacked page pools ``[L, Hkv, P, ps, Dh]`` (page 0 = trash)."""
+def init_serving_pages(cfg, total_pages: int, page_size: int,
+                       max_batch: int = 0):
+    """The serving cache pytree: layer-stacked page pools ``[L, Hkv, P,
+    ps, Dh]`` (page 0 = trash). ``max_batch`` (the slots) sizes what a
+    layer kind keeps a slot; a model of pages alone has no use for it."""
     L, Hkv, Dh = (cfg.num_hidden_layers, cfg.num_key_value_heads,
                   cfg.head_dim)
     shape = (L, Hkv, total_pages, page_size, Dh)
@@ -1592,21 +1595,33 @@ def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
     whole-prompt pass would (tests pin greedy equality to
     ``generate()`` in every cache state).
     """
+    *out, cache = serving_tick_cache(
+        params, tokens, meta, {"k_pages": k_pages, "v_pages": v_pages},
+        cfg, tq=tq, decode_tail=decode_tail, spec_k=spec_k,
+        attn_impl=attn_impl, walk=_one_kind_walk(_block_fn))
+    return (*out, cache["k_pages"], cache["v_pages"])
+
+
+def _one_kind_walk(block_fn=None):
+    """The walk of a model whose layers are all ``block_fn`` (None:
+    ``_block``)."""
+    return partial(_walk_one_kind,
+                   block_fn=block_fn if block_fn is not None else _block)
+
+
+def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl, block_fn):
+    """The layer walk of a model whose layers are ONE kind, each holding
+    K and V: ``params['layers']`` scanned, the two stacked pools in the
+    carry. A walk is ``walk(params, h [1, T, D], cache, meta, cfg, tq,
+    attn_impl) -> (h, cache)`` over the model's WHOLE cache pytree;
+    ``serving_tick_cache`` owns everything around it. A model whose
+    layers follow a pattern of kinds brings its own
+    (``models/lfm2_moe.py``)."""
     from ..ops.pallas.ragged_paged_attention import (
         ragged_paged_attention_packed)
-    block_fn = _block_fn if _block_fn is not None else _block
-    tq = int(tq)
-    spec_k = int(spec_k)
-    decode_tail = int(decode_tail)
-    if spec_k and decode_tail:
-        raise ValueError("spec_k and decode_tail are mutually "
-                         "exclusive (speculation replaces the "
-                         "fused greedy tail)")
-    S = meta["q_len"].shape[0]
+    k_pages, v_pages = cache["k_pages"], cache["v_pages"]
     tok_slot = meta["tok_slot"]
     tok_qoff = meta["tok_qoff"]
-    with jax.named_scope("embed"):
-        h = params["embed"].astype(cfg.dtype)[tokens[None]]    # [1, T, D]
     positions = meta["tok_pos"][None]
     heads = jnp.arange(k_pages.shape[1], dtype=jnp.int32)[None, :]  # [1, Hkv]
     tok_page = meta["tok_page"][:, None]                            # [T, 1]
@@ -1655,6 +1670,35 @@ def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
             body, (h, k_pages, v_pages),
             (params["layers"],
              jnp.arange(k_pages.shape[0], dtype=jnp.int32)))
+    return h, {"k_pages": kp_new, "v_pages": vp_new}
+
+
+def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
+                       decode_tail: int = 0, spec_k: int = 0,
+                       attn_impl: str = "auto", walk=None):
+    """``serving_tick`` over a model's whole cache pytree, which is how
+    the engine calls every model: the embedding, the final norm, the
+    head, the fused sampler, the verify pass and the fused decode tail
+    are here, once for every model; the layers are ``walk``'s (None:
+    this model's, see ``_walk_one_kind``; another model's module hands
+    its own). ``cache`` is what the model's
+    ``init_serving_pages`` built — always with ``k_pages`` / ``v_pages``
+    ``[L_attn, Hkv, P, ps, Dh]``, and whatever else its layer kinds keep
+    (a fixed row a slot, ...). Returns ``serving_tick``'s results with
+    the cache pytree last in place of the two pools."""
+    if walk is None:
+        walk = _one_kind_walk()
+    tq = int(tq)
+    spec_k = int(spec_k)
+    decode_tail = int(decode_tail)
+    if spec_k and decode_tail:
+        raise ValueError("spec_k and decode_tail are mutually "
+                         "exclusive (speculation replaces the "
+                         "fused greedy tail)")
+    S = meta["q_len"].shape[0]
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(cfg.dtype)[tokens[None]]    # [1, T, D]
+    h, cache_new = walk(params, h, cache, meta, cfg, tq, attn_impl)
     with jax.named_scope("lm_head"):
         h = rms_norm(h[0], params["final_norm"], cfg.rms_norm_eps)  # [T, D]
     # fused sampling (r16): when the meta carries per-slot sampling
@@ -1719,23 +1763,23 @@ def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
             toks, accept = verify(logits_ver)
         # row 0 == the plain tick's logits for every non-speculating
         # slot (ver_idx[:, 0] = last there)
-        return toks, accept, logits_ver[:, 0], kp_new, vp_new
+        return toks, accept, logits_ver[:, 0], cache_new
     with jax.named_scope("lm_head"):
         h_last = h[meta["last"]]                                # [S, D]
         logits = _mm(h_last, params["lm_head"]).astype(jnp.float32)
     with jax.named_scope("sampler"):
         toks = pick(logits, meta["produced"] if samp else None)
     if not decode_tail:
-        return toks, logits, kp_new, vp_new
+        return toks, logits, cache_new
 
-    ps = k_pages.shape[-2]
+    ps = cache["k_pages"].shape[-2]
     pps = meta["tables"].shape[1]
     b_idx = jnp.arange(S, dtype=jnp.int32)
     zeros = jnp.zeros((S,), jnp.int32)
     live = meta["tail_live"].astype(jnp.bool_)
 
     def step(carry, _):
-        tok, lens, idx, kp, vp = carry
+        tok, lens, idx, cache_t = carry
         slot = lens // ps
         # rows out of pages (retiring overruns), dead all-TRASH rows
         # and tail-dead (mid-prefill) slots land on the trash page,
@@ -1754,18 +1798,18 @@ def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
             m.update(temp=meta["temp"], top_p=meta["top_p"],
                      top_k=meta["top_k"], key=meta["key"],
                      produced=idx)
-        nxt, _, kp, vp = serving_tick(params, tok, m, kp, vp, cfg,
-                                      tq=1, attn_impl=attn_impl,
-                                      _block_fn=_block_fn)
-        return (nxt, lens + 1, idx + 1, kp, vp), nxt
+        nxt, _, cache_t = serving_tick_cache(
+            params, tok, m, cache_t, cfg, tq=1, attn_impl=attn_impl,
+            walk=walk)
+        return (nxt, lens + 1, idx + 1, cache_t), nxt
 
     idx0 = (meta["produced"] + 1) if samp else zeros
-    (_, _, _, kp_new, vp_new), tail = lax.scan(
-        step, (toks, meta["kv_len"], idx0, kp_new, vp_new), None,
+    (_, _, _, cache_new), tail = lax.scan(
+        step, (toks, meta["kv_len"], idx0, cache_new), None,
         length=decode_tail)
     toks = jnp.concatenate([toks[:, None], jnp.moveaxis(tail, 0, 1)],
                            axis=1)                    # [S, 1+tail]
-    return toks, logits, kp_new, vp_new
+    return toks, logits, cache_new
 
 
 def serving_tick_block(params, tok, lengths, tables, k_pages, v_pages,
@@ -1783,9 +1827,23 @@ def serving_tick_block(params, tok, lengths, tables, k_pages, v_pages,
     ``produced + j`` via the fold_in discipline); None keeps the
     all-greedy block. Returns
     ``(toks [S, num_steps] i32, k_pages', v_pages')``."""
+    toks, cache = serving_tick_block_cache(
+        params, tok, lengths, tables,
+        {"k_pages": k_pages, "v_pages": v_pages}, cfg, num_steps,
+        attn_impl=attn_impl, sampling=sampling,
+        walk=_one_kind_walk(_block_fn))
+    return toks, cache["k_pages"], cache["v_pages"]
+
+
+def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
+                             num_steps: int, attn_impl: str = "auto",
+                             sampling=None, walk=None):
+    """``serving_tick_block`` over a model's whole cache pytree and its
+    layer ``walk`` (see ``serving_tick_cache``). Returns ``(toks [S,
+    num_steps] i32, cache')``."""
     S = tok.shape[0]
     pps = tables.shape[1]
-    ps = k_pages.shape[-2]
+    ps = cache["k_pages"].shape[-2]
     b_idx = jnp.arange(S, dtype=jnp.int32)
     slot = lengths // ps
     # rows out of pages (retiring overruns) and dead all-TRASH rows
@@ -1801,13 +1859,12 @@ def serving_tick_block(params, tok, lengths, tables, k_pages, v_pages,
         meta.update(temp=sampling["temp"], top_p=sampling["top_p"],
                     top_k=sampling["top_k"], key=sampling["key"],
                     produced=sampling["produced"])
-    toks, _, kp_new, vp_new = serving_tick(
-        params, tok, meta, k_pages, v_pages, cfg, tq=1,
-        decode_tail=num_steps - 1, attn_impl=attn_impl,
-        _block_fn=_block_fn)
+    toks, _, cache = serving_tick_cache(
+        params, tok, meta, cache, cfg, tq=1, decode_tail=num_steps - 1,
+        attn_impl=attn_impl, walk=walk)
     if num_steps == 1:
         toks = toks[:, None]
-    return toks, kp_new, vp_new
+    return toks, cache
 
 
 def make_batch(cfg: LlamaConfig, batch_size: int, seq_len: int, mesh: Mesh,

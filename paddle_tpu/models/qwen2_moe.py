@@ -402,7 +402,7 @@ def abstract_params(cfg: Qwen2MoeConfig):
 
 
 def init_serving_pages(cfg: Qwen2MoeConfig, total_pages: int,
-                       page_size: int):
+                       page_size: int, max_batch: int = 0):
     from .llama import init_serving_pages as _impl
     return _impl(cfg, total_pages, page_size)
 
@@ -452,3 +452,19 @@ def serving_tick_block(params, tok, lengths, tables, k_pages, v_pages,
     return _impl(params, tok, lengths, tables, k_pages, v_pages, cfg,
                  num_steps, attn_impl=attn_impl, _block_fn=_decode_block,
                  sampling=sampling)
+
+
+def serving_tick_cache(params, tokens, meta, cache, cfg, **kw):
+    """The tick over the cache pytree, as the engine calls it
+    (``models/llama.py serving_tick_cache``), walking this model's
+    block."""
+    from .llama import _one_kind_walk, serving_tick_cache as _impl
+    return _impl(params, tokens, meta, cache, cfg,
+                 walk=_one_kind_walk(_decode_block), **kw)
+
+
+def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
+                             num_steps: int, **kw):
+    from .llama import _one_kind_walk, serving_tick_block_cache as _impl
+    return _impl(params, tok, lengths, tables, cache, cfg, num_steps,
+                 walk=_one_kind_walk(_decode_block), **kw)

@@ -84,6 +84,7 @@ on TPU and the reference elsewhere.
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 import jax
@@ -702,6 +703,62 @@ def _packed_impl(q, k_pages, v_pages, tok_slot, tok_qoff, q_len, kv_len,
     return o.reshape(T, H, Dh).astype(q.dtype)
 
 
+# A pool whose rows are narrower than the chip's 128 lanes cannot be
+# read by the kernel: Mosaic refuses the page DMA ("Slice shape along
+# dimension 4 must be aligned to tiling (128), but is 64", described
+# v5e compile at head size 64). Such a model keeps its pool LANE-PACKED:
+# ``f`` neighbouring KV heads share one row, ``[L, Hkv/f, P, ps, f*Dh]``
+# (the row-major reshape of ``[..., Hkv, Dh]``), and the packed entry
+# below hands the kernel queries widened to the row with zeros on the
+# other heads' lanes. To the kernel that is the 128-wide geometry with
+# ``Hkv/f`` heads of ``f*G`` query rows: no second kernel, no new
+# autotune key, every byte of the pool read once, and the MXU passes a
+# 64-deep contraction would be padded to anyway.
+LANES = 128
+
+
+def lane_pack_factor(head_dim: int, num_kv_heads: int) -> int:
+    """How many KV heads share one row of a lane-packed pool (1: the
+    pool is not packed)."""
+    if head_dim >= LANES or LANES % head_dim:
+        return 1
+    return math.gcd(LANES // head_dim, num_kv_heads)
+
+
+def lane_pack_heads(x, f: int):
+    """``[..., Hkv, Dh] -> [..., Hkv/f, f*Dh]``: a span's K or V rows
+    as a lane-packed pool stores them."""
+    *lead, hkv, dh = x.shape
+    return x.reshape(*lead, hkv // f, f * dh)
+
+
+def _lane_member(q, k_pages):
+    """``[H]``: which of its row's ``f`` KV heads each query head of
+    ``q [T, H, Dh]`` reads (head ``h`` reads KV head ``h // G``, member
+    ``(h // G) % f`` of its row) over a lane-packed pool."""
+    H, Dh = q.shape[1:]
+    f = k_pages.shape[-1] // Dh
+    G = H // (k_pages.shape[-4] * f)
+    return (jnp.arange(H, dtype=jnp.int32) // G) % f, f
+
+
+def _lane_widen(q, member, f):
+    """``[T, H, Dh] -> [T, H, f*Dh]``: each head's values on its
+    member's lanes and zeros elsewhere, so that its scores over the row
+    see its own KV head alone."""
+    T, H, Dh = q.shape
+    lanes = jax.nn.one_hot(member, f, dtype=q.dtype)            # [H, f]
+    return (q[:, :, None, :] * lanes[None, :, :, None]).reshape(T, H, f * Dh)
+
+
+def _lane_narrow(o, member, f):
+    """``[T, H, f*Dh] -> [T, H, Dh]``: its member's lanes of each
+    head's row-wide result."""
+    T, H, wide = o.shape
+    return jnp.take_along_axis(o.reshape(T, H, f, wide // f),
+                               member[None, :, None, None], axis=2)[:, :, 0]
+
+
 def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
                                   q_len, kv_len, tables, tq: int,
                                   sm_scale=None, impl: str = "auto",
@@ -719,12 +776,21 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
     (None = geometry auto — the serving tick passes nothing and a
     100k-token table picks the tiled walk by itself on TPU).
     ``layer`` as in ``ragged_paged_attention``: with it the pools are
-    the stacked ``[L, Hkv, P, page_size, Dh]``.
+    the stacked ``[L, Hkv, P, page_size, Dh]``. Pools with rows wider
+    than ``Dh`` are lane-packed (``lane_pack_factor``).
     """
     if impl not in ("auto", "pallas", "dense", "packed"):
         raise ValueError(
             f"impl must be auto|pallas|dense|packed, got {impl!r}")
     T, H, Dh = q.shape
+    if k_pages.shape[-1] != Dh:
+        member, f = _lane_member(q, k_pages)
+        o = ragged_paged_attention_packed(
+            _lane_widen(q, member, f), k_pages, v_pages, tok_slot, tok_qoff,
+            q_len, kv_len, tables, tq,
+            sm_scale=sm_scale or 1.0 / float(np.sqrt(Dh)), impl=impl,
+            kv_tile_pages=kv_tile_pages, layer=layer)
+        return _lane_narrow(o, member, f)
     S = tables.shape[0]
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(Dh))
@@ -772,6 +838,11 @@ AUDIT_GEOMETRIES = (
     # kernel (KA003 proves its start/wait pairing)
     {"pages_per_slot": 1024, "page_size": 16, "head_dim": 128,
      "dtype": "bfloat16"},
+    # head size 64, 2k-token table: a lane-packed pool, so the launch
+    # under audit is the 128-wide one with half the KV heads and twice
+    # the query rows a head (see ``lane_pack_factor``)
+    {"pages_per_slot": 128, "page_size": 16, "head_dim": 64,
+     "dtype": "bfloat16"},
 )
 
 
@@ -785,6 +856,8 @@ def audit_launches(geom, config=None):
     dh = int(geom["head_dim"])
     dt = jnp.dtype(geom["dtype"])
     S, Hkv, G, Tq = 4, 2, 2, 8
+    f = lane_pack_factor(dh, Hkv)
+    Hkv, G, dh = Hkv // f, G * f, dh * f
     qs = jax.ShapeDtypeStruct((S, Hkv, G * Tq, dh), dt)
     pages = jax.ShapeDtypeStruct((1, Hkv, S * pps, ps, dh), dt)
     layer = np.zeros((1,), np.int32)

@@ -95,10 +95,21 @@ def _resolve_model(model, cfg):
     if "qwen2moe" in name.lower().replace("_", ""):
         from ..models import qwen2_moe
         return qwen2_moe
+    if "lfm2moe" in name.lower().replace("_", ""):
+        from ..models import lfm2_moe
+        return lfm2_moe
     raise ValueError(
         f"cannot infer serving model from {name!r}; pass model='llama', "
-        "'qwen2_moe', or a module exposing init_serving_pages/"
-        "serving_prefill/serving_decode_step")
+        "'qwen2_moe', 'lfm2_moe', or a module exposing "
+        "init_serving_pages/serving_tick_cache/serving_tick_block_cache")
+
+
+def _cache_kinds(mod, cfg) -> tuple:
+    """What the model's layer kinds keep between ticks
+    (``serving_cache_kinds(cfg)``); a model that declares none is of
+    one kind, each layer holding pages of K and V."""
+    kinds = getattr(mod, "serving_cache_kinds", None)
+    return () if kinds is None else tuple(kinds(cfg))
 
 
 from collections import OrderedDict
@@ -145,29 +156,30 @@ def _jit_step_fns(mod, cfg, attn_impl: str, rewrites: bool = False):
     else:
         def _rw(fn):
             return fn
-    # donate the pool arrays: the engine rebinds the returned pools
-    # immediately, and without donation every tick pays a full pool
-    # copy — measured 2-3x the whole step time on the CPU mesh at
+    # the step functions take the model's whole cache pytree (the two
+    # page pools, and whatever else its layer kinds keep) as ONE donated
+    # argument: the engine rebinds the returned cache immediately, and
+    # without donation every tick pays a full pool copy — measured 2-3x the whole step time on the CPU mesh at
     # bench shapes
     # named wrappers, not bare partials: the function's name is the
     # HLO module's (``jit_serving_tick``), which is how a profiler
     # trace tells the tick programs from everything else on the chip
-    def serving_tick(params, tokens, meta, k_pages, v_pages, tq=1,
-                     decode_tail=0, spec_k=0):
-        return mod.serving_tick(params, tokens, meta, k_pages, v_pages,
-                                cfg, tq=tq, decode_tail=decode_tail,
-                                spec_k=spec_k, attn_impl=attn_impl)
+    def serving_tick(params, tokens, meta, cache, tq=1, decode_tail=0,
+                     spec_k=0):
+        return mod.serving_tick_cache(params, tokens, meta, cache, cfg,
+                                      tq=tq, decode_tail=decode_tail,
+                                      spec_k=spec_k, attn_impl=attn_impl)
 
-    def serving_tick_block(params, tok, lengths, tables, k_pages, v_pages,
-                           num_steps, sampling=None):
-        return mod.serving_tick_block(params, tok, lengths, tables,
-                                      k_pages, v_pages, cfg, num_steps,
-                                      attn_impl=attn_impl,
-                                      sampling=sampling)
+    def serving_tick_block(params, tok, lengths, tables, cache, num_steps,
+                           sampling=None):
+        return mod.serving_tick_block_cache(params, tok, lengths, tables,
+                                            cache, cfg, num_steps,
+                                            attn_impl=attn_impl,
+                                            sampling=sampling)
 
-    tick = jax.jit(_rw(serving_tick), donate_argnums=(3, 4),
+    tick = jax.jit(_rw(serving_tick), donate_argnums=(3,),
                    static_argnames=("tq", "decode_tail", "spec_k"))
-    blk = jax.jit(_rw(serving_tick_block), donate_argnums=(4, 5),
+    blk = jax.jit(_rw(serving_tick_block), donate_argnums=(4,),
                   static_argnames=("num_steps",))
     _JIT_CACHE[key] = (cfg, tick, blk)
     if len(_JIT_CACHE) > _JIT_CACHE_MAX:
@@ -387,6 +399,18 @@ class ServingEngine:
         self._params = params
         self._cfg = cfg
         self._mod = _resolve_model(model, cfg)
+        # a layer kind that keeps a fixed row a slot (not pages) holds
+        # state that a prefix's pages cannot rebuild: no snapshots exist
+        # yet, so what attaches, moves or rolls back pages is off
+        kinds = _cache_kinds(self._mod, cfg)
+        self._stateful = [k.name for k in kinds if k.cache == "slot_rows"]
+        if self._stateful:
+            if speculative is not None:
+                raise ValueError(
+                    f"speculative decoding is not available for a model "
+                    f"with per-slot state ({self._stateful} layers): a "
+                    f"rejected draft's state cannot be rolled back")
+            prefix_cache = False    # so: no chains, no cold tier
         self._attn_impl = attn_impl
         self._max_new_cap = int(max_new_tokens_cap)
         self._buckets = sorted(set(int(b) for b in (
@@ -499,8 +523,18 @@ class ServingEngine:
             if recompile_sentinel else None
         self._tick_no = 0
 
-        pools = self._mod.init_serving_pages(cfg, total_pages, page_size)
-        self._kp, self._vp = pools["k_pages"], pools["v_pages"]
+        # the cache pytree the model made: the two page pools, and what
+        # its other layer kinds keep (sized by the slots)
+        self._cache = dict(self._mod.init_serving_pages(
+            cfg, total_pages, page_size, max_batch=max_batch))
+        self._tick_layers = {}
+        if kinds:
+            self._tick_layers = dict(
+                state_layers=len(self._stateful),
+                attn_layers=sum(k.cache == "pages" for k in kinds))
+            self._slot_state_bytes = sum(
+                int(a.nbytes) for name, a in self._cache.items()
+                if name not in ("k_pages", "v_pages"))
         import jax
         self._jnp = jax.numpy
         self._tick_jit, self._block_jit = _jit_step_fns(
@@ -563,6 +597,40 @@ class ServingEngine:
         self._worker = threading.Thread(target=self._loop, daemon=True,
                                         name="serving-engine")
         self._worker.start()
+
+    # ------------------------------------------------------------- cache ----
+    # the two page pools are two leaves of the cache pytree: what moves
+    # pages (defrag, migration, the cold tier) reads and rebinds them
+    def _step(self, fn, *args, **static):
+        """One call of a jitted step function (``_tick_jit`` /
+        ``_block_jit``) over the donated cache; rebinds what it returns
+        and hands back the rest of the results."""
+        *out, self._cache = fn(self._params, *args, self._cache, **static)
+        return out
+
+    def _pull_pages(self, idx):
+        """Pages ``idx`` of every layer's K and V on the host, ``[L, Hkv,
+        n, ps, Dh]`` each (the pools' page axis is 2); caller holds the
+        tick lock."""
+        jnp = self._jnp
+        k = np.asarray(jnp.take(self._cache["k_pages"], idx, axis=2))  # noqa: PT005 — migration export and cold-tier spill are sanctioned one-shot device pulls
+        v = np.asarray(jnp.take(self._cache["v_pages"], idx, axis=2))  # noqa: PT005 — rides the same pull
+        return k, v
+
+    def _write_pages(self, idx, k, v) -> None:
+        """Host pages written back at ``idx`` (caller holds the tick
+        lock)."""
+        jnp = self._jnp
+        for name, rows in (("k_pages", k), ("v_pages", v)):
+            self._cache[name] = self._cache[name].at[:, :, idx].set(
+                jnp.asarray(rows))
+
+    def _refuse_stateful(self, what: str) -> None:
+        if self._stateful:
+            raise RuntimeError(
+                f"{what} is not available for a model with per-slot "
+                f"state ({self._stateful} layers): a chain of pages does "
+                f"not carry the state its prefix left behind")
 
     # --------------------------------------------------------------- API ----
     def submit(self, prompt, max_new_tokens: int, *,
@@ -737,6 +805,8 @@ class ServingEngine:
             g["prefix_cache"] = self.prefix_cache.stats()
         if self._cold is not None:
             g["cold_tier"] = self._cold.stats()
+        if self._tick_layers:
+            g["slot_state_bytes"] = self._slot_state_bytes
         return g
 
     def snapshot(self) -> dict:
@@ -809,6 +879,7 @@ class ServingEngine:
         page moves — and post-defrag ``node.page`` ids are already
         the live ids (``PrefixCache.remap``), so a scattered-then-
         compacted source exports correctly by construction."""
+        self._refuse_stateful("export_chain")
         if self.prefix_cache is None:
             return None
         jnp = self._jnp
@@ -822,8 +893,7 @@ class ServingEngine:
             # gather along the page axis (pools are [L, Hkv, P, ps, Dh]);
             # the pull to host is the POINT: the blob must be plain
             # numpy to pickle across the fleet/proc worker boundary
-            k = np.asarray(jnp.take(self._kp, idx, axis=2))  # noqa: PT005 — migration export is a sanctioned one-shot device pull
-            v = np.asarray(jnp.take(self._vp, idx, axis=2))  # noqa: PT005 — migration export is a sanctioned one-shot device pull
+            k, v = self._pull_pages(idx)
             host_sync("serving.migrate_export")
         return {"fp": int(fp), "page_size": int(self.pool.page_size),
                 "tokens": tokens, "k": k, "v": v}
@@ -841,6 +911,7 @@ class ServingEngine:
         ``{"matched_pages", "adopted_pages"}``; raises ValueError on
         a page-size mismatch and RuntimeError when the pool cannot
         hold the suffix even after eviction."""
+        self._refuse_stateful("adopt_chain")
         if self.prefix_cache is None:
             raise RuntimeError("adopt_chain needs prefix_cache=True")
         if int(blob["page_size"]) != int(self.pool.page_size):
@@ -863,10 +934,8 @@ class ServingEngine:
                     f"{self.pool.free_pages} free after eviction")
             pages = self.pool.alloc(need)
             idx = jnp.asarray(pages, jnp.int32)
-            self._kp = self._kp.at[:, :, idx].set(
-                jnp.asarray(blob["k"][:, :, have:]))
-            self._vp = self._vp.at[:, :, idx].set(
-                jnp.asarray(blob["v"][:, :, have:]))
+            self._write_pages(idx, blob["k"][:, :, have:],
+                              blob["v"][:, :, have:])
             pc.adopt_chain(tokens, pages, start=have)
         return {"matched_pages": have, "adopted_pages": need}
 
@@ -889,6 +958,7 @@ class ServingEngine:
         ``{"xid", "fp", "page_size", "tokens"}`` (no KV bytes yet) —
         or ``None`` when nothing hashes to ``fp``. Pins release at
         :meth:`export_chain_end` (also call it on failure paths)."""
+        self._refuse_stateful("export_chain_begin")
         if self.prefix_cache is None:
             return None
         with self._tick_lock:
@@ -916,8 +986,7 @@ class ServingEngine:
             ent = self._exports[xid]
             nodes = ent["nodes"][start:start + count]
             idx = jnp.asarray([nd.page for nd in nodes], jnp.int32)
-            k = np.asarray(jnp.take(self._kp, idx, axis=2))  # noqa: PT005 — migration export is a sanctioned one-shot device pull
-            v = np.asarray(jnp.take(self._vp, idx, axis=2))  # noqa: PT005 — migration export is a sanctioned one-shot device pull
+            k, v = self._pull_pages(idx)
             host_sync("serving.migrate_export")
         return {"start": int(start), "count": len(nodes), "k": k, "v": v}
 
@@ -942,6 +1011,7 @@ class ServingEngine:
         transfer (not the trie) until :meth:`adopt_chain_commit`;
         :meth:`adopt_chain_abort` frees them. Raises ValueError on a
         page-size mismatch, RuntimeError when the suffix cannot fit."""
+        self._refuse_stateful("adopt_chain_begin")
         if self.prefix_cache is None:
             raise RuntimeError("adopt_chain needs prefix_cache=True")
         if int(header["page_size"]) != int(self.pool.page_size):
@@ -982,8 +1052,7 @@ class ServingEngine:
             off = int(start) - ent["have"]
             count = int(k.shape[2])
             idx = jnp.asarray(ent["pages"][off:off + count], jnp.int32)
-            self._kp = self._kp.at[:, :, idx].set(jnp.asarray(k))
-            self._vp = self._vp.at[:, :, idx].set(jnp.asarray(v))
+            self._write_pages(idx, k, v)
             ent["filled"] += count
 
     def adopt_chain_commit(self, aid: int) -> dict:
@@ -1059,8 +1128,7 @@ class ServingEngine:
         fp = self.prefix_cache.node_fingerprint(nd)
         jnp = self._jnp
         idx = jnp.asarray([nd.page], jnp.int32)
-        k = np.asarray(jnp.take(self._kp, idx, axis=2))  # noqa: PT005 — cold-tier spill is a sanctioned one-shot device pull
-        v = np.asarray(jnp.take(self._vp, idx, axis=2))  # noqa: PT005 — cold-tier spill is a sanctioned one-shot device pull
+        k, v = self._pull_pages(idx)
         host_sync("serving.cold_spill")
         if self._cold.put(fp, nd.toks, k, v):
             self.metrics.inc("cold_spills")
@@ -1120,8 +1188,7 @@ class ServingEngine:
                 idx = jnp.asarray(pages, jnp.int32)
                 k = np.concatenate([e["k"] for e in run], axis=2)
                 v = np.concatenate([e["v"] for e in run], axis=2)
-                self._kp = self._kp.at[:, :, idx].set(jnp.asarray(k))
-                self._vp = self._vp.at[:, :, idx].set(jnp.asarray(v))
+                self._write_pages(idx, k, v)
                 pc.adopt_chain(tuples[:warm + n], pages, start=warm)
                 for i in range(warm, warm + n):
                     self._cold.pop(fps[i])
@@ -1197,11 +1264,11 @@ class ServingEngine:
                 T = S + w
                 out[f"tick@{w}"] = self._tick_jit.lower(
                     self._params, jnp.asarray(np.zeros((T,), np.int32)),
-                    pad_meta(T), self._kp, self._vp, tq=w,
+                    pad_meta(T), self._cache, tq=w,
                     decode_tail=0).as_text(debug_info=debug_info)
             out["block"] = self._block_jit.lower(
                 self._params, jnp.asarray(zs), jnp.asarray(zs),
-                jnp.asarray(tabs), self._kp, self._vp,
+                jnp.asarray(tabs), self._cache,
                 num_steps=self._decode_block,
                 sampling=samp).as_text(debug_info=debug_info)
         return out
@@ -1219,6 +1286,13 @@ class ServingEngine:
         guarantee to cover. Safe any time (serialized against ticks;
         real pages are never read into outputs that matter nor
         written). Returns the number of jit invocations made."""
+        from ..core.stack_anchor import above_stack_anchor
+        # every call in there traces and lowers a program: seconds of
+        # deeply nested Python, whose speed would otherwise depend on
+        # the depth of whoever called us (core/stack_anchor.py)
+        return above_stack_anchor(self._warm_programs)
+
+    def _warm_programs(self) -> int:
         import jax
         jnp = self._jnp
         S = self.scheduler.max_batch
@@ -1248,26 +1322,22 @@ class ServingEngine:
                 T = S + w
                 tok = jnp.asarray(np.zeros((T,), np.int32))
                 if self._spec_k:
-                    _, _, _, self._kp, self._vp = self._tick_jit(
-                        self._params, tok, spec_meta(T), self._kp,
-                        self._vp, tq=w, decode_tail=0,
-                        spec_k=self._spec_k)
+                    self._step(self._tick_jit, tok, spec_meta(T), tq=w,
+                               decode_tail=0, spec_k=self._spec_k)
                     n += 1
                 else:
                     tails = {self._decode_block - 1, 0}
                     for tail in sorted(tails, reverse=True):
-                        _, _, self._kp, self._vp = self._tick_jit(
-                            self._params, tok, pad_meta(T), self._kp,
-                            self._vp, tq=w, decode_tail=tail)
+                        self._step(self._tick_jit, tok, pad_meta(T), tq=w,
+                                   decode_tail=tail)
                         n += 1
             # width S: the fused block — the ONLY pure-decode program
             # since r16 (the single-step sampling tick is gone: its
             # traffic rides the block through the in-graph sampler)
             tok = jnp.asarray(zs)
-            _, self._kp, self._vp = self._block_jit(
-                self._params, tok, jnp.asarray(zs), jnp.asarray(tabs),
-                self._kp, self._vp, num_steps=self._decode_block,
-                sampling=samp)
+            self._step(self._block_jit, tok, jnp.asarray(zs),
+                       jnp.asarray(tabs), num_steps=self._decode_block,
+                       sampling=samp)
             n += 1
         return n
 
@@ -1330,8 +1400,10 @@ class ServingEngine:
                 if bad:
                     raise KVInvariantError(
                         bad, context=self._geometry_desc())
-            self._kp, self._vp, tables = apply_defrag(
-                plan, self._kp, self._vp, self.scheduler.tables)
+            kp, vp, tables = apply_defrag(
+                plan, self._cache["k_pages"], self._cache["v_pages"],
+                self.scheduler.tables)
+            self._cache.update(k_pages=kp, v_pages=vp)
             # np.array (not asarray): the jnp result is a zero-copy
             # READ-ONLY view, and retire()/admit() write tables in place
             self.scheduler.tables = np.array(tables, np.int32)
@@ -1368,7 +1440,8 @@ class ServingEngine:
         self.metrics.inc("tick_rows", rows)
         self.metrics.inc("tick_rows_real", rows_real)
         self.metrics.inc("kv_tokens_attended", kv_tokens)
-        return dict(rows=rows, rows_real=rows_real, kv_tokens=kv_tokens)
+        return dict(rows=rows, rows_real=rows_real, kv_tokens=kv_tokens,
+                    **self._tick_layers)
 
     def _record_tick(self, t0: float, t1: float, live, spans,
                      admitted: int) -> None:
@@ -1555,6 +1628,8 @@ class ServingEngine:
             self.metrics.inc("prefix_pages_saved", len(req.prefix_nodes))
         elif self.prefix_cache is not None:
             self.metrics.inc("prefix_misses")
+        elif self._stateful:
+            self.metrics.inc("prefix_bypassed_stateful")
         req.prefilling = True
         req.chunk_done = 0
         req.table_row = self.scheduler.tables[slot].copy()
@@ -1840,19 +1915,17 @@ class ServingEngine:
                               **counts):
             ph.enter("serving.phase.dispatch", at=t_build)
             if spec:
-                toks_d, accept_d, _logits_d, self._kp, self._vp = \
-                    self._tick_jit(self._params, tok_d, meta,
-                                   self._kp, self._vp, tq=tq,
-                                   decode_tail=0, spec_k=spec)
+                toks_d, accept_d, _logits_d = self._step(
+                    self._tick_jit, tok_d, meta, tq=tq, decode_tail=0,
+                    spec_k=spec)
                 ph.enter("serving.phase.readback")
                 # [S, 1+spec_k] i32 + [S] i32 — the eager pulls
                 toks = np.asarray(toks_d)      # noqa: PT005 - THE sanctioned per-tick verify read-back
                 accept = np.asarray(accept_d)  # noqa: PT005 - rides the same sync
                 host_sync("serving.tick.readback")
             else:
-                toks_d, _logits_d, self._kp, self._vp = self._tick_jit(
-                    self._params, tok_d, meta, self._kp,
-                    self._vp, tq=tq, decode_tail=tail)
+                toks_d, _logits_d = self._step(
+                    self._tick_jit, tok_d, meta, tq=tq, decode_tail=tail)
                 ph.enter("serving.phase.readback")
                 # [S] (tail=0) or [S, 1+tail] i32 — the only eager
                 # pull: sampling happens IN-GRAPH (r16), so no [S, V]
@@ -1946,9 +2019,8 @@ class ServingEngine:
                               tick=self._tick_no, kind="block",
                               live=len(live), steps=k, **counts):
             ph.enter("serving.phase.dispatch", at=t_build)
-            toks, self._kp, self._vp = self._block_jit(
-                self._params, *args, self._kp, self._vp, num_steps=k,
-                sampling=sampling)
+            toks, = self._step(self._block_jit, *args, num_steps=k,
+                               sampling=sampling)
             ph.enter("serving.phase.readback")
             toks = np.asarray(toks)  # noqa: PT005 - sanctioned per-block token read-back ([S, k] i32)
             host_sync("serving.tick.readback")

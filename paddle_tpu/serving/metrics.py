@@ -91,7 +91,9 @@ class ServingMetrics:
     decode_steps, tokens_out), prefix-cache effectiveness (prefix_hits /
     prefix_misses per admission, prefix_hit_tokens — prompt tokens NOT
     recomputed, prefix_pages_saved — pages attached instead of
-    allocated), invariant_violations, recompiles (post-warmup XLA
+    allocated; prefix_bypassed_stateful — admissions of a model whose
+    layers keep per-slot state, for which the prefix cache is not
+    consulted), invariant_violations, recompiles (post-warmup XLA
     compiles the recompile sentinel observed), and speculative
     decoding (spec_ticks — verify launches; draft_tokens /
     draft_accepted / draft_rejected — per-draft-token outcomes:
@@ -153,7 +155,8 @@ class ServingMetrics:
                 "recompiles", "spec_ticks", "draft_tokens",
                 "draft_accepted", "draft_rejected", "handed_back",
                 "cold_hits", "cold_hit_pages", "cold_spills",
-                "tick_rows", "tick_rows_real", "kv_tokens_attended")
+                "tick_rows", "tick_rows_real", "kv_tokens_attended",
+                "prefix_bypassed_stateful")
     HISTOGRAMS = ("queue_wait_s", "ttft_s", "decode_step_s",
                   "decode_stall_s", "batch_occupancy",
                   "page_utilization", "chunk_queue_depth",

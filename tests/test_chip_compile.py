@@ -138,6 +138,45 @@ def test_ragged_layer_indexed_chat_geometry(chip, walk):
                            CHAT["pages"]))
 
 
+# the benchmark's generate cell (benchmark/workloads/
+# lfm2moe-serve-generate.json): head size 64, 64 slots of 128 pages,
+# 2 attention layers, 64-row chunks
+GENERATE = dict(slots=64, pps=128, layers=2, tq=64, heads=32, kv_heads=8,
+                head_dim=64)
+
+
+def test_ragged_head_size_64_enters_lane_packed(chip, monkeypatch):
+    """Head size 64 through the PACKED entry over a lane-packed pool
+    (two KV heads a 128-lane row), stacked, with a layer index, at the
+    generate cell's geometry: the kernel the chip's compiler is handed
+    is the 128-wide one. The same entry over the plain ``[.., 8, P, 16,
+    64]`` pool is what it refuses (a 64-lane page DMA), which is why the
+    pool is packed."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    monkeypatch.setattr(R, "_on_tpu", lambda: True)
+    g = GENERATE
+    S, tq, T = g["slots"], g["tq"], g["slots"] + g["tq"]
+    f = R.lane_pack_factor(g["head_dim"], g["kv_heads"])
+    assert f == 2
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    meta = (i32((T,)), i32((T,)), i32((S,)), i32((S,)), i32((S, g["pps"])))
+
+    def attend(q, kp, vp, layer, *m):
+        return R.ragged_paged_attention_packed(q, kp, vp, *m, tq=tq,
+                                               layer=layer[0])
+
+    def pool(heads, width):
+        return sds((g["layers"], heads, S * g["pps"] + 1, PAGE, width))
+
+    q = sds((T, g["heads"], g["head_dim"]))
+    packed = pool(g["kv_heads"] // f, f * g["head_dim"])
+    text = chip(attend, q, packed, packed, i32((1,)), *meta)
+    assert "bf16[64,4,512,128]" in text.compiled    # 2 x 4 x 64 rows a slot
+    plain = pool(g["kv_heads"], g["head_dim"])
+    with pytest.raises(Exception, match="aligned to tiling"):
+        chip(attend, q, plain, plain, i32((1,)), *meta)
+
+
 def _mistral_tick_shapes(tq, layers, pages):
     """``serving_tick``'s operands at Mistral-7B-v0.3 widths (the
     Llama-3-8B ones above but for the vocabulary) and the chat cell's
