@@ -29,40 +29,38 @@ FLAGSHIP_MODELS = ("llama", "qwen2_moe")
 def ragged_walk_model(*, kv_len: int, page_size: int, head_dim: int,
                       num_kv_heads: int, num_heads: int,
                       num_layers: int, dtype_bytes: int = 2,
-                      kv_tile_pages: int = 0) -> Dict[str, Any]:
+                      kv_tile_pages=None) -> Dict[str, Any]:
     """Analytic flops/bytes of ONE slot's decode-step KV walk through
-    the ragged kernel (ops/pallas/ragged_paged_attention.py), for the
-    one-shot and tiled formulations alike — the model decode_profile's
-    long-context ceiling prices the tiled walk with.
+    the ragged kernel (ops/pallas/ragged_paged_attention.py) — the
+    model decode_profile's long-context ceiling prices the walk with.
 
-    Both walks stream each live page exactly once per (slot, kv-head),
-    so HBM bytes are identical — ``2 · L · ceil(kv_len/ps) · ps · Dh``
-    per kv head — and the tiled walk's only cost deltas are (a) the
-    flash-combine flops (one extra exp/mul pair per score — noise next
-    to the dots) and (b) a second in-flight DMA buffer. What changes
-    is VMEM residency: one-shot pins the whole table's scratch, tiled
-    pins O(tile) (``vmem_scratch_bytes``) — which is the quantity that
-    caps context length on-chip, not bandwidth."""
+    The walk streams each live page exactly once per (slot, kv-head),
+    so HBM bytes are ``2 · L · ceil(kv_len/ps) · ps · Dh`` per kv head;
+    its VMEM residency is O(tile) (``vmem_scratch_bytes``), whatever
+    the table's width — which is why context length is capped by
+    bandwidth, not by on-chip memory. ``kv_tile_pages`` None: the
+    tile the kernel's geometry selection picks."""
     from ..ops.pallas.ragged_paged_attention import (
-        ONE_SHOT_VMEM_BUDGET, vmem_scratch_bytes)
+        default_kv_tile_pages, vmem_scratch_bytes)
     pages = -(-int(kv_len) // int(page_size))
     kv_bytes = (2 * num_layers * num_kv_heads * pages * page_size
                 * head_dim * dtype_bytes)
     # decode q_len=1: scores + weighted sum, 2 dots of [1, Dh] x
     # [Dh/., kv] per head
     flops = 2 * 2 * num_layers * num_heads * int(kv_len) * head_dim
-    one_shot = vmem_scratch_bytes(pages, page_size, head_dim,
-                                  jnp_dtype_of(dtype_bytes))
-    tiled = (vmem_scratch_bytes(pages, page_size, head_dim,
-                                jnp_dtype_of(dtype_bytes),
-                                kv_tile_pages=kv_tile_pages)
-             if kv_tile_pages else None)
+    dtype = jnp_dtype_of(dtype_bytes)
+    if kv_tile_pages is None:
+        kv_tile_pages = default_kv_tile_pages(pages, page_size, head_dim,
+                                              dtype)
     return {
         "kv_len": int(kv_len), "pages": pages,
         "kv_bytes_per_step": kv_bytes, "attn_flops_per_step": flops,
-        "vmem_scratch_bytes_oneshot": one_shot,
-        "oneshot_fits_vmem": one_shot <= ONE_SHOT_VMEM_BUDGET,
-        "vmem_scratch_bytes_tiled": tiled,
+        "kv_tile_pages": int(kv_tile_pages),
+        "kv_tiles": -(-pages // max(int(kv_tile_pages), 1)),
+        "vmem_scratch_bytes": vmem_scratch_bytes(
+            pages, page_size, head_dim, dtype,
+            kv_tile_pages=kv_tile_pages,
+            rows=num_heads // num_kv_heads),
     }
 
 

@@ -1817,9 +1817,13 @@ def serving_tick_block(params, tok, lengths, tables, k_pages, v_pages,
                        _block_fn=None, sampling=None):
     """``num_steps`` fused decode ticks built on the ragged tick (the
     multi-step scheduling lever — same contract as the retired
-    ``serving_decode_block``: greedy slots are in-graph argmax and
-    match single-step decode exactly, dead slots write to and read
-    from the trash page). tok/lengths ``[S]`` i32, tables
+    ``serving_decode_block`` for every slot that holds context:
+    greedy slots are in-graph argmax and match single-step decode
+    exactly). A slot with ``lengths == 0`` (free, or admitted and not
+    yet prefilled) is DEAD to the block: it enters the tick with no
+    query row (``q_len`` 0, the slot sentinel for its token), attends
+    nothing, writes to the trash page, and its returned tokens mean
+    nothing. tok/lengths ``[S]`` i32, tables
     ``[S, pps]``. ``sampling`` (r16): a dict of the fused-sampling
     meta arrays — ``temp``/``top_p`` f32 [S], ``top_k`` i32 [S],
     ``key`` u32 [S, 2], ``produced`` i32 [S] — letting SAMPLING slots
@@ -1846,15 +1850,22 @@ def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
     ps = cache["k_pages"].shape[-2]
     b_idx = jnp.arange(S, dtype=jnp.int32)
     slot = lengths // ps
+    # a slot that holds no context (free, or admitted and not yet
+    # prefilled: the scheduler keeps its length 0) is DEAD to the step,
+    # as a tail-dead slot is to the tail's: no query row, so the ragged
+    # kernel's walk skips it, where a row of its own would walk the
+    # trash page in every layer
+    live = lengths > 0
     # rows out of pages (retiring overruns) and dead all-TRASH rows
     # land on the trash page, exactly like write_token_pages
-    page = jnp.where(slot < pps,
-                     tables[b_idx, jnp.minimum(slot, pps - 1)], 0)
-    meta = dict(tok_slot=b_idx, tok_pos=lengths, tok_page=page,
-                tok_off=lengths % ps, tok_qoff=jnp.zeros((S,), jnp.int32),
-                q_len=jnp.ones((S,), jnp.int32), kv_len=lengths + 1,
-                last=b_idx, tables=tables,
-                tail_live=jnp.ones((S,), jnp.bool_))
+    ok = live & (slot < pps)
+    page = jnp.where(ok, tables[b_idx, jnp.minimum(slot, pps - 1)], 0)
+    meta = dict(tok_slot=jnp.where(live, b_idx, S).astype(jnp.int32),
+                tok_pos=lengths, tok_page=page,
+                tok_off=jnp.where(ok, lengths % ps, 0),
+                tok_qoff=jnp.zeros((S,), jnp.int32),
+                q_len=live.astype(jnp.int32), kv_len=lengths + 1,
+                last=b_idx, tables=tables, tail_live=live)
     if sampling is not None:
         meta.update(temp=sampling["temp"], top_p=sampling["top_p"],
                     top_k=sampling["top_k"], key=sampling["key"],

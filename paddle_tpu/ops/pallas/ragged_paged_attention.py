@@ -31,50 +31,65 @@ Layout contract:
   scalar-prefetch operand). That is what lets the tick's layer scan
   CARRY the pools — one buffer from the program's parameter to its
   result — where slicing a layer out in front of the kernel copied a
-  layer's pages per layer. There is one kernel body per walk: a 4-D
-  pool enters as a one-layer stack read at layer 0. Off the kernel
-  (the dense and packed formulations) the layer is sliced out in
-  front of the same code as ever.
+  layer's pages per layer. There is one kernel body: a 4-D pool
+  enters as a one-layer stack read at layer 0. Off the kernel (the
+  dense and packed formulations) the layer is sliced out in front of
+  the same code as ever.
 * ``kv_len[s]`` counts every key visible at the END of slot ``s``'s
   span (context + the span itself); query row ``t`` attends key
   positions ``0 .. kv_len[s]-q_len[s]+t`` — the bottom-right causal
   mask that makes a chunked prefill bitwise-equal to a whole-prompt
-  one.
+  one. A ``kv_len[s]`` past the table (``pages_per_slot·page_size``:
+  the fused decode tail steps a retiring slot past its last page) is
+  read as the table's width, in the kernel and in both references.
 * ``tables``: ``[S, pages_per_slot]`` int32; entries past the covered
   range may be TRASH (0) — the kernel walks only
   ``ceil(kv_len/page_size)`` entries, so HBM traffic scales with the
   tokens actually cached, not the table width.
 
-Grid ``(S, Hkv)``: each program DMAs its slot's valid pages into VMEM
-scratch (all copies started, then awaited — pages overlap in flight),
-computes the full masked score block ``[G·Tq, KV_max]`` in f32 and a
-ONE-SHOT softmax. The one-shot formulation (not an online-softmax
-accumulator) is deliberate: it makes the kernel bitwise-equal to the
-dense-gather reference below, which is the verification story the
-engine's exactness bar rests on (tests/test_ragged_attention.py). At
-serving shapes ``KV_max = pages_per_slot · page_size`` fits VMEM
-comfortably.
+**One walk, three trip counts read from the tick's data.** Grid
+``(S, Hkv)``; a program's cost follows what its slot holds, not the
+launch's static extents (slots, table width, query rows a slot):
 
-**Tiled flash combine (r16 — the long-context walk).** The one-shot
-scratch is ``O(pages_per_slot · page_size)``, so max context is capped
-by VMEM. Past that knee the kernel switches to a TILED walk (the
-Ragged Paged Attention paper's formulation, arxiv 2604.15464): the
-slot's live pages are walked in fixed ``kv_tile_pages``-sized tiles
-with double-buffered DMA (tile ``t+1``'s copies start while tile ``t``
-computes), carrying running max / denominator / accumulator in f32 —
-VMEM scratch becomes ``O(tile)``, independent of ``pages_per_slot``,
-so a 100k-token page table costs the same on-chip bytes as a 2k one.
-Exactness discipline: the tiled KERNEL is bitwise-equal to the tiled
-dense reference (the same ``_flash_tile`` math at two call sites —
-the one-shot kernel's own pin, replayed), and tiled-vs-one-shot is
-held to a measured ulp-at-row-scale bound (``TILED_ULP_BOUND`` /
-``tiled_ulp_error``, the fused-rmsnorm measured-sweep contract style
-from analysis/rewrite.py) — the flash combine reassociates the
-softmax reductions, so bitwise is off the table by construction, and
-the bound is what the tests enforce across the geometry grid. Selection is by geometry (``default_kv_tile_pages``):
-one-shot stays the bitwise-pinned fast path while its K+V scratch
-fits ``ONE_SHOT_VMEM_BUDGET``; the tiled walk takes over past the
-knee. ``kv_tile_pages=`` overrides (0 forces one-shot).
+* *slots*: a slot with ``q_len == 0`` writes its zeros and does
+  nothing else — no predicate, no copy, no dot;
+* *pages*: the slot's live pages are walked in fixed
+  ``kv_tile_pages``-sized tiles with the flash combine (the Ragged
+  Paged Attention paper's formulation: float32 running max /
+  denominator / accumulator), ``cdiv(kv_len, tile)`` trips, each
+  tile's page copies issued and awaited by loops of the tile's live
+  page count, DOUBLE-BUFFERED (tile ``t+1``'s copies start while tile
+  ``t`` computes). VMEM is ``O(tile)``, independent of
+  ``pages_per_slot``: a 100k-token table costs the on-chip bytes of a
+  2k one, and every live page is read once a (slot, kv head);
+* *query rows*: rows enter ordered (token, group), so a slot's real
+  rows are its first ``G·q_len``; they are walked in blocks of
+  ``ROW_BLOCK`` rows, ``cdiv(G·q_len, ROW_BLOCK)`` trips inside each
+  KV tile, the flash state of every row block kept in VMEM scratch. A
+  decoding slot in a launch that carries a prefill span costs one row
+  block; the score block in VMEM is ``ROW_BLOCK × tile`` whatever
+  ``Tq`` and ``pages_per_slot`` are.
+
+Decode against prefill, short against long, dead against live are
+trip counts of this one loop nest, not paths. A table no wider than
+one tile is walked in one trip: the one-shot walk, by the same code.
+The tile is chosen by geometry alone (``default_kv_tile_pages``, or a
+``kernel_bench --ragged-sweep`` winner in the autotune store);
+``kv_tile_pages=`` overrides.
+
+Exactness discipline: the kernel is BITWISE-equal to its dense twin
+(``impl="dense"`` at the same ``kv_tile_pages``): the same
+``_flash_tile`` math at two call sites, the twin walking every tile
+and row block statically — a tile past ``kv_len`` is an exact no-op
+and a row block past ``G·q_len`` comes out zero, which is what the
+kernel's skipped trips leave. Against the ONE-SHOT dense reference
+(``_attend``: the whole context in one softmax, ``kv_tile_pages=0``
+off the kernel) the walk is held to a measured ulp-at-row-scale bound
+(``TILED_ULP_BOUND`` / ``tiled_ulp_error``, the fused-rmsnorm
+measured-sweep contract style from analysis/rewrite.py): the flash
+combine reassociates the softmax reductions, so bitwise is off the
+table by construction, and the bound is what
+tests/test_ragged_attention.py enforces across the geometry grid.
 
 Off-TPU the kernel runs in interpreter mode (CPU-testable, like the
 int8/flash kernels); ``impl="dense"`` selects the reference gather
@@ -95,24 +110,25 @@ from . import on_tpu as _on_tpu
 
 __all__ = ["ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_paged_attention_packed", "default_kv_tile_pages",
-           "vmem_scratch_bytes", "ONE_SHOT_VMEM_BUDGET",
-           "TILED_ULP_BOUND", "tiled_ulp_error"]
+           "vmem_scratch_bytes", "ROW_BLOCK", "TILED_ULP_BOUND",
+           "tiled_ulp_error"]
 
 _MASK = -1e30  # matches the repo's dense-attention mask value
 
-# K+V VMEM scratch budget of the ONE-SHOT walk: past this the kernel
-# auto-selects the tiled flash combine. 4 MiB leaves headroom for the
-# q/out blocks and the compiler's own allocations inside ~16 MiB/core;
-# at Dh=128/bf16 the knee sits at 8k KV tokens.
-ONE_SHOT_VMEM_BUDGET = 4 * 2 ** 20
-# default tile of the flash walk, in KV TOKENS (converted to pages by
-# default_kv_tile_pages): big enough that the per-tile dot amortizes
-# the DMA turnaround, small enough that double-buffered K+V scratch
-# stays ~512 KiB at Dh=128/bf16. The kernel_bench ragged sweep is the
-# measured A/B over this choice (the first entry of the KForge-style
-# autotune loop, PAPERS.md 2606.02963).
-DEFAULT_TILE_KV_TOKENS = 512
-# tiled-vs-one-shot exactness contract (the fused-rmsnorm measured-
+# bytes of ONE buffer of one pool's tile (the walk holds two buffers of
+# K and two of V): big enough that the tile's dots amortize the DMA
+# turnaround, small enough that the double-buffered K+V scratch stays
+# 512 KiB. At Dh=128/bf16 that is 512 KV tokens a tile. The
+# kernel_bench ragged sweep is the measured A/B over this choice (the
+# first entry of the KForge-style autotune loop, PAPERS.md 2606.02963).
+DEFAULT_TILE_BYTES = 128 * 2 ** 10
+# query rows a block (rows are (token, group)-ordered; a launch with
+# fewer rows a slot has one block of them all): the score block in
+# VMEM is ROW_BLOCK x tile float32 whatever the launch's Tq is
+ROW_BLOCK = 128
+# page copies started a trip of the copy loop (the last trips take one)
+PAGE_UNROLL = 8
+# flash-vs-one-shot exactness contract (the fused-rmsnorm measured-
 # sweep style, analysis/rewrite.py): the flash combine reassociates
 # the softmax sum and rescales the accumulator per tile, so bitwise
 # equality is structurally off the table. A PER-ELEMENT ulp bound is
@@ -149,36 +165,45 @@ def tiled_ulp_error(got, ref) -> float:
                   / (eps * linf)).max())
 
 
+def default_kv_tile_pages(pages_per_slot: int, page_size: int,
+                          head_dim: int, dtype=jnp.bfloat16) -> int:
+    """Geometry selection of the KV walk's tile, in pages: what
+    ``DEFAULT_TILE_BYTES`` holds of this geometry's rows, and never
+    more than the table (a table that fits one tile is walked in one
+    trip). The engine never chooses: ``serving_tick`` passes geometry
+    through and this picks per (pages_per_slot, page_size, Dh,
+    dtype)."""
+    tokens = DEFAULT_TILE_BYTES // (int(head_dim)
+                                    * jnp.dtype(dtype).itemsize)
+    return min(int(pages_per_slot), max(1, tokens // int(page_size)))
+
+
+def _row_block(rows: int) -> int:
+    """Query rows a block for a launch of ``rows`` rows a (slot, kv
+    head): ``ROW_BLOCK``, or all of them where there are fewer."""
+    return min(ROW_BLOCK, int(rows))
+
+
 def vmem_scratch_bytes(pages_per_slot: int, page_size: int,
                        head_dim: int, dtype=jnp.bfloat16,
-                       kv_tile_pages: int = 0) -> int:
-    """K+V VMEM scratch one grid program pins, straight from the
-    kernels' ``scratch_shapes``: the one-shot walk holds the whole
-    table (``2 · pps · ps · Dh``), the tiled walk two double-buffer
-    tiles (``2 · 2 · tile · ps · Dh``) — independent of
-    ``pages_per_slot``, which is the whole point. Shared by the
-    kernel_bench sweep's ``vmem_scratch_bytes`` column and the
-    decode_profile long-context ceiling."""
+                       kv_tile_pages=None, rows: int = 0) -> int:
+    """VMEM scratch one grid program pins, straight from the kernel's
+    ``scratch_shapes``: two double-buffer tiles of K and of V
+    (``2 · 2 · tile · ps · Dh`` — independent of ``pages_per_slot``
+    past one tile, which is the whole point) plus the float32 flash
+    state (running max, denominator, accumulator) of the launch's
+    ``rows`` query rows a (slot, kv head). ``kv_tile_pages`` as the
+    kernel takes it: None the geometry's default, 0 the whole table in
+    one tile. Shared by the kernel_bench sweep, the decode_profile
+    long-context ceiling and the kernel auditor's KA001 pin."""
+    if kv_tile_pages is None:
+        kv_tile_pages = default_kv_tile_pages(pages_per_slot, page_size,
+                                              head_dim, dtype)
+    tile = min(int(kv_tile_pages) or int(pages_per_slot),
+               int(pages_per_slot))
     item = jnp.dtype(dtype).itemsize
-    if kv_tile_pages:
-        return 2 * 2 * int(kv_tile_pages) * page_size * head_dim * item
-    return 2 * int(pages_per_slot) * page_size * head_dim * item
-
-
-def default_kv_tile_pages(pages_per_slot: int, page_size: int,
-                          head_dim: int, dtype=jnp.bfloat16,
-                          budget_bytes: int = ONE_SHOT_VMEM_BUDGET
-                          ) -> int:
-    """Geometry selection of the KV walk: 0 (one-shot — the
-    bitwise-pinned fast path) while the one-shot K+V scratch fits the
-    VMEM budget, else the default flash-combine tile in pages. The
-    engine never chooses: ``serving_tick`` passes geometry through and
-    this picks per (pages_per_slot, page_size, Dh, dtype)."""
-    if vmem_scratch_bytes(pages_per_slot, page_size, head_dim,
-                          dtype) <= budget_bytes:
-        return 0
-    return min(int(pages_per_slot),
-               max(1, DEFAULT_TILE_KV_TOKENS // int(page_size)))
+    return (2 * 2 * tile * page_size * head_dim * item
+            + int(rows) * (head_dim + 2) * 4)
 
 
 def _mxu_dot(a, b, dims):
@@ -197,28 +222,37 @@ def _mxu_dot(a, b, dims):
         preferred_element_type=jnp.float32).astype(a.dtype)
 
 
-def _attend(qs, ks, vs, q_len, kv_len, tq: int):
-    """One (slot, kv-head) attention block — the single source of the
-    math, shared verbatim by the kernel body and the reference (the
-    bitwise-equality pin compares two call sites of THIS function, not
-    two formulations).
+def _row_mask(r0, shape, k0, q_len, kv_len, g: int):
+    """Bottom-right causal mask ``[rows, keys]`` (broadcast from a
+    column of tokens and a row of key positions) of (token, group)-
+    ordered query rows ``r0 ..`` against key positions ``k0 ..``: row
+    ``r`` is token ``t = r // g`` and sees keys
+    ``0 .. (kv_len - q_len) + t``; rows past ``g·q_len`` (span padding)
+    are fully masked."""
+    t = jax.lax.div(
+        r0 + jax.lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0),
+        jnp.int32(g))
+    k_idx = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1)
+    return (t < q_len) & (k_idx <= (kv_len - q_len) + t)
 
-    qs ``[G*Tq, Dh]`` (pre-scaled, rows ordered (g, t)); ks/vs
-    ``[KV_max, Dh]`` — positions >= kv_len may hold garbage (stale
-    kernel scratch / trash-page contents) and are zeroed here so a NaN
-    in dead space can never leak through a 0-weight product.
-    Returns ``[G*Tq, Dh]`` in vs.dtype.
+
+def _attend(qs, ks, vs, q_len, kv_len, g: int):
+    """One (slot, kv-head) attention block in ONE softmax over the
+    whole padded context: the one-shot dense reference the flash walk
+    is held to under ``TILED_ULP_BOUND``.
+
+    qs ``[Tq*G, Dh]`` (pre-scaled, rows ordered (t, g)); ks/vs
+    ``[KV_max, Dh]`` — positions >= kv_len may hold garbage (trash-page
+    contents) and are zeroed here so a NaN in dead space can never
+    leak through a 0-weight product. Returns ``[Tq*G, Dh]`` in
+    vs.dtype.
     """
     kv_max = ks.shape[0]
     kmask = jax.lax.broadcasted_iota(jnp.int32, (kv_max, 1), 0) < kv_len
     ks = jnp.where(kmask, ks, 0)
     vs = jnp.where(kmask, vs, 0)
     s = _mxu_dot(qs, ks, (((1,), (1,)), ((), ()))).astype(jnp.float32)
-    t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % tq
-    k_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # bottom-right causal: row t sees keys 0 .. (kv_len - q_len) + t;
-    # rows past q_len (span padding) are fully masked
-    mask = (t < q_len) & (k_idx <= (kv_len - q_len) + t)
+    mask = _row_mask(0, s.shape, 0, q_len, kv_len, g)
     s = jnp.where(mask, s, _MASK)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -229,32 +263,29 @@ def _attend(qs, ks, vs, q_len, kv_len, tq: int):
     return (o / jnp.where(l > 0, l, 1.0).astype(o.dtype)).astype(vs.dtype)
 
 
-def _flash_tile(qs, ks_t, vs_t, k0, q_len, kv_len, tq: int, m, l, acc):
-    """One TILE of the online-softmax (flash-combine) KV walk — the
-    single source of the tiled math, shared verbatim by the tiled
-    kernel body and the tiled dense reference (the bitwise pin
-    compares two call sites of THIS function, exactly like
-    ``_attend``'s).
+def _flash_tile(qs, ks_t, vs_t, k0, r0, q_len, kv_len, g: int, m, l, acc):
+    """One (row block, KV tile) step of the online-softmax (flash-
+    combine) walk — the single source of the walk's math, shared
+    verbatim by the kernel body and its dense twin (the bitwise pin
+    compares two call sites of THIS function).
 
-    qs ``[G*Tq, Dh]`` pre-scaled; ks_t/vs_t ``[tile_kv, Dh]`` — the
+    qs ``[rows, Dh]`` pre-scaled: the (t, g)-ordered query rows
+    ``r0 .. r0+rows-1`` of the slot; ks_t/vs_t ``[tile_kv, Dh]`` — the
     tile's keys/values, covering global KV positions
     ``k0 .. k0+tile_kv-1`` (positions >= kv_len may hold garbage —
-    stale double-buffer contents, un-DMA'd pages — and are masked /
-    zeroed here exactly as ``_attend`` does for its dead span).
-    m/l ``[G*Tq, 1]`` f32 running max / denominator, acc
-    ``[G*Tq, Dh]`` f32 running accumulator. A tile fully past
+    stale double-buffer contents, un-DMA'd pages: their scores are
+    REPLACED by the mask, a NaN's too, and their values zeroed).
+    m/l ``[rows, 1]`` f32 running max / denominator, acc
+    ``[rows, Dh]`` f32 running accumulator. A tile fully past
     ``kv_len`` is an exact no-op (alpha == 1, p == 0), which is why
-    the reference may walk a static tile count while the kernel walks
-    only live tiles and the two stay bitwise-equal."""
-    gt = qs.shape[0]
+    the twin may walk a static tile count while the kernel walks only
+    live tiles and the two stay bitwise-equal."""
     tile_kv = ks_t.shape[0]
-    k_idx = k0 + jax.lax.broadcasted_iota(jnp.int32, (gt, tile_kv), 1)
     vmask = (k0 + jax.lax.broadcasted_iota(jnp.int32, (tile_kv, 1), 0)
              < kv_len)
     vs_t = jnp.where(vmask, vs_t, 0)
     s = _mxu_dot(qs, ks_t, (((1,), (1,)), ((), ()))).astype(jnp.float32)
-    t = jax.lax.broadcasted_iota(jnp.int32, (gt, tile_kv), 0) % tq
-    mask = (t < q_len) & (k_idx <= (kv_len - q_len) + t)
+    mask = _row_mask(r0, s.shape, k0, q_len, kv_len, g)
     s = jnp.where(mask, s, _MASK)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -267,29 +298,28 @@ def _flash_tile(qs, ks_t, vs_t, k0, q_len, kv_len, tq: int, m, l, acc):
     return m_new, l_new, acc_new
 
 
-def _flash_init(gt: int, dh: int):
-    """Flash-combine carry init: running max starts at the MASK value
+def _flash_init(rows: int, dh: int):
+    """Flash-combine state init: running max starts at the MASK value
     (not -inf — ``exp(_MASK - _MASK)`` must be a defined 1.0 for rows
     that never see a live key, so fully-masked rows emit 0, not NaN —
     the same dead-row contract as ``_attend``)."""
-    return (jnp.full((gt, 1), _MASK, jnp.float32),
-            jnp.zeros((gt, 1), jnp.float32),
-            jnp.zeros((gt, dh), jnp.float32))
+    return (jnp.full((rows, 1), _MASK, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, dh), jnp.float32))
 
 
-def _flash_final(m, l, acc, dtype):
-    del m  # fully-masked rows: l == 0 -> emit 0, not NaN
+def _flash_final(l, acc, dtype):
+    # fully-masked rows: l == 0 -> emit 0, not NaN
     return (acc / jnp.where(l > 0, l, 1.0)).astype(dtype)
 
 
-def _attend_tiled(qs, ks, vs, q_len, kv_len, tq: int, tile_kv: int):
-    """Tiled (flash-combine) counterpart of ``_attend``: the SAME per
-    (slot, kv-head) block, but the KV axis walked in ``tile_kv``-sized
-    tiles through ``_flash_tile``. This is the tiled DENSE REFERENCE —
-    the Pallas tiled kernel is proven bitwise-equal to it, and IT is
-    held to the ulp contract vs ``_attend`` (one-shot). Walks every
-    tile of the padded KV_max statically; tiles past ``kv_len`` are
-    exact no-ops (see ``_flash_tile``)."""
+def _attend_tiled(qs, ks, vs, r0, q_len, kv_len, g: int, tile_kv: int):
+    """One row block of the kernel's DENSE TWIN: query rows
+    ``r0 .. r0+rows-1`` over the KV axis walked in ``tile_kv``-sized
+    tiles through ``_flash_tile``. Walks every tile of the padded
+    KV_max statically; tiles past ``kv_len`` are exact no-ops (see
+    ``_flash_tile``), and a row block past the slot's real rows comes
+    out zero."""
     kv_max, dh = ks.shape
     n_tiles = -(-kv_max // tile_kv)
     pad = n_tiles * tile_kv - kv_max
@@ -303,192 +333,170 @@ def _attend_tiled(qs, ks, vs, q_len, kv_len, tq: int, tile_kv: int):
         k0 = t * tile_kv
         ks_t = jax.lax.dynamic_slice_in_dim(ks, k0, tile_kv)
         vs_t = jax.lax.dynamic_slice_in_dim(vs, k0, tile_kv)
-        return _flash_tile(qs, ks_t, vs_t, k0, q_len, kv_len, tq,
+        return _flash_tile(qs, ks_t, vs_t, k0, r0, q_len, kv_len, g,
                            *carry)
 
-    m, l, acc = jax.lax.fori_loop(0, n_tiles, body,
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, body,
                                   _flash_init(qs.shape[0], dh))
-    return _flash_final(m, l, acc, vs.dtype)
+    return _flash_final(l, acc, vs.dtype)
 
 
 def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
-            o_ref, k_scr, v_scr, sems, *, pps: int, page_size: int,
-            tq: int):
+            o_ref, k_scr, v_scr, m_scr, l_scr, acc_scr, sems, *, pps: int,
+            page_size: int, g: int, tile_pages: int, rb: int):
+    """One (slot, kv head): see the module docstring's loop nest. The
+    K/V scratch is ``(2, tile_pages, page_size, Dh)`` a pool — two
+    buffers of one tile — and the flash state ``(rows, 1 | Dh)``
+    float32, a row block's slice loaded and stored around each
+    ``_flash_tile``."""
     s = pl.program_id(0)
     h = pl.program_id(1)
-    layer = layer_ref[0]
     qn = qlen_ref[s]
-    kn = kvlen_ref[s]
-    n_pages = pl.cdiv(kn, page_size)
-
-    def dma(p, pages_ref, scr, lane):
-        page = tab_ref[s * pps + p]
-        return pltpu.make_async_copy(pages_ref.at[layer, h, page],
-                                     scr.at[p], sems.at[lane, p])
-
-    # start every valid page's K and V copy, then await them — the
-    # copies overlap in flight; a dead slot (qn == 0) moves no bytes
-    for p in range(pps):
-        @pl.when((qn > 0) & (p < n_pages))
-        def _(p=p):
-            dma(p, kp_ref, k_scr, 0).start()
-            dma(p, vp_ref, v_scr, 1).start()
-    for p in range(pps):
-        @pl.when((qn > 0) & (p < n_pages))
-        def _(p=p):
-            dma(p, kp_ref, k_scr, 0).wait()
-            dma(p, vp_ref, v_scr, 1).wait()
-
-    @pl.when(qn > 0)
-    def _():
-        kv_max = pps * page_size
-        dh = k_scr.shape[-1]
-        ks = k_scr[...].reshape(kv_max, dh)
-        vs = v_scr[...].reshape(kv_max, dh)
-        o_ref[...] = _attend(q_ref[...], ks, vs, qn, kn, tq)
 
     @pl.when(qn == 0)
     def _():
-        # dead slot: emit defined zeros (the reference's fully-masked
-        # rows), not stale output-buffer contents — the bitwise pin
-        # covers empty slots too
+        # dead slot: emit defined zeros (the twin's fully-masked rows),
+        # not stale output-buffer contents, and nothing else
         o_ref[...] = jnp.zeros_like(o_ref)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("tq", "g", "interpret"))
-def _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables, tq,
-                 g, interpret):
-    """qs ``[S, Hkv, G*Tq, Dh]`` pre-scaled; returns the same shape.
-    k_pages/v_pages are the STACKED pools ``[L, Hkv, P, ps, Dh]`` and
-    ``layer`` ``[1]`` i32 picks the layer whose pages the DMAs read:
-    the pools stay in HBM (``pl.ANY``) whole, nothing is sliced out."""
-    S, Hkv, GT, Dh = qs.shape
-    pps = tables.shape[1]
-    page_size = k_pages.shape[3]
-    kernel = functools.partial(_kernel, pps=pps, page_size=page_size,
-                               tq=tq)
-    block = pl.BlockSpec((None, None, GT, Dh),
-                         lambda s, h, *_: (s, h, 0, 0))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(S, Hkv),
-            in_specs=[
-                block,
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=block,
-            scratch_shapes=[
-                # the explicitly ONE-SHOT path: scratch deliberately
-                # scales with the table width to keep the bitwise pin;
-                # every other walk must be O(tile) (PT004). The growth
-                # is bounded, not trusted: the kernel auditor's KA001
-                # proves this footprint against the 14 MiB per-core
-                # budget for every registered/swept geometry, and the
-                # autotune gate refuses any winner past it — by the
-                # knee (ONE_SHOT_VMEM_BUDGET) the default walk is
-                # tiled anyway
-                pltpu.VMEM((pps, page_size, Dh), k_pages.dtype),  # noqa: PT004 — one-shot by design, KA001-audited
-                pltpu.VMEM((pps, page_size, Dh), v_pages.dtype),  # noqa: PT004 — one-shot by design, KA001-audited
-                pltpu.SemaphoreType.DMA((2, pps)),
-            ]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        out_shape=jax.ShapeDtypeStruct(qs.shape, k_pages.dtype),
-        interpret=interpret,
-        # a stable name: how the kernel shows in lowered and compiled
-        # program text (chip_smoke.py) and, later, in a device trace
-        name="ragged_paged_attention",
-    )(layer, q_len, kv_len, tables.reshape(-1), qs, k_pages, v_pages)
-
-
-def _tiled_kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref,
-                  vp_ref, o_ref, k_scr, v_scr, sems, *, pps: int,
-                  page_size: int, tq: int, tile_pages: int):
-    """Flash-combine walk: live pages in ``tile_pages``-sized tiles,
-    DOUBLE-BUFFERED — tile ``t+1``'s K/V page copies start while tile
-    ``t`` computes, so past the first tile the DMA hides under the
-    dots. Scratch is ``(2, tile_pages, page_size, Dh)`` per pool —
-    O(tile), independent of ``pps`` — plus the f32 (m, l, acc) carry
-    in registers/VMEM via the fori_loop."""
-    s = pl.program_id(0)
-    h = pl.program_id(1)
-    layer = layer_ref[0]
-    qn = qlen_ref[s]
-    kn = kvlen_ref[s]
-    n_pages = pl.cdiv(kn, page_size)
-    tile_kv = tile_pages * page_size
-    n_tiles = pl.cdiv(kn, tile_kv)
-
-    def tile_dma(t, buf, p, pages_ref, scr, lane):
-        page = tab_ref[s * pps + t * tile_pages + p]
-        return pltpu.make_async_copy(pages_ref.at[layer, h, page],
-                                     scr.at[buf, p],
-                                     sems.at[lane, buf, p])
-
-    def start_tile(t, buf):
-        # static unroll over the tile's page slots; a slot past the
-        # live range moves no bytes (its stale scratch is masked by
-        # kv_len in _flash_tile)
-        for p in range(tile_pages):
-            @pl.when((t * tile_pages + p) < n_pages)
-            def _(p=p):
-                tile_dma(t, buf, p, kp_ref, k_scr, 0).start()
-                tile_dma(t, buf, p, vp_ref, v_scr, 1).start()
-
-    def wait_tile(t, buf):
-        for p in range(tile_pages):
-            @pl.when((t * tile_pages + p) < n_pages)
-            def _(p=p):
-                tile_dma(t, buf, p, kp_ref, k_scr, 0).wait()
-                tile_dma(t, buf, p, vp_ref, v_scr, 1).wait()
 
     @pl.when(qn > 0)
     def _():
+        layer = layer_ref[0]
+        # the table bounds the walk: a kv_len past it (a retiring
+        # slot's overrun in the fused decode tail) is the table's
+        # width, so no tile, page-table entry or key past it is read
+        kn = jnp.minimum(kvlen_ref[s], pps * page_size)
+        n_pages = pl.cdiv(kn, page_size)
+        tile_kv = tile_pages * page_size
+        n_tiles = pl.cdiv(kn, tile_kv)
+        n_blocks = pl.cdiv(g * qn, rb)
         dh = k_scr.shape[-1]
-        qs = q_ref[...]
+
+        def tile_live_pages(t):
+            return jnp.minimum(tile_pages, n_pages - t * tile_pages)
+
+        def start_tile(t, buf):
+            # start the K and V copies of tile t's live pages: loops of
+            # their count, so a slot's copies cost what it holds and a
+            # page past the live range moves no bytes (its stale
+            # scratch is masked by kv_len in _flash_tile). A pool's
+            # copies into one buffer share one semaphore.
+            def start_page(p):
+                page = tab_ref[s * pps + t * tile_pages + p]
+                pltpu.make_async_copy(kp_ref.at[layer, h, page],
+                                      k_scr.at[buf, p],
+                                      sems.at[0, buf]).start()
+                pltpu.make_async_copy(vp_ref.at[layer, h, page],
+                                      v_scr.at[buf, p],
+                                      sems.at[1, buf]).start()
+
+            def chunk(c, carry):
+                for i in range(PAGE_UNROLL):
+                    start_page(c * PAGE_UNROLL + i)
+                return carry
+
+            def rest(p, carry):
+                start_page(p)
+                return carry
+
+            n = tile_live_pages(t)
+            whole = n // PAGE_UNROLL
+            jax.lax.fori_loop(0, whole, chunk, 0)
+            jax.lax.fori_loop(whole * PAGE_UNROLL, n, rest, 0)
+
+        def wait_tile(t, buf):
+            # a DMA semaphore counts bytes, so ONE wait a pool awaits a
+            # whole tile's copies: a descriptor over the whole buffer
+            # (it is only its size: nothing is copied). The slot's last
+            # tile, where it holds fewer pages, awaits them one by one.
+            def wait(scr, lane, *at):
+                dst = scr.at[(buf, *at)]
+                pltpu.make_async_copy(dst, dst, sems.at[lane, buf]).wait()
+
+            n = tile_live_pages(t)
+
+            @pl.when(n == tile_pages)
+            def _():
+                wait(k_scr, 0)
+                wait(v_scr, 1)
+
+            @pl.when(n < tile_pages)
+            def _():
+                def wait_page(p, carry):
+                    wait(k_scr, 0, p)
+                    wait(v_scr, 1, p)
+                    return carry
+
+                jax.lax.fori_loop(0, n, wait_page, 0)
+
+        def rows(b):
+            # a launch of one block (fewer rows than ROW_BLOCK, which
+            # need not sit on the dtype's sublane tiling) reads it at
+            # a static offset
+            if o_ref.shape[0] == rb:
+                return pl.ds(0, rb)
+            return pl.ds(pl.multiple_of(b * rb, rb), rb)
+
+        def init_block(b, carry):
+            r = rows(b)
+            m_scr[r], l_scr[r], acc_scr[r] = _flash_init(rb, dh)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, init_block, 0)
         start_tile(0, 0)
 
-        def body(t, carry):
+        def tile_body(t, carry):
             buf = jax.lax.rem(t, 2)
 
             @pl.when(t + 1 < n_tiles)
             def _():
-                start_tile(t + 1, jax.lax.rem(t + 1, 2))
+                start_tile(t + 1, 1 - buf)
 
             wait_tile(t, buf)
-            ks_t = k_scr[buf].reshape(tile_kv, dh)
-            vs_t = v_scr[buf].reshape(tile_kv, dh)
-            return _flash_tile(qs, ks_t, vs_t, t * tile_kv, qn, kn,
-                               tq, *carry)
 
-        m, l, acc = jax.lax.fori_loop(
-            0, n_tiles, body, _flash_init(qs.shape[0], dh))
-        o_ref[...] = _flash_final(m, l, acc, o_ref.dtype)
+            def block_body(b, carry):
+                r = rows(b)
+                m_scr[r], l_scr[r], acc_scr[r] = _flash_tile(
+                    q_ref[r], k_scr[buf].reshape(tile_kv, dh),
+                    v_scr[buf].reshape(tile_kv, dh), t * tile_kv, b * rb,
+                    qn, kn, g, m_scr[r], l_scr[r], acc_scr[r])
+                return carry
 
-    @pl.when(qn == 0)
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+            return jax.lax.fori_loop(0, n_blocks, block_body, carry)
+
+        jax.lax.fori_loop(0, n_tiles, tile_body, 0)
+
+        def final_block(b, carry):
+            r = rows(b)
+            o_ref[r] = _flash_final(l_scr[r], acc_scr[r], o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, final_block, 0)
+
+        def zero_block(b, carry):
+            o_ref[rows(b)] = jnp.zeros((rb, dh), o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(n_blocks, o_ref.shape[0] // rb, zero_block, 0)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("tq", "g", "tile_pages", "interpret"))
-def _pallas_tiled_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables,
-                       tq, g, tile_pages, interpret):
-    """The tiled walk behind the same slot-major entry contract as
-    ``_pallas_impl`` (stacked pools + layer index); scratch shapes are
-    the whole VMEM story — O(tile), never O(pps)."""
-    S, Hkv, GT, Dh = qs.shape
+                   static_argnames=("g", "tile_pages", "interpret"))
+def _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables, g,
+                 tile_pages, interpret):
+    """qs ``[S, Hkv, R, Dh]`` pre-scaled, rows (t, g)-ordered, ``R`` a
+    multiple of its row block; returns the same shape. k_pages/v_pages
+    are the STACKED pools ``[L, Hkv, P, ps, Dh]`` and ``layer`` ``[1]``
+    i32 picks the layer whose pages the DMAs read: the pools stay in
+    HBM (``pl.ANY``) whole, nothing is sliced out. The scratch shapes
+    are the whole VMEM story — O(tile) and O(rows), never O(pps)."""
+    S, Hkv, R, Dh = qs.shape
     pps = tables.shape[1]
     page_size = k_pages.shape[3]
     tile_pages = min(int(tile_pages), pps)
-    kernel = functools.partial(_tiled_kernel, pps=pps,
-                               page_size=page_size, tq=tq,
-                               tile_pages=tile_pages)
-    block = pl.BlockSpec((None, None, GT, Dh),
+    kernel = functools.partial(_kernel, pps=pps, page_size=page_size, g=g,
+                               tile_pages=tile_pages, rb=_row_block(R))
+    block = pl.BlockSpec((None, None, R, Dh),
                          lambda s, h, *_: (s, h, 0, 0))
     return pl.pallas_call(
         kernel,
@@ -504,44 +512,67 @@ def _pallas_tiled_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables,
             scratch_shapes=[
                 pltpu.VMEM((2, tile_pages, page_size, Dh), k_pages.dtype),
                 pltpu.VMEM((2, tile_pages, page_size, Dh), v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, 2, tile_pages)),
+                pltpu.VMEM((R, 1), jnp.float32),
+                pltpu.VMEM((R, 1), jnp.float32),
+                pltpu.VMEM((R, Dh), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
             ]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         out_shape=jax.ShapeDtypeStruct(qs.shape, k_pages.dtype),
         interpret=interpret,
-        name="ragged_paged_attention_tiled",
+        # a stable name: how the kernel shows in lowered and compiled
+        # program text (chip_smoke.py) and in a device trace (the
+        # benchmark's reducer reads ``^ragged_paged_attention``)
+        name="ragged_paged_attention",
     )(layer, q_len, kv_len, tables.reshape(-1), qs, k_pages, v_pages)
 
 
-def _reference_impl(qs, k_pages, v_pages, q_len, kv_len, tables, tq, g,
+def _reference_impl(qs, k_pages, v_pages, q_len, kv_len, tables, g,
                     tile_pages: int = 0):
     """Dense-gather reference with identical semantics: per slot,
-    gather the table's pages and run the SAME ``_attend`` block per kv
-    head. vmapped over (slot, head) — proven bitwise-equal to the
-    kernel's sequential grid by tests/test_ragged_attention.py.
-    ``tile_pages > 0`` selects the TILED dense reference (the same
-    gather, attended through ``_attend_tiled``'s flash combine) — the
-    off-chip twin of the tiled kernel."""
-    S, Hkv, GT, Dh = qs.shape
+    gather the table's pages and attend per kv head.
+
+    ``tile_pages == 0`` is the ONE-SHOT reference (``_attend``),
+    vmapped over (slot, head). ``tile_pages > 0`` is the kernel's
+    DENSE TWIN: the same gather attended in the kernel's row blocks
+    and KV tiles through ``_attend_tiled`` (the same ``_flash_tile`` as
+    the kernel, every trip taken statically), proven bitwise-equal to
+    the kernel by tests/test_ragged_attention.py. It takes its (slot,
+    head, row block) steps one after another like the kernel's grid,
+    not batched: a batched dot is another XLA program than the
+    kernel's, and at some shapes rounds differently in the last
+    place."""
+    S, Hkv, R, Dh = qs.shape
     pps = tables.shape[1]
     ps = k_pages.shape[2]
-    if tile_pages:
-        tile_kv = min(int(tile_pages), pps) * ps
-        attend = lambda qh, kh, vh, qn, kn: _attend_tiled(  # noqa: E731
-            qh, kh, vh, qn, kn, tq, tile_kv)
-    else:
-        attend = lambda qh, kh, vh, qn, kn: _attend(  # noqa: E731
-            qh, kh, vh, qn, kn, tq)
+    kv_len = jnp.minimum(kv_len, pps * ps)     # the kernel's clamp
 
-    def per_slot(q_s, qn, kn, tab):
-        ks = k_pages[:, tab].reshape(Hkv, pps * ps, Dh)
-        vs = v_pages[:, tab].reshape(Hkv, pps * ps, Dh)
-        return jax.vmap(
-            lambda qh, kh, vh: attend(qh, kh, vh, qn, kn)
-        )(q_s, ks, vs)
+    def gathered(pages, h, tab):
+        return pages[h][tab].reshape(pps * ps, Dh)
 
-    return jax.vmap(per_slot)(qs, q_len, kv_len, tables)
+    if not tile_pages:
+        def per_slot(q_s, qn, kn, tab):
+            return jax.vmap(lambda qh, h: _attend(
+                qh, gathered(k_pages, h, tab), gathered(v_pages, h, tab),
+                qn, kn, g))(q_s, jnp.arange(Hkv))
+
+        return jax.vmap(per_slot)(qs, q_len, kv_len, tables)
+
+    tile_kv = min(int(tile_pages), pps) * ps
+    rb = _row_block(R)
+
+    def step(shb):
+        s, h, b = shb
+        qb = jax.lax.dynamic_slice_in_dim(qs[s, h], b * rb, rb)
+        return _attend_tiled(qb, gathered(k_pages, h, tables[s]),
+                             gathered(v_pages, h, tables[s]), b * rb,
+                             q_len[s], kv_len[s], g, tile_kv)
+
+    steps = jnp.stack(jnp.meshgrid(
+        jnp.arange(S), jnp.arange(Hkv), jnp.arange(R // rb),
+        indexing="ij"), -1).reshape(-1, 3)
+    return jax.lax.map(step, steps).reshape(S, Hkv, R, Dh)
 
 
 def _layer_pages(pages, layer):
@@ -571,15 +602,14 @@ def ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len, tables,
     impl: "auto" (pallas kernel on TPU, dense-gather reference
     elsewhere), "pallas" (strict — interpreter mode off-TPU), "dense".
 
-    kv_tile_pages: the KV walk. None (default) = geometry AUTO on the
-    pallas path — a persistent autotune winner for this geometry if
-    ``kernel_bench --ragged-sweep`` recorded one, else one-shot while
-    its scratch fits the VMEM budget and the tiled flash combine past
-    the knee (``default_kv_tile_pages``; the dense path stays
-    one-shot, it has no VMEM to protect);
-    0 forces one-shot; N > 0 forces the tiled walk at an N-page tile
-    (dense included — the tiled dense reference the kernel's bitwise
-    pin runs against).
+    kv_tile_pages: the KV walk's tile. None (default) = geometry AUTO
+    on the pallas path — a persistent autotune winner for this
+    geometry if ``kernel_bench --ragged-sweep`` recorded one, else
+    ``default_kv_tile_pages`` — and the one-shot reference on the
+    dense path (it has no VMEM to protect); N > 0 = an N-page tile
+    (dense included — the kernel's bitwise twin); 0 = one softmax
+    over the whole context: the one-shot reference on the dense path,
+    the whole table in one tile on the kernel's.
     """
     if impl not in ("auto", "pallas", "dense"):
         raise ValueError(f"impl must be auto|pallas|dense, got {impl!r}")
@@ -593,55 +623,59 @@ def ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len, tables,
     q_len = jnp.asarray(q_len, jnp.int32)
     kv_len = jnp.asarray(kv_len, jnp.int32)
     tables = jnp.asarray(tables, jnp.int32)
-    # [S, Tq, H, Dh] -> [S, Hkv, G*Tq, Dh], rows (g, t)-ordered — the
-    # head axis is kv-head-major (H = Hkv*G), matching the GQA reshape
-    # every other kernel in the repo uses
+    pps = int(tables.shape[1])
+    # [S, Tq, H, Dh] -> [S, Hkv, Tq*G, Dh], rows (t, g)-ordered: a
+    # slot's real rows are its first G*q_len, which is what lets the
+    # walk stop at them — the head axis is kv-head-major (H = Hkv*G),
+    # matching the GQA reshape every other kernel in the repo uses
     qs = (q * sm_scale).astype(q.dtype)
-    qs = qs.reshape(S, Tq, Hkv, G, Dh).transpose(0, 2, 3, 1, 4)
-    qs = qs.reshape(S, Hkv, G * Tq, Dh)
+    qs = qs.reshape(S, Tq, Hkv, G, Dh).transpose(0, 2, 1, 3, 4)
+    qs = qs.reshape(S, Hkv, Tq * G, Dh)
     use_pallas = impl == "pallas" or (impl == "auto" and _on_tpu())
     tile = kv_tile_pages
     if tile is None:
         if use_pallas:
             # KForge flywheel: a ragged-sweep winner recorded for this
-            # geometry overrides the static VMEM-budget selection; an
-            # unswept geometry (or unset store) keeps the default —
-            # either way the same flash-combine math, only retiled.
+            # geometry overrides the static selection; an unswept
+            # geometry (or unset store) keeps the default — either way
+            # the same flash-combine math, only retiled.
             from .. import autotune as at
             win = at.lookup("ragged_paged_attention",
-                            pages_per_slot=int(tables.shape[1]),
+                            pages_per_slot=pps,
                             page_size=int(page_size),
                             head_dim=int(Dh),
                             dtype=str(jnp.dtype(k_pages.dtype)))
             if win is not None and "kv_tile_pages" in win:
                 tile = int(win["kv_tile_pages"])
             else:
-                tile = default_kv_tile_pages(tables.shape[1], page_size,
-                                             Dh, k_pages.dtype)
+                tile = default_kv_tile_pages(pps, page_size, Dh,
+                                             k_pages.dtype)
         else:
             tile = 0
     tile = int(tile)
+    if use_pallas:
+        tile = tile or pps
+    rows = Tq * G
+    if tile:
+        # whole row blocks (the twin walks the kernel's)
+        pad = -rows % _row_block(rows)
+        if pad:
+            qs = jnp.pad(qs, ((0, 0), (0, 0), (0, pad), (0, 0)))
     if use_pallas:
         # one kernel body: a single layer's 4-D pool enters as a
         # one-layer stack (a bitcast) read at layer 0
         if layer is None:
             k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
         layer = jnp.asarray(layer, jnp.int32).reshape(1)
-        if tile:
-            out = _pallas_tiled_impl(qs, k_pages, v_pages, layer, q_len,
-                                     kv_len, tables, tq=Tq, g=G,
-                                     tile_pages=tile,
-                                     interpret=not _on_tpu())
-        else:
-            out = _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len,
-                               tables, tq=Tq, g=G,
-                               interpret=not _on_tpu())
+        out = _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len,
+                           tables, g=G, tile_pages=tile,
+                           interpret=not _on_tpu())
     else:
         out = _reference_impl(qs, _layer_pages(k_pages, layer),
                               _layer_pages(v_pages, layer), q_len, kv_len,
-                              tables, tq=Tq, g=G, tile_pages=tile)
-    out = out.reshape(S, Hkv, G, Tq, Dh).transpose(0, 3, 1, 2, 4)
-    return out.reshape(S, Tq, H, Dh).astype(q.dtype)
+                              tables, g=G, tile_pages=tile)
+    out = out[:, :, :rows].reshape(S, Hkv, Tq, G, Dh)
+    return out.transpose(0, 2, 1, 3, 4).reshape(S, Tq, H, Dh).astype(q.dtype)
 
 
 def ragged_paged_attention_reference(q, k_pages, v_pages, q_len, kv_len,
@@ -820,22 +854,20 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
 # kernel-audit registration (analysis/kernel_audit.py)
 # ---------------------------------------------------------------------------
 # Geometry keys are EXACTLY the autotune lookup kwargs above, so every
-# winners.json entry for this kind audits directly. The one-shot
-# flagship geometry pins the deliberate O(pps) scratch (KA001's number
-# is the waived PT004 lines' justification); the long-context geometry
-# sits past the ONE_SHOT_VMEM_BUDGET knee so the default walk under
-# audit is the tiled double-buffered kernel.
+# winners.json entry for this kind audits directly. The flagship
+# geometry's table fits one tile (the walk's one-trip case); the
+# long-context and head-size-64 geometries walk several, double-
+# buffered. KA001 proves the scratch O(tile) + O(rows), KA003 the
+# start / wait pairing of the page-copy loops.
 
 AUDIT_KIND = "ragged_paged_attention"
 AUDIT_GEOM_KEYS = ("pages_per_slot", "page_size", "head_dim", "dtype")
 AUDIT_CONFIG_KEYS = ("kv_tile_pages",)
 AUDIT_GEOMETRIES = (
-    # serving flagship: 4k-token table, one-shot walk
+    # serving flagship: 256-token table, one tile
     {"pages_per_slot": 16, "page_size": 16, "head_dim": 128,
      "dtype": "bfloat16"},
-    # long context: 16k tokens — 8 MiB one-shot scratch is past the
-    # 4 MiB knee, so the default walk here is the tiled double-buffered
-    # kernel (KA003 proves its start/wait pairing)
+    # long context: 16k tokens in 32 tiles of 512
     {"pages_per_slot": 1024, "page_size": 16, "head_dim": 128,
      "dtype": "bfloat16"},
     # head size 64, 2k-token table: a lane-packed pool, so the launch
@@ -844,6 +876,8 @@ AUDIT_GEOMETRIES = (
     {"pages_per_slot": 128, "page_size": 16, "head_dim": 64,
      "dtype": "bfloat16"},
 )
+# the audited launch: 4 slots, 2 KV heads of 2 query heads, 8-row spans
+AUDIT_SHAPE = dict(slots=4, kv_heads=2, group=2, tq=8)
 
 
 def audit_launches(geom, config=None):
@@ -855,7 +889,8 @@ def audit_launches(geom, config=None):
     ps = int(geom["page_size"])
     dh = int(geom["head_dim"])
     dt = jnp.dtype(geom["dtype"])
-    S, Hkv, G, Tq = 4, 2, 2, 8
+    S, Hkv, G, Tq = (AUDIT_SHAPE[k]
+                     for k in ("slots", "kv_heads", "group", "tq"))
     f = lane_pack_factor(dh, Hkv)
     Hkv, G, dh = Hkv // f, G * f, dh * f
     qs = jax.ShapeDtypeStruct((S, Hkv, G * Tq, dh), dt)
@@ -866,13 +901,10 @@ def audit_launches(geom, config=None):
     tables = np.arange(S * pps, dtype=np.int32).reshape(S, pps)
     args = (qs, pages, pages, layer, q_len, kv_len, tables)
     if config is not None and "kv_tile_pages" in config:
-        tile = int(config["kv_tile_pages"])
+        tile = int(config["kv_tile_pages"]) or pps
     else:
         tile = default_kv_tile_pages(pps, ps, dh, dt)
-    if tile:
-        tile = min(tile, pps)
-        fn = functools.partial(_pallas_tiled_impl, tq=Tq, g=G,
-                               tile_pages=tile, interpret=False)
-        return [(f"tiled[kv_tile_pages={tile}]", fn, args)]
-    fn = functools.partial(_pallas_impl, tq=Tq, g=G, interpret=False)
-    return [("one_shot", fn, args)]
+    tile = min(tile, pps)
+    fn = functools.partial(_pallas_impl, g=G, tile_pages=tile,
+                           interpret=False)
+    return [(f"walk[kv_tile_pages={tile}]", fn, args)]

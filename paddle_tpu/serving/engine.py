@@ -1431,17 +1431,30 @@ class ServingEngine:
             return len(plan)
 
     # ----------------------------------------------------- observability ----
-    def _count_tick(self, rows: int, rows_real: int,
-                    kv_tokens: int) -> dict:
+    def _count_tick(self, rows: int, rows_real: int, kv_tokens: int,
+                    walks) -> dict:
         """What a tick launches against what it needs, counted where
         the tick's arrays are built: into the counters (operators) and,
         returned, into the ``serving.tick`` span's args (the profiler's
-        annotation then carries them for exactly the ticks traced)."""
+        annotation then carries them for exactly the ticks traced).
+        ``walks``: for each attention launch of the tick (the main
+        step, each fused step after it), the host array of the cache
+        tokens its live slots attend. ``kv_pages / kv_pages_table`` is the share of a
+        static walk over slots x table the launches needed."""
+        ps = self.pool.page_size
+        live_slots = sum(len(w) for w in walks)
+        kv_pages = sum(int((-(-w // ps)).sum()) for w in walks)
+        table = (len(walks) * self.scheduler.max_batch
+                 * self.scheduler.pages_per_slot)
         self.metrics.inc("tick_rows", rows)
         self.metrics.inc("tick_rows_real", rows_real)
         self.metrics.inc("kv_tokens_attended", kv_tokens)
+        self.metrics.inc("tick_live_slots", live_slots)
+        self.metrics.inc("kv_pages_walked", kv_pages)
+        self.metrics.inc("kv_pages_table", table)
         return dict(rows=rows, rows_real=rows_real, kv_tokens=kv_tokens,
-                    **self._tick_layers)
+                    live_slots=live_slots, kv_pages=kv_pages,
+                    kv_pages_table=table, **self._tick_layers)
 
     def _record_tick(self, t0: float, t1: float, live, spans,
                      admitted: int) -> None:
@@ -1903,7 +1916,9 @@ class ServingEngine:
             T + S * tail, int(real.sum()) + n_tail * tail,
             int(kv_len[q_len > 0].sum())
             + tail * int(kv_len[tail_live].sum())
-            + n_tail * tail * (tail + 1) // 2)
+            + n_tail * tail * (tail + 1) // 2,
+            [kv_len[q_len > 0]] + [kv_len[tail_live] + j
+                                   for j in range(1, tail + 1)])
         t0 = time.perf_counter()
         m0 = time.monotonic()
         tok_d = jnp.asarray(tok)
@@ -2008,7 +2023,8 @@ class ServingEngine:
         lens = self.scheduler.lengths[[slot for slot, _ in live]]
         counts = self._count_tick(
             self.scheduler.max_batch * k, len(live) * k,
-            k * int(lens.sum()) + len(live) * k * (k + 1) // 2)
+            k * int(lens.sum()) + len(live) * k * (k + 1) // 2,
+            [lens + j for j in range(1, k + 1)])
         t0 = time.perf_counter()
         args = (jnp.asarray(self._cur_tok),
                 jnp.asarray(self.scheduler.lengths),

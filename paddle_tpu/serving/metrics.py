@@ -112,7 +112,12 @@ class ServingMetrics:
     included; tick_rows_real — those that carried a live decoder's,
     a draft's or a prompt span's token: the ratio is the share of a
     launch that was not padding; kv_tokens_attended — cache tokens the
-    real rows attended, the least the attention kernel had to read).
+    real rows attended, the least the attention kernel had to read;
+    tick_live_slots, kv_pages_walked, kv_pages_table — over the ticks'
+    attention launches, the slots that had a query row, the cache
+    pages those slots held, and slots x pages_per_slot: walked / table
+    is the share of a static walk over the page tables that was
+    needed).
     Labeled counters (``inc_labeled``): the same monotonic semantics
     with a small label set — e.g. ``recompiles{during="serving.tick"}``
     names WHAT a post-warmup compile interrupted. Kept separate from
@@ -156,6 +161,7 @@ class ServingMetrics:
                 "draft_accepted", "draft_rejected", "handed_back",
                 "cold_hits", "cold_hit_pages", "cold_spills",
                 "tick_rows", "tick_rows_real", "kv_tokens_attended",
+                "tick_live_slots", "kv_pages_walked", "kv_pages_table",
                 "prefix_bypassed_stateful")
     HISTOGRAMS = ("queue_wait_s", "ttft_s", "decode_step_s",
                   "decode_stall_s", "batch_occupancy",

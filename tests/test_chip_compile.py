@@ -78,22 +78,31 @@ def sds(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _ragged_args(tq, pps, slots=SLOTS, layers=1, pages=None):
-    """The kernels' operands: the STACKED pools and the layer index in
-    front of the geometry (a 4-D pool enters as a one-layer stack)."""
-    pages = sds((layers, HKV, pages or slots * pps + 1, PAGE, DH))
+def _ragged_args(tq, pps, slots=SLOTS, layers=1, pages=None, kv_heads=HKV,
+                 group=G):
+    """The kernel's operands: the STACKED pools and the layer index in
+    front of the geometry (a 4-D pool enters as a one-layer stack);
+    ``group * tq`` (token, group)-ordered query rows a (slot, kv
+    head)."""
+    pages = sds((layers, kv_heads, pages or slots * pps + 1, PAGE, DH))
     i32 = functools.partial(sds, dtype=jnp.int32)
-    return (sds((slots, HKV, G * tq, DH)), pages, pages, i32((1,)),
+    return (sds((slots, kv_heads, group * tq, DH)), pages, pages, i32((1,)),
             i32((slots,)), i32((slots,)), i32((slots, pps)))
+
+
+def _ragged_walk(pps, group=G):
+    """The kernel as a default call launches it at this table width."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    return functools.partial(
+        R._pallas_impl, g=group, interpret=False,
+        tile_pages=R.default_kv_tile_pages(pps, PAGE, DH))
 
 
 # the engine's packed widths in the smoke: the fused block (tq=1), a
 # decode-heavy tick (32) and a full prefill chunk (256)
 @pytest.mark.parametrize("tq", [1, 32, 256])
-def test_ragged_one_shot(chip, tq):
-    from paddle_tpu.ops.pallas import ragged_paged_attention as R
-    fn = functools.partial(R._pallas_impl, tq=tq, g=G, interpret=False)
-    text = chip(fn, *_ragged_args(tq, PPS))
+def test_ragged_smoke_geometry(chip, tq):
+    text = chip(_ragged_walk(PPS), *_ragged_args(tq, PPS))
     # chip_smoke.py finds the kernel by name in the train step's
     # compiled text and in the serving programs' lowered text
     from chip_smoke import kernels_in
@@ -102,79 +111,88 @@ def test_ragged_one_shot(chip, tq):
 
 
 @pytest.mark.parametrize("tq", [1, 256])
-def test_ragged_tiled(chip, tq):
-    """The long-context walk: a 16k-token table is past the one-shot
-    VMEM knee, so this is what ``kv_tile_pages=None`` picks there."""
-    from paddle_tpu.ops.pallas import ragged_paged_attention as R
-    pps = 1024
-    tile = R.default_kv_tile_pages(pps, PAGE, DH)
-    assert tile > 0
-    fn = functools.partial(R._pallas_tiled_impl, tq=tq, g=G,
-                           tile_pages=tile, interpret=False)
-    chip(fn, *_ragged_args(tq, pps))
+def test_ragged_long_context(chip, tq):
+    """A 16k-token table: 32 tiles of the same walk, the same VMEM."""
+    chip(_ragged_walk(1024), *_ragged_args(tq, 1024))
 
 
-# the benchmark's chat cell (benchmark/workloads/mistral7b-serve-chat.json):
-# 32 slots of up to 160 pages over a pool of 3584, 16 layers, 128-row chunks
-CHAT = dict(slots=32, pps=160, pages=3584, layers=16, tq=128)
+# the benchmark's serving cells, what ONE layer's launch looks like
+# there, read from the files the cells run from (tools/kernel_bench.py:
+# ragged_cells; today chat: 32 slots of up to 160 pages over a pool of
+# 3584, 16 layers, 128-row chunks; batch: 16 slots of 88 pages, 16 KV
+# heads of one query head, 256-row chunks; generate: below).
+# ``refused``: twice the cell's chunk, which the one-shot walk this
+# kernel replaced could not hold in VMEM (23 MiB of scoped VMEM at the
+# chat cell's 256 rows, limit 16): the workload files keep their
+# chunks, raising them is a benchmark PR's.
+from tools.kernel_bench import ragged_cells  # noqa: E402
+
+CELLS = ragged_cells()
+CHAT, BATCH_CELL, GENERATE = (CELLS[c] for c in ("chat", "batch", "generate"))
+_CELL_LAUNCHES = [
+    ("chat-decode", CHAT, 1), ("chat-chunk", CHAT, CHAT["span"]),
+    ("chat-refused", CHAT, 2 * CHAT["span"]),
+    ("batch-decode", BATCH_CELL, 1),
+    ("batch-chunk", BATCH_CELL, BATCH_CELL["span"]),
+]
 
 
-@pytest.mark.parametrize("walk", ["one_shot", "tiled"])
-def test_ragged_layer_indexed_chat_geometry(chip, walk):
+@pytest.mark.parametrize("name,cell,tq", _CELL_LAUNCHES,
+                         ids=[c[0] for c in _CELL_LAUNCHES])
+def test_ragged_layer_indexed_cell_geometry(chip, name, cell, tq):
     """What the serving tick launches per layer: the kernel over the
-    stacked pool with the scan's layer index, at the chat cell's
-    geometry, both walks (the tiled one is what a recorded autotune
-    winner or a longer table would select there)."""
+    stacked pool with the scan's layer index, at the chat and batch
+    cells' geometries; no cell's table fits one tile, so none selects a
+    walk whose cost follows ``pages_per_slot``."""
     from paddle_tpu.ops.pallas import ragged_paged_attention as R
-    tq = CHAT["tq"]
-    if walk == "tiled":
-        fn = functools.partial(
-            R._pallas_tiled_impl, tq=tq, g=G, interpret=False,
-            tile_pages=R.DEFAULT_TILE_KV_TOKENS // PAGE)
-    else:
-        assert R.default_kv_tile_pages(CHAT["pps"], PAGE, DH) == 0
-        fn = functools.partial(R._pallas_impl, tq=tq, g=G, interpret=False)
-    chip(fn, *_ragged_args(tq, CHAT["pps"], CHAT["slots"], CHAT["layers"],
-                           CHAT["pages"]))
+    assert (cell["page_size"], cell["head_dim"]) == (PAGE, DH)
+    assert R.default_kv_tile_pages(cell["pps"], PAGE, DH) < cell["pps"]
+    chip(_ragged_walk(cell["pps"], cell["group"]),
+         *_ragged_args(tq, cell["pps"], cell["slots"], cell["layers"],
+                       cell["pages"], cell["kv_heads"], cell["group"]))
 
 
 # the benchmark's generate cell (benchmark/workloads/
 # lfm2moe-serve-generate.json): head size 64, 64 slots of 128 pages,
 # 2 attention layers, 64-row chunks
-GENERATE = dict(slots=64, pps=128, layers=2, tq=64, heads=32, kv_heads=8,
-                head_dim=64)
-
-
-def test_ragged_head_size_64_enters_lane_packed(chip, monkeypatch):
+@pytest.mark.parametrize("tq", [GENERATE["span"], 2 * GENERATE["span"]],
+                         ids=["chunk", "refused"])
+def test_ragged_head_size_64_enters_lane_packed(chip, monkeypatch, tq):
     """Head size 64 through the PACKED entry over a lane-packed pool
     (two KV heads a 128-lane row), stacked, with a layer index, at the
     generate cell's geometry: the kernel the chip's compiler is handed
     is the 128-wide one. The same entry over the plain ``[.., 8, P, 16,
     64]`` pool is what it refuses (a 64-lane page DMA), which is why the
-    pool is packed."""
+    pool is packed. 128 rows a slot (256 a packed KV head) ran out of
+    scoped VMEM under the one-shot walk; the cell's chunk stays 64."""
     from paddle_tpu.ops.pallas import ragged_paged_attention as R
     monkeypatch.setattr(R, "_on_tpu", lambda: True)
-    g = GENERATE
-    S, tq, T = g["slots"], g["tq"], g["slots"] + g["tq"]
-    f = R.lane_pack_factor(g["head_dim"], g["kv_heads"])
+    g, m = GENERATE, GENERATE["model"]
+    S, T = g["slots"], g["slots"] + tq
+    f = R.lane_pack_factor(m["head_dim"], m["kv_heads"])
     assert f == 2
+    assert (g["kv_heads"], g["head_dim"]) == (m["kv_heads"] // f,
+                                              f * m["head_dim"])
     i32 = functools.partial(sds, dtype=jnp.int32)
     meta = (i32((T,)), i32((T,)), i32((S,)), i32((S,)), i32((S, g["pps"])))
 
-    def attend(q, kp, vp, layer, *m):
-        return R.ragged_paged_attention_packed(q, kp, vp, *m, tq=tq,
+    def attend(q, kp, vp, layer, *geom):
+        return R.ragged_paged_attention_packed(q, kp, vp, *geom, tq=tq,
                                                layer=layer[0])
 
     def pool(heads, width):
-        return sds((g["layers"], heads, S * g["pps"] + 1, PAGE, width))
+        return sds((g["layers"], heads, g["pages"], PAGE, width))
 
-    q = sds((T, g["heads"], g["head_dim"]))
-    packed = pool(g["kv_heads"] // f, f * g["head_dim"])
+    q = sds((T, m["heads"], m["head_dim"]))
+    packed = pool(g["kv_heads"], g["head_dim"])
     text = chip(attend, q, packed, packed, i32((1,)), *meta)
-    assert "bf16[64,4,512,128]" in text.compiled    # 2 x 4 x 64 rows a slot
-    plain = pool(g["kv_heads"], g["head_dim"])
-    with pytest.raises(Exception, match="aligned to tiling"):
-        chip(attend, q, plain, plain, i32((1,)), *meta)
+    # 2 x 4 query heads x tq rows a slot and packed KV head
+    assert (f"bf16[{S},{g['kv_heads']},{g['group'] * tq},{g['head_dim']}]"
+            in text.compiled)
+    if tq == g["span"]:
+        plain = pool(m["kv_heads"], m["head_dim"])
+        with pytest.raises(Exception, match="aligned to tiling"):
+            chip(attend, q, plain, plain, i32((1,)), *meta)
 
 
 def _mistral_tick_shapes(tq, layers, pages):

@@ -224,22 +224,25 @@ def test_kernel_signatures_cover_autotuned_kinds():
 
 def test_vmem_scratch_bytes_agrees_with_ka001():
     """The bench column and the auditor's KA001 accounting are the
-    same number, byte for byte, across the sweep grid — one-shot
-    (scratch grows with the table) and tiled (O(tile)) alike."""
+    same number, byte for byte, across the sweep grid: the K+V tiles
+    (the whole table where it fits one tile, O(tile) past that) plus
+    the float32 flash state of the audited launch's query rows."""
     from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
-    grid = [(16, 16, 0), (64, 16, 0), (128, 32, 0),
+    shape = rpa.AUDIT_SHAPE
+    rows = shape["group"] * shape["tq"]
+    grid = [(16, 16, None), (64, 16, 0), (128, 32, None),
             (256, 16, 8), (512, 16, 16), (1024, 16, 32)]
     for pps, ps, tile in grid:
         geom = {"pages_per_slot": pps, "page_size": ps,
                 "head_dim": 128, "dtype": "bfloat16"}
-        for label, fn, args in rpa.audit_launches(
-                geom, {"kv_tile_pages": tile}):
+        config = None if tile is None else {"kv_tile_pages": tile}
+        for label, fn, args in rpa.audit_launches(geom, config):
             _, _, vmem, _ = ka.audit_callable(
                 "ragged_paged_attention", label, fn, args,
                 rules=("KA001",))
             got = sum(row["scratch_bytes"] for row in vmem)
             want = rpa.vmem_scratch_bytes(
-                pps, ps, 128, jnp.bfloat16, kv_tile_pages=tile)
+                pps, ps, 128, jnp.bfloat16, kv_tile_pages=tile, rows=rows)
             assert got == want, (pps, ps, tile, got, want)
 
 

@@ -457,17 +457,28 @@ def test_tick_counts_match_the_hand_computed_run(params, scripted):
     tick 2: 8 rows, 2 real (A decodes, B[2:3]), A attends 7 + B 3;
     tick 3 (block): 4 rows, 2 real, A attends 8 + B 4.
     The span args carry each tick's share; a second run repeats them
-    exactly (they are counts, not times)."""
+    exactly (they are counts, not times). And what a static walk over
+    slots x table would have cost against what the live slots hold
+    (pages of 4 tokens, 8 a slot, one attention launch a tick): tick 0
+    one live slot of 1 page; tick 1 A's 6 tokens (2 pages) + B's 2
+    (1); tick 2 7 (2) + 3 (1); tick 3 8 (2) + 4 (1); a table of
+    4 x 8 = 32 pages each time."""
     eng, _, outs = scripted
     c = eng.metrics.snapshot()["counters"]
     assert (c["tick_rows"], c["tick_rows_real"],
             c["kv_tokens_attended"]) == (28, 12, 34)
-    per_tick = [(s.args["rows"], s.args["rows_real"], s.args["kv_tokens"])
-                for s in eng.tracer.spans() if s.name == "serving.tick"]
+    assert (c["tick_live_slots"], c["kv_pages_walked"],
+            c["kv_pages_table"]) == (7, 10, 128)
+    ticks = [s.args for s in eng.tracer.spans() if s.name == "serving.tick"]
+    per_tick = [(a["rows"], a["rows_real"], a["kv_tokens"]) for a in ticks]
     assert per_tick == [(8, 4, 4), (8, 4, 8), (8, 2, 10), (4, 2, 12)]
+    walked = [(a["live_slots"], a["kv_pages"], a["kv_pages_table"])
+              for a in ticks]
+    assert walked == [(1, 1, 32), (2, 3, 32), (2, 3, 32), (2, 3, 32)]
     eng2, _, outs2 = _scripted_run(params, trace=False)
     c2 = eng2.metrics.snapshot()["counters"]
     for k in ("tick_rows", "tick_rows_real", "kv_tokens_attended",
+              "tick_live_slots", "kv_pages_walked", "kv_pages_table",
               "decode_steps", "tokens_out"):
         assert c2[k] == c[k]
     # a disabled ring changes nothing served and records nothing

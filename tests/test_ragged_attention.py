@@ -56,32 +56,37 @@ def _ref(params, prompt, n):
 
 
 # ---------------------------------------------------------------------------
-# kernel vs dense-gather reference: bitwise on seeded ragged batches
+# kernel vs its dense twin: bitwise on seeded ragged batches; vs the
+# one-shot reference: the ulp-at-row-scale contract
 # ---------------------------------------------------------------------------
 
 def _ragged_case(seed, S=4, Tq=6, H=4, Hkv=2, Dh=8, ps=4, P=24, pps=5,
-                 scatter_tables=False, layers=None):
+                 scatter_tables=False, layers=None, q_len=None,
+                 kv_len=None):
     """One seeded ragged batch: mixed prefill spans (q_len>1), decode
     steps (q_len=1), an empty slot (q_len=0), partial tail pages
     (kv_len % page_size != 0), TRASH entries past the covered range.
     ``layers`` makes the pools the serving tick's STACKED ones,
-    ``[layers, Hkv, P, ps, Dh]``, every layer its own values."""
+    ``[layers, Hkv, P, ps, Dh]``, every layer its own values;
+    ``q_len`` / ``kv_len`` replace the drawn lengths."""
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(S, Tq, H, Dh).astype(np.float32))
     pool = (Hkv, P, ps, Dh) if layers is None else (layers, Hkv, P, ps, Dh)
     kp = jnp.asarray(rng.randn(*pool).astype(np.float32))
     vp = jnp.asarray(rng.randn(*pool).astype(np.float32))
     kv_max = pps * ps
-    q_len = np.zeros((S,), np.int32)
-    kv_len = np.zeros((S,), np.int32)
+    ql = np.zeros((S,), np.int32)
+    kl = np.zeros((S,), np.int32)
     for s in range(S):
         kind = s % 3          # 0: prefill span, 1: decode, 2: empty
         if kind == 0:
-            q_len[s] = rng.randint(2, Tq + 1)
-            kv_len[s] = rng.randint(q_len[s], kv_max + 1)
+            ql[s] = rng.randint(2, Tq + 1)
+            kl[s] = rng.randint(ql[s], kv_max + 1)
         elif kind == 1:
-            q_len[s] = 1
-            kv_len[s] = rng.randint(1, kv_max + 1)
+            ql[s] = 1
+            kl[s] = rng.randint(1, kv_max + 1)
+    if q_len is not None:
+        ql, kl = np.asarray(q_len, np.int32), np.asarray(kv_len, np.int32)
     if scatter_tables:
         # post-defrag shape: page ids scattered anywhere in the pool,
         # non-monotone per row (defrag remaps rows entry-by-entry)
@@ -90,57 +95,171 @@ def _ragged_case(seed, S=4, Tq=6, H=4, Hkv=2, Dh=8, ps=4, P=24, pps=5,
         ids = np.arange(1, S * pps + 1)
     tables = ids.reshape(S, pps).astype(np.int32)
     for s in range(S):
-        covered = -(-int(kv_len[s]) // ps)
+        covered = -(-int(kl[s]) // ps)
         tables[s, covered:] = 0              # TRASH past the span
-    return (q, kp, vp, jnp.asarray(q_len), jnp.asarray(kv_len),
+    return (q, kp, vp, jnp.asarray(ql), jnp.asarray(kl),
             jnp.asarray(tables))
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_kernel_matches_reference_bitwise(seed):
-    """Pallas kernel (interpret off-TPU) vs dense-gather reference:
-    BITWISE on mixed prefill+decode batches with empty slots and
-    partial tail pages."""
-    case = _ragged_case(seed)
-    out_k = ragged_paged_attention(*case, impl="pallas")
-    out_r = ragged_paged_attention(*case, impl="dense")
+from paddle_tpu.ops.pallas.ragged_paged_attention import (  # noqa: E402
+    ROW_BLOCK, TILED_ULP_BOUND, default_kv_tile_pages, vmem_scratch_bytes)
+
+
+def _twin_and_contract(case, tile, **kw):
+    """The kernel (interpret off-TPU) at ``tile`` against its dense
+    twin, BITWISE, and against the one-shot reference under the
+    ulp-at-row-scale contract. ``tile`` None: the walk a default call
+    selects."""
+    out_k = ragged_paged_attention(*case, impl="pallas",
+                                   kv_tile_pages=tile, **kw)
+    if tile is None:
+        pps, ps, dh = case[5].shape[1], case[1].shape[-2], case[1].shape[-1]
+        tile = default_kv_tile_pages(pps, ps, dh, case[1].dtype)
+    out_r = ragged_paged_attention(*case, impl="dense", kv_tile_pages=tile,
+                                   **kw)
     assert out_k.dtype == out_r.dtype
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
+    one = ragged_paged_attention(*case, impl="dense", **kw)
+    err = tiled_ulp_error(out_k, one)
+    assert err <= TILED_ULP_BOUND, err
+    return np.asarray(out_k)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("tile", [None, 1, 3, 5])
+def test_kernel_matches_twin_bitwise(seed, tile):
+    """Pallas kernel (double-buffered page-copy loops, interpret
+    off-TPU) vs its dense twin — the same ``_flash_tile`` at two call
+    sites: BITWISE on mixed prefill+decode batches with empty slots
+    and partial tail pages. tile=3 does not divide pps=5 (ragged last
+    tile); tile=5 is the whole table in one tile, which is also what
+    the default selects at this width."""
+    _twin_and_contract(_ragged_case(seed), tile)
 
 
 LAYERS = 3
 
 
 @pytest.mark.parametrize("layer", range(LAYERS))
-@pytest.mark.parametrize("tile", [0, 3], ids=["one_shot", "tiled"])
+@pytest.mark.parametrize("tile", [0, 3], ids=["one_tile", "tiled"])
 def test_layer_indexed_kernel_matches_layer_slice_bitwise(tile, layer):
     """The serving tick's way in: the kernel handed the STACKED pools
     and a layer index (its DMAs read ``pages[layer, h, page]``) against
-    the kernel, and the dense reference, handed that layer's pages
-    sliced out: BITWISE, both walks, every layer; the index may be a
-    traced value (the layer scan's)."""
+    the kernel, and its dense twin, handed that layer's pages sliced
+    out: BITWISE, one tile and several, every layer; the index may be
+    a traced value (the layer scan's)."""
     q, kps, vps, *geom = _ragged_case(layer, layers=LAYERS,
                                       scatter_tables=True)
-    run = functools.partial(ragged_paged_attention, kv_tile_pages=tile)
-    got = jax.jit(lambda l: run(q, kps, vps, *geom, impl="pallas",
-                                layer=l))(jnp.int32(layer))
-    for impl in ("pallas", "dense"):
-        want = run(q, kps[layer], vps[layer], *geom, impl=impl)
+    twin = tile or geom[2].shape[1]
+    got = jax.jit(lambda l: ragged_paged_attention(
+        q, kps, vps, *geom, impl="pallas", kv_tile_pages=tile,
+        layer=l))(jnp.int32(layer))
+    for impl, t in (("pallas", tile), ("dense", twin)):
+        want = ragged_paged_attention(q, kps[layer], vps[layer], *geom,
+                                      impl=impl, kv_tile_pages=t)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # off the TPU the layer is sliced out in front of the same code
     np.testing.assert_array_equal(
-        np.asarray(run(q, kps, vps, *geom, impl="dense", layer=layer)),
+        np.asarray(ragged_paged_attention(q, kps, vps, *geom, impl="dense",
+                                          kv_tile_pages=twin, layer=layer)),
         np.asarray(got))
+
+
+# the walk's three trip counts, one case each way: (name, case kwargs,
+# tile). Row blocks are ROW_BLOCK rows, so the launches that exercise
+# them carry that many rows a slot and more.
+_WIDE = dict(S=3, Tq=ROW_BLOCK, H=4, Hkv=2, pps=36, P=110)  # G=2: 2 blocks
+_WALKS = [
+    # slots: dead ones between live ones, first and last
+    ("dead_between_live", dict(S=5, q_len=[0, 3, 0, 1, 0],
+                               kv_len=[0, 9, 0, 20, 0]), 2),
+    # query rows: a decoding slot inside a launch of ROW_BLOCK-row spans
+    # costs one block; the span beside it all of them
+    ("decode_in_span_launch", dict(_WIDE, q_len=[1, ROW_BLOCK, 0],
+                                   kv_len=[17, ROW_BLOCK + 5, 0]), 3),
+    # G x q_len one row past a block's edge, G = 3 (rows padded to
+    # whole blocks; token = row // 3)
+    ("rows_off_block_edge", dict(S=2, Tq=50, H=6, Hkv=2, pps=14, P=30,
+                                 q_len=[43, 1], kv_len=[50, 3]), 4),
+    ("rows_at_block_edge", dict(_WIDE, q_len=[ROW_BLOCK // 2, 2, 1],
+                                kv_len=[ROW_BLOCK // 2, 31, 32]), 8),
+    # pages: nothing, one key, a tile's edge and one past it, the table
+    ("kv_len_0_1", dict(q_len=[1, 1, 0, 1], kv_len=[0, 1, 0, 2]), 2),
+    ("kv_len_at_tile_edge", dict(q_len=[1, 2, 6, 1],
+                                 kv_len=[8, 9, 16, 7]), 2),
+    ("kv_len_full_table", dict(q_len=[6, 1, 1, 0],
+                               kv_len=[20, 20, 19, 0]), 2),
+    # a retiring slot's overrun (the fused decode tail steps past a
+    # retirement): kv_len past the table, on the LAST slot, is read as
+    # the table's width: no tile, page or key past it (tile 2 does not
+    # divide the 5 pages: the last tile's sixth page stays masked)
+    ("kv_len_past_table_last_slot", dict(q_len=[1, 2, 0, 1],
+                                         kv_len=[5, 9, 0, 23]), 2),
+    ("kv_len_past_table_one_tile", dict(q_len=[1, 0, 1, 1],
+                                        kv_len=[21, 0, 20, 21]), 5),
+    ("scattered_pages", dict(scatter_tables=True), 2),
+    ("scattered_pages_tile_1", dict(scatter_tables=True), 1),
+    ("stacked_pool", dict(layers=2, scatter_tables=True), 3),
+]
+
+
+@pytest.mark.parametrize("name,kw,tile", _WALKS,
+                         ids=[w[0] for w in _WALKS])
+def test_walk_trip_counts_follow_the_data(name, kw, tile):
+    """Every way the kernel's loops can run short or long against the
+    twin, which takes every trip: dead slots, one row block of many,
+    rows off a block's edge, no key / one key / a tile's edge / the
+    whole table, scattered page lists, the stacked pool. Bitwise, and
+    the contract against the one-shot reference; dead slots and rows
+    past ``q_len`` come out zero."""
+    case = _ragged_case(len(name), **kw)
+    layer = {"layer": 1} if kw.get("layers") else {}
+    out = _twin_and_contract(case, tile, **layer)
+    q_len = np.asarray(case[3])
+    for s, n in enumerate(q_len):
+        assert not out[s, n:].any(), (name, s)
+
+
+def test_lane_packed_head_size_64_matches_the_plain_pool():
+    """Head size 64 enters the kernel lane-packed, two KV heads a
+    128-lane row and the queries widened with zeros: the kernel over
+    the packed pool against its twin (bitwise) and against the dense
+    one-shot reference over the PLAIN pool (the contract's bound at
+    float32's eps)."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    rng = np.random.RandomState(3)
+    T, H, Hkv, Dh, P, ps, S = 9, 8, 4, 64, 7, 4, 3
+    f = R.lane_pack_factor(Dh, Hkv)
+    assert f == 2
+    q = jnp.asarray(rng.randn(T, H, Dh).astype(np.float32))
+    k = jnp.asarray(rng.randn(2, Hkv, P, ps, Dh).astype(np.float32))
+    v = jnp.asarray(rng.randn(2, Hkv, P, ps, Dh).astype(np.float32))
+
+    def packed(x):      # [L, Hkv, P, ps, Dh] -> [L, Hkv/f, P, ps, f*Dh]
+        return R.lane_pack_heads(x.transpose(0, 2, 3, 1, 4), f).transpose(
+            0, 3, 1, 2, 4)
+
+    meta = (jnp.asarray([0, 0, 0, 0, 1, 2, 2, S, S], jnp.int32),
+            jnp.asarray([0, 1, 2, 3, 0, 0, 1, 0, 0], jnp.int32),
+            jnp.asarray([4, 1, 2], jnp.int32),
+            jnp.asarray([9, 5, 2], jnp.int32),
+            jnp.asarray([[1, 2, 3], [4, 5, 0], [6, 0, 0]], jnp.int32))
+    run = functools.partial(ragged_paged_attention_packed, tq=4, layer=1)
+    got = run(q, packed(k), packed(v), *meta, impl="pallas",
+              kv_tile_pages=2)
+    twin = run(q, packed(k), packed(v), *meta, impl="dense",
+               kv_tile_pages=2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(twin))
+    want = run(q, k, v, *meta, impl="dense")
+    assert tiled_ulp_error(got, want) <= TILED_ULP_BOUND
+    assert not np.asarray(got)[7:].any()        # padding tokens: zero
 
 
 def test_kernel_matches_reference_post_defrag_page_lists():
     """Scattered, non-monotone page tables (the shape defrag remaps
     produce) change nothing: the kernel walks the table, not an
     arithmetic page layout."""
-    case = _ragged_case(7, scatter_tables=True)
-    out_k = ragged_paged_attention(*case, impl="pallas")
-    out_r = ragged_paged_attention(*case, impl="dense")
-    np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
+    _twin_and_contract(_ragged_case(7, scatter_tables=True), None)
 
 
 def test_empty_batch_and_full_pages():
@@ -153,11 +272,7 @@ def test_empty_batch_and_full_pages():
     assert not np.asarray(out).any()
     q_len = jnp.asarray([4, 1, 2, 1], jnp.int32)
     kv_len = jnp.asarray([8, 4, 20, 12], jnp.int32)   # all % ps == 0
-    a = ragged_paged_attention(q, kp, vp, q_len, kv_len, tables,
-                               impl="pallas")
-    b = ragged_paged_attention(q, kp, vp, q_len, kv_len, tables,
-                               impl="dense")
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _twin_and_contract((q, kp, vp, q_len, kv_len, tables), None)
 
 
 def test_packed_matches_slot_major():
@@ -238,40 +353,10 @@ def test_bottom_right_causal_prefill_equals_whole():
                                   np.asarray(part)[0, : n - split])
 
 
-# ---------------------------------------------------------------------------
-# tiled flash-combine walk (r16): bitwise vs the tiled reference,
-# ulp-at-row-scale contract vs the one-shot kernel, O(tile) scratch
-# ---------------------------------------------------------------------------
-
-from paddle_tpu.ops.pallas.ragged_paged_attention import (  # noqa: E402
-    ONE_SHOT_VMEM_BUDGET, TILED_ULP_BOUND, default_kv_tile_pages,
-    vmem_scratch_bytes)
-
-
-@pytest.mark.parametrize("seed", [0, 1, 2])
-@pytest.mark.parametrize("tile", [1, 3, 5])
-def test_tiled_kernel_matches_tiled_reference_bitwise(seed, tile):
-    """The tiled Pallas kernel (double-buffered DMA walk, interpret
-    off-TPU) is BITWISE-equal to the tiled dense reference — the same
-    ``_flash_tile`` math at two call sites, the one-shot kernel's own
-    verification story replayed. tile=3 does not divide pps=5 (ragged
-    last tile); tile=5 is the whole table in one tile."""
-    case = _ragged_case(seed)
-    out_k = ragged_paged_attention(*case, impl="pallas",
-                                   kv_tile_pages=tile)
-    out_r = ragged_paged_attention(*case, impl="dense",
-                                   kv_tile_pages=tile)
-    assert out_k.dtype == out_r.dtype
-    np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
-
-
 def test_tiled_kernel_post_defrag_and_degenerate_slots():
     """Scattered page tables, kv_len=0 (dead slot -> exact zeros),
-    kv_len=1 and single-page slots through the tiled walk."""
-    case = _ragged_case(7, scatter_tables=True)
-    a = ragged_paged_attention(*case, impl="pallas", kv_tile_pages=2)
-    b = ragged_paged_attention(*case, impl="dense", kv_tile_pages=2)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    kv_len=1 and single-page slots through a two-page tile."""
+    _twin_and_contract(_ragged_case(7, scatter_tables=True), 2)
     q, kp, vp, _, _, tables = _ragged_case(3)
     zeros = jnp.zeros((4,), jnp.int32)
     out = ragged_paged_attention(q, kp, vp, zeros, zeros, tables,
@@ -280,17 +365,13 @@ def test_tiled_kernel_post_defrag_and_degenerate_slots():
     # kv_len 1 and single-page (kv_len <= page_size) slots
     q_len = jnp.asarray([1, 1, 1, 1], jnp.int32)
     kv_len = jnp.asarray([1, 4, 2, 3], jnp.int32)
-    a = ragged_paged_attention(q, kp, vp, q_len, kv_len, tables,
-                               impl="pallas", kv_tile_pages=2)
-    b = ragged_paged_attention(q, kp, vp, q_len, kv_len, tables,
-                               impl="dense", kv_tile_pages=2)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _twin_and_contract((q, kp, vp, q_len, kv_len, tables), 2)
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 5])
 @pytest.mark.parametrize("pps,ps", [(5, 4), (32, 8)])
 def test_tiled_vs_oneshot_ulp_contract(seed, pps, ps):
-    """The tiled walk's exactness contract vs the one-shot kernel
+    """The walk's exactness contract vs the one-shot reference
     (TILED_ULP_BOUND — ulp measured at the slot's output scale; a raw
     per-element ulp bound cannot survive the flash combine's
     reassociation at cancellation-small components, see the kernel
@@ -306,32 +387,30 @@ def test_tiled_vs_oneshot_ulp_contract(seed, pps, ps):
         assert err <= TILED_ULP_BOUND, (seed, pps, ps, tile, err)
 
 
-def test_tiled_scratch_independent_of_table_width():
+def test_scratch_independent_of_table_width_and_rows_of_the_launch():
     """The acceptance property in numbers, straight from the scratch
-    shapes: one-shot K+V scratch grows with pages_per_slot; the tiled
-    walk's does not — a 100k-token table pins the same VMEM as a 2k
-    one — and the geometry auto-selection flips to tiled exactly at
-    the budget knee."""
+    shapes: past one tile the K+V scratch does not grow with
+    pages_per_slot — a 100k-token table pins the same VMEM as a 2k one
+    — a table under one tile pins its own width, the flash state grows
+    with the launch's query rows alone, and no serving geometry of the
+    benchmark selects a tile as wide as its table."""
     ps, dh = 16, 128
-    tiles = [vmem_scratch_bytes(pps, ps, dh, jnp.bfloat16,
-                                kv_tile_pages=32)
-             for pps in (128, 512, 6250)]
-    assert len(set(tiles)) == 1
-    shots = [vmem_scratch_bytes(pps, ps, dh, jnp.bfloat16)
-             for pps in (128, 512, 6250)]
-    assert shots == sorted(shots) and shots[0] < shots[-1]
-    # knee: <= budget -> one-shot (0); past it -> a tile
-    assert default_kv_tile_pages(128, ps, dh, jnp.bfloat16) == 0
-    big = default_kv_tile_pages(6250, ps, dh, jnp.bfloat16)
-    assert big > 0
-    assert vmem_scratch_bytes(6250, ps, dh, jnp.bfloat16,
-                              kv_tile_pages=big) \
-        <= ONE_SHOT_VMEM_BUDGET
-    # the knee itself sits at the budget boundary
-    knee_pps = ONE_SHOT_VMEM_BUDGET // (2 * ps * dh * 2)
-    assert default_kv_tile_pages(knee_pps, ps, dh, jnp.bfloat16) == 0
-    assert default_kv_tile_pages(knee_pps + 1, ps, dh,
-                                 jnp.bfloat16) > 0
+    walks = [vmem_scratch_bytes(pps, ps, dh, jnp.bfloat16)
+             for pps in (88, 128, 160, 512, 6250)]
+    assert len(set(walks)) == 1 and walks[0] == 512 * 2 ** 10
+    assert default_kv_tile_pages(6250, ps, dh, jnp.bfloat16) == 32
+    for pps in (88, 128, 160):          # batch, generate, chat
+        assert default_kv_tile_pages(pps, ps, dh, jnp.bfloat16) < pps
+    # a table under one tile is one trip over its own width
+    assert default_kv_tile_pages(16, ps, dh, jnp.bfloat16) == 16
+    assert vmem_scratch_bytes(16, ps, dh, jnp.bfloat16) == walks[0] // 2
+    assert vmem_scratch_bytes(16, ps, dh, jnp.bfloat16,
+                              kv_tile_pages=0) == walks[0] // 2
+    # the tile is bytes, not tokens: float32 rows halve its pages
+    assert default_kv_tile_pages(6250, ps, dh, jnp.float32) == 16
+    # flash state: (max, denominator, accumulator) a query row
+    assert (vmem_scratch_bytes(160, ps, dh, rows=512) - walks[0]
+            == 512 * (dh + 2) * 4)
 
 
 # ---------------------------------------------------------------------------
@@ -344,6 +423,51 @@ def _engine(params, **kw):
     kw.setdefault("max_prompt_len", 16)
     kw.setdefault("max_new_tokens_cap", 16)
     return ServingEngine(params, CFG, **kw)
+
+
+@pytest.mark.parametrize("attn_impl", ["auto", "pallas"])
+def test_block_tick_free_slots_are_dead_and_live_ones_decode(
+        params, monkeypatch, attn_impl):
+    """The fused decode block with FREE slots (length 0) between live
+    ones: the free slots enter the tick dead (``q_len == lengths > 0``,
+    slot sentinel, ``tail_live`` false: the kernel's walk skips them)
+    and every live slot's tokens equal single-step greedy decode,
+    through the packed formulation and through the kernel."""
+    S, ps, pps, K = 5, 4, 6, 3
+    rng = np.random.RandomState(4)
+    cache = L.init_serving_pages(CFG, 1 + S * pps, ps)
+    kp, vp = cache["k_pages"], cache["v_pages"]
+    tables = np.zeros((S, pps), np.int32)
+    lengths = np.zeros((S,), np.int32)
+    tok = np.zeros((S,), np.int32)
+    for s, n in ((1, 7), (3, 4)):          # slots 0, 2 and 4 stay free
+        tables[s] = 1 + s * pps + np.arange(pps)
+        prompt = np.zeros((1, 8), np.int32)
+        prompt[0, :n] = rng.randint(0, CFG.vocab_size, (n,))
+        logits, kp, vp = L.serving_prefill(
+            params, jnp.asarray(prompt), jnp.int32(n),
+            jnp.asarray(tables[s]), kp, vp, CFG)
+        lengths[s], tok[s] = n, int(jnp.argmax(logits))
+    args = (params, jnp.asarray(tok), jnp.asarray(lengths),
+            jnp.asarray(tables), kp, vp, CFG, K)
+    want, _, _ = L.serving_decode_block(*args)
+    seen = {}
+    tick = L.serving_tick_cache
+
+    def spy(params, tokens, meta, *a, **kw):
+        if not seen:            # the block's own call, not the tail's
+            seen.update(meta)
+        return tick(params, tokens, meta, *a, **kw)
+
+    monkeypatch.setattr(L, "serving_tick_cache", spy)
+    got, _, _ = L.serving_tick_block(*args, attn_impl=attn_impl)
+    live = lengths > 0
+    np.testing.assert_array_equal(np.asarray(got)[live],
+                                  np.asarray(want)[live])
+    np.testing.assert_array_equal(np.asarray(seen["q_len"]), live)
+    np.testing.assert_array_equal(np.asarray(seen["tail_live"]), live)
+    np.testing.assert_array_equal(np.asarray(seen["tok_slot"]),
+                                  np.where(live, np.arange(S), S))
 
 
 def test_engine_matches_generate_cold_warm_partial(params):
@@ -569,11 +693,9 @@ def test_100k_token_page_table_serves_end_to_end(params):
     kl = jnp.full((1,), 100_000, jnp.int32)
     tabs = jnp.asarray(1 + np.arange(pps, dtype=np.int32)[None])
     tile = default_kv_tile_pages(pps, ps, Dh, jnp.float32)
-    assert tile > 0                              # past the VMEM knee
-    assert vmem_scratch_bytes(pps, ps, Dh, jnp.float32,
-                              kv_tile_pages=tile) == \
-        vmem_scratch_bytes(128, ps, Dh, jnp.float32,
-                           kv_tile_pages=tile)
+    assert 0 < tile < pps                        # O(tile), not O(table)
+    assert vmem_scratch_bytes(pps, ps, Dh, jnp.float32) == \
+        vmem_scratch_bytes(2 * tile, ps, Dh, jnp.float32)
     one = np.asarray(ragged_paged_attention(
         q, kp, vp, ql, kl, tabs, impl="dense", kv_tile_pages=0))
     tiled = np.asarray(ragged_paged_attention(

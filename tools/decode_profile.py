@@ -173,48 +173,37 @@ def speculative_ceiling(ceiling_tok_s, ks=(1, 2, 3, 4, 6, 8),
 def long_context_ceiling(cfg, bw, weight_bytes,
                          kv_lens=(4096, 16384, 65536, 102400),
                          page_size=16):
-    """The r16 long-context extension of the same bandwidth ceiling:
-    price the decode step at context lengths the ONE-SHOT ragged
-    kernel cannot even hold — its K+V VMEM scratch grows with the
-    page table, so past the knee only the TILED flash-combine walk
-    runs on-chip. Both walks stream each live page exactly once per
-    (slot, kv-head) (analysis/serving_graphs.ragged_walk_model), so
-    the bytes term — and therefore the tok/s ceiling — is the same;
-    what the table shows is the ceiling the tiled walk UNLOCKS
-    (oneshot_fits_vmem goes False) and the O(tile) scratch it pays
-    for it. The measured counterpart is the kernel_bench
-    ``--ragged-sweep`` A/B on the chip."""
+    """The long-context extension of the same bandwidth ceiling: price
+    the decode step at long contexts. The ragged kernel walks a slot's
+    live pages in tiles, each page once per (slot, kv-head)
+    (analysis/serving_graphs.ragged_walk_model), with O(tile) VMEM
+    scratch whatever the table's width: the table shows the ceiling
+    the bytes alone set, the tiles a step walks and the scratch it
+    pins. The measured counterpart is the kernel_bench
+    ``--ragged-sweep`` on the chip."""
     from paddle_tpu.analysis.serving_graphs import ragged_walk_model
-    from paddle_tpu.ops.pallas.ragged_paged_attention import (
-        default_kv_tile_pages)
     dtype_bytes = jnp.dtype(cfg.dtype).itemsize
     rows = {}
     for n in kv_lens:
-        pages = -(-int(n) // page_size)
-        tile = default_kv_tile_pages(pages, page_size, cfg.head_dim,
-                                     cfg.dtype)
         m = ragged_walk_model(
             kv_len=n, page_size=page_size, head_dim=cfg.head_dim,
             num_kv_heads=cfg.num_key_value_heads,
             num_heads=cfg.num_attention_heads,
             num_layers=cfg.num_hidden_layers,
-            dtype_bytes=dtype_bytes, kv_tile_pages=tile)
+            dtype_bytes=dtype_bytes)
         total = weight_bytes + m["kv_bytes_per_step"]
         rows[f"kv={n}"] = {
             "kv_bytes_per_step": m["kv_bytes_per_step"],
             "bw_ceiling_tok_per_s": round(bw / total, 1),
-            "oneshot_fits_vmem": m["oneshot_fits_vmem"],
-            "vmem_scratch_bytes_oneshot":
-                m["vmem_scratch_bytes_oneshot"],
-            "kv_tile_pages": tile,
-            "vmem_scratch_bytes_tiled": m["vmem_scratch_bytes_tiled"],
-            "walk": "tiled" if tile else "oneshot",
+            "kv_tile_pages": m["kv_tile_pages"],
+            "kv_tiles": m["kv_tiles"],
+            "vmem_scratch_bytes": m["vmem_scratch_bytes"],
         }
     return {"page_size": page_size,
-            "model": "ceiling = bw / (weight_bytes + kv_bytes); both "
-                     "walks stream each live page once, so the tiled "
-                     "walk changes VMEM residency (O(tile) scratch), "
-                     "not the bytes term — it UNLOCKS the long rows",
+            "model": "ceiling = bw / (weight_bytes + kv_bytes); the "
+                     "walk streams each live page once and pins "
+                     "O(tile) VMEM scratch, so the bytes term alone "
+                     "caps long contexts",
             "table": rows}
 
 
@@ -549,8 +538,7 @@ def main():
         out[tag]["spec_ceiling"] = speculative_ceiling(
             ceiling, draft_cost=spec_draft_cost)
         # the long-context extension (r16): the ceiling at 4k..100k
-        # context, with the VMEM story — which rows only the tiled
-        # flash-combine walk can serve on-chip
+        # context, with the walk's tiles and its O(tile) VMEM scratch
         out[tag]["long_context_ceiling"] = long_context_ceiling(
             cfg, bw, wbytes)
     if "fp" in out and "int8" in out:
@@ -593,15 +581,13 @@ def main():
               + " | ".join(f"{row[a]['tok_s']:.0f}" for a in alphas))
     lc = out[variants[0][0]]["long_context_ceiling"]
     print(f"\n# long-context ceiling ({variants[0][0]}, page_size "
-          f"{lc['page_size']}): the rows the tiled KV walk unlocks")
-    print("kv_len | ceiling tok/s | one-shot fits VMEM | walk | "
-          "scratch bytes")
+          f"{lc['page_size']}): what the bytes allow, and the walk")
+    print("kv_len | ceiling tok/s | tile pages | tiles | scratch bytes")
     for krow, row in lc["table"].items():
         print(f"{krow.split('=')[1]:>6s} | "
               f"{row['bw_ceiling_tok_per_s']:13.1f} | "
-              f"{str(row['oneshot_fits_vmem']):>18s} | "
-              f"{row['walk']:6s} | "
-              f"{row['vmem_scratch_bytes_tiled'] or row['vmem_scratch_bytes_oneshot']:>12,}")
+              f"{row['kv_tile_pages']:>10d} | {row['kv_tiles']:>5d} | "
+              f"{row['vmem_scratch_bytes']:>12,}")
     if "ragged_step_ab" in out:
         ab = out["ragged_step_ab"]
         print(f"\n# ragged tick A/B (serving decode step, "

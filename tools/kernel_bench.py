@@ -8,20 +8,21 @@ dispatch cost that can swamp a sub-millisecond kernel, device time is
 what the hardware actually spends. The kernel table in docs/PERF.md is
 from a machine that is gone; nothing is re-measured yet (PERF.md).
 
-``--ragged-sweep`` (r16) runs the tiled-vs-one-shot ragged
-paged-attention A/B instead: a sweep over (pages_per_slot, page_size,
-kv_tile_pages) geometries, ONE JSON LINE PER CONFIG on stdout (and
-``--out=path`` as JSONL), each carrying a ``vmem_scratch_bytes``
-column computed from the kernels' actual scratch shapes — the
-evidence that tiled scratch is O(tile) while one-shot scratch grows
-with the table. Per geometry the fastest variant is then recorded
-through ``ops.autotune`` (key ``("ragged_kv_walk", ...)``) — the
-first entry of the KForge-style autotune loop (PAPERS.md
-2606.02963): block shapes searched against the bench harness, cache
-picks the winner per geometry. On TPU it times device events; off
-TPU it still runs end-to-end in interpreter mode (wall-clock,
-``timing_honest: false`` — the smoke path; the overdue on-chip round,
-ROADMAP item 3, reruns it unmodified for real numbers).
+``--ragged-sweep`` times the ragged paged-attention kernel ALONE at
+the serving cells' geometries (``ragged_cells()``, read from the
+benchmark's own files): one JSON line for each (cell, decode or span
+tick, share of the slots live, share of the table live,
+``kv_tile_pages``) on stdout (and ``--out=path`` as JSONL), so one
+reads whether the kernel's time follows the data or the launch's
+static extents. ``--cells=chat,batch`` picks cells,
+``--tiles=auto,16,64`` the tiles, ``--label=`` names the walk in the
+rows. Where several tiles are given and ``$PADDLE_TPU_AUTOTUNE_DIR``
+is set, the fastest is recorded through ``ops.autotune`` — the first
+entry of the KForge-style autotune loop (PAPERS.md 2606.02963): block
+shapes searched against the bench harness, cache picks the winner per
+geometry. On TPU it reads the kernel's device events from a profiler
+trace; off TPU it still runs end-to-end in interpreter mode at a tiny
+size (wall-clock, ``timing_honest: false`` — the smoke path).
 
 ``--block-sweep`` (r23) is the flywheel's write side for the other
 swept kernels: per geometry it times every candidate block shape for
@@ -191,117 +192,202 @@ def _walltime(f, args, n=3):
     return best * 1e3
 
 
-def ragged_tiling_sweep(out=None, iters=3):
-    """Tiled-vs-one-shot ragged paged-attention A/B (module
-    docstring). Returns the list of per-config result dicts."""
+def ragged_cells():
+    """What ONE layer's launch of the ragged kernel looks like in each
+    serving cell of the benchmark, keyed by the cell's traffic name
+    (chat, batch, generate), READ from the files the cell runs from:
+    ``BENCHMARK.json``, the cell's workload file (slots, page size,
+    the table's width as the engine sizes it, the pool, ``span``: the
+    prefill chunk, the query rows a slot of a span tick gets) and its
+    configuration with the workload's overrides (heads, head size, how
+    many layers attend). ``kv_heads`` / ``group`` / ``head_dim`` are
+    what the KERNEL sees: a head size under the chip's 128 lanes is
+    served from a lane-packed pool (the generate cell: 4 KV heads of 8
+    query heads at width 128); ``model`` keeps the configuration's own
+    three. The one copy of these numbers: the sweep below and
+    tests/test_chip_compile.py both read it."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import lane_pack_factor
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def read(*path):
+        with open(os.path.join(root, *path)) as f:
+            return json.load(f)
+
+    bench = read("BENCHMARK.json")
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    cells = {}
+    for w in bench["workloads"]:
+        work = read("benchmark", "workloads", w["name"] + ".json")
+        if not work.get("mode", "").startswith("serve"):
+            continue
+        model = {**read(files[w["config"]]), **work.get("overrides", {})}
+        eng = work["engine"]
+        heads, kv = model["num_attention_heads"], model["num_key_value_heads"]
+        dh = model.get("head_dim") or model["hidden_size"] // heads
+        kinds = model.get("layer_types")
+        f = lane_pack_factor(dh, kv)
+        ps, slots = eng["page_size"], eng["max_batch"]
+        longest = max(eng.get("prompt_buckets") or [eng["max_prompt_len"]])
+        pps = -(-(longest + eng["max_new_tokens_cap"] - 1) // ps)
+        cells[w["traffic"]] = dict(
+            slots=slots, kv_heads=kv // f, group=heads // kv * f,
+            head_dim=dh * f, page_size=ps, pps=pps,
+            pages=eng.get("total_pages") or slots * pps + 1,
+            span=eng["prefill_chunk"],
+            layers=(kinds.count("full_attention") if kinds
+                    else model["num_hidden_layers"]),
+            model=dict(heads=heads, kv_heads=kv, head_dim=dh))
+    return cells
+
+
+# off the chip: the same shape of sweep at a size interpret mode can run
+RAGGED_CELLS_TINY = {
+    "tiny": dict(slots=4, kv_heads=2, group=2, head_dim=8, page_size=4,
+                 pps=8, pages=33, span=4),
+}
+
+
+def _kernel_ms(trace_dir, prefix="ragged_paged_attention"):
+    """Device time, in ms, of every event of the traced device's
+    operation line whose name starts with ``prefix``, in the order
+    they ran."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    evs = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/device:TPU:0"):
+            continue
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                evs += [(ev.start_ns, ev.duration_ns) for ev in line.events
+                        if ev.name.split(" = ", 1)[0].lstrip("%")
+                        .startswith(prefix)]
+    return [d / 1e6 for _, d in sorted(evs)]
+
+
+def ragged_sweep(out=None, iters=5, cells=None, tiles=(None,), label=""):
+    """The ragged kernel alone at the serving cells' geometries
+    (``ragged_cells()``), through the slot-major entry over a stacked
+    pool with a layer index, as the serving tick launches it: one row
+    for each (cell, tick kind, share of the slots live, share of the
+    table live, ``kv_tile_pages``). A ``decode`` tick gives every live
+    slot one query row; a ``span`` tick gives the launch the cell's
+    prefill chunk of rows a slot, one live slot a whole chunk and the
+    others one token. Only the public entry is called, so the same
+    file times another checkout's kernel (copy it into that tree):
+    ``label`` names the walk in the rows. On the chip a row's ``ms`` is
+    the kernel's own device time from a profiler trace; off it the
+    interpreter's wall clock (``timing_honest: false``). Where several
+    ``tiles`` are given and ``$PADDLE_TPU_AUTOTUNE_DIR`` is set, the
+    tile with the least summed time is recorded for the cell's
+    geometry (audit-gated), and a resolution row says what a default
+    call then resolves to."""
+    import tempfile
     from paddle_tpu.ops import autotune as at
-    from paddle_tpu.ops.pallas.ragged_paged_attention import (
-        ragged_paged_attention, vmem_scratch_bytes)
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
     on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        dt = jnp.bfloat16
-        S, H, Hkv, Dh = 8, 32, 8, 128
-        # pps x page_size spans the knee: 2k tokens (one-shot
-        # territory) to 100k (tiled-only)
-        geoms = [(128, 16), (512, 16), (2048, 16), (6250, 16)]
-        tiles = (0, 8, 16, 32, 64)
-    else:
-        dt = jnp.float32
-        S, H, Hkv, Dh = 2, 4, 2, 8
-        geoms = [(8, 4), (32, 4)]
-        tiles = (0, 2, 4, 8)
-    rng = np.random.RandomState(0)
+    table = ragged_cells() if on_tpu else RAGGED_CELLS_TINY
+    dt = jnp.bfloat16 if on_tpu else jnp.float32
+    layers = 2
     results = []
-    for pps, ps in geoms:
-        P = S * pps + 1
-        kv_len = pps * ps
-        q = jnp.asarray(rng.randn(S, 1, H, Dh), dt)       # decode spans
-        kp = jnp.asarray(rng.randn(Hkv, P, ps, Dh), dt)
-        vp = jnp.asarray(rng.randn(Hkv, P, ps, Dh), dt)
-        ql = jnp.ones((S,), jnp.int32)
-        kl = jnp.full((S,), kv_len, jnp.int32)
-        tabs = jnp.asarray(
-            1 + np.arange(S * pps, dtype=np.int32).reshape(S, pps))
-        args = (q, kp, vp, ql, kl, tabs)
-
-        def make(tile):
-            return jax.jit(functools.partial(
-                ragged_paged_attention, impl="pallas",
-                kv_tile_pages=tile))
-
+    for cell in cells or table:
+        c = table[cell]
+        S, Hkv, G, Dh = c["slots"], c["kv_heads"], c["group"], c["head_dim"]
+        ps, pps, P = c["page_size"], c["pps"], c["pages"]
+        rng = np.random.RandomState(0)
+        kk, kv_ = jax.random.split(jax.random.PRNGKey(0))
+        kp = jax.random.normal(kk, (layers, Hkv, P, ps, Dh), dt)
+        vp = jax.random.normal(kv_, (layers, Hkv, P, ps, Dh), dt)
+        # scattered page lists, as a pool in service has them
+        tabs = jnp.asarray(1 + rng.randint(0, P - 1, (S, pps)), jnp.int32)
         ageom = dict(pages_per_slot=pps, page_size=ps, head_dim=Dh,
                      dtype=str(jnp.dtype(dt)))
-        cands, rows = [], []
+        runs = []                       # (row, jitted fn, args)
         for tile in tiles:
-            if tile > pps:
-                continue
-            scratch = vmem_scratch_bytes(pps, ps, Dh, dt,
-                                         kv_tile_pages=tile)
-            row = {
-                "bench": "ragged_kv_walk", "pps": pps, "page_size": ps,
-                "kv_len": kv_len, "slots": S, "heads": H,
-                "kv_heads": Hkv, "head_dim": Dh, "dtype": str(jnp.dtype(dt)),
-                "kv_tile_pages": tile,
-                "walk": "tiled" if tile else "oneshot",
-                "vmem_scratch_bytes": scratch,
-                "timing_honest": on_tpu,
-                "audit": _audit_verdict("ragged_paged_attention", ageom,
-                                        {"kv_tile_pages": tile}),
-            }
-            # the one-shot variant past the VMEM knee cannot even
-            # compile on the chip — that IS the result (the row the
-            # tiled walk exists for), not a reason to abort the sweep
-            if on_tpu and tile == 0 and scratch > 12 * 2 ** 20:
-                rows.append(dict(row, ms=None,
-                                 skipped="oneshot scratch exceeds VMEM"))
-                continue
-            fn = make(tile)
+            audit = _audit_verdict(
+                "ragged_paged_attention", ageom,
+                None if tile is None else {"kv_tile_pages": tile})
+            for kind, tq in (("decode", 1), ("span", c["span"])):
+                fn = jax.jit(functools.partial(
+                    R.ragged_paged_attention, impl="pallas",
+                    kv_tile_pages=tile, layer=1))
+                q = jnp.asarray(rng.randn(S, tq, Hkv * G, Dh), dt)
+                for slots_live, table_live in (
+                        [(0.0, 0.0)] + [(a, b) for a in (0.25, 1.0)
+                                        for b in (0.25, 0.4, 1.0)]):
+                    n_live = int(round(slots_live * S))
+                    kv = int(round(table_live * pps * ps))
+                    ql = np.zeros((S,), np.int32)
+                    # live slots spread over the table's rows, the
+                    # span (if any) in the first of them
+                    live = (np.arange(n_live) * S) // max(n_live, 1)
+                    ql[live] = 1
+                    if kind == "span" and n_live:
+                        ql[live[0]] = min(tq, kv)
+                    kl = np.where(ql > 0, kv, 0).astype(np.int32)
+                    row = {
+                        "bench": "ragged_sweep", "label": label,
+                        "cell": cell, "tick": kind, "tq": tq,
+                        "slots": S, "kv_heads": Hkv, "group": G,
+                        "head_dim": Dh, "pps": pps, "page_size": ps,
+                        "slots_live": slots_live, "table_live": table_live,
+                        "live_slots": n_live, "kv_len": kv,
+                        "kv_pages": int(n_live * -(-kv // ps)),
+                        "kv_pages_table": S * pps,
+                        "kv_tile_pages": tile, "audit": audit,
+                        "timing_honest": on_tpu}
+                    runs.append((row, fn, (q, kp, vp, jnp.asarray(ql),
+                                           jnp.asarray(kl), tabs)))
+        ran = []
+        for row, fn, args in runs:      # compile outside the trace
             try:
-                if on_tpu:
-                    ms = devtime(fn, args, f"rg_{pps}_{ps}_{tile}",
-                                 n=iters)
-                else:
-                    ms = _walltime(fn, args, n=iters)
-            except Exception as e:   # compile/scratch failure = a row
-                rows.append(dict(row, ms=None, error=str(e)[:200]))
-                continue
-            rows.append(dict(row, ms=round(ms, 4)))
-            cands.append((len(rows) - 1, fn))
-        # the KForge-style loop's first entry: cache the measured
-        # winner per geometry so a runtime dispatcher can pick it
-        # (skipped/failed variants never become candidates)
-        if cands:
-            key = ("ragged_kv_walk", pps, ps, Dh, Hkv,
-                   str(jnp.dtype(dt)))
-            at.autotune(key, [f for _, f in cands], args,
-                        iters=max(iters, 2))
-            win_row = cands[at.cache_info()[0][key]][0]
-            for i, row in enumerate(rows):
-                row["autotune_winner"] = bool(i == win_row)
-                row["tiling_source"] = "explicit"
-            # persist the winner under the EXACT geometry key the
-            # entry point's lookup uses — audit-gated: a measured
-            # winner failing KA001/KA002 is refused and emits an
-            # audit_failed row instead — then report what a
-            # kv_tile_pages=None call now resolves to
-            winner_cfg = {"kv_tile_pages":
-                          rows[win_row]["kv_tile_pages"]}
-            if at.store_dir():
-                try:
-                    at.record("ragged_paged_attention", winner_cfg,
-                              audit=True, **ageom)
-                except at.AutotuneAuditError as e:
-                    rows.append({"bench": "ragged_kv_walk", **ageom,
-                                 **winner_cfg,
-                                 "audit_failed": str(e)[:200]})
-            win = at.lookup("ragged_paged_attention", **ageom)
-            rows.append({"bench": "ragged_kv_walk", "resolution": True,
-                         **ageom, **(win or {}),
-                         "tiling_source": "swept" if win else "default"})
-        results.extend(rows)
+                jax.block_until_ready(fn(*args))
+                ran.append((row, fn, args))
+            except Exception as e:      # a refused compile IS the row
+                results.append(dict(row, ms=None, error=str(e)[-300:]))
+        if on_tpu:
+            tdir = tempfile.mkdtemp(prefix=f"kb_ragged_{cell}_")
+            with jax.profiler.trace(tdir):
+                for _, fn, args in ran:
+                    for _ in range(iters):
+                        y = fn(*args)
+                    jax.block_until_ready(y)
+            ms = _kernel_ms(tdir)
+            assert len(ms) == iters * len(ran), (len(ms), iters, len(ran))
+            for i, (row, _, _) in enumerate(ran):
+                mine = sorted(ms[i * iters:(i + 1) * iters])
+                results.append(dict(row, ms=round(mine[len(mine) // 2], 5)))
+        else:
+            for row, fn, args in ran:
+                results.append(dict(
+                    row, ms=round(_walltime(fn, args, n=iters), 4)))
+        # the flywheel's write side: the tile with the least summed
+        # time over the cell's rows, audit-gated, then what a default
+        # call resolves to
+        mine = [r for r in results if r["cell"] == cell]
+        totals = {t: sum(r["ms"] for r in mine if r["kv_tile_pages"] == t)
+                  for t in tiles if t is not None
+                  and all(r.get("ms") is not None for r in mine
+                          if r["kv_tile_pages"] == t)}
+        if len(totals) > 1 and at.store_dir():
+            winner = {"kv_tile_pages": min(totals, key=totals.get)}
+            try:
+                at.record("ragged_paged_attention", winner, audit=True,
+                          **ageom)
+            except at.AutotuneAuditError as e:
+                results.append({"bench": "ragged_sweep", "cell": cell,
+                                **ageom, **winner,
+                                "audit_failed": str(e)[:200]})
+        win = at.lookup("ragged_paged_attention", **ageom)
+        results.append({"bench": "ragged_sweep", "cell": cell,
+                        "resolution": True, **ageom, **(win or {}),
+                        "tiling_source": "swept" if win else "default"})
     for row in results:
         print(json.dumps(row))
     if out:
+        os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
         with open(out, "w") as f:
             for row in results:
                 f.write(json.dumps(row) + "\n")
@@ -467,12 +553,18 @@ if __name__ == "__main__":
     from paddle_tpu.compile_cache import enable_compile_cache
     enable_compile_cache()
     if "--block-sweep" in sys.argv or "--ragged-sweep" in sys.argv:
-        path = next((a.split("=", 1)[1] for a in sys.argv
-                     if a.startswith("--out=")), None)
+        opt = {a.split("=", 1)[0]: a.split("=", 1)[1] for a in sys.argv
+               if a.startswith("--") and "=" in a}
+        path = opt.get("--out")
         if "--block-sweep" in sys.argv:
             block_sweep(out=path)
         else:
-            ragged_tiling_sweep(out=path)
+            ragged_sweep(
+                out=path, label=opt.get("--label", ""),
+                cells=(opt["--cells"].split(",") if "--cells" in opt
+                       else None),
+                tiles=tuple(None if t == "auto" else int(t) for t in
+                            opt.get("--tiles", "auto").split(",")))
     else:
         assert jax.default_backend() == "tpu", "run on the TPU chip"
         bench_moe()
