@@ -66,7 +66,6 @@ from .planner import (CostModel, PlanCost, PlanPoint,
                       plan_auto_parallel, price_plan_point,
                       verify_plan)
 from .recompile import (RecompileHazardPass, ServingGeometry,
-                        enumerate_chunk_programs,
                         enumerate_tick_programs)
 from .rewrite import (FusedRmsNormPass, Int8EpilogueFusePass,
                       RewriteResult, VerifyOutcome, count_matches,
@@ -101,7 +100,7 @@ __all__ = [
     "audit_serving_state", "build_train_target", "check_tree",
     "check_stage_consistency", "collective_cost_bytes",
     "collective_signature", "count_matches", "default_passes",
-    "default_rewrites", "engine_geometry", "enumerate_chunk_programs",
+    "default_rewrites", "engine_geometry",
     "enumerate_plan_points", "enumerate_tick_programs",
     "estimate_hbm_peak", "flagship_train_objects",
     "fuzz_fleet_scenario", "jit_donation_flags", "kernel_signatures",

@@ -86,7 +86,7 @@ def default_rewrites(names=None) -> List["RewritePass"]:
 def default_passes(**ctor_kwargs) -> List["LintPass"]:
     """One instance of every registered pass, in registration order.
     ``ctor_kwargs[name]`` supplies per-pass constructor kwargs (e.g.
-    ``{"recompile-hazard": {"limit": 16}})``."""
+    ``{"recompile-hazard": {"ragged_limit": 2}})``."""
     return [cls(**ctor_kwargs.get(name, {}))
             for name, cls in PASS_REGISTRY.items()]
 
@@ -104,7 +104,7 @@ class Finding:
     """One lint result: which pass, on which graph, what and where."""
     pass_name: str
     severity: str
-    graph: str                 # target name (e.g. "llama.serving_decode_block")
+    graph: str                 # target name (e.g. "llama.serving_tick[mixed]")
     message: str
     #: control-flow path to the offending eqn, e.g. (("scan","jaxpr"),)
     path: Tuple = ()

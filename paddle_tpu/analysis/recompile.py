@@ -1,33 +1,18 @@
 """Recompile-hazard pass: statically enumerate the program set a
 serving call site can produce.
 
-Two reachability models live here, matching the two engine designs:
-
-**Ragged one-program tick (r12+, ``geom.ragged``).** The engine's only
-step functions are ``serving_tick`` (decode tokens + prompt spans as
-one program; geometry rides in device arrays) and
-``serving_tick_block`` (the fused decode block). The compiled-program
-key is the packed token width, and the reachable set is fixed by
-construction: mixed widths run the tail/no-tail tick pair, width
-``S`` exactly ONE program (the fused block — since r16 sampling rides
-it as data and the single-step sampling tick is gone).
-``enumerate_tick_programs`` enumerates that set
-so the invariant — ≤ 2 programs per width bucket — is *proven* from
-engine dispatch, not asserted, and any future dispatch change that
-silently multiplies the set fails the pass (and warns at engine
-construction) before traffic does.
-
-**Legacy bucketed dispatch (``ragged=False``).** The pre-r12
-``serving_prefill_chunk`` took ``prefix_pages`` as a STATIC argument —
-the gathered-prefix width was a shape — so every distinct value XLA
-saw was one more compile landing *inside the serving tick* (a multi-
-second stall per novel prefix length). ``enumerate_chunk_programs``
-walks that dispatch exactly (attach quanta on the chunk grid, page-
-aligned chunk starts, ≥ 1 suffix token) and proves or refutes the
-≤ ``limit``-programs-per-bucket invariant. It is retained both as the
-model for the still-exported bucketed step fns (offline callers,
-benches A/B-ing against the old path) and as the regression oracle the
-tests seed hazards through.
+The engine's only step functions are ``serving_tick`` (decode tokens +
+prompt spans as one program; geometry rides in device arrays) and
+``serving_tick_block`` (the fused decode block), jitted over a family's
+``serving_tick_cache`` / ``serving_tick_block_cache``. The
+compiled-program key is the packed token width, and the reachable set
+is fixed by construction: mixed widths run the tail/no-tail tick pair,
+width ``S`` exactly ONE program (the fused block — sampling rides it as
+data). ``enumerate_tick_programs`` enumerates that set so the invariant
+— ≤ 2 programs per width bucket — is *proven* from engine dispatch,
+not asserted, and any future dispatch change that silently multiplies
+the set fails the pass (and warns at engine construction) before
+traffic does.
 """
 from __future__ import annotations
 
@@ -37,8 +22,8 @@ from typing import Dict, List, Optional, Set
 from .framework import (Finding, GraphTarget, LintPass, Severity,
                         register_pass)
 
-__all__ = ["ServingGeometry", "enumerate_chunk_programs",
-           "enumerate_tick_programs", "program_inventory",
+__all__ = ["ServingGeometry", "enumerate_tick_programs",
+           "program_inventory",
            "tick_budget", "tick_width_grid", "RecompileHazardPass"]
 
 
@@ -48,11 +33,7 @@ class ServingGeometry:
     page_size: int
     pages_per_slot: int
     buckets: List[int]          # prompt-length buckets (sorted)
-    attach_quantum: int = 1     # 0/None = prefix cache off
     prefill_chunk: Optional[int] = None
-    # ragged one-program-tick engine (r12+): program widths are
-    # S / S+budget and the set below is reachable
-    ragged: bool = False
     max_batch: int = 0
     decode_block: int = 1
     # speculative decoding (r15): draft-length cap; > 0 routes every
@@ -66,20 +47,10 @@ class ServingGeometry:
             page_size=engine.pool.page_size,
             pages_per_slot=engine.scheduler.pages_per_slot,
             buckets=list(engine._buckets),
-            attach_quantum=(engine.prefix_cache.attach_quantum
-                            if engine.prefix_cache is not None else 0),
             prefill_chunk=engine._chunk,
-            ragged=True,
             max_batch=engine.scheduler.max_batch,
             decode_block=engine._decode_block,
             spec_k=engine._spec_k)
-
-
-def _bucket(n: int, buckets) -> int:
-    for b in buckets:
-        if n <= b:
-            return b
-    return buckets[-1]
 
 
 def tick_budget(geom: ServingGeometry) -> int:
@@ -177,67 +148,23 @@ def program_inventory(geom: ServingGeometry) -> Dict[str, object]:
     }
 
 
-def enumerate_chunk_programs(geom: ServingGeometry) -> Dict[int,
-                                                            Set[int]]:
-    """Exact reachable ``{chunk_width: {prefix_pages}}`` under the
-    LEGACY bucketed dispatch rules (see module docstring). Empty when
-    no code path can ever call the chunk program (no cache and no
-    chunking)."""
-    ps = geom.page_size
-    q = geom.attach_quantum
-    chunk = geom.prefill_chunk
-    max_prompt = geom.buckets[-1]
-    out: Dict[int, Set[int]] = {}
-    if not q and chunk is None:
-        return out
-
-    def add(width: int, pp: int) -> None:
-        out.setdefault(int(width), set()).add(int(pp))
-
-    c_pages = chunk // ps if chunk is not None else None
-    for n in range(1, max_prompt + 1):
-        cap = (n - 1) // ps                      # match cap: >=1 suffix tok
-        attaches = [0]
-        if q:
-            attaches = list(range(0, (cap // q) * q + 1, q))
-        for a in attaches:
-            suffix = n - a * ps
-            if chunk is None:
-                if a == 0:
-                    continue    # whole-prompt prefill program, not chunk
-                add(_bucket(suffix, geom.buckets), a)
-                continue
-            if suffix <= chunk:
-                add(chunk, a)   # single suffix chunk at width `chunk`
-                continue
-            # parked: one chunk per tick at page-aligned starts
-            start_pages = a
-            done = 0
-            while done < suffix:
-                add(chunk, start_pages)
-                take = min(suffix - done, chunk)
-                done += take
-                start_pages += c_pages
-    return out
-
-
 @register_pass
 class RecompileHazardPass(LintPass):
     """Runs on targets whose ``meta['geometry']`` is a
     :class:`ServingGeometry` (the CLI attaches the flagship engines');
     jaxpr-free — the hazard is host-side dispatch, not graph content.
-
-    Ragged geometries are held to ``ragged_limit`` (the one-program-
-    tick invariant: ≤ 2 per width bucket); legacy bucketed geometries
-    to ``limit`` (≤ 16 static prefix_pages per chunk width)."""
+    Geometries are held to ``ragged_limit`` (the one-program-tick
+    invariant: ≤ 2 per width bucket)."""
 
     name = "recompile-hazard"
 
-    def __init__(self, limit: int = 16, ragged_limit: int = 2):
-        self.limit = int(limit)
+    def __init__(self, ragged_limit: int = 2):
         self.ragged_limit = int(ragged_limit)
 
-    def _run_ragged(self, target, geom) -> List[Finding]:
+    def run(self, target: GraphTarget) -> List[Finding]:
+        geom = target.meta.get("geometry")
+        if geom is None:
+            return []
         findings: List[Finding] = []
         programs = enumerate_tick_programs(geom)
         for width in sorted(programs):
@@ -256,35 +183,5 @@ class RecompileHazardPass(LintPass):
             target,
             f"program inventory (ragged tick): {inventory} — proven "
             f"bound {worst} programs/bucket (limit {self.ragged_limit})",
-            severity=Severity.INFO))
-        return findings
-
-    def run(self, target: GraphTarget) -> List[Finding]:
-        geom = target.meta.get("geometry")
-        if geom is None:
-            return []
-        if geom.ragged:
-            return self._run_ragged(target, geom)
-        findings: List[Finding] = []
-        programs = enumerate_chunk_programs(geom)
-        total = sum(len(v) for v in programs.values())
-        for width in sorted(programs):
-            vals = programs[width]
-            if len(vals) > self.limit:
-                findings.append(self.finding(
-                    target,
-                    f"chunk-prefill width {width} reaches "
-                    f"{len(vals)} distinct static prefix_pages values "
-                    f"({sorted(vals)}) > limit {self.limit}: each is "
-                    f"one XLA compile inside the serving tick — raise "
-                    f"attach_quantum/prefill_chunk or shrink the "
-                    f"prompt budget"))
-        findings.append(self.finding(
-            target,
-            f"program inventory: {len(geom.buckets)} prefill buckets, "
-            f"{total} chunk programs over {len(programs)} width(s), "
-            f"1 decode shape — proven bound "
-            f"{max((len(v) for v in programs.values()), default=0)} "
-            f"prefix_pages/bucket (limit {self.limit})",
             severity=Severity.INFO))
         return findings

@@ -619,6 +619,10 @@ class Int8EpilogueFusePass(RewritePass):
     name = "int8-epilogue-fuse"
     contract = ExactnessContract(bitwise=False, rtol=0.05, atol=0.1)
     arg_names = ("x", "q", "scale")
+    # outranks decode-tail-fuse (10): that pattern takes the head as an
+    # opaque input, so on an un-fused int8 head it would claim the dot
+    # and leave the dequantized weight materialised outside it
+    priority = 5
 
     def patterns(self):
         qf = Op(_CONVERT, In("q", dtype=np.int8))

@@ -3,7 +3,9 @@
 One place that knows how to hand each flagship program to the
 analysers: abstract-trace (``jax.make_jaxpr`` over ShapeDtypeStructs —
 nothing allocates, nothing compiles) the serving step functions of a
-model module exactly as the engine jits them, tagged with the
+model family exactly as the engine jits them (the family's
+``init_serving_pages`` cache pytree through ``serving_tick_cache`` /
+``serving_tick_block_cache``), tagged with the
 call-site facts the passes need (compute dtype, donated pool outputs,
 slot/step counts, engine geometry for the recompile pass, pp stage
 grouping for the collective pass).
@@ -75,16 +77,15 @@ def engine_geometry(*, page_size: int, max_prompt_len: int,
                     max_new_tokens_cap: int,
                     prefill_chunk: Optional[int] = None,
                     prompt_buckets=None,
-                    prefix_cache: bool = True,
                     max_batch: int = 8,
                     decode_block: int = 1,
                     spec_k: int = 0) -> ServingGeometry:
     """The ``ServingGeometry`` a ``ServingEngine(**same_kwargs)`` would
     run — the same arithmetic as the engine ctor, computable without
     building pools or starting workers (tests pin the two against each
-    other so this cannot drift). The r12 engine is RAGGED: prefix
-    attach is exact (quantum 1 — attach size is device data, not a
-    compile shape) and the program set is keyed by packed token width
+    other so this cannot drift). Prefix attach is exact (attach size
+    is device data, not a compile shape), so the prefix cache plays no
+    part in it, and the program set is keyed by packed token width
     (``enumerate_tick_programs``)."""
     from ..serving.engine import _default_buckets
     buckets = sorted(set(int(b) for b in (
@@ -93,25 +94,65 @@ def engine_geometry(*, page_size: int, max_prompt_len: int,
                        // page_size)
     return ServingGeometry(
         page_size=page_size, pages_per_slot=pages_per_slot,
-        buckets=buckets,
-        attach_quantum=1 if prefix_cache else 0,
-        prefill_chunk=prefill_chunk,
-        ragged=True, max_batch=int(max_batch),
+        buckets=buckets, prefill_chunk=prefill_chunk,
+        max_batch=int(max_batch),
         decode_block=int(decode_block), spec_k=int(spec_k))
 
 
 def _get_model(name: str):
-    if name == "llama":
-        from ..models import llama as mod
-        cfg = mod.LlamaConfig.tiny(use_flash_attention=False, remat=False)
-    elif name == "qwen2_moe":
-        from ..models import qwen2_moe as mod
-        cfg = mod.Qwen2MoeConfig.tiny(use_flash_attention=False,
-                                      remat=False)
-    else:
-        raise ValueError(f"unknown flagship model {name!r}; "
-                         f"one of {FLAGSHIP_MODELS}")
-    return mod, cfg
+    """``(module, tiny config)`` of the serving family ``name``."""
+    import dataclasses
+
+    from ..models import SERVING_FAMILIES, resolve_family
+    mod = resolve_family(name)
+    cfg_cls = getattr(mod, SERVING_FAMILIES[name])
+    off = {"use_flash_attention": False, "remat": False}
+    fields = {f.name for f in dataclasses.fields(cfg_cls)}
+    return mod, cfg_cls.tiny(**{k: v for k, v in off.items()
+                                if k in fields})
+
+
+def _abstract_cache(mod, cfg, slots: int, pps: int, page_size: int):
+    """The family's cache pytree as the engine has ``init_serving_pages``
+    build it (``slots * pps`` pages and the trash page), abstractly."""
+    import jax
+    return jax.eval_shape(lambda: mod.init_serving_pages(
+        cfg, slots * pps + 1, page_size, max_batch=slots))
+
+
+def _donated(cache, first: int):
+    """Result positions of the cache's leaves when they start at
+    ``first``: the cache is the LAST result of both step functions, and
+    the engine donates and rebinds it whole, so they never cross to the
+    host."""
+    import jax
+    return tuple(range(first, first + len(jax.tree_util.tree_leaves(cache))))
+
+
+def _sampling_meta(slots: int) -> Dict[str, Any]:
+    """The fused in-graph sampler's per-slot DATA: the engine passes
+    these with every tick, so the linted graphs carry the sampling head
+    exactly as production compiles it."""
+    import jax
+    import jax.numpy as jnp
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    return {"temp": sds((slots,), jnp.float32),
+            "top_p": sds((slots,), jnp.float32),
+            "top_k": sds((slots,), i32),
+            "key": sds((slots, 2), jnp.uint32),
+            "produced": sds((slots,), i32)}
+
+
+def _tick_meta(T: int, slots: int, pps: int) -> Dict[str, Any]:
+    """``serving_tick_cache``'s ``meta`` at packed width ``T``, abstractly."""
+    import jax
+    import jax.numpy as jnp
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    return {"tok_slot": sds((T,), i32), "tok_pos": sds((T,), i32),
+            "tok_page": sds((T,), i32), "tok_off": sds((T,), i32),
+            "tok_qoff": sds((T,), i32), "q_len": sds((slots,), i32),
+            "kv_len": sds((slots,), i32), "last": sds((slots,), i32),
+            "tables": sds((slots, pps), i32), **_sampling_meta(slots)}
 
 
 def serving_targets(model: str = "llama", *, slots: int = 4,
@@ -120,23 +161,27 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
                     prefill_chunk: int = 8,
                     decode_block: int = 4,
                     spec_k: int = 3) -> List[GraphTarget]:
-    """GraphTargets for one model's flagship serving programs — the
-    r12 one-program-tick set as r16 reshaped it: ``serving_tick`` at
-    the mixed packed width, ``serving_tick_block`` (the fused decode
-    path — since r16 the ONLY pure-decode program: sampling slots ride
-    it through the fused in-graph sampler, whose per-slot
+    """GraphTargets for one family's flagship serving programs, traced
+    through the THREE functions the engine calls (the cache pytree of
+    ``init_serving_pages`` abstractly, then ``serving_tick_cache`` /
+    ``serving_tick_block_cache``): the tick at the mixed packed width,
+    the fused decode block (the ONLY pure-decode program: sampling
+    slots ride it through the fused in-graph sampler, whose per-slot
     temperature/top-k/top-p/key/produced state is traced here exactly
-    as the engine passes it, and the width-S single-step sampling tick
-    no longer exists) and ``generate_paged`` (the offline batched
-    decode), plus the engine geometry riding the block target for the
-    recompile-hazard pass — and, since r15, the speculative VERIFY
-    tick (``serving_tick[verify]`` at the all-slots-drafting width,
-    spec_k static, draft/acceptance geometry as device data) carrying
-    the SPECULATIVE engine geometry, so the recompile pass statically
+    as the engine passes it) and ``generate_paged`` (the offline
+    batched decode, where the family has one), plus the engine geometry
+    riding the block target for the recompile-hazard pass — and, where
+    the family can verify (no layer kind keeps per-slot rows a rejected
+    draft could not roll back), the speculative VERIFY tick
+    (``serving_tick[verify]`` at the all-slots-drafting width, spec_k
+    static, draft/acceptance geometry as device data) carrying the
+    SPECULATIVE engine geometry, so the recompile pass statically
     proves the draft/verify program set keeps the
     ≤2-programs-per-width-bucket invariant too."""
     import jax
     import jax.numpy as jnp
+
+    from ..serving.engine import _cache_kinds
 
     mod, cfg = _get_model(model)
     geom = engine_geometry(
@@ -145,7 +190,6 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
         prefill_chunk=prefill_chunk, max_batch=slots,
         decode_block=decode_block)
     pps = geom.pages_per_slot
-    total_pages = slots * pps + 1
     meta: Dict[str, Any] = {}
     if model == "qwen2_moe":
         # the router GEMM is fp32 BY DESIGN (stable softmax over expert
@@ -157,96 +201,77 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
             lambda lhs, rhs: rhs.shape and rhs.shape[-1] == n_e)
 
     params = mod.abstract_params(cfg)
-    pools = jax.eval_shape(
-        lambda: mod.init_serving_pages(cfg, total_pages, page_size))
-    kp, vp = pools["k_pages"], pools["v_pages"]
+    cache = _abstract_cache(mod, cfg, slots, pps, page_size)
+
     sds = jax.ShapeDtypeStruct
     i32 = jnp.int32
 
     targets: List[GraphTarget] = []
 
-    def sampling_meta():
-        # the fused in-graph sampler's per-slot DATA (r16): the engine
-        # passes these with every tick, so the linted graphs carry the
-        # sampling head exactly as production compiles it
-        return {"temp": sds((slots,), jnp.float32),
-                "top_p": sds((slots,), jnp.float32),
-                "top_k": sds((slots,), i32),
-                "key": sds((slots, 2), jnp.uint32),
-                "produced": sds((slots,), i32)}
-
-    def tick_meta(T):
-        return {"tok_slot": sds((T,), i32), "tok_pos": sds((T,), i32),
-                "tok_page": sds((T,), i32), "tok_off": sds((T,), i32),
-                "tok_qoff": sds((T,), i32), "q_len": sds((slots,), i32),
-                "kv_len": sds((slots,), i32), "last": sds((slots,), i32),
-                "tables": sds((slots, pps), i32), **sampling_meta()}
-
     # --- the ragged tick at its mixed width ---------------------------
-    # widths mirror enumerate_tick_programs: S+budget (mixed ticks);
-    # the pre-r16 width-S single-step sampling tick is GONE — sampling
-    # rides the fused block below as data. The mixed tick carries
-    # prefill, which legitimately returns one [S, V] logits row set per
-    # prompt completion — in_decode_loop stays False so the host-pull
-    # budget (whose hot-path guard is the block program below) does not
-    # charge it per step; the engine pulls only the [S(,1+tail)] i32
-    # token block whoever samples.
+    # widths mirror enumerate_tick_programs: S+budget (mixed ticks).
+    # The mixed tick carries prefill, which legitimately returns one
+    # [S, V] logits row set per prompt completion — in_decode_loop
+    # stays False so the host-pull budget (whose hot-path guard is the
+    # block program below) does not charge it per step; the engine
+    # pulls only the [S(,1+tail)] i32 token block whoever samples.
     from .recompile import tick_budget
     budget = tick_budget(geom)
     T = slots + budget
     targets.append(trace_graph(
         f"{model}.serving_tick[mixed]",
-        mod.serving_tick,
-        (params, sds((T,), i32), tick_meta(T), kp, vp),
+        mod.serving_tick_cache,
+        (params, sds((T,), i32), _tick_meta(T, slots, pps), cache),
         static_kwargs=dict(cfg=cfg, tq=budget, attn_impl="dense"),
         compute_dtype=cfg.dtype, slots=slots,
-        donated_outputs=(2, 3), meta=dict(meta)))
+        donated_outputs=_donated(cache, 2), meta=dict(meta)))
 
-    # --- the speculative verify tick (r15): drafted slots as ragged
-    # spans + in-graph longest-prefix acceptance. Traced at the
+    # --- the speculative verify tick: drafted slots as ragged spans +
+    # in-graph longest-prefix acceptance. Traced at the
     # all-slots-drafting width; the SPECULATIVE engine geometry rides
     # this target, so graph_lint proves the draft/verify program set
     # stays within the per-bucket bound (emitted as
     # serving_programs_spec in --json)
-    spec_geom = engine_geometry(
-        page_size=page_size, max_prompt_len=max_prompt_len,
-        max_new_tokens_cap=max_new_tokens_cap,
-        prefill_chunk=prefill_chunk, max_batch=slots,
-        decode_block=decode_block, spec_k=spec_k)
-    Tv = slots + slots * (1 + spec_k)
-    ver_meta = dict(
-        tick_meta(Tv),
-        ver_idx=sds((slots, 1 + spec_k), i32),
-        draft_tok=sds((slots, spec_k), i32),
-        draft_len=sds((slots,), i32),
-        tail_live=jax.ShapeDtypeStruct((slots,), jnp.bool_))
-    targets.append(trace_graph(
-        f"{model}.serving_tick[verify,spec_k={spec_k}]",
-        mod.serving_tick,
-        (params, sds((Tv,), i32), ver_meta, kp, vp),
-        static_kwargs=dict(cfg=cfg, tq=slots * (1 + spec_k),
-                           spec_k=spec_k, attn_impl="dense"),
-        compute_dtype=cfg.dtype, slots=slots,
-        donated_outputs=(3, 4), meta=dict(meta, geometry=spec_geom)))
+    if not any(k.cache == "slot_rows" for k in _cache_kinds(mod, cfg)):
+        spec_geom = engine_geometry(
+            page_size=page_size, max_prompt_len=max_prompt_len,
+            max_new_tokens_cap=max_new_tokens_cap,
+            prefill_chunk=prefill_chunk, max_batch=slots,
+            decode_block=decode_block, spec_k=spec_k)
+        Tv = slots + slots * (1 + spec_k)
+        ver_meta = dict(
+            _tick_meta(Tv, slots, pps),
+            ver_idx=sds((slots, 1 + spec_k), i32),
+            draft_tok=sds((slots, spec_k), i32),
+            draft_len=sds((slots,), i32),
+            tail_live=sds((slots,), jnp.bool_))
+        targets.append(trace_graph(
+            f"{model}.serving_tick[verify,spec_k={spec_k}]",
+            mod.serving_tick_cache,
+            (params, sds((Tv,), i32), ver_meta, cache),
+            static_kwargs=dict(cfg=cfg, tq=slots * (1 + spec_k),
+                               spec_k=spec_k, attn_impl="dense"),
+            compute_dtype=cfg.dtype, slots=slots,
+            donated_outputs=_donated(cache, 3),
+            meta=dict(meta, geometry=spec_geom)))
 
     # --- fused decode block: the per-tick hot program (greedy AND
-    # sampling slots since r16 — the sampling state is a traced arg,
-    # exactly as the engine passes it) ---------------------------------
-    def _block_with_sampling(p, tok, lens, tabs, kp_, vp_, samp):
-        return mod.serving_tick_block(p, tok, lens, tabs, kp_, vp_,
-                                      cfg=cfg, num_steps=decode_block,
-                                      attn_impl="dense", sampling=samp)
+    # sampling slots — the sampling state is a traced arg, exactly as
+    # the engine passes it) --------------------------------------------
+    def _block_with_sampling(p, tok, lens, tabs, cache_, samp):
+        return mod.serving_tick_block_cache(
+            p, tok, lens, tabs, cache_, cfg, decode_block,
+            attn_impl="dense", sampling=samp)
 
     targets.append(trace_graph(
         f"{model}.serving_tick_block[k={decode_block}]",
         _block_with_sampling,
         (params, sds((slots,), i32), sds((slots,), i32),
-         sds((slots, pps), i32), kp, vp, sampling_meta()),
+         sds((slots, pps), i32), cache, _sampling_meta(slots)),
         compute_dtype=cfg.dtype, slots=slots,
         steps_per_call=decode_block, in_decode_loop=True,
-        # outputs (toks, k_pages, v_pages): the engine donates + rebinds
-        # the pools, so only toks crosses to the host
-        donated_outputs=(1, 2),
+        # outputs (toks, cache'): only toks crosses to the host
+        donated_outputs=_donated(cache, 1),
         meta=dict(meta, geometry=geom)))
 
     # --- offline batched decode: generate_paged ----------------------
@@ -269,13 +294,14 @@ def rewrite_targets(models=("llama",), *, slots: int = 4,
                     serving_pool: Optional[List[GraphTarget]] = None
                     ) -> List[GraphTarget]:
     """Flagship targets for the REWRITE suite (graph_lint --suite
-    rewrite): per model, the fused decode block and the cold prefill
-    chunk — both traced with the fused norm/rope kernels OFF (the
+    rewrite): per model, the fused decode block and the mixed tick
+    — both traced with the fused norm/rope kernels OFF (the
     default off-TPU), so the jnp rmsnorm formulation the
     ``fused-rmsnorm`` substitution targets is really present — plus,
-    for llama, the int8 decode step traced with the UNFUSED
-    dequantize-then-matmul idiom (``PADDLE_TPU_INT8_IMPL=unfused``),
-    the seeded graph the ``int8-epilogue-fuse`` pass must fire on.
+    for llama, the decode-width tick over ``quantize_for_decode``
+    params traced with the UNFUSED dequantize-then-matmul idiom
+    (``PADDLE_TPU_INT8_IMPL=unfused``), the seeded graph the
+    ``int8-epilogue-fuse`` pass must fire on.
 
     Each target's ``meta['expect_rewrites']`` names the rewrites that
     MUST fire there — the suite errors if one does not, so the
@@ -310,8 +336,9 @@ def rewrite_targets(models=("llama",), *, slots: int = 4,
                                              "decode-tail-fuse")
                 targets.append(t)
 
-    # --- int8: the un-fused dequant-matmul decode step (llama is the
-    # int8 flagship — skipped when the caller excluded llama) ---------
+    # --- int8: the decode-width tick over weight-only-quantized params,
+    # traced with the un-fused dequant-matmul idiom (llama is the int8
+    # flagship — skipped when the caller excluded llama) --------------
     if "llama" not in models:
         return targets
     from ..quantization.decode import quantize_for_decode
@@ -320,24 +347,20 @@ def rewrite_targets(models=("llama",), *, slots: int = 4,
         page_size=page_size, max_prompt_len=max_prompt_len,
         max_new_tokens_cap=max_new_tokens_cap)
     pps = geom.pages_per_slot
-    total_pages = slots * pps + 1
     qparams = jax.eval_shape(lambda: quantize_for_decode(
         mod.init_params(cfg, jax.random.PRNGKey(0)), cfg))
-    pools = jax.eval_shape(
-        lambda: mod.init_serving_pages(cfg, total_pages, page_size))
-    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    cache = _abstract_cache(mod, cfg, slots, pps, page_size)
     prev = os.environ.get("PADDLE_TPU_INT8_IMPL")
     os.environ["PADDLE_TPU_INT8_IMPL"] = "unfused"
     try:
         t = trace_graph(
-            "llama.serving_decode_step[int8-unfused]",
-            mod.serving_decode_step,
-            (qparams, sds((slots,), i32), sds((slots,), i32),
-             sds((slots, pps), i32), pools["k_pages"],
-             pools["v_pages"]),
-            static_kwargs=dict(cfg=cfg, attn_impl="dense"),
+            "llama.serving_tick[int8-unfused]",
+            mod.serving_tick_cache,
+            (qparams, jax.ShapeDtypeStruct((slots,), jnp.int32),
+             _tick_meta(slots, slots, pps), cache),
+            static_kwargs=dict(cfg=cfg, tq=1, attn_impl="dense"),
             compute_dtype=cfg.dtype, slots=slots, in_decode_loop=True,
-            donated_outputs=(1, 2))
+            donated_outputs=_donated(cache, 2))
     finally:
         if prev is None:
             os.environ.pop("PADDLE_TPU_INT8_IMPL", None)

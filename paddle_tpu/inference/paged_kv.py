@@ -29,8 +29,8 @@ import jax
 import jax.numpy as jnp
 from ..ops.pallas import on_tpu as _on_tpu
 
-__all__ = ["PagePool", "paged_attention", "write_prompt_pages",
-           "write_token_pages", "apply_defrag"]
+__all__ = ["PagePool", "paged_attention_with_tail",
+           "prompt_pages_from_dense", "apply_defrag"]
 
 
 class PagePool:
@@ -159,52 +159,6 @@ class PagePool:
         self._free = sorted(set(range(1, self.total_pages)) - used_after,
                             reverse=True)
         self._free_set = set(self._free)
-
-
-def _ref_paged_attention(q, k_pages, v_pages, lengths, page_indices,
-                         sm_scale):
-    """Dense reference with paged semantics: gather each sequence's
-    pages, mask positions >= length. q ``[B, H, Dh]``; pages
-    ``[Hkv, P, ps, Dh]``; returns ``[B, H, Dh]``. One formulation —
-    the stats variant — is the single source of the math."""
-    out, _, _ = _ref_paged_attention_stats(
-        (q * sm_scale).astype(q.dtype), k_pages, v_pages, lengths,
-        page_indices)
-    return out
-
-
-def paged_attention(q, k_pages, v_pages, lengths, page_indices,
-                    sm_scale: Optional[float] = None,
-                    pages_per_compute_block: int = 4, impl: str = "auto"):
-    """Decode attention over a paged KV cache.
-
-    q: ``[B, H, Dh]`` (one query token per sequence).
-    k_pages/v_pages: ``[Hkv, total_pages, page_size, Dh]``.
-    lengths: i32 ``[B]`` valid tokens per sequence (INCLUDING the one
-    just written for the current step).
-    page_indices: i32 ``[B, pages_per_seq]``.
-    impl: "auto" (pallas kernel on TPU, reference elsewhere), "pallas"
-    (strict), "dense".
-    """
-    if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    if impl not in ("auto", "pallas", "dense"):
-        raise ValueError(f"impl must be auto|pallas|dense, got {impl!r}")
-    use_pallas = impl == "pallas" or (impl == "auto" and _on_tpu())
-    if use_pallas:
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention as _kernel)
-        pps = page_indices.shape[1]
-        blk = pages_per_compute_block
-        while pps % blk:
-            blk -= 1
-        # the kernel applies no softmax scale (all its "scales" are int8
-        # quantization scales) — fold it into q like the splash wrapper
-        return _kernel((q * sm_scale).astype(q.dtype), k_pages, v_pages,
-                       lengths, page_indices,
-                       pages_per_compute_block=blk)
-    return _ref_paged_attention(q, k_pages, v_pages, lengths, page_indices,
-                                sm_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -405,56 +359,6 @@ def prompt_pages_from_dense(k, v, page_size: int):
         return jnp.concatenate([trash, x], axis=1)
     tables = (1 + np.arange(B * pps, dtype=np.int32)).reshape(B, pps)
     return to_pages(k), to_pages(v), jnp.asarray(tables)
-
-
-def write_token_pages(k_pages, v_pages, k_t, v_t, lengths, page_indices):
-    """Write ONE new token per sequence at position ``lengths[b]``.
-
-    k_t/v_t: ``[B, Hkv, Dh]``. Returns updated (k_pages, v_pages).
-    Sequences whose table row has run out of pages write to the trash
-    page (callers guarantee capacity via PagePool).
-    """
-    ps = k_pages.shape[2]
-    B = k_t.shape[0]
-    b_idx = jnp.arange(B)
-    slot = lengths // ps
-    slot_ok = slot < page_indices.shape[1]
-    page = jnp.where(slot_ok,
-                     page_indices[b_idx, jnp.minimum(
-                         slot, page_indices.shape[1] - 1)],
-                     PagePool.TRASH)
-    off = lengths % ps
-    # pages[:, page[b], off[b]] = token b  ->  value laid out [Hkv, B, Dh]
-    k_pages = k_pages.at[:, page, off].set(k_t.transpose(1, 0, 2))
-    v_pages = v_pages.at[:, page, off].set(v_t.transpose(1, 0, 2))
-    return k_pages, v_pages
-
-
-def write_prompt_pages(k_pages, v_pages, k, v, lengths, page_indices,
-                       offset: int = 0):
-    """Write a whole (right-padded) prompt's KV: positions ``t >=
-    lengths[b]`` land on the trash page.
-
-    k/v: ``[B, T0, Hkv, Dh]``. Returns updated (k_pages, v_pages).
-    ``offset`` shifts every write by that many tokens — k[:, t] lands at
-    cache position ``offset + t`` (chunked prefill writes later chunks
-    of one prompt at their absolute offset; ``lengths`` then counts the
-    valid tokens of the CHUNK, not of the whole prompt).
-    """
-    B, T0 = k.shape[0], k.shape[1]
-    ps = k_pages.shape[2]
-    t = jnp.arange(T0)[None, :]                       # [1, T0]
-    valid = t < lengths[:, None]                      # [B, T0]
-    t_abs = t + offset
-    slot = jnp.broadcast_to(
-        jnp.minimum(t_abs // ps, page_indices.shape[1] - 1), (B, T0))
-    page = jnp.take_along_axis(page_indices, slot.astype(jnp.int32),
-                               axis=1)
-    page = jnp.where(valid, page, PagePool.TRASH)     # [B, T0]
-    off = jnp.broadcast_to(t_abs % ps, (B, T0))
-    k_pages = k_pages.at[:, page, off].set(k.transpose(2, 0, 1, 3))
-    v_pages = v_pages.at[:, page, off].set(v.transpose(2, 0, 1, 3))
-    return k_pages, v_pages
 
 
 def apply_defrag(plan: Dict[int, int], k_pages, v_pages, tables,

@@ -5,6 +5,8 @@ the train step is one jitted SPMD program over the hybrid mesh. Eager
 ``nn.Layer`` wrappers exist for the vision models (lenet.py, resnet.py),
 mirroring the reference's python/paddle/vision/models/.
 """
+import importlib
+
 from . import llama
 from . import qwen2_moe
 from .llama import LlamaConfig
@@ -26,3 +28,29 @@ from .googlenet import GoogLeNet, googlenet
 from .inceptionv3 import InceptionV3, inception_v3
 from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
                     ErnieForPretraining)
+
+
+# A serving family is a module that brings the THREE functions the
+# engine calls: ``init_serving_pages`` / ``serving_tick_cache`` /
+# ``serving_tick_block_cache`` (contracts: models/llama.py). Family
+# (module) name -> its config class; this is the one place a name
+# becomes a module.
+SERVING_FAMILIES = {"llama": "LlamaConfig",
+                    "qwen2_moe": "Qwen2MoeConfig",
+                    "lfm2_moe": "Lfm2MoeConfig"}
+
+
+def resolve_family(model, cfg=None):
+    """The serving family's module: ``model`` is a module-like object
+    (returned as it is), a family name, or None (then the family whose
+    config class ``cfg`` is an instance of, by name)."""
+    if model is not None and not isinstance(model, str):
+        return model
+    name = model or type(cfg).__name__
+    for family, cfg_cls in SERVING_FAMILIES.items():
+        if name in (family, cfg_cls):
+            return importlib.import_module(f"{__name__}.{family}")
+    raise ValueError(
+        f"cannot infer serving model from {name!r}; pass one of "
+        f"{sorted(SERVING_FAMILIES)} or a module exposing "
+        "init_serving_pages/serving_tick_cache/serving_tick_block_cache")

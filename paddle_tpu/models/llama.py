@@ -1256,12 +1256,14 @@ def generate_paged(params, prompt, lengths, cfg: LlamaConfig,
 
 
 # ---------------------------------------------------------------------------
-# serving: single-step prefill/decode over a SHARED page pool
+# serving: the tick over a SHARED page pool
 # ---------------------------------------------------------------------------
-# The continuous-batching engine (paddle_tpu/serving/) needs step
-# functions it can call once per tick against a persistent per-layer
-# page pool — unlike generate_paged, whose cache is built fresh per
-# batch and whose decode loop is fused into one scan. Pages here are
+# The continuous-batching engine (paddle_tpu/serving/) calls a model
+# through THREE functions, once per tick against a persistent cache
+# pytree: ``init_serving_pages`` builds the cache, ``serving_tick_cache``
+# runs one ragged tick over it and ``serving_tick_block_cache`` a fused
+# block of decode ticks. (generate_paged, by contrast, builds its cache
+# fresh per batch and fuses its decode loop into one scan.) Pages are
 # allocated per REQUEST by the host-side PagePool (serving/scheduler.py)
 # and freed the moment a sequence retires, so a long generation never
 # holds cache capacity hostage for the whole batch. The block math is
@@ -1279,327 +1281,6 @@ def init_serving_pages(cfg, total_pages: int, page_size: int,
     shape = (L, Hkv, total_pages, page_size, Dh)
     return {"k_pages": jnp.zeros(shape, cfg.dtype),
             "v_pages": jnp.zeros(shape, cfg.dtype)}
-
-
-def serving_prefill(params, tokens, length, table, k_pages, v_pages, cfg,
-                    attn_impl: str = "auto", _block_fn=None):
-    """Prefill ONE request into its allocated pages.
-
-    tokens ``[1, Tb]`` right-padded to a compile bucket; length scalar
-    i32 (valid tokens); table ``[pps]`` i32 — the slot's page-table row
-    (trailing entries may be TRASH). k_pages/v_pages: the layer-stacked
-    pools. Returns ``(logits [V] f32 at the last valid position,
-    k_pages', v_pages')``. Padding positions write to the trash page and
-    never influence valid logits (causal attention).
-    """
-    from ..inference.paged_kv import write_prompt_pages
-    from ..ops.pallas.flash_attention import flash_attention as _fa
-    block_fn = _block_fn if _block_fn is not None else _block
-    B, T0 = tokens.shape
-    lengths = jnp.reshape(length, (1,)).astype(jnp.int32)
-    tables = jnp.reshape(table, (1, -1)).astype(jnp.int32)
-    h = params["embed"].astype(cfg.dtype)[tokens]
-    positions = jnp.broadcast_to(jnp.arange(T0), (B, T0))
-    if attn_impl != "auto":
-        impl = attn_impl
-    else:
-        fa = cfg.use_flash_attention
-        impl = fa if isinstance(fa, str) else ("auto" if fa else "dense")
-
-    def body(h, xs):
-        lp, kp, vp = xs
-        cell = {}
-
-        def attn_fn(q, k, v):
-            kp2, vp2 = write_prompt_pages(
-                kp, vp, k.astype(kp.dtype), v.astype(vp.dtype), lengths,
-                tables)
-            cell["kp"], cell["vp"] = kp2, vp2
-            return _fa(q, k, v, causal=True, impl=impl)
-
-        h = block_fn(lp, h, positions, cfg, attn_fn)
-        return h, (cell["kp"], cell["vp"])
-
-    h, (kp_new, vp_new) = lax.scan(body, h, (params["layers"], k_pages,
-                                             v_pages))
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    idx = jnp.maximum(lengths - 1, 0)[:, None, None]
-    h_last = jnp.take_along_axis(h, idx, axis=1)[:, 0]
-    logits = _mm(h_last, params["lm_head"])
-    return logits[0].astype(jnp.float32), kp_new, vp_new
-
-
-def serving_prefill_chunk(params, tokens, length, table, k_pages, v_pages,
-                          cfg, prefix_pages: int, attn_impl: str = "auto",
-                          _block_fn=None):
-    """Prefill ONE chunk of a request's prompt at a page-aligned offset.
-
-    tokens ``[1, Tc]`` right-padded chunk; length scalar i32 (valid
-    tokens IN the chunk); table ``[pps]`` i32 — the slot's full page-table
-    row. ``prefix_pages`` (STATIC — one compile per value) is the number
-    of pages already holding this request's earlier tokens: attached
-    prefix-cache pages plus previously prefilled chunks. The chunk's
-    first token sits at absolute position ``prefix_pages * page_size``
-    (chunk boundaries are page-aligned by the engine: the chunk length
-    and cache-attach granularity are both multiples of page_size).
-    Returns ``(logits [V] f32 at the chunk's last valid position,
-    k_pages', v_pages')``.
-
-    Exactness: causal attention makes a prefix's KV a function of the
-    prefix tokens alone, so the gathered pages hold exactly the bits a
-    whole-prompt prefill would have produced for those positions; the
-    chunk rows then see the same score rows (prefix gathered dense ++
-    in-graph chunk, bottom-right causal mask) as the full flash program,
-    and padding/width changes only add exact zeros to the reductions.
-    Chunked, suffix-only and whole-prompt prefill therefore produce
-    bitwise-identical KV and logits (tests/test_prefix_cache.py pins
-    greedy equality through the engine in every cache state).
-    """
-    from ..inference.paged_kv import write_prompt_pages
-    from ..ops.pallas.flash_attention import flash_attention as _fa
-    block_fn = _block_fn if _block_fn is not None else _block
-    prefix_pages = int(prefix_pages)
-    B, Tc = tokens.shape
-    Hkv, Dh = k_pages.shape[1], k_pages.shape[-1]
-    ps = k_pages.shape[-2]
-    off = prefix_pages * ps
-    lengths = jnp.reshape(length, (1,)).astype(jnp.int32)
-    tables = jnp.reshape(table, (1, -1)).astype(jnp.int32)
-    pref_ids = tables[0, :prefix_pages]               # static length
-    h = params["embed"].astype(cfg.dtype)[tokens]
-    positions = jnp.broadcast_to(off + jnp.arange(Tc), (B, Tc))
-    if attn_impl != "auto":
-        impl = attn_impl
-    else:
-        fa = cfg.use_flash_attention
-        impl = fa if isinstance(fa, str) else ("auto" if fa else "dense")
-
-    def gather_prefix(pages):
-        # [Hkv, n_pre, ps, Dh] -> [1, n_pre*ps, Hkv, Dh] (position-major)
-        pre = pages[:, pref_ids].reshape(Hkv, off, Dh)
-        return pre.transpose(1, 0, 2)[None]
-
-    def body(h, xs):
-        lp, kp, vp = xs
-        cell = {}
-
-        def attn_fn(q, k, v):
-            kp2, vp2 = write_prompt_pages(
-                kp, vp, k.astype(kp.dtype), v.astype(vp.dtype), lengths,
-                tables, offset=off)
-            cell["kp"], cell["vp"] = kp2, vp2
-            if prefix_pages:
-                kc = jnp.concatenate(
-                    [gather_prefix(kp).astype(k.dtype), k], axis=1)
-                vc = jnp.concatenate(
-                    [gather_prefix(vp).astype(v.dtype), v], axis=1)
-            else:
-                kc, vc = k, v
-            # bottom-right-aligned causal (S = off + Tc > Tc = T): every
-            # chunk query attends the whole gathered prefix plus its own
-            # causal window — _dense_reference's tril(k=S-T) / splash's
-            # CausalMask(offset=S-T) implement exactly this
-            return _fa(q, kc, vc, causal=True, impl=impl)
-
-        h = block_fn(lp, h, positions, cfg, attn_fn)
-        return h, (cell["kp"], cell["vp"])
-
-    h, (kp_new, vp_new) = lax.scan(body, h, (params["layers"], k_pages,
-                                             v_pages))
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    idx = jnp.maximum(lengths - 1, 0)[:, None, None]
-    h_last = jnp.take_along_axis(h, idx, axis=1)[:, 0]
-    logits = _mm(h_last, params["lm_head"])
-    return logits[0].astype(jnp.float32), kp_new, vp_new
-
-
-def serving_decode_step(params, tok, lengths, tables, k_pages, v_pages,
-                        cfg, attn_impl: str = "auto", _block_fn=None):
-    """One decode tick for ALL slots of the serving batch.
-
-    tok ``[S]`` i32 — each slot's current token; lengths ``[S]`` i32 —
-    tokens already in that slot's cache (0 for dead slots, whose table
-    rows are all-TRASH: they write to and read from the trash page and
-    their logits are discarded by the host); tables ``[S, pps]``.
-    Returns ``(logits [S, V] f32, k_pages', v_pages')``. The token's KV
-    lands at position ``lengths[s]``; attention then covers
-    ``lengths + 1`` positions — the paged counterpart of
-    forward_with_cache's decode step.
-    """
-    from ..inference.paged_kv import paged_attention, write_token_pages
-    block_fn = _block_fn if _block_fn is not None else _block
-    h = params["embed"].astype(cfg.dtype)[tok[:, None]]      # [S, 1, D]
-    positions = lengths[:, None]
-
-    def body(h, xs):
-        lp, kp, vp = xs
-        cell = {}
-
-        def attn_fn(q, k, v):
-            kp2, vp2 = write_token_pages(
-                kp, vp, k[:, 0].astype(kp.dtype), v[:, 0].astype(vp.dtype),
-                lengths, tables)
-            cell["kp"], cell["vp"] = kp2, vp2
-            o = paged_attention(q[:, 0], kp2, vp2, lengths + 1, tables,
-                                impl=attn_impl)
-            return o[:, None].astype(q.dtype)
-
-        h = block_fn(lp, h, positions, cfg, attn_fn)
-        return h, (cell["kp"], cell["vp"])
-
-    h, (kp_new, vp_new) = lax.scan(body, h, (params["layers"], k_pages,
-                                             v_pages))
-    h = rms_norm(h[:, 0], params["final_norm"], cfg.rms_norm_eps)
-    logits = _mm(h, params["lm_head"])
-    return logits.astype(jnp.float32), kp_new, vp_new
-
-
-def serving_decode_block(params, tok, lengths, tables, k_pages, v_pages,
-                         cfg, num_steps: int, attn_impl: str = "auto",
-                         _block_fn=None):
-    """``num_steps`` fused GREEDY decode ticks in one program (the
-    multi-step scheduling lever: per-call dispatch + host bookkeeping
-    amortize over the block). Sampling is in-graph argmax over the f32
-    logits — bit-identical to sample_logits(temperature=0), so tokens
-    still match single-step decode exactly. Returns
-    ``(toks [S, num_steps] i32, k_pages', v_pages')``; the host
-    truncates a sequence's tokens at EOS/max_new_tokens (positions a
-    retiring sequence wrote past its budget land on the trash page via
-    the table-width guard, so neighbours never see them)."""
-
-    def step(carry, _):
-        tok, lens, kp, vp = carry
-        logits, kp, vp = serving_decode_step(
-            params, tok, lens, tables, kp, vp, cfg, attn_impl, _block_fn)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (nxt, lens + 1, kp, vp), nxt
-
-    (_, _, kp_new, vp_new), toks = lax.scan(
-        step, (tok, lengths, k_pages, v_pages), None, length=num_steps)
-    return jnp.moveaxis(toks, 0, 1), kp_new, vp_new
-
-
-def serving_tick(params, tokens, meta, k_pages, v_pages, cfg, tq: int = 1,
-                 decode_tail: int = 0, spec_k: int = 0,
-                 attn_impl: str = "auto", _block_fn=None):
-    """ONE ragged serving tick: any mix of chunked prefills, warm-prefix
-    attaches and decode steps as a single static program.
-
-    The pre-r12 engine dispatched separate geometry-bucketed programs
-    (``serving_prefill`` per prompt bucket, ``serving_prefill_chunk``
-    per static prefix_pages value, ``serving_decode_step``); this one
-    step fn replaces all of them — sequence geometry rides in ``meta``
-    as DEVICE ARRAYS, so XLA compiles exactly one program per packed
-    width and the engine's compile-geometry quantization (chunk grids,
-    attach quanta) is gone at the root.
-
-    tokens ``[T]`` i32 — the tick's packed token stream: each live
-    slot's current decode token and/or a span of some prompt's next
-    uncached tokens, concatenated (padding tokens allowed anywhere).
-    meta — a dict of device arrays describing the packing:
-
-    * ``tok_slot [T]``: owning slot of each packed token (``S`` = a
-      padding token that must touch nothing real);
-    * ``tok_pos [T]``: the token's absolute sequence position;
-    * ``tok_page [T]`` / ``tok_off [T]``: the page id and in-page
-      offset its KV lands at (TRASH page for padding);
-    * ``tok_qoff [T]``: offset of the token inside its slot's span;
-    * ``q_len [S]``: span length per slot (0 = slot idle this tick);
-    * ``kv_len [S]``: keys visible at the END of the span (context +
-      the span itself);
-    * ``last [T-indexed scalar per slot] [S]``: packed index of each
-      slot's LAST span token — its hidden state feeds that slot's
-      logits row (idle slots may point anywhere; their row is junk the
-      host discards);
-    * ``tables [S, pps]``: the page-table rows.
-
-    FUSED SAMPLING (r16) — five more optional meta arrays, all DATA,
-    turn every token selection in the tick (last-position pick, fused
-    tail steps, speculative verify) into a per-slot
-    temperature/top-k/top-p gumbel draw via ``_fused_sample``:
-    ``temp [S]`` f32 / ``top_p [S]`` f32 / ``top_k [S]`` i32 (0 =
-    off) / ``key [S, 2]`` u32 raw per-slot PRNG keys / ``produced
-    [S]`` i32 — the continuation index of the token this launch
-    emits; token ``n`` is always drawn with ``fold_in(key, n)``, so a
-    fixed seed yields one stream whatever the batch composition,
-    block fusion or speculation (see ``_fused_sample``). Greedy rows
-    (temp == 0) keep the bitwise argmax. The engine ALWAYS passes
-    these (presence is a trace-time fact): SAMPLING slots ride the
-    same fused programs as greedy ones, and the pre-r16 width-S
-    single-step sampling program is gone from the inventory.
-
-    ``tq`` (STATIC — one compile per value; the engine uses exactly
-    two: the prefill budget and 1) is the maximum span length, sizing
-    the kernel's slot-major query layout.
-
-    ``decode_tail`` (STATIC) fuses that many extra GREEDY decode steps
-    after the ragged pass — the multi-step scheduling lever that keeps
-    an admission tick producing a full decode block for in-flight
-    streams (the seed engine got this by running prefill + the fused
-    block as two programs; here the tail rides in the SAME program).
-    ``meta['tail_live'] [S]`` bool gates it: only tail-live slots
-    (decoding slots, plus spans that complete their prompt this tick)
-    advance — mid-prefill slots stay dead through the tail (q_len 0,
-    KV writes to the trash page).
-
-    ``spec_k`` (STATIC — the engine's draft-length cap; one compile
-    per value, and a speculative engine uses exactly one) turns the
-    tick into the speculative VERIFY program: speculating slots
-    submitted their current token plus up to ``spec_k`` draft tokens
-    as an ordinary ragged span (the same packed stream, mixed with
-    prefill spans and plain decode slots), and the tick additionally
-    computes the target model's greedy argmax at EVERY span position
-    plus the in-graph longest-prefix acceptance against the drafts.
-    Three extra ``meta`` arrays carry the (per-slot, DATA-not-shape)
-    speculation geometry:
-
-    * ``ver_idx [S, 1+spec_k]``: packed index of each slot's span
-      token ``j`` (position ``j``'s hidden state predicts span
-      position ``j+1``); non-speculating slots point every entry at
-      their ``last`` token, so their row 0 reproduces the plain
-      tick's logits/argmax exactly;
-    * ``draft_tok [S, spec_k]`` / ``draft_len [S]``: the draft tokens
-      and each slot's actual draft count ``k_s <= spec_k`` (0 for
-      non-speculating slots — adaptive k is data, the cap is the only
-      shape).
-
-    ``spec_k`` and ``decode_tail`` are mutually exclusive (speculation
-    IS the multi-token lever on a speculative engine).
-
-    Returns ``(toks, logits [S, V] f32, k_pages', v_pages')``:
-    ``toks`` is each slot's in-graph token pick at its last position
-    (argmax, or the fused sampler's draw) — ``[S]`` i32 when
-    ``decode_tail == 0``, else ``[S, 1+decode_tail]`` (the host pulls
-    only these ints, whoever samples); ``logits`` is the RAGGED
-    pass's (first step's) logits, kept for OFFLINE callers that
-    sample their own way — since r16 the engine never reads it (the
-    fused sampler replaced the host path), it stays on device and is
-    dropped. With ``spec_k > 0`` the
-    return is ``(toks [S, 1+spec_k], accept [S], logits [S, V] f32,
-    k_pages', v_pages')``: ``toks[s, j]`` is the target argmax after
-    consuming span tokens ``0..j``, ``accept[s]`` the number of
-    leading drafts matching it (``toks[s, :accept[s]]`` equal the
-    drafts token-for-token and ``toks[s, accept[s]]`` is the bonus/
-    correction token — ``1 + accept`` emitted tokens from ONE target
-    launch), and ``logits`` is row 0's logits (``ver_idx[:, 0]``
-    points at ``last`` for every slot a host would sample from).
-    Rejected draft KV needs no device-side rollback: the stale rows
-    sit past the slot's advanced length, masked by ``kv_len`` until
-    the sequence's real tokens overwrite them positionally — the same
-    trash-row discipline retiring overruns already rely on.
-
-    Exactness: the span's KV is scattered into the pages FIRST, then
-    the ragged kernel attends over pages only, bottom-right causal —
-    so a prefix's KV is a function of the prefix tokens alone and
-    chunked/whole/warm prefills all produce the bits a single
-    whole-prompt pass would (tests pin greedy equality to
-    ``generate()`` in every cache state).
-    """
-    *out, cache = serving_tick_cache(
-        params, tokens, meta, {"k_pages": k_pages, "v_pages": v_pages},
-        cfg, tq=tq, decode_tail=decode_tail, spec_k=spec_k,
-        attn_impl=attn_impl, walk=_one_kind_walk(_block_fn))
-    return (*out, cache["k_pages"], cache["v_pages"])
 
 
 def _one_kind_walk(block_fn=None):
@@ -1676,16 +1357,111 @@ def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl, block_fn):
 def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
                        decode_tail: int = 0, spec_k: int = 0,
                        attn_impl: str = "auto", walk=None):
-    """``serving_tick`` over a model's whole cache pytree, which is how
-    the engine calls every model: the embedding, the final norm, the
-    head, the fused sampler, the verify pass and the fused decode tail
-    are here, once for every model; the layers are ``walk``'s (None:
-    this model's, see ``_walk_one_kind``; another model's module hands
-    its own). ``cache`` is what the model's
-    ``init_serving_pages`` built — always with ``k_pages`` / ``v_pages``
+    """ONE ragged serving tick over a model's whole cache pytree: any mix
+    of chunked prefills, warm-prefix attaches and decode steps as a
+    single static program. Sequence geometry rides in ``meta`` as DEVICE
+    ARRAYS, so XLA compiles exactly one program per packed width:
+    prompt length, chunk position and attached-prefix size are data.
+
+    This is how the engine calls every model: the embedding, the final
+    norm, the head, the fused sampler, the verify pass and the fused
+    decode tail are here, once; the layers are ``walk``'s (None: this
+    model's, see ``_walk_one_kind``; another model's module hands its
+    own). ``cache`` is what the model's ``init_serving_pages`` built and
+    is DONATED by the engine — always with ``k_pages`` / ``v_pages``
     ``[L_attn, Hkv, P, ps, Dh]``, and whatever else its layer kinds keep
-    (a fixed row a slot, ...). Returns ``serving_tick``'s results with
-    the cache pytree last in place of the two pools."""
+    (a fixed row a slot, ...); the new cache is the last result.
+
+    tokens ``[T]`` i32 — the tick's packed token stream: each live
+    slot's current decode token and/or a span of some prompt's next
+    uncached tokens, concatenated (padding tokens allowed anywhere).
+    meta — a dict of device arrays describing the packing:
+
+    * ``tok_slot [T]``: owning slot of each packed token (``S`` = a
+      padding token that must touch nothing real);
+    * ``tok_pos [T]``: the token's absolute sequence position;
+    * ``tok_page [T]`` / ``tok_off [T]``: the page id and in-page
+      offset its KV lands at (TRASH page for padding);
+    * ``tok_qoff [T]``: offset of the token inside its slot's span;
+    * ``q_len [S]``: span length per slot (0 = slot idle this tick);
+    * ``kv_len [S]``: keys visible at the END of the span (context +
+      the span itself);
+    * ``last [S]``: packed index of each slot's LAST span token — its
+      hidden state feeds that slot's logits row (idle slots may point
+      anywhere; their row is junk the host discards);
+    * ``tables [S, pps]``: the page-table rows.
+
+    FUSED SAMPLING — five more optional meta arrays, all DATA, turn
+    every token selection in the tick (last-position pick, fused tail
+    steps, speculative verify) into a per-slot temperature/top-k/top-p
+    gumbel draw via ``_fused_sample``: ``temp [S]`` f32 / ``top_p [S]``
+    f32 / ``top_k [S]`` i32 (0 = off) / ``key [S, 2]`` u32 raw per-slot
+    PRNG keys / ``produced [S]`` i32 — the continuation index of the
+    token this launch emits; token ``n`` is always drawn with
+    ``fold_in(key, n)``, so a fixed seed yields one stream whatever the
+    batch composition, block fusion or speculation (see
+    ``_fused_sample``). Greedy rows (temp == 0) keep the bitwise
+    argmax. The engine ALWAYS passes these (presence is a trace-time
+    fact): sampling slots ride the same programs as greedy ones.
+
+    THREE MODES, chosen by two STATIC arguments (one compile per value):
+
+    * plain (``decode_tail == spec_k == 0``): the ragged pass alone;
+    * ``decode_tail`` fuses that many extra decode steps after the
+      ragged pass — the multi-step scheduling lever that keeps an
+      admission tick producing a full decode block for in-flight
+      streams, in the SAME program. ``meta['tail_live'] [S]`` bool
+      gates it: only tail-live slots (decoding slots, plus spans that
+      complete their prompt this tick) advance — mid-prefill slots stay
+      dead through the tail (q_len 0, KV writes to the trash page);
+    * ``spec_k`` (the engine's draft-length cap; a speculative engine
+      uses exactly one) turns the tick into the speculative VERIFY
+      program: speculating slots submitted their current token plus up
+      to ``spec_k`` draft tokens as an ordinary ragged span (the same
+      packed stream, mixed with prefill spans and plain decode slots),
+      and the tick additionally computes the target model's token at
+      EVERY span position plus the in-graph longest-prefix acceptance
+      against the drafts. Three extra ``meta`` arrays carry the
+      (per-slot, DATA-not-shape) speculation geometry: ``ver_idx [S,
+      1+spec_k]``, the packed index of each slot's span token ``j``
+      (position ``j``'s hidden state predicts span position ``j+1``;
+      non-speculating slots point every entry at their ``last`` token,
+      so their row 0 reproduces the plain tick's logits exactly), and
+      ``draft_tok [S, spec_k]`` / ``draft_len [S]``, the draft tokens
+      and each slot's actual draft count ``k_s <= spec_k`` (0 for
+      non-speculating slots — adaptive k is data, the cap is the only
+      shape). ``spec_k`` and ``decode_tail`` are mutually exclusive
+      (speculation IS the multi-token lever on a speculative engine).
+
+    ``tq`` (STATIC; the engine passes the span width of the tick's entry
+    in its width grid) is the maximum span length, sizing the kernel's
+    slot-major query layout.
+
+    Returns ``(toks, logits [S, V] f32, cache')``: ``toks`` is each
+    slot's in-graph token pick at its last position (argmax, or the
+    fused sampler's draw) — ``[S]`` i32 when ``decode_tail == 0``, else
+    ``[S, 1+decode_tail]`` (the host pulls only these ints); ``logits``
+    is the RAGGED pass's (first step's) logits, kept for callers that
+    sample their own way — the engine never reads it, it stays on
+    device and is dropped. With ``spec_k > 0`` the return is ``(toks
+    [S, 1+spec_k], accept [S], logits [S, V] f32, cache')``: ``toks[s,
+    j]`` is the target's token after consuming span tokens ``0..j``,
+    ``accept[s]`` the number of leading drafts matching it (``toks[s,
+    :accept[s]]`` equal the drafts token-for-token and ``toks[s,
+    accept[s]]`` is the bonus/correction token — ``1 + accept`` emitted
+    tokens from ONE target launch), and ``logits`` is row 0's logits.
+    Rejected draft KV needs no device-side rollback: the stale rows sit
+    past the slot's advanced length, masked by ``kv_len`` until the
+    sequence's real tokens overwrite them positionally — the same
+    trash-row discipline retiring overruns already rely on.
+
+    Exactness: the span's KV is scattered into the pages FIRST, then
+    the ragged kernel attends over pages only, bottom-right causal —
+    so a prefix's KV is a function of the prefix tokens alone and
+    chunked/whole/warm prefills all produce the bits a single
+    whole-prompt pass would (tests pin greedy equality to
+    ``generate()`` in every cache state).
+    """
     if walk is None:
         walk = _one_kind_walk()
     tq = int(tq)
@@ -1782,8 +1558,8 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
         tok, lens, idx, cache_t = carry
         slot = lens // ps
         # rows out of pages (retiring overruns), dead all-TRASH rows
-        # and tail-dead (mid-prefill) slots land on the trash page,
-        # exactly like write_token_pages
+        # and tail-dead (mid-prefill) slots land on the trash page
+        # (page 0, offset 0), which nothing reads
         ok = live & (slot < pps)
         page = jnp.where(
             ok, meta["tables"][b_idx, jnp.minimum(slot, pps - 1)], 0)
@@ -1812,39 +1588,30 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
     return toks, logits, cache_new
 
 
-def serving_tick_block(params, tok, lengths, tables, k_pages, v_pages,
-                       cfg, num_steps: int, attn_impl: str = "auto",
-                       _block_fn=None, sampling=None):
-    """``num_steps`` fused decode ticks built on the ragged tick (the
-    multi-step scheduling lever — same contract as the retired
-    ``serving_decode_block`` for every slot that holds context:
-    greedy slots are in-graph argmax and match single-step decode
-    exactly). A slot with ``lengths == 0`` (free, or admitted and not
-    yet prefilled) is DEAD to the block: it enters the tick with no
-    query row (``q_len`` 0, the slot sentinel for its token), attends
-    nothing, writes to the trash page, and its returned tokens mean
-    nothing. tok/lengths ``[S]`` i32, tables
-    ``[S, pps]``. ``sampling`` (r16): a dict of the fused-sampling
-    meta arrays — ``temp``/``top_p`` f32 [S], ``top_k`` i32 [S],
-    ``key`` u32 [S, 2], ``produced`` i32 [S] — letting SAMPLING slots
-    ride the fused block too (step ``j`` draws continuation index
-    ``produced + j`` via the fold_in discipline); None keeps the
-    all-greedy block. Returns
-    ``(toks [S, num_steps] i32, k_pages', v_pages')``."""
-    toks, cache = serving_tick_block_cache(
-        params, tok, lengths, tables,
-        {"k_pages": k_pages, "v_pages": v_pages}, cfg, num_steps,
-        attn_impl=attn_impl, sampling=sampling,
-        walk=_one_kind_walk(_block_fn))
-    return toks, cache["k_pages"], cache["v_pages"]
-
-
 def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
                              num_steps: int, attn_impl: str = "auto",
                              sampling=None, walk=None):
-    """``serving_tick_block`` over a model's whole cache pytree and its
-    layer ``walk`` (see ``serving_tick_cache``). Returns ``(toks [S,
-    num_steps] i32, cache')``."""
+    """``num_steps`` fused decode ticks built on the ragged tick (the
+    multi-step scheduling lever: per-call dispatch + host bookkeeping
+    amortize over the block) over a model's whole cache pytree and its
+    layer ``walk`` (see ``serving_tick_cache``). Greedy slots are
+    in-graph argmax and match single-step decode exactly. tok/lengths
+    ``[S]`` i32, tables ``[S, pps]``.
+
+    A slot with ``lengths == 0`` (free, or admitted and not yet
+    prefilled) is DEAD to the block: it enters the tick with no query
+    row (``q_len`` 0, the slot sentinel for its token), attends
+    nothing, writes to the trash page, and its returned tokens mean
+    nothing. The host truncates a sequence's tokens at
+    EOS/max_new_tokens; positions a retiring sequence wrote past its
+    pages land on the trash page, so neighbours never see them.
+
+    ``sampling``: a dict of the fused-sampling meta arrays —
+    ``temp``/``top_p`` f32 [S], ``top_k`` i32 [S], ``key`` u32 [S, 2],
+    ``produced`` i32 [S] — letting SAMPLING slots ride the fused block
+    too (step ``j`` draws continuation index ``produced + j`` via the
+    fold_in discipline); None keeps the all-greedy block. Returns
+    ``(toks [S, num_steps] i32, cache')``."""
     S = tok.shape[0]
     pps = tables.shape[1]
     ps = cache["k_pages"].shape[-2]
@@ -1857,7 +1624,7 @@ def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
     # trash page in every layer
     live = lengths > 0
     # rows out of pages (retiring overruns) and dead all-TRASH rows
-    # land on the trash page, exactly like write_token_pages
+    # land on the trash page (page 0, offset 0), which nothing reads
     ok = live & (slot < pps)
     page = jnp.where(ok, tables[b_idx, jnp.minimum(slot, pps - 1)], 0)
     meta = dict(tok_slot=jnp.where(live, b_idx, S).astype(jnp.int32),

@@ -387,12 +387,11 @@ def make_batch(cfg: Qwen2MoeConfig, batch_size: int, seq_len: int,
 
 
 # ---------------------------------------------------------------------------
-# serving: single-step prefill/decode over a shared page pool
+# serving: the tick over a shared page pool
 # ---------------------------------------------------------------------------
-# Same contracts as models/llama.py's serving fns — the drivers are
-# shared; only the block math (here: _decode_block with the drop-free
-# MoE FFN) differs. The continuous-batching engine (paddle_tpu/serving/)
-# dispatches on the config type.
+# The three functions the engine calls (models/llama.py has the
+# contracts): the tick is llama's, walking this model's block
+# (_decode_block with the drop-free MoE FFN).
 
 
 def abstract_params(cfg: Qwen2MoeConfig):
@@ -405,53 +404,6 @@ def init_serving_pages(cfg: Qwen2MoeConfig, total_pages: int,
                        page_size: int, max_batch: int = 0):
     from .llama import init_serving_pages as _impl
     return _impl(cfg, total_pages, page_size)
-
-
-def serving_prefill(params, tokens, length, table, k_pages, v_pages, cfg,
-                    attn_impl: str = "auto"):
-    from .llama import serving_prefill as _impl
-    return _impl(params, tokens, length, table, k_pages, v_pages, cfg,
-                 attn_impl=attn_impl, _block_fn=_decode_block)
-
-
-def serving_prefill_chunk(params, tokens, length, table, k_pages, v_pages,
-                          cfg, prefix_pages: int, attn_impl: str = "auto"):
-    from .llama import serving_prefill_chunk as _impl
-    return _impl(params, tokens, length, table, k_pages, v_pages, cfg,
-                 prefix_pages, attn_impl=attn_impl,
-                 _block_fn=_decode_block)
-
-
-def serving_decode_step(params, tok, lengths, tables, k_pages, v_pages,
-                        cfg, attn_impl: str = "auto"):
-    from .llama import serving_decode_step as _impl
-    return _impl(params, tok, lengths, tables, k_pages, v_pages, cfg,
-                 attn_impl=attn_impl, _block_fn=_decode_block)
-
-
-def serving_decode_block(params, tok, lengths, tables, k_pages, v_pages,
-                         cfg, num_steps: int, attn_impl: str = "auto"):
-    from .llama import serving_decode_block as _impl
-    return _impl(params, tok, lengths, tables, k_pages, v_pages, cfg,
-                 num_steps, attn_impl=attn_impl, _block_fn=_decode_block)
-
-
-def serving_tick(params, tokens, meta, k_pages, v_pages, cfg,
-                 tq: int = 1, decode_tail: int = 0, spec_k: int = 0,
-                 attn_impl: str = "auto"):
-    from .llama import serving_tick as _impl
-    return _impl(params, tokens, meta, k_pages, v_pages, cfg, tq=tq,
-                 decode_tail=decode_tail, spec_k=spec_k,
-                 attn_impl=attn_impl, _block_fn=_decode_block)
-
-
-def serving_tick_block(params, tok, lengths, tables, k_pages, v_pages,
-                       cfg, num_steps: int, attn_impl: str = "auto",
-                       sampling=None):
-    from .llama import serving_tick_block as _impl
-    return _impl(params, tok, lengths, tables, k_pages, v_pages, cfg,
-                 num_steps, attn_impl=attn_impl, _block_fn=_decode_block,
-                 sampling=sampling)
 
 
 def serving_tick_cache(params, tokens, meta, cache, cfg, **kw):

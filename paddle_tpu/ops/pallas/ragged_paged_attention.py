@@ -21,9 +21,9 @@ Layout contract:
   into real outputs.
 * ``k_pages``/``v_pages``: ``[Hkv, total_pages, page_size, Dh]`` — the
   shared serving pools. The span's OWN fresh KV must already be
-  written into the pages (the step fn scatters before attending, like
-  ``serving_decode_step``), so the kernel is purely paged: no separate
-  current-chunk operand, no gathered-prefix concat.
+  written into the pages (``serving_tick_cache`` scatters before
+  attending), so the kernel is purely paged: no separate current-chunk
+  operand, no gathered-prefix concat.
 * ``layer`` (optional): with it the pools are the serving tick's
   STACKED ones, ``[L, Hkv, total_pages, page_size, Dh]``, and the
   kernel reads that layer's pages where they lie (its page DMAs start
@@ -170,9 +170,9 @@ def default_kv_tile_pages(pages_per_slot: int, page_size: int,
     """Geometry selection of the KV walk's tile, in pages: what
     ``DEFAULT_TILE_BYTES`` holds of this geometry's rows, and never
     more than the table (a table that fits one tile is walked in one
-    trip). The engine never chooses: ``serving_tick`` passes geometry
-    through and this picks per (pages_per_slot, page_size, Dh,
-    dtype)."""
+    trip). The engine never chooses: ``serving_tick_cache`` passes
+    geometry through and this picks per (pages_per_slot, page_size,
+    Dh, dtype)."""
     tokens = DEFAULT_TILE_BYTES // (int(head_dim)
                                     * jnp.dtype(dtype).itemsize)
     return min(int(pages_per_slot), max(1, tokens // int(page_size)))

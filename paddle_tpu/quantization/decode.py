@@ -26,9 +26,9 @@ Deliberately NOT quantized:
   * qwen's shared-expert sigmoid gate — O(D·1).
 
 The quantized pytree drops into every decode entry point unchanged —
-``generate``, ``generate_paged``, ``serving_prefill`` /
-``serving_decode_step`` / ``serving_decode_block`` — because the model
-bodies dispatch matmuls through ``_mm`` (dense array or Int8Weight).
+``generate``, ``generate_paged``, ``serving_tick_cache`` /
+``serving_tick_block_cache`` — because the model bodies dispatch
+matmuls through ``_mm`` (dense array or Int8Weight).
 Training paths are out of scope: quantize AFTER training, for serving.
 """
 from __future__ import annotations

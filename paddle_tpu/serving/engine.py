@@ -15,16 +15,15 @@ behind it); this engine batches per STEP:
     prefix_cache.py — refcounted KV page reuse across requests:
     system prompts and few-shot headers are computed once) — and only
     the uncached suffix is ever computed;
-  - every engine tick is ONE jitted ragged program
-    (``models/*.serving_tick`` over the ragged-paged-attention Pallas
-    kernel): each live slot's decode token AND up to a per-tick token
-    budget of pending prompt spans run in the same launch, with
+  - every engine tick is ONE jitted ragged program (the model
+    family's ``serving_tick_cache`` over the ragged-paged-attention
+    Pallas kernel): each live slot's decode token AND up to a per-tick
+    token budget of pending prompt spans run in the same launch, with
     sequence geometry (span lengths, cache lengths, page tables)
     carried as device arrays. Prompt length, chunk position and
-    attached-prefix size are DATA, not compile shapes — the pre-r12
-    geometry quantization (prompt buckets, chunk grids, attach quanta)
-    is gone and the recompile-hazard pass proves the whole engine
-    compiles 1-2 programs per packed width;
+    attached-prefix size are DATA, not compile shapes, and the
+    recompile-hazard pass proves the whole engine compiles 1-2
+    programs per packed width;
   - ``prefill_chunk=N`` caps the per-tick prefill token budget (its
     scheduling role — bounded inter-token stall for in-flight streams
     while long prompts are absorbed); it no longer affects what
@@ -85,25 +84,6 @@ def _env_flag(name: str, default: bool) -> bool:
     return raw.strip().lower() in ("1", "true", "yes", "on")
 
 
-def _resolve_model(model, cfg):
-    if model is not None and not isinstance(model, str):
-        return model  # module-like: init_serving_pages/prefill/decode
-    name = model or type(cfg).__name__
-    if "llama" in name.lower():
-        from ..models import llama
-        return llama
-    if "qwen2moe" in name.lower().replace("_", ""):
-        from ..models import qwen2_moe
-        return qwen2_moe
-    if "lfm2moe" in name.lower().replace("_", ""):
-        from ..models import lfm2_moe
-        return lfm2_moe
-    raise ValueError(
-        f"cannot infer serving model from {name!r}; pass model='llama', "
-        "'qwen2_moe', 'lfm2_moe', or a module exposing "
-        "init_serving_pages/serving_tick_cache/serving_tick_block_cache")
-
-
 def _cache_kinds(mod, cfg) -> tuple:
     """What the model's layer kinds keep between ticks
     (``serving_cache_kinds(cfg)``); a model that declares none is of
@@ -127,12 +107,14 @@ def _jit_step_fns(mod, cfg, attn_impl: str, rewrites: bool = False):
     engines over one config (tests, blue/green restarts) reuse the same
     jit objects, so XLA's executable cache carries across instances.
 
-    Exactly TWO step functions serve everything (the one-program-tick
-    design, ISSUE r12): ``serving_tick`` — any mix of decode tokens and
-    prompt spans as one ragged program (one compile per packed width;
-    widths come from the engine's small width grid — see
-    ``ServingEngine._w_grid``) — and ``serving_tick_block`` — the
-    fused multi-step greedy decode path.
+    Exactly TWO step functions serve everything, one over each of the
+    family's two step entry points (``init_serving_pages`` built the
+    cache they take): ``serving_tick`` (``mod.serving_tick_cache``) —
+    any mix of decode tokens and prompt spans as one ragged program
+    (one compile per packed width; widths come from the engine's small
+    width grid — see ``ServingEngine._w_grid``) — and
+    ``serving_tick_block`` (``mod.serving_tick_block_cache``) — the
+    fused multi-step decode path.
 
     ``rewrites=True`` routes every step function through the analysis
     subsystem's verified rewrite passes (analysis/rewrite.py) before
@@ -227,8 +209,8 @@ class ServingEngine:
     prefix_cache: True (default) keeps full prompt-KV pages registered
     across requests (refcounted; LRU-evicted under page pressure) so a
     shared prompt prefix is prefilled once — and attached EXACTLY: any
-    cached page count, no attach quantum (prefix size is data to the
-    ragged tick, not a compile shape). Greedy outputs stay
+    cached page count (prefix size is data to the ragged tick, not a
+    compile shape). Greedy outputs stay
     byte-identical to ``generate()`` whether a prefix was cached,
     partially cached, or cold (tests/test_prefix_cache.py).
     prefill_chunk: per-tick prefill token budget. None (default)
@@ -398,7 +380,8 @@ class ServingEngine:
         self._decode_block = int(decode_block_size)
         self._params = params
         self._cfg = cfg
-        self._mod = _resolve_model(model, cfg)
+        from ..models import resolve_family
+        self._mod = resolve_family(model, cfg)
         # a layer kind that keeps a fixed row a slot (not pages) holds
         # state that a prefix's pages cannot rebuild: no snapshots exist
         # yet, so what attaches, moves or rolls back pages is off
@@ -421,10 +404,9 @@ class ServingEngine:
         if total_pages is None:
             total_pages = max_batch * pages_per_slot + 1
         self.pool = PagePool(total_pages=total_pages, page_size=page_size)
-        # EXACT prefix attach (attach_quantum=1): cached-prefix size is
-        # carried to the ragged tick as data, so any page count costs
-        # zero extra compiles — the r8-r11 attach-quantum compile-
-        # geometry machinery is deleted at the root (ISSUE r12)
+        # EXACT prefix attach: cached-prefix size is carried to the
+        # ragged tick as data, so any page count costs zero extra
+        # compiles
         self.prefix_cache = PrefixCache(self.pool) if prefix_cache \
             else None
         self._chunk = prefill_chunk
@@ -448,7 +430,7 @@ class ServingEngine:
         # is 40 tokens must not pay the 256-wide cold program). This
         # pads the program like any jit bucket pad — geometry stays
         # data (span offsets, prefix sizes, cache lengths), so it has
-        # no exactness role, unlike the deleted chunk/attach quanta.
+        # no exactness role.
         # With speculation on, spec spans add up to S*(1+spec_k)
         # tokens on top of the prefill budget: the grid grows two
         # entries (the all-slots-drafting width and the combined
@@ -474,9 +456,7 @@ class ServingEngine:
                                           program_inventory)
         geom = ServingGeometry(
             page_size=page_size, pages_per_slot=pages_per_slot,
-            buckets=list(self._buckets),
-            attach_quantum=1 if self.prefix_cache is not None else 0,
-            prefill_chunk=prefill_chunk, ragged=True,
+            buckets=list(self._buckets), prefill_chunk=prefill_chunk,
             max_batch=max_batch, decode_block=self._decode_block,
             spec_k=self._spec_k)
         # the static proof's inventory, kept on the engine: the

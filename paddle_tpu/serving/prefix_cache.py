@@ -192,18 +192,9 @@ class PrefixCache:
     with the same plan applied to the pool arrays.
     """
 
-    def __init__(self, pool, attach_quantum: int = 1):
+    def __init__(self, pool):
         self.pool = pool
         self.page_size = int(pool.page_size)
-        # acquire() attaches only multiples of this many pages: the
-        # chunk program's gathered-prefix width (prefix_pages) is a
-        # STATIC compile dimension, so unrestricted attach counts mean
-        # one XLA compile per distinct cached-prefix length — a compile
-        # storm inside the serving tick under diverse traffic. Quantum q
-        # bounds the value set at pps/q while giving up at most q-1
-        # pages of reuse per request. The trie still CACHES at full
-        # page granularity; only attachment is quantized.
-        self.attach_quantum = max(1, int(attach_quantum))
         self._root = _Node((), None, -1, 0)
         self._nodes = set()                 # every cached node
         self._tick = itertools.count(1)
@@ -254,13 +245,11 @@ class PrefixCache:
         return len(self._walk(prompt, self._max_pages(len(prompt))))
 
     def acquire(self, prompt) -> List[_Node]:
-        """Longest cached page-aligned prefix of ``prompt`` — truncated
-        to a multiple of ``attach_quantum`` pages — with every attached
-        node's refcount bumped (pinned against eviction). The caller
-        owns one release() per acquire()."""
+        """Longest cached page-aligned prefix of ``prompt``, any page
+        count (the attached size reaches the tick as data), with every
+        attached node's refcount bumped (pinned against eviction). The
+        caller owns one release() per acquire()."""
         nodes = self._walk(prompt, self._max_pages(len(prompt)))
-        q = self.attach_quantum
-        nodes = nodes[:(len(nodes) // q) * q]
         t = next(self._tick)
         for nd in nodes:
             nd.refs += 1

@@ -85,3 +85,35 @@ def _seed_all():
     pt.seed(2024)
     np.random.seed(2024)
     yield
+
+
+def _span_tick(mod, params, cfg, cache, tables, slot, toks, pos0, width,
+               **kw):
+    """ONE ``serving_tick_cache`` call of family ``mod`` whose only query
+    rows are ``slot``'s span ``toks`` at positions ``pos0..``, packed at
+    the front of a ``width``-token stream (the rest is padding): what
+    the engine sends for one chunk of one prompt. ``tables [S, pps]``
+    and the cache's page size place the rows. Returns the tick's
+    results."""
+    import jax.numpy as jnp
+    S, ps, n = tables.shape[0], cache["k_pages"].shape[-2], len(toks)
+    tok = np.zeros((width,), np.int32)
+    tok[:n] = toks
+    real = np.arange(width) < n
+    pos = np.where(real, pos0 + np.arange(width), 0)
+    meta = dict(
+        tok_slot=np.where(real, slot, S), tok_pos=pos,
+        tok_page=np.where(real, tables[slot, pos // ps], 0),
+        tok_off=np.where(real, pos % ps, 0),
+        tok_qoff=np.where(real, np.arange(width), 0),
+        q_len=np.where(np.arange(S) == slot, n, 0),
+        kv_len=np.where(np.arange(S) == slot, pos0 + n, 0),
+        last=np.full((S,), n - 1), tables=tables)
+    meta = {k: jnp.asarray(v, jnp.int32) for k, v in meta.items()}
+    return mod.serving_tick_cache(params, jnp.asarray(tok), meta, cache,
+                                  cfg, tq=width, **kw)
+
+
+@pytest.fixture(scope="session")
+def span_tick():
+    return _span_tick

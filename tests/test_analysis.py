@@ -30,7 +30,6 @@ from paddle_tpu.analysis import (TRAIN_GEOMETRIES,
                                  audit_serving_state,
                                  check_stage_consistency,
                                  collective_signature, engine_geometry,
-                                 enumerate_chunk_programs,
                                  estimate_hbm_peak,
                                  flagship_train_objects,
                                  jit_donation_flags, pp_stage_targets,
@@ -39,7 +38,8 @@ from paddle_tpu.analysis import (TRAIN_GEOMETRIES,
                                  train_stage_targets, train_step_target,
                                  training_targets, xla_peak_bytes)
 from paddle_tpu.inference.paged_kv import PagePool, apply_defrag
-from paddle_tpu.models import llama as L
+from paddle_tpu.models import (SERVING_FAMILIES, llama as L,
+                               resolve_family)
 from paddle_tpu.serving import PrefixCache, ServingEngine
 
 sds = jax.ShapeDtypeStruct
@@ -73,6 +73,65 @@ def test_flagship_serving_graphs_lint_clean(model):
     assert any(f.pass_name == "recompile-hazard"
                and "proven bound" in f.message
                for f in report.findings)
+
+
+_THREE = ("init_serving_pages", "serving_tick_cache",
+          "serving_tick_block_cache")
+
+
+@pytest.mark.parametrize("program", ["serving_tick[mixed]",
+                                     "serving_tick_block[k=4]"])
+@pytest.mark.parametrize("model", sorted(SERVING_FAMILIES))
+def test_serving_targets_trace_a_family_through_its_three_functions(
+        model, program, monkeypatch):
+    """Analysis reaches a family as the engine does: the cache pytree of
+    ``init_serving_pages`` through ``serving_tick_cache`` /
+    ``serving_tick_block_cache``, abstractly (zero compiles), with the
+    whole cache donated back. Fails when a family grows a fourth
+    serving entry point or analysis a private one."""
+    from paddle_tpu.observability import RecompileSentinel
+    from paddle_tpu.serving.engine import _cache_kinds
+    mod = resolve_family(model)
+    public = {n for n in dir(mod) if "serving" in n
+              and not n.startswith("_") and callable(getattr(mod, n))}
+    assert public - {"serving_cache_kinds"} == set(_THREE)
+    calls = dict.fromkeys(_THREE, 0)
+    caches = []
+
+    def spy(name):
+        fn = getattr(mod, name)
+
+        def wrapped(*a, **kw):
+            calls[name] += 1
+            out = fn(*a, **kw)
+            if name == "init_serving_pages":
+                caches.append(out)
+            return out
+        return wrapped
+
+    for name in _THREE:
+        monkeypatch.setattr(mod, name, spy(name))
+    sentinel = RecompileSentinel()
+    try:
+        targets = {t.name: t for t in serving_targets(model)}
+    finally:
+        sentinel.close()
+    assert sentinel.warmup_compiles == 0
+    assert all(calls.values()), calls
+    target = targets[f"{model}.{program}"]
+    # the results end with the family's whole cache, donated
+    leaves = jax.tree_util.tree_leaves(caches[0])
+    outs = target.jaxpr.jaxpr.outvars
+    assert target.donated_outputs == tuple(
+        range(len(outs) - len(leaves), len(outs)))
+    assert [(o.aval.shape, o.aval.dtype) for o in outs[-len(leaves):]] \
+        == [(x.shape, x.dtype) for x in leaves]
+    # a verify target only where the family can verify: what its layer
+    # kinds keep tells, not its name
+    cfg_cls = getattr(mod, SERVING_FAMILIES[model])
+    stateful = any(k.cache == "slot_rows"
+                   for k in _cache_kinds(mod, cfg_cls.tiny()))
+    assert any("[verify" in n for n in targets) == (not stateful)
 
 
 def test_pp_stage_chunks_consistent():
@@ -289,14 +348,12 @@ def test_recompile_enumeration_matches_live_engine_geometry(params):
     with ServingEngine(params, CFG, max_batch=2, **kw) as eng:
         live = ServingGeometry.of_engine(eng)
     assert engine_geometry(max_batch=2, **kw) == live
-    assert live.ragged and live.attach_quantum == 1
 
 
 def test_recompile_pass_proves_flagship_bound_and_flags_hazard():
-    """The ragged engine's program set is 1-2 per packed-width bucket
-    BY CONSTRUCTION; the legacy bucketed model (still the oracle for
-    the retained bucketed step fns) keeps flagging its hazard class,
-    now with the offending value set spelled out."""
+    """The engine's program set is 1-2 per packed-width bucket BY
+    CONSTRUCTION; a bound the dispatch does not keep is an ERROR that
+    spells the offending program set out."""
     from paddle_tpu.analysis import enumerate_tick_programs
     good = engine_geometry(page_size=4, max_prompt_len=16,
                            max_new_tokens_cap=16, prefill_chunk=8,
@@ -311,28 +368,19 @@ def test_recompile_pass_proves_flagship_bound_and_flags_hazard():
     assert not _errors(found)
     assert any("proven bound" in f.message for f in found)
 
-    # seeded hazard through the LEGACY model: quantum 1 with a large
-    # prompt/slot budget — the pre-r9 failure mode (attach grid off
-    # the chunk grid); the error now carries the offending value set
-    bad = ServingGeometry(page_size=8, pages_per_slot=40,
-                          buckets=[32, 64, 128, 256],
-                          attach_quantum=1, prefill_chunk=32)
-    over = enumerate_chunk_programs(bad)
-    assert any(len(v) > 16 for v in over.values())
-    t = trace_graph("geom", lambda x: x, (sds((1,), jnp.float32),),
-                    meta={"geometry": bad})
-    errs = _errors(RecompileHazardPass().run(t))
-    assert errs and "prefix_pages" in errs[0].message
-    worst = max(over.values(), key=len)
-    assert str(sorted(worst)) in errs[0].message  # offending set named
+    # seeded hazard: held to ONE program a bucket, the mixed width's
+    # tail / no-tail pair is over the bound; the error names the set
+    errs = _errors(RecompileHazardPass(ragged_limit=1).run(t_good))
+    assert len(errs) == 1 and "tick width 12" in errs[0].message
+    assert str(sorted(progs[12])) in errs[0].message
 
 
 def test_engine_geometry_hazard_died_with_quantization(params):
-    """The pre-r12 compile-storm geometry (tiny chunk against a big
-    prompt budget — 38 programs where ≤16 was claimed) now compiles
-    the SAME two programs as any other geometry: the ctor enumeration
-    stays silent because the hazard is gone at the root, not because
-    the check was dropped."""
+    """A tiny chunk against a big prompt budget (once a compile storm:
+    one program per static prefix size) compiles the SAME two programs
+    as any other geometry: the ctor enumeration stays silent because
+    prefix size and chunk position are data, not because the check was
+    dropped."""
     import warnings
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
@@ -345,12 +393,6 @@ def test_engine_geometry_hazard_died_with_quantization(params):
     from paddle_tpu.analysis import enumerate_tick_programs
     progs = enumerate_tick_programs(geom)
     assert all(len(v) <= 2 for v in progs.values())
-    # the legacy dispatch model confirms this geometry WAS the hazard
-    legacy = ServingGeometry(
-        page_size=geom.page_size, pages_per_slot=geom.pages_per_slot,
-        buckets=geom.buckets, attach_quantum=1, prefill_chunk=4)
-    assert any(len(v) > 16
-               for v in enumerate_chunk_programs(legacy).values())
 
 
 def test_graph_lint_json_reports_serving_program_set(capsys):
@@ -390,18 +432,16 @@ def test_graph_lint_json_reports_serving_program_set(capsys):
 
 
 def test_prefix_attach_is_exact(params):
-    """r12: attach quantum is gone — the engine attaches EVERY cached
-    full page (cap floor((n-1)/ps) only), whatever the chunk size."""
+    """The engine attaches EVERY cached full page (cap floor((n-1)/ps)
+    only), whatever the chunk size."""
     with ServingEngine(params, CFG, max_batch=2, page_size=4,
                        max_prompt_len=16, max_new_tokens_cap=16,
                        prefill_chunk=8) as eng:
-        assert eng.prefix_cache.attach_quantum == 1
         prompt = np.arange(1, 16, dtype=np.int32)      # 15 tokens
         eng.submit(prompt, 4).result(timeout=300)
         eng.submit(prompt, 4).result(timeout=300)
         c = eng.stats()["counters"]
-    # floor(14/4) = 3 pages = 12 tokens attach — the r8-r11 quantum
-    # (chunk grid: 2 pages) would have attached only 2
+    # floor(14/4) = 3 pages = 12 tokens attach, past the chunk (2 pages)
     assert c["prefix_pages_saved"] == 3
     assert c["prefix_hit_tokens"] == 12
 

@@ -196,7 +196,7 @@ def test_ragged_head_size_64_enters_lane_packed(chip, monkeypatch, tq):
 
 
 def _mistral_tick_shapes(tq, layers, pages):
-    """``serving_tick``'s operands at Mistral-7B-v0.3 widths (the
+    """``serving_tick_cache``'s operands at Mistral-7B-v0.3 widths (the
     Llama-3-8B ones above but for the vocabulary) and the chat cell's
     slots and table width, cut to ``layers`` layers and ``pages``
     pages."""
@@ -218,7 +218,8 @@ def _mistral_tick_shapes(tq, layers, pages):
                 top_p=f32((S,)), top_k=i32((S,)),
                 key=sds((S, 2), jnp.uint32), produced=i32((S,)))
     pool = sds((layers, HKV, pages, PAGE, DH))
-    return cfg, (params, i32((T,)), meta, pool, pool)
+    return cfg, (params, i32((T,)), meta,
+                 {"k_pages": pool, "v_pages": pool})
 
 
 _HLO_RESULT = re.compile(
@@ -275,11 +276,11 @@ def test_serving_tick_holds_the_pool_once(chip, monkeypatch, tq):
     layers, pages = 2, CHAT["pages"]
     cfg, shapes = _mistral_tick_shapes(tq, layers, pages)
 
-    def serving_tick(params, tokens, meta, k_pages, v_pages):
-        return L.serving_tick(params, tokens, meta, k_pages, v_pages, cfg,
-                              tq=tq)
+    # the pool as ONE donated pytree, as the engine's jitted wrapper has it
+    def serving_tick(params, tokens, meta, cache):
+        return L.serving_tick_cache(params, tokens, meta, cache, cfg, tq=tq)
 
-    text = chip(serving_tick, *shapes, donate=(3, 4))
+    text = chip(serving_tick, *shapes, donate=(3,))
     from chip_smoke import kernels_in
     assert kernels_in(text.compiled)["ragged_paged_attention"] == 1
     layer_pages = HKV * pages * PAGE * DH * 2     # bytes of one layer's K
@@ -332,12 +333,6 @@ def _paged_kv_args():
     pages = sds((HKV, B * pps, page, DH))
     return (sds((B, H, DH)), pages, pages, sds((B,), jnp.int32),
             sds((B, pps), jnp.int32))
-
-
-def test_paged_kv_paged_attention(chip):
-    from paddle_tpu.inference.paged_kv import paged_attention
-    chip(functools.partial(paged_attention, impl="pallas"),
-         *_paged_kv_args())
 
 
 def test_paged_kv_stats_call(chip):

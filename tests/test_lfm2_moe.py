@@ -416,9 +416,12 @@ def test_engine_refuses_chain_migration_for_a_stateful_model(call):
 
 
 def test_engine_resolves_the_model_by_name_and_by_config():
-    from paddle_tpu.serving.engine import _resolve_model
-    assert _resolve_model("lfm2_moe", None) is M
-    assert _resolve_model(None, M.Lfm2MoeConfig.tiny()) is M
+    from paddle_tpu.models import resolve_family
+    assert resolve_family("lfm2_moe") is M
+    assert resolve_family(None, M.Lfm2MoeConfig.tiny()) is M
+    assert resolve_family(M) is M
+    with pytest.raises(ValueError, match="serving_tick_block_cache"):
+        resolve_family("no_such_family")
 
 
 # ------------------------------------------------------------------- cut ----

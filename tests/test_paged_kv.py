@@ -13,8 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.inference.paged_kv import (
-    PagePool, paged_attention, write_prompt_pages, write_token_pages)
+from paddle_tpu.inference.paged_kv import PagePool
 from paddle_tpu.models import llama as L
 
 
@@ -24,7 +23,7 @@ def _cfg(**kw):
 
 
 # ---------------------------------------------------------------------------
-# pool + page writes
+# pool
 # ---------------------------------------------------------------------------
 
 def test_page_pool_alloc_free_exhaust():
@@ -36,61 +35,6 @@ def test_page_pool_alloc_free_exhaust():
         pool.alloc(2)
     pool.free(a)
     assert pool.free_pages == 4
-
-
-def test_write_token_and_prompt_pages_roundtrip():
-    Hkv, P, ps, Dh = 2, 5, 4, 8
-    kp = jnp.zeros((Hkv, P, ps, Dh))
-    vp = jnp.zeros((Hkv, P, ps, Dh))
-    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)   # B=2, pps=2
-    # prompt write: lens (5, 3) into a T0=6 padded prompt
-    k = jnp.arange(2 * 6 * Hkv * Dh, dtype=jnp.float32).reshape(2, 6, Hkv, Dh)
-    lens = jnp.asarray([5, 3], jnp.int32)
-    kp2, vp2 = write_prompt_pages(kp, vp, k, k, lens, tables)
-    # token t of seq b lives at pages[tables[b, t//ps], t%ps]
-    np.testing.assert_allclose(np.asarray(kp2[:, 1, 2]),      # b0 t2
-                               np.asarray(k[0, 2]))
-    np.testing.assert_allclose(np.asarray(kp2[:, 2, 0]),      # b0 t4
-                               np.asarray(k[0, 4]))
-    np.testing.assert_allclose(np.asarray(kp2[:, 3, 2]),      # b1 t2
-                               np.asarray(k[1, 2]))
-    # beyond-len tokens went to the trash page, not seq pages
-    assert np.all(np.asarray(kp2[:, 4, 0]) == 0)              # b1 t4 unset
-    # decode token append at position lens[b]
-    kt = jnp.full((2, Hkv, Dh), 7.0)
-    kp3, _ = write_token_pages(kp2, vp2, kt, kt, lens, tables)
-    np.testing.assert_allclose(np.asarray(kp3[:, 2, 1]), 7.0)  # b0 pos5
-    np.testing.assert_allclose(np.asarray(kp3[:, 3, 3]), 7.0)  # b1 pos3
-
-
-# ---------------------------------------------------------------------------
-# paged attention semantics == dense cached attention
-# ---------------------------------------------------------------------------
-
-def test_paged_attention_matches_dense_cache():
-    B, H, Hkv, Dh, ps, pps = 2, 4, 2, 8, 4, 3
-    S = ps * pps
-    rng = jax.random.PRNGKey(0)
-    kq, kk, kv = jax.random.split(rng, 3)
-    q = jax.random.normal(kq, (B, H, Dh))
-    kd = jax.random.normal(kk, (B, S, Hkv, Dh))   # dense layout
-    vd = jax.random.normal(kv, (B, S, Hkv, Dh))
-    lens = jnp.asarray([7, 11], jnp.int32)
-    # build the paged layout holding the same values
-    kp = jnp.zeros((Hkv, B * pps + 1, ps, Dh))
-    vp = jnp.zeros((Hkv, B * pps + 1, ps, Dh))
-    tables = (1 + np.arange(B * pps).reshape(B, pps)).astype(np.int32)
-    kp, vp = write_prompt_pages(kp, vp, kd, vd, lens, jnp.asarray(tables))
-    out_p = paged_attention(q, kp, vp, lens, jnp.asarray(tables),
-                            impl="dense")
-    # dense reference: _cached_attention with pos0 = lens-1 per sequence
-    outs = []
-    for b in range(B):
-        o = L._cached_attention(q[b:b + 1, None], kd[b:b + 1],
-                                vd[b:b + 1], int(lens[b]) - 1, _cfg())
-        outs.append(o[0, 0])
-    np.testing.assert_allclose(np.asarray(out_p), np.asarray(jnp.stack(outs)),
-                               atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

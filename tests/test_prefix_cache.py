@@ -4,10 +4,11 @@ Correctness bar: greedy engine outputs stay BYTE-IDENTICAL to
 standalone ``generate()`` whether a prompt's prefix was cached,
 partially cached, or cold, and whether its suffix was prefilled whole
 or in page-aligned chunks interleaved with decode. The enabling claim
-— the chunk program (gathered prefix pages ++ in-graph chunk, bottom-
-right causal flash) produces bitwise-identical KV and logits to the
-whole-prompt program — is pinned at the model layer first, then
-through the engine in every cache state.
+— a prompt's span split over several ticks (each scattering its KV
+into the pages, then attending over pages only, bottom-right causal)
+produces bitwise-identical KV and logits to one tick carrying the whole
+span — is pinned at the model layer first, then through the engine in
+every cache state.
 """
 import time
 
@@ -53,46 +54,35 @@ def _engine(params, **kw):
 
 
 # ---------------------------------------------------------------------------
-# model layer: the chunk program is bitwise-equal to the whole-prompt one
+# model layer: chunked spans are bitwise-equal to the whole-prompt span
 # ---------------------------------------------------------------------------
 
-def test_chunked_prefill_bitwise_matches_whole_prompt(params):
-    """Cold chunked prefill (two page-aligned chunks) must write the
-    SAME KV bits and produce the SAME last-position logits as one
-    whole-prompt serving_prefill — the exactness foundation everything
-    engine-level rests on."""
-    ps, n = 4, 11
+def test_chunked_prefill_bitwise_matches_whole_prompt(params, span_tick):
+    """Cold chunked prefill (an 8-row and a 3-row tick) must write the
+    SAME KV bits and produce the SAME last-position logits as ONE tick
+    carrying the whole 11-row span — the exactness foundation
+    everything engine-level rests on."""
+    ps, n, width = 4, 11, 12
     rng = np.random.RandomState(0)
     prompt = rng.randint(0, CFG.vocab_size, (n,)).astype(np.int32)
-    pools = L.init_serving_pages(CFG, 16, ps)
-    table = np.zeros((8,), np.int32)
-    table[:4] = [1, 2, 3, 4]
+    tables = np.zeros((1, 8), np.int32)
+    tables[0, :4] = [1, 2, 3, 4]
 
-    pad = np.zeros((1, 16), np.int32)
-    pad[0, :n] = prompt
-    lg_full, kp_f, vp_f = L.serving_prefill(
-        params, jnp.asarray(pad), jnp.int32(n), jnp.asarray(table),
-        jnp.array(pools["k_pages"]), jnp.array(pools["v_pages"]), CFG)
+    def tick(cache, lo, hi):
+        _, logits, cache = span_tick(L, params, CFG, cache, tables, 0,
+                                     prompt[lo:hi], lo, width)
+        return np.asarray(logits)[0], cache
 
-    c0 = np.zeros((1, 8), np.int32)
-    c0[0] = prompt[:8]
-    _, kp_c, vp_c = L.serving_prefill_chunk(
-        params, jnp.asarray(c0), jnp.int32(8), jnp.asarray(table),
-        jnp.array(pools["k_pages"]), jnp.array(pools["v_pages"]), CFG,
-        prefix_pages=0)
-    c1 = np.zeros((1, 8), np.int32)
-    c1[0, :3] = prompt[8:]
-    lg_chunk, kp_c, vp_c = L.serving_prefill_chunk(
-        params, jnp.asarray(c1), jnp.int32(3), jnp.asarray(table),
-        kp_c, vp_c, CFG, prefix_pages=2)
+    lg_full, whole = tick(L.init_serving_pages(CFG, 16, ps), 0, n)
+    _, chunked = tick(L.init_serving_pages(CFG, 16, ps), 0, 8)
+    lg_chunk, chunked = tick(chunked, 8, n)
 
-    np.testing.assert_array_equal(np.asarray(lg_full),
-                                  np.asarray(lg_chunk))
+    np.testing.assert_array_equal(lg_full, lg_chunk)
     # pages 1..3 hold the prompt's 11 valid positions (page 3 partially)
-    np.testing.assert_array_equal(np.asarray(kp_f)[:, :, 1:4],
-                                  np.asarray(kp_c)[:, :, 1:4])
-    np.testing.assert_array_equal(np.asarray(vp_f)[:, :, 1:4],
-                                  np.asarray(vp_c)[:, :, 1:4])
+    for pool in ("k_pages", "v_pages"):
+        np.testing.assert_array_equal(
+            np.asarray(whole[pool])[:, :, 1:4],
+            np.asarray(chunked[pool])[:, :, 1:4])
 
 
 # ---------------------------------------------------------------------------
@@ -285,21 +275,6 @@ def test_prefix_cache_trie_acquire_insert_release():
     pc.release(adopted)       # drop the insert-time ownership: refs 0
     with pytest.raises(AssertionError):
         pc.release(adopted)   # refcount underflow is loud, not silent
-
-
-def test_prefix_cache_attach_quantum_bounds_compile_shapes():
-    """attach_quantum=q truncates attachment to multiples of q pages
-    (bounding the chunk program's static prefix_pages value set); the
-    trie still caches every full page."""
-    pool = PagePool(total_pages=16, page_size=2)
-    pc = PrefixCache(pool, attach_quantum=2)
-    prompt = _toks(1, 2, 3, 4, 5, 6, 7)     # 3 full pages + 1 tail
-    nodes = pc.insert(prompt, [], pool.alloc(3))[0]
-    assert pc.cached_pages == 3             # caching is NOT quantized
-    got = pc.acquire(prompt)                # match 3 -> attach 2
-    assert len(got) == 2
-    pc.release(got)
-    pc.release(nodes)
 
 
 def test_prefix_cache_insert_dedups_concurrent_identical_prompts():

@@ -298,8 +298,8 @@ def test_flagship_rewrite_suite_clean():
     errs = [f for f in findings if f.severity == Severity.ERROR]
     assert not errs, [str(f) for f in errs]
     by_graph = {row["graph"]: row for row in table}
-    int8 = by_graph["llama.serving_decode_step[int8-unfused]"]
-    # every projection in the 2-layer step dequantizes unfused: q/k/v/o
+    int8 = by_graph["llama.serving_tick[int8-unfused]"]
+    # every projection in the 2-layer tick dequantizes unfused: q/k/v/o
     # + gate/up/down per layer land on the stacked per-layer weights
     # (scan body counts once) + lm_head
     assert int8["fired"]["int8-epilogue-fuse"] >= 2

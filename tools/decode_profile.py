@@ -37,16 +37,9 @@ rewrite deltas. This is the acceptance A/B for the optimizer passes:
 the rewritten graph must beat the unfused baseline and land at (or
 within noise of) the hand fusion it reproduces.
 
-``ragged`` adds the r12 serving-tick A/B: one serving-batch decode
-step (S=8 slots on a paged pool) measured through the one-program
-ragged tick (``serving_tick_block`` at num_steps=1) and the legacy
-``serving_decode_step`` it replaced — fresh function object per
-variant (the r11 trace-cache lesson) — reporting XLA flops/bytes per
-step and the slope-timed ratio.
-
 ``trace=out.json`` records one observability span per measured section
-(per-variant whole-step / tail / embed slope chains, the rewrite and
-ragged A/B arms) and exports them as Perfetto-loadable Chrome-trace
+(per-variant whole-step / tail / embed slope chains, the rewrite
+A/B arms) and exports them as Perfetto-loadable Chrome-trace
 JSON — the same exporter ``serving_bench --trace`` uses, so a profile
 session and a serving run read in the same UI.
 
@@ -62,7 +55,7 @@ spec_ab``.
 
 Usage:
   python tools/decode_profile.py [flagship|deep|mid|tiny] [int8] [json]
-      [rewrites] [ragged] [trace=out.json] [bw=819e9] [steps=64]
+      [rewrites] [trace=out.json] [bw=819e9] [steps=64]
       [spec_draft_cost=0.0]
 
 ``flagship`` is the 1.72B bench model (TPU-sized; expect minutes per
@@ -416,77 +409,6 @@ def rewrite_ab(params, cfg, steps, prompt_len=32):
     return ab
 
 
-def ragged_step_ab(params, cfg, steps, S=8, ctx=48, page_size=16):
-    """The ragged-tick decode A/B (ISSUE r12): one serving-batch decode
-    step measured two ways on identical state — the r12 one-program
-    tick (``serving_tick_block`` at num_steps=1, in-graph argmax) and
-    the legacy ``serving_decode_step`` it replaced. Each variant gets a
-    FRESH function object (the r11 trace-cache lesson: jax keys traces
-    on function identity, and a shared wrapper would hand the second
-    variant the first one's jaxpr), is lowered for XLA's own
-    flops/bytes accounting, then slope-timed on a chained greedy run.
-    Neither variant donates the pools, so both pay the same copy —
-    the RATIOS are the signal, not the absolute ms."""
-    from paddle_tpu.analysis.hbm import xla_cost_analysis
-
-    pps = -(-(ctx + steps + 8) // page_size)
-    pools = L.init_serving_pages(cfg, S * pps + 1, page_size)
-    kp0, vp0 = pools["k_pages"], pools["v_pages"]
-    tables = jnp.asarray(
-        1 + np.arange(S * pps, dtype=np.int32).reshape(S, pps))
-    tok0 = jnp.zeros((S,), jnp.int32)
-    len0 = jnp.full((S,), ctx, jnp.int32)
-
-    def make_ragged():
-        def step(p, tok, lengths, kp, vp):
-            toks, kp, vp = L.serving_tick_block(
-                p, tok, lengths, tables, kp, vp, cfg, num_steps=1)
-            return toks[:, 0], lengths + 1, kp, vp
-        return step
-
-    def make_bucketed():
-        def step(p, tok, lengths, kp, vp):
-            logits, kp, vp = L.serving_decode_step(
-                p, tok, lengths, tables, kp, vp, cfg)
-            return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                    lengths + 1, kp, vp)
-        return step
-
-    n0 = max(steps // 4, 2)
-    n1 = max(steps, n0 + 4)
-
-    def measure(mk):
-        jitted = jax.jit(mk())
-        lowered = jitted.lower(params, tok0, len0, kp0, vp0)
-        ca = xla_cost_analysis(lowered.compile())
-
-        def run(n):
-            tok, lens, kp, vp = tok0, len0, kp0, vp0
-            for _ in range(n):
-                tok, lens, kp, vp = jitted(params, tok, lens, kp, vp)
-            int(np.asarray(tok)[0])
-
-        with _span(f"ragged_ab.{mk.__name__}"):
-            ms = slope(run, n0, n1) * 1e3
-        return {"step_ms": round(ms, 4),
-                "xla_flops": float(ca.get("flops", -1)),
-                "xla_bytes_accessed": float(ca.get("bytes accessed", -1))}
-
-    ab = {"slots": S, "ctx": ctx,
-          "ragged": measure(make_ragged),
-          "bucketed": measure(make_bucketed)}
-    rb, bb = (ab["ragged"]["xla_bytes_accessed"],
-              ab["bucketed"]["xla_bytes_accessed"])
-    rf, bf = ab["ragged"]["xla_flops"], ab["bucketed"]["xla_flops"]
-    if rb > 0 and bb > 0:
-        ab["bytes_vs_bucketed"] = round(rb / bb, 4)
-    if rf > 0 and bf > 0:
-        ab["flops_vs_bucketed"] = round(rf / bf, 4)
-    ab["time_vs_bucketed"] = round(
-        ab["ragged"]["step_ms"] / ab["bucketed"]["step_ms"], 4)
-    return ab
-
-
 def main():
     flags = set(sys.argv[1:])
     preset = next((f for f in flags if f in PRESETS), None)
@@ -547,9 +469,6 @@ def main():
     if "rewrites" in flags:
         with _span("rewrite_ab"):
             out["rewrite_ab"] = rewrite_ab(params, cfg, steps)
-    if "ragged" in flags:
-        with _span("ragged_step_ab"):
-            out["ragged_step_ab"] = ragged_step_ab(params, cfg, steps)
     if trace_path:
         out["trace"] = _TRACER.export(trace_path)
 
@@ -588,20 +507,6 @@ def main():
               f"{row['bw_ceiling_tok_per_s']:13.1f} | "
               f"{row['kv_tile_pages']:>10d} | {row['kv_tiles']:>5d} | "
               f"{row['vmem_scratch_bytes']:>12,}")
-    if "ragged_step_ab" in out:
-        ab = out["ragged_step_ab"]
-        print(f"\n# ragged tick A/B (serving decode step, "
-              f"S={ab['slots']}, ctx={ab['ctx']})")
-        print("variant    | step ms  | XLA flops/step | XLA bytes/step")
-        for tag in ("ragged", "bucketed"):
-            r = ab[tag]
-            print(f"{tag:10s} | {r['step_ms']:8.3f} | "
-                  f"{r['xla_flops']:>14,.0f} | "
-                  f"{r['xla_bytes_accessed']:>14,.0f}")
-        print(f"ragged vs bucketed: flops "
-              f"{ab.get('flops_vs_bucketed')}x, bytes "
-              f"{ab.get('bytes_vs_bucketed')}x, time "
-              f"{ab['time_vs_bucketed']}x")
     if "rewrite_ab" in out:
         ab = out["rewrite_ab"]
         print("\n# rewrite A/B (int8 decode step, unfused idiom)")

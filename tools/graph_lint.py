@@ -27,7 +27,7 @@ runs, in seconds and with zero XLA compiles:
     (analysis/training_graphs.py);
   * the REWRITE suite (analysis/rewrite.py): every registered rewrite
     pass applied to its flagship targets — the jnp-rmsnorm serving
-    graphs and the unfused-int8 decode step — with each expected
+    graphs and the unfused-int8 decode tick — with each expected
     rewrite required to fire, the rewriter required to be idempotent,
     and every fired site verified against its exactness contract
     (bitwise / pinned tolerance) on concrete seeded inputs;
@@ -77,14 +77,10 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
-def build_passes(limit: int):
-    from paddle_tpu.analysis import default_passes
-    return default_passes(**{"recompile-hazard": {"limit": limit}})
-
-
-def run_graph_passes(models, limit, suite="all"):
-    from paddle_tpu.analysis import (pp_stage_targets, run_passes,
-                                     serving_targets, training_targets)
+def run_graph_passes(models, suite="all"):
+    from paddle_tpu.analysis import (default_passes, pp_stage_targets,
+                                     run_passes, serving_targets,
+                                     training_targets)
     targets = []
     serving_pool = []
     if suite in ("all", "serving"):
@@ -94,7 +90,7 @@ def run_graph_passes(models, limit, suite="all"):
         targets += pp_stage_targets()
     if suite in ("all", "training"):
         targets += training_targets()
-    passes = build_passes(limit)
+    passes = default_passes()
     report = run_passes(passes, targets)
     hbm = next((p for p in passes if p.name == "hbm-peak"), None)
     return report, (hbm.reports if hbm is not None else {}), serving_pool
@@ -115,8 +111,6 @@ def main(argv=None):
     ap.add_argument("--models", nargs="+",
                     default=["llama", "qwen2_moe"],
                     help="flagship models to lint (serving suite)")
-    ap.add_argument("--limit", type=int, default=16,
-                    help="recompile-hazard programs-per-bucket bound")
     ap.add_argument("--suite",
                     choices=["all", "serving", "training", "rewrite",
                              "concurrency", "kernels"],
@@ -141,8 +135,7 @@ def main(argv=None):
     force_host_cpu_devices(8)
 
     t0 = time.time()
-    report, hbm, serving_pool = run_graph_passes(
-        args.models, args.limit, args.suite)
+    report, hbm, serving_pool = run_graph_passes(args.models, args.suite)
     rw_table = None
     if args.suite in ("all", "rewrite"):
         from paddle_tpu.analysis.rewrite import run_rewrite_suite
@@ -163,8 +156,7 @@ def main(argv=None):
         # gate on programs_per_bucket <= 2)
         from paddle_tpu.analysis.recompile import program_inventory
         geoms = [t.meta["geometry"] for t in serving_pool
-                 if t.meta.get("geometry") is not None
-                 and getattr(t.meta["geometry"], "ragged", False)]
+                 if t.meta.get("geometry") is not None]
         geom = next((g for g in geoms if not g.spec_k), None)
         if geom is not None:
             inventory = program_inventory(geom)

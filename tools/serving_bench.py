@@ -14,7 +14,9 @@ strategies over the SAME model params:
                       surface when the whole batch finishes;
   (c) engine        — `serving.ServingEngine` continuous batching:
                       per-step admission/retirement over the shared
-                      page pool, tokens streamed as decoded.
+                      page pool, tokens streamed as decoded (the model
+                      family's `init_serving_pages` /
+                      `serving_tick_cache` / `serving_tick_block_cache`).
 
 Reported per mode: wall_s, useful tok/s (only each request's OWN
 requested tokens count), time-to-first-token p50/p99 (ms), and mean
@@ -589,13 +591,11 @@ class Bench:
     def _admission_stall(self, chunk):
         """Max inter-token gap (ms) an in-flight VICTIM stream feels
         while a max-length intruder is admitted mid-stream — the
-        latency a user actually observes. Pre-r12 the admission's
-        prefill ran as a separate program BETWEEN decode ticks (the
-        engine's ``decode_stall_s`` histogram measured it directly);
-        the ragged one-program tick folds prefill INTO the tick, so
-        the between-tick gap is structurally ~0 and the felt latency
-        is the tick's own duration: chunking bounds it by capping the
-        per-tick prefill token budget (= the packed program width).
+        latency a user actually observes. The ragged one-program
+        tick folds prefill INTO the tick, so the between-tick gap is
+        structurally ~0 and the felt latency is the tick's own
+        duration: chunking bounds it by capping the per-tick prefill
+        token budget (= the packed program width).
         Median of 3 fresh-engine repeats (any single gap swings with
         co-tenant CPU load)."""
         rng = np.random.RandomState(self.args.seed + 2)
@@ -629,227 +629,6 @@ class Bench:
             eng.close()
             stalls.append(gap)
         return round(float(np.median(stalls)) * 1e3, 1)
-
-    # ------------------------------------------------- ragged vs bucketed --
-    def run_ragged_ab(self, trace):
-        """ISSUE r12 acceptance A/B: the one-program ragged tick vs the
-        legacy bucketed path (whole-prompt ``serving_prefill`` per
-        prompt bucket run BETWEEN ``serving_decode_block`` ticks — the
-        pre-r12 program structure, replayed synchronously over the same
-        Scheduler/PagePool). Three measurements in one JSON row:
-
-        * ``program_set`` — the STATIC program-set sizes both dispatch
-          models reach at this geometry under EXACT prefix attach
-          (attach_quantum=1, what the ragged tick gives for free), from
-          the recompile-hazard pass's two enumerations. Deterministic:
-          this is the structural claim, provable without running;
-        * per-arm replay stats over the same trace — tok/s, TTFT
-          p50/p99, measured per-decode-step latency, max between-tick
-          stall, and the MEASURED compile count (fresh jit objects per
-          arm, per the r11 trace-cache lesson);
-        * ``tick_latency_*`` — a controlled chained pure-decode A/B on
-          matched state (same slots, lengths, tables, pools, fused
-          block size): the parity number the slow test pins.
-        """
-        from paddle_tpu.analysis.recompile import (
-            enumerate_chunk_programs, enumerate_tick_programs)
-        from paddle_tpu.analysis.serving_graphs import engine_geometry
-        a = self.args
-        geom = engine_geometry(
-            page_size=a.page_size, max_prompt_len=a.max_prompt,
-            max_new_tokens_cap=self.mnt_cap,
-            prefill_chunk=a.prefill_chunk or None,
-            prompt_buckets=self.buckets, prefix_cache=True,
-            max_batch=a.max_batch, decode_block=a.decode_block)
-        tick_progs = enumerate_tick_programs(geom)
-        chunk_progs = enumerate_chunk_programs(geom)
-        ragged_set = sum(len(v) for v in tick_progs.values())
-        # + one whole-prompt prefill per bucket + the fused decode block
-        bucketed_set = (sum(len(v) for v in chunk_progs.values())
-                        + len(self.buckets) + 1)
-        ragged_worst = max((len(v) for v in tick_progs.values()),
-                           default=0)
-        bucketed_worst = max((len(v) for v in chunk_progs.values()),
-                             default=0)
-        rag = self._replay_ragged(trace)
-        buck = self._replay_bucketed(trace)
-        t_rag = self._tick_chain("ragged")
-        t_buck = self._tick_chain("bucketed")
-        ratio = t_rag / t_buck if t_buck > 0 else float("nan")
-        out = {
-            "mode": "ragged_ab",
-            "program_set": {"ragged": int(ragged_set),
-                            "bucketed": int(bucketed_set),
-                            "ragged_worst_per_bucket": int(ragged_worst),
-                            "bucketed_worst_per_bucket":
-                                int(bucketed_worst)},
-            "ragged": rag,
-            "bucketed": buck,
-            "tick_latency_ragged_ms": round(t_rag * 1e3, 3),
-            "tick_latency_bucketed_ms": round(t_buck * 1e3, 3),
-            "tick_latency_ratio": round(ratio, 3),
-            # the documented parity band (docs/PERF.md, pinned <=1.10
-            # by test_ragged_ab_acceptance)
-            "tick_parity": bool(ratio <= 1.10),
-        }
-        return out
-
-    def _replay_ragged(self, trace):
-        """The real engine over the trace, twice: a warm pass to pay
-        (and then count) the compiles, then a paced pass for the
-        latency/throughput stats. Fresh jit objects via a cleared step-
-        fn cache, so ``_cache_size`` counts THIS geometry's programs.
-
-        The warm pass submits SEQUENTIALLY, one bucket-length prompt at
-        the mnt cap per width-grid entry: each request runs alone, so
-        it exercises both its mixed-tick width AND the pure-decode
-        fused block (a flooded warm pass never reaches pure decode —
-        spans are always pending — and the block would then compile in
-        the middle of the measured pass).
-
-        Invariant checking stays OFF unless --check-invariants was
-        passed: the suite-wide env default would add per-tick audit
-        host work to the engine arm that the bucketed sim never pays,
-        skewing the A/B."""
-        from paddle_tpu.serving import engine as _em
-        _em._JIT_CACHE.clear()
-        check = self.args.check_invariants or False
-        eng = self._mk_engine(check_invariants=check)
-        rng = np.random.RandomState(self.args.seed + 3)
-        for b in self.buckets:
-            p = rng.randint(0, 256, (b,)).astype(np.int32)
-            eng.submit(p, self.mnt_cap).result(timeout=600)
-        eng.close()
-        # warm fns via the step-fn cache
-        eng = self._mk_engine(check_invariants=check)
-        t0 = time.perf_counter()
-        handles = []
-        for arrival, prompt, mnt in trace:
-            now = time.perf_counter() - t0
-            if now < arrival:
-                time.sleep(arrival - now)
-            handles.append(eng.submit(prompt, mnt))
-        outs = [h.result(timeout=600) for h in handles]
-        wall = time.perf_counter() - t0
-        snap = eng.stats()
-        compiles = (eng._tick_jit._cache_size()
-                    + eng._block_jit._cache_size())
-        eng.close()
-        useful = sum(len(o) for o in outs)
-        ttfts = [h.ttft_s for h in handles]
-        out = _report("ragged", wall, useful, ttfts)
-        out["decode_step_p50_ms"] = round(
-            snap["histograms"]["decode_step_s"]["p50"] * 1e3, 3)
-        st = snap["histograms"]["decode_stall_s"]
-        out["stall_max_ms"] = round(st["max"] * 1e3, 1) if st["count"] \
-            else 0.0
-        out["compiles"] = int(compiles)
-        return out
-
-    def _replay_bucketed(self, trace):
-        """The pre-r12 program structure, replayed synchronously: on
-        admission, ONE whole-prompt prefill program (right-padded to
-        its prompt bucket — one compile per bucket) runs between decode
-        ticks; decode is the fused ``serving_decode_block``. Same
-        Scheduler/PagePool, same admission policy, greedy only."""
-        import jax
-        from paddle_tpu.inference.paged_kv import PagePool
-        from paddle_tpu.serving.scheduler import (COMPLETED, Request,
-                                                  Scheduler)
-        jnp, Lm, a = self.jnp, self.L, self.args
-        k = a.decode_block
-        max_bucket = self.buckets[-1]
-        ps = a.page_size
-        pps = -(-(max_bucket + self.mnt_cap - 1) // ps)
-        prefill = jax.jit(partial(Lm.serving_prefill, cfg=self.cfg),
-                          donate_argnums=(4, 5))
-        block = jax.jit(partial(Lm.serving_decode_block, cfg=self.cfg),
-                        donate_argnums=(4, 5),
-                        static_argnames=("num_steps",))
-
-        def replay(paced):
-            pool = PagePool(total_pages=a.max_batch * pps + 1,
-                            page_size=ps)
-            sched = Scheduler(max_batch=a.max_batch, pages_per_slot=pps,
-                              pool=pool, max_prompt_len=max_bucket)
-            pools = Lm.init_serving_pages(self.cfg, pool.total_pages, ps)
-            kp, vp = pools["k_pages"], pools["v_pages"]
-            cur = np.zeros((a.max_batch,), np.int32)
-            produced = np.zeros((a.max_batch,), np.int64)
-            arrival_of, ttfts, steps = {}, [], []
-            useful = 0
-            stall_max, last_tick_end = 0.0, None
-            i = 0
-            t0 = time.perf_counter()
-            while True:
-                now = time.perf_counter() - t0
-                while i < len(trace) and (trace[i][0] <= now
-                                          or not paced):
-                    arr, prompt, mnt = trace[i]
-                    req = Request(prompt, mnt)
-                    sched.submit(req)
-                    arrival_of[id(req)] = arr
-                    i += 1
-                for slot, req in sched.admit():
-                    n = req.prompt.size
-                    tb = _bucket(n, self.buckets)
-                    padded = np.zeros((1, tb), np.int32)
-                    padded[0, :n] = req.prompt
-                    logits, kp, vp = prefill(
-                        self.params, jnp.asarray(padded), jnp.int32(n),
-                        jnp.asarray(sched.tables[slot]), kp, vp)
-                    tok = int(np.argmax(np.asarray(logits)))
-                    sched.lengths[slot] = n
-                    cur[slot] = tok
-                    produced[slot] = 1
-                    useful += 1
-                    ttfts.append(time.perf_counter() - t0
-                                 - arrival_of[id(req)])
-                    if produced[slot] >= req.max_new_tokens:
-                        sched.retire(slot, COMPLETED)
-                        produced[slot] = 0
-                live = sched.live()
-                if live:
-                    td0 = time.perf_counter()
-                    toks, kp, vp = block(
-                        self.params, jnp.asarray(cur),
-                        jnp.asarray(sched.lengths),
-                        jnp.asarray(sched.tables), kp, vp, num_steps=k)
-                    toks = np.asarray(toks)
-                    td1 = time.perf_counter()
-                    steps.append((td1 - td0) / k)
-                    if last_tick_end is not None:
-                        stall_max = max(stall_max, td0 - last_tick_end)
-                    last_tick_end = td1
-                    for slot, req in live:
-                        sched.lengths[slot] += k
-                        for j in range(k):
-                            cur[slot] = int(toks[slot, j])
-                            produced[slot] += 1
-                            useful += 1
-                            if produced[slot] >= req.max_new_tokens:
-                                sched.retire(slot, COMPLETED)
-                                produced[slot] = 0
-                                break
-                    continue
-                if i >= len(trace) and not sched.queued():
-                    break
-                if paced and i < len(trace):
-                    nxt = trace[i][0] - (time.perf_counter() - t0)
-                    if nxt > 0:
-                        time.sleep(min(nxt, 0.05))
-            return (time.perf_counter() - t0, useful, ttfts, steps,
-                    stall_max)
-
-        replay(paced=False)                      # pay the compiles
-        wall, useful, ttfts, steps, stall = replay(paced=True)
-        out = _report("bucketed", wall, useful, ttfts)
-        out["decode_step_p50_ms"] = round(
-            float(np.median(steps)) * 1e3, 3) if steps else float("nan")
-        out["stall_max_ms"] = round(stall * 1e3, 1)
-        out["compiles"] = int(prefill._cache_size()
-                              + block._cache_size())
-        return out
 
     # ------------------------------------------------- speculative A/B ----
     def run_spec_ab(self, trace=None):
@@ -1433,42 +1212,6 @@ class Bench:
                 on["revisit_ttft_p50_ms"] < off["revisit_ttft_p50_ms"]),
         }
 
-    def _tick_chain(self, kind, ctx=24, iters=12, reps=3):
-        """Controlled pure-decode tick latency on matched state: all
-        slots live at cache length ``ctx``, ``iters`` chained fused
-        blocks (donated pools, token fed back so calls serialize),
-        fresh jit fn per arm. Returns median per-step seconds."""
-        import jax
-        jnp, Lm, a = self.jnp, self.L, self.args
-        S, k, ps = a.max_batch, a.decode_block, a.page_size
-        pps = -(-(self.buckets[-1] + self.mnt_cap - 1) // ps)
-        fn = {"ragged": Lm.serving_tick_block,
-              "bucketed": Lm.serving_decode_block}[kind]
-        jitted = jax.jit(partial(fn, cfg=self.cfg), donate_argnums=(4, 5),
-                         static_argnames=("num_steps",))
-        tables = jnp.asarray(
-            1 + np.arange(S * pps, dtype=np.int32).reshape(S, pps))
-        best = float("inf")
-        for _ in range(reps):
-            pools = Lm.init_serving_pages(self.cfg, S * pps + 1, ps)
-            kp, vp = pools["k_pages"], pools["v_pages"]
-            tok = jnp.zeros((S,), jnp.int32)
-            lengths = jnp.full((S,), ctx, jnp.int32)
-            # compile outside the timed chain
-            toks, kp, vp = jitted(self.params, tok, lengths, tables, kp,
-                                  vp, num_steps=k)
-            tok = toks[:, -1]
-            lengths = lengths + k
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                toks, kp, vp = jitted(self.params, tok, lengths, tables,
-                                      kp, vp, num_steps=k)
-                tok = toks[:, -1]
-                lengths = lengths + k
-            np.asarray(tok)
-            best = min(best, (time.perf_counter() - t0) / (iters * k))
-        return best
-
     def warmup(self, modes):
         """Compile the selected modes' program shapes outside the timed
         runs (only theirs — the full grid is seconds of XLA compiles)."""
@@ -1614,7 +1357,7 @@ def main(argv=None):
                          "the ON arm's budget, 0 = 64 MiB default)")
     ap.add_argument("--modes", nargs="+", default=None,
                     help="any of: sequential batcher engine prefix_ab "
-                         "ragged_ab trace_overhead spec_ab fleet "
+                         "trace_overhead spec_ab fleet "
                          "migration_ab cold_tier "
                          "(default: sequential batcher engine, or "
                          "fleet when --replicas > 1)")
@@ -1652,8 +1395,8 @@ def main(argv=None):
                         shared_prefix=args.shared_prefix,
                         arrival=parse_arrival(args.arrival))
     bench.warmup([m for m in args.modes
-                  if m not in ("prefix_ab", "ragged_ab", "spec_ab",
-                               "fleet", "migration_ab", "cold_tier")])
+                  if m not in ("prefix_ab", "spec_ab", "fleet",
+                               "migration_ab", "cold_tier")])
     results = {}
     for mode in args.modes:
         results[mode] = getattr(bench, f"run_{mode}")(list(trace))
