@@ -121,12 +121,13 @@ def _abstract_cache(mod, cfg, slots: int, pps: int, page_size: int):
 
 
 def _donated(cache, first: int):
-    """Result positions of the cache's leaves when they start at
-    ``first``: the cache is the LAST result of both step functions, and
-    the engine donates and rebinds it whole, so they never cross to the
-    host."""
+    """Result positions of the slots' current tokens (at ``first``) and
+    of the cache's leaves after them: they are the LAST results of both
+    step functions, and the engine rebinds them (the cache donated,
+    whole), so they never cross to the host."""
     import jax
-    return tuple(range(first, first + len(jax.tree_util.tree_leaves(cache))))
+    return tuple(range(
+        first, first + 1 + len(jax.tree_util.tree_leaves(cache))))
 
 
 def _sampling_meta(slots: int) -> Dict[str, Any]:
@@ -152,7 +153,9 @@ def _tick_meta(T: int, slots: int, pps: int) -> Dict[str, Any]:
             "tok_page": sds((T,), i32), "tok_off": sds((T,), i32),
             "tok_qoff": sds((T,), i32), "q_len": sds((slots,), i32),
             "kv_len": sds((slots,), i32), "last": sds((slots,), i32),
-            "tables": sds((slots, pps), i32), **_sampling_meta(slots)}
+            "tables": sds((slots, pps), i32),
+            "tail_live": sds((slots,), jnp.bool_),
+            "cur_tok": sds((slots,), i32), **_sampling_meta(slots)}
 
 
 def serving_targets(model: str = "llama", *, slots: int = 4,
@@ -243,8 +246,7 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
             _tick_meta(Tv, slots, pps),
             ver_idx=sds((slots, 1 + spec_k), i32),
             draft_tok=sds((slots, spec_k), i32),
-            draft_len=sds((slots,), i32),
-            tail_live=sds((slots,), jnp.bool_))
+            draft_len=sds((slots,), i32))
         targets.append(trace_graph(
             f"{model}.serving_tick[verify,spec_k={spec_k}]",
             mod.serving_tick_cache,
@@ -270,7 +272,7 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
          sds((slots, pps), i32), cache, _sampling_meta(slots)),
         compute_dtype=cfg.dtype, slots=slots,
         steps_per_call=decode_block, in_decode_loop=True,
-        # outputs (toks, cache'): only toks crosses to the host
+        # outputs (toks, tok', cache'): only toks crosses to the host
         donated_outputs=_donated(cache, 1),
         meta=dict(meta, geometry=geom)))
 

@@ -555,7 +555,8 @@ def serving_tick_cache(params, tokens, meta, cache, cfg: Lfm2MoeConfig,
                        attn_impl: str = "auto"):
     """ONE ragged serving tick (``models/llama.py serving_tick_cache``
     with this model's walk) over this model's cache pytree: returns
-    ``(toks, logits, cache')``."""
+    ``(toks, logits, cache')``, with ``meta['cur_tok']``
+    ``(toks, logits, cur_tok', cache')``."""
     if spec_k:
         raise ValueError("no speculative verify for a model with per-slot "
                          "state: a rejected draft's state cannot be rolled "
@@ -568,7 +569,8 @@ def serving_tick_cache(params, tokens, meta, cache, cfg: Lfm2MoeConfig,
 def serving_tick_block_cache(params, tok, lengths, tables, cache,
                              cfg: Lfm2MoeConfig, num_steps: int,
                              attn_impl: str = "auto", sampling=None):
-    """``num_steps`` fused decode ticks: ``(toks [S, num_steps], cache')``."""
+    """``num_steps`` fused decode ticks: ``(toks [S, num_steps], tok'
+    [S], cache')``."""
     return _llama.serving_tick_block_cache(
         params, tok, lengths, tables, cache, cfg, num_steps,
         attn_impl=attn_impl, sampling=sampling, walk=_walk)

@@ -1391,6 +1391,19 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
       anywhere; their row is junk the host discards);
     * ``tables [S, pps]``: the page-table rows.
 
+    THE SLOTS' CURRENT TOKENS — optional ``meta['cur_tok'] [S]`` i32 (a
+    trace-time fact, like the sampling state; the engine ALWAYS passes
+    it): the token each slot produced last, kept on the device from one
+    tick to the next, so the host need not read tick N's tokens back
+    before it launches tick N+1. A packed token ``< 0`` takes its value
+    from ``cur_tok[tok_slot]`` in-graph (a decode row, the first token
+    of a drafted span; prompt-span tokens come from the host as they
+    are), and the successor is returned just before the cache: for
+    every slot that produced a token this tick (``meta['tail_live']``:
+    a decode row, a span completing its prompt) its LAST token (the
+    last fused tail step's; with ``spec_k`` the bonus/correction token
+    ``toks[s, accept[s]]``), for every other slot the old value.
+
     FUSED SAMPLING — five more optional meta arrays, all DATA, turn
     every token selection in the tick (last-position pick, fused tail
     steps, speculative verify) into a per-slot temperature/top-k/top-p
@@ -1437,7 +1450,9 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
     in its width grid) is the maximum span length, sizing the kernel's
     slot-major query layout.
 
-    Returns ``(toks, logits [S, V] f32, cache')``: ``toks`` is each
+    Returns ``(toks, logits [S, V] f32, cache')`` (with ``cur_tok``:
+    ``(toks, logits, cur_tok', cache')``; with ``spec_k`` too:
+    ``(toks, accept, logits, cur_tok', cache')``): ``toks`` is each
     slot's in-graph token pick at its last position (argmax, or the
     fused sampler's draw) — ``[S]`` i32 when ``decode_tail == 0``, else
     ``[S, 1+decode_tail]`` (the host pulls only these ints); ``logits``
@@ -1472,7 +1487,12 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
                          "exclusive (speculation replaces the "
                          "fused greedy tail)")
     S = meta["q_len"].shape[0]
+    cur = meta.get("cur_tok")
     with jax.named_scope("embed"):
+        if cur is not None:
+            tokens = jnp.where(
+                tokens < 0, cur[jnp.minimum(meta["tok_slot"], S - 1)],
+                tokens)
         h = params["embed"].astype(cfg.dtype)[tokens[None]]    # [1, T, D]
     h, cache_new = walk(params, h, cache, meta, cfg, tq, attn_impl)
     with jax.named_scope("lm_head"):
@@ -1529,6 +1549,15 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
                     .sum(axis=1).astype(jnp.int32)
         return toks, accept
 
+    def result(last, *rest):
+        """The tick's results; with ``cur_tok`` its successor (``last``
+        ``[S]`` where the slot produced a token) before the cache."""
+        if cur is None:
+            return (*rest, cache_new)
+        with jax.named_scope("sampler"):
+            nxt = jnp.where(meta["tail_live"], last, cur)
+        return (*rest, nxt, cache_new)
+
     if spec_k:
         # logits at EVERY span position of every slot — the verify
         # pass's whole point: one launch prices 1+spec_k predictions
@@ -1539,14 +1568,15 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
             toks, accept = verify(logits_ver)
         # row 0 == the plain tick's logits for every non-speculating
         # slot (ver_idx[:, 0] = last there)
-        return toks, accept, logits_ver[:, 0], cache_new
+        return result(toks[jnp.arange(S), accept], toks, accept,
+                      logits_ver[:, 0])
     with jax.named_scope("lm_head"):
         h_last = h[meta["last"]]                                # [S, D]
         logits = _mm(h_last, params["lm_head"]).astype(jnp.float32)
     with jax.named_scope("sampler"):
         toks = pick(logits, meta["produced"] if samp else None)
     if not decode_tail:
-        return toks, logits, cache_new
+        return result(toks, toks, logits)
 
     ps = cache["k_pages"].shape[-2]
     pps = meta["tables"].shape[1]
@@ -1585,7 +1615,7 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
         length=decode_tail)
     toks = jnp.concatenate([toks[:, None], jnp.moveaxis(tail, 0, 1)],
                            axis=1)                    # [S, 1+tail]
-    return toks, logits, cache_new
+    return result(toks[:, -1], toks, logits)
 
 
 def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
@@ -1596,7 +1626,10 @@ def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
     amortize over the block) over a model's whole cache pytree and its
     layer ``walk`` (see ``serving_tick_cache``). Greedy slots are
     in-graph argmax and match single-step decode exactly. tok/lengths
-    ``[S]`` i32, tables ``[S, pps]``.
+    ``[S]`` i32, tables ``[S, pps]``. ``tok`` is the slots' current
+    tokens as the engine keeps them on the device (``meta['cur_tok']``
+    of the tick), and its successor is returned: a live slot's last
+    token of the block, a dead slot's old value.
 
     A slot with ``lengths == 0`` (free, or admitted and not yet
     prefilled) is DEAD to the block: it enters the tick with no query
@@ -1611,7 +1644,7 @@ def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
     ``produced`` i32 [S] — letting SAMPLING slots ride the fused block
     too (step ``j`` draws continuation index ``produced + j`` via the
     fold_in discipline); None keeps the all-greedy block. Returns
-    ``(toks [S, num_steps] i32, cache')``."""
+    ``(toks [S, num_steps] i32, tok' [S] i32, cache')``."""
     S = tok.shape[0]
     pps = tables.shape[1]
     ps = cache["k_pages"].shape[-2]
@@ -1632,17 +1665,17 @@ def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
                 tok_off=jnp.where(ok, lengths % ps, 0),
                 tok_qoff=jnp.zeros((S,), jnp.int32),
                 q_len=live.astype(jnp.int32), kv_len=lengths + 1,
-                last=b_idx, tables=tables, tail_live=live)
+                last=b_idx, tables=tables, tail_live=live, cur_tok=tok)
     if sampling is not None:
         meta.update(temp=sampling["temp"], top_p=sampling["top_p"],
                     top_k=sampling["top_k"], key=sampling["key"],
                     produced=sampling["produced"])
-    toks, _, cache = serving_tick_cache(
+    toks, _, nxt, cache = serving_tick_cache(
         params, tok, meta, cache, cfg, tq=1, decode_tail=num_steps - 1,
         attn_impl=attn_impl, walk=walk)
     if num_steps == 1:
         toks = toks[:, None]
-    return toks, cache
+    return toks, nxt, cache
 
 
 def make_batch(cfg: LlamaConfig, batch_size: int, seq_len: int, mesh: Mesh,

@@ -171,7 +171,7 @@ class Phases:
     span it interrupted, not after the phase around it."""
 
     __slots__ = ("_tr", "_track", "_args", "_closed", "_name", "_t0",
-                 "_ann")
+                 "_ann", "_at")
 
     def __init__(self, tr: "SpanTracer", track: str, args):
         self._tr = tr
@@ -179,12 +179,17 @@ class Phases:
         self._args = args
         self._closed = []           # [(name, t0_ns, t1_ns)]
         self._name = None
+        self._at = None             # the last boundary
 
     def stop(self) -> int:
         """Ends the open phase; returns the boundary
         (``time.monotonic_ns()``) for the ``enter(..., at=)`` that
-        follows a span opened or closed in between."""
-        t = time.monotonic_ns()
+        follows a span opened or closed in between. With no phase open
+        the last boundary stands: the next phase starts where the last
+        one ended, whatever ran in between."""
+        if self._name is None and self._at is not None:
+            return self._at
+        t = self._at = time.monotonic_ns()
         if self._name is not None:
             self._ann.__exit__(None, None, None)
             self._closed.append((self._name, self._t0, t))
@@ -206,7 +211,10 @@ class Phases:
             for name, t0, t1 in self._closed:
                 tr._append(Span(name, self._track, t0, t1, self._args,
                                 tid))
-        return {name: (t1 - t0) / 1e9 for name, t0, t1 in self._closed}
+        out: Dict[str, float] = {}
+        for name, t0, t1 in self._closed:   # a phase entered twice sums
+            out[name] = out.get(name, 0.0) + (t1 - t0) / 1e9
+        return out
 
 
 class SpanTracer:

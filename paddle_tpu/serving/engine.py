@@ -30,7 +30,13 @@ behind it); this engine batches per STEP:
     compiles;
   - sequences retire at EOS / max_new_tokens / deadline / cancel and
     their pages return to the pool the same tick, so the next queued
-    request starts without waiting for the rest of the batch.
+    request starts without waiting for the rest of the batch;
+  - the engine keeps ONE TICK IN FLIGHT ahead of the host: a slot's
+    current token lives on the device (``_cur_tok_d``, beside the
+    cache), so tick N+1 is built from the PREDICTED state and launched
+    before tick N's tokens are read back, and the device never waits
+    for the engine thread (see ``_loop``; docs/SERVING.md "One tick in
+    flight").
 
 Correctness bar (tests/test_serving.py): with greedy sampling every
 request's tokens equal a standalone ``generate()`` run token-for-token,
@@ -167,6 +173,27 @@ def _jit_step_fns(mod, cfg, attn_impl: str, rewrites: bool = False):
     if len(_JIT_CACHE) > _JIT_CACHE_MAX:
         _JIT_CACHE.popitem(last=False)
     return tick, blk
+
+
+class _Tick:
+    """One dispatched tick whose tokens the host has not read back: the
+    device handles, and per row the ``(slot, req)`` it was launched for.
+    Completion emits a row only if its slot still holds that request."""
+
+    __slots__ = ("no", "outs", "live", "spans", "drafts", "tail",
+                 "admitted", "ahead", "t0", "m0")
+
+    def __init__(self, no, live, spans, drafts, tail, ahead):
+        self.no = no                # the tick's number
+        self.outs = ()              # (toks_d,) or (toks_d, accept_d)
+        self.live = live            # decode rows [(slot, req)]
+        self.spans = spans          # [(slot, req, start, take)]
+        self.drafts = drafts        # {slot: draft tokens} (verify tick)
+        self.tail = tail            # fused steps after the first
+        self.admitted = 0           # requests its iteration admitted
+        self.ahead = ahead          # launched while another was in flight
+        self.t0 = time.perf_counter()
+        self.m0 = time.monotonic()
 
 
 def _default_buckets(max_prompt_len: int):
@@ -525,8 +552,23 @@ class ServingEngine:
         self._prefill_q: "deque" = deque()
         self._last_decode_t: Optional[float] = None
 
-        self._cur_tok = np.zeros((max_batch,), np.int32)
+        # each slot's current token ON THE DEVICE, like its KV: both
+        # tick programs take it and return its successor, so the next
+        # tick's decode rows never wait for a read-back
+        self._cur_tok_d = self._jnp.zeros((max_batch,), self._jnp.int32)
+        # tokens DISPATCHED per slot (emitted + in flight): the index
+        # the fused sampler draws the next token at, and what says a
+        # request reaches max_new_tokens with the tick in flight;
+        # ``_emitted`` is what its client has seen
         self._produced = np.zeros((max_batch,), np.int64)
+        self._emitted = np.zeros((max_batch,), np.int64)
+        # the tick in flight (None: the host has read everything back)
+        # and how many the loop keeps ahead of its read-back: one,
+        # unless a drafter reads ``req.tokens`` on the host to build
+        # the next tick
+        self._inflight: Optional[_Tick] = None
+        self._depth = 0 if self._drafter is not None else 1
+        self._last_done_t: Optional[float] = None
         # per-slot raw PRNG key data (fused in-graph sampling, r16):
         # PRNGKey(seed) at admission, CONSTANT for the request's whole
         # life — the tick folds the token's continuation index in
@@ -538,7 +580,7 @@ class ServingEngine:
         self._samp_cache = None
 
         # ------------------------------------- migration + cold tier ----
-        # chain-completion hook (fired by _finish_prefill, tick lock
+        # chain-completion hook (fired by _register_prompt, tick lock
         # held) — the fleet wires this to surface events to the router
         self.on_chain_complete = on_chain_complete
         # in-flight chunked transfers, both directions. Exports pin
@@ -581,12 +623,21 @@ class ServingEngine:
     # ------------------------------------------------------------- cache ----
     # the two page pools are two leaves of the cache pytree: what moves
     # pages (defrag, migration, the cold tier) reads and rebinds them
-    def _step(self, fn, *args, **static):
-        """One call of a jitted step function (``_tick_jit`` /
-        ``_block_jit``) over the donated cache; rebinds what it returns
-        and hands back the rest of the results."""
-        *out, self._cache = fn(self._params, *args, self._cache, **static)
+    def _step_tick(self, tok_d, meta, **static):
+        """One call of the jitted ragged tick over the slots' current
+        tokens and the donated cache; rebinds both successors and hands
+        back the rest of the results."""
+        *out, self._cur_tok_d, self._cache = self._tick_jit(
+            self._params, tok_d, dict(meta, cur_tok=self._cur_tok_d),
+            self._cache, **static)
         return out
+
+    def _step_block(self, lengths_d, tables_d, sampling):
+        """Likewise the jitted fused decode block; returns its tokens."""
+        toks_d, self._cur_tok_d, self._cache = self._block_jit(
+            self._params, self._cur_tok_d, lengths_d, tables_d,
+            self._cache, num_steps=self._decode_block, sampling=sampling)
+        return toks_d
 
     def _pull_pages(self, idx):
         """Pages ``idx`` of every layer's K and V on the host, ``[L, Hkv,
@@ -864,6 +915,7 @@ class ServingEngine:
             return None
         jnp = self._jnp
         with self._tick_lock:
+            self._drain_inflight("migrate")
             nodes = self.prefix_cache.chain_by_fingerprint(fp, max_depth)
             if not nodes:
                 return None
@@ -901,6 +953,7 @@ class ServingEngine:
         tokens = [tuple(int(t) for t in tt) for tt in blob["tokens"]]
         jnp = self._jnp
         with self._tick_lock:
+            self._drain_inflight("migrate")
             pc = self.prefix_cache
             have = pc.match_chain(tokens)
             need = len(tokens) - have
@@ -942,6 +995,7 @@ class ServingEngine:
         if self.prefix_cache is None:
             return None
         with self._tick_lock:
+            self._drain_inflight("migrate")
             nodes = self.prefix_cache.chain_by_fingerprint(fp, max_depth)
             if not nodes:
                 return None
@@ -963,6 +1017,7 @@ class ServingEngine:
         pins only stop the pages being FREED, not moved."""
         jnp = self._jnp
         with self._tick_lock:
+            self._drain_inflight("migrate")
             ent = self._exports[xid]
             nodes = ent["nodes"][start:start + count]
             idx = jnp.asarray([nd.page for nd in nodes], jnp.int32)
@@ -1000,6 +1055,7 @@ class ServingEngine:
                 f"this engine serves {self.pool.page_size}")
         tokens = [tuple(int(t) for t in tt) for tt in header["tokens"]]
         with self._tick_lock:
+            self._drain_inflight("migrate")
             pc = self.prefix_cache
             pinned = pc.chain_nodes(tokens)
             have = len(pinned)
@@ -1028,6 +1084,7 @@ class ServingEngine:
         checks completeness."""
         jnp = self._jnp
         with self._tick_lock:
+            self._drain_inflight("migrate")
             ent = self._adopts[aid]
             off = int(start) - ent["have"]
             count = int(k.shape[2])
@@ -1044,6 +1101,7 @@ class ServingEngine:
         release the prefix pins. Returns ``{"matched_pages",
         "adopted_pages"}`` mirroring :meth:`adopt_chain`."""
         with self._tick_lock:
+            self._drain_inflight("migrate")
             ent = self._adopts.pop(aid)
             pc = self.prefix_cache
             dup = 0
@@ -1101,7 +1159,9 @@ class ServingEngine:
         """``PrefixCache.spill`` hook: page one evicted refcount-0
         chain node's KV out to the host-RAM cold tier before its
         device page is freed. Runs inside ``PrefixCache.evict`` —
-        tick lock already held; failures are swallowed by the caller
+        tick lock already held, and no tick in flight: whoever evicts
+        with a cold tier on (admission in ``_loop``, ``adopt_chain*``)
+        completed it first; failures are swallowed by the caller
         (spill is an optimization, eviction must always succeed)."""
         if self._cold is None:
             return
@@ -1244,10 +1304,11 @@ class ServingEngine:
                 T = S + w
                 out[f"tick@{w}"] = self._tick_jit.lower(
                     self._params, jnp.asarray(np.zeros((T,), np.int32)),
-                    pad_meta(T), self._cache, tq=w,
+                    dict(pad_meta(T), cur_tok=self._cur_tok_d),
+                    self._cache, tq=w,
                     decode_tail=0).as_text(debug_info=debug_info)
             out["block"] = self._block_jit.lower(
-                self._params, jnp.asarray(zs), jnp.asarray(zs),
+                self._params, self._cur_tok_d, jnp.asarray(zs),
                 jnp.asarray(tabs), self._cache,
                 num_steps=self._decode_block,
                 sampling=samp).as_text(debug_info=debug_info)
@@ -1265,7 +1326,9 @@ class ServingEngine:
         per-tick draft counts, which a traffic-shaped warmup cannot
         guarantee to cover. Safe any time (serialized against ticks;
         real pages are never read into outputs that matter nor
-        written). Returns the number of jit invocations made."""
+        written; no slot is tail-live, so every slot's current token
+        passes through unchanged — a tick in flight is not disturbed).
+        Returns the number of jit invocations made."""
         from ..core.stack_anchor import above_stack_anchor
         # every call in there traces and lowers a program: seconds of
         # deeply nested Python, whose speed would otherwise depend on
@@ -1302,22 +1365,19 @@ class ServingEngine:
                 T = S + w
                 tok = jnp.asarray(np.zeros((T,), np.int32))
                 if self._spec_k:
-                    self._step(self._tick_jit, tok, spec_meta(T), tq=w,
-                               decode_tail=0, spec_k=self._spec_k)
+                    self._step_tick(tok, spec_meta(T), tq=w,
+                                    decode_tail=0, spec_k=self._spec_k)
                     n += 1
                 else:
                     tails = {self._decode_block - 1, 0}
                     for tail in sorted(tails, reverse=True):
-                        self._step(self._tick_jit, tok, pad_meta(T), tq=w,
-                                   decode_tail=tail)
+                        self._step_tick(tok, pad_meta(T), tq=w,
+                                        decode_tail=tail)
                         n += 1
             # width S: the fused block — the ONLY pure-decode program
             # since r16 (the single-step sampling tick is gone: its
             # traffic rides the block through the in-graph sampler)
-            tok = jnp.asarray(zs)
-            self._step(self._block_jit, tok, jnp.asarray(zs),
-                       jnp.asarray(tabs), num_steps=self._decode_block,
-                       sampling=samp)
+            self._step_block(jnp.asarray(zs), jnp.asarray(tabs), samp)
             n += 1
         return n
 
@@ -1366,6 +1426,7 @@ class ServingEngine:
         of pages moved. Safe mid-generation (serialized against ticks)."""
         with self._tick_lock, \
                 self.tracer.span("serving.defrag", track="engine.defrag"):
+            self._drain_inflight("defragment")
             plan = self.pool.defrag_plan()
             if not plan:
                 return 0
@@ -1436,15 +1497,14 @@ class ServingEngine:
                     live_slots=live_slots, kv_pages=kv_pages,
                     kv_pages_table=table, **self._tick_layers)
 
-    def _record_tick(self, t0: float, t1: float, live, spans,
-                     admitted: int) -> None:
-        """Per-tick evidence (caller holds the tick lock): slot-track
-        spans for each live decoder and prefill span, plus one compact
-        flight-recorder record with the tick's geometry and the live
-        pool/queue gauges. Requests may have retired inside the tick —
-        only ids are used, never slot re-reads."""
-        tick = self._tick_no
-        self._tick_no += 1
+    def _record_tick(self, tk: _Tick, t1: float) -> None:
+        """Per-tick evidence, at the tick's completion (caller holds the
+        tick lock): slot-track spans for each live decoder and prefill
+        span, from the tick's dispatch to its completion, plus one
+        compact flight-recorder record with the tick's geometry and the
+        live pool/queue gauges. Requests may have retired since the
+        dispatch — only ids are used, never slot re-reads."""
+        tick, t0, live, spans = tk.no, tk.m0, tk.live, tk.spans
         for slot, req, start, _ in spans:
             if start == req.cached_len:
                 # the request's first chunk rode this tick: its wait
@@ -1465,7 +1525,7 @@ class ServingEngine:
             tick=tick, t_mono_s=round(t0, 6), dur_s=round(t1 - t0, 6),
             live=len(live), prefill_spans=len(spans),
             span_tokens=int(sum(t for _, _, _, t in spans)),
-            admitted=int(admitted), queued=self.scheduler.queued(),
+            admitted=int(tk.admitted), queued=self.scheduler.queued(),
             occupancy=self.scheduler.occupancy,
             free_pages=self.pool.free_pages,
             prefill_queue_depth=len(self._prefill_q))
@@ -1522,9 +1582,10 @@ class ServingEngine:
         """The fused sampler's per-slot DATA (r16): temperature /
         top_p / top_k from each occupied slot's request, the constant
         per-slot PRNG key, and the produced-token count that keys each
-        draw. Passed with EVERY tick (greedy slots carry temp 0 and
-        take the bitwise argmax path in-graph), so sampling is never a
-        different program. The composition-dependent arrays
+        draw (the DISPATCHED count, emitted + in flight: the index the
+        token this launch emits has in its stream). Passed with EVERY
+        tick (greedy slots carry temp 0 and take the bitwise argmax
+        path in-graph), so sampling is never a different program. The composition-dependent arrays
         (params + keys) change only at admission/retirement, so they
         are cached on-device and rebuilt on invalidation (``_park`` /
         ``_retire``); only ``produced`` uploads per tick — the hot
@@ -1563,9 +1624,9 @@ class ServingEngine:
                             req=req.id)
         req.tokens.append(tok)
         req.stream.put(tok)
-        self._produced[slot] += 1
+        self._emitted[slot] += 1
         self.metrics.inc("tokens_out")
-        done = (self._produced[slot] >= req.max_new_tokens
+        done = (self._emitted[slot] >= req.max_new_tokens
                 or (req.eos_token_id is not None
                     and tok == req.eos_token_id))
         return bool(done)
@@ -1584,9 +1645,12 @@ class ServingEngine:
                             req.finish_t, req=req.id, state=state,
                             tokens=len(req.tokens))
 
+        # the slot's token on the device stays as it is: the next
+        # occupant prefills first, and completing its prompt overwrites
+        # it before any decode row reads it
         self.scheduler.retire(slot, state, before_finish=record)
-        self._cur_tok[slot] = 0
         self._produced[slot] = 0
+        self._emitted[slot] = 0
         self._key_data[slot] = 0
         self._samp_cache = None
 
@@ -1600,9 +1664,7 @@ class ServingEngine:
         continuation index, so the next launch re-draws them
         identically)."""
         for j in range(j0, j1):
-            t = int(toks_row[j])
-            self._cur_tok[slot] = t
-            if self._emit(slot, req, t):
+            if self._emit(slot, req, int(toks_row[j])):
                 self._retire(slot, COMPLETED)
                 break
 
@@ -1644,10 +1706,10 @@ class ServingEngine:
     def _collect_spans(self):
         """The tick's prefill work: FIFO over parked requests, capped at
         the per-tick token budget. Returns [(slot, req, start, take)];
-        advances no state (the tick driver does, after the program
-        ran). A later request only gets budget once every earlier one's
-        span completed its prompt, so finishing spans are always a
-        prefix of the queue."""
+        advances no state (the tick's dispatch does: ``chunk_done``
+        says what is computed or in flight). A later request only gets
+        budget once every earlier one's span completed its prompt, so
+        finishing spans are always a prefix of the queue."""
         while self._prefill_q:          # drop entries retired by sweeps
             slot, req = self._prefill_q[0]
             if self.scheduler.slots[slot] is req and req.prefilling:
@@ -1670,15 +1732,35 @@ class ServingEngine:
                 break                   # budget exhausted mid-prompt
         return spans
 
-    def _finish_prefill(self, slot: int, req: Request, tok: int) -> None:
-        """Common prefill tail: re-install the real row, register the
-        prompt's full pages in the prefix cache, join the decode batch,
-        emit the first sampled token."""
-        n = req.prompt.size
-        self.metrics.inc("prefills")
+    def _join_decode(self, slot: int, req: Request, tail: int) -> None:
+        """A prompt's completion, the half taken at the DISPATCH of the
+        tick that computes its last span: the real row re-installed and
+        the slot in the decode batch, so the next tick carries its
+        decode row (whose token the device holds: this tick's
+        ``cur_tok`` successor)."""
+        if self._prefill_q and self._prefill_q[0][1] is req:
+            self._prefill_q.popleft()
         req.prefilling = False
         self.scheduler.tables[slot, :] = req.table_row
         req.table_row = None
+        self.scheduler.lengths[slot] = req.prompt.size - 1
+        self._advance(slot, req, 1 + tail)
+
+    def _advance(self, slot: int, req: Request, steps: int) -> None:
+        """``steps`` decode steps of ``slot`` dispatched: the tokens
+        they produce and the KV they land, both counted only as far as
+        the request goes (a fused block runs on past ``max_new_tokens``
+        onto the trash page; the length stays inside the funded row)."""
+        n = min(steps, req.max_new_tokens - int(self._produced[slot]))
+        self.scheduler.lengths[slot] += n
+        self._produced[slot] += n
+
+    def _register_prompt(self, req: Request) -> None:
+        """A prompt's completion, the half taken at that tick's
+        COMPLETION (its slot still holding ``req``), before the first
+        token is emitted: the prompt's full pages registered in the
+        prefix cache and the chain-completion event."""
+        n = req.prompt.size
         if self.prefix_cache is not None:
             new_full = n // self.pool.page_size - len(req.prefix_nodes)
             if new_full > 0:
@@ -1708,10 +1790,6 @@ class ServingEngine:
                             "pages": n_pages, "prompt_tokens": int(n)})
                     except Exception:
                         pass    # policy failure must not kill the tick
-        self.scheduler.lengths[slot] = n
-        self._cur_tok[slot] = tok
-        if self._emit(slot, req, tok):
-            self._retire(slot, COMPLETED)
 
     # ------------------------------------------------------- speculation ----
     def _collect_drafts(self, live):
@@ -1761,19 +1839,32 @@ class ServingEngine:
 
     # -------------------------------------------------------------- tick ----
     def _ragged_tick(self, ph, live, spans, tail: int = 0,
-                     drafts=None) -> None:
-        """ONE serving_tick call covering every live slot's decode token
-        plus the collected prompt spans. Geometry is data: the program
-        compiles once per packed width (S when no prefill work is
-        pending, S + the smallest width-grid entry covering the span
-        tokens otherwise). ``tail`` fuses that many extra decode steps
-        into the same program for tail-live slots — decoding slots
-        plus spans COMPLETING their prompt this tick — so an admission
-        tick still produces a full decode block for in-flight streams
-        (mid-prefill slots sit the tail out on the trash page). Since
-        r16 sampling slots ride the tail too: token selection is the
-        in-graph fused sampler, per-slot params and keys are meta
-        DATA.
+                     drafts=None) -> _Tick:
+        """DISPATCH one serving_tick call covering every live slot's
+        decode token plus the collected prompt spans; returns the
+        pending ``_Tick`` (``_complete`` reads it back). Geometry is
+        data: the program compiles once per packed width (S when no
+        prefill work is pending, S + the smallest width-grid entry
+        covering the span tokens otherwise). ``tail`` fuses that many
+        extra decode steps into the same program for tail-live slots —
+        decoding slots plus spans COMPLETING their prompt this tick —
+        so an admission tick still produces a full decode block for
+        in-flight streams (mid-prefill slots sit the tail out on the
+        trash page). Since r16 sampling slots ride the tail too: token
+        selection is the in-graph fused sampler, per-slot params and
+        keys are meta DATA.
+
+        A decode row's input token is a sentinel (-1): the program takes
+        it from the slots' current tokens on the device, where the tick
+        before left it — the host has not read that tick back yet.
+
+        What the next tick's build needs is advanced HERE, before the
+        launch, from what the tick will do whatever its tokens are: a
+        decode row's ``lengths`` and ``_produced`` by ``1 + tail``, a
+        span's ``chunk_done``, a completing prompt's place in the decode
+        batch (``_join_decode``). A drafted slot advances by what the
+        verify pass accepts, which only completion knows (a drafter
+        engine completes each tick before it builds the next).
 
         ``drafts`` (``{slot: draft tokens}``, speculative engines only)
         turns drafted slots into ordinary ragged SPANS: current token
@@ -1785,8 +1876,8 @@ class ServingEngine:
         keeps the per-bucket program count at 1 there.
 
         ``ph``: the iteration's ``Phases`` (``_loop``), in ``build`` on
-        entry; the tick moves it through ``dispatch`` and ``readback``
-        and leaves it in ``emit``."""
+        entry; the tick moves it through ``dispatch`` and stops it
+        there (the next phase entered starts at that boundary)."""
         jnp = self._jnp
         S = self.scheduler.max_batch
         ps = self.pool.page_size
@@ -1794,7 +1885,7 @@ class ServingEngine:
         drafts = drafts or {}
         # speculative engines route every span-carrying tick through
         # the verify program (one program per mixed width); draft-less
-        # pure-decode ticks run the fused block instead (_decode_tick)
+        # pure-decode ticks run the fused block instead (_dispatch_tick)
         spec = self._spec_k if (drafts or spans) else 0
         if spec:
             tail = 0    # speculation replaces the fused greedy tail
@@ -1817,7 +1908,7 @@ class ServingEngine:
         for slot, req in live:
             if slot in drafts:
                 continue    # rides the span region below
-            tok[slot] = self._cur_tok[slot]
+            tok[slot] = -1                  # the device's cur_tok[slot]
             tok_slot[slot] = slot
             tok_pos[slot] = self.scheduler.lengths[slot]
             q_len[slot] = 1
@@ -1832,7 +1923,7 @@ class ServingEngine:
                 continue
             k_s = int(d.size)
             p0 = int(self.scheduler.lengths[slot])
-            tok[idx] = self._cur_tok[slot]
+            tok[idx] = -1
             tok[idx + 1: idx + 1 + k_s] = d
             tok_slot[idx: idx + 1 + k_s] = slot
             tok_pos[idx: idx + 1 + k_s] = np.arange(p0, p0 + 1 + k_s)
@@ -1899,95 +1990,42 @@ class ServingEngine:
             + n_tail * tail * (tail + 1) // 2,
             [kv_len[q_len > 0]] + [kv_len[tail_live] + j
                                    for j in range(1, tail + 1)])
-        t0 = time.perf_counter()
-        m0 = time.monotonic()
+        # the state the NEXT build reads, advanced before the launch
+        for slot, req in live:
+            if slot not in drafts:
+                self._advance(slot, req, 1 + tail)
+        for slot, req, start, take in spans:
+            req.chunk_done += take
+            if tail_live[slot]:
+                self._join_decode(slot, req, tail)
+        tk = self._new_tick(live, spans, drafts, tail)
         tok_d = jnp.asarray(tok)
         t_build = ph.stop()
         with self.tracer.span("serving.tick", track="engine.decode",
-                              tick=self._tick_no, width=int(width),
+                              tick=tk.no, width=int(width),
                               live=len(live), span_tokens=int(span_tok),
                               tail=int(tail), spec=len(spec_rows),
                               **counts):
             ph.enter("serving.phase.dispatch", at=t_build)
             if spec:
-                toks_d, accept_d, _logits_d = self._step(
-                    self._tick_jit, tok_d, meta, tq=tq, decode_tail=0,
-                    spec_k=spec)
-                ph.enter("serving.phase.readback")
-                # [S, 1+spec_k] i32 + [S] i32 — the eager pulls
-                toks = np.asarray(toks_d)      # noqa: PT005 - THE sanctioned per-tick verify read-back
-                accept = np.asarray(accept_d)  # noqa: PT005 - rides the same sync
-                host_sync("serving.tick.readback")
+                # toks [S, 1+spec_k] i32 + accept [S] i32
+                toks_d, accept_d, _logits_d = self._step_tick(
+                    tok_d, meta, tq=tq, decode_tail=0, spec_k=spec)
+                tk.outs = (toks_d, accept_d)
             else:
-                toks_d, _logits_d = self._step(
-                    self._tick_jit, tok_d, meta, tq=tq, decode_tail=tail)
-                ph.enter("serving.phase.readback")
-                # [S] (tail=0) or [S, 1+tail] i32 — the only eager
-                # pull: sampling happens IN-GRAPH (r16), so no [S, V]
-                # logits row ever crosses to the host
-                toks = np.asarray(toks_d)  # noqa: PT005 - THE sanctioned per-tick token read-back
-                host_sync("serving.tick.readback")
-            t_read = ph.stop()
-        ph.enter("serving.phase.emit", at=t_read)
-        m1 = time.monotonic()
-        if toks.ndim == 1:
-            toks = toks[:, None]
-        if live:
-            self.metrics.inc("decode_steps", 1 + tail)
-            self.metrics.observe("decode_step_s",
-                                 (time.perf_counter() - t0) / (1 + tail))
-        if spec_rows:
-            self.metrics.inc("spec_ticks")
+                # toks [S] (tail=0) or [S, 1+tail] i32: sampling
+                # happens IN-GRAPH (r16), so no [S, V] logits row ever
+                # crosses to the host
+                toks_d, _logits_d = self._step_tick(
+                    tok_d, meta, tq=tq, decode_tail=tail)
+                tk.outs = (toks_d,)
+            ph.stop()
+        return tk
 
-        for slot, req in live:
-            d = drafts.get(slot)
-            if d is not None:
-                # speculative slot: 1 + accept tokens from this ONE
-                # launch (verified prefix + the bonus/correction
-                # token); rejected draft KV stays past the advanced
-                # length — masked by kv_len until real tokens
-                # positionally overwrite it (no device-side rollback)
-                k_s = int(d.size)
-                a = int(accept[slot])
-                self.scheduler.lengths[slot] += 1 + a
-                self.metrics.inc("draft_tokens", k_s)
-                self.metrics.inc("draft_accepted", a)
-                self.metrics.inc("draft_rejected", k_s - a)
-                self.metrics.observe("spec_accept_rate", a / k_s)
-                self._spec_policy.update(req, k_s, a)
-                if self.tracer.enabled:
-                    self.tracer.add("spec.verify", f"slot{slot}", m0, m1,
-                                    req=req.id, drafted=k_s, accepted=a)
-                    if k_s > a:
-                        self.tracer.add("spec.rollback", f"slot{slot}",
-                                        m1, m1, req=req.id,
-                                        rejected=k_s - a)
-                self._emit_toks(slot, req, toks[slot], 0, a + 1)
-                continue
-            self.scheduler.lengths[slot] += 1 + tail
-            t = int(toks[slot, 0])     # in-graph argmax OR fused sample
-            self._cur_tok[slot] = t
-            if self._emit(slot, req, t):
-                self._retire(slot, COMPLETED)
-                continue
-            self._emit_toks(slot, req, toks[slot], 1, 1 + tail)
-        for slot, req, start, take in spans:
-            req.chunk_done += take
-            self.metrics.inc("prefill_chunks")
-            if req.cached_len + req.chunk_done >= req.prompt.size:
-                if self._prefill_q and self._prefill_q[0][1] is req:
-                    self._prefill_q.popleft()
-                self._finish_prefill(slot, req, int(toks[slot, 0]))
-                if tail and self.scheduler.slots[slot] is req:
-                    # the completing slot rode the tail too: its first
-                    # 1+tail tokens landed in this same program
-                    self.scheduler.lengths[slot] += tail
-                    self._emit_toks(slot, req, toks[slot], 1, 1 + tail)
-
-    def _block_tick(self, ph, live) -> None:
-        """Fast path when no prefill work is pending: ``num_steps``
-        fused decode ticks in one program — token selection is
-        in-graph (argmax for greedy slots, the fused
+    def _block_tick(self, ph, live) -> _Tick:
+        """DISPATCH the fast path when no prefill work is pending:
+        ``num_steps`` fused decode ticks in one program — token
+        selection is in-graph (argmax for greedy slots, the fused
         temperature/top-k/top-p sampler for sampling ones, r16), so
         the device→host pull is [S, k] i32 tokens and NO [S, V] f32
         logits row ever crosses, whoever is sampling. Fused ticks
@@ -1995,41 +2033,47 @@ class ServingEngine:
         would compile one program per distinct cap; at worst K-1 cheap
         steps run past the last retirement and their tokens are
         discarded (budget overruns land on the trash page, and
-        discarded sampled tokens burn no key state)."""
+        discarded sampled tokens burn no key state). The slots' input
+        tokens are the device's own (``_cur_tok_d``); a slot that is
+        not in ``live`` (free, or ending by count with the tick in
+        flight) enters with length 0 and is dead to the block."""
         jnp = self._jnp
         k = self._decode_block
         # S rows a step, the live slots' real; step j attends the
         # slot's length + j cache tokens
-        lens = self.scheduler.lengths[[slot for slot, _ in live]]
+        rows = [slot for slot, _ in live]
+        lens = self.scheduler.lengths[rows]
         counts = self._count_tick(
             self.scheduler.max_batch * k, len(live) * k,
             k * int(lens.sum()) + len(live) * k * (k + 1) // 2,
             [lens + j for j in range(1, k + 1)])
-        t0 = time.perf_counter()
-        args = (jnp.asarray(self._cur_tok),
-                jnp.asarray(self.scheduler.lengths),
-                jnp.asarray(self.scheduler.tables))
+        lengths = np.zeros_like(self.scheduler.lengths)
+        lengths[rows] = lens
+        lengths_d = jnp.asarray(lengths)
+        tables_d = jnp.asarray(self.scheduler.tables)
         sampling = self._sampling_arrays()
+        for slot, req in live:              # the block's KV, as launched
+            self._advance(slot, req, k)
+        tk = self._new_tick(live, [], {}, k - 1)
         t_build = ph.stop()
         with self.tracer.span("serving.tick", track="engine.decode",
-                              tick=self._tick_no, kind="block",
+                              tick=tk.no, kind="block",
                               live=len(live), steps=k, **counts):
             ph.enter("serving.phase.dispatch", at=t_build)
-            toks, = self._step(self._block_jit, *args, num_steps=k,
-                               sampling=sampling)
-            ph.enter("serving.phase.readback")
-            toks = np.asarray(toks)  # noqa: PT005 - sanctioned per-block token read-back ([S, k] i32)
-            host_sync("serving.tick.readback")
-            t_read = ph.stop()
-        ph.enter("serving.phase.emit", at=t_read)
-        self.metrics.inc("decode_steps", k)
-        self.metrics.observe("decode_step_s",
-                             (time.perf_counter() - t0) / k)
-        for slot, req in live:
-            self.scheduler.lengths[slot] += k  # block's KV just landed
-            self._emit_toks(slot, req, toks[slot], 0, k)
+            tk.outs = (self._step_block(lengths_d, tables_d, sampling),)
+            ph.stop()
+        return tk
 
-    def _decode_tick(self, ph, live, spans) -> None:
+    def _new_tick(self, live, spans, drafts, tail: int) -> _Tick:
+        """The pending record of the tick being dispatched, numbered."""
+        ahead = self._inflight is not None
+        if ahead:
+            self.metrics.inc("ticks_ahead")
+        tk = _Tick(self._tick_no, live, spans, drafts, tail, ahead)
+        self._tick_no += 1
+        return tk
+
+    def _dispatch_tick(self, ph, live, spans) -> _Tick:
         """Tick dispatch (r16 — sampling is DATA, so temperature never
         picks a program): the fused block when the tick is pure
         decode, else the ragged one-program tick with the fused decode
@@ -2050,12 +2094,115 @@ class ServingEngine:
         if self._drafter is not None:
             drafts = self._collect_drafts(live)
             if drafts or spans:
-                self._ragged_tick(ph, live, spans, 0, drafts)
-                return
-        if not spans and live:
-            self._block_tick(ph, live)
-        elif spans:
-            self._ragged_tick(ph, live, spans, self._decode_block - 1)
+                return self._ragged_tick(ph, live, spans, 0, drafts)
+        if not spans:
+            return self._block_tick(ph, live)
+        return self._ragged_tick(ph, live, spans, self._decode_block - 1)
+
+    def _complete(self, tk: _Tick, ph=None) -> None:
+        """COMPLETE a dispatched tick (caller holds the tick lock):
+        read its tokens back — the one blocking pull; with another tick
+        in flight the device is already working on that one — then do
+        what clients see, for every row whose slot still holds the
+        request the row was launched for: ``req.tokens``, the stream,
+        ``first_token_t``, retirement at EOS / ``max_new_tokens``, a
+        completed prompt's registration. A row whose request has ended
+        since the dispatch (an EOS the tick before found, a cancel, a
+        deadline) is dropped: its tokens are discarded, its KV writes
+        landed in pages that were the request's own at dispatch, and
+        the device ran them before anything launched later. ``ph``:
+        the engine thread's ``Phases``, stopped after ``dispatch``;
+        moved through ``readback`` and left in ``emit`` (None: another
+        thread completes the tick under the tick lock)."""
+        if ph is not None:
+            ph.enter("serving.phase.readback")
+        toks = np.asarray(tk.outs[0])  # noqa: PT005 - THE sanctioned per-tick token read-back ([S], [S, 1+tail] or [S, k] i32)
+        accept = (np.asarray(tk.outs[1])  # noqa: PT005 - rides the same sync (verify tick: [S] i32)
+                  if len(tk.outs) > 1 else None)
+        host_sync("serving.tick.readback")
+        if ph is not None:
+            ph.enter("serving.phase.emit")
+        now = time.perf_counter()
+        m1 = time.monotonic()
+        if toks.ndim == 1:
+            toks = toks[:, None]
+        tail, drafts = tk.tail, tk.drafts
+        if tk.live:
+            # the pace a stream feels: from the completion before this
+            # one — or, for a tick launched with nothing in flight,
+            # from its own dispatch — a decode step
+            start = (self._last_done_t
+                     if tk.ahead and self._last_done_t is not None
+                     else tk.t0)
+            self.metrics.inc("decode_steps", 1 + tail)
+            self.metrics.observe("decode_step_s",
+                                 (now - start) / (1 + tail))
+        self._last_done_t = now
+        if drafts:
+            self.metrics.inc("spec_ticks")
+        overrun = 0
+        for slot, req in tk.live:
+            if self.scheduler.slots[slot] is not req:
+                overrun += 1
+                continue
+            d = drafts.get(slot)
+            if d is None:
+                # token 0: in-graph argmax OR fused sample; 1..tail
+                # the fused steps'
+                self._emit_toks(slot, req, toks[slot], 0, 1 + tail)
+                continue
+            # speculative slot: 1 + accept tokens from this ONE
+            # launch (verified prefix + the bonus/correction
+            # token); rejected draft KV stays past the advanced
+            # length — masked by kv_len until real tokens
+            # positionally overwrite it (no device-side rollback)
+            k_s = int(d.size)
+            a = int(accept[slot])
+            self._advance(slot, req, 1 + a)
+            self.metrics.inc("draft_tokens", k_s)
+            self.metrics.inc("draft_accepted", a)
+            self.metrics.inc("draft_rejected", k_s - a)
+            self.metrics.observe("spec_accept_rate", a / k_s)
+            self._spec_policy.update(req, k_s, a)
+            if self.tracer.enabled:
+                self.tracer.add("spec.verify", f"slot{slot}", tk.m0, m1,
+                                req=req.id, drafted=k_s, accepted=a)
+                if k_s > a:
+                    self.tracer.add("spec.rollback", f"slot{slot}",
+                                    m1, m1, req=req.id,
+                                    rejected=k_s - a)
+            self._emit_toks(slot, req, toks[slot], 0, a + 1)
+        for slot, req, start, take in tk.spans:
+            self.metrics.inc("prefill_chunks")
+            if start + take < req.prompt.size:
+                continue
+            self.metrics.inc("prefills")
+            if self.scheduler.slots[slot] is not req:
+                overrun += 1
+                continue
+            self._register_prompt(req)
+            # the completing slot rode the tail too: its first 1+tail
+            # tokens landed in this same program
+            self._emit_toks(slot, req, toks[slot], 0, 1 + tail)
+        if overrun:
+            self.metrics.inc("overrun_slot_ticks", overrun)
+        self._record_tick(tk, m1)
+
+    def _drain_inflight(self, reason: str, ph=None) -> None:
+        """Complete the tick in flight, if any, before its time (caller
+        holds the tick lock): what rewrites pool, tables or trie, a
+        drafter that reads ``req.tokens``, an engine with nothing more
+        to launch and ``close`` all want the host's state and the
+        device's in step."""
+        tk, self._inflight = self._inflight, None
+        if tk is None:
+            return
+        self.metrics.inc("inflight_drains")
+        self.metrics.inc_labeled("inflight_drains", reason=reason)
+        t0 = time.monotonic()
+        self._complete(tk, ph)
+        self.tracer.add("serving.drain", "engine.drain", t0,
+                        time.monotonic(), reason=reason, tick=tk.no)
 
     def _sweep(self, now: float) -> None:
         """Apply cancellations + deadlines to queued and occupied
@@ -2098,13 +2245,19 @@ class ServingEngine:
                         if handed:
                             self._returned.extend(handed)
                             self.metrics.inc("handed_back", len(handed))
-                    if self._cold is not None and len(self._cold) \
+                    if self._cold is not None \
                             and self.scheduler.queued():
-                        # cold-tier rewarm BEFORE admission: a queued
-                        # prompt whose warm match ends where a spilled
-                        # chain begins re-adopts those pages now, so
-                        # _try_reserve sees them as a warm hit
-                        self._rewarm_cold()
+                        # admission may spill an evicted chain, the
+                        # rewarm scatters one: pages move, so the host
+                        # and the device first come in step
+                        self._drain_inflight("migrate")
+                        if len(self._cold):
+                            # cold-tier rewarm BEFORE admission: a
+                            # queued prompt whose warm match ends where
+                            # a spilled chain begins re-adopts those
+                            # pages now, so _try_reserve sees them as a
+                            # warm hit
+                            self._rewarm_cold()
                     t_adm = time.monotonic()
                     admitted = self.scheduler.admit()
                     if admitted:
@@ -2129,34 +2282,51 @@ class ServingEngine:
                         self._park(slot, req)
                     ph.enter("serving.phase.build")
                     spans = self._collect_spans()
-                    live = self.scheduler.live()
+                    # built from the PREDICTED state: a request that
+                    # reaches max_new_tokens with the tick in flight
+                    # gets no row (known by counting; only an EOS is
+                    # found a tick late)
+                    live = [(slot, req)
+                            for slot, req in self.scheduler.live()
+                            if self._produced[slot] < req.max_new_tokens]
                     self.metrics.observe("batch_occupancy",
                                          self.scheduler.occupancy)
                     self.metrics.observe("page_utilization",
                                          self.pool.utilization)
                     self.metrics.observe("chunk_queue_depth",
                                          len(self._prefill_q))
-                    ticked = bool(live) or bool(spans)
-                    if ticked:
+                    prev = self._inflight
+                    ticked = prev is not None or bool(live) or bool(spans)
+                    if live or spans:
                         # inter-decode-tick stall: everything since the
-                        # last tick ended (host work, metadata builds)
-                        # shows up as this gap — the latency in-flight
-                        # streams actually feel. Prefill spans now ride
-                        # INSIDE the tick, budget-bounded, instead of
-                        # stalling between ticks.
+                        # last tick's completion (host work, metadata
+                        # builds) shows up as this gap; with a tick in
+                        # flight the device works through it. Prefill
+                        # spans ride INSIDE the tick, budget-bounded,
+                        # instead of stalling between ticks.
                         t = time.perf_counter()
                         if live and self._last_decode_t is not None:
                             self.metrics.observe(
                                 "decode_stall_s",
                                 t - self._last_decode_t)
-                        t_tick0 = time.monotonic()
-                        self._decode_tick(ph, live, spans)
-                        t_tick1 = time.monotonic()
+                        # dispatch tick N+1, THEN complete tick N: its
+                        # read-back returns while N+1 runs
+                        self._inflight = self._dispatch_tick(
+                            ph, live, spans)
+                        self._inflight.admitted = len(admitted)
+                        if prev is not None:
+                            self._complete(prev, ph)
+                        if self._depth == 0:
+                            # in step: the next build reads what this
+                            # tick emits (a drafter: ``req.tokens``)
+                            self._drain_inflight(
+                                "drafter" if self._drafter is not None
+                                else "step", ph)
                         self._last_decode_t = (time.perf_counter()
                                                if live else None)
-                        self._record_tick(t_tick0, t_tick1, live, spans,
-                                          len(admitted))
                     else:
+                        # nothing to launch: the engine runs empty
+                        self._drain_inflight("empty", ph)
                         self._last_decode_t = None
                     if ticked and self._check_invariants:
                         self._audit_or_raise()
@@ -2186,6 +2356,7 @@ class ServingEngine:
                 # defragment() must not rewrite pool/rows/trie while
                 # the dump walks them
                 with self._tick_lock:
+                    self._settle_inflight()
                     self._write_postmortem(e)
             except Exception:
                 pass        # a failing dump must not mask the error
@@ -2198,6 +2369,7 @@ class ServingEngine:
             # callers may still be mid-read, and the teardown rewrites
             # the very slot/table/trie state they walk
             with self._tick_lock:
+                self._settle_inflight()     # a cancel-close mid-tick
                 for r in self.scheduler.drop_queued(lambda r: True):
                     r.finish(CANCELLED)
                     self.metrics.inc("cancelled")
@@ -2215,6 +2387,16 @@ class ServingEngine:
                     self.prefix_cache.spill = None
                     self.prefix_cache.evict(
                         self.prefix_cache.cached_pages)
+
+    def _settle_inflight(self) -> None:
+        """The worker is leaving (close, or dying): complete the tick
+        in flight so the state torn down or dumped is one the device
+        agrees with; a tick that cannot be completed (the failure was
+        its own) is dropped. Caller holds the tick lock."""
+        try:
+            self._drain_inflight("close")
+        except Exception:
+            pass            # ``_inflight`` is already cleared
 
     def _observe_phases(self, phases: Dict[str, float]) -> None:
         """One ticked iteration's phases into their histograms, with

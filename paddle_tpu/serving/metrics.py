@@ -117,7 +117,14 @@ class ServingMetrics:
     attention launches, the slots that had a query row, the cache
     pages those slots held, and slots x pages_per_slot: walked / table
     is the share of a static walk over the page tables that was
-    needed).
+    needed), and the tick the engine keeps in flight ahead of the host
+    (ticks_ahead — ticks dispatched while another was in flight: over
+    the ticks dispatched, the share that engaged; inflight_drains —
+    times a tick was completed before its time, also labeled by
+    ``reason``: drafter / step / defragment / migrate / empty / close;
+    overrun_slot_ticks — rows run for a request that had ended since
+    the dispatch: what an EOS found a tick late, a cancel or a
+    deadline cost). decode_steps counts at a tick's COMPLETION.
     Labeled counters (``inc_labeled``): the same monotonic semantics
     with a small label set — e.g. ``recompiles{during="serving.tick"}``
     names WHAT a post-warmup compile interrupted. Kept separate from
@@ -126,10 +133,14 @@ class ServingMetrics:
     ``*_breakdown_total`` Prometheus family so aggregating either
     family never double-counts.
     Histograms: queue_wait_s (submit -> admission), ttft_s (submit ->
-    first token), decode_step_s (one engine tick), decode_stall_s (gap
-    between consecutive decode ticks while streams are live — the
+    first token), decode_step_s (the interval between consecutive
+    COMPLETED ticks, per decode step — the pace a stream feels; for a
+    tick launched with nothing in flight, its dispatch to its
+    completion), decode_stall_s (host time between one tick's
+    completion and the next one's dispatch while streams are live — the
     chunked-prefill acceptance metric: an unchunked long-prompt
-    admission shows up here as one huge stall), batch_occupancy (live
+    admission shows up here as one huge stall; with a tick in flight
+    the device works through it), batch_occupancy (live
     slots / max_batch per tick), page_utilization (used / allocatable
     pages, sampled per tick), chunk_queue_depth (requests mid
     chunked-prefill, sampled per tick), spec_accept_rate (accepted /
@@ -143,10 +154,13 @@ class ServingMetrics:
     that ticked — phase_admit_s (sweep, rewarm, admission, parking),
     phase_build_s (the tick's arrays packed and sent), phase_dispatch_s
     (the jitted call until it returns), phase_readback_s (the blocking
-    token pull: the device's time, mostly), phase_emit_s (tokens
-    streamed, retirements, the tick's records, the optional audit) —
-    with tick_host_s = the iteration less its read-back, the host
-    time a tick costs (decode_stall_s is a part of it). Histogram
+    token pull of the tick launched the iteration BEFORE, while the
+    one just dispatched runs: what is left of the device's time),
+    phase_emit_s (that tick's tokens streamed, retirements, the tick's
+    records, the optional audit) — with tick_host_s = the iteration
+    less its read-back, the host time a tick costs, hidden behind the
+    device while a tick is in flight (decode_stall_s is a part of
+    it). Histogram
     summaries report the
     lifetime mean AND the windowed mean/percentiles separately — see
     :class:`Histogram`.
@@ -162,7 +176,8 @@ class ServingMetrics:
                 "cold_hits", "cold_hit_pages", "cold_spills",
                 "tick_rows", "tick_rows_real", "kv_tokens_attended",
                 "tick_live_slots", "kv_pages_walked", "kv_pages_table",
-                "prefix_bypassed_stateful")
+                "prefix_bypassed_stateful", "ticks_ahead",
+                "inflight_drains", "overrun_slot_ticks")
     HISTOGRAMS = ("queue_wait_s", "ttft_s", "decode_step_s",
                   "decode_stall_s", "batch_occupancy",
                   "page_utilization", "chunk_queue_depth",

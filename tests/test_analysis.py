@@ -119,13 +119,17 @@ def test_serving_targets_trace_a_family_through_its_three_functions(
     assert sentinel.warmup_compiles == 0
     assert all(calls.values()), calls
     target = targets[f"{model}.{program}"]
-    # the results end with the family's whole cache, donated
+    # the results end with the slots' next current tokens ([S] i32,
+    # kept on the device like the cache) and the family's whole cache,
+    # donated: neither crosses to the host
     leaves = jax.tree_util.tree_leaves(caches[0])
     outs = target.jaxpr.jaxpr.outvars
     assert target.donated_outputs == tuple(
-        range(len(outs) - len(leaves), len(outs)))
+        range(len(outs) - len(leaves) - 1, len(outs)))
     assert [(o.aval.shape, o.aval.dtype) for o in outs[-len(leaves):]] \
         == [(x.shape, x.dtype) for x in leaves]
+    cur = outs[-len(leaves) - 1].aval
+    assert (cur.shape, cur.dtype) == ((target.slots,), jnp.int32)
     # a verify target only where the family can verify: what its layer
     # kinds keep tells, not its name
     cfg_cls = getattr(mod, SERVING_FAMILIES[model])

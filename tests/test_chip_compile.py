@@ -216,7 +216,9 @@ def _mistral_tick_shapes(tq, layers, pages):
                 kv_len=i32((S,)), last=i32((S,)),
                 tables=i32((S, CHAT["pps"])), temp=f32((S,)),
                 top_p=f32((S,)), top_k=i32((S,)),
-                key=sds((S, 2), jnp.uint32), produced=i32((S,)))
+                key=sds((S, 2), jnp.uint32), produced=i32((S,)),
+                # the slots' current tokens, kept on the device
+                tail_live=sds((S,), jnp.bool_), cur_tok=i32((S,)))
     pool = sds((layers, HKV, pages, PAGE, DH))
     return cfg, (params, i32((T,)), meta,
                  {"k_pages": pool, "v_pages": pool})
@@ -295,6 +297,110 @@ def test_serving_tick_holds_the_pool_once(chip, monkeypatch, tq):
     assert not moved, f"the tick moves a layer's pages or more: {moved}"
     assert text.memory.alias_size_in_bytes >= 2 * layers * layer_pages
     assert text.memory.temp_size_in_bytes < layers * layer_pages
+
+
+# the three serving cells' tick programs as the ENGINE jits them
+# (``serving/engine.py: _jit_step_fns``: the family's own walk, the
+# slots' current tokens in and their successor out, the cache donated),
+# at the cell's slots, table, pool and chunk, the model at its published
+# widths cut to two layers (LFM2: one of each kind)
+_TWO_LAYERS = {
+    "dense_decoder": dict(num_hidden_layers=2),
+    "qwen2_moe": dict(num_hidden_layers=2),
+    "lfm2_moe": dict(num_hidden_layers=2, num_dense_layers=1,
+                     layer_types=["conv", "full_attention"]),
+}
+_CELL_PROGRAMS = [("chat", "tick"), ("batch", "tick"), ("generate", "tick"),
+                  ("chat", "block")]
+
+
+def _cell_program_args(traffic):
+    """``(mod, cfg, S, pps, chunk, params, cache)`` of the serving cell
+    with that traffic, abstractly, read from the files it runs from."""
+    import json
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if os.path.join(root, "benchmark") not in sys.path:
+        sys.path.insert(0, os.path.join(root, "benchmark"))
+    from harness import manifest
+
+    def read(*path):
+        with open(os.path.join(root, *path)) as f:
+            return json.load(f)
+
+    bench = manifest.load_manifest()
+    cell = next(w for w in bench["workloads"] if w["traffic"] == traffic
+                and w["name"].split("-")[1] == "serve")
+    work = read("benchmark", "workloads", cell["name"] + ".json")
+    conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    model = {**read(conf["file"]), **work.get("overrides", {})}
+    model.update(_TWO_LAYERS[model["family"]])
+    cfg, mod = manifest.load_family(model["family"]).program_config(model)
+    g = CELLS[traffic]
+    params = jax.eval_shape(
+        lambda: mod.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: mod.init_serving_pages(
+        cfg, g["pages"], g["page_size"], max_batch=g["slots"]))
+    return mod, cfg, g["slots"], g["pps"], g["span"], params, cache
+
+
+@pytest.mark.parametrize("traffic,program", _CELL_PROGRAMS,
+                         ids=["-".join(c) for c in _CELL_PROGRAMS])
+def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
+        topo, chip, monkeypatch, traffic, program):
+    """The engine's jitted tick (at the cell's chunk width) and fused
+    block, with the slots' current tokens as an operand and their
+    successor as a result, compile for the described chip at every
+    serving cell's geometry; the successor is one more ``s32[S]`` result
+    in front of the donated cache, and a decode row's token is gathered
+    from it in the program (no host upload of the slots' tokens)."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    from paddle_tpu.serving import engine as E
+    monkeypatch.setattr(R, "_on_tpu", lambda: True)
+    mod, cfg, S, pps, chunk, params, cache = _cell_program_args(traffic)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    f32 = functools.partial(sds, dtype=jnp.float32)
+    samp = dict(temp=f32((S,)), top_p=f32((S,)), top_k=i32((S,)),
+                key=sds((S, 2), jnp.uint32), produced=i32((S,)))
+    E._JIT_CACHE.clear()        # jit objects of THIS precision context
+    tick, block = E._jit_step_fns(mod, cfg, "auto")
+    if program == "tick":
+        T = S + chunk
+        meta = dict(tok_slot=i32((T,)), tok_pos=i32((T,)),
+                    tok_page=i32((T,)), tok_off=i32((T,)),
+                    tok_qoff=i32((T,)), q_len=i32((S,)), kv_len=i32((S,)),
+                    last=i32((S,)), tables=i32((S, pps)),
+                    tail_live=sds((S,), jnp.bool_), cur_tok=i32((S,)),
+                    **samp)
+        lowered = tick.lower(*on_chip((params, i32((T,)), meta, cache)),
+                             tq=chunk, decode_tail=0)
+        results = 3             # toks, logits, cur_tok'
+    else:
+        lowered = block.lower(
+            *on_chip((params, i32((S,)), i32((S,)), i32((S, pps)), cache)),
+            num_steps=1, sampling=on_chip(samp))
+        results = 2             # toks, cur_tok'
+    compiled = lowered.compile()
+    E._JIT_CACHE.clear()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel"
+    outs = jax.tree.leaves(compiled.out_info)
+    leaves = jax.tree.leaves(cache)
+    assert len(outs) == results + len(leaves)
+    nxt = outs[results - 1]
+    assert (nxt.shape, nxt.dtype) == ((S,), jnp.int32)
+    assert [(o.shape, o.dtype) for o in outs[results:]] == [
+        (a.shape, a.dtype) for a in leaves]
+    # the whole cache is donated: the pools are held once
+    pools = sum(math.prod(a.shape) * a.dtype.itemsize for a in leaves)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
 
 
 def test_splash_fwd(chip):

@@ -235,7 +235,7 @@ def test_fused_block_against_the_reference():
     b = seq(6, 5, 1)
     t = Ticks(cfg, params)
     first, _ = t.run({1: b})
-    toks, t.cache = M.serving_tick_block_cache(
+    toks, _, t.cache = M.serving_tick_block_cache(
         params, jnp.asarray(np.array([0, first[1], 0], np.int32)),
         jnp.asarray(t.lens), jnp.asarray(t.tables), t.cache, cfg, 3)
     cont = np.concatenate([b, first[1:2], np.asarray(toks)[1]])

@@ -385,9 +385,12 @@ def _scripted_run(params, **kw):
     rng = np.random.RandomState(7)
     a = rng.randint(0, 256, (6,)).astype(np.int32)
     b = rng.randint(0, 256, (3,)).astype(np.int32)
+    depth = kw.pop("_depth", None)
     with _engine(params, prefill_chunk=4, prefix_cache=False,
                  **kw) as eng:
         with eng._tick_lock:
+            if depth is not None:
+                eng._depth = depth      # private: no public knob
             ha, hb = eng.submit(a, 3), eng.submit(b, 2)
         outs = [ha.result(timeout=300), hb.result(timeout=300)]
     return eng, (ha, hb), outs
@@ -410,9 +413,12 @@ def _phases_by_tick(eng):
 def test_five_phases_partition_a_ticked_iteration(scripted, kind):
     """The engine thread's time in an iteration that ticked is cut into
     admit, build, dispatch, readback, emit: each ends where the next
-    starts, they sum to the iteration, and the ``serving.tick`` span
-    covers dispatch + read-back — for the ragged tick and for the
-    fused block."""
+    starts and they sum to the iteration. With one tick in flight the
+    iteration that DISPATCHES tick N reads back and emits tick N-1
+    (tick 0's iteration has nothing to read back, and one more
+    iteration, carrying the number of a tick it does not launch,
+    completes the last), and the ``serving.tick`` span covers its
+    tick's dispatch — for the ragged tick and for the fused block."""
     eng, _, _ = scripted
     ticks = {s.args["tick"]: s for s in eng.tracer.spans()
              if s.name == "serving.tick"}
@@ -421,32 +427,80 @@ def test_five_phases_partition_a_ticked_iteration(scripted, kind):
     assert [t for t, s in sorted(ticks.items())
             if (s.args.get("kind") == "block") == (kind == "block")] == want
     by_tick = _phases_by_tick(eng)
-    assert sorted(by_tick) == [0, 1, 2, 3]      # idle polls left nothing
-    for t in want:
+    assert sorted(by_tick) == [0, 1, 2, 3, 4]   # idle polls left nothing
+    names = {0: PHASES[:3], 4: PHASES[:2] + PHASES[3:]}
+    for t in want + [4]:
         ph = by_tick[t]
-        assert [s.name for s in ph] == PHASES
+        assert [s.name for s in ph] == names.get(t, PHASES)
         for s0, s1 in zip(ph, ph[1:]):
             assert s0.t1 == s1.t0
         assert sum(s.t1 - s.t0 for s in ph) == ph[-1].t1 - ph[0].t0
-        tick = ticks[t]
-        # opens as dispatch starts, closes as the read-back ends
-        assert ph[2].t0 <= tick.t0 <= ph[2].t1
-        assert ph[3].t1 <= tick.t1 <= ph[4].t1
+    for t in want:
+        # opens as the dispatch starts, closes as it ends: before the
+        # read-back of the tick before it returns
+        tick, dispatch = ticks[t], by_tick[t][2]
+        assert dispatch.t0 <= tick.t0 <= dispatch.t1 <= tick.t1
+        if t:
+            assert tick.t1 <= by_tick[t][3].t1
 
 
 def test_tick_host_is_the_iteration_less_its_readback(scripted):
     eng, _, _ = scripted
     h = eng.metrics.histograms
     by_tick = _phases_by_tick(eng)
+    seen = dict.fromkeys(PHASES, 0)
     for i, (t, ph) in enumerate(sorted(by_tick.items())):
         whole = (ph[-1].t1 - ph[0].t0) / 1e9
-        assert (h["tick_host_s"]._vals[i] + h["phase_readback_s"]._vals[i]
-                == pytest.approx(whole, abs=1e-9))
+        blocked = sum(s.dur_s for s in ph if s.name == PHASES[3])
+        assert h["tick_host_s"]._vals[i] + blocked == pytest.approx(
+            whole, abs=1e-9)
         for s in ph:
             short = s.name.rsplit(".", 1)[1]
-            assert h[f"phase_{short}_s"]._vals[i] == pytest.approx(
-                s.dur_s, abs=1e-9)
-    assert h["tick_host_s"]._count == len(by_tick) == 4
+            assert h[f"phase_{short}_s"]._vals[seen[s.name]] == \
+                pytest.approx(s.dur_s, abs=1e-9)
+            seen[s.name] += 1
+    assert h["tick_host_s"]._count == len(by_tick) == 5
+    assert [seen[p] for p in PHASES] == [5, 5, 4, 4, 4]
+
+
+def test_tick_in_flight_counters_and_completed_steps(params, scripted):
+    """``decode_steps`` counts at COMPLETION (the scripted run: tick 1
+    completes no decode row... ticks 2 and 3 carry decode rows), one
+    ``decode_step_s`` observation a completed decode tick, and the
+    three counters the mechanism brings are in ``snapshot()``: ticks
+    1..3 were dispatched with the tick before in flight, the loop
+    completed early once (the engine ran empty), and no row outlived
+    its request."""
+    eng, _, _ = scripted
+    snap = eng.snapshot()
+    c = snap["counters"]
+    assert c["decode_steps"] == 2
+    assert eng.metrics.histograms["decode_step_s"]._count == 2
+    assert (c["ticks_ahead"], c["inflight_drains"],
+            c["overrun_slot_ticks"]) == (3, 1, 0)
+    assert snap["labeled"]["inflight_drains"] == [
+        {"labels": {"reason": "empty"}, "value": 1}]
+    drains = [s for s in eng.tracer.spans() if s.name == "serving.drain"]
+    assert [(s.args["reason"], s.args["tick"]) for s in drains] == [
+        ("empty", 3)]
+    # the slot spans of a tick run from its dispatch to its completion
+    tick3 = next(s for s in eng.tracer.spans()
+                 if s.name == "serving.tick" and s.args["tick"] == 3)
+    decode3 = [s for s in eng.tracer.spans()
+               if s.name == "decode" and s.args["tick"] == 3]
+    assert len(decode3) == 2
+    for s in decode3:
+        assert s.t0 <= tick3.t1 and s.t1 >= drains[0].t0
+    # an engine held in step (what a drafter's engine observes) keeps
+    # the lock-step order through the same dispatch/complete pair
+    eng2, _, outs2 = _scripted_run(params, _depth=0)
+    c2 = eng2.snapshot()["counters"]
+    assert (c2["ticks_ahead"], c2["inflight_drains"],
+            c2["decode_steps"]) == (0, 4, 2)
+    by_tick = _phases_by_tick(eng2)
+    assert sorted(by_tick) == [0, 1, 2, 3]
+    for ph in by_tick.values():
+        assert [s.name for s in ph] == PHASES
 
 
 def test_tick_counts_match_the_hand_computed_run(params, scripted):

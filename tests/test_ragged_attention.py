@@ -445,10 +445,15 @@ def test_block_tick_free_slots_are_dead_and_live_ones_decode(
     # single-step greedy decode: K blocks of one step each
     want, cur, lens, c = [], jnp.asarray(tok), lengths.copy(), cache
     for _ in range(K):
-        t, c = L.serving_tick_block_cache(
+        t, nxt, c = L.serving_tick_block_cache(
             params, cur, jnp.asarray(lens), jnp.asarray(tables), c, CFG, 1)
         want.append(np.asarray(t)[:, 0])
-        cur, lens = t[:, 0], lens + live
+        # the successor of the slots' current tokens: a live slot's new
+        # token, a dead slot's old value
+        np.testing.assert_array_equal(
+            np.asarray(nxt), np.where(live, np.asarray(t)[:, 0],
+                                      np.asarray(cur)))
+        cur, lens = nxt, lens + live
     want = np.stack(want, axis=1)
     seen = {}
     tick = L.serving_tick_cache
@@ -459,10 +464,12 @@ def test_block_tick_free_slots_are_dead_and_live_ones_decode(
         return tick(params, tokens, meta, *a, **kw)
 
     monkeypatch.setattr(L, "serving_tick_cache", spy)
-    got, _ = L.serving_tick_block_cache(
+    got, nxt, _ = L.serving_tick_block_cache(
         params, jnp.asarray(tok), jnp.asarray(lengths),
         jnp.asarray(tables), cache, CFG, K, attn_impl=attn_impl)
     np.testing.assert_array_equal(np.asarray(got)[live], want[live])
+    np.testing.assert_array_equal(np.asarray(nxt),
+                                  np.where(live, want[:, -1], tok))
     np.testing.assert_array_equal(np.asarray(seen["q_len"]), live)
     np.testing.assert_array_equal(np.asarray(seen["tail_live"]), live)
     np.testing.assert_array_equal(np.asarray(seen["tok_slot"]),
