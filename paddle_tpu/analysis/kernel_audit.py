@@ -93,6 +93,7 @@ _KERNEL_MODULES = (
     "int8_matmul",
     "conv_epilogue",
     "fused_norm_rope",
+    "ssd_update",
 )
 
 ALL_RULES = ("KA001", "KA002", "KA003", "KA004")

@@ -37,7 +37,8 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
 # becomes a module.
 SERVING_FAMILIES = {"llama": "LlamaConfig",
                     "qwen2_moe": "Qwen2MoeConfig",
-                    "lfm2_moe": "Lfm2MoeConfig"}
+                    "lfm2_moe": "Lfm2MoeConfig",
+                    "granite_hybrid": "GraniteHybridConfig"}
 
 
 def resolve_family(model, cfg=None):

@@ -29,6 +29,14 @@ layer is its operator's ordinal and its feed-forward's ordinal)::
     moe    ffn_norm [Lm, D]  router [Lm, D, E] f32  expert_bias [Lm, E] f32
            experts.w_gate, .w_up [Lm, E, D, Fm]  .w_down [Lm, E, Fm, D]
 
+THE WALK PIECES that do not know this model's kinds — ``LayerKind``,
+``Group``, ``layer_groups``, ``_layer_params``, the loop over the groups
+(``walk_groups``) and the convolution's window a slot (``earlier_rows``,
+``window_rows``) — live in ``models/layer_walk.py`` since PR 38, shared
+with ``models/granite_hybrid.py``; the names this module always had
+(``LayerKind``, ``Group``, ``_layer_params``, ``layer_kinds``,
+``layer_groups``) stay importable from here.
+
 LAYER KINDS AND THEIR CACHE STATE (ROADMAP D1, begun here): a kind says
 what it keeps between ticks (``KINDS``): pages of K and V
 (``full_attention``) or a fixed row a slot (``conv``).
@@ -56,7 +64,7 @@ at a capacity equal to the cohort (C = N, nothing dropped), as
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 import jax
@@ -66,21 +74,13 @@ from jax import lax
 from ..incubate.moe.functional import moe_ffn
 from ..ops.pallas.ragged_paged_attention import (lane_pack_factor,
                                                  lane_pack_heads)
+from . import layer_walk as _lw
 from . import llama as _llama
+from .layer_walk import (Group, LayerKind,  # noqa: F401  (this module's names)
+                         _layer_params)
 from .llama import _mm, rms_norm, rope
 
 ATTN, CONV = "full_attention", "conv"
-
-
-class LayerKind(NamedTuple):
-    """An operator kind and what it keeps between ticks: ``pages`` (K
-    and V in the paged pool, rebuilt from a prefix's pages) or
-    ``slot_rows`` (a fixed row a slot, which nothing but the tokens
-    themselves can rebuild)."""
-    name: str
-    cache: str
-
-
 KINDS = {ATTN: LayerKind(ATTN, "pages"), CONV: LayerKind(CONV, "slot_rows")}
 
 
@@ -150,48 +150,17 @@ class Lfm2MoeConfig:
 def layer_kinds(cfg: Lfm2MoeConfig):
     """``[(operator, feed-forward, operator's ordinal, feed-forward's
     ordinal)]`` for every layer, in order."""
-    seen: Dict[str, int] = {}
-    out = []
-    for i, op in enumerate(cfg.layer_types):
-        ffn = "dense" if i < cfg.num_dense_layers else "moe"
-        out.append((op, ffn, seen.get(op, 0), seen.get(ffn, 0)))
-        seen[op] = seen.get(op, 0) + 1
-        seen[ffn] = seen.get(ffn, 0) + 1
-    return out
-
-
-class Group(NamedTuple):
-    """``repeats`` times the layers of one pattern: ``layers`` holds
-    ``(operator, feed-forward, operator's ordinal, feed-forward's
-    ordinal)`` for the FIRST repeat, and a kind's ordinal grows by
-    ``stride[kind]`` (its layers in the pattern) with every repeat."""
-    layers: tuple
-    repeats: int
-    stride: dict
+    return _lw.layer_kinds(
+        cfg.layer_types,
+        lambda i: "dense" if i < cfg.num_dense_layers else "moe")
 
 
 def layer_groups(cfg: Lfm2MoeConfig):
-    """The stack as the walk takes it: the leading dense layers (one
-    group, walked once), the whole periods of the expert layers'
-    pattern (one group, SCANNED), the trailing part of a period (one
-    group, walked once). The period is the shortest that the expert
-    layers repeat with."""
-    kinds = layer_kinds(cfg)
-    nd = min(cfg.num_dense_layers, len(kinds))
-    rest = [k[:2] for k in kinds[nd:]]
-    period = next((p for p in range(1, len(rest) + 1)
-                   if all(rest[i] == rest[i % p]
-                          for i in range(len(rest)))), 0)
-    n = len(rest) // period if period else 0
-    groups = []
-    for lo, size, repeats in ((0, nd, 1), (nd, period, n),
-                              (nd + n * period, len(rest) - n * period, 1)):
-        first = tuple(kinds[lo:lo + size])
-        if first and repeats:
-            names = [k for layer in first for k in layer[:2]]
-            groups.append(Group(first, repeats,
-                                {k: names.count(k) for k in set(names)}))
-    return groups
+    """The stack as the walk takes it (``layer_walk.layer_groups``): the
+    leading dense layers (one group, walked once), the whole periods of
+    the expert layers' pattern (one group, SCANNED), the trailing part
+    of a period (one group, walked once)."""
+    return _lw.layer_groups(layer_kinds(cfg), cfg.num_dense_layers)
 
 
 def init_params(cfg: Lfm2MoeConfig, key: jax.Array) -> Dict[str, Any]:
@@ -243,16 +212,6 @@ def abstract_params(cfg: Lfm2MoeConfig):
     """ShapeDtypeStruct pytree of ``init_params`` (tracing-only
     tooling; see models/llama.py abstract_params)."""
     return jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
-
-
-def _layer_params(stack, i):
-    """One layer's parameters out of a kind's stack: a static ordinal
-    inside a group walked once, a traced one inside the scanned group
-    (the dynamic slice a ``lax.scan`` over ``xs`` would make)."""
-    if isinstance(i, int):
-        return jax.tree_util.tree_map(lambda a: a[i], stack)
-    return jax.tree_util.tree_map(
-        lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), stack)
 
 
 # ------------------------------------------------------------ the layers ----
@@ -453,7 +412,6 @@ def _walk(params, h, cache, meta, cfg: Lfm2MoeConfig, tq, attn_impl):
     0 there: mid-prefill) leave every row as it was."""
     from ..ops.pallas.ragged_paged_attention import (
         ragged_paged_attention_packed)
-    S = meta["q_len"].shape[0]
     K = cfg.conv_L_cache
     tok_slot, tok_qoff = meta["tok_slot"], meta["tok_qoff"]
     positions = meta["tok_pos"][None]
@@ -489,32 +447,13 @@ def _walk(params, h, cache, meta, cfg: Lfm2MoeConfig, tq, attn_impl):
 
         def earlier_fn(u):                                      # [1, T, D]
             u = cell["u"] = u[0]
-            slot_rows = rows[tok_slot]                          # [T, K-1, D]
-            prevs = []
-            for d in range(1, K):
-                stream = jnp.concatenate(
-                    [jnp.zeros_like(u[:d]), u[:-d]], axis=0)
-                state = jnp.take_along_axis(
-                    slot_rows, jnp.clip(K - 1 - d + tok_qoff, 0, K - 2)[
-                        :, None, None], axis=1)[:, 0]
-                prev = jnp.where((tok_qoff >= d)[:, None], stream, state)
-                prevs.append(jnp.where((meta["tok_pos"] >= d)[:, None],
-                                       prev, 0)[None])
-            return prevs
+            return _lw.earlier_rows(u, rows, tok_slot, tok_qoff,
+                                    meta["tok_pos"], K)
 
         h = _conv_op(lp, h, cfg, earlier_fn)
         with jax.named_scope("conv_state.write"):
-            u, new = cell["u"], []
-            for r in range(K - 1):
-                back = K - 2 - r            # rows up from the span's last
-                kept = jnp.take_along_axis(
-                    rows[:S], jnp.clip(r + q_len, 0, K - 2)[:, None, None],
-                    axis=1)[:, 0]
-                new.append(jnp.where(
-                    (q_len > back)[:, None],
-                    u[jnp.maximum(last - back, 0)].astype(cs.dtype), kept))
-            new = jnp.where((q_len > 0)[:, None, None],
-                            jnp.stack(new, axis=1), rows[:S])
+            new = _lw.window_rows(cell["u"], rows, q_len, last, K,
+                                  cs.dtype)
             cs = lax.dynamic_update_slice(cs, new[None], (layer, 0, 0, 0))
         return h, cs
 
@@ -539,13 +478,7 @@ def _walk(params, h, cache, meta, cfg: Lfm2MoeConfig, tq, attn_impl):
     # an operation under bare ``layers`` is a loop's own: the slicing
     # of a layer's weights out of its kind's stack
     with jax.named_scope("layers"):
-        for group in layer_groups(cfg):
-            if group.repeats == 1:
-                carry = run(group, carry, 0)
-            else:
-                carry, _ = lax.scan(
-                    lambda c, i, g=group: (run(g, c, i), None), carry,
-                    jnp.arange(group.repeats, dtype=jnp.int32))
+        carry = _lw.walk_groups(layer_groups(cfg), carry, run)
     h, kp, vp, cs = carry
     return h, {"k_pages": kp, "v_pages": vp, "conv_state": cs}
 

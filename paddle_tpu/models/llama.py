@@ -1372,6 +1372,12 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
     ``[L_attn, Hkv, P, ps, Dh]``, and whatever else its layer kinds keep
     (a fixed row a slot, ...); the new cache is the last result.
 
+    TWO SCALARS OF THE CONFIG, read with ``getattr`` as trace-time
+    facts: ``embedding_multiplier`` (the embedding lookup times it) and
+    ``logits_scaling`` (the float32 logits over it, before the sampler
+    and the verify pass). A config without them, or with 1.0, emits no
+    operation (``models/granite_hybrid.py`` sets both).
+
     tokens ``[T]`` i32 — the tick's packed token stream: each live
     slot's current decode token and/or a span of some prompt's next
     uncached tokens, concatenated (padding tokens allowed anywhere).
@@ -1494,6 +1500,12 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
                 tokens < 0, cur[jnp.minimum(meta["tok_slot"], S - 1)],
                 tokens)
         h = params["embed"].astype(cfg.dtype)[tokens[None]]    # [1, T, D]
+        # a family that scales its embedding (trace-time facts of the
+        # config, like the divisor of the logits below: 1.0 emits
+        # nothing)
+        if getattr(cfg, "embedding_multiplier", 1.0) != 1.0:
+            h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
+    logits_scaling = float(getattr(cfg, "logits_scaling", 1.0))
     h, cache_new = walk(params, h, cache, meta, cfg, tq, attn_impl)
     with jax.named_scope("lm_head"):
         h = rms_norm(h[0], params["final_norm"], cfg.rms_norm_eps)  # [T, D]
@@ -1564,6 +1576,8 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
         with jax.named_scope("lm_head"):
             h_ver = h[meta["ver_idx"]]              # [S, 1+spec_k, D]
             logits_ver = _mm(h_ver, params["lm_head"]).astype(jnp.float32)
+            if logits_scaling != 1.0:
+                logits_ver = logits_ver / logits_scaling
         with jax.named_scope("sampler"):
             toks, accept = verify(logits_ver)
         # row 0 == the plain tick's logits for every non-speculating
@@ -1573,6 +1587,8 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
     with jax.named_scope("lm_head"):
         h_last = h[meta["last"]]                                # [S, D]
         logits = _mm(h_last, params["lm_head"]).astype(jnp.float32)
+        if logits_scaling != 1.0:
+            logits = logits / logits_scaling
     with jax.named_scope("sampler"):
         toks = pick(logits, meta["produced"] if samp else None)
     if not decode_tail:

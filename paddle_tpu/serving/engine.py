@@ -542,6 +542,9 @@ class ServingEngine:
             self._slot_state_bytes = sum(
                 int(a.nbytes) for name, a in self._cache.items()
                 if name not in ("k_pages", "v_pages"))
+            # what ONE slot holds of it (the trash row is one more)
+            self._state_bytes_per_slot = (
+                self._slot_state_bytes // (max_batch + 1))
         import jax
         self._jnp = jax.numpy
         self._tick_jit, self._block_jit = _jit_step_fns(
@@ -1493,6 +1496,9 @@ class ServingEngine:
         self.metrics.inc("tick_live_slots", live_slots)
         self.metrics.inc("kv_pages_walked", kv_pages)
         self.metrics.inc("kv_pages_table", table)
+        if self._stateful:
+            self.metrics.inc("slot_state_bytes_moved",
+                             2 * live_slots * self._state_bytes_per_slot)
         return dict(rows=rows, rows_real=rows_real, kv_tokens=kv_tokens,
                     live_slots=live_slots, kv_pages=kv_pages,
                     kv_pages_table=table, **self._tick_layers)

@@ -117,7 +117,10 @@ class ServingMetrics:
     attention launches, the slots that had a query row, the cache
     pages those slots held, and slots x pages_per_slot: walked / table
     is the share of a static walk over the page tables that was
-    needed), and the tick the engine keeps in flight ahead of the host
+    needed; slot_state_bytes_moved — for a model whose layers keep
+    per-slot state, live slots x the state bytes a slot x 2: what the
+    launches had to read once and write once of it), and the tick the
+    engine keeps in flight ahead of the host
     (ticks_ahead — ticks dispatched while another was in flight: over
     the ticks dispatched, the share that engaged; inflight_drains —
     times a tick was completed before its time, also labeled by
@@ -176,7 +179,8 @@ class ServingMetrics:
                 "cold_hits", "cold_hit_pages", "cold_spills",
                 "tick_rows", "tick_rows_real", "kv_tokens_attended",
                 "tick_live_slots", "kv_pages_walked", "kv_pages_table",
-                "prefix_bypassed_stateful", "ticks_ahead",
+                "slot_state_bytes_moved", "prefix_bypassed_stateful",
+                "ticks_ahead",
                 "inflight_drains", "overrun_slot_ticks")
     HISTOGRAMS = ("queue_wait_s", "ttft_s", "decode_step_s",
                   "decode_stall_s", "batch_occupancy",

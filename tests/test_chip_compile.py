@@ -403,6 +403,109 @@ def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
     assert compiled.memory_analysis().alias_size_in_bytes >= pools
 
 
+# granite-4.0-h-micro's serving cell (``granite4h-serve-generate``; the
+# second cell of the traffic ``generate``, so keyed by its own name): the
+# WHOLE model at its published widths and depth, 64 slots, the state-
+# space state 4.57 GiB beside the KV pool
+GRANITE = CELLS["granite4h-serve-generate"]
+
+
+@pytest.mark.parametrize("rows", [GRANITE["slots"],
+                                  GRANITE["slots"] + GRANITE["span"]],
+                         ids=["decode", "span"])
+def test_ssd_update_at_the_cell_s_geometry(chip, monkeypatch, rows):
+    """The state pass over the stacked state with a layer index, in a
+    loop that carries the state as the tick's layer loops do: the loop's
+    temporaries are a sliver of ONE layer's state (the kernel updates
+    the donated buffer in place) and the whole state is aliased."""
+    from paddle_tpu.ops.pallas import ssd_update as K
+    monkeypatch.setattr(K, "_on_tpu", lambda: True)
+    m, S = GRANITE["ssm"], GRANITE["slots"]
+    L, N, HP = m["layers"], m["state"], m["heads"] * m["head_dim"]
+    f32 = functools.partial(sds, dtype=jnp.float32)
+
+    def fn(state, c, b, w, dec, tok_slot):
+        def body(i, carry):
+            ys, state = carry
+            y, state = K.ssd_update(state, i, c, b, w, dec, tok_slot)
+            return ys + y, state
+        return jax.lax.fori_loop(
+            0, L, body, (jnp.zeros((rows, HP), jnp.float32), state))
+
+    text = chip(fn, f32((L, S + 1, N, HP)), f32((rows, N)), f32((rows, N)),
+                f32((rows, HP)), f32((S, HP)), sds((rows,), jnp.int32),
+                donate=(0,))
+    assert "ssd_update" in text.compiled
+    state = L * (S + 1) * N * HP * 4
+    assert text.memory.alias_size_in_bytes >= state
+    assert text.memory.temp_size_in_bytes < N * HP * 4 * S // 8
+
+
+def test_granite_cell_tick_updates_the_state_in_place(topo, chip,
+                                                      monkeypatch):
+    """The engine's jitted tick at the cell's geometry (64 slots + one
+    128-row span), the published model WHOLE (40 layers, 100 352 rows of
+    vocabulary): it compiles for the described chip, holds parameters,
+    cache and temporaries within the chip's 15.75 GiB, aliases the whole
+    cache, and its temporaries stay far under one copy of the state
+    (4.57 GiB): no operation copies it."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    from paddle_tpu.ops.pallas import ssd_update as K
+    from paddle_tpu.serving import engine as E
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if os.path.join(root, "benchmark") not in sys.path:
+        sys.path.insert(0, os.path.join(root, "benchmark"))
+    from harness import manifest
+    monkeypatch.setattr(R, "_on_tpu", lambda: True)
+    monkeypatch.setattr(K, "_on_tpu", lambda: True)
+    cell = manifest.Cell(manifest.load_manifest(),
+                         "granite4h-serve-generate")
+    family = cell.family
+    cfg, mod = family.program_config(cell.model)
+    g = GRANITE
+    S, pps, chunk = g["slots"], g["pps"], g["span"]
+    params = jax.eval_shape(lambda: family.make_params(cell.model, 0))
+    cache = jax.eval_shape(lambda: mod.init_serving_pages(
+        cfg, g["pages"], g["page_size"], max_batch=S))
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    f32 = functools.partial(sds, dtype=jnp.float32)
+    T = S + chunk
+    meta = dict(tok_slot=i32((T,)), tok_pos=i32((T,)), tok_page=i32((T,)),
+                tok_off=i32((T,)), tok_qoff=i32((T,)), q_len=i32((S,)),
+                kv_len=i32((S,)), last=i32((S,)), tables=i32((S, pps)),
+                tail_live=sds((S,), jnp.bool_), cur_tok=i32((S,)),
+                temp=f32((S,)), top_p=f32((S,)), top_k=i32((S,)),
+                key=sds((S, 2), jnp.uint32), produced=i32((S,)))
+    E._JIT_CACHE.clear()        # jit objects of THIS precision context
+    tick, _ = E._jit_step_fns(mod, cfg, "auto")
+    compiled = tick.lower(*on_chip((params, i32((T,)), meta, cache)),
+                          tq=chunk, decode_tail=0).compile()
+    E._JIT_CACHE.clear()
+    text = compiled.as_text()
+    assert "ssd_update" in text and "ragged_paged_attention" in text
+    mem = compiled.memory_analysis()
+    held = sum(math.prod(a.shape) * a.dtype.itemsize
+               for a in jax.tree.leaves(cache))
+    state = math.prod(cache["ssm_state"].shape) * 4
+    assert state == 36 * 65 * 2 * 2 ** 20
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < state // 8
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert live < 15.75 * 2 ** 30
+    # the stacked weights enter the loops as they lie: no re-laid-out
+    # copy of a whole stack (a stack 8512 columns wide was one: 1.2 GiB)
+    assert not re.search(r"= bf16\[36,2048,(4096|4352)\]\S* copy\(", text)
+
+
 def test_splash_fwd(chip):
     from paddle_tpu.ops.pallas.flash_attention import _splash
     fn = functools.partial(_splash, causal=True, sm_scale=DH ** -0.5)

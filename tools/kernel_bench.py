@@ -24,6 +24,11 @@ geometry. On TPU it reads the kernel's device events from a profiler
 trace; off TPU it still runs end-to-end in interpreter mode at a tiny
 size (wall-clock, ``timing_honest: false`` — the smoke path).
 
+``--ssd-sweep`` times the Mamba-2 state pass (``ops/pallas/
+ssd_update.py``) ALONE at the geometry of the serving cells with
+state-space layers: decode rows only and with the cell's span, 0 / 25 /
+100 % of the slots live, ``ms`` and the GB/s of the live slots' state.
+
 ``--block-sweep`` (r23) is the flywheel's write side for the other
 swept kernels: per geometry it times every candidate block shape for
 ``fused_rms_norm`` (row tile), the conv-epilogue matmul (tm/tn/tk),
@@ -204,7 +209,12 @@ def ragged_cells():
     what the KERNEL sees: a head size under the chip's 128 lanes is
     served from a lane-packed pool (the generate cell: 4 KV heads of 8
     query heads at width 128); ``model`` keeps the configuration's own
-    three. The one copy of these numbers: the sweep below and
+    three. A second cell of a traffic name is keyed by its CELL's name
+    (``granite4h-serve-generate``; the first keeps the traffic name). A
+    configuration with state-space layers adds ``ssm``: how many such
+    layers, their heads, head size and state size (what ``ssd_sweep``
+    and the described-chip compile of ``ssd_update`` read). The one
+    copy of these numbers: the sweeps below and
     tests/test_chip_compile.py both read it."""
     from paddle_tpu.ops.pallas.ragged_paged_attention import lane_pack_factor
 
@@ -230,14 +240,20 @@ def ragged_cells():
         ps, slots = eng["page_size"], eng["max_batch"]
         longest = max(eng.get("prompt_buckets") or [eng["max_prompt_len"]])
         pps = -(-(longest + eng["max_new_tokens_cap"] - 1) // ps)
-        cells[w["traffic"]] = dict(
+        kinds = kinds and kinds[:model["num_hidden_layers"]]
+        cell = dict(
             slots=slots, kv_heads=kv // f, group=heads // kv * f,
             head_dim=dh * f, page_size=ps, pps=pps,
             pages=eng.get("total_pages") or slots * pps + 1,
             span=eng["prefill_chunk"],
-            layers=(kinds.count("full_attention") if kinds
-                    else model["num_hidden_layers"]),
+            layers=(sum(k in ("full_attention", "attention") for k in kinds)
+                    if kinds else model["num_hidden_layers"]),
             model=dict(heads=heads, kv_heads=kv, head_dim=dh))
+        if kinds and "mamba" in kinds:
+            cell["ssm"] = dict(
+                layers=kinds.count("mamba"), heads=model["mamba_n_heads"],
+                head_dim=model["mamba_d_head"], state=model["mamba_d_state"])
+        cells[w["name"] if w["traffic"] in cells else w["traffic"]] = cell
     return cells
 
 
@@ -265,6 +281,19 @@ def _kernel_ms(trace_dir, prefix="ragged_paged_attention"):
                         if ev.name.split(" = ", 1)[0].lstrip("%")
                         .startswith(prefix)]
     return [d / 1e6 for _, d in sorted(evs)]
+
+
+def _emit(results, out=None):
+    """A sweep's rows: printed one JSON object a line, and written to
+    ``out`` where one is named."""
+    for row in results:
+        print(json.dumps(row))
+    if out:
+        os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
+        with open(out, "w") as f:
+            for row in results:
+                f.write(json.dumps(row) + "\n")
+    return results
 
 
 def ragged_sweep(out=None, iters=5, cells=None, tiles=(None,), label=""):
@@ -384,14 +413,87 @@ def ragged_sweep(out=None, iters=5, cells=None, tiles=(None,), label=""):
         results.append({"bench": "ragged_sweep", "cell": cell,
                         "resolution": True, **ageom, **(win or {}),
                         "tiling_source": "swept" if win else "default"})
-    for row in results:
-        print(json.dumps(row))
-    if out:
-        os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
-        with open(out, "w") as f:
-            for row in results:
-                f.write(json.dumps(row) + "\n")
-    return results
+    return _emit(results, out)
+
+
+def ssd_sweep(out=None, iters=5, cells=None, label=""):
+    """The state pass of a Mamba-2 layer (``ops/pallas/ssd_update.py``)
+    ALONE at the geometry of every serving cell whose configuration
+    has state-space layers (``ragged_cells()[...]["ssm"]``), through the
+    public entry over a stacked state with a layer index, as the tick
+    launches it: one row for each (cell, decode rows only or with the
+    cell's span, share of the slots live). ``gbps`` is the live slots'
+    state read once and written once over the kernel's device time.
+    Off the chip: a tiny size in interpret mode, the wall clock
+    (``timing_honest: false``)."""
+    import tempfile
+    from paddle_tpu.ops.pallas import ssd_update as K
+    on_tpu = jax.default_backend() == "tpu"
+    table = ({k: v for k, v in ragged_cells().items() if "ssm" in v}
+             if on_tpu else
+             {"tiny": dict(slots=4, span=8,
+                           ssm=dict(heads=2, head_dim=64, state=16))})
+    results = []
+    for cell in cells or table:
+        c = table[cell]
+        S, span, m = c["slots"], c["span"], c["ssm"]
+        N, HP = m["state"], m["heads"] * m["head_dim"]
+        rng = np.random.RandomState(0)
+        state = jnp.asarray(rng.randn(2, S + 1, N, HP), jnp.float32)
+        fn = jax.jit(functools.partial(K.ssd_update, impl="pallas"),
+                     donate_argnums=(0,))
+        runs = []
+        for kind, extra in (("decode", 0), ("span", span)):
+            for share in (0.0, 0.25, 1.0):
+                n_live = int(round(share * S))
+                live = (np.arange(n_live) * S) // max(n_live, 1)
+                # one row a live slot, then the span on the first of
+                # them; the launch's width is the tick's: slots + span
+                T = S + extra
+                tok = np.full((T,), S, np.int32)
+                rows = list(live[:1]) * extra + list(live)
+                tok[:len(rows)] = rows
+                args = (jnp.asarray(1, jnp.int32),
+                        jnp.asarray(rng.randn(T, N), jnp.float32),
+                        jnp.asarray(rng.randn(T, N), jnp.float32),
+                        jnp.asarray(rng.randn(T, HP), jnp.float32),
+                        jnp.asarray(rng.rand(S, HP), jnp.float32),
+                        jnp.asarray(tok))
+                geom = dict(slots=S, rows=T, state=N, lanes=HP)
+                runs.append(({
+                    "bench": "ssd_sweep", "label": label, "cell": cell,
+                    "tick": kind, **geom, "slots_live": share,
+                    "live_slots": n_live,
+                    "state_bytes": 2 * K.state_bytes(n_live, HP, N),
+                    "head_blocks": K.default_head_blocks(T, S, HP, N),
+                    "audit": _audit_verdict("ssd_update", geom, None),
+                    "timing_honest": on_tpu}, fn, args))
+        for _, fn, args in runs:        # compile outside the trace
+            _, state = fn(state, *args)
+        jax.block_until_ready(state)
+        if on_tpu:
+            tdir = tempfile.mkdtemp(prefix=f"kb_ssd_{cell}_")
+            with jax.profiler.trace(tdir):
+                for _, fn, args in runs:
+                    for _ in range(iters):
+                        _, state = fn(state, *args)
+                jax.block_until_ready(state)
+            ms = _kernel_ms(tdir, prefix="ssd_update")
+            assert len(ms) == iters * len(runs), (len(ms), iters, len(runs))
+            for i, (row, _, _) in enumerate(runs):
+                mid = sorted(ms[i * iters:(i + 1) * iters])[iters // 2]
+                results.append(dict(
+                    row, ms=round(mid, 5),
+                    gbps=round(row["state_bytes"] / mid / 1e6, 1)))
+        else:
+            for row, fn, args in runs:
+                t0 = time.perf_counter()
+                for _ in range(iters):
+                    _, state = fn(state, *args)
+                jax.block_until_ready(state)
+                results.append(dict(row, ms=round(
+                    (time.perf_counter() - t0) / iters * 1e3, 4)))
+    return _emit(results, out)
 
 
 def block_sweep(out=None, iters=3):
@@ -552,12 +654,16 @@ def block_sweep(out=None, iters=3):
 if __name__ == "__main__":
     from paddle_tpu.compile_cache import enable_compile_cache
     enable_compile_cache()
-    if "--block-sweep" in sys.argv or "--ragged-sweep" in sys.argv:
+    if {"--block-sweep", "--ragged-sweep", "--ssd-sweep"} & set(sys.argv):
         opt = {a.split("=", 1)[0]: a.split("=", 1)[1] for a in sys.argv
                if a.startswith("--") and "=" in a}
         path = opt.get("--out")
         if "--block-sweep" in sys.argv:
             block_sweep(out=path)
+        elif "--ssd-sweep" in sys.argv:
+            ssd_sweep(out=path, label=opt.get("--label", ""),
+                      cells=(opt["--cells"].split(",") if "--cells" in opt
+                             else None))
         else:
             ragged_sweep(
                 out=path, label=opt.get("--label", ""),
