@@ -36,10 +36,11 @@ def ragged_walk_model(*, kv_len: int, page_size: int, head_dim: int,
     the ragged kernel (ops/pallas/ragged_paged_attention.py) — the
     model decode_profile's long-context ceiling prices the walk with.
 
-    The walk streams each live page exactly once per (slot, kv-head),
-    so HBM bytes are ``2 · L · ceil(kv_len/ps) · ps · Dh`` per kv head;
-    its VMEM residency is O(tile) (``vmem_scratch_bytes``), whatever
-    the table's width — which is why context length is capped by
+    The walk streams each live page exactly once a slot (one strided
+    copy a pool moves its KV heads), so HBM bytes are
+    ``2 · L · ceil(kv_len/ps) · ps · Dh`` per kv head; its VMEM
+    residency is O(heads · tile) (``vmem_scratch_bytes`` of a grid
+    step holding the slot's heads), whatever the table's width — which is why context length is capped by
     bandwidth, not by on-chip memory. ``kv_tile_pages`` None: the
     tile the kernel's geometry selection picks."""
     from ..ops.pallas.ragged_paged_attention import (
@@ -62,7 +63,7 @@ def ragged_walk_model(*, kv_len: int, page_size: int, head_dim: int,
         "vmem_scratch_bytes": vmem_scratch_bytes(
             pages, page_size, head_dim, dtype,
             kv_tile_pages=kv_tile_pages,
-            rows=num_heads // num_kv_heads),
+            rows=num_heads // num_kv_heads, kv_heads=num_kv_heads),
     }
 
 

@@ -27,7 +27,7 @@ Layout contract:
 * ``layer`` (optional): with it the pools are the serving tick's
   STACKED ones, ``[L, Hkv, total_pages, page_size, Dh]``, and the
   kernel reads that layer's pages where they lie (its page DMAs start
-  from ``pages[layer, h, page]``; the index is one more
+  from ``pages[layer, h0:h0+heads, page]``; the index is one more
   scalar-prefetch operand). That is what lets the tick's layer scan
   CARRY the pools — one buffer from the program's parameter to its
   result — where slicing a layer out in front of the kernel copied a
@@ -48,8 +48,12 @@ Layout contract:
   tokens actually cached, not the table width.
 
 **One walk, three trip counts read from the tick's data.** Grid
-``(S, Hkv)``; a program's cost follows what its slot holds, not the
-launch's static extents (slots, table width, query rows a slot):
+``(S, Hkv / heads)``: a grid step is a SLOT and ``heads`` of its KV
+heads — all of them, a grid over slots alone, wherever VMEM holds
+them (``heads_per_step``; every launch of the benchmark's cells but
+the 16-head cell's 256-row span launch, which takes two). A step's
+cost follows what its slot holds, not the launch's static
+extents (slots, table width, query rows a slot):
 
 * *slots*: a slot with ``q_len == 0`` writes its zeros and does
   nothing else — no predicate, no copy, no dot;
@@ -59,23 +63,37 @@ launch's static extents (slots, table width, query rows a slot):
   denominator / accumulator), ``cdiv(kv_len, tile)`` trips, each
   tile's page copies issued and awaited by loops of the tile's live
   page count, DOUBLE-BUFFERED (tile ``t+1``'s copies start while tile
-  ``t`` computes). VMEM is ``O(tile)``, independent of
+  ``t`` computes). A live page moves with ONE copy a pool: the
+  descriptor ``pages[layer, h0:h0+heads, page] -> scratch[buf, p]``
+  is strided over the pool's head axis (the pool's layout is not
+  touched) and lands the step's heads side by side — a copy started
+  costs several times its 4 KiB of bytes, so a slot starts ``2`` a
+  page, not ``2 · Hkv``. VMEM is ``O(heads · tile)``, independent of
   ``pages_per_slot``: a 100k-token table costs the on-chip bytes of a
-  2k one, and every live page is read once a (slot, kv head);
-* *query rows*: rows enter ordered (token, group), so a slot's real
-  rows are its first ``G·q_len``; they are walked in blocks of
-  ``ROW_BLOCK`` rows, ``cdiv(G·q_len, ROW_BLOCK)`` trips inside each
-  KV tile, the flash state of every row block kept in VMEM scratch. A
-  decoding slot in a launch that carries a prefill span costs one row
-  block; the score block in VMEM is ``ROW_BLOCK × tile`` whatever
-  ``Tq`` and ``pages_per_slot`` are.
+  2k one, and every live page is read once a slot;
+* *heads and query rows*: inside a tile, a loop over the step's heads,
+  and inside it over the head's query rows. Rows enter ordered
+  (token, group), so a slot's real rows are its first ``G·q_len``;
+  they are walked in blocks of ``ROW_BLOCK`` rows,
+  ``cdiv(G·q_len, ROW_BLOCK)`` trips, the flash state of every (head,
+  row block) kept in VMEM scratch. A decoding slot in a launch that
+  carries a prefill span costs one row block a head; the score block
+  in VMEM is ``ROW_BLOCK × tile`` whatever ``Tq`` and
+  ``pages_per_slot`` are.
 
 Decode against prefill, short against long, dead against live are
 trip counts of this one loop nest, not paths. A table no wider than
 one tile is walked in one trip: the one-shot walk, by the same code.
 The tile is chosen by geometry alone (``default_kv_tile_pages``, or a
 ``kernel_bench --ragged-sweep`` winner in the autotune store);
-``kv_tile_pages=`` overrides.
+``kv_tile_pages=`` overrides. The heads a step holds follow from the
+tile, the launch's query rows and ``STEP_VMEM_BUDGET``
+(``heads_per_step``: the heads give way, never the tile — a flash step
+costs more than a copy). ``page_copies`` says what a launch starts for
+a live page (the engine's ``kv_page_copies``). A step's last tile
+starts the NEXT live step's first tile into the buffer it leaves free
+(``nxt_ref``), so only a launch's first live slot starts cold; a decode
+launch (one row block a head) takes its heads ``HEAD_UNROLL`` a trip.
 
 Exactness discipline: the kernel is BITWISE-equal to its dense twin
 (``impl="dense"`` at the same ``kv_tile_pages``): the same
@@ -115,19 +133,34 @@ __all__ = ["ragged_paged_attention", "ragged_paged_attention_reference",
 
 _MASK = -1e30  # matches the repo's dense-attention mask value
 
-# bytes of ONE buffer of one pool's tile (the walk holds two buffers of
-# K and two of V): big enough that the tile's dots amortize the DMA
-# turnaround, small enough that the double-buffered K+V scratch stays
-# 512 KiB. At Dh=128/bf16 that is 512 KV tokens a tile. The
-# kernel_bench ragged sweep is the measured A/B over this choice (the
-# first entry of the KForge-style autotune loop, PAPERS.md 2606.02963).
+# bytes of ONE buffer of one pool's tile A HEAD (the walk holds two
+# buffers of K and two of V for each head of the step): big enough that
+# the tile's dots amortize the flash step's fixed cost, small enough
+# that the double-buffered K+V scratch stays 512 KiB a head. At
+# Dh=128/bf16 that is 512 KV tokens a tile. The kernel_bench ragged
+# sweep is the measured A/B over this choice (the first entry of the
+# KForge-style autotune loop, PAPERS.md 2606.02963): on a v5e a tile
+# half as wide costs a decode launch a third more and a span launch
+# half more, whatever it saves in heads a step; a tile twice as wide
+# gains at long contexts and loses at short ones (PERF.md, PR 39).
 DEFAULT_TILE_BYTES = 128 * 2 ** 10
+# what a grid step's scratch and blocks may pin of the chip's 16 MiB of
+# scoped VMEM; the rest is the compiler's (the score blocks: 0.8 MiB at
+# a 128-row block and a 512-token tile). The heads a step holds are
+# chosen under it.
+STEP_VMEM_BUDGET = 14 * 2 ** 20
+# the chip's lane count: the minor dimension a VMEM array is padded to
+LANES = 128
 # query rows a block (rows are (token, group)-ordered; a launch with
 # fewer rows a slot has one block of them all): the score block in
 # VMEM is ROW_BLOCK x tile float32 whatever the launch's Tq is
 ROW_BLOCK = 128
 # page copies started a trip of the copy loop (the last trips take one)
 PAGE_UNROLL = 8
+# heads a trip of a decode launch's head loop (fewer where the step's
+# heads do not divide by it): a flash step of a few query rows is a
+# chain of latencies, and neighbouring heads' chains are independent
+HEAD_UNROLL = 4
 # flash-vs-one-shot exactness contract (the fused-rmsnorm measured-
 # sweep style, analysis/rewrite.py): the flash combine reassociates
 # the softmax sum and rescales the accumulator per tile, so bitwise
@@ -165,17 +198,82 @@ def tiled_ulp_error(got, ref) -> float:
                   / (eps * linf)).max())
 
 
+def _step_vmem_bytes(heads: int, tile_pages: int, page_size: int,
+                     head_dim: int, rows: int, itemsize: int) -> int:
+    """What one grid step of ``heads`` KV heads pins of VMEM as the
+    chip's compiler counts it: the double-buffered K and V tiles, the
+    float32 flash state (each ``(rows, 1)`` column of running max and
+    denominator fills whole 128-lane rows there), and the q and o
+    blocks, which the pipeline double-buffers."""
+    rows = -(-rows // ROW_BLOCK) * ROW_BLOCK if rows > ROW_BLOCK else rows
+    kv = 2 * 2 * heads * tile_pages * page_size * head_dim * itemsize
+    state = heads * rows * (head_dim + 2 * LANES) * 4
+    blocks = 2 * 2 * heads * rows * head_dim * itemsize
+    return kv + state + blocks
+
+
+def heads_per_step(kv_heads: int, tile_pages: int, page_size: int,
+                   head_dim: int, dtype=jnp.bfloat16, rows: int = 0) -> int:
+    """KV heads one grid step holds at a ``tile_pages`` tile and
+    ``rows`` query rows a head: all of them where ``STEP_VMEM_BUDGET``
+    allows (a page then moves with one copy a pool), else the largest
+    divisor of ``kv_heads`` it does. The heads give way, not the tile:
+    a narrower tile costs more flash steps than a second copy a page
+    (module constants above)."""
+    item = jnp.dtype(dtype).itemsize
+    for heads in range(int(kv_heads), 1, -1):
+        if kv_heads % heads == 0 and _step_vmem_bytes(
+                heads, tile_pages, page_size, head_dim, int(rows),
+                item) <= STEP_VMEM_BUDGET:
+            return heads
+    return 1
+
+
 def default_kv_tile_pages(pages_per_slot: int, page_size: int,
                           head_dim: int, dtype=jnp.bfloat16) -> int:
     """Geometry selection of the KV walk's tile, in pages: what
-    ``DEFAULT_TILE_BYTES`` holds of this geometry's rows, and never
-    more than the table (a table that fits one tile is walked in one
-    trip). The engine never chooses: ``serving_tick_cache`` passes
+    ``DEFAULT_TILE_BYTES`` holds of this geometry's rows a head, and
+    never more than the table (a table that fits one tile is walked in
+    one trip). The engine never chooses: ``serving_tick_cache`` passes
     geometry through and this picks per (pages_per_slot, page_size,
-    Dh, dtype)."""
+    Dh, dtype); ``heads_per_step`` then fits the step to VMEM."""
     tokens = DEFAULT_TILE_BYTES // (int(head_dim)
                                     * jnp.dtype(dtype).itemsize)
     return min(int(pages_per_slot), max(1, tokens // int(page_size)))
+
+
+def page_copies(kv_heads: int, pages_per_slot: int, page_size: int,
+                head_dim: int, dtype=jnp.bfloat16, rows: int = 0,
+                kv_tile_pages=None) -> int:
+    """Copies a launch of ``rows`` query rows a (slot, kv head) starts
+    for ONE live page of a layer: one a pool a grid step that walks it
+    (2 where a step holds every head)."""
+    tile = _tile_pages(pages_per_slot, page_size, head_dim, dtype,
+                       kv_tile_pages)
+    return 2 * (int(kv_heads) // heads_per_step(
+        kv_heads, tile, page_size, head_dim, dtype, rows))
+
+
+def _tile_pages(pages_per_slot, page_size, head_dim, dtype,
+                kv_tile_pages) -> int:
+    """``kv_tile_pages`` as the kernel takes it: None the geometry's
+    AUTO, 0 the whole table in one tile. AUTO is the KForge flywheel: a
+    ragged-sweep winner recorded for this geometry overrides the static
+    selection; an unswept geometry (or unset store) keeps the default
+    — either way the same flash-combine math, only retiled."""
+    if kv_tile_pages is None:
+        from .. import autotune as at
+        win = at.lookup("ragged_paged_attention",
+                        pages_per_slot=int(pages_per_slot),
+                        page_size=int(page_size), head_dim=int(head_dim),
+                        dtype=str(jnp.dtype(dtype)))
+        if win is not None and "kv_tile_pages" in win:
+            kv_tile_pages = int(win["kv_tile_pages"])
+        else:
+            return default_kv_tile_pages(pages_per_slot, page_size,
+                                         head_dim, dtype)
+    return min(int(kv_tile_pages) or int(pages_per_slot),
+               int(pages_per_slot))
 
 
 def _row_block(rows: int) -> int:
@@ -186,24 +284,26 @@ def _row_block(rows: int) -> int:
 
 def vmem_scratch_bytes(pages_per_slot: int, page_size: int,
                        head_dim: int, dtype=jnp.bfloat16,
-                       kv_tile_pages=None, rows: int = 0) -> int:
+                       kv_tile_pages=None, rows: int = 0,
+                       kv_heads: int = 1) -> int:
     """VMEM scratch one grid program pins, straight from the kernel's
-    ``scratch_shapes``: two double-buffer tiles of K and of V
-    (``2 · 2 · tile · ps · Dh`` — independent of ``pages_per_slot``
-    past one tile, which is the whole point) plus the float32 flash
-    state (running max, denominator, accumulator) of the launch's
-    ``rows`` query rows a (slot, kv head). ``kv_tile_pages`` as the
-    kernel takes it: None the geometry's default, 0 the whole table in
-    one tile. Shared by the kernel_bench sweep, the decode_profile
-    long-context ceiling and the kernel auditor's KA001 pin."""
-    if kv_tile_pages is None:
-        kv_tile_pages = default_kv_tile_pages(pages_per_slot, page_size,
-                                              head_dim, dtype)
-    tile = min(int(kv_tile_pages) or int(pages_per_slot),
-               int(pages_per_slot))
+    ``scratch_shapes``: for each of the KV heads a step holds
+    (``heads_per_step`` of the launch's ``kv_heads``), two
+    double-buffer tiles of K and of V (``2 · 2 · tile · ps · Dh`` —
+    independent of ``pages_per_slot`` past one tile, which is the whole
+    point) plus the float32 flash state (running max, denominator,
+    accumulator) of the launch's ``rows`` query rows a (slot, kv
+    head). ``kv_tile_pages`` as the kernel takes it: None the
+    geometry's default, 0 the whole table in one tile. Shared by the
+    kernel_bench sweep, the decode_profile long-context ceiling and the
+    kernel auditor's KA001 pin."""
+    pps = int(pages_per_slot)
+    tile = (default_kv_tile_pages(pps, page_size, head_dim, dtype)
+            if kv_tile_pages is None else min(int(kv_tile_pages) or pps, pps))
+    heads = heads_per_step(kv_heads, tile, page_size, head_dim, dtype, rows)
     item = jnp.dtype(dtype).itemsize
-    return (2 * 2 * tile * page_size * head_dim * item
-            + int(rows) * (head_dim + 2) * 4)
+    return heads * (2 * 2 * tile * page_size * head_dim * item
+                    + int(rows) * (head_dim + 2) * 4)
 
 
 def _mxu_dot(a, b, dims):
@@ -342,16 +442,90 @@ def _attend_tiled(qs, ks, vs, r0, q_len, kv_len, g: int, tile_kv: int):
 
 
 def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
-            o_ref, k_scr, v_scr, m_scr, l_scr, acc_scr, sems, *, pps: int,
-            page_size: int, g: int, tile_pages: int, rb: int):
-    """One (slot, kv head): see the module docstring's loop nest. The
-    K/V scratch is ``(2, tile_pages, page_size, Dh)`` a pool — two
-    buffers of one tile — and the flash state ``(rows, 1 | Dh)``
-    float32, a row block's slice loaded and stored around each
-    ``_flash_tile``."""
-    s = pl.program_id(0)
-    h = pl.program_id(1)
+            o_ref, k_scr, v_scr, m_scr, l_scr, acc_scr, sems, nxt_ref, *,
+            pps: int, page_size: int, g: int, tile_pages: int, rb: int):
+    """One slot, and the step's KV heads of it (all of them on a grid
+    over slots alone): see the module docstring's loop nest. The K/V
+    scratch is ``(2, tile_pages, heads, page_size, Dh)`` a pool — two
+    buffers of one tile, a page's heads side by side as ONE copy lands
+    them — and the flash state ``(heads, rows, 1 | Dh)`` float32, a
+    (head, row block)'s slice loaded and stored around each
+    ``_flash_tile``. ``nxt_ref`` (SMEM) hands the next live step the
+    first tile this one started for it: the step's number + 1 (0:
+    none), and the buffer it lands in."""
+    s, j = pl.program_id(0), pl.program_id(1)
+    n_slots, head_steps = pl.num_programs(0), pl.num_programs(1)
+    step = s * head_steps + j
+    heads, n_rows, dh = o_ref.shape
     qn = qlen_ref[s]
+    tile_kv = tile_pages * page_size
+
+    def live_pages(slot):
+        # the table bounds the walk: a kv_len past it (a retiring
+        # slot's overrun in the fused decode tail) is the table's
+        # width, so no tile, page-table entry or key past it is read
+        kn = jnp.minimum(kvlen_ref[slot], pps * page_size)
+        return kn, pl.cdiv(kn, page_size)
+
+    def tile_live_pages(n_pages, t):
+        return jnp.minimum(tile_pages, n_pages - t * tile_pages)
+
+    def start_tile(slot, jh, n_pages, t, buf):
+        # start the K and V copies of the live pages of tile t of
+        # (slot, head step jh): loops of their count, so a slot's
+        # copies cost what it holds and a page past the live range
+        # moves no bytes (its stale scratch is masked by kv_len in
+        # _flash_tile). ONE copy a pool moves a page's heads, strided
+        # over the pool's head axis; a pool's copies into one buffer
+        # share one semaphore.
+        layer = layer_ref[0]
+
+        def start_page(p):
+            page = tab_ref[slot * pps + t * tile_pages + p]
+            pltpu.make_async_copy(
+                kp_ref.at[layer, pl.ds(jh * heads, heads), page],
+                k_scr.at[buf, p], sems.at[0, buf]).start()
+            pltpu.make_async_copy(
+                vp_ref.at[layer, pl.ds(jh * heads, heads), page],
+                v_scr.at[buf, p], sems.at[1, buf]).start()
+
+        def chunk(c, carry):
+            for i in range(PAGE_UNROLL):
+                start_page(c * PAGE_UNROLL + i)
+            return carry
+
+        def rest(p, carry):
+            start_page(p)
+            return carry
+
+        n = tile_live_pages(n_pages, t)
+        whole = n // PAGE_UNROLL
+        jax.lax.fori_loop(0, whole, chunk, 0)
+        jax.lax.fori_loop(whole * PAGE_UNROLL, n, rest, 0)
+
+    def start_next_step(buf):
+        # the slot's last tile computes: start the first tile of the
+        # next live step (this slot's next heads, else the next slot
+        # with a query row) into the buffer that tile leaves free, so
+        # that step does not start cold
+        def dead(c):
+            return (c < n_slots) & (
+                qlen_ref[jnp.minimum(c, n_slots - 1)] == 0)
+
+        more = j + 1 < head_steps
+        s2 = jnp.where(more, s, jax.lax.while_loop(
+            dead, lambda c: c + 1, s + 1))
+        j2 = jnp.where(more, j + 1, 0)
+
+        @pl.when(s2 < n_slots)
+        def _():
+            start_tile(s2, j2, live_pages(s2)[1], 0, buf)
+            nxt_ref[0] = s2 * head_steps + j2 + 1
+            nxt_ref[1] = buf
+
+    @pl.when(step == 0)
+    def _():
+        nxt_ref[0] = 0
 
     @pl.when(qn == 0)
     def _():
@@ -361,48 +535,9 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
 
     @pl.when(qn > 0)
     def _():
-        layer = layer_ref[0]
-        # the table bounds the walk: a kv_len past it (a retiring
-        # slot's overrun in the fused decode tail) is the table's
-        # width, so no tile, page-table entry or key past it is read
-        kn = jnp.minimum(kvlen_ref[s], pps * page_size)
-        n_pages = pl.cdiv(kn, page_size)
-        tile_kv = tile_pages * page_size
+        kn, n_pages = live_pages(s)
         n_tiles = pl.cdiv(kn, tile_kv)
         n_blocks = pl.cdiv(g * qn, rb)
-        dh = k_scr.shape[-1]
-
-        def tile_live_pages(t):
-            return jnp.minimum(tile_pages, n_pages - t * tile_pages)
-
-        def start_tile(t, buf):
-            # start the K and V copies of tile t's live pages: loops of
-            # their count, so a slot's copies cost what it holds and a
-            # page past the live range moves no bytes (its stale
-            # scratch is masked by kv_len in _flash_tile). A pool's
-            # copies into one buffer share one semaphore.
-            def start_page(p):
-                page = tab_ref[s * pps + t * tile_pages + p]
-                pltpu.make_async_copy(kp_ref.at[layer, h, page],
-                                      k_scr.at[buf, p],
-                                      sems.at[0, buf]).start()
-                pltpu.make_async_copy(vp_ref.at[layer, h, page],
-                                      v_scr.at[buf, p],
-                                      sems.at[1, buf]).start()
-
-            def chunk(c, carry):
-                for i in range(PAGE_UNROLL):
-                    start_page(c * PAGE_UNROLL + i)
-                return carry
-
-            def rest(p, carry):
-                start_page(p)
-                return carry
-
-            n = tile_live_pages(t)
-            whole = n // PAGE_UNROLL
-            jax.lax.fori_loop(0, whole, chunk, 0)
-            jax.lax.fori_loop(whole * PAGE_UNROLL, n, rest, 0)
 
         def wait_tile(t, buf):
             # a DMA semaphore counts bytes, so ONE wait a pool awaits a
@@ -413,7 +548,7 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
                 dst = scr.at[(buf, *at)]
                 pltpu.make_async_copy(dst, dst, sems.at[lane, buf]).wait()
 
-            n = tile_live_pages(t)
+            n = tile_live_pages(n_pages, t)
 
             @pl.when(n == tile_pages)
             def _():
@@ -433,51 +568,87 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
             # a launch of one block (fewer rows than ROW_BLOCK, which
             # need not sit on the dtype's sublane tiling) reads it at
             # a static offset
-            if o_ref.shape[0] == rb:
+            if n_rows == rb:
                 return pl.ds(0, rb)
             return pl.ds(pl.multiple_of(b * rb, rb), rb)
 
-        def init_block(b, carry):
-            r = rows(b)
-            m_scr[r], l_scr[r], acc_scr[r] = _flash_init(rb, dh)
-            return carry
+        # a launch of one row block a head (a decode tick) takes no
+        # loop over them, and its heads HEAD_UNROLL a trip
+        one_block = n_rows == rb
+        unroll = math.gcd(HEAD_UNROLL, heads) if one_block else 1
 
-        jax.lax.fori_loop(0, n_blocks, init_block, 0)
-        start_tile(0, 0)
+        def each_head(fn):
+            def trip(i, carry):
+                for u in range(unroll):
+                    fn(i * unroll + u)
+                return carry
+
+            jax.lax.fori_loop(0, heads // unroll, trip, 0)
+
+        def each_block(lo, hi, fn):
+            # fn(head, rows) over the step's heads and row blocks lo..hi
+            def head(h):
+                if one_block:
+                    return fn(h, 0, rows(0))
+
+                def block_body(b, carry):
+                    fn(h, b, rows(b))
+                    return carry
+
+                jax.lax.fori_loop(lo, hi, block_body, 0)
+
+            each_head(head)
+
+        def init_block(h, b, r):
+            m_scr[h, r], l_scr[h, r], acc_scr[h, r] = _flash_init(rb, dh)
+
+        each_block(0, n_blocks, init_block)
+        # the step before may have started this one's first tile
+        started = nxt_ref[0] == step + 1
+        buf0 = jnp.where(started, nxt_ref[1], 0)
+
+        @pl.when(jnp.logical_not(started))
+        def _():
+            start_tile(s, j, n_pages, 0, buf0)
 
         def tile_body(t, carry):
-            buf = jax.lax.rem(t, 2)
+            buf = jax.lax.rem(buf0 + t, 2)
 
             @pl.when(t + 1 < n_tiles)
             def _():
-                start_tile(t + 1, 1 - buf)
+                start_tile(s, j, n_pages, t + 1, 1 - buf)
+
+            @pl.when(t + 1 == n_tiles)
+            def _():
+                start_next_step(1 - buf)
 
             wait_tile(t, buf)
 
-            def block_body(b, carry):
-                r = rows(b)
-                m_scr[r], l_scr[r], acc_scr[r] = _flash_tile(
-                    q_ref[r], k_scr[buf].reshape(tile_kv, dh),
-                    v_scr[buf].reshape(tile_kv, dh), t * tile_kv, b * rb,
-                    qn, kn, g, m_scr[r], l_scr[r], acc_scr[r])
-                return carry
+            def flash_block(h, b, r):
+                # the head's tile is loaded inside the row-block loop:
+                # a span's row blocks are few and the load is VMEM's
+                m_scr[h, r], l_scr[h, r], acc_scr[h, r] = _flash_tile(
+                    q_ref[h, r], k_scr[buf, :, h].reshape(tile_kv, dh),
+                    v_scr[buf, :, h].reshape(tile_kv, dh), t * tile_kv,
+                    b * rb, qn, kn, g, m_scr[h, r], l_scr[h, r],
+                    acc_scr[h, r])
 
-            return jax.lax.fori_loop(0, n_blocks, block_body, carry)
+            each_block(0, n_blocks, flash_block)
+            return carry
 
         jax.lax.fori_loop(0, n_tiles, tile_body, 0)
 
-        def final_block(b, carry):
-            r = rows(b)
-            o_ref[r] = _flash_final(l_scr[r], acc_scr[r], o_ref.dtype)
-            return carry
+        def final_block(h, b, r):
+            o_ref[h, r] = _flash_final(l_scr[h, r], acc_scr[h, r],
+                                       o_ref.dtype)
 
-        jax.lax.fori_loop(0, n_blocks, final_block, 0)
+        each_block(0, n_blocks, final_block)
 
-        def zero_block(b, carry):
-            o_ref[rows(b)] = jnp.zeros((rb, dh), o_ref.dtype)
-            return carry
+        if not one_block:
+            def zero_block(h, b, r):
+                o_ref[h, r] = jnp.zeros((rb, dh), o_ref.dtype)
 
-        jax.lax.fori_loop(n_blocks, o_ref.shape[0] // rb, zero_block, 0)
+            each_block(n_blocks, n_rows // rb, zero_block)
 
 
 @functools.partial(jax.jit,
@@ -488,21 +659,24 @@ def _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables, g,
     multiple of its row block; returns the same shape. k_pages/v_pages
     are the STACKED pools ``[L, Hkv, P, ps, Dh]`` and ``layer`` ``[1]``
     i32 picks the layer whose pages the DMAs read: the pools stay in
-    HBM (``pl.ANY``) whole, nothing is sliced out. The scratch shapes
-    are the whole VMEM story — O(tile) and O(rows), never O(pps)."""
+    HBM (``pl.ANY``) whole, nothing is sliced out. The grid is the
+    slots, times the steps a slot's heads take (one wherever
+    ``heads_per_step`` holds them all). The scratch shapes are the
+    whole VMEM story — O(tile) and O(rows) a head, never O(pps)."""
     S, Hkv, R, Dh = qs.shape
     pps = tables.shape[1]
     page_size = k_pages.shape[3]
     tile_pages = min(int(tile_pages), pps)
+    heads = heads_per_step(Hkv, tile_pages, page_size, Dh, k_pages.dtype, R)
     kernel = functools.partial(_kernel, pps=pps, page_size=page_size, g=g,
                                tile_pages=tile_pages, rb=_row_block(R))
-    block = pl.BlockSpec((None, None, R, Dh),
+    block = pl.BlockSpec((None, heads, R, Dh),
                          lambda s, h, *_: (s, h, 0, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(S, Hkv),
+            grid=(S, Hkv // heads),
             in_specs=[
                 block,
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -510,12 +684,15 @@ def _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables, g,
             ],
             out_specs=block,
             scratch_shapes=[
-                pltpu.VMEM((2, tile_pages, page_size, Dh), k_pages.dtype),
-                pltpu.VMEM((2, tile_pages, page_size, Dh), v_pages.dtype),
-                pltpu.VMEM((R, 1), jnp.float32),
-                pltpu.VMEM((R, 1), jnp.float32),
-                pltpu.VMEM((R, Dh), jnp.float32),
+                pltpu.VMEM((2, tile_pages, heads, page_size, Dh),
+                           k_pages.dtype),
+                pltpu.VMEM((2, tile_pages, heads, page_size, Dh),
+                           v_pages.dtype),
+                pltpu.VMEM((heads, R, 1), jnp.float32),
+                pltpu.VMEM((heads, R, 1), jnp.float32),
+                pltpu.VMEM((heads, R, Dh), jnp.float32),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
             ]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
@@ -578,7 +755,7 @@ def _reference_impl(qs, k_pages, v_pages, q_len, kv_len, tables, g,
 def _layer_pages(pages, layer):
     """One layer's ``[Hkv, P, ps, Dh]`` pages of a stacked pool, for the
     formulations that gather from them (the kernel never slices: it
-    DMAs from ``pages_ref.at[layer, h, page]``)."""
+    DMAs from ``pages_ref.at[layer, heads, page]``)."""
     if layer is None:
         return pages
     return jax.lax.dynamic_index_in_dim(pages, layer, 0, keepdims=False)
@@ -632,30 +809,11 @@ def ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len, tables,
     qs = qs.reshape(S, Tq, Hkv, G, Dh).transpose(0, 2, 1, 3, 4)
     qs = qs.reshape(S, Hkv, Tq * G, Dh)
     use_pallas = impl == "pallas" or (impl == "auto" and _on_tpu())
-    tile = kv_tile_pages
-    if tile is None:
-        if use_pallas:
-            # KForge flywheel: a ragged-sweep winner recorded for this
-            # geometry overrides the static selection; an unswept
-            # geometry (or unset store) keeps the default — either way
-            # the same flash-combine math, only retiled.
-            from .. import autotune as at
-            win = at.lookup("ragged_paged_attention",
-                            pages_per_slot=pps,
-                            page_size=int(page_size),
-                            head_dim=int(Dh),
-                            dtype=str(jnp.dtype(k_pages.dtype)))
-            if win is not None and "kv_tile_pages" in win:
-                tile = int(win["kv_tile_pages"])
-            else:
-                tile = default_kv_tile_pages(pps, page_size, Dh,
-                                             k_pages.dtype)
-        else:
-            tile = 0
-    tile = int(tile)
-    if use_pallas:
-        tile = tile or pps
     rows = Tq * G
+    if use_pallas:
+        tile = _tile_pages(pps, page_size, Dh, k_pages.dtype, kv_tile_pages)
+    else:
+        tile = int(kv_tile_pages or 0)
     if tile:
         # whole row blocks (the twin walks the kernel's)
         pad = -rows % _row_block(rows)
@@ -748,7 +906,6 @@ def _packed_impl(q, k_pages, v_pages, tok_slot, tok_qoff, q_len, kv_len,
 # ``Hkv/f`` heads of ``f*G`` query rows: no second kernel, no new
 # autotune key, every byte of the pool read once, and the MXU passes a
 # 64-deep contraction would be padded to anyway.
-LANES = 128
 
 
 def lane_pack_factor(head_dim: int, num_kv_heads: int) -> int:

@@ -534,6 +534,7 @@ class ServingEngine:
         # its other layer kinds keep (sized by the slots)
         self._cache = dict(self._mod.init_serving_pages(
             cfg, total_pages, page_size, max_batch=max_batch))
+        self._page_copies: Dict[int, int] = {}   # by query rows a slot
         self._tick_layers = {}
         if kinds:
             self._tick_layers = dict(
@@ -1475,19 +1476,40 @@ class ServingEngine:
             return len(plan)
 
     # ----------------------------------------------------- observability ----
+    def _copies_a_page(self, tq: int) -> int:
+        """Copies a tick's attention launches of ``tq`` query rows a
+        slot start for ONE live page, over the attention layers: from
+        the geometry the kernel is launched with (the pool as it
+        stands, lane-packed or not)."""
+        if tq not in self._page_copies:
+            from ..ops.pallas.ragged_paged_attention import page_copies
+            pool = self._cache["k_pages"]
+            layers, kv_heads, _, ps, dh = pool.shape
+            self._page_copies[tq] = layers * page_copies(
+                kv_heads, self.scheduler.pages_per_slot, ps, dh, pool.dtype,
+                rows=tq * (self._cfg.num_attention_heads // kv_heads))
+        return self._page_copies[tq]
+
     def _count_tick(self, rows: int, rows_real: int, kv_tokens: int,
-                    walks) -> dict:
+                    walks, tq: int = 1) -> dict:
         """What a tick launches against what it needs, counted where
         the tick's arrays are built: into the counters (operators) and,
         returned, into the ``serving.tick`` span's args (the profiler's
         annotation then carries them for exactly the ticks traced).
         ``walks``: for each attention launch of the tick (the main
-        step, each fused step after it), the host array of the cache
-        tokens its live slots attend. ``kv_pages / kv_pages_table`` is the share of a
-        static walk over slots x table the launches needed."""
+        step of ``tq`` query rows a slot, each fused step of one after
+        it), the host array of the cache tokens its live slots attend.
+        ``kv_pages / kv_pages_table`` is the share of a static walk
+        over slots x table the launches needed; ``kv_page_copies`` the
+        copies the kernel starts to walk them, all attention layers
+        counted (over ``kv_pages`` x those layers: the copies a
+        page)."""
         ps = self.pool.page_size
         live_slots = sum(len(w) for w in walks)
-        kv_pages = sum(int((-(-w // ps)).sum()) for w in walks)
+        pages = [int((-(-w // ps)).sum()) for w in walks]
+        kv_pages = sum(pages)
+        copies = sum(n * self._copies_a_page(tq if i == 0 else 1)
+                     for i, n in enumerate(pages))
         table = (len(walks) * self.scheduler.max_batch
                  * self.scheduler.pages_per_slot)
         self.metrics.inc("tick_rows", rows)
@@ -1496,12 +1518,14 @@ class ServingEngine:
         self.metrics.inc("tick_live_slots", live_slots)
         self.metrics.inc("kv_pages_walked", kv_pages)
         self.metrics.inc("kv_pages_table", table)
+        self.metrics.inc("kv_page_copies", copies)
         if self._stateful:
             self.metrics.inc("slot_state_bytes_moved",
                              2 * live_slots * self._state_bytes_per_slot)
         return dict(rows=rows, rows_real=rows_real, kv_tokens=kv_tokens,
                     live_slots=live_slots, kv_pages=kv_pages,
-                    kv_pages_table=table, **self._tick_layers)
+                    kv_pages_table=table, kv_page_copies=copies,
+                    **self._tick_layers)
 
     def _record_tick(self, tk: _Tick, t1: float) -> None:
         """Per-tick evidence, at the tick's completion (caller holds the
@@ -1995,7 +2019,7 @@ class ServingEngine:
             + tail * int(kv_len[tail_live].sum())
             + n_tail * tail * (tail + 1) // 2,
             [kv_len[q_len > 0]] + [kv_len[tail_live] + j
-                                   for j in range(1, tail + 1)])
+                                   for j in range(1, tail + 1)], tq=tq)
         # the state the NEXT build reads, advanced before the launch
         for slot, req in live:
             if slot not in drafts:

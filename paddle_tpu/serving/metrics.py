@@ -117,7 +117,10 @@ class ServingMetrics:
     attention launches, the slots that had a query row, the cache
     pages those slots held, and slots x pages_per_slot: walked / table
     is the share of a static walk over the page tables that was
-    needed; slot_state_bytes_moved — for a model whose layers keep
+    needed; kv_page_copies — the copies the attention kernel starts to
+    walk those pages, all attention layers counted: over
+    kv_pages_walked x those layers, the copies a page (one a pool
+    where a grid step holds every KV head); slot_state_bytes_moved — for a model whose layers keep
     per-slot state, live slots x the state bytes a slot x 2: what the
     launches had to read once and write once of it), and the tick the
     engine keeps in flight ahead of the host
@@ -179,7 +182,7 @@ class ServingMetrics:
                 "cold_hits", "cold_hit_pages", "cold_spills",
                 "tick_rows", "tick_rows_real", "kv_tokens_attended",
                 "tick_live_slots", "kv_pages_walked", "kv_pages_table",
-                "slot_state_bytes_moved", "prefix_bypassed_stateful",
+                "kv_page_copies", "slot_state_bytes_moved", "prefix_bypassed_stateful",
                 "ticks_ahead",
                 "inflight_drains", "overrun_slot_ticks")
     HISTOGRAMS = ("queue_wait_s", "ttft_s", "decode_step_s",

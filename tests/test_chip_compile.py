@@ -91,7 +91,8 @@ def _ragged_args(tq, pps, slots=SLOTS, layers=1, pages=None, kv_heads=HKV,
 
 
 def _ragged_walk(pps, group=G):
-    """The kernel as a default call launches it at this table width."""
+    """The kernel as a default call launches it at this table width
+    (the heads a grid step holds follow from the launch's shapes)."""
     from paddle_tpu.ops.pallas import ragged_paged_attention as R
     return functools.partial(
         R._pallas_impl, g=group, interpret=False,
@@ -129,11 +130,23 @@ from tools.kernel_bench import ragged_cells  # noqa: E402
 
 CELLS = ragged_cells()
 CHAT, BATCH_CELL, GENERATE = (CELLS[c] for c in ("chat", "batch", "generate"))
+# granite-4.0-h-micro's cell: the second of the traffic ``generate``, so
+# keyed by its own name
+GRANITE = CELLS["granite4h-serve-generate"]
 _CELL_LAUNCHES = [
     ("chat-decode", CHAT, 1), ("chat-chunk", CHAT, CHAT["span"]),
     ("chat-refused", CHAT, 2 * CHAT["span"]),
     ("batch-decode", BATCH_CELL, 1),
     ("batch-chunk", BATCH_CELL, BATCH_CELL["span"]),
+    # since a grid step holds a slot's KV heads: the two lane-packed
+    # cells' launches as the kernel sees them (4 heads of 8 query rows a
+    # token at width 128), and twice the batch cell's chunk
+    ("batch-wide", BATCH_CELL, 2 * BATCH_CELL["span"]),
+    ("generate-decode", GENERATE, 1),
+    ("generate-chunk", GENERATE, GENERATE["span"]),
+    ("generate-refused", GENERATE, 2 * GENERATE["span"]),
+    ("granite-decode", GRANITE, 1),
+    ("granite-chunk", GRANITE, GRANITE["span"]),
 ]
 
 
@@ -141,12 +154,20 @@ _CELL_LAUNCHES = [
                          ids=[c[0] for c in _CELL_LAUNCHES])
 def test_ragged_layer_indexed_cell_geometry(chip, name, cell, tq):
     """What the serving tick launches per layer: the kernel over the
-    stacked pool with the scan's layer index, at the chat and batch
-    cells' geometries; no cell's table fits one tile, so none selects a
-    walk whose cost follows ``pages_per_slot``."""
+    stacked pool with the scan's layer index, at the four cells'
+    geometries, a grid step holding the slot's KV heads (the scratch
+    and the q and o blocks times the heads: the compiler's scoped VMEM
+    is the judge); no cell's table fits one tile, so none selects a
+    walk whose cost follows ``pages_per_slot``; at a cell's own
+    launches a page moves with one copy a pool, but for the batch
+    cell's 16 heads under a 256-row chunk (two steps a slot)."""
     from paddle_tpu.ops.pallas import ragged_paged_attention as R
     assert (cell["page_size"], cell["head_dim"]) == (PAGE, DH)
     assert R.default_kv_tile_pages(cell["pps"], PAGE, DH) < cell["pps"]
+    if tq <= cell["span"]:
+        assert R.page_copies(cell["kv_heads"], cell["pps"], PAGE, DH,
+                             rows=cell["group"] * tq) == (
+            4 if name == "batch-chunk" else 2)
     chip(_ragged_walk(cell["pps"], cell["group"]),
          *_ragged_args(tq, cell["pps"], cell["slots"], cell["layers"],
                        cell["pages"], cell["kv_heads"], cell["group"]))
@@ -311,7 +332,11 @@ _TWO_LAYERS = {
                      layer_types=["conv", "full_attention"]),
 }
 _CELL_PROGRAMS = [("chat", "tick"), ("batch", "tick"), ("generate", "tick"),
-                  ("chat", "block")]
+                  ("chat", "block"),
+                  # twice the cell's chunk (chat 256 rows a slot, generate
+                  # 128): the kernel's step then holds its heads' flash
+                  # state and blocks at a narrower tile, or fewer heads
+                  ("chat", "tick-wide"), ("generate", "tick-wide")]
 
 
 def _cell_program_args(traffic):
@@ -349,7 +374,8 @@ def _cell_program_args(traffic):
                          ids=["-".join(c) for c in _CELL_PROGRAMS])
 def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
         topo, chip, monkeypatch, traffic, program):
-    """The engine's jitted tick (at the cell's chunk width) and fused
+    """The engine's jitted tick (at the cell's chunk width, and at
+    twice it) and fused
     block, with the slots' current tokens as an operand and their
     successor as a result, compile for the described chip at every
     serving cell's geometry; the successor is one more ``s32[S]`` result
@@ -371,7 +397,8 @@ def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
                 key=sds((S, 2), jnp.uint32), produced=i32((S,)))
     E._JIT_CACHE.clear()        # jit objects of THIS precision context
     tick, block = E._jit_step_fns(mod, cfg, "auto")
-    if program == "tick":
+    if program.startswith("tick"):
+        chunk *= 2 if program == "tick-wide" else 1
         T = S + chunk
         meta = dict(tok_slot=i32((T,)), tok_pos=i32((T,)),
                     tok_page=i32((T,)), tok_off=i32((T,)),
@@ -403,11 +430,9 @@ def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
     assert compiled.memory_analysis().alias_size_in_bytes >= pools
 
 
-# granite-4.0-h-micro's serving cell (``granite4h-serve-generate``; the
-# second cell of the traffic ``generate``, so keyed by its own name): the
+# granite-4.0-h-micro's serving cell (``granite4h-serve-generate``): the
 # WHOLE model at its published widths and depth, 64 slots, the state-
 # space state 4.57 GiB beside the KV pool
-GRANITE = CELLS["granite4h-serve-generate"]
 
 
 @pytest.mark.parametrize("rows", [GRANITE["slots"],

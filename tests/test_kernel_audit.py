@@ -227,7 +227,8 @@ def test_vmem_scratch_bytes_agrees_with_ka001():
     """The bench column and the auditor's KA001 accounting are the
     same number, byte for byte, across the sweep grid: the K+V tiles
     (the whole table where it fits one tile, O(tile) past that) plus
-    the float32 flash state of the audited launch's query rows."""
+    the float32 flash state of the audited launch's query rows, for
+    the KV heads a grid step holds (both of the audited launch's)."""
     from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
     shape = rpa.AUDIT_SHAPE
     rows = shape["group"] * shape["tq"]
@@ -243,7 +244,8 @@ def test_vmem_scratch_bytes_agrees_with_ka001():
                 rules=("KA001",))
             got = sum(row["scratch_bytes"] for row in vmem)
             want = rpa.vmem_scratch_bytes(
-                pps, ps, 128, jnp.bfloat16, kv_tile_pages=tile, rows=rows)
+                pps, ps, 128, jnp.bfloat16, kv_tile_pages=tile, rows=rows,
+                kv_heads=shape["kv_heads"])
             assert got == want, (pps, ps, tile, got, want)
 
 

@@ -529,11 +529,17 @@ def test_tick_counts_match_the_hand_computed_run(params, scripted):
     walked = [(a["live_slots"], a["kv_pages"], a["kv_pages_table"])
               for a in ticks]
     assert walked == [(1, 1, 32), (2, 3, 32), (2, 3, 32), (2, 3, 32)]
+    # the copies the kernel starts to walk them: a grid step holds a
+    # slot's KV heads, so ONE a pool a live page a layer
+    layers = eng._cache["k_pages"].shape[0]
+    assert [a["kv_page_copies"] for a in ticks] == [
+        2 * layers * a["kv_pages"] for a in ticks]
+    assert c["kv_page_copies"] == 2 * layers * c["kv_pages_walked"]
     eng2, _, outs2 = _scripted_run(params, trace=False)
     c2 = eng2.metrics.snapshot()["counters"]
     for k in ("tick_rows", "tick_rows_real", "kv_tokens_attended",
               "tick_live_slots", "kv_pages_walked", "kv_pages_table",
-              "decode_steps", "tokens_out"):
+              "kv_page_copies", "decode_steps", "tokens_out"):
         assert c2[k] == c[k]
     # a disabled ring changes nothing served and records nothing
     assert eng2.tracer.spans() == []

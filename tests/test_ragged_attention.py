@@ -196,6 +196,12 @@ _WALKS = [
     ("scattered_pages", dict(scatter_tables=True), 2),
     ("scattered_pages_tile_1", dict(scatter_tables=True), 1),
     ("stacked_pool", dict(layers=2, scatter_tables=True), 3),
+    # a grid step holds a slot's KV heads: 1, 4, 8 and 16 of them over a
+    # table five tiles wide, dead, decoding and span slots side by side
+    *[(f"kv_heads_{n}", dict(S=5, H=2 * n, Hkv=n, pps=9, P=50,
+                             scatter_tables=True, q_len=[0, 1, 5, 1, 0],
+                             kv_len=[0, 33, 30, 7, 0]), 2)
+      for n in (1, 4, 8, 16)],
 ]
 
 
@@ -407,6 +413,65 @@ def test_scratch_independent_of_table_width_and_rows_of_the_launch():
     # flash state: (max, denominator, accumulator) a query row
     assert (vmem_scratch_bytes(160, ps, dh, rows=512) - walks[0]
             == 512 * (dh + 2) * 4)
+
+
+def test_a_step_that_cannot_hold_every_head_takes_them_in_blocks(
+        monkeypatch):
+    """Where the VMEM budget does not hold a slot's KV heads in one
+    grid step, the step holds the largest divisor of them that fits and
+    a slot takes several steps: the same walk, bitwise against the
+    twin, and the copies a page rise by the steps."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    case = _ragged_case(4, S=5, H=16, Hkv=8, pps=9, P=50,
+                        scatter_tables=True, q_len=[0, 1, 5, 1, 0],
+                        kv_len=[0, 33, 30, 7, 0])
+    geom = dict(kv_heads=8, pages_per_slot=9, page_size=4, head_dim=8,
+                dtype=jnp.float32, rows=12, kv_tile_pages=2)
+    assert R.page_copies(**geom) == 2
+    whole = R._step_vmem_bytes(8, 2, 4, 8, 12, 4)
+    monkeypatch.setattr(R, "STEP_VMEM_BUDGET", whole // 3)
+    assert R.page_copies(**geom) == 2 * 4       # two heads a step
+    R._pallas_impl.clear_cache()
+    try:
+        _twin_and_contract(case, 2)
+    finally:
+        R._pallas_impl.clear_cache()
+
+
+def test_geometry_chosen_tile_fits_the_cells_scoped_vmem():
+    """The four serving cells' launches (``ragged_cells()``: a decode
+    tick, the cell's chunk, twice the chunk): at the geometry's tile
+    (512 KV tokens, never given up) the heads a step the geometry
+    selects keep the scratch, and the whole step with its
+    double-buffered blocks as the chip's compiler counts them, under
+    the budget inside the 16 MiB of scoped VMEM; at the cell's own
+    launches a step holds every KV head, so a page moves with ONE copy
+    a pool — but the batch cell's 16 heads under its 256-row chunk,
+    which take two steps a slot."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    from tools.kernel_bench import ragged_cells
+    cells = ragged_cells()
+    assert len(cells) == 4
+    assert R.STEP_VMEM_BUDGET < 16 * 2 ** 20
+    for name, c in cells.items():
+        kv, ps, dh, pps = (c["kv_heads"], c["page_size"], c["head_dim"],
+                           c["pps"])
+        tile = default_kv_tile_pages(pps, ps, dh, jnp.bfloat16)
+        assert tile == 32
+        for tq in (1, c["span"], 2 * c["span"]):
+            rows = tq * c["group"]
+            heads = R.heads_per_step(kv, tile, ps, dh, jnp.bfloat16, rows)
+            assert kv % heads == 0
+            scratch = vmem_scratch_bytes(pps, ps, dh, jnp.bfloat16,
+                                         rows=rows, kv_heads=kv)
+            step = R._step_vmem_bytes(heads, tile, ps, dh, rows, 2)
+            assert scratch < step <= R.STEP_VMEM_BUDGET, (name, tq)
+            assert scratch == heads * vmem_scratch_bytes(
+                pps, ps, dh, jnp.bfloat16, rows=rows)
+            copies = R.page_copies(kv, pps, ps, dh, jnp.bfloat16, rows)
+            assert copies == 2 * kv // heads
+            if tq <= c["span"]:
+                assert copies == (4 if (name, tq) == ("batch", 256) else 2)
 
 
 # ---------------------------------------------------------------------------

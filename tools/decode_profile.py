@@ -168,9 +168,9 @@ def long_context_ceiling(cfg, bw, weight_bytes,
                          page_size=16):
     """The long-context extension of the same bandwidth ceiling: price
     the decode step at long contexts. The ragged kernel walks a slot's
-    live pages in tiles, each page once per (slot, kv-head)
-    (analysis/serving_graphs.ragged_walk_model), with O(tile) VMEM
-    scratch whatever the table's width: the table shows the ceiling
+    live pages in tiles, each page once a slot (its KV heads in one
+    strided copy a pool; analysis/serving_graphs.ragged_walk_model),
+    with O(heads x tile) VMEM scratch whatever the table's width: the table shows the ceiling
     the bytes alone set, the tiles a step walks and the scratch it
     pins. The measured counterpart is the kernel_bench
     ``--ragged-sweep`` on the chip."""

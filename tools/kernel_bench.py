@@ -301,8 +301,9 @@ def ragged_sweep(out=None, iters=5, cells=None, tiles=(None,), label=""):
     (``ragged_cells()``), through the slot-major entry over a stacked
     pool with a layer index, as the serving tick launches it: one row
     for each (cell, tick kind, share of the slots live, share of the
-    table live, ``kv_tile_pages``). A ``decode`` tick gives every live
-    slot one query row; a ``span`` tick gives the launch the cell's
+    table live, ``kv_tile_pages``), with the ``copies`` the launch
+    starts beside its ``ms`` (live pages x the copies a page). A
+    ``decode`` tick gives every live slot one query row; a ``span`` tick gives the launch the cell's
     prefill chunk of rows a slot, one live slot a whole chunk and the
     others one token. Only the public entry is called, so the same
     file times another checkout's kernel (copy it into that tree):
@@ -334,6 +335,17 @@ def ragged_sweep(out=None, iters=5, cells=None, tiles=(None,), label=""):
         ageom = dict(pages_per_slot=pps, page_size=ps, head_dim=Dh,
                      dtype=str(jnp.dtype(dt)))
         runs = []                       # (row, jitted fn, args)
+
+        def copies_a_page(tile, tq):
+            # copies the walk starts for one live page: the kernel's
+            # own count where it has one (a grid step of several heads
+            # moves them with one copy a pool), else one a (pool, head)
+            count = getattr(R, "page_copies", None)
+            if count is None:
+                return 2 * Hkv
+            return count(Hkv, pps, ps, Dh, dt, rows=tq * G,
+                         kv_tile_pages=tile)
+
         for tile in tiles:
             audit = _audit_verdict(
                 "ragged_paged_attention", ageom,
@@ -365,6 +377,8 @@ def ragged_sweep(out=None, iters=5, cells=None, tiles=(None,), label=""):
                         "live_slots": n_live, "kv_len": kv,
                         "kv_pages": int(n_live * -(-kv // ps)),
                         "kv_pages_table": S * pps,
+                        "copies": int(n_live * -(-kv // ps)
+                                      * copies_a_page(tile, tq)),
                         "kv_tile_pages": tile, "audit": audit,
                         "timing_honest": on_tpu}
                     runs.append((row, fn, (q, kp, vp, jnp.asarray(ql),
