@@ -94,6 +94,7 @@ _KERNEL_MODULES = (
     "conv_epilogue",
     "fused_norm_rope",
     "ssd_update",
+    "mla_paged_attention",
 )
 
 ALL_RULES = ("KA001", "KA002", "KA003", "KA004")

@@ -206,6 +206,9 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
 
     params = mod.abstract_params(cfg)
     cache = _abstract_cache(mod, cfg, slots, pps, page_size)
+    # a family that hands counts back beside its tokens (TICK_COUNTERS:
+    # one more small result in front of the slots' tokens)
+    counts = 1 if getattr(mod, "TICK_COUNTERS", ()) else 0
 
     sds = jax.ShapeDtypeStruct
     i32 = jnp.int32
@@ -228,7 +231,7 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
         (params, sds((T,), i32), _tick_meta(T, slots, pps), cache),
         static_kwargs=dict(cfg=cfg, tq=budget, attn_impl="dense"),
         compute_dtype=cfg.dtype, slots=slots,
-        donated_outputs=_donated(cache, 2), meta=dict(meta)))
+        donated_outputs=_donated(cache, 2 + counts), meta=dict(meta)))
 
     # --- the speculative verify tick: drafted slots as ragged spans +
     # in-graph longest-prefix acceptance. Traced at the
@@ -255,7 +258,7 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
             static_kwargs=dict(cfg=cfg, tq=slots * (1 + spec_k),
                                spec_k=spec_k, attn_impl="dense"),
             compute_dtype=cfg.dtype, slots=slots,
-            donated_outputs=_donated(cache, 3),
+            donated_outputs=_donated(cache, 3 + counts),
             meta=dict(meta, geometry=spec_geom)))
 
     # --- fused decode block: the per-tick hot program (greedy AND
@@ -274,7 +277,7 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
         compute_dtype=cfg.dtype, slots=slots,
         steps_per_call=decode_block, in_decode_loop=True,
         # outputs (toks, tok', cache'): only toks crosses to the host
-        donated_outputs=_donated(cache, 1),
+        donated_outputs=_donated(cache, 1 + counts),
         meta=dict(meta, geometry=geom)))
 
     # --- offline batched decode: generate_paged ----------------------

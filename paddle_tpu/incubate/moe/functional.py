@@ -226,3 +226,95 @@ def moe_ffn(
         y = moe_expert_compute(xs, dispatch, combine, w_gate, w_up, w_down,
                                ep_axis=ep_axis, activation=activation)
     return y.reshape(orig_shape), aux.astype(jnp.float32)
+
+
+def moe_ffn_share(
+    x: jax.Array,
+    router_w: jax.Array,
+    select_bias: Optional[jax.Array],
+    experts: dict,
+    *,
+    held: Tuple[int, int],
+    num_routed: int,
+    zero_experts: int = 0,
+    top_k: int = 2,
+    scale: float = 1.0,
+    layer=None,
+    row_mask: Optional[jax.Array] = None,
+    impl: str = "auto",
+    tile_m: int = 16,
+) -> Tuple[jax.Array, jax.Array]:
+    """The expert layer of ONE CHIP of an expert-parallel deployment: it
+    routes over every router output, computes the part of the experts it
+    HOLDS and the identity experts' part, and drops nothing.
+
+    ``x [N, D]``; ``router_w [D, num_routed + zero_experts]`` (float32
+    router, softmax over all outputs, NOT renormalised over the choice);
+    ``select_bias`` enters the choice of the ``top_k`` only. ``experts``:
+    ``{"w_gate", "w_up": [n, D, F], "w_down": [n, F, D]}``, the ``n =
+    held[1]`` experts ``held[0] .. held[0] + n - 1`` of the
+    ``num_routed`` — or, with ``layer`` (i32 scalar), the model's stacks
+    ``[L, n, ...]`` read at that layer. Outputs ``>= num_routed`` are
+    IDENTITY experts: a choice of one adds ``scale * p * x``, on every
+    chip for its own tokens. A choice of a routed expert this chip does
+    not hold adds nothing HERE (its chip adds it, in a deployment that
+    exchanges tokens); the sum over all shares' held parts plus the
+    identity part once is the uncut layer (``tests/
+    test_longcat_flash.py``).
+
+    ``row_mask [N]`` bool: rows that are no token (a tick's padding)
+    route nowhere and count nowhere. ``impl``: ``auto`` (the grouped
+    matmul of ``ops/pallas/grouped_matmul.py: held_experts_swiglu`` on
+    TPU, a masked einsum over the held experts elsewhere), ``pallas``,
+    ``dense``.
+
+    Returns ``(y [N, D] in x.dtype, counts [4] int32)``: ``counts =
+    [pairs_held, pairs_zero, pairs_absent, experts_touched]``, with
+    ``pairs_held + pairs_zero + pairs_absent = top_k x rows``.
+    """
+    from ...ops.pallas import grouped_matmul as _gmm
+    lo, n = int(held[0]), int(held[1])
+    N, D = x.shape
+    with jax.named_scope("moe.router"):
+        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+        p = jax.nn.softmax(logits, axis=-1)
+        sel = p if select_bias is None else p + select_bias.astype(
+            jnp.float32)[None]
+        _, idx = lax.top_k(sel, top_k)                              # [N, k]
+        w = jnp.take_along_axis(p, idx, axis=-1) * scale
+        if row_mask is not None:
+            w = jnp.where(row_mask[:, None], w, 0.0)
+            # a masked row's choices land nowhere
+            idx = jnp.where(row_mask[:, None], idx, -1)
+        local = idx - lo
+        here = (local >= 0) & (local < n)
+        zero = idx >= num_routed
+    with jax.named_scope("moe.experts"):
+        local = jnp.where(here, local, n).astype(jnp.int32)
+        w_here = jnp.where(here, w, 0.0)
+        if impl == "pallas" or (impl == "auto" and _gmm._on_tpu()):
+            y, rows = _gmm.held_experts_swiglu(
+                x, local, w_here, experts["w_gate"], experts["w_up"],
+                experts["w_down"], layer=layer, tile_m=tile_m)
+        else:
+            ex = experts if layer is None else {
+                k: lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
+                for k, v in experts.items()}
+            onehot = jax.nn.one_hot(local, n + 1, dtype=jnp.float32)[..., :n]
+            per = jnp.einsum("nk,nke->ne", w_here, onehot)          # [N, n]
+            rows = (onehot.sum((0, 1))).astype(jnp.int32)
+            h = jax.nn.silu(jnp.einsum("nd,edf->enf", x, ex["w_gate"]))
+            h = h * jnp.einsum("nd,edf->enf", x, ex["w_up"])
+            y = jnp.einsum("enf,efd,ne->nd", h, ex["w_down"],
+                           per.astype(x.dtype)).astype(jnp.float32)
+    with jax.named_scope("moe.zero"):
+        z = jnp.sum(jnp.where(zero, w, 0.0), axis=-1)               # [N]
+        y = (y + z[:, None] * x.astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("moe.router"):
+        pairs_held = here.sum()
+        pairs_zero = zero.sum()
+        real = (N if row_mask is None else row_mask.sum()) * top_k
+        counts = jnp.stack([pairs_held, pairs_zero,
+                            real - pairs_held - pairs_zero,
+                            (rows > 0).sum()]).astype(jnp.int32)
+    return y, counts

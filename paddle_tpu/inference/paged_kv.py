@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from ..ops.pallas import on_tpu as _on_tpu
 
 __all__ = ["PagePool", "paged_attention_with_tail",
-           "prompt_pages_from_dense", "apply_defrag"]
+           "prompt_pages_from_dense", "apply_defrag", "defrag_pools"]
 
 
 class PagePool:
@@ -361,6 +361,24 @@ def prompt_pages_from_dense(k, v, page_size: int):
     return to_pages(k), to_pages(v), jnp.asarray(tables)
 
 
+def defrag_pools(plan: Dict[int, int], pools, tables):
+    """Rewrite any number of page pools + tables per a
+    ``PagePool.defrag_plan()``: ``pools`` is ``[(array, page_axis)]``
+    (K and V a head, a latent pool, ...: every pool's pages move by the
+    same plan, each along its own axis). Returns ``(arrays, tables)``."""
+    if not plan:
+        return [a for a, _ in pools], tables
+    P_total = pools[0][0].shape[pools[0][1]]
+    src = np.arange(P_total, dtype=np.int32)
+    dst_map = np.arange(P_total, dtype=np.int32)
+    for old, new in plan.items():
+        src[new] = old          # gather: new slot <- old page's contents
+        dst_map[old] = new      # remap: table entries old -> new
+    gather = jnp.asarray(src)
+    moved = [jnp.take(a, gather, axis=axis) for a, axis in pools]
+    return moved, jnp.asarray(dst_map)[jnp.asarray(tables)]
+
+
 def apply_defrag(plan: Dict[int, int], k_pages, v_pages, tables,
                  page_axis: int = -3):
     """Rewrite pool arrays + tables per a ``PagePool.defrag_plan()``.
@@ -370,16 +388,6 @@ def apply_defrag(plan: Dict[int, int], k_pages, v_pages, tables,
     layer-stacked ``[L, Hkv, P, ps, Dh]`` pools alike). ``tables`` is any
     int array of page indices. Returns ``(k_pages, v_pages, tables)``;
     callers then ``commit_defrag(plan)`` on the pool."""
-    if not plan:
-        return k_pages, v_pages, tables
-    P_total = k_pages.shape[page_axis]
-    src = np.arange(P_total, dtype=np.int32)
-    dst_map = np.arange(P_total, dtype=np.int32)
-    for old, new in plan.items():
-        src[new] = old          # gather: new slot <- old page's contents
-        dst_map[old] = new      # remap: table entries old -> new
-    gather = jnp.asarray(src)
-    k_pages = jnp.take(k_pages, gather, axis=page_axis)
-    v_pages = jnp.take(v_pages, gather, axis=page_axis)
-    tables = jnp.asarray(dst_map)[jnp.asarray(tables)]
+    (k_pages, v_pages), tables = defrag_pools(
+        plan, [(k_pages, page_axis), (v_pages, page_axis)], tables)
     return k_pages, v_pages, tables

@@ -38,7 +38,8 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
 SERVING_FAMILIES = {"llama": "LlamaConfig",
                     "qwen2_moe": "Qwen2MoeConfig",
                     "lfm2_moe": "Lfm2MoeConfig",
-                    "granite_hybrid": "GraniteHybridConfig"}
+                    "granite_hybrid": "GraniteHybridConfig",
+                    "longcat_flash": "LongcatFlashConfig"}
 
 
 def resolve_family(model, cfg=None):

@@ -29,6 +29,21 @@ class LayerKind(NamedTuple):
     cache: str
 
 
+class PagePoolSpec(NamedTuple):
+    """One page pool of a family's cache pytree, as its
+    ``cache_page_pools(cfg)`` declares it: the leaf's ``name`` and the
+    axis its pages lie on. What moves pages (defrag, the KV auditor's
+    gathers, chain export and the cold tier where they carry the pool)
+    goes by these, not by the names ``k_pages`` / ``v_pages``."""
+    name: str
+    page_axis: int
+
+
+# what a family that declares nothing holds: K and V a head in two pools
+# ``[L, Hkv, P, ps, Dh]``
+KV_POOLS = (PagePoolSpec("k_pages", 2), PagePoolSpec("v_pages", 2))
+
+
 class Group(NamedTuple):
     """``repeats`` times the layers of one pattern: ``layers`` holds
     ``(operator, feed-forward, operator's ordinal, feed-forward's

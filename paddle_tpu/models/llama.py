@@ -1356,7 +1356,8 @@ def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl, block_fn):
 
 def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
                        decode_tail: int = 0, spec_k: int = 0,
-                       attn_impl: str = "auto", walk=None):
+                       attn_impl: str = "auto", walk=None,
+                       page_pool: str = "k_pages"):
     """ONE ragged serving tick over a model's whole cache pytree: any mix
     of chunked prefills, warm-prefix attaches and decode steps as a
     single static program. Sequence geometry rides in ``meta`` as DEVICE
@@ -1368,9 +1369,12 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
     decode tail are here, once; the layers are ``walk``'s (None: this
     model's, see ``_walk_one_kind``; another model's module hands its
     own). ``cache`` is what the model's ``init_serving_pages`` built and
-    is DONATED by the engine — always with ``k_pages`` / ``v_pages``
-    ``[L_attn, Hkv, P, ps, Dh]``, and whatever else its layer kinds keep
-    (a fixed row a slot, ...); the new cache is the last result.
+    is DONATED by the engine — its page pools (``k_pages`` / ``v_pages``
+    ``[L_attn, Hkv, P, ps, Dh]``, or what the family declares:
+    ``page_pool`` names one whose second-to-last axis is the page's
+    tokens, which is all this function reads of it), and whatever else
+    its layer kinds keep (a fixed row a slot, ...); the new cache is the
+    last result.
 
     TWO SCALARS OF THE CONFIG, read with ``getattr`` as trace-time
     facts: ``embedding_multiplier`` (the embedding lookup times it) and
@@ -1594,7 +1598,7 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
     if not decode_tail:
         return result(toks, toks, logits)
 
-    ps = cache["k_pages"].shape[-2]
+    ps = cache[page_pool].shape[-2]
     pps = meta["tables"].shape[1]
     b_idx = jnp.arange(S, dtype=jnp.int32)
     zeros = jnp.zeros((S,), jnp.int32)
@@ -1622,7 +1626,7 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
                      produced=idx)
         nxt, _, cache_t = serving_tick_cache(
             params, tok, m, cache_t, cfg, tq=1, attn_impl=attn_impl,
-            walk=walk)
+            walk=walk, page_pool=page_pool)
         return (nxt, lens + 1, idx + 1, cache_t), nxt
 
     idx0 = (meta["produced"] + 1) if samp else zeros
@@ -1636,7 +1640,8 @@ def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
 
 def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
                              num_steps: int, attn_impl: str = "auto",
-                             sampling=None, walk=None):
+                             sampling=None, walk=None,
+                             page_pool: str = "k_pages"):
     """``num_steps`` fused decode ticks built on the ragged tick (the
     multi-step scheduling lever: per-call dispatch + host bookkeeping
     amortize over the block) over a model's whole cache pytree and its
@@ -1663,7 +1668,7 @@ def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
     ``(toks [S, num_steps] i32, tok' [S] i32, cache')``."""
     S = tok.shape[0]
     pps = tables.shape[1]
-    ps = cache["k_pages"].shape[-2]
+    ps = cache[page_pool].shape[-2]
     b_idx = jnp.arange(S, dtype=jnp.int32)
     slot = lengths // ps
     # a slot that holds no context (free, or admitted and not yet
@@ -1688,7 +1693,7 @@ def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
                     produced=sampling["produced"])
     toks, _, nxt, cache = serving_tick_cache(
         params, tok, meta, cache, cfg, tq=1, decode_tail=num_steps - 1,
-        attn_impl=attn_impl, walk=walk)
+        attn_impl=attn_impl, walk=walk, page_pool=page_pool)
     if num_steps == 1:
         toks = toks[:, None]
     return toks, nxt, cache

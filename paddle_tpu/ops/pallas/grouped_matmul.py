@@ -296,6 +296,164 @@ def moe_mlp_dropless(x, expert_ids, combine_weights, w_gate, w_up, w_down,
 
 
 # ---------------------------------------------------------------------------
+# held_gmm: the serving block's grouped matmul over the experts a chip HOLDS
+# ---------------------------------------------------------------------------
+# ``gmm`` above walks ROW TILES and fetches the tile's expert: with a
+# static worst-case row count (every pair could land on this chip) nearly
+# all of its grid steps are padding, each re-fetching a weight block.
+# Here a grid step is an EXPERT (and a column block of its weights), each
+# weight block is fetched once whatever the rows, and the expert's row
+# tiles are a loop whose trip count is data: an expert nobody chose costs
+# one empty step and, through ``blk``, no fetch. Rows are sorted by
+# expert, each expert's first row on a ``tile_m`` boundary
+# (``sort_rows_by_held_expert``); weights enter as the model's STACKS
+# ``[L, E, K, N]`` with the layer as a scalar, so no layer is sliced out
+# in front of the call.
+
+def _held_gmm_kernel(layer_ref, blk_ref, first_ref, tiles_ref, lhs_hbm,
+                     *refs, tile_m: int, tile_n: int, gated: bool):
+    del layer_ref, blk_ref                   # the index maps read them
+    rhs = refs[:2] if gated else refs[:1]
+    out_hbm, x_scr, o_scr, sems = refs[len(rhs):]
+    e, j = pl.program_id(0), pl.program_id(1)
+    col = pl.ds(pl.multiple_of(j * tile_n, tile_n), tile_n)
+
+    def tile(t, carry):
+        rows = pl.ds(pl.multiple_of((first_ref[e] + t) * tile_m, tile_m),
+                     tile_m)
+        cp = pltpu.make_async_copy(lhs_hbm.at[rows], x_scr, sems.at[0])
+        cp.start()
+        cp.wait()
+        x = x_scr[...]
+        y = jnp.dot(x, rhs[0][...], preferred_element_type=jnp.float32)
+        if gated:
+            y = jax.nn.silu(y) * jnp.dot(
+                x, rhs[1][...], preferred_element_type=jnp.float32)
+        o_scr[...] = y.astype(o_scr.dtype)
+        cp = pltpu.make_async_copy(o_scr, out_hbm.at[rows, col], sems.at[1])
+        cp.start()
+        cp.wait()
+        return carry
+
+    jax.lax.fori_loop(0, tiles_ref[e], tile, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("tile_m", "tile_n",
+                                             "interpret"))
+def _held_gmm_call(lhs, rhs, layer, blk, first, tiles, tile_m, tile_n,
+                   interpret):
+    """``lhs [M, K]`` rows sorted by expert; ``rhs``: one stack ``[L, E,
+    K, N]`` (``lhs @ W``) or two (``silu(lhs @ W0) * (lhs @ W1)``).
+    Rows outside the experts' live tiles are NOT written."""
+    M, K = lhs.shape
+    _, E, K2, N = rhs[0].shape
+    assert K == K2 and M % tile_m == 0 and N % tile_n == 0
+    w_spec = pl.BlockSpec((None, None, K, tile_n),
+                          lambda e, j, layer, blk, *_: (layer[0], blk[e], 0, j))
+    kernel = functools.partial(_held_gmm_kernel, tile_m=tile_m,
+                               tile_n=tile_n, gated=len(rhs) == 2)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(E, N // tile_n),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
+            + [w_spec] * len(rhs),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((tile_m, K), lhs.dtype),
+                            pltpu.VMEM((tile_m, tile_n), lhs.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
+        interpret=interpret,
+        name="held_experts_matmul",
+    )(layer, blk, first, tiles, lhs, *rhs)
+
+
+def held_tile_n(K: int, N: int, itemsize: int = 2,
+                budget: int = 4 << 20) -> int:
+    """Columns a weight block: the widest power-of-two divisor of ``N``
+    whose ``K x tile_n`` block stays under ``budget`` bytes (the
+    pipeline holds two of each stack's)."""
+    tn = N
+    while tn > 128 and (K * tn * itemsize > budget or N % tn):
+        tn //= 2
+    return tn if N % tn == 0 else N
+
+
+def sort_rows_by_held_expert(local_ids, num_held: int, tile_m: int):
+    """``local_ids [A]``: each assignment's expert among the ``num_held``
+    held ones, or ``num_held`` for an assignment that lands elsewhere.
+    Returns ``(dest [A], first [E], tiles [E], counts [E], m_pad)``:
+    ``dest`` the row of an assignment in the sorted buffer (``m_pad``
+    for one that lands elsewhere: past the buffer), each expert's rows
+    from ``first[e] * tile_m`` on, in ``tiles[e]`` tiles. A counting
+    sort, as ``sort_and_pad_by_expert``."""
+    A = local_ids.shape[0]
+    E = int(num_held)
+    m_pad = (-(-A // tile_m) + E) * tile_m
+    onehot = local_ids[:, None] == jnp.arange(E, dtype=local_ids.dtype)
+    incl = jnp.cumsum(onehot.astype(jnp.int32), axis=0)            # [A, E]
+    counts = incl[-1]
+    here = local_ids < E
+    rank = jnp.take_along_axis(
+        incl, jnp.minimum(local_ids, E - 1)[:, None], axis=1)[:, 0] - 1
+    tiles = -(-counts // tile_m)
+    first = jnp.cumsum(tiles) - tiles
+    dest = jnp.where(
+        here, first[jnp.minimum(local_ids, E - 1)] * tile_m + rank, m_pad)
+    return (dest.astype(jnp.int32), first.astype(jnp.int32),
+            tiles.astype(jnp.int32), counts, m_pad)
+
+
+def held_experts_swiglu(x, local_ids, weights, w_gate, w_up, w_down, *,
+                        layer=None, tile_m: int = 16):
+    """Dropless SwiGLU over the experts this chip holds. ``x [N, D]``;
+    ``local_ids`` / ``weights [N, k]``: every token's ``k`` choices as
+    held-expert ordinals (``E`` = the choice lands elsewhere: it costs
+    nothing here and adds nothing) and their combine weights; ``w_gate``
+    / ``w_up [E, D, F]``, ``w_down [E, F, D]``, or with ``layer`` (i32
+    scalar) the stacks ``[L, E, ...]``. Returns ``(y [N, D] float32,
+    counts [E])``: ``y[n] = sum_j weights[n, j] swiglu(x[n], expert
+    local_ids[n, j])`` over the held choices, and the rows each expert
+    took."""
+    N, D = x.shape
+    k = local_ids.shape[1]
+    if layer is None:
+        w_gate, w_up, w_down, layer = w_gate[None], w_up[None], w_down[None], 0
+    E, F = w_gate.shape[1], w_gate.shape[3]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    flat = local_ids.reshape(-1).astype(jnp.int32)
+    dest, first, tiles, counts, m_pad = sort_rows_by_held_expert(
+        flat, E, tile_m)
+    # the sorted buffer's rows as tokens (a row nothing lands on reads
+    # token 0: finite, and nothing gathers its result)
+    row_token = jnp.zeros((m_pad,), jnp.int32).at[dest].set(
+        jnp.arange(N * k, dtype=jnp.int32) // k, mode="drop")
+    xs = x[row_token]
+    # an expert nobody chose fetches nothing: its steps name the block
+    # of the last expert before it that somebody chose
+    e_idx = jnp.arange(E, dtype=jnp.int32)
+    blk = jax.lax.cummax(jnp.where(counts > 0, e_idx, 0))
+    interp = not _on_tpu()
+    item = x.dtype.itemsize
+    h = _held_gmm_call(xs, (w_gate, w_up), layer, blk, first, tiles,
+                       tile_m=tile_m, tile_n=held_tile_n(D, F, item),
+                       interpret=interp)
+    ys = _held_gmm_call(h, (w_down,), layer, blk, first, tiles,
+                        tile_m=tile_m, tile_n=held_tile_n(F, D, item),
+                        interpret=interp)
+    # pairs are token-major, so the combine is a gather and a sum over
+    # a token's k; a row outside the live tiles was never written
+    here = (flat < E)[:, None]
+    rows = jnp.where(here, ys[jnp.minimum(dest, m_pad - 1)], 0)
+    y = (rows.astype(jnp.float32)
+         * weights.reshape(-1, 1).astype(jnp.float32))
+    return y.reshape(N, k, D).sum(axis=1), counts
+
+
+# ---------------------------------------------------------------------------
 # kernel-audit registration (analysis/kernel_audit.py)
 # ---------------------------------------------------------------------------
 # Geometry keys match moe_mlp_dropless's autotune lookup kwargs, so
@@ -335,7 +493,21 @@ def audit_launches(geom, config=None):
     tn_gate = _fit_tile_n(D, tile_m, tile_n, F, item)
     tn_down = _fit_tile_n(F, tile_m, tile_n, D, item)
     tn_grad = _fit_tile_n(D, tile_m, tile_n, F, item)
-    return [
+    # the serving block's walk over held experts: every expert two row
+    # tiles of 16, the stacks two layers deep, read at layer 1
+    held_m = 16
+    i32 = functools.partial(np.asarray, dtype=np.int32)
+    held = (i32([1]), i32(np.arange(E)), i32(2 * np.arange(E)),
+            i32(np.full((E,), 2)))
+    hx = jax.ShapeDtypeStruct((2 * E * held_m, D), dt)
+    stack = jax.ShapeDtypeStruct((2, E, D, F), dt)
+    held_gate_up = (
+        f"held_gmm_gate_up[{held_m}x{held_tile_n(D, F, item)}]",
+        lambda x, wg, wu, *pre: _held_gmm_call(
+            x, (wg, wu), *pre, tile_m=held_m,
+            tile_n=held_tile_n(D, F, item), interpret=False),
+        (hx, stack, stack, *held))
+    return [held_gate_up,
         (f"gmm_gate[{tile_m}x{tn_gate}]",
          functools.partial(_gmm_call, tile_m=tile_m, tile_n=tn_gate,
                            interpret=False),

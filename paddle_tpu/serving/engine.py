@@ -72,7 +72,7 @@ from collections import deque
 
 import itertools
 
-from ..inference.paged_kv import PagePool, apply_defrag
+from ..inference.paged_kv import PagePool, defrag_pools
 from ..observability import FlightRecorder, RecompileSentinel, SpanTracer
 from .locktrace import get_tracer, host_sync, wrap_lock
 from .metrics import ServingMetrics
@@ -96,6 +96,16 @@ def _cache_kinds(mod, cfg) -> tuple:
     one kind, each layer holding pages of K and V."""
     kinds = getattr(mod, "serving_cache_kinds", None)
     return () if kinds is None else tuple(kinds(cfg))
+
+
+def _page_pools(mod, cfg) -> tuple:
+    """The page pools of the model's cache pytree
+    (``cache_page_pools(cfg)``: name and page axis of each); a model
+    that declares none keeps K and V a head in ``k_pages`` /
+    ``v_pages``."""
+    from ..models.layer_walk import KV_POOLS
+    pools = getattr(mod, "cache_page_pools", None)
+    return KV_POOLS if pools is None else tuple(pools(cfg))
 
 
 from collections import OrderedDict
@@ -180,12 +190,13 @@ class _Tick:
     device handles, and per row the ``(slot, req)`` it was launched for.
     Completion emits a row only if its slot still holds that request."""
 
-    __slots__ = ("no", "outs", "live", "spans", "drafts", "tail",
+    __slots__ = ("no", "outs", "counts", "live", "spans", "drafts", "tail",
                  "admitted", "ahead", "t0", "m0")
 
     def __init__(self, no, live, spans, drafts, tail, ahead):
         self.no = no                # the tick's number
         self.outs = ()              # (toks_d,) or (toks_d, accept_d)
+        self.counts = None          # the family's TICK_COUNTERS, [n] i32
         self.live = live            # decode rows [(slot, req)]
         self.spans = spans          # [(slot, req, start, take)]
         self.drafts = drafts        # {slot: draft tokens} (verify tick)
@@ -534,15 +545,24 @@ class ServingEngine:
         # its other layer kinds keep (sized by the slots)
         self._cache = dict(self._mod.init_serving_pages(
             cfg, total_pages, page_size, max_batch=max_batch))
+        # its page pools by name, and whether they are the K and V pools
+        # that chain export / adopt and the cold tier carry
+        self._pools = _page_pools(self._mod, cfg)
+        self._kv_pools = tuple(p.name for p in self._pools) == (
+            "k_pages", "v_pages")
+        # counts a family's tick programs return beside their tokens
+        # (``TICK_COUNTERS``): added when the tick completes
+        self._tick_counters = tuple(getattr(self._mod, "TICK_COUNTERS", ()))
         self._page_copies: Dict[int, int] = {}   # by query rows a slot
         self._tick_layers = {}
         if kinds:
             self._tick_layers = dict(
                 state_layers=len(self._stateful),
                 attn_layers=sum(k.cache == "pages" for k in kinds))
+            pool_names = {p.name for p in self._pools}
             self._slot_state_bytes = sum(
                 int(a.nbytes) for name, a in self._cache.items()
-                if name not in ("k_pages", "v_pages"))
+                if name not in pool_names)
             # what ONE slot holds of it (the trash row is one more)
             self._state_bytes_per_slot = (
                 self._slot_state_bytes // (max_batch + 1))
@@ -601,7 +621,14 @@ class ServingEngine:
         # match instead of recomputing prefill — see class docstring
         self._cold = (ColdTier(int(cold_tier_bytes))
                       if int(cold_tier_bytes) > 0
-                      and self.prefix_cache is not None else None)
+                      and self.prefix_cache is not None
+                      and self._kv_pools else None)
+        if int(cold_tier_bytes) > 0 and not self._kv_pools:
+            # the cold tier's entries are K and V pages: another pool
+            # is not spilled, and says so
+            self.metrics.inc_labeled(
+                "cold_tier_refused",
+                pool=",".join(p.name for p in self._pools))
         if self._cold is not None:
             self.prefix_cache.spill = self._spill_node
 
@@ -634,31 +661,41 @@ class ServingEngine:
         *out, self._cur_tok_d, self._cache = self._tick_jit(
             self._params, tok_d, dict(meta, cur_tok=self._cur_tok_d),
             self._cache, **static)
-        return out
+        return self._split_counts(out)
 
     def _step_block(self, lengths_d, tables_d, sampling):
-        """Likewise the jitted fused decode block; returns its tokens."""
-        toks_d, self._cur_tok_d, self._cache = self._block_jit(
+        """Likewise the jitted fused decode block; returns its tokens
+        (and the family's counts, or None)."""
+        *out, self._cur_tok_d, self._cache = self._block_jit(
             self._params, self._cur_tok_d, lengths_d, tables_d,
             self._cache, num_steps=self._decode_block, sampling=sampling)
-        return toks_d
+        (toks_d,), counts_d = self._split_counts(out)
+        return toks_d, counts_d
+
+    def _split_counts(self, out):
+        """``(results, counts)``: a family with ``TICK_COUNTERS`` hands
+        its counts back as the last result before the slots' tokens."""
+        if self._tick_counters:
+            return out[:-1], out[-1]
+        return out, None
 
     def _pull_pages(self, idx):
-        """Pages ``idx`` of every layer's K and V on the host, ``[L, Hkv,
-        n, ps, Dh]`` each (the pools' page axis is 2); caller holds the
-        tick lock."""
+        """Pages ``idx`` of every pool on the host, in the pools' order
+        (K and V: ``[L, Hkv, n, ps, Dh]`` each); caller holds the tick
+        lock."""
         jnp = self._jnp
-        k = np.asarray(jnp.take(self._cache["k_pages"], idx, axis=2))  # noqa: PT005 — migration export and cold-tier spill are sanctioned one-shot device pulls
-        v = np.asarray(jnp.take(self._cache["v_pages"], idx, axis=2))  # noqa: PT005 — rides the same pull
-        return k, v
+        return tuple(
+            np.asarray(jnp.take(self._cache[p.name], idx, axis=p.page_axis))  # noqa: PT005 — migration export and cold-tier spill are sanctioned one-shot device pulls
+            for p in self._pools)
 
-    def _write_pages(self, idx, k, v) -> None:
-        """Host pages written back at ``idx`` (caller holds the tick
-        lock)."""
+    def _write_pages(self, idx, *rows) -> None:
+        """Host pages written back at ``idx``, one array a pool in the
+        pools' order (caller holds the tick lock)."""
         jnp = self._jnp
-        for name, rows in (("k_pages", k), ("v_pages", v)):
-            self._cache[name] = self._cache[name].at[:, :, idx].set(
-                jnp.asarray(rows))
+        for p, r in zip(self._pools, rows):
+            at = (slice(None),) * p.page_axis + (idx,)
+            self._cache[p.name] = self._cache[p.name].at[at].set(
+                jnp.asarray(r))
 
     def _refuse_stateful(self, what: str) -> None:
         if self._stateful:
@@ -666,6 +703,15 @@ class ServingEngine:
                 f"{what} is not available for a model with per-slot "
                 f"state ({self._stateful} layers): a chain of pages does "
                 f"not carry the state its prefix left behind")
+        if not self._kv_pools:
+            # the chain blob and the cold tier's entries are K and V
+            # pages; a family with another pool is refused by name
+            pools = ",".join(p.name for p in self._pools)
+            self.metrics.inc_labeled("chain_refused", pool=pools)
+            raise RuntimeError(
+                f"{what} is not available for a cache whose page pools "
+                f"are ({pools}): the chain wire format carries k_pages / "
+                f"v_pages")
 
     # --------------------------------------------------------------- API ----
     def submit(self, prompt, max_new_tokens: int, *,
@@ -1445,10 +1491,12 @@ class ServingEngine:
                 if bad:
                     raise KVInvariantError(
                         bad, context=self._geometry_desc())
-            kp, vp, tables = apply_defrag(
-                plan, self._cache["k_pages"], self._cache["v_pages"],
-                self.scheduler.tables)
-            self._cache.update(k_pages=kp, v_pages=vp)
+            # every pool's pages move by the same plan, each along its
+            # own page axis
+            moved, tables = defrag_pools(
+                plan, [(self._cache[p.name], p.page_axis)
+                       for p in self._pools], self.scheduler.tables)
+            self._cache.update(zip((p.name for p in self._pools), moved))
             # np.array (not asarray): the jnp result is a zero-copy
             # READ-ONLY view, and retire()/admit() write tables in place
             self.scheduler.tables = np.array(tables, np.int32)
@@ -1481,6 +1529,10 @@ class ServingEngine:
         slot start for ONE live page, over the attention layers: from
         the geometry the kernel is launched with (the pool as it
         stands, lane-packed or not)."""
+        if tq not in self._page_copies and not self._kv_pools:
+            # another pool's kernel: the family says what it starts
+            self._page_copies[tq] = int(self._mod.cache_page_copies(
+                self._cfg, self._cache, self.scheduler.pages_per_slot, tq))
         if tq not in self._page_copies:
             from ..ops.pallas.ragged_paged_attention import page_copies
             pool = self._cache["k_pages"]
@@ -1491,7 +1543,7 @@ class ServingEngine:
         return self._page_copies[tq]
 
     def _count_tick(self, rows: int, rows_real: int, kv_tokens: int,
-                    walks, tq: int = 1) -> dict:
+                    walks, tq: int = 1, attn_pairs: int = 0) -> dict:
         """What a tick launches against what it needs, counted where
         the tick's arrays are built: into the counters (operators) and,
         returned, into the ``serving.tick`` span's args (the profiler's
@@ -1503,7 +1555,10 @@ class ServingEngine:
         over slots x table the launches needed; ``kv_page_copies`` the
         copies the kernel starts to walk them, all attention layers
         counted (over ``kv_pages`` x those layers: the copies a
-        page)."""
+        page). ``attn_pairs``: the (query token, key) pairs the
+        launches score, ``q_len x (kv_len - (q_len - 1) / 2)`` a slot:
+        what attention costs where it is bound by arithmetic, as
+        ``kv_tokens`` is what it costs where it is bound by bytes."""
         ps = self.pool.page_size
         live_slots = sum(len(w) for w in walks)
         pages = [int((-(-w // ps)).sum()) for w in walks]
@@ -1515,6 +1570,7 @@ class ServingEngine:
         self.metrics.inc("tick_rows", rows)
         self.metrics.inc("tick_rows_real", rows_real)
         self.metrics.inc("kv_tokens_attended", kv_tokens)
+        self.metrics.inc("attn_score_pairs", attn_pairs)
         self.metrics.inc("tick_live_slots", live_slots)
         self.metrics.inc("kv_pages_walked", kv_pages)
         self.metrics.inc("kv_pages_table", table)
@@ -1523,6 +1579,7 @@ class ServingEngine:
             self.metrics.inc("slot_state_bytes_moved",
                              2 * live_slots * self._state_bytes_per_slot)
         return dict(rows=rows, rows_real=rows_real, kv_tokens=kv_tokens,
+                    attn_pairs=attn_pairs,
                     live_slots=live_slots, kv_pages=kv_pages,
                     kv_pages_table=table, kv_page_copies=copies,
                     **self._tick_layers)
@@ -2013,13 +2070,18 @@ class ServingEngine:
         # drafts' and spans' tokens; the cache tokens those attend
         # (tail step j attends j more per tail-live slot)
         n_tail = int(tail_live.sum())
+        # a fused step is one row a tail-live slot: its cache tokens
+        # are its (query token, key) pairs too
+        tail_tokens = (tail * int(kv_len[tail_live].sum())
+                       + n_tail * tail * (tail + 1) // 2)
         counts = self._count_tick(
             T + S * tail, int(real.sum()) + n_tail * tail,
-            int(kv_len[q_len > 0].sum())
-            + tail * int(kv_len[tail_live].sum())
-            + n_tail * tail * (tail + 1) // 2,
+            int(kv_len[q_len > 0].sum()) + tail_tokens,
             [kv_len[q_len > 0]] + [kv_len[tail_live] + j
-                                   for j in range(1, tail + 1)], tq=tq)
+                                   for j in range(1, tail + 1)], tq=tq,
+            attn_pairs=int((q_len.astype(np.int64) * (
+                2 * kv_len.astype(np.int64) - q_len + 1) // 2).sum())
+            + tail_tokens)
         # the state the NEXT build reads, advanced before the launch
         for slot, req in live:
             if slot not in drafts:
@@ -2039,14 +2101,14 @@ class ServingEngine:
             ph.enter("serving.phase.dispatch", at=t_build)
             if spec:
                 # toks [S, 1+spec_k] i32 + accept [S] i32
-                toks_d, accept_d, _logits_d = self._step_tick(
+                (toks_d, accept_d, _logits_d), tk.counts = self._step_tick(
                     tok_d, meta, tq=tq, decode_tail=0, spec_k=spec)
                 tk.outs = (toks_d, accept_d)
             else:
                 # toks [S] (tail=0) or [S, 1+tail] i32: sampling
                 # happens IN-GRAPH (r16), so no [S, V] logits row ever
                 # crosses to the host
-                toks_d, _logits_d = self._step_tick(
+                (toks_d, _logits_d), tk.counts = self._step_tick(
                     tok_d, meta, tq=tq, decode_tail=tail)
                 tk.outs = (toks_d,)
             ph.stop()
@@ -2073,10 +2135,11 @@ class ServingEngine:
         # slot's length + j cache tokens
         rows = [slot for slot, _ in live]
         lens = self.scheduler.lengths[rows]
+        kv_tokens = k * int(lens.sum()) + len(live) * k * (k + 1) // 2
         counts = self._count_tick(
-            self.scheduler.max_batch * k, len(live) * k,
-            k * int(lens.sum()) + len(live) * k * (k + 1) // 2,
-            [lens + j for j in range(1, k + 1)])
+            self.scheduler.max_batch * k, len(live) * k, kv_tokens,
+            [lens + j for j in range(1, k + 1)],
+            attn_pairs=kv_tokens)   # one query row a step: a pair a token
         lengths = np.zeros_like(self.scheduler.lengths)
         lengths[rows] = lens
         lengths_d = jnp.asarray(lengths)
@@ -2090,7 +2153,9 @@ class ServingEngine:
                               tick=tk.no, kind="block",
                               live=len(live), steps=k, **counts):
             ph.enter("serving.phase.dispatch", at=t_build)
-            tk.outs = (self._step_block(lengths_d, tables_d, sampling),)
+            toks_d, tk.counts = self._step_block(lengths_d, tables_d,
+                                                  sampling)
+            tk.outs = (toks_d,)
             ph.stop()
         return tk
 
@@ -2149,6 +2214,11 @@ class ServingEngine:
         toks = np.asarray(tk.outs[0])  # noqa: PT005 - THE sanctioned per-tick token read-back ([S], [S, 1+tail] or [S, k] i32)
         accept = (np.asarray(tk.outs[1])  # noqa: PT005 - rides the same sync (verify tick: [S] i32)
                   if len(tk.outs) > 1 else None)
+        if tk.counts is not None:
+            # the family's counts ride the same sync: no pull of their own
+            for name, n in zip(self._tick_counters,
+                               np.asarray(tk.counts)):  # noqa: PT005 - rides the token read-back ([n] i32)
+                self.metrics.inc(name, int(n))
         host_sync("serving.tick.readback")
         if ph is not None:
             ph.enter("serving.phase.emit")

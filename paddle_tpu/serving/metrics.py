@@ -113,6 +113,18 @@ class ServingMetrics:
     a draft's or a prompt span's token: the ratio is the share of a
     launch that was not padding; kv_tokens_attended — cache tokens the
     real rows attended, the least the attention kernel had to read;
+    attn_score_pairs — the (query token, key) pairs those launches
+    scored, ``q_len x (kv_len - (q_len - 1) / 2)`` a slot: what a
+    prompt span costs an attention bound by arithmetic;
+    moe_pairs_held / moe_pairs_zero / moe_pairs_absent — for a model
+    that serves ONE CHIP'S SHARE of its experts, the (token, choice)
+    pairs that landed on an expert this chip holds, on an identity
+    expert, and on an expert another chip holds (their sum is
+    ``top_k`` x the rows routed, over the expert layers), and
+    moe_experts_touched — held experts that took at least one row, a
+    launch a layer: their weights are what the expert layer had to
+    read; the four come back from the tick program beside its tokens
+    and are added when the tick completes;
     tick_live_slots, kv_pages_walked, kv_pages_table — over the ticks'
     attention launches, the slots that had a query row, the cache
     pages those slots held, and slots x pages_per_slot: walked / table
@@ -181,6 +193,8 @@ class ServingMetrics:
                 "draft_accepted", "draft_rejected", "handed_back",
                 "cold_hits", "cold_hit_pages", "cold_spills",
                 "tick_rows", "tick_rows_real", "kv_tokens_attended",
+                "attn_score_pairs", "moe_pairs_held", "moe_pairs_zero",
+                "moe_pairs_absent", "moe_experts_touched",
                 "tick_live_slots", "kv_pages_walked", "kv_pages_table",
                 "kv_page_copies", "slot_state_bytes_moved", "prefix_bypassed_stateful",
                 "ticks_ahead",

@@ -530,6 +530,145 @@ def test_granite_cell_tick_updates_the_state_in_place(topo, chip,
     # copy of a whole stack (a stack 8512 columns wide was one: 1.2 GiB)
     assert not re.search(r"= bf16\[36,2048,(4096|4352)\]\S* copy\(", text)
 
+# longcat-flash-chat's serving cell (``longcat-serve-longprompt``): one
+# chip's share of an EP-32 deployment at published widths, 4 layers, a
+# latent pool, 48 slots, 512-row chunks
+
+
+def _longcat():
+    from tools.kernel_bench import mla_cells
+    return mla_cells()["longprompt"]
+
+
+@pytest.mark.parametrize("kind", ["decode", "span"])
+def test_mla_kernel_at_the_cell_s_geometry(chip, kind):
+    """Attention over the latent pages as ONE sublayer's launch: the
+    stacked pool with a layer index, 64 heads over 640-lane rows, the
+    cell's table of 17 408 tokens; a decode launch and one that carries
+    the 512-row chunk. What interpret mode cannot refuse (a page DMA off
+    the tiling, the VMEM of a 1024-row block) fails here."""
+    from paddle_tpu.ops.pallas import mla_paged_attention as K
+    c = _longcat()
+    S, pps, ps, H = c["slots"], c["pps"], c["page_size"], c["heads"]
+    T = S + (c["span"] if kind == "span" else 0)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    fn = functools.partial(
+        K._pallas_impl, heads=H, dv=c["dv"], tb=K.BLOCK_TOKENS,
+        tile_pages=K.default_kv_tile_pages(pps, ps), interpret=False)
+    text = chip(fn, sds(((T + K.BLOCK_TOKENS) * H, c["row_width"])),
+                sds((c["layers"], c["pages"], ps, c["row_width"])),
+                i32((1,)), i32((S,)), i32((S,)), i32((S,)), i32((S, pps)))
+    assert "mla_paged_attention" in text.compiled
+    # the kernel holds a block and two tiles, never the table
+    assert text.memory.temp_size_in_bytes < 2 ** 20
+
+
+def test_held_experts_matmul_at_the_cell_s_geometry(chip, monkeypatch):
+    """The expert share at the cell's widths: 560 rows routed over 768
+    outputs, 16 held experts of 6144 x 2048 read from the model's stacks
+    at a layer index; the grouped matmul's weight blocks fit VMEM and no
+    layer's experts (1.2 GB) are copied in front of the kernel."""
+    from paddle_tpu.incubate.moe.functional import moe_ffn_share
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+    monkeypatch.setattr(G, "_on_tpu", lambda: True)
+    N, D, F, E, L = 560, 6144, 2048, 16, 4
+
+    def fn(x, router, bias, wg, wu, wd, layer, mask):
+        return moe_ffn_share(
+            x, router, bias, {"w_gate": wg, "w_up": wu, "w_down": wd},
+            held=(0, E), num_routed=512, zero_experts=256, top_k=12,
+            scale=6.0, layer=layer, row_mask=mask)
+
+    f32 = functools.partial(sds, dtype=jnp.float32)
+    text = chip(fn, sds((N, D)), f32((D, 768)), f32((768,)),
+                sds((L, E, D, F)), sds((L, E, D, F)), sds((L, E, F, D)),
+                sds((), jnp.int32), sds((N,), jnp.bool_))
+    assert text.compiled.count("held_experts_matmul") >= 2
+    assert text.memory.temp_size_in_bytes < E * 3 * D * F * 2 // 4
+
+
+@pytest.mark.parametrize("program", ["tick", "block"])
+def test_longcat_cell_programs_hold_the_latent_pool_once(topo, chip,
+                                                         monkeypatch,
+                                                         program):
+    """The engine's jitted tick (48 slots + one 512-row chunk) and fused
+    block at the cell's geometry and published widths: they compile for
+    the described chip, the latent pool is aliased (held once), the
+    tick holds at least 80 % of the chip's 15.75 GiB and fits it, the
+    share's counts come back as one more ``s32[4]`` result beside the
+    tokens, and no stack of weights is re-laid out in front of the
+    layer loop."""
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+    from paddle_tpu.ops.pallas import mla_paged_attention as K
+    from paddle_tpu.serving import engine as E
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if os.path.join(root, "benchmark") not in sys.path:
+        sys.path.insert(0, os.path.join(root, "benchmark"))
+    from harness import manifest
+    monkeypatch.setattr(K, "_on_tpu", lambda: True)
+    monkeypatch.setattr(G, "_on_tpu", lambda: True)
+    cell = manifest.Cell(manifest.load_manifest(),
+                         "longcat-serve-longprompt")
+    family = cell.family
+    cfg, mod = family.program_config(cell.model)
+    g = _longcat()
+    S, pps, chunk = g["slots"], g["pps"], g["span"]
+    params = jax.eval_shape(lambda: family.make_params(cell.model, 0))
+    cache = jax.eval_shape(lambda: mod.init_serving_pages(
+        cfg, g["pages"], g["page_size"], max_batch=S))
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    f32 = functools.partial(sds, dtype=jnp.float32)
+    samp = dict(temp=f32((S,)), top_p=f32((S,)), top_k=i32((S,)),
+                key=sds((S, 2), jnp.uint32), produced=i32((S,)))
+    E._JIT_CACHE.clear()        # jit objects of THIS precision context
+    tick, block = E._jit_step_fns(mod, cfg, "auto")
+    if program == "tick":
+        T = S + chunk
+        meta = dict(tok_slot=i32((T,)), tok_pos=i32((T,)),
+                    tok_page=i32((T,)), tok_off=i32((T,)),
+                    tok_qoff=i32((T,)), q_len=i32((S,)), kv_len=i32((S,)),
+                    last=i32((S,)), tables=i32((S, pps)),
+                    tail_live=sds((S,), jnp.bool_), cur_tok=i32((S,)),
+                    **samp)
+        compiled = tick.lower(*on_chip((params, i32((T,)), meta, cache)),
+                              tq=chunk, decode_tail=0).compile()
+        results = 4             # toks, logits, counts, cur_tok'
+    else:
+        compiled = block.lower(
+            *on_chip((params, i32((S,)), i32((S,)), i32((S, pps)), cache)),
+            num_steps=1, sampling=on_chip(samp)).compile()
+        results = 3             # toks, counts, cur_tok'
+    E._JIT_CACHE.clear()
+    text = compiled.as_text()
+    assert "mla_paged_attention" in text and "held_experts_matmul" in text
+    outs = jax.tree.leaves(compiled.out_info)
+    assert len(outs) == results + 1
+    counts, nxt, pool = outs[results - 2:]
+    assert (counts.shape, counts.dtype) == ((4,), jnp.int32)
+    assert (nxt.shape, nxt.dtype) == ((S,), jnp.int32)
+    assert pool.shape == cache[mod.POOL].shape == (
+        8, g["pages"], g["page_size"], 640)
+    mem = compiled.memory_analysis()
+    held = math.prod(pool.shape) * 2
+    assert mem.alias_size_in_bytes >= held
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0.80 * 15.75 * 2 ** 30 <= live < 15.75 * 2 ** 30
+    assert mem.temp_size_in_bytes < held // 8
+    # the stacked weights enter the layer loop as they lie (held as the
+    # published matrices, q_b_proj and kv_b_proj were copied whole in
+    # every tick: 436 MB)
+    assert not re.search(r"= bf16\[8,(1536|512|64),\d+,?\d*\]\S* copy\(",
+                         text)
+
 
 def test_splash_fwd(chip):
     from paddle_tpu.ops.pallas.flash_attention import _splash

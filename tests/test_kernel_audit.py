@@ -198,8 +198,8 @@ def test_clean_tree_pin(monkeypatch):
                        rep["stale_waivers"])
     assert sorted(rep["kernels"]) == [
         "conv_epilogue", "flash_attention", "fused_norm_rope",
-        "grouped_matmul", "int8_matmul", "ragged_paged_attention",
-        "ssd_update"]
+        "grouped_matmul", "int8_matmul", "mla_paged_attention",
+        "ragged_paged_attention", "ssd_update"]
     # non-vacuity: every rule actually evaluated something
     assert all(rep["rule_evals"][r] > 0 for r in ka.ALL_RULES), \
         rep["rule_evals"]
@@ -213,7 +213,8 @@ def test_clean_tree_pin(monkeypatch):
 def test_kernel_signatures_cover_autotuned_kinds():
     sigs = ka.kernel_signatures()
     assert set(sigs) == {"ragged_paged_attention", "fused_rms_norm",
-                         "conv_epilogue", "grouped_matmul", "ssd_update"}
+                         "conv_epilogue", "grouped_matmul", "ssd_update",
+                         "mla_paged_attention"}
     assert tuple(sigs["fused_rms_norm"]["config_keys"]) == ("tile_n",)
     # geom_keys are kept sorted — the store validator compares them
     # against sorted(loaded geometry) keys

@@ -24,6 +24,11 @@ geometry. On TPU it reads the kernel's device events from a profiler
 trace; off TPU it still runs end-to-end in interpreter mode at a tiny
 size (wall-clock, ``timing_honest: false`` — the smoke path).
 
+``--mla-sweep`` times attention over latent pages (``ops/pallas/
+mla_paged_attention.py``) ALONE at the geometry of every cell with a
+latent cache (``mla_cells()``), and the expanded form of a span as plain
+XLA beside it.
+
 ``--ssd-sweep`` times the Mamba-2 state pass (``ops/pallas/
 ssd_update.py``) ALONE at the geometry of the serving cells with
 state-space layers: decode rows only and with the cell's span, 0 / 25 /
@@ -197,27 +202,11 @@ def _walltime(f, args, n=3):
     return best * 1e3
 
 
-def ragged_cells():
-    """What ONE layer's launch of the ragged kernel looks like in each
-    serving cell of the benchmark, keyed by the cell's traffic name
-    (chat, batch, generate), READ from the files the cell runs from:
-    ``BENCHMARK.json``, the cell's workload file (slots, page size,
-    the table's width as the engine sizes it, the pool, ``span``: the
-    prefill chunk, the query rows a slot of a span tick gets) and its
-    configuration with the workload's overrides (heads, head size, how
-    many layers attend). ``kv_heads`` / ``group`` / ``head_dim`` are
-    what the KERNEL sees: a head size under the chip's 128 lanes is
-    served from a lane-packed pool (the generate cell: 4 KV heads of 8
-    query heads at width 128); ``model`` keeps the configuration's own
-    three. A second cell of a traffic name is keyed by its CELL's name
-    (``granite4h-serve-generate``; the first keeps the traffic name). A
-    configuration with state-space layers adds ``ssm``: how many such
-    layers, their heads, head size and state size (what ``ssd_sweep``
-    and the described-chip compile of ``ssd_update`` read). The one
-    copy of these numbers: the sweeps below and
-    tests/test_chip_compile.py both read it."""
-    from paddle_tpu.ops.pallas.ragged_paged_attention import lane_pack_factor
-
+def _serving_cells():
+    """``(workloads entry, model, engine geometry, slots x table)`` of
+    every serving cell of ``BENCHMARK.json``, READ from the files the
+    cell runs from: its workload file and its configuration with the
+    workload's overrides."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
     def read(*path):
@@ -226,26 +215,53 @@ def ragged_cells():
 
     bench = read("BENCHMARK.json")
     files = {c["name"]: c["file"] for c in bench["configs"]}
-    cells = {}
     for w in bench["workloads"]:
         work = read("benchmark", "workloads", w["name"] + ".json")
         if not work.get("mode", "").startswith("serve"):
             continue
         model = {**read(files[w["config"]]), **work.get("overrides", {})}
         eng = work["engine"]
+        ps, slots = eng["page_size"], eng["max_batch"]
+        longest = max(eng.get("prompt_buckets") or [eng["max_prompt_len"]])
+        pps = -(-(longest + eng["max_new_tokens_cap"] - 1) // ps)
+        yield w, model, dict(
+            slots=slots, page_size=ps, pps=pps,
+            pages=eng.get("total_pages") or slots * pps + 1,
+            span=eng["prefill_chunk"])
+
+
+def ragged_cells():
+    """What ONE layer's launch of the ragged kernel looks like in each
+    serving cell of the benchmark that launches it, keyed by the cell's
+    traffic name (chat, batch, generate), READ from the files the cell
+    runs from (``_serving_cells``: slots, page size, the table's width
+    as the engine sizes it, the pool, ``span``: the prefill chunk, the
+    query rows a slot of a span tick gets; heads, head size, how many
+    layers attend). ``kv_heads`` / ``group`` / ``head_dim`` are what
+    the KERNEL sees: a head size under the chip's 128 lanes is served
+    from a lane-packed pool (the generate cell: 4 KV heads of 8 query
+    heads at width 128); ``model`` keeps the configuration's own three.
+    A second cell of a traffic name is keyed by its CELL's name
+    (``granite4h-serve-generate``; the first keeps the traffic name). A
+    configuration with state-space layers adds ``ssm``: how many such
+    layers, their heads, head size and state size (what ``ssd_sweep``
+    and the described-chip compile of ``ssd_update`` read). A cell
+    whose cache is latent pages is ``mla_cells()``'s. The one copy of
+    these numbers: the sweeps below and tests/test_chip_compile.py both
+    read it."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import lane_pack_factor
+
+    cells = {}
+    for w, model, geo in _serving_cells():
+        if "kv_lora_rank" in model:
+            continue
         heads, kv = model["num_attention_heads"], model["num_key_value_heads"]
         dh = model.get("head_dim") or model["hidden_size"] // heads
         kinds = model.get("layer_types")
         f = lane_pack_factor(dh, kv)
-        ps, slots = eng["page_size"], eng["max_batch"]
-        longest = max(eng.get("prompt_buckets") or [eng["max_prompt_len"]])
-        pps = -(-(longest + eng["max_new_tokens_cap"] - 1) // ps)
         kinds = kinds and kinds[:model["num_hidden_layers"]]
         cell = dict(
-            slots=slots, kv_heads=kv // f, group=heads // kv * f,
-            head_dim=dh * f, page_size=ps, pps=pps,
-            pages=eng.get("total_pages") or slots * pps + 1,
-            span=eng["prefill_chunk"],
+            geo, kv_heads=kv // f, group=heads // kv * f, head_dim=dh * f,
             layers=(sum(k in ("full_attention", "attention") for k in kinds)
                     if kinds else model["num_hidden_layers"]),
             model=dict(heads=heads, kv_heads=kv, head_dim=dh))
@@ -255,6 +271,22 @@ def ragged_cells():
                 head_dim=model["mamba_d_head"], state=model["mamba_d_state"])
         cells[w["name"] if w["traffic"] in cells else w["traffic"]] = cell
     return cells
+
+
+def mla_cells():
+    """``ragged_cells()`` for the cells whose cache is LATENT pages
+    (``ops/pallas/mla_paged_attention.py``), keyed by the cell's traffic
+    name: the same geometry, the heads, the pool row's lanes
+    (``row_width``), the value's lane prefix (``dv``) and the attention
+    SUBLAYERS (two a layer)."""
+    from paddle_tpu.ops.pallas.mla_paged_attention import latent_row_width
+
+    return {w["traffic"]: dict(
+        geo, heads=model["num_attention_heads"],
+        row_width=latent_row_width(model["kv_lora_rank"],
+                                   model["qk_rope_head_dim"]),
+        dv=model["kv_lora_rank"], layers=2 * model["num_layers"])
+        for w, model, geo in _serving_cells() if "kv_lora_rank" in model}
 
 
 # off the chip: the same shape of sweep at a size interpret mode can run
@@ -427,6 +459,139 @@ def ragged_sweep(out=None, iters=5, cells=None, tiles=(None,), label=""):
         results.append({"bench": "ragged_sweep", "cell": cell,
                         "resolution": True, **ageom, **(win or {}),
                         "tiling_source": "swept" if win else "default"})
+    return _emit(results, out)
+
+
+def mla_sweep(out=None, iters=5, cells=None, label="", blocks=(None,)):
+    """Attention over latent pages (``ops/pallas/mla_paged_attention.py``)
+    ALONE at the geometry of every cell with a latent cache
+    (``mla_cells()``), through the public entry over a stacked pool with
+    a layer index, as the tick launches it: one row for each (cell, tick
+    kind, share of the slots live, context, ``block_tokens``). A
+    ``decode`` launch gives every live slot one token (``heads`` rows);
+    a ``span`` launch adds the cell's prefill chunk on one more slot,
+    whose context is the row's ``kv_len`` at the span's END. ``pct`` is
+    the share of the roofline the benchmark's reader would give the
+    launch (the longer of the published latent bytes over the peak
+    bandwidth and the absorbed form's FLOPs over the peak rate; v5e
+    peaks). ``expanded`` rows time the OTHER form of a span as plain
+    XLA: ``kv_b`` applied to the span's context and a dense causal
+    softmax over it (what a program that expands would add to a span;
+    its decode rows would stay absorbed). Off the chip: a tiny size in
+    interpret mode, the wall clock (``timing_honest: false``)."""
+    import tempfile
+    from paddle_tpu.ops.pallas import mla_paged_attention as K
+    on_tpu = jax.default_backend() == "tpu"
+    table = mla_cells() if on_tpu else {"tiny": dict(
+        slots=4, page_size=16, pps=6, pages=25, span=16, heads=4,
+        row_width=128, dv=64, layers=2)}
+    dt = jnp.bfloat16 if on_tpu else jnp.float32
+    results = []
+    for cell in cells or table:
+        c = table[cell]
+        S, ps, pps, P = c["slots"], c["page_size"], c["pps"], c["pages"]
+        H, dk, dv, span = c["heads"], c["row_width"], c["dv"], c["span"]
+        rng = np.random.RandomState(0)
+        pool = jax.random.normal(jax.random.PRNGKey(0), (2, P, ps, dk), dt)
+        tabs = jnp.asarray(1 + rng.randint(0, P - 1, (S, pps)), jnp.int32)
+        cap = pps * ps
+        contexts = sorted({min(cap, x) for x in
+                           ((span, 2 * span, cap) if not on_tpu else
+                            (512, 2048, 4096, 8192, cap))})
+        runs = []
+        for tb in blocks:
+            fn = jax.jit(functools.partial(
+                K.mla_paged_attention, dv=dv, sm_scale=0.07, impl="pallas",
+                layer=1, block_tokens=tb))
+            for kind in ("decode", "span"):
+                T = S + (span if kind == "span" else 0)
+                q = jnp.asarray(rng.randn(T, H, dk), dt)
+                for share in ((0.0, 0.9375) if kind == "decode" else (0.9375,)):
+                    for kv in contexts:
+                        n_live = int(round(share * (S - 1)))
+                        ql = np.zeros((S,), np.int32)
+                        ql[:n_live] = 1
+                        kl = np.where(ql > 0, kv, 0).astype(np.int32)
+                        start = np.arange(S, dtype=np.int32)
+                        pairs = float(n_live * kv)
+                        if kind == "span":      # the last slot prefills
+                            ql[S - 1], kl[S - 1], start[S - 1] = (
+                                min(span, kv), kv, S)
+                            pairs += ql[S - 1] * (kv - (ql[S - 1] - 1) / 2)
+                        kv_tokens = float(kl.sum())
+                        # the published row: dv + 64 values of 2 B
+                        least = max(kv_tokens * 2 * (dv + 64) / 819e9,
+                                    pairs * H * (2 * dv + 64) * 2 / 197e12)
+                        runs.append(({
+                            "bench": "mla_sweep", "label": label,
+                            "cell": cell, "tick": kind, "slots": S,
+                            "heads": H, "pps": pps, "page_size": ps,
+                            "slots_live": n_live + (kind == "span"),
+                            "kv_len": kv, "kv_tokens": kv_tokens,
+                            "attn_pairs": pairs, "block_tokens": tb,
+                            "least_ms": round(least * 1e3, 5),
+                            "timing_honest": on_tpu}, fn,
+                            (q, pool, jnp.asarray(start), jnp.asarray(ql),
+                             jnp.asarray(kl), tabs)))
+        ran = []
+        for row, fn, args in runs:      # compile outside the trace
+            try:
+                jax.block_until_ready(fn(*args))
+                ran.append((row, fn, args))
+            except Exception as e:      # a refused compile IS the row
+                results.append(dict(row, ms=None, error=str(e)[-300:]))
+        if on_tpu:
+            tdir = tempfile.mkdtemp(prefix=f"kb_mla_{cell}_")
+            with jax.profiler.trace(tdir):
+                for _, fn, args in ran:
+                    for _ in range(iters):
+                        y = fn(*args)
+                    jax.block_until_ready(y)
+            ms = _kernel_ms(tdir, prefix="mla_paged_attention")
+            assert len(ms) == iters * len(ran), (len(ms), iters, len(ran))
+            for i, (row, _, _) in enumerate(ran):
+                mid = sorted(ms[i * iters:(i + 1) * iters])[iters // 2]
+                results.append(dict(row, ms=round(mid, 5), pct=round(
+                    100 * row["least_ms"] / mid, 2) if mid else None))
+        else:
+            for row, fn, args in ran:
+                results.append(dict(
+                    row, ms=round(_walltime(fn, args, n=iters), 4)))
+        # the expanded form of a span, as plain XLA, by the wall clock
+        # around block_until_ready (no kernel of its own to find in a
+        # trace): kv_b on the context, then dense causal attention
+        nope, rp, vd = (128, 64, 128) if on_tpu else (16, 8, 16)
+
+        def expanded(qn, qr, lat, w_k, w_v):
+            c, kr = lat[:, :dv], lat[:, dv:dv + rp]
+            k_n = jnp.einsum("sc,hnc->shn", c, w_k)
+            v = jnp.einsum("sc,hcv->shv", c, w_v)
+            sc = (jnp.einsum("thn,shn->hts", qn, k_n)
+                  + jnp.einsum("thr,sr->hts", qr, kr)).astype(jnp.float32)
+            n = lat.shape[0]
+            mask = (jnp.arange(n)[None] <= (n - qn.shape[0]
+                                            + jnp.arange(qn.shape[0]))[:, None])
+            p = jax.nn.softmax(jnp.where(mask[None], sc, -1e30), -1)
+            return jnp.einsum("hts,shv->thv", p.astype(v.dtype), v)
+
+        for kv in contexts:
+            n = min(span, kv)
+            args = (jnp.asarray(rng.randn(n, H, nope), dt),
+                    jnp.asarray(rng.randn(n, H, rp), dt),
+                    jnp.asarray(rng.randn(kv, dk), dt),
+                    jnp.asarray(rng.randn(H, nope, dv), dt),
+                    jnp.asarray(rng.randn(H, dv, vd), dt))
+            try:
+                ms = _walltime(jax.jit(expanded), args, n=iters)
+            except Exception as e:
+                results.append({"bench": "mla_sweep", "cell": cell,
+                                "tick": "span-expanded-xla", "kv_len": kv,
+                                "ms": None, "error": str(e)[-200:]})
+                continue
+            results.append({"bench": "mla_sweep", "label": label,
+                            "cell": cell, "tick": "span-expanded-xla",
+                            "kv_len": kv, "span": n, "ms": round(ms, 4),
+                            "timing_honest": on_tpu})
     return _emit(results, out)
 
 
@@ -668,12 +833,19 @@ def block_sweep(out=None, iters=3):
 if __name__ == "__main__":
     from paddle_tpu.compile_cache import enable_compile_cache
     enable_compile_cache()
-    if {"--block-sweep", "--ragged-sweep", "--ssd-sweep"} & set(sys.argv):
+    if {"--block-sweep", "--ragged-sweep", "--ssd-sweep",
+            "--mla-sweep"} & set(sys.argv):
         opt = {a.split("=", 1)[0]: a.split("=", 1)[1] for a in sys.argv
                if a.startswith("--") and "=" in a}
         path = opt.get("--out")
         if "--block-sweep" in sys.argv:
             block_sweep(out=path)
+        elif "--mla-sweep" in sys.argv:
+            mla_sweep(out=path, label=opt.get("--label", ""),
+                      cells=(opt["--cells"].split(",") if "--cells" in opt
+                             else None),
+                      blocks=tuple(None if b == "auto" else int(b) for b in
+                                   opt.get("--blocks", "auto").split(",")))
         elif "--ssd-sweep" in sys.argv:
             ssd_sweep(out=path, label=opt.get("--label", ""),
                       cells=(opt["--cells"].split(",") if "--cells" in opt

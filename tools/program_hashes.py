@@ -19,6 +19,7 @@ the hash of its assembly printed WITHOUT debug info first.
 import base64
 import functools
 import hashlib
+import importlib
 import json
 import os
 import re
@@ -60,11 +61,12 @@ def main(names) -> dict:
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
     R._on_tpu = lambda: True
-    try:
-        from paddle_tpu.ops.pallas import ssd_update as K
-        K._on_tpu = lambda: True
-    except ImportError:         # a parent from before that kernel
-        pass
+    for kernel in ("ssd_update", "mla_paged_attention", "grouped_matmul"):
+        try:
+            importlib.import_module(
+                f"paddle_tpu.ops.pallas.{kernel}")._on_tpu = lambda: True
+        except ImportError:     # a parent from before that kernel
+            pass
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
