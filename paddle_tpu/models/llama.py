@@ -428,8 +428,51 @@ def decoder_layer(lp, h, cfg: LlamaConfig, sp_spec=None, mesh=None,
                   mesh=mesh)
 
 
+def _grads_leave_sharded(tree, specs, grad_specs, mesh):
+    """Identity on a subtree of the parameters whose backward lays each
+    cotangent out in ``grad_specs`` (the leaf's dp-sharded ZeRO layout)
+    by way of ``specs`` (the leaf's own tp layout). The forward and an
+    undifferentiated program are untouched.
+
+    Two constraints, in that order, are what makes the compiler reduce a
+    weight gradient by reduce-scatters fused onto the matmul that made
+    it: the first (left out where the leaf's own spec shards nothing on
+    this mesh: it would then ask for the whole all-reduce) keeps the tp
+    reduce-scatter it emits today, the second turns the dp all-reduce
+    into a second one. Constrained straight to the dp layout, a
+    gradient that is partial over tp AND dp (the MLP's, computed on
+    each chip's half of the sequence) falls back to one synchronous
+    four-way all-reduce of the full matrix (described v5e:2x2 compile,
+    PR 41)."""
+
+    @jax.custom_vjp
+    def ident(t):
+        return t
+
+    def lay(x, own, sharded):
+        if any(mesh.shape.get(a, 1) > 1 for a in own if a is not None):
+            x = lax.with_sharding_constraint(x, NamedSharding(mesh, own))
+        return lax.with_sharding_constraint(x, NamedSharding(mesh, sharded))
+
+    def bwd(_, ct):
+        return (jax.tree_util.tree_map(lay, ct, specs, grad_specs),)
+
+    ident.defvjp(lambda t: (t, None), bwd)
+    return ident(tree)
+
+
+def _layer_specs(stacked_specs):
+    """One layer's specs from the stacked leaves': the layer axis off."""
+    return jax.tree_util.tree_map(lambda s: P(*tuple(s)[1:]), stacked_specs,
+                                  is_leaf=lambda s: isinstance(s, P))
+
+
 def _scan_layers(layer_params, h, cfg: LlamaConfig, sp_spec=None, remat=False,
-                 mesh=None, positions=None):
+                 mesh=None, positions=None, grad_specs=None):
+    """``grad_specs``: the stacked leaves' dp-sharded layout, when the
+    caller's update runs on dp shards (``_loss_with_sharded_grads``):
+    each layer's weight gradients then leave the backward loop
+    reduce-scattered over dp instead of all-reduced at its tail."""
     fn = partial(decoder_layer, cfg=cfg, sp_spec=sp_spec, mesh=mesh,
                  positions=positions)
     if remat:
@@ -437,8 +480,13 @@ def _scan_layers(layer_params, h, cfg: LlamaConfig, sp_spec=None, remat=False,
         # save_only_these_names("attn_out") by ~2% step time at bench
         # shapes (the saved flash recompute is outweighed by HBM pressure)
         fn = jax.checkpoint(fn)
+    if grad_specs is not None:
+        own, sharded = (_layer_specs(param_specs(cfg)["layers"]),
+                        _layer_specs(grad_specs))
 
     def body(carry, lp):
+        if grad_specs is not None:
+            lp = _grads_leave_sharded(lp, own, sharded, mesh)
         return fn(lp, carry), None
 
     h, _ = lax.scan(body, h, layer_params)
@@ -458,6 +506,12 @@ def forward(params, tokens, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     zigzag_global_perm) — logits come back in that order; loss_fn
     permutes the labels identically, so training is order-consistent.
     """
+    return _forward(params, tokens, cfg, mesh)
+
+
+def _forward(params, tokens, cfg: LlamaConfig, mesh, layer_grad_specs=None):
+    """``forward``; ``layer_grad_specs`` is ``_scan_layers``'
+    ``grad_specs``."""
     sp_spec = None
     positions = None
     if mesh is not None and mesh.shape.get("cp", 1) > 1:
@@ -474,7 +528,8 @@ def forward(params, tokens, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     if sp_spec is not None:
         h = lax.with_sharding_constraint(h, sp_spec)
     h = _scan_layers(params["layers"], h, cfg, sp_spec, remat=cfg.remat,
-                     mesh=mesh, positions=positions)
+                     mesh=mesh, positions=positions,
+                     grad_specs=layer_grad_specs)
     fin_spec = sp_spec.spec if sp_spec is not None else None
     if fin_spec is not None and not _spec_divides(mesh, fin_spec, h.shape):
         fin_spec = None  # uneven split: run the jnp norm instead
@@ -537,18 +592,34 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     lower to the reference's _c_softmax_with_cross_entropy collective
     pattern (mp_ops.py:414), never a logits all-gather
     (tests/test_fused_ce.py checks the HLO)."""
+    return _loss(params, batch, cfg, mesh)
+
+
+def _loss(params, batch, cfg: LlamaConfig, mesh, layer_grad_specs=None):
     from ..ops.fused import fused_softmax_cross_entropy
     tokens, labels = batch["tokens"], batch["labels"]
     if mesh is not None and cfg.pp_stages > 1:
         logits = forward_pipelined(params, tokens, cfg, mesh)
     else:
-        logits = forward(params, tokens, cfg, mesh)
+        logits = _forward(params, tokens, cfg, mesh, layer_grad_specs)
         if _zigzag_on(cfg, mesh):
             # logits are in the zigzag layout; pair labels the same way
             from ..parallel.context_parallel import zigzag_global_perm
             labels = labels[:, zigzag_global_perm(labels.shape[1],
                                                   mesh.shape["cp"])]
     return fused_softmax_cross_entropy(logits, labels).mean()
+
+
+def _loss_with_sharded_grads(params, batch, cfg: LlamaConfig, mesh: Mesh,
+                             grad_specs):
+    """``loss_fn`` for a trainer whose update runs on dp shards
+    (``grad_specs``: ``zero_param_specs``): every parameter's gradient
+    comes out of the backward pass in that layout, reduce-scattered
+    where it is made — the layers' inside their loop, the rest here."""
+    outer = lambda t: {k: v for k, v in t.items() if k != "layers"}
+    params = {**params, **_grads_leave_sharded(
+        outer(params), outer(param_specs(cfg)), outer(grad_specs), mesh)}
+    return _loss(params, batch, cfg, mesh, grad_specs["layers"])
 
 
 from ..parallel.pipeline_async import PP_SCHEDULES
@@ -748,8 +819,48 @@ def default_train_optimizer():
     return optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
 
 
+def zero_param_specs(cfg: LlamaConfig, dp: int):
+    """``param_specs`` with one more dim of every leaf sharded over dp
+    (``zero_spec``: the first free dim that dp divides) — the layout of
+    a ZeRO shard: the moments' from stage 1, the gradients' as they
+    leave the backward pass, the stored parameters' at stage 3.
+
+    A stacked layer leaf takes dp on a dim WITHIN the layer, never on
+    the layer axis, however many layers dp divides: a gradient sharded
+    by layers cannot be reduce-scattered inside the loop over layers,
+    and moments laid out otherwise than their gradients would pay a
+    reshard every step. A leaf no dim of which dp divides keeps its
+    spec (its gradient is all-reduced, its update replicated)."""
+    from ..distributed.sharding import zero_spec
+
+    def place(sp, a, layer_axes=0):
+        rest = tuple(sp)[layer_axes:]
+        zs = zero_spec(P(*rest), a.shape[layer_axes:], dp)
+        return sp if zs is None else P(*tuple(sp)[:layer_axes], *zs)
+
+    pspecs, abs_params = param_specs(cfg), abstract_params(cfg)
+    is_spec = lambda x: isinstance(x, P)
+    out = jax.tree_util.tree_map(place, pspecs, abs_params, is_leaf=is_spec)
+    out["layers"] = jax.tree_util.tree_map(
+        partial(place, layer_axes=1), pspecs["layers"],
+        abs_params["layers"], is_leaf=is_spec)
+    return out
+
+
+def _zero_stage_of(cfg: LlamaConfig, mesh: Mesh, zero_stage) -> int:
+    """``zero_stage=None`` is "by the mesh": where the batch is split
+    over dp > 1 and the step is the plain ``value_and_grad`` (no
+    pipeline schedule), stage 1; else 0 (nothing to shard over, or a
+    pipelined gradient path, which keeps its all-reduce)."""
+    if zero_stage is None:
+        return int(mesh.shape.get("dp", 1) > 1 and cfg.pp_stages == 1)
+    if zero_stage not in (0, 1, 2, 3):
+        raise ValueError(f"zero_stage must be 0..3, got {zero_stage}")
+    return zero_stage
+
+
 def train_state_specs(cfg: LlamaConfig, mesh: Mesh, optimizer=None,
-                      zero_stage: int = 0):
+                      zero_stage: Optional[int] = None):
     """PartitionSpec pytree matching ``make_train_step``'s state
     ``{"params", "opt", "step"}`` — the declared layout, computed
     without allocating anything. ``init_fn`` places by these specs and
@@ -758,24 +869,18 @@ def train_state_specs(cfg: LlamaConfig, mesh: Mesh, optimizer=None,
 
     Optimizer-state leaves inherit the owning param's (tp/pp) spec
     (every params-shaped subtree of the optax state maps one-to-one);
-    zero_stage >= 1 layers a dp dim on top of each leaf's own spec via
-    ``zero_spec``; zero_stage >= 3 does the same to the params.
+    zero_stage >= 1 (``None``: by the mesh, as ``make_train_step``)
+    gives them ``zero_param_specs``' dp dim on top; zero_stage >= 3
+    does the same to the params.
     """
-    from ..distributed.sharding import zero_spec
     if optimizer is None:
         optimizer = default_train_optimizer()
+    zero_stage = _zero_stage_of(cfg, mesh, zero_stage)
     dp = mesh.shape.get("dp", 1)
     pspecs = param_specs(cfg)
     abs_params = abstract_params(cfg)
-
-    def add_zero(tree, abs_tree):
-        def place(sp, a):
-            if not getattr(a, "shape", None):
-                return sp  # scalars (step counts) stay replicated
-            zs = zero_spec(sp, a.shape, dp)
-            return sp if zs is None else zs
-        return jax.tree_util.tree_map(
-            place, tree, abs_tree, is_leaf=lambda x: isinstance(x, P))
+    moment_specs = (zero_param_specs(cfg, dp)
+                    if zero_stage >= 1 and dp > 1 else pspecs)
 
     # opt-state leaves mirror params subtree-by-subtree (adamw mu/nu);
     # anything not params-shaped (count scalars) replicates
@@ -789,17 +894,15 @@ def train_state_specs(cfg: LlamaConfig, mesh: Mesh, optimizer=None,
             return False
 
     opt_specs = jax.tree_util.tree_map(
-        lambda node: pspecs if params_like(node) else P(),
+        lambda node: moment_specs if params_like(node) else P(),
         abs_opt, is_leaf=params_like)
-    if zero_stage >= 1 and dp > 1:
-        opt_specs = add_zero(opt_specs, abs_opt)
-    if zero_stage >= 3 and dp > 1:
-        pspecs = add_zero(pspecs, abs_params)
+    if zero_stage >= 3:
+        pspecs = moment_specs
     return {"params": pspecs, "opt": opt_specs, "step": P()}
 
 
 def make_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer=None,
-                    zero_stage: int = 0):
+                    zero_stage: Optional[int] = None):
     """Build the jitted SPMD train step (fwd+bwd+adamw) over ``mesh``.
 
     Returns (step_fn, init_fn). ``init_fn(key)`` places params and
@@ -808,20 +911,40 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer=None,
     optimizer buffers are updated in place, never doubly resident).
 
     zero_stage (reference: fleet group-sharded stages,
-    dygraph_sharding_optimizer.py:48 / group_sharded_stage3.py):
-      0 — optimizer state inherits the param (tp/pp) sharding only.
-      1 — optimizer moments additionally sharded over dp (ZeRO-1).
-      2 — same layout as 1; gradients arrive reduce-scattered into the
-          dp-sharded layout because the only consumer (the sharded
-          update) demands it — asserted on HLO in tests.
-      3 — parameters themselves dp-sharded too; GSPMD all-gathers at
-          use (ZeRO-3).
+    dygraph_sharding_optimizer.py:48 / group_sharded_stage3.py). A
+    caller need set nothing: the default, ``None``, goes by the mesh —
+    stage 1 where its dp degree is above 1 (and the step is not
+    pipelined), stage 0 where there is nothing to shard over (dp 1:
+    the program is the one stage 0 always gave).
+      0 — optimizer state inherits the param (tp/pp) sharding only;
+          every weight gradient is all-reduced over dp where the
+          backward pass makes it (for a layer: synchronously, at the
+          tail of each iteration of the backward loop) and every
+          replica runs the whole update. Set explicitly on a dp > 1
+          mesh it is the REFERENCE path the sharded update is tested
+          against, not a mode to train in.
+      1 — gradients, moments and update sharded over dp
+          (``zero_param_specs``; ZeRO-1/2). INSIDE the backward loop
+          each of a layer's weight gradients is reduce-scattered over
+          dp at the matmul that makes it (an all-reduce is a
+          reduce-scatter plus an all-gather; only the first half stays
+          in the loop, in the form the TPU compiler fuses onto the
+          matmul); the stacked gradients leave the loop dp-sharded, the
+          optimizer updates each replica's shard against moments laid
+          out the same way, and the updated parameters are all-gathered
+          once, after the update. Same sums, same dtype, same AdamW as
+          stage 0. The pipelined steps (``pp_stages > 1``) keep their
+          own gradient path: there the moments' layout alone asks for
+          the scatter.
+      2 — the same program as 1 (the gradients of stage 1 already
+          arrive reduce-scattered; asserted on HLO in tests).
+      3 — parameters themselves stored dp-sharded too, gathered for
+          the forward and backward passes (ZeRO-3); the update as in 1.
     """
     import optax
     if optimizer is None:
         optimizer = default_train_optimizer()
-    if zero_stage not in (0, 1, 2, 3):
-        raise ValueError(f"zero_stage must be 0..3, got {zero_stage}")
+    zero_stage = _zero_stage_of(cfg, mesh, zero_stage)
 
     use_1f1b = cfg.pp_stages > 1 and cfg.pp_schedule in PP_SCHEDULES
     if cfg.pp_schedule not in ("gpipe",) + tuple(PP_SCHEDULES):
@@ -856,10 +979,16 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer=None,
     # differentiated layer scan, which the CPU SPMD partitioner
     # miscompiles (fwd+bwd loss drifts 3e-3 from the f64 reference —
     # pinned by tests/test_zero_sharding.py numerics tests).
-    fwd_pspecs = param_specs(cfg) if zero_stage >= 3 else None
-    stored_pspecs = (train_state_specs(cfg, mesh, optimizer,
-                                       zero_stage)["params"]
-                     if zero_stage >= 3 else None)
+    fwd_pspecs = param_specs(cfg)
+    # the dp shards the update runs on (None: every replica updates the
+    # whole of its tp shard, as stage 0 and dp 1 do)
+    shard_specs = (zero_param_specs(cfg, mesh.shape["dp"])
+                   if zero_stage >= 1 and mesh.shape.get("dp", 1) > 1
+                   else None)
+    stored_pspecs = shard_specs if zero_stage >= 3 else fwd_pspecs
+    # the pipelined gradient paths (grads_1f1b, forward_pipelined) are
+    # left as they are: their reductions stand where GSPMD puts them
+    grad_specs = shard_specs if cfg.pp_stages == 1 else None
 
     def _constrain(params, specs):
         return jax.tree_util.tree_map(
@@ -874,13 +1003,20 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer=None,
         with jax.named_scope("loss"):
             if use_1f1b:
                 loss, grads = grads_1f1b(params, batch, cfg, mesh)
+            elif grad_specs is not None:
+                loss, grads = jax.value_and_grad(_loss_with_sharded_grads)(
+                    params, batch, cfg, mesh, grad_specs)
             else:
                 loss, grads = jax.value_and_grad(loss_fn)(
                     params, batch, cfg, mesh)
         with jax.named_scope("optimizer"):
+            if shard_specs is not None:
+                # each replica's slice of what it already holds
+                params = _constrain(params, shard_specs)
             updates, opt = optimizer.update(grads, state["opt"], params)
             params = optax.apply_updates(params, updates)
-        if zero_stage >= 3:
+        if shard_specs is not None:
+            # stage 1/2: one all-gather a leaf, behind its update
             params = _constrain(params, stored_pspecs)
         return {"params": params, "opt": opt,
                 "step": state["step"] + 1}, loss

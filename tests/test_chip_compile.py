@@ -670,6 +670,82 @@ def test_longcat_cell_programs_hold_the_latent_pool_once(topo, chip,
                          text)
 
 
+# the trainer's step (``mistral7b-train-dp2tp2``: Mistral widths, strict
+# Pallas kernels, 4 x 2048 tokens a dp replica), 2 of the cell's 5 layers
+
+
+def _train_step_for(topo, monkeypatch, dp, tp, **step_kw):
+    """``make_train_step`` lowered for ``dp x tp`` described chips over
+    shapes only: (lowered, mesh)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.models import llama as L
+    from paddle_tpu.ops.pallas import flash_attention as FA
+    from paddle_tpu.ops.pallas import fused_norm_rope as FN
+    from paddle_tpu.parallel import init_hybrid_mesh
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    monkeypatch.setattr(FN, "_on_tpu", lambda: True)
+    cfg = L.LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_hidden_layers=2, num_attention_heads=32, num_key_value_heads=8,
+        rms_norm_eps=1e-5, rope_theta=1e6, dtype=jnp.bfloat16,
+        max_position_embeddings=2048, use_flash_attention="pallas",
+        use_fused_norm_rope="pallas")
+    mesh = init_hybrid_mesh(dp=dp, pp=1, tp=tp, set_global=False,
+                            devices=topo.devices[:dp * tp]).mesh
+    with mesh:
+        step, init = L.make_train_step(cfg, mesh, **step_kw)
+        specs = L.train_state_specs(cfg, mesh, **step_kw)
+        state = jax.tree.map(
+            lambda a, sp: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, sp)),
+            jax.eval_shape(init, jax.random.PRNGKey(0)), specs)
+        rows = NamedSharding(mesh, P("dp", None))
+        batch = {k: jax.ShapeDtypeStruct((4 * dp, 2048), jnp.int32,
+                                         sharding=rows)
+                 for k in ("tokens", "labels")}
+        return step.lower(state, batch), mesh
+
+
+def test_train_step_reduce_scatters_its_gradients_in_the_loop(
+        topo, chip, monkeypatch):
+    """dp 2 x tp 2, ``zero_stage`` left to the mesh: the body of the
+    backward loop holds no all-reduce across dp but the two norms' 16 KB
+    (at stage 0 it ends in two tuple all-reduces of the layer's seven
+    matrices, 218 MB, with nothing left to run beside them), and each
+    of the seven is reduced across dp by a reduce-scatter fused onto
+    the matmul that made it, beside the tp ones it already had."""
+    from hlo_collectives import backward_loop_collectives
+    lowered, mesh = _train_step_for(topo, monkeypatch, 2, 2)
+    compiled = lowered.compile()
+    got = backward_loop_collectives(compiled.as_text(), mesh.shape)
+    assert got.body is not None
+    norms = 2 * D * 2
+    assert [r for r in got.all_reduces
+            if r.crosses and r.bytes > norms] == [], got.all_reduces
+    over_dp = [r for r in got.reduce_scatters if r.crosses]
+    assert len(over_dp) == 7, got.reduce_scatters
+    assert len(got.reduce_scatters) - len(over_dp) >= 5    # tp's, kept
+    # a layer's gradients leave the loop halved: wq + wk + wv + wo +
+    # w_gate + w_up + w_down over tp 2 x dp 2, padded a little
+    layer = 2 * (2 * D * D + 2 * D * HKV * DH + 3 * D * 14336) // 4
+    assert layer <= sum(r.bytes for r in over_dp) < 1.02 * layer
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert live < 4.0 * 2 ** 30     # 3.62 GiB; stage 0: 4.44 at 2 layers
+
+
+def test_train_step_on_one_chip_is_the_stage_0_program(topo, chip,
+                                                       monkeypatch):
+    """dp 1: nothing to shard over, so "by the mesh" is stage 0 and an
+    explicit stage 1 finds no dp either: one lowered text."""
+    texts = {str(kw): _train_step_for(topo, monkeypatch, 1, 1,
+                                      **kw)[0].as_text()
+             for kw in ({}, {"zero_stage": 0}, {"zero_stage": 1})}
+    assert len(set(texts.values())) == 1, list(texts)
+    assert "sharding_constraint" not in texts["{}"]
+
+
 def test_splash_fwd(chip):
     from paddle_tpu.ops.pallas.flash_attention import _splash
     fn = functools.partial(_splash, causal=True, sm_scale=DH ** -0.5)

@@ -38,8 +38,10 @@ def _per_device_bytes(tree):
     return total
 
 
-def _state(zero_stage, dp=8):
-    hm = init_hybrid_mesh(dp=dp, pp=1, tp=1, set_global=False)
+def _state(zero_stage, dp=8, tp=1):
+    """``zero_stage=None``: left to the mesh, ``make_train_step``'s
+    default."""
+    hm = init_hybrid_mesh(dp=dp, pp=1, tp=tp, set_global=False)
     with hm.mesh:
         step, init = L.make_train_step(CFG, hm.mesh,
                                        zero_stage=zero_stage)
@@ -219,3 +221,89 @@ def test_train_state_specs_match_placed_state():
     for leaf, spec in zip(flat_s, flat_p):
         assert tuple(leaf.sharding.spec) == tuple(spec), \
             (leaf.shape, leaf.sharding.spec, spec)
+
+
+def _flat(tree):
+    return jax.tree_util.tree_leaves(tree,
+                                     is_leaf=lambda x: isinstance(x, P))
+
+
+@pytest.mark.parametrize("dp,tp", [(2, 2), (4, 2)])
+def test_default_by_the_mesh_matches_replicated_update(dp, tp):
+    """``zero_stage`` left out on a dp > 1 mesh: gradients constrained
+    dp-sharded INSIDE the differentiated layer scan, the update on dp
+    shards, the parameters gathered back. Same sums, same AdamW as the
+    explicit stage 0: three steps' losses, the first gradient (``mu``
+    after step 1 is ``(1 - b1) g``) and the parameters agree; the
+    moments carry the dp layout, the parameters ``param_specs``'."""
+    hm, step, state, batch = _state(None, dp, tp)
+    _, step0, state0, _ = _state(0, dp, tp)
+    specs = L.train_state_specs(CFG, hm.mesh)
+    zspecs = L.zero_param_specs(CFG, dp)
+    assert specs["params"] == L.param_specs(CFG)
+    for i in range(3):
+        state, loss = step(state, batch)
+        state0, loss0 = step0(state0, batch)
+        np.testing.assert_allclose(np.asarray(loss), np.asarray(loss0),
+                                   rtol=1e-5, atol=1e-6)
+        if i == 0:
+            mu, mu0 = state["opt"][0].mu, state0["opt"][0].mu
+            for k, (g, g0) in enumerate(zip(jax.tree_util.tree_leaves(mu),
+                                            jax.tree_util.tree_leaves(mu0))):
+                np.testing.assert_allclose(
+                    np.asarray(g), np.asarray(g0), rtol=1e-4, atol=1e-7,
+                    err_msg=f"first gradient, leaf {k}")
+    for p, p0 in zip(_flat(state["params"]), _flat(state0["params"])):
+        np.testing.assert_allclose(np.asarray(p), np.asarray(p0),
+                                   rtol=1e-4, atol=1e-5)
+    # every leaf has a dp-divisible dim at this size: all are sharded
+    for m in (state["opt"][0].mu, state["opt"][0].nu):
+        for leaf, zs in zip(_flat(m), _flat(zspecs)):
+            assert "dp" in tuple(zs) and tuple(leaf.sharding.spec) == \
+                tuple(zs), (leaf.shape, leaf.sharding.spec, zs)
+    for leaf, sp in zip(_flat(state["params"]), _flat(L.param_specs(CFG))):
+        assert leaf.sharding.is_equivalent_to(
+            jax.sharding.NamedSharding(hm.mesh, sp), leaf.ndim), \
+            (leaf.shape, leaf.sharding.spec, sp)
+
+
+def test_gradients_leave_the_backward_pass_dp_sharded():
+    """The layout the update is handed: the trainer's loss
+    (``_loss_with_sharded_grads``) yields every gradient in
+    ``zero_param_specs``' layout, equal to ``loss_fn``'s, which come in
+    the parameters'."""
+    hm, _, state, batch = _state(0, 2, 2)
+    zspecs = L.zero_param_specs(CFG, 2)
+    with hm.mesh:
+        grad = lambda loss, *a: jax.jit(jax.grad(
+            lambda p: loss(p, batch, CFG, hm.mesh, *a)))(state["params"])
+        g = grad(L._loss_with_sharded_grads, zspecs)
+        g0 = grad(L.loss_fn)
+    for a, b, zs in zip(_flat(g), _flat(g0), _flat(zspecs)):
+        assert tuple(a.sharding.spec) == tuple(zs), (a.shape, zs)
+        assert "dp" not in tuple(b.sharding.spec)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-7)
+
+
+def test_zero_layout_never_shards_the_layer_axis():
+    """2 and 4 layers divide by dp 2: the first free dp-divisible dim of
+    a stacked leaf is then its LAYER axis, and a gradient sharded by
+    layers cannot be reduce-scattered inside the loop over layers. The
+    stage-1 layout shards a dim within the layer, moments and gradients
+    alike."""
+    assert CFG.num_hidden_layers % 2 == 0
+    zs = L.zero_param_specs(CFG, 2)
+    ps = L.param_specs(CFG)
+    for name, spec in zs["layers"].items():
+        assert tuple(spec)[0] is None, (name, spec)
+        assert "dp" in tuple(spec)[1:], (name, spec)
+        # the leaf's own axes stay where they were
+        assert all(b in (a, "dp") for a, b in zip(
+            tuple(ps["layers"][name]), tuple(spec))), (name, spec)
+    assert tuple(zs["embed"]) == ("tp", "dp")
+    assert tuple(zs["lm_head"]) == ("dp", "tp")
+    hm = init_hybrid_mesh(dp=2, pp=1, tp=2, set_global=False)
+    mom = L.train_state_specs(CFG, hm.mesh)["opt"][0].mu
+    assert mom == zs
+    assert L.train_state_specs(CFG, hm.mesh, zero_stage=0)["opt"][0].mu == ps
