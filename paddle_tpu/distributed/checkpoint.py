@@ -22,11 +22,19 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 
-try:
-    import orbax.checkpoint as ocp
-    _HAS_ORBAX = True
-except Exception:  # pragma: no cover
-    _HAS_ORBAX = False
+
+def _orbax(what: str):
+    """``orbax.checkpoint``, imported at FIRST USE: the import costs
+    20-35 s on the chip's host (it pulls ``google.cloud.logging``, whose
+    version check walks every installed distribution's metadata), and
+    ``make_train_step`` reaches this module through the package's
+    ``__init__`` on any mesh with dp > 1 (PERF.md §6, PR 42)."""
+    try:
+        import orbax.checkpoint as ocp
+    except Exception as e:  # pragma: no cover
+        raise RuntimeError(
+            f"orbax-checkpoint is required for sharded {what}") from e
+    return ocp
 
 
 def _replicated_global_sharding():
@@ -66,6 +74,7 @@ def _async_checkpointer():
     are reused rather than leaked per call)."""
     global _ASYNC_CKPT
     if _ASYNC_CKPT is None:
+        ocp = _orbax("save")
         _ASYNC_CKPT = ocp.AsyncCheckpointer(ocp.StandardCheckpointHandler())
     return _ASYNC_CKPT
 
@@ -79,8 +88,7 @@ def save_state_dict(state_dict: Dict[str, Any], path: str,
     queue). Call ``.wait_until_finished()`` on the returned checkpointer
     before READING the files; back-to-back async saves are safe (the
     shared checkpointer serializes its own commits)."""
-    if not _HAS_ORBAX:
-        raise RuntimeError("orbax-checkpoint is required for sharded save")
+    ocp = _orbax("save")
     path = os.path.abspath(path)
     arrays = _to_arrays(state_dict)
     if async_save:
@@ -99,8 +107,7 @@ def load_state_dict(state_dict: Dict[str, Any], path: str,
     """Restore INTO ``state_dict`` — each entry's current sharding is the
     target layout, so loading onto a different mesh re-shards (reference:
     load_state_dict.py cross-degree reshard)."""
-    if not _HAS_ORBAX:
-        raise RuntimeError("orbax-checkpoint is required for sharded load")
+    ocp = _orbax("load")
     path = os.path.abspath(path)
     ckpt = ocp.StandardCheckpointer()
     multi = jax.process_count() > 1

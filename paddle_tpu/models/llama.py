@@ -31,6 +31,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability import in_setup_span, setup_span
 from ..parallel.pipeline_spmd import pipeline_spmd, microbatch
 
 
@@ -941,85 +942,90 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer=None,
       3 — parameters themselves stored dp-sharded too, gathered for
           the forward and backward passes (ZeRO-3); the update as in 1.
     """
-    import optax
-    if optimizer is None:
-        optimizer = default_train_optimizer()
-    zero_stage = _zero_stage_of(cfg, mesh, zero_stage)
+    # a ``with``, not a decorator: no frame is added beneath the build
+    # (what it imports and traces is sensitive to its stack depth:
+    # core/stack_anchor.py)
+    with setup_span("train.setup.build"):
+        import optax
+        if optimizer is None:
+            optimizer = default_train_optimizer()
+        zero_stage = _zero_stage_of(cfg, mesh, zero_stage)
 
-    use_1f1b = cfg.pp_stages > 1 and cfg.pp_schedule in PP_SCHEDULES
-    if cfg.pp_schedule not in ("gpipe",) + tuple(PP_SCHEDULES):
-        raise ValueError(
-            f"pp_schedule must be one of "
-            f"{('gpipe',) + tuple(PP_SCHEDULES)}, got "
-            f"{cfg.pp_schedule!r}")
+        use_1f1b = cfg.pp_stages > 1 and cfg.pp_schedule in PP_SCHEDULES
+        if cfg.pp_schedule not in ("gpipe",) + tuple(PP_SCHEDULES):
+            raise ValueError(
+                f"pp_schedule must be one of "
+                f"{('gpipe',) + tuple(PP_SCHEDULES)}, got "
+                f"{cfg.pp_schedule!r}")
 
-    def init_fn(key):
-        specs = train_state_specs(cfg, mesh, optimizer, zero_stage)
-        params = jax.tree_util.tree_map(
-            lambda x, sp: jax.device_put(x, NamedSharding(mesh, sp)),
-            init_params(cfg, key), specs["params"])
-        # moments are born directly in their declared (possibly
-        # dp-sharded) layout: optimizer.init on unsharded params would
-        # transiently hold 2x full param bytes replicated per device —
-        # the exact peak ZeRO stages exist to avoid
-        opt_shardings = jax.tree_util.tree_map(
-            lambda sp: NamedSharding(mesh, sp), specs["opt"],
-            is_leaf=lambda x: isinstance(x, P))
-        opt_state = jax.jit(optimizer.init,
-                            out_shardings=opt_shardings)(params)
-        return {"params": params, "opt": opt_state,
-                "step": jax.device_put(
-                    jnp.zeros((), jnp.int32),
-                    NamedSharding(mesh, specs["step"]))}
+        @in_setup_span("train.setup.init", ready=True)
+        def init_fn(key):
+            specs = train_state_specs(cfg, mesh, optimizer, zero_stage)
+            params = jax.tree_util.tree_map(
+                lambda x, sp: jax.device_put(x, NamedSharding(mesh, sp)),
+                init_params(cfg, key), specs["params"])
+            # moments are born directly in their declared (possibly
+            # dp-sharded) layout: optimizer.init on unsharded params would
+            # transiently hold 2x full param bytes replicated per device —
+            # the exact peak ZeRO stages exist to avoid
+            opt_shardings = jax.tree_util.tree_map(
+                lambda sp: NamedSharding(mesh, sp), specs["opt"],
+                is_leaf=lambda x: isinstance(x, P))
+            opt_state = jax.jit(optimizer.init,
+                                out_shardings=opt_shardings)(params)
+            return {"params": params, "opt": opt_state,
+                    "step": jax.device_put(
+                        jnp.zeros((), jnp.int32),
+                        NamedSharding(mesh, specs["step"]))}
 
-    # ZeRO-3 rebuild-on-forward (group_sharded_stage3.py): compute runs
-    # on params gathered back to their tp/pp-only layout; only STORAGE
-    # (the state between steps) is dp-sharded. Besides being the
-    # reference semantics, this keeps dp-sharded weights out of the
-    # differentiated layer scan, which the CPU SPMD partitioner
-    # miscompiles (fwd+bwd loss drifts 3e-3 from the f64 reference —
-    # pinned by tests/test_zero_sharding.py numerics tests).
-    fwd_pspecs = param_specs(cfg)
-    # the dp shards the update runs on (None: every replica updates the
-    # whole of its tp shard, as stage 0 and dp 1 do)
-    shard_specs = (zero_param_specs(cfg, mesh.shape["dp"])
-                   if zero_stage >= 1 and mesh.shape.get("dp", 1) > 1
-                   else None)
-    stored_pspecs = shard_specs if zero_stage >= 3 else fwd_pspecs
-    # the pipelined gradient paths (grads_1f1b, forward_pipelined) are
-    # left as they are: their reductions stand where GSPMD puts them
-    grad_specs = shard_specs if cfg.pp_stages == 1 else None
+        # ZeRO-3 rebuild-on-forward (group_sharded_stage3.py): compute runs
+        # on params gathered back to their tp/pp-only layout; only STORAGE
+        # (the state between steps) is dp-sharded. Besides being the
+        # reference semantics, this keeps dp-sharded weights out of the
+        # differentiated layer scan, which the CPU SPMD partitioner
+        # miscompiles (fwd+bwd loss drifts 3e-3 from the f64 reference —
+        # pinned by tests/test_zero_sharding.py numerics tests).
+        fwd_pspecs = param_specs(cfg)
+        # the dp shards the update runs on (None: every replica updates the
+        # whole of its tp shard, as stage 0 and dp 1 do)
+        shard_specs = (zero_param_specs(cfg, mesh.shape["dp"])
+                       if zero_stage >= 1 and mesh.shape.get("dp", 1) > 1
+                       else None)
+        stored_pspecs = shard_specs if zero_stage >= 3 else fwd_pspecs
+        # the pipelined gradient paths (grads_1f1b, forward_pipelined) are
+        # left as they are: their reductions stand where GSPMD puts them
+        grad_specs = shard_specs if cfg.pp_stages == 1 else None
 
-    def _constrain(params, specs):
-        return jax.tree_util.tree_map(
-            lambda x, sp: lax.with_sharding_constraint(
-                x, NamedSharding(mesh, sp)), params, specs)
+        def _constrain(params, specs):
+            return jax.tree_util.tree_map(
+                lambda x, sp: lax.with_sharding_constraint(
+                    x, NamedSharding(mesh, sp)), params, specs)
 
-    @partial(jax.jit, donate_argnums=(0,))
-    def step_fn(state, batch):
-        params = state["params"]
-        if zero_stage >= 3:
-            params = _constrain(params, fwd_pspecs)
-        with jax.named_scope("loss"):
-            if use_1f1b:
-                loss, grads = grads_1f1b(params, batch, cfg, mesh)
-            elif grad_specs is not None:
-                loss, grads = jax.value_and_grad(_loss_with_sharded_grads)(
-                    params, batch, cfg, mesh, grad_specs)
-            else:
-                loss, grads = jax.value_and_grad(loss_fn)(
-                    params, batch, cfg, mesh)
-        with jax.named_scope("optimizer"):
+        @partial(jax.jit, donate_argnums=(0,))
+        def step_fn(state, batch):
+            params = state["params"]
+            if zero_stage >= 3:
+                params = _constrain(params, fwd_pspecs)
+            with jax.named_scope("loss"):
+                if use_1f1b:
+                    loss, grads = grads_1f1b(params, batch, cfg, mesh)
+                elif grad_specs is not None:
+                    loss, grads = jax.value_and_grad(_loss_with_sharded_grads)(
+                        params, batch, cfg, mesh, grad_specs)
+                else:
+                    loss, grads = jax.value_and_grad(loss_fn)(
+                        params, batch, cfg, mesh)
+            with jax.named_scope("optimizer"):
+                if shard_specs is not None:
+                    # each replica's slice of what it already holds
+                    params = _constrain(params, shard_specs)
+                updates, opt = optimizer.update(grads, state["opt"], params)
+                params = optax.apply_updates(params, updates)
             if shard_specs is not None:
-                # each replica's slice of what it already holds
-                params = _constrain(params, shard_specs)
-            updates, opt = optimizer.update(grads, state["opt"], params)
-            params = optax.apply_updates(params, updates)
-        if shard_specs is not None:
-            # stage 1/2: one all-gather a leaf, behind its update
-            params = _constrain(params, stored_pspecs)
-        return {"params": params, "opt": opt,
-                "step": state["step"] + 1}, loss
+                # stage 1/2: one all-gather a leaf, behind its update
+                params = _constrain(params, stored_pspecs)
+            return {"params": params, "opt": opt,
+                    "step": state["step"] + 1}, loss
 
     return step_fn, init_fn
 

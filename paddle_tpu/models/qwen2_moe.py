@@ -23,6 +23,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..incubate.moe.functional import moe_ffn
+from ..observability import in_setup_span, setup_span
 from .llama import _mm, rms_norm, rope
 
 
@@ -246,25 +247,28 @@ def loss_fn(params, batch, cfg: Qwen2MoeConfig, mesh=None):
 def make_train_step(cfg: Qwen2MoeConfig, mesh: Mesh, optimizer=None):
     """Jitted SPMD train step; optimizer state inherits param sharding
     (ZeRO-style, like models/llama.py make_train_step)."""
-    import optax
-    if optimizer is None:
-        optimizer = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
+    with setup_span("train.setup.build"):   # no frame added: llama.py
+        import optax
+        if optimizer is None:
+            optimizer = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
 
-    def init_fn(key):
-        params = init_params(cfg, key)
-        params = shard_params(params, cfg, mesh)
-        opt_state = optimizer.init(params)
-        return {"params": params, "opt": opt_state,
-                "step": jnp.zeros((), jnp.int32)}
+        @in_setup_span("train.setup.init", ready=True)
+        def init_fn(key):
+            params = init_params(cfg, key)
+            params = shard_params(params, cfg, mesh)
+            opt_state = optimizer.init(params)
+            return {"params": params, "opt": opt_state,
+                    "step": jnp.zeros((), jnp.int32)}
 
-    @partial(jax.jit, donate_argnums=(0,))
-    def step_fn(state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(
-            state["params"], batch, cfg, mesh)
-        updates, opt = optimizer.update(grads, state["opt"], state["params"])
-        params = optax.apply_updates(state["params"], updates)
-        return {"params": params, "opt": opt,
-                "step": state["step"] + 1}, loss
+        @partial(jax.jit, donate_argnums=(0,))
+        def step_fn(state, batch):
+            loss, grads = jax.value_and_grad(loss_fn)(
+                state["params"], batch, cfg, mesh)
+            updates, opt = optimizer.update(grads, state["opt"],
+                                            state["params"])
+            params = optax.apply_updates(state["params"], updates)
+            return {"params": params, "opt": opt,
+                    "step": state["step"] + 1}, loss
 
     return step_fn, init_fn
 

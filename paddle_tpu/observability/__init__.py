@@ -21,6 +21,14 @@ anomaly instead of reconstructed after it.
                        named span and a RecompileWarning, cross-checked
                        against the static program inventory
                        (sentinel.py)
+    compile_ledger   — the same listener's record of every program the
+                       process materialised: traced, lowered, compiled
+                       or read from the persistent cache, by name
+                       (sentinel.py: compile_ledger, compile_totals,
+                       ledger_health)
+    setup_report     — time to ready: the set-up spans of the engine
+                       and the trainer on the process tracer, joined
+                       to the ledger (setup.py)
 
 Wired through ``serving.ServingEngine`` (``trace=``, ``flight_ticks=``,
 ``recompile_sentinel=`` ctor knobs; on by default — measured overhead
@@ -31,9 +39,14 @@ docs/OBSERVABILITY.md.
 """
 from .flight import FlightRecorder, default_flight_dir  # noqa: F401
 from .sentinel import (COMPILE_EVENT, RECOMPILES_METRIC,  # noqa: F401
-                       RecompileSentinel, RecompileWarning)
-from .tracer import Span, SpanTracer, current_span  # noqa: F401
+                       RecompileSentinel, RecompileWarning,
+                       compile_ledger, compile_totals, ledger_health)
+from .setup import in_setup_span, setup_report, setup_span  # noqa: F401
+from .tracer import (Span, SpanTracer, current_span,  # noqa: F401
+                     process_tracer)
 
-__all__ = ["SpanTracer", "Span", "current_span", "FlightRecorder",
-           "default_flight_dir", "RecompileSentinel", "RecompileWarning",
-           "COMPILE_EVENT", "RECOMPILES_METRIC"]
+__all__ = ["SpanTracer", "Span", "current_span", "process_tracer",
+           "FlightRecorder", "default_flight_dir", "RecompileSentinel",
+           "RecompileWarning", "COMPILE_EVENT", "RECOMPILES_METRIC",
+           "compile_ledger", "compile_totals", "ledger_health", "setup_span",
+           "in_setup_span", "setup_report"]

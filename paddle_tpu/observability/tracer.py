@@ -49,7 +49,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-__all__ = ["Span", "SpanTracer", "Phases", "current_span"]
+__all__ = ["Span", "SpanTracer", "Phases", "current_span", "process_tracer"]
 
 _tls = threading.local()
 _TraceAnnotation = None     # jax.profiler's, imported at first use
@@ -335,3 +335,15 @@ class SpanTracer:
             # not kill the export
             json.dump(self.to_chrome_trace(), f, default=str)
         return path
+
+
+# set-up begins before any engine exists (weights, a trainer's state,
+# an engine's own construction), so its spans go to ONE tracer of the
+# process, on track ``setup``; an engine's ring is its own
+_PROCESS_TRACER = SpanTracer(capacity=256)
+
+
+def process_tracer() -> SpanTracer:
+    """The process-wide tracer the set-up spans are recorded on
+    (``serving.setup.*``, ``train.setup.*``; observability/setup.py)."""
+    return _PROCESS_TRACER

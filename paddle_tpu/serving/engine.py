@@ -73,7 +73,8 @@ from collections import deque
 import itertools
 
 from ..inference.paged_kv import PagePool, defrag_pools
-from ..observability import FlightRecorder, RecompileSentinel, SpanTracer
+from ..observability import (FlightRecorder, RecompileSentinel, SpanTracer,
+                             in_setup_span, setup_report, setup_span)
 from .locktrace import get_tracer, host_sync, wrap_lock
 from .metrics import ServingMetrics
 from .prefix_cache import ColdTier, PrefixCache, _fp_extend
@@ -368,6 +369,7 @@ class ServingEngine:
                         "only evicts under the engine tick lock"],
     }
 
+    @in_setup_span("serving.setup.init")
     def __init__(self, params, cfg, *, model=None, max_batch: int = 8,
                  page_size: int = 16, total_pages: Optional[int] = None,
                  max_prompt_len: int = 64, max_new_tokens_cap: int = 64,
@@ -390,6 +392,10 @@ class ServingEngine:
                  spec_k: int = 3,
                  cold_tier_bytes: int = 0,
                  on_chain_complete=None):
+        # time to ready (``stats()["setup"]``): from here to the first
+        # tick that carries a request
+        self._setup_t0 = time.monotonic()
+        self._ready_t: Optional[float] = None
         if max_batch < 1:
             raise ValueError("max_batch must be >= 1")
         if prefill_chunk is not None:
@@ -490,30 +496,31 @@ class ServingEngine:
         # dispatch change that silently multiplies the program set
         # warns at construction instead of stalling under traffic; the
         # warning names the offending program set.
-        from ..analysis.recompile import (ServingGeometry,
-                                          program_inventory)
-        geom = ServingGeometry(
-            page_size=page_size, pages_per_slot=pages_per_slot,
-            buckets=list(self._buckets), prefill_chunk=prefill_chunk,
-            max_batch=max_batch, decode_block=self._decode_block,
-            spec_k=self._spec_k)
-        # the static proof's inventory, kept on the engine: the
-        # recompile sentinel reports it as "expected", the flight
-        # recorder ships it with every postmortem, and graph_lint
-        # --json emits the identical schema — one diffable document
-        self.program_inventory = program_inventory(geom)
-        worst = self.program_inventory["programs_per_bucket"]
-        if worst > 2:
-            import warnings
-            warnings.warn(
-                f"serving geometry (page_size={page_size}, "
-                f"buckets={self._buckets}, "
-                f"prefill_chunk={prefill_chunk}, "
-                f"decode_block={self._decode_block}) reaches {worst} "
-                f"distinct tick programs in one width bucket (> 2): "
-                f"{self.program_inventory['widths']}"
-                f" — each is an XLA compile inside a serving tick; see "
-                f"docs/ANALYSIS.md recompile-hazard.", stacklevel=2)
+        with setup_span("serving.setup.init.inventory"):
+            from ..analysis.recompile import (ServingGeometry,
+                                              program_inventory)
+            geom = ServingGeometry(
+                page_size=page_size, pages_per_slot=pages_per_slot,
+                buckets=list(self._buckets), prefill_chunk=prefill_chunk,
+                max_batch=max_batch, decode_block=self._decode_block,
+                spec_k=self._spec_k)
+            # the static proof's inventory, kept on the engine: the
+            # recompile sentinel reports it as "expected", the flight
+            # recorder ships it with every postmortem, and graph_lint
+            # --json emits the identical schema — one diffable document
+            self.program_inventory = program_inventory(geom)
+            worst = self.program_inventory["programs_per_bucket"]
+            if worst > 2:
+                import warnings
+                warnings.warn(
+                    f"serving geometry (page_size={page_size}, "
+                    f"buckets={self._buckets}, "
+                    f"prefill_chunk={prefill_chunk}, "
+                    f"decode_block={self._decode_block}) reaches {worst} "
+                    f"distinct tick programs in one width bucket (> 2): "
+                    f"{self.program_inventory['widths']}"
+                    f" — each is an XLA compile inside a serving tick; see "
+                    f"docs/ANALYSIS.md recompile-hazard.", stacklevel=3)
         if check_invariants is None:
             check_invariants = _env_flag(
                 "PADDLE_TPU_SERVING_CHECK_INVARIANTS", False)
@@ -543,8 +550,11 @@ class ServingEngine:
 
         # the cache pytree the model made: the two page pools, and what
         # its other layer kinds keep (sized by the slots)
-        self._cache = dict(self._mod.init_serving_pages(
-            cfg, total_pages, page_size, max_batch=max_batch))
+        import jax
+        with setup_span("serving.setup.init.cache"):
+            self._cache = jax.block_until_ready(dict(  # noqa: PT002 — the set-up span holds the allocation, once an engine
+                self._mod.init_serving_pages(
+                    cfg, total_pages, page_size, max_batch=max_batch)))
         # its page pools by name, and whether they are the K and V pools
         # that chain export / adopt and the cold tier carry
         self._pools = _page_pools(self._mod, cfg)
@@ -566,7 +576,6 @@ class ServingEngine:
             # what ONE slot holds of it (the trash row is one more)
             self._state_bytes_per_slot = (
                 self._slot_state_bytes // (max_batch + 1))
-        import jax
         self._jnp = jax.numpy
         self._tick_jit, self._block_jit = _jit_step_fns(
             self._mod, cfg, attn_impl, rewrites=rewrites)
@@ -902,8 +911,20 @@ class ServingEngine:
         return snap
 
     def stats(self) -> dict:
-        """Alias of :meth:`snapshot` (the pre-r13 name)."""
-        return self.snapshot()
+        """:meth:`snapshot` plus ``setup``: where the time to ready
+        went (``observability.setup_report``: the set-up spans, the
+        compile ledger's totals and slowest programs, the rows that
+        partition it), as of the first tick that carried a request;
+        until then (``ready`` False) as of now."""
+        snap = self.snapshot()
+        with self._tick_lock:
+            ready_t = self._ready_t
+        snap["setup"] = dict(
+            setup_report(since=self._setup_t0, until=ready_t),
+            ready=ready_t is not None,
+            time_to_ready_s=(None if ready_t is None
+                             else ready_t - self._setup_t0))
+        return snap
 
     def gauges(self) -> dict:
         """Flat ``{name: number}`` view of the live pool/queue gauges
@@ -1385,6 +1406,7 @@ class ServingEngine:
         # the depth of whoever called us (core/stack_anchor.py)
         return above_stack_anchor(self._warm_programs)
 
+    @in_setup_span("serving.setup.warm")
     def _warm_programs(self) -> int:
         import jax
         jnp = self._jnp
@@ -1415,20 +1437,30 @@ class ServingEngine:
                 T = S + w
                 tok = jnp.asarray(np.zeros((T,), np.int32))
                 if self._spec_k:
-                    self._step_tick(tok, spec_meta(T), tq=w,
-                                    decode_tail=0, spec_k=self._spec_k)
+                    with setup_span("serving.setup.warm.program", tq=w,
+                                    decode_tail=0, spec_k=self._spec_k):
+                        self._step_tick(tok, spec_meta(T), tq=w,
+                                        decode_tail=0, spec_k=self._spec_k)
                     n += 1
                 else:
                     tails = {self._decode_block - 1, 0}
                     for tail in sorted(tails, reverse=True):
-                        self._step_tick(tok, pad_meta(T), tq=w,
-                                        decode_tail=tail)
+                        with setup_span("serving.setup.warm.program",
+                                        tq=w, decode_tail=tail, spec_k=0):
+                            self._step_tick(tok, pad_meta(T), tq=w,
+                                            decode_tail=tail)
                         n += 1
             # width S: the fused block — the ONLY pure-decode program
             # since r16 (the single-step sampling tick is gone: its
             # traffic rides the block through the in-graph sampler)
-            self._step_block(jnp.asarray(zs), jnp.asarray(tabs), samp)
+            with setup_span("serving.setup.warm.program",
+                            block=self._decode_block):
+                self._step_block(jnp.asarray(zs), jnp.asarray(tabs), samp)
             n += 1
+            # the calls above return at dispatch: the programs' first
+            # runs end here
+            with setup_span("serving.setup.warm.sync"):
+                jax.block_until_ready((self._cur_tok_d, self._cache))  # noqa: PT002 — warm-up ends when the programs' first runs do
         return n
 
     def audit(self):
@@ -2398,6 +2430,8 @@ class ServingEngine:
                     prev = self._inflight
                     ticked = prev is not None or bool(live) or bool(spans)
                     if live or spans:
+                        if self._ready_t is None:
+                            self._ready_t = now
                         # inter-decode-tick stall: everything since the
                         # last tick's completion (host work, metadata
                         # builds) shows up as this gap; with a tick in

@@ -32,7 +32,10 @@ import jax.numpy as jnp
 
 from paddle_tpu.models import llama as L
 from paddle_tpu.observability import (FlightRecorder, RecompileWarning,
-                                      SpanTracer, current_span)
+                                      SpanTracer, compile_ledger,
+                                      compile_totals, current_span,
+                                      process_tracer, setup_report,
+                                      setup_span)
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.metrics import Histogram, ServingMetrics
 
@@ -757,6 +760,330 @@ def test_sentinel_expected_inventory_matches_static_proof(params):
         assert inv["programs_per_bucket"] <= 2
     # closed engine: sentinel detached from the process listener
     assert eng.sentinel._closed
+
+
+# ---------------------------------------------------------------------------
+# the compile ledger and the set-up spans (ISSUE 42)
+# ---------------------------------------------------------------------------
+
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND = "/jax/core/compile/backend_compile_duration"
+CC = "/jax/compilation_cache/"
+
+
+def _feed(kind, name=None, seconds=0.0):
+    """One monitoring event handed straight to the ledger's listener
+    (no program compiles, the shared ``.jax_cache`` is never touched)."""
+    from paddle_tpu.observability import sentinel as S
+    if kind == "event":
+        S._on_event(CC + name)
+    elif kind in (TRACE, LOWER, BACKEND):
+        fun = name if kind == TRACE else f"jit({name})"
+        S._on_event_duration(kind, seconds, fun_name=fun)
+    else:
+        S._on_event_duration(CC + kind, seconds)
+
+
+def _program_events(name, cache, trace=0.5, lower=0.25, backend=2.0):
+    """The stream JAX emits for one program, in its order."""
+    evs = [(TRACE, "inner_of_" + name, 0.125),   # a jit traced INTO it
+           (TRACE, name, trace), (LOWER, name, lower)]
+    if cache != "uncached":
+        evs.append(("event", "compile_requests_use_cache", 0.0))
+    if cache == "hit":
+        evs += [("event", "cache_hits", 0.0),
+                ("compile_time_saved_sec", None, 7.5),
+                ("cache_retrieval_time_sec", None, 0.0625)]
+    elif cache == "miss":
+        evs.append(("event", "cache_misses", 0.0))
+    return evs + [(BACKEND, name, backend)]
+
+
+def _mine(prefix):
+    return [r for r in compile_ledger() if r["fun_name"].startswith(prefix)]
+
+
+def test_ledger_pairs_a_synthetic_event_stream():
+    """A hit, a miss, an uncached program, and what is no program: the
+    records carry the right seconds, cache state and open span."""
+    with process_tracer().span("t42.outer", track="setup"):
+        for ev in _program_events("t42a_hit", "hit", backend=0.75):
+            _feed(*ev)
+    for ev in _program_events("t42a_miss", "miss", backend=3.0):
+        _feed(*ev)
+    for ev in _program_events("t42a_quick", "miss", backend=0.01)[:-2] \
+            + [(BACKEND, "t42a_quick", 0.01)]:
+        _feed(*ev)      # asked, neither held nor written: still a miss
+    for ev in _program_events("t42a_plain", "uncached", backend=1.0):
+        _feed(*ev)
+    _feed(TRACE, "t42a_shape_only", 4.0)        # eval_shape: no record
+    _feed(BACKEND, "t42a_aot", 0.5)             # compiled, traced long ago
+    _feed(LOWER, "t42a_relowered", 0.25)        # lowered again, no trace
+    _feed(BACKEND, "t42a_relowered", 0.5)
+    recs = {r["fun_name"]: r for r in _mine("t42a_")}
+    assert set(recs) == {"t42a_hit", "t42a_miss", "t42a_quick",
+                         "t42a_plain", "t42a_aot", "t42a_relowered"}
+    hit = recs["t42a_hit"]
+    assert (hit["cache"], hit["trace_s"], hit["lower_s"], hit["backend_s"],
+            hit["retrieval_s"], hit["saved_s"], hit["during"]) == (
+        "hit", 0.5, 0.25, 0.75, 0.0625, 7.5, "t42.outer")
+    assert hit["thread"] == threading.current_thread().name
+    assert hit["t0"] <= hit["t_end"] <= time.monotonic()
+    assert recs["t42a_miss"]["cache"] == "miss"
+    assert recs["t42a_miss"]["during"] is None
+    assert recs["t42a_quick"]["cache"] == "miss"
+    assert recs["t42a_plain"]["cache"] == "uncached"
+    assert recs["t42a_plain"]["trace_s"] == 0.5     # not the inner jit's
+    aot = recs["t42a_aot"]
+    assert (aot["trace_s"], aot["lower_s"], aot["cache"]) == (
+        0.0, 0.0, "uncached")       # the orphan trace is not its own
+    assert recs["t42a_relowered"]["trace_s"] == 0.0
+    assert recs["t42a_relowered"]["lower_s"] == 0.25
+    tot = compile_totals(records=list(recs.values()))
+    assert (tot["programs"], tot["hits"], tot["misses"], tot["uncached"]) \
+        == (6, 1, 2, 3)
+    assert tot["compile_s"] == 3.0 + 0.01 + 1.0 + 0.5 + 0.5
+    assert tot["hit_s"] == 0.75 and tot["cache_read_s"] == 0.0625
+    assert tot["saved_s"] == 7.5
+    assert tot["trace_s"] == 2.0 and tot["lower_s"] == 1.25
+    # since / until bound the records by their end
+    assert compile_totals(since=time.monotonic())["programs"] == 0
+    assert compile_totals(until=hit["t_end"])["programs"] \
+        < compile_totals()["programs"]
+
+
+def test_ledger_pairs_per_thread_when_two_threads_interleave():
+    import queue
+    streams = {"A": _program_events("t42b_A", "hit", trace=1.0),
+               "B": _program_events("t42b_B", "uncached", trace=2.0)}
+    qs = {k: queue.Queue() for k in streams}
+    done = queue.Queue()
+
+    def worker(k):
+        for ev in iter(qs[k].get, None):
+            _feed(*ev)
+            done.put(k)
+    threads = [threading.Thread(target=worker, args=(k,), name=f"t42b-{k}")
+               for k in streams]
+    for th in threads:
+        th.start()
+    for a, b in zip(streams["A"], streams["B"] + [None] * 4):
+        for k, ev in (("A", a), ("B", b)):      # A, B, A, B, ...
+            if ev is not None:
+                qs[k].put(ev)
+                done.get(timeout=10)
+    for k, th in zip(streams, threads):
+        qs[k].put(None)
+        th.join(timeout=10)
+    recs = {r["fun_name"]: r for r in _mine("t42b_")}
+    assert recs["t42b_A"]["cache"] == "hit"
+    assert recs["t42b_A"]["trace_s"] == 1.0
+    assert recs["t42b_A"]["thread"] == "t42b-A"
+    assert recs["t42b_B"]["cache"] == "uncached"    # A's hit is not B's
+    assert recs["t42b_B"]["trace_s"] == 2.0
+    assert recs["t42b_B"]["retrieval_s"] == 0.0
+    assert recs["t42b_B"]["thread"] == "t42b-B"
+
+
+def test_a_real_jit_is_one_record_named_after_the_open_span():
+    def t42c_probe(x):
+        return jnp.tanh(x) * jnp.arange(5.0) + jnp.where(x > 0, x, 0.0)
+    x = jnp.ones((5,), jnp.float32)
+    jax.block_until_ready(x)
+    since = time.monotonic()
+    with process_tracer().span("t42.real_jit", track="setup"):
+        jax.block_until_ready(jax.jit(t42c_probe)(x))
+    recs = [r for r in compile_ledger() if r["t_end"] > since]
+    assert [r["fun_name"] for r in recs] == ["t42c_probe"]
+    rec = recs[0]
+    assert rec["trace_s"] > 0 and rec["lower_s"] > 0 and rec["backend_s"] > 0
+    assert rec["during"] == "t42.real_jit"
+    assert rec["cache"] in ("hit", "miss", "uncached")
+    assert rec["t0"] >= since - 1e-3
+    # the three phases lie inside the record's own interval
+    assert rec["trace_s"] + rec["lower_s"] + rec["backend_s"] \
+        <= rec["t_end"] - rec["t0"] + 5e-3
+
+
+def _by_name(report):
+    out = {}
+    for s in report["spans"]:
+        out.setdefault(s["name"], []).append(s)
+    return out
+
+
+def test_engine_setup_spans_and_stats_block(params):
+    from paddle_tpu.serving import engine as _em
+    _em._JIT_CACHE.clear()      # fresh jit objects: the programs compile
+    eng = _engine(params)
+    try:
+        n = eng.warm_programs()
+        before = eng.stats()["setup"]
+        assert before["ready"] is False and before["time_to_ready_s"] is None
+        eng.submit(np.arange(5, dtype=np.int32), 3).result(timeout=300)
+        setup = eng.stats()["setup"]
+    finally:
+        eng.close()
+    assert setup["ready"] is True
+    spans = _by_name(setup)
+    (init,), (warm,) = spans["serving.setup.init"], spans["serving.setup.warm"]
+    assert init["parent"] is None and warm["parent"] is None
+    kids = spans["serving.setup.init.inventory"] \
+        + spans["serving.setup.init.cache"]
+    assert all(k["parent"] == "serving.setup.init" for k in kids)
+    assert sum(k["dur_s"] for k in kids) <= init["dur_s"]
+    assert init["self_s"] == pytest.approx(
+        init["dur_s"] - sum(k["dur_s"] for k in kids), abs=1e-9)
+    programs = spans["serving.setup.warm.program"]
+    assert len(programs) == n
+    assert {"tq", "decode_tail", "spec_k"} <= set(programs[0]["args"])
+    assert "block" in programs[-1]["args"]
+    (sync,) = spans["serving.setup.warm.sync"]
+    inside = programs + [sync]
+    assert all(k["parent"] == "serving.setup.warm" for k in inside)
+    assert sum(k["dur_s"] for k in inside) <= warm["dur_s"]
+    assert all(warm["t0_s"] <= k["t0_s"] and k["t0_s"] + k["dur_s"]
+               <= warm["t0_s"] + warm["dur_s"] + 1e-9 for k in inside)
+    # every program the warm-up materialised is named after its span
+    warmed = [r for r in compile_ledger()
+              if warm["t0_s"] < r["t_end"] <= warm["t0_s"] + warm["dur_s"]]
+    assert len(warmed) == n
+    assert {r["during"] for r in warmed} == {"serving.setup.warm.program"}
+    assert {r["fun_name"] for r in warmed} == {"serving_tick",
+                                               "serving_tick_block"}
+    # the rows partition the program's part of time to ready
+    rows = setup["rows"]
+    assert rows["train_init_s"] == 0
+    parts = ("compile_s", "cache_read_s", "trace_lower_s",
+             "engine_init_s", "warm_s")
+    assert sum(rows[k] for k in parts) == pytest.approx(
+        rows["in_program_s"], abs=1e-6)
+    assert rows["in_program_s"] <= setup["time_to_ready_s"]
+    assert setup["by_during"]["serving.setup.warm.program"]["programs"] == n
+    assert setup["slowest"][0]["fun_name"].startswith("serving_tick")
+
+
+def test_train_setup_spans_close_when_the_state_is_ready(monkeypatch):
+    from paddle_tpu.parallel.mesh import init_hybrid_mesh
+    hm = init_hybrid_mesh(dp=2, pp=1, tp=2, set_global=False)
+    blocked_in = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: (blocked_in.append(current_span()), real(x))[1])
+    key = jax.block_until_ready(jax.random.PRNGKey(0))
+    since = time.monotonic()
+    with hm.mesh:
+        step, init = L.make_train_step(CFG, hm.mesh)
+        state = init(key)
+        t_back = time.monotonic()
+    assert "train.setup.init" in blocked_in
+    assert all(leaf.is_ready() for leaf in jax.tree_util.tree_leaves(state))
+    rep = setup_report(since=since)
+    spans = _by_name(rep)
+    (build,), (ini,) = spans["train.setup.build"], spans["train.setup.init"]
+    assert build["parent"] is None and ini["parent"] is None
+    assert build["t0_s"] + build["dur_s"] <= ini["t0_s"]
+    assert ini["t0_s"] + ini["dur_s"] <= t_back
+    made = [r for r in compile_ledger() if r["t_end"] > since]
+    assert made and {r["during"] for r in made} <= {"train.setup.build",
+                                                    "train.setup.init"}
+    rows = rep["rows"]
+    assert rows["train_init_s"] > 0 and rows["engine_init_s"] == 0
+    assert rows["train_init_s"] + rows["compile_s"] + rows["cache_read_s"] \
+        + rows["trace_lower_s"] == pytest.approx(rows["in_program_s"],
+                                                 abs=1e-6)
+    # the MoE family's trainer opens the same spans
+    from paddle_tpu.models import qwen2_moe as Q
+    qcfg = Q.Qwen2MoeConfig.tiny(dtype=jnp.float32)
+    since = time.monotonic()
+    with hm.mesh:
+        _, qinit = Q.make_train_step(qcfg, hm.mesh)
+        qinit(key)
+    assert {"train.setup.build", "train.setup.init"} <= set(
+        _by_name(setup_report(since=since)))
+
+
+def test_armed_sentinel_names_the_program_and_the_cache():
+    from paddle_tpu.observability import RecompileSentinel
+    m, tr = ServingMetrics(), SpanTracer()
+    s = RecompileSentinel(tracer=tr, metrics=m, label="t42")
+    try:
+        for ev in _program_events("t42e_warm", "miss"):
+            _feed(*ev)
+        assert s.report()["warmup_compiles"] == 1 and s.clean
+        s.arm()
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            with tr.span("serving.tick"):
+                for ev in _program_events("t42e_late", "hit", backend=0.5):
+                    _feed(*ev)
+            for ev in _program_events("t42e_idle", "miss"):
+                _feed(*ev)
+    finally:
+        s.close()
+    msgs = [str(w.message) for w in caught
+            if isinstance(w.message, RecompileWarning)]
+    assert len(msgs) == 2
+    assert "t42e_late" in msgs[0] and "(hit)" in msgs[0]
+    assert "during serving.tick" in msgs[0]
+    assert "t42e_idle" in msgs[1] and "(miss)" in msgs[1]
+    rep = s.report()
+    assert rep["post_warmup_compiles"] == 2 and not rep["clean"]
+    late = rep["events"][-2]
+    assert (late["program"], late["cache"], late["during"], late["phase"]) \
+        == ("t42e_late", "hit", "serving.tick", "post_warmup")
+    assert late["compile_s"] == 0.5
+    labels = [lbl["labels"] for lbl in m.snapshot()["labeled"]["recompiles"]]
+    assert {"during": "serving.tick", "program": "t42e_late",
+            "cache": "hit"} in labels
+    assert {"during": "idle", "program": "t42e_idle",
+            "cache": "miss"} in labels
+    sent = [sp for sp in tr.spans() if sp.track == "sentinel"]
+    assert [sp.args["program"] for sp in sent] == ["t42e_late", "t42e_idle"]
+    assert "t42e_late" in sent[0].name and "hit" in sent[0].name
+    # detached: a later compile reaches the ledger, not the sentinel
+    _feed(BACKEND, "t42e_after_close", 0.25)
+    assert s.report()["post_warmup_compiles"] == 2
+    assert _mine("t42e_after_close")
+
+
+def test_perf_counter_and_monotonic_are_one_clock_here():
+    """The benchmark stamps ``time.perf_counter()``, the program
+    ``time.monotonic()``; its set-up readers convert through one offset,
+    which on Linux is 0 (both read CLOCK_MONOTONIC)."""
+    offs = [time.monotonic() - time.perf_counter() for _ in range(5)]
+    assert max(abs(o) for o in offs) < 1e-3
+
+
+def test_ledger_and_process_ring_stay_bounded():
+    """Last of the set-up tests: it pushes everything older out."""
+    from paddle_tpu.observability import sentinel as S
+    from paddle_tpu.observability import ledger_health
+    held, dropped = len(compile_ledger()), ledger_health()["dropped"]
+    extra = S.LEDGER_CAPACITY + 10
+    for i in range(extra):
+        _feed(BACKEND, f"t42f_{i}", 0.001)
+    recs = compile_ledger()
+    assert len(recs) == S.LEDGER_CAPACITY
+    assert recs[-1]["fun_name"] == f"t42f_{extra - 1}"
+    tot = ledger_health()
+    assert tot["held"] == S.LEDGER_CAPACITY
+    assert tot["dropped"] - dropped == held + extra - S.LEDGER_CAPACITY
+    assert tot["listener_events"] >= extra and tot["listener_s"] > 0
+    assert tot["listener_s"] / tot["listener_events"] < 1e-3
+    tr = process_tracer()
+    cap = tr._ring.maxlen
+    assert cap == 256
+    d0, n0 = tr.dropped, len(tr.spans())
+    for i in range(cap + 5):
+        with setup_span("t42f.span", i=i):
+            pass
+    assert len(tr.spans()) == cap
+    assert tr.dropped - d0 == n0 + 5
+    assert all(s.track == "setup" for s in tr.spans())
+    assert setup_report()["rows"]["in_program_s"] >= 0
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,7 @@
-"""Hashes of the serving cells' LOWERED tick programs, for a described
-v5e (no chip): what a PR that moves shared code compares at parent and
-change to show that a family's programs did not move.
+"""Hashes of the serving cells' LOWERED tick programs and the training
+cells' LOWERED train steps, for a described v5e (no chip): what a PR
+that moves shared code compares at parent and change to show that a
+family's programs did not move.
 
     JAX_PLATFORMS=cpu python tools/program_hashes.py [cell ...] > change.json
     (cd <a checkout of the parent> && JAX_PLATFORMS=cpu python \\
@@ -11,7 +12,8 @@ For every serving cell of ``BENCHMARK.json`` (or those named), at the
 cell's own configuration, slots, table, pool and chunk: the engine's
 jitted tick at ``slots + prefill_chunk`` rows (plain and with a fused
 tail of 3) and its fused block of 4, lowered to StableHLO text and
-hashed. A Mosaic kernel's serialized body carries the Python call stack
+hashed; for every training cell, ``make_train_step``'s step at the
+cell's widths, depth, batch and mesh (``<cell>.step``). A Mosaic kernel's serialized body carries the Python call stack
 of its call site (file names, function names, line numbers), so an edit
 to a docstring above the call would change it: each body is replaced by
 the hash of its assembly printed WITHOUT debug info first.
@@ -53,6 +55,35 @@ def strip_kernel_locations(text: str) -> str:
     return re.sub(r'backend_config = "(\{.*?\})"', sub, text)
 
 
+def train_step_text(cell, topo) -> str:
+    """The cell's train step lowered over shapes only, built the way
+    ``benchmark/harness/train.py: Trainer`` builds it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.ops.pallas import flash_attention as FA
+    from paddle_tpu.ops.pallas import fused_norm_rope as FN
+    from paddle_tpu.parallel import init_hybrid_mesh
+    FA._on_tpu = FN._on_tpu = lambda: True
+    tr = cell.workload["trainer"]
+    dp, tp = int(tr.get("dp", 1)), int(tr.get("tp", 1))
+    B, T = int(tr["batch"]), int(tr["seq_len"])
+    cfg, L = cell.family.program_config(
+        dict(cell.model), max_position_embeddings=T,
+        use_flash_attention="pallas", use_fused_norm_rope="pallas")
+    mesh = init_hybrid_mesh(dp=dp, pp=1, tp=tp, set_global=False,
+                            devices=topo.devices[:dp * tp]).mesh
+    with mesh:
+        step, init = L.make_train_step(cfg, mesh)
+        state = jax.tree.map(
+            lambda a, sp: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, sp)),
+            jax.eval_shape(init, jax.random.PRNGKey(0)),
+            L.train_state_specs(cfg, mesh))
+        batch = {k: jax.ShapeDtypeStruct(
+            (B, T), jnp.int32, sharding=NamedSharding(mesh, P("dp", None)))
+            for k in ("tokens", "labels")}
+        return step.lower(state, batch).as_text()
+
+
 def main(names) -> dict:
     from harness import manifest
     from paddle_tpu.ops.pallas import ragged_paged_attention as R
@@ -79,7 +110,10 @@ def main(names) -> dict:
     out = {}
     for name in names or [w["name"] for w in bench["workloads"]]:
         cell = manifest.Cell(bench, name)
-        if not cell.mode.startswith("serve"):
+        if cell.mode == "train":
+            text = strip_kernel_locations(train_step_text(cell, topo))
+            out[f"{name}.step"] = hashlib.sha256(
+                text.encode()).hexdigest()[:16]
             continue
         cfg, mod = cell.family.program_config(dict(cell.model))
         eng = cell.workload["engine"]
