@@ -29,6 +29,61 @@ def default_capacity(num_tokens: int, num_experts: int, top_k: int,
     return max(cap, top_k)
 
 
+def _renormalize(gate_vals):
+    """The chosen experts' scores over their sum (mixtral-style), one
+    ``[S]`` array a choice."""
+    denom = sum(gate_vals)
+    denom = jnp.where(denom > 0, denom, 1.0)
+    return [gv / denom for gv in gate_vals]
+
+
+def top_k_choice(
+    logits: jax.Array,
+    top_k: int,
+    *,
+    score_fn: str = "softmax",
+    select_bias: Optional[jax.Array] = None,
+    normalize_topk: bool = False,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The router's choice: ``logits [S, E]`` -> ``(idx [S, k] int32,
+    w [S, k] float32, raw_gates [S, E])``, a token's ``top_k`` experts
+    in the order it prefers them and their combine weights. The ONE
+    place the choice is made: :func:`top_k_gating` builds its capacity
+    dispatch from it and the serving block (:func:`moe_ffn_share`) its
+    sorted rows, so the two cannot drift.
+
+    ``score_fn``: ``"softmax"`` over the experts or ``"sigmoid"``, each
+    expert scored on its own. ``select_bias [E]`` is added to the scores
+    for the CHOICE only; the weights stay the unbiased scores.
+    ``normalize_topk`` divides them by their sum over the choice."""
+    E = logits.shape[-1]
+    compute_dtype = jnp.float32
+    if score_fn == "softmax":
+        raw_gates = jax.nn.softmax(logits.astype(compute_dtype), axis=-1)
+    elif score_fn == "sigmoid":
+        raw_gates = jax.nn.sigmoid(logits.astype(compute_dtype))
+    else:
+        raise ValueError(f"score_fn must be 'softmax' or 'sigmoid', got "
+                         f"{score_fn!r}")
+    # iteratively peel off the top-k experts per token
+    idxs, gate_vals = [], []
+    g = raw_gates
+    if select_bias is not None:
+        g = g + select_bias.astype(compute_dtype)[None]
+    for _ in range(top_k):
+        idx = jnp.argmax(g, axis=-1)
+        m = jax.nn.one_hot(idx, E, dtype=compute_dtype)      # [S, E]
+        # a chosen expert is never picked again (a biased score may be
+        # negative: zero would not keep it out)
+        g = (g * (1.0 - m) if select_bias is None
+             else jnp.where(m > 0, -jnp.inf, g))
+        idxs.append(idx.astype(jnp.int32))
+        gate_vals.append(jnp.sum(raw_gates * m, axis=-1))    # [S]
+    if normalize_topk:
+        gate_vals = _renormalize(gate_vals)
+    return jnp.stack(idxs, axis=-1), jnp.stack(gate_vals, axis=-1), raw_gates
+
+
 def top_k_gating(
     logits: jax.Array,
     top_k: int,
@@ -49,10 +104,8 @@ def top_k_gating(
       key: optional PRNG key; with ``second_policy='random'`` the 2nd+
         expert is kept with probability proportional to its gate value
         (gshard_gate.py random routing).
-      score_fn: ``"softmax"`` over the experts (default) or
-        ``"sigmoid"``, each expert scored on its own.
-      select_bias: optional ``[E]`` added to the scores for the CHOICE
-        of experts only; the combine weights stay the unbiased scores.
+      score_fn, select_bias, normalize_topk: the router's, as
+        :func:`top_k_choice` takes them.
 
     Returns:
       (dispatch, combine, aux_loss) with dispatch ``[S, E, C]`` one-hot,
@@ -61,36 +114,23 @@ def top_k_gating(
     """
     S, E = logits.shape
     compute_dtype = jnp.float32
-    if score_fn == "softmax":
-        raw_gates = jax.nn.softmax(logits.astype(compute_dtype), axis=-1)
-    elif score_fn == "sigmoid":
-        raw_gates = jax.nn.sigmoid(logits.astype(compute_dtype))
-    else:
-        raise ValueError(f"score_fn must be 'softmax' or 'sigmoid', got "
-                         f"{score_fn!r}")
-
-    # iteratively peel off the top-k experts per token
-    masks, gate_vals = [], []
-    g = raw_gates
-    if select_bias is not None:
-        g = g + select_bias.astype(compute_dtype)[None]
-    for i in range(top_k):
-        idx = jnp.argmax(g, axis=-1)
-        m = jax.nn.one_hot(idx, E, dtype=compute_dtype)      # [S, E]
-        # peel BEFORE random drop so a dropped expert is never
-        # re-picked at the next iteration (a biased score may be
-        # negative: zero would not keep it out)
-        g = (g * (1.0 - m) if select_bias is None
-             else jnp.where(m > 0, -jnp.inf, g))
-        gv = jnp.sum(raw_gates * m, axis=-1)                 # [S]
-        if i > 0 and second_policy == "random" and key is not None:
+    random = second_policy == "random" and key is not None
+    # with random routing the weights are renormalised over what is KEPT
+    idx, w, raw_gates = top_k_choice(
+        logits, top_k, score_fn=score_fn, select_bias=select_bias,
+        normalize_topk=normalize_topk and not random)
+    masks = [jax.nn.one_hot(idx[:, i], E, dtype=compute_dtype)
+             for i in range(top_k)]
+    gate_vals = [w[:, i] for i in range(top_k)]
+    if random:
+        for i in range(1, top_k):
             # keep the i-th expert with prob 2*gate (gshard random routing)
             key, sub = jax.random.split(key)
-            keep = jax.random.uniform(sub, (S,)) < (2.0 * gv)
-            m = m * keep[:, None].astype(compute_dtype)
-            gv = gv * keep.astype(compute_dtype)
-        masks.append(m)
-        gate_vals.append(gv)
+            keep = jax.random.uniform(sub, (S,)) < (2.0 * gate_vals[i])
+            masks[i] = masks[i] * keep[:, None].astype(compute_dtype)
+            gate_vals[i] = gate_vals[i] * keep.astype(compute_dtype)
+        if normalize_topk:
+            gate_vals = _renormalize(gate_vals)
 
     # aux load-balance loss uses the top-1 assignment (switch_gate.py)
     density = jnp.mean(masks[0], axis=0)                     # fraction routed
@@ -101,10 +141,6 @@ def top_k_gating(
     # earlier tokens win capacity (cumsum ordering == reference prioritizing)
     dispatch = jnp.zeros((S, E, capacity), compute_dtype)
     combine = jnp.zeros((S, E, capacity), compute_dtype)
-    if normalize_topk:  # mixtral-style renormalization over the chosen k
-        denom = sum(gate_vals)
-        denom = jnp.where(denom > 0, denom, 1.0)
-        gate_vals = [gv / denom for gv in gate_vals]
     running = jnp.zeros((E,), compute_dtype)
     for m, gv in zip(masks, gate_vals):
         pos_all = jnp.cumsum(m, axis=0) - m + running        # [S, E]
@@ -241,16 +277,22 @@ def moe_ffn_share(
     scale: float = 1.0,
     layer=None,
     row_mask: Optional[jax.Array] = None,
+    score_fn: str = "softmax",
+    normalize_topk: bool = False,
     impl: str = "auto",
     tile_m: int = 16,
 ) -> Tuple[jax.Array, jax.Array]:
-    """The expert layer of ONE CHIP of an expert-parallel deployment: it
-    routes over every router output, computes the part of the experts it
-    HOLDS and the identity experts' part, and drops nothing.
+    """The SERVING expert layer, dropless: the experts a chip HOLDS of
+    an expert-parallel deployment's (``held = (0, num_routed)``: all of
+    them, a chip that serves the whole layer). It routes over every
+    router output, computes the part of the experts it holds and the
+    identity experts' part, and reads the weights of the held experts
+    that took a row and of no other.
 
     ``x [N, D]``; ``router_w [D, num_routed + zero_experts]`` (float32
-    router, softmax over all outputs, NOT renormalised over the choice);
-    ``select_bias`` enters the choice of the ``top_k`` only. ``experts``:
+    router; ``score_fn``, ``select_bias`` and ``normalize_topk`` as
+    :func:`top_k_choice` takes them, ``scale`` times the weights after
+    it). ``experts``:
     ``{"w_gate", "w_up": [n, D, F], "w_down": [n, F, D]}``, the ``n =
     held[1]`` experts ``held[0] .. held[0] + n - 1`` of the
     ``num_routed`` — or, with ``layer`` (i32 scalar), the model's stacks
@@ -277,11 +319,10 @@ def moe_ffn_share(
     N, D = x.shape
     with jax.named_scope("moe.router"):
         logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
-        p = jax.nn.softmax(logits, axis=-1)
-        sel = p if select_bias is None else p + select_bias.astype(
-            jnp.float32)[None]
-        _, idx = lax.top_k(sel, top_k)                              # [N, k]
-        w = jnp.take_along_axis(p, idx, axis=-1) * scale
+        idx, w, _ = top_k_choice(
+            logits, top_k, score_fn=score_fn, select_bias=select_bias,
+            normalize_topk=normalize_topk)                          # [N, k]
+        w = w * scale
         if row_mask is not None:
             w = jnp.where(row_mask[:, None], w, 0.0)
             # a masked row's choices land nowhere
@@ -307,9 +348,11 @@ def moe_ffn_share(
             h = h * jnp.einsum("nd,edf->enf", x, ex["w_up"])
             y = jnp.einsum("enf,efd,ne->nd", h, ex["w_down"],
                            per.astype(x.dtype)).astype(jnp.float32)
-    with jax.named_scope("moe.zero"):
-        z = jnp.sum(jnp.where(zero, w, 0.0), axis=-1)               # [N]
-        y = (y + z[:, None] * x.astype(jnp.float32)).astype(x.dtype)
+    if zero_experts:
+        with jax.named_scope("moe.zero"):
+            z = jnp.sum(jnp.where(zero, w, 0.0), axis=-1)           # [N]
+            y = y + z[:, None] * x.astype(jnp.float32)
+    y = y.astype(x.dtype)
     with jax.named_scope("moe.router"):
         pairs_held = here.sum()
         pairs_zero = zero.sum()

@@ -3,7 +3,8 @@
 granite_hybrid.py`` is their second user): what a kind keeps between
 ticks (``LayerKind``), the stack cut into a handful of groups
 (``layer_groups``), one layer's parameters out of a kind's stack
-(``_layer_params``), the loop over the groups (``walk_groups``) and
+(``_layer_params``), the loop over the groups (``walk_groups``), the
+counts a tick hands back beside its tokens (``with_tick_counts``) and
 the per-slot window of a causal depthwise convolution in a ragged tick
 (``earlier_rows`` / ``window_rows``).
 
@@ -42,6 +43,44 @@ class PagePoolSpec(NamedTuple):
 # what a family that declares nothing holds: K and V a head in two pools
 # ``[L, Hkv, P, ps, Dh]``
 KV_POOLS = (PagePoolSpec("k_pages", 2), PagePoolSpec("v_pages", 2))
+
+
+# a tick's per-launch counts (a family's ``TICK_COUNTERS``), carried
+# through its walk beside the pools under this key of the cache pytree
+COUNTS = "tick_counts"
+
+
+# what a family that holds EVERY routed expert counts over a tick's
+# expert launches (one an expert layer): the (row, choice) pairs it
+# computed, the experts that took a row (whose weights it read) and the
+# experts those launches held (touched / held: the share of the expert
+# bytes a window read)
+EXPERT_COUNTERS = ("moe_pairs_held", "moe_experts_touched",
+                   "moe_experts_held")
+
+
+def expert_counts(share_counts, num_experts: int):
+    """``EXPERT_COUNTERS`` of one launch from ``moe_ffn_share``'s
+    ``[pairs_held, pairs_zero, pairs_absent, experts_touched]`` at the
+    share ``(0, num_experts)``."""
+    return jnp.stack([share_counts[0], share_counts[3],
+                      num_experts]).astype(jnp.int32)
+
+
+def with_tick_counts(fn, cache, n: int, has_cur: bool):
+    """Run a tick entry point (``fn(cache) -> (..., [cur_tok',]
+    cache')``) with ``n`` counts carried in the cache under ``COUNTS``,
+    and hand them back BESIDE the tokens: ``(..., counts [n] i32,
+    [cur_tok',] cache')``. The family's walk adds to ``cache[COUNTS]``
+    where it finds it; the engine adds the counts to its counters when
+    the tick completes (no pull of their own)."""
+    *out, new = fn({**cache, COUNTS: jnp.zeros((n,), jnp.int32)})
+    new = dict(new)
+    counts = new.pop(COUNTS)
+    if has_cur:
+        *out, nxt = out
+        return (*out, counts, nxt, new)
+    return (*out, counts, new)
 
 
 class Group(NamedTuple):

@@ -89,15 +89,16 @@ from ..ops.pallas.mla_paged_attention import (latent_row_width,
                                               mla_paged_attention)
 from . import layer_walk as _lw
 from . import llama as _llama
-from .layer_walk import Group, LayerKind, PagePoolSpec, _layer_params
+from .layer_walk import (COUNTS, Group, LayerKind, PagePoolSpec,
+                         _layer_params)
 from .llama import _mm, rms_norm
 
 MLA = "mla"
 POOL = "latent_pages"
 # the tick's per-launch counts, carried through the walk beside the pool
-# and handed back BESIDE the tokens (``TICK_COUNTERS`` names them for the
-# engine, which adds them when the tick completes)
-COUNTS = "moe_counts"
+# (``layer_walk.COUNTS``) and handed back BESIDE the tokens
+# (``TICK_COUNTERS`` names them for the engine, which adds them when the
+# tick completes)
 TICK_COUNTERS = ("moe_pairs_held", "moe_pairs_zero", "moe_pairs_absent",
                  "moe_experts_touched")
 
@@ -446,7 +447,6 @@ def _walk(params, h, cache, meta, cfg: LongcatFlashConfig, tq, attn_impl):
     start = meta["last"] - q_len + 1
     real = tok_slot < S
     pad = cfg.row_width - cfg.latent_width
-    counts0 = cache.get(COUNTS)
 
     def mla_sublayer(lp, h, lat, sub):
         q_n, q_r, c_kv, k_r = _mla_qkv(lp, h, positions, cfg)
@@ -483,29 +483,14 @@ def _walk(params, h, cache, meta, cfg: LongcatFlashConfig, tq, attn_impl):
             h = _dense(dp, h, m)
         return h + s[None], lat, counts
 
-    counts = (jnp.zeros((len(TICK_COUNTERS),), jnp.int32)
-              if counts0 is None else counts0)
+    counts = cache.get(COUNTS, jnp.zeros((len(TICK_COUNTERS),), jnp.int32))
     with jax.named_scope("layers"):
         h, lat, counts = _lw.walk_groups(layer_groups(cfg),
                                          (h, cache[POOL], counts), run)
     new = {POOL: lat}
-    if counts0 is not None:
+    if COUNTS in cache:
         new[COUNTS] = counts
     return h, new
-
-
-def _with_counts(fn, cache, has_cur: bool):
-    """Run a tick entry point with the counts carried in the cache, and
-    hand them back beside the tokens: ``(..., counts, [cur_tok',]
-    cache')``."""
-    carried = {**cache, COUNTS: jnp.zeros((len(TICK_COUNTERS),), jnp.int32)}
-    *out, new = fn(carried)
-    new = dict(new)
-    counts = new.pop(COUNTS)
-    if has_cur:
-        *out, nxt = out
-        return (*out, counts, nxt, new)
-    return (*out, counts, new)
 
 
 def serving_tick_cache(params, tokens, meta, cache, cfg: LongcatFlashConfig,
@@ -517,11 +502,11 @@ def serving_tick_cache(params, tokens, meta, cache, cfg: LongcatFlashConfig,
     counts, cur_tok', cache')`` (with ``spec_k``: ``toks, accept,
     logits, counts, ...``); ``counts [4]`` i32 are the tick's
     ``TICK_COUNTERS`` over its launches."""
-    return _with_counts(
+    return _lw.with_tick_counts(
         lambda c: _llama.serving_tick_cache(
             params, tokens, meta, c, cfg, tq=tq, decode_tail=decode_tail,
             spec_k=spec_k, attn_impl=attn_impl, walk=_walk, page_pool=POOL),
-        cache, "cur_tok" in meta)
+        cache, len(TICK_COUNTERS), "cur_tok" in meta)
 
 
 def serving_tick_block_cache(params, tok, lengths, tables, cache,
@@ -529,9 +514,9 @@ def serving_tick_block_cache(params, tok, lengths, tables, cache,
                              attn_impl: str = "auto", sampling=None):
     """``num_steps`` fused decode ticks: ``(toks [S, num_steps], counts
     [4], tok' [S], cache')``."""
-    return _with_counts(
+    return _lw.with_tick_counts(
         lambda c: _llama.serving_tick_block_cache(
             params, tok, lengths, tables, c, cfg, num_steps,
             attn_impl=attn_impl, sampling=sampling, walk=_walk,
             page_pool=POOL),
-        cache, True)
+        cache, len(TICK_COUNTERS), True)

@@ -22,9 +22,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..incubate.moe.functional import moe_ffn
+from ..incubate.moe.functional import moe_ffn, moe_ffn_share
 from ..observability import in_setup_span, setup_span
+from .layer_walk import (COUNTS, EXPERT_COUNTERS, expert_counts,
+                         with_tick_counts)
 from .llama import _mm, rms_norm, rope
+
+# what a serving tick hands back beside its tokens
+TICK_COUNTERS = EXPERT_COUNTERS
 
 
 def _dense_w(w, dtype):
@@ -290,22 +295,40 @@ def init_kv_cache(cfg: Qwen2MoeConfig, batch_size: int, max_len: int):
             "v": jnp.zeros(shape, cfg.dtype)}
 
 
-def _decode_block(lp, h, positions, cfg: Qwen2MoeConfig, attn_fn):
+def _routed_capacity(lp, x, cfg: Qwen2MoeConfig):
+    """The routed experts through the capacity einsum, DROP-FREE:
+    capacity cf = E/top_k makes expert capacity == cohort size (C = N),
+    so no token is ever dropped. Training capacity drops are a
+    throughput regularizer; at inference a dropped token silently loses
+    its FFN contribution — and the drop pattern depends on cohort size,
+    which would make cached decode diverge from a full forward. It
+    reads EVERY expert of the layer whatever the rows chose: the
+    dense-cache decode's path, and a serving tick's where the expert
+    leaves are weight-only int8 (the grouped matmul reads bfloat16
+    stacks)."""
+    nodrop_cf = cfg.num_experts / cfg.num_experts_per_tok
+    routed, _ = moe_ffn(
+        x, lp["router"], _dense_w(lp["experts"]["w_gate"], cfg.dtype),
+        _dense_w(lp["experts"]["w_up"], cfg.dtype),
+        _dense_w(lp["experts"]["w_down"], cfg.dtype),
+        top_k=cfg.num_experts_per_tok,
+        capacity_factor=nodrop_cf, ep_axis=None)
+    return routed
+
+
+def _decode_block(lp, h, positions, cfg: Qwen2MoeConfig, attn_fn,
+                  routed_fn=_routed_capacity):
     """Qwen block math shared by every cached-decode consumer (dense
     cache forward_with_cache AND the serving engine's paged step fns):
     norm -> QKV -> rope -> attn_fn -> o-proj+residual -> norm -> MoE FFN
-    (DROP-FREE: capacity cf = E/top_k makes expert capacity == cohort
-    size, so no token is ever dropped. Training capacity drops are a
-    throughput regularizer; at inference a dropped token silently loses
-    its FFN contribution — and the drop pattern depends on cohort size,
-    which would make cached decode diverge from a full forward) + shared
-    expert + residual. Same signature as models/llama.py _block, so the
-    serving step drivers take either."""
+    (``routed_fn(lp, x, cfg)``: dropless either way, see
+    ``_routed_capacity`` and the serving ``_walk``) + shared expert +
+    residual."""
     B, T, D = h.shape
     H, Hkv, Dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                   cfg.head_dim)
     # the scopes of models/llama.py _block (metadata only); the MoE FFN
-    # brings ``moe.router`` and ``moe.experts`` (moe_ffn)
+    # brings ``moe.router`` and ``moe.experts``
     with jax.named_scope("attn.qkv_rope"):
         x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
         q = _mm(x, lp["wq"]).reshape(B, T, H, Dh)
@@ -318,13 +341,7 @@ def _decode_block(lp, h, positions, cfg: Qwen2MoeConfig, attn_fn):
 
     with jax.named_scope("moe.router"):     # the norm that feeds it
         x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
-    nodrop_cf = cfg.num_experts / cfg.num_experts_per_tok
-    routed, _ = moe_ffn(
-        x, lp["router"], _dense_w(lp["experts"]["w_gate"], cfg.dtype),
-        _dense_w(lp["experts"]["w_up"], cfg.dtype),
-        _dense_w(lp["experts"]["w_down"], cfg.dtype),
-        top_k=cfg.num_experts_per_tok,
-        capacity_factor=nodrop_cf, ep_axis=None)
+    routed = routed_fn(lp, x, cfg)
     with jax.named_scope("moe.shared"):
         sh = lp["shared"]
         shared = _mm(jax.nn.silu(_mm(x, sh["w_gate"]))
@@ -394,8 +411,10 @@ def make_batch(cfg: Qwen2MoeConfig, batch_size: int, seq_len: int,
 # serving: the tick over a shared page pool
 # ---------------------------------------------------------------------------
 # The three functions the engine calls (models/llama.py has the
-# contracts): the tick is llama's, walking this model's block
-# (_decode_block with the drop-free MoE FFN).
+# contracts): the tick is llama's, over this model's walk. A tick's
+# routed experts go through the held-experts grouped matmul at the share
+# ``(0, E)`` (``incubate/moe/functional.py: moe_ffn_share``): it fetches
+# the weights of the experts that took a row and of no other.
 
 
 def abstract_params(cfg: Qwen2MoeConfig):
@@ -410,17 +429,100 @@ def init_serving_pages(cfg: Qwen2MoeConfig, total_pages: int,
     return _impl(cfg, total_pages, page_size)
 
 
+def _walk(params, h, cache, meta, cfg: Qwen2MoeConfig, tq, attn_impl):
+    """The tick's layer walk (``models/llama.py _walk_one_kind``'s
+    contract and its scan: the stacked pools in the carry, a layer's
+    KV scattered in place, one ragged launch over the pages), with the
+    expert STACKS kept out of the scanned ``xs``: a Pallas call cannot
+    take a scanned slice without the compiler copying the layer's
+    experts (1.04 GB) in front of it, so the block gets the stacks and
+    the layer index. Rows that are no token (``tok_slot == S``: padding
+    and dead slots) route nowhere. The tick's counts (``TICK_COUNTERS``)
+    ride the carry where the caller carries them (``cache[COUNTS]``)."""
+    from ..ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention_packed)
+    k_pages, v_pages = cache["k_pages"], cache["v_pages"]
+    E, top_k = cfg.num_experts, cfg.num_experts_per_tok
+    tok_slot, tok_qoff = meta["tok_slot"], meta["tok_qoff"]
+    positions = meta["tok_pos"][None]
+    real = tok_slot < meta["q_len"].shape[0]
+    heads = jnp.arange(k_pages.shape[1], dtype=jnp.int32)[None, :]  # [1, Hkv]
+    tok_page = meta["tok_page"][:, None]                            # [T, 1]
+    tok_off = meta["tok_off"][:, None]
+    layers = dict(params["layers"])
+    experts = layers["experts"]
+    # what the code observes in its input: bfloat16 stacks go to the
+    # grouped matmul whole, weight-only int8 leaves stay scanned and
+    # keep the einsum
+    held = not any(hasattr(w, "dequant") for w in experts.values())
+    if held:
+        del layers["experts"]
+
+    def body(carry, xs):
+        h, kp, vp, counts = carry
+        lp, layer = xs
+        cell = {}
+
+        def attn_fn(q, k, v):
+            with jax.named_scope("kv_pool.write"):
+                kp2 = kp.at[layer, heads, tok_page, tok_off].set(
+                    k[0].astype(kp.dtype))
+                vp2 = vp.at[layer, heads, tok_page, tok_off].set(
+                    v[0].astype(vp.dtype))
+            cell["kp"], cell["vp"] = kp2, vp2
+            with jax.named_scope("ragged_attn"):
+                o = ragged_paged_attention_packed(
+                    q[0], kp2, vp2, tok_slot, tok_qoff, meta["q_len"],
+                    meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl,
+                    layer=layer)
+            return o[None].astype(q.dtype)
+
+        def routed_fn(lp, x, cfg):
+            if not held:
+                cell["counts"] = jnp.stack(
+                    [top_k * real.sum(), E, E]).astype(jnp.int32)
+                return _routed_capacity(lp, x, cfg)
+            y, c = moe_ffn_share(
+                x[0], lp["router"], None, experts, held=(0, E),
+                num_routed=E, top_k=top_k, layer=layer, row_mask=real)
+            cell["counts"] = expert_counts(c, E)
+            return y[None]
+
+        h = _decode_block(lp, h, positions, cfg, attn_fn, routed_fn)
+        return (h, cell["kp"], cell["vp"], counts + cell["counts"]), None
+
+    counts = cache.get(COUNTS, jnp.zeros((len(TICK_COUNTERS),), jnp.int32))
+    # an operation under bare ``layers`` is the scan's own: the slicing
+    # of a layer's weights
+    with jax.named_scope("layers"):
+        (h, kp_new, vp_new, counts), _ = lax.scan(
+            body, (h, k_pages, v_pages, counts),
+            (layers, jnp.arange(k_pages.shape[0], dtype=jnp.int32)))
+    new = {"k_pages": kp_new, "v_pages": vp_new}
+    if COUNTS in cache:
+        new[COUNTS] = counts
+    return h, new
+
+
 def serving_tick_cache(params, tokens, meta, cache, cfg, **kw):
     """The tick over the cache pytree, as the engine calls it
     (``models/llama.py serving_tick_cache``), walking this model's
-    block."""
-    from .llama import _one_kind_walk, serving_tick_cache as _impl
-    return _impl(params, tokens, meta, cache, cfg,
-                 walk=_one_kind_walk(_decode_block), **kw)
+    block: ``(toks, logits, counts, cache')``, with ``meta['cur_tok']``
+    ``(toks, logits, counts, cur_tok', cache')`` (with ``spec_k``:
+    ``toks, accept, logits, counts, ...``); ``counts [3]`` i32 are the
+    tick's ``TICK_COUNTERS`` over its launches."""
+    from .llama import serving_tick_cache as _impl
+    return with_tick_counts(
+        lambda c: _impl(params, tokens, meta, c, cfg, walk=_walk, **kw),
+        cache, len(TICK_COUNTERS), "cur_tok" in meta)
 
 
 def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
                              num_steps: int, **kw):
-    from .llama import _one_kind_walk, serving_tick_block_cache as _impl
-    return _impl(params, tok, lengths, tables, cache, cfg, num_steps,
-                 walk=_one_kind_walk(_decode_block), **kw)
+    """``num_steps`` fused decode ticks: ``(toks [S, num_steps], counts
+    [3], tok' [S], cache')``."""
+    from .llama import serving_tick_block_cache as _impl
+    return with_tick_counts(
+        lambda c: _impl(params, tok, lengths, tables, c, cfg, num_steps,
+                        walk=_walk, **kw),
+        cache, len(TICK_COUNTERS), True)

@@ -304,7 +304,8 @@ def moe_mlp_dropless(x, expert_ids, combine_weights, w_gate, w_up, w_down,
 # Here a grid step is an EXPERT (and a column block of its weights), each
 # weight block is fetched once whatever the rows, and the expert's row
 # tiles are a loop whose trip count is data: an expert nobody chose costs
-# one empty step and, through ``blk``, no fetch. Rows are sorted by
+# its empty steps and, through the index map (``_held_w_index``), no
+# fetch at any number of column blocks. Rows are sorted by
 # expert, each expert's first row on a ``tile_m`` boundary
 # (``sort_rows_by_held_expert``); weights enter as the model's STACKS
 # ``[L, E, K, N]`` with the layer as a scalar, so no layer is sliced out
@@ -338,6 +339,20 @@ def _held_gmm_kernel(layer_ref, blk_ref, first_ref, tiles_ref, lhs_hbm,
     jax.lax.fori_loop(0, tiles_ref[e], tile, 0)
 
 
+def _held_w_index(e, j, layer, blk, first, tiles, *, last_j: int):
+    """The weight block of grid step ``(e, j)``. An expert nobody chose
+    names the block of the step before it in BOTH coordinates, so the
+    pipeline fetches nothing for it: the last chosen expert (``blk``)
+    at its LAST column block."""
+    del first
+    return layer[0], blk[e], 0, jnp.where(tiles[e] > 0, j, last_j)
+
+
+# what the compiler keeps beside the call's own blocks (its internal
+# scratch for the matmul's operands and result)
+HELD_VMEM_SLACK = 16 << 20
+
+
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_n",
                                              "interpret"))
 def _held_gmm_call(lhs, rhs, layer, blk, first, tiles, tile_m, tile_n,
@@ -348,10 +363,16 @@ def _held_gmm_call(lhs, rhs, layer, blk, first, tiles, tile_m, tile_n,
     M, K = lhs.shape
     _, E, K2, N = rhs[0].shape
     assert K == K2 and M % tile_m == 0 and N % tile_n == 0
-    w_spec = pl.BlockSpec((None, None, K, tile_n),
-                          lambda e, j, layer, blk, *_: (layer[0], blk[e], 0, j))
+    w_spec = pl.BlockSpec(
+        (None, None, K, tile_n),
+        functools.partial(_held_w_index, last_j=N // tile_n - 1))
     kernel = functools.partial(_held_gmm_kernel, tile_m=tile_m,
                                tile_n=tile_n, gated=len(rhs) == 2)
+    item = lhs.dtype.itemsize
+    # two buffers a stack's block, the row tile and its result; stated,
+    # because a whole expert a step (5.8 MB a stack at 2048 x 1408)
+    # passes the compiler's default scope of 16 MiB
+    vmem = (2 * len(rhs) * K * tile_n + tile_m * (K + tile_n)) * item
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -364,22 +385,29 @@ def _held_gmm_call(lhs, rhs, layer, blk, first, tiles, tile_m, tile_n,
                             pltpu.VMEM((tile_m, tile_n), lhs.dtype),
                             pltpu.SemaphoreType.DMA((2,))]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem + HELD_VMEM_SLACK),
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
         interpret=interpret,
         name="held_experts_matmul",
     )(layer, blk, first, tiles, lhs, *rhs)
 
 
-def held_tile_n(K: int, N: int, itemsize: int = 2,
+def held_tile_n(K: int, N: int, itemsize: int = 2, whole: int = 8 << 20,
                 budget: int = 4 << 20) -> int:
-    """Columns a weight block: the widest power-of-two divisor of ``N``
-    whose ``K x tile_n`` block stays under ``budget`` bytes (the
-    pipeline holds two of each stack's)."""
-    tn = N
-    while tn > 128 and (K * tn * itemsize > budget or N % tn):
-        tn //= 2
-    return tn if N % tn == 0 else N
+    """Columns a weight block: ``N`` or a multiple of 128 lanes that
+    divides it (Mosaic refuses any other block). A WHOLE expert a step
+    where ``K x N`` stays under ``whole`` bytes (two stacks, two buffers
+    each: a quarter of the chip's VMEM), else the widest block under
+    ``budget``. From the chip (``tools/kernel_bench.py --held-sweep``,
+    PERF.md §6, PR 43): at 2048 x 1408 every legal block reads within
+    1 % of the others, at 1536 x 2048 the whole expert is 2.3 % ahead
+    of two halves."""
+    if K * N * itemsize <= whole:
+        return N
+    legal = [tn for tn in range(128, N, 128) if N % tn == 0]
+    under = [tn for tn in legal if K * tn * itemsize <= budget]
+    return under[-1] if under else (legal[0] if legal else N)
 
 
 def sort_rows_by_held_expert(local_ids, num_held: int, tile_m: int):
@@ -433,9 +461,12 @@ def held_experts_swiglu(x, local_ids, weights, w_gate, w_up, w_down, *,
         jnp.arange(N * k, dtype=jnp.int32) // k, mode="drop")
     xs = x[row_token]
     # an expert nobody chose fetches nothing: its steps name the block
-    # of the last expert before it that somebody chose
+    # of the last expert before it that somebody chose (the FIRST one
+    # chosen, where none comes before it)
     e_idx = jnp.arange(E, dtype=jnp.int32)
-    blk = jax.lax.cummax(jnp.where(counts > 0, e_idx, 0))
+    chosen = counts > 0
+    blk = jax.lax.cummax(jnp.where(chosen, e_idx, -1))
+    blk = jnp.where(blk < 0, jnp.argmax(chosen).astype(jnp.int32), blk)
     interp = not _on_tpu()
     item = x.dtype.itemsize
     h = _held_gmm_call(xs, (w_gate, w_up), layer, blk, first, tiles,

@@ -123,8 +123,11 @@ class ServingMetrics:
     ``top_k`` x the rows routed, over the expert layers), and
     moe_experts_touched — held experts that took at least one row, a
     launch a layer: their weights are what the expert layer had to
-    read; the four come back from the tick program beside its tokens
-    and are added when the tick completes;
+    read; moe_experts_held — the experts those launches held (``E`` a
+    launch a layer, for a model that serves every expert: touched /
+    held is the share of the expert bytes a window read); they come
+    back from the tick program beside its tokens and are added when
+    the tick completes;
     tick_live_slots, kv_pages_walked, kv_pages_table — over the ticks'
     attention launches, the slots that had a query row, the cache
     pages those slots held, and slots x pages_per_slot: walked / table
@@ -195,7 +198,7 @@ class ServingMetrics:
                 "tick_rows", "tick_rows_real", "kv_tokens_attended",
                 "attn_score_pairs", "moe_pairs_held", "moe_pairs_zero",
                 "moe_pairs_absent", "moe_experts_touched",
-                "tick_live_slots", "kv_pages_walked", "kv_pages_table",
+                "moe_experts_held", "tick_live_slots", "kv_pages_walked", "kv_pages_table",
                 "kv_page_copies", "slot_state_bytes_moved", "prefix_bypassed_stateful",
                 "ticks_ahead",
                 "inflight_drains", "overrun_slot_ticks")

@@ -332,7 +332,7 @@ _TWO_LAYERS = {
                      layer_types=["conv", "full_attention"]),
 }
 _CELL_PROGRAMS = [("chat", "tick"), ("batch", "tick"), ("generate", "tick"),
-                  ("chat", "block"),
+                  ("chat", "block"), ("batch", "block"),
                   # twice the cell's chunk (chat 256 rows a slot, generate
                   # 128): the kernel's step then holds its heads' flash
                   # state and blocks at a narrower tile, or fewer heads
@@ -380,11 +380,17 @@ def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
     successor as a result, compile for the described chip at every
     serving cell's geometry; the successor is one more ``s32[S]`` result
     in front of the donated cache, and a decode row's token is gathered
-    from it in the program (no host upload of the slots' tokens)."""
+    from it in the program (no host upload of the slots' tokens). A
+    family with routed experts hands its counts back in front of the
+    successor, runs them through the held-experts grouped matmul, and
+    copies no layer's experts in front of it."""
+    from paddle_tpu.ops.pallas import grouped_matmul as G
     from paddle_tpu.ops.pallas import ragged_paged_attention as R
     from paddle_tpu.serving import engine as E
     monkeypatch.setattr(R, "_on_tpu", lambda: True)
+    monkeypatch.setattr(G, "_on_tpu", lambda: True)
     mod, cfg, S, pps, chunk, params, cache = _cell_program_args(traffic)
+    counters = getattr(mod, "TICK_COUNTERS", ())
     one = SingleDeviceSharding(topo.devices[0])
 
     def on_chip(tree):
@@ -414,6 +420,7 @@ def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
             *on_chip((params, i32((S,)), i32((S,)), i32((S, pps)), cache)),
             num_steps=1, sampling=on_chip(samp))
         results = 2             # toks, cur_tok'
+    results += bool(counters)   # the family's counts, before cur_tok'
     compiled = lowered.compile()
     E._JIT_CACHE.clear()
     text = compiled.as_text()
@@ -423,6 +430,14 @@ def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
     assert len(outs) == results + len(leaves)
     nxt = outs[results - 1]
     assert (nxt.shape, nxt.dtype) == ((S,), jnp.int32)
+    if counters:
+        counts = outs[results - 2]
+        assert (counts.shape, counts.dtype) == ((len(counters),), jnp.int32)
+        assert "held_experts_matmul" in text
+        ex = params["layers"]["experts"]
+        one_layer = sum(math.prod(a.shape[1:]) * a.dtype.itemsize
+                        for a in jax.tree.leaves(ex))
+        assert compiled.memory_analysis().temp_size_in_bytes < one_layer // 4
     assert [(o.shape, o.dtype) for o in outs[results:]] == [
         (a.shape, a.dtype) for a in leaves]
     # the whole cache is donated: the pools are held once
@@ -585,6 +600,43 @@ def test_held_experts_matmul_at_the_cell_s_geometry(chip, monkeypatch):
                 sds((), jnp.int32), sds((N,), jnp.bool_))
     assert text.compiled.count("held_experts_matmul") >= 2
     assert text.memory.temp_size_in_bytes < E * 3 * D * F * 2 // 4
+
+
+@pytest.mark.parametrize("traffic,kind", [
+    ("batch", "decode"), ("batch", "span"), ("generate", "decode"),
+    ("generate", "span")], ids=lambda v: v)
+def test_every_expert_held_at_the_cells_geometry(chip, monkeypatch, traffic,
+                                                 kind):
+    """An older family's expert block as a tick launches it, at the
+    share ``(0, E)``: the batch cell's 16 and 272 rows over 60 experts
+    of 2048 x 1408 (a column block of 704 was refused: 1408 = 11 x 128)
+    and, for the day the generate cell's ticks take it (PERF.md §6, PR
+    43: its roofline reader has to count touched experts first), its 64
+    and 128 rows over 64 of 2048 x 1536, read
+    from the model's stacks at a layer index. Both grouped matmuls
+    compile with a whole expert a step (their VMEM stated), and no
+    layer's experts (1.04 / 1.21 GB) are copied in front of them."""
+    from paddle_tpu.incubate.moe.functional import moe_ffn_share
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+    from tools.kernel_bench import moe_cells
+    monkeypatch.setattr(G, "_on_tpu", lambda: True)
+    c = moe_cells()[traffic]
+    N = c["slots"] + (c["span"] if kind == "span" else 0)
+    D, F, n_e, L = c["hidden"], c["width"], c["held"], c["layers"]
+    assert G.held_tile_n(D, F) % 128 == 0 and G.held_tile_n(F, D) % 128 == 0
+
+    def fn(x, router, wg, wu, wd, layer, mask):
+        return moe_ffn_share(
+            x, router, None, {"w_gate": wg, "w_up": wu, "w_down": wd},
+            held=(0, n_e), num_routed=n_e, top_k=c["top_k"], layer=layer,
+            row_mask=mask, score_fn="sigmoid", normalize_topk=True)
+
+    text = chip(fn, sds((N, D)), sds((D, n_e), jnp.float32),
+                sds((L, n_e, D, F)), sds((L, n_e, D, F)),
+                sds((L, n_e, F, D)), sds((), jnp.int32),
+                sds((N,), jnp.bool_))
+    assert text.compiled.count("held_experts_matmul") >= 2
+    assert text.memory.temp_size_in_bytes < n_e * 3 * D * F * 2 // 8
 
 
 @pytest.mark.parametrize("program", ["tick", "block"])

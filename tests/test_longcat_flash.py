@@ -500,13 +500,16 @@ def test_engine_leaves_the_cold_tier_off_for_a_latent_pool_and_says_so():
 @pytest.mark.parametrize("family", ["llama", "qwen2_moe", "lfm2_moe",
                                     "granite_hybrid"])
 def test_the_older_families_keep_their_two_pools(family):
-    """They declare nothing: ``k_pages`` / ``v_pages``, page axis 2, no
-    counts beside the tokens."""
+    """They declare no pool: ``k_pages`` / ``v_pages``, page axis 2;
+    ``qwen2_moe`` hands three counts back beside the tokens (its routed
+    experts go through the share ``(0, E)``), the others none."""
     import importlib
     from paddle_tpu.serving.engine import _page_pools
     mod = importlib.import_module(f"paddle_tpu.models.{family}")
     assert _page_pools(mod, None) == layer_walk.KV_POOLS
-    assert not getattr(mod, "TICK_COUNTERS", ())
+    assert getattr(mod, "TICK_COUNTERS", ()) == (
+        ("moe_pairs_held", "moe_experts_touched", "moe_experts_held")
+        if family == "qwen2_moe" else ())
 
 
 def test_engine_resolves_the_model_by_name_and_by_config():

@@ -34,6 +34,13 @@ ssd_update.py``) ALONE at the geometry of the serving cells with
 state-space layers: decode rows only and with the cell's span, 0 / 25 /
 100 % of the slots live, ``ms`` and the GB/s of the live slots' state.
 
+``--held-sweep`` times ONE layer's routed experts through the
+held-experts grouped matmul (``ops/pallas/grouped_matmul.py:
+held_experts_swiglu``) ALONE at the geometry of the serving cells with
+routed experts (``moe_cells()``), by share of the held experts that no
+row chose, ``--tile-ms=`` and ``--tile-ns=<gate/up>x<down>``, and the
+capacity einsum at C = N over the same rows beside it.
+
 ``--block-sweep`` (r23) is the flywheel's write side for the other
 swept kernels: per geometry it times every candidate block shape for
 ``fused_rms_norm`` (row tile), the conv-epilogue matmul (tm/tn/tk),
@@ -287,6 +294,37 @@ def mla_cells():
                                    model["qk_rope_head_dim"]),
         dv=model["kv_lora_rank"], layers=2 * model["num_layers"])
         for w, model, geo in _serving_cells() if "kv_lora_rank" in model}
+
+
+def moe_cells():
+    """The routed-expert layer of every serving cell whose configuration
+    has one, keyed by the cell's traffic name, READ from the files the
+    cell runs from: the tick's rows (``slots``, and ``span`` more on a
+    tick that carries a chunk), ``hidden`` x ``width`` an expert,
+    ``held`` experts on this chip of ``routed`` router outputs, ``top_k``
+    choices a row, ``layers`` such layers. The one copy of these
+    numbers: ``held_sweep`` and tests/test_chip_compile.py read it."""
+    cells = {}
+    for w, model, geo in _serving_cells():
+        if "n_routed_experts" in model:             # a share of the experts
+            held = model["n_routed_experts"]
+            moe = dict(held=held, top_k=model["moe_topk"],
+                       routed=(model.get("router_experts", held)
+                               + model.get("zero_expert_num", 0)),
+                       width=model["expert_ffn_hidden_size"],
+                       layers=model["num_layers"])
+        elif model.get("num_experts"):
+            held = model["num_experts"]
+            moe = dict(held=held, routed=held,
+                       top_k=model["num_experts_per_tok"],
+                       width=model["moe_intermediate_size"],
+                       layers=(model["num_hidden_layers"]
+                               - model.get("num_dense_layers", 0)))
+        else:
+            continue
+        cells[w["traffic"]] = dict(slots=geo["slots"], span=geo["span"],
+                                   hidden=model["hidden_size"], **moe)
+    return cells
 
 
 # off the chip: the same shape of sweep at a size interpret mode can run
@@ -675,6 +713,148 @@ def ssd_sweep(out=None, iters=5, cells=None, label=""):
     return _emit(results, out)
 
 
+def _busy_ms(trace_dir):
+    """The traced device's busy time in ms: the union of its operations'
+    intervals (the line holds a loop AND the operations inside it)."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    evs = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:TPU:0"):
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    evs += [(ev.start_ns, ev.start_ns + ev.duration_ns)
+                            for ev in line.events]
+    busy, end = 0, 0
+    for a, b in sorted(evs):
+        busy += max(0, b - max(a, end))
+        end = max(end, b)
+    return busy / 1e6
+
+
+def held_sweep(out=None, iters=5, cells=None, label="", tile_ms=(None,),
+               tile_ns=(None,)):
+    """The routed experts of ONE layer (``ops/pallas/grouped_matmul.py:
+    held_experts_swiglu`` over the model's stacks at a layer index)
+    ALONE at the geometry of every serving cell with routed experts
+    (``moe_cells()``): one row for each (cell, decode rows only or with
+    the cell's span, share of the held experts that NO row chose,
+    ``tile_m``, the two matmuls' ``tile_n``). ``ms`` is the device's
+    busy time a call (sort, gather, both grouped matmuls, combine),
+    ``kernel_ms`` the two matmuls', ``gbps`` the touched experts' bytes
+    over ``ms``. Beside them ``impl: einsum``: the capacity einsum at C
+    = N over the same rows (``incubate/moe/functional.py:
+    moe_expert_compute``), which reads every expert. ``--tile-ms=16,32``
+    and ``--tile-ns=auto,1408x2048`` (gate/up x down) pick the blocks.
+    Off the chip: a tiny size in interpret mode, the wall clock
+    (``timing_honest: false``)."""
+    import tempfile
+    from paddle_tpu.incubate.moe.functional import moe_expert_compute
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+    on_tpu = jax.default_backend() == "tpu"
+    table = moe_cells() if on_tpu else {
+        "tiny": dict(slots=4, span=8, hidden=128, width=256, held=6,
+                     routed=6, top_k=2, layers=2)}
+    auto_tile_n = G.held_tile_n
+    results = []
+    for cell in cells or table:
+        c = table[cell]
+        D, F, E, k = c["hidden"], c["width"], c["held"], c["top_k"]
+        rng = np.random.RandomState(0)
+        draw = jax.jit(lambda key, shape: (jax.random.normal(
+            key, shape, jnp.float32) * 0.02).astype(jnp.bfloat16),
+            static_argnums=1)
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        wg, wu = draw(keys[0], (2, E, D, F)), draw(keys[1], (2, E, D, F))
+        wd = draw(keys[2], (2, E, F, D))
+        layer = jnp.asarray(1, jnp.int32)
+        runs = []
+        for kind, rows in (("decode", c["slots"]),
+                           ("span", c["slots"] + c["span"])):
+            x = jnp.asarray(rng.randn(rows, D), jnp.bfloat16)
+            # the pairs this chip holds: every pair where it holds every
+            # expert, the deployment's share of them otherwise
+            pairs = rows * k * E // c["routed"]
+            for idle in (0.0, 1 / 3, 0.5):
+                n_idle = int(round(idle * E))
+                live = np.sort(rng.permutation(E)[:E - n_idle])
+                ids = np.full((rows * k,), E, np.int32)
+                # round-robin: every live expert takes a row where the
+                # pairs reach that far
+                ids[rng.permutation(rows * k)[:pairs]] = live[
+                    np.arange(pairs) % len(live)]
+                touched = len(set(ids.tolist()) - {E})
+                ids = jnp.asarray(ids.reshape(rows, k))
+                wts = jnp.asarray(rng.rand(rows, k), jnp.float32)
+                for tm in tile_ms:
+                    for tn in tile_ns:
+                        def fn(x, ids, wts, wg, wu, wd, layer, tm=tm):
+                            return G.held_experts_swiglu(
+                                x, ids, wts, wg, wu, wd, layer=layer,
+                                **({} if tm is None else {"tile_m": tm}))[0]
+
+                        blocks = (None if tn is None else
+                                  {(D, F): tn[0], (F, D): tn[1]})
+                        row = {"bench": "held_sweep", "label": label,
+                               "cell": cell, "tick": kind, "rows": rows,
+                               "impl": "held", "experts": E,
+                               "experts_idle": n_idle,
+                               "experts_touched": touched, "pairs": pairs,
+                               "tile_m": tm or 16,
+                               "tile_n": [(blocks or {}).get(s) or
+                                          auto_tile_n(*s)
+                                          for s in ((D, F), (F, D))],
+                               "bytes": touched * 3 * D * F * 2,
+                               "timing_honest": on_tpu}
+                        runs.append((row, jax.jit(fn), blocks,
+                                     (x, ids, wts, wg, wu, wd, layer)))
+            # the capacity einsum at C = N over the same rows
+            if E == c["routed"]:
+                disp = jnp.asarray(rng.rand(rows, E, rows) < k / E / rows,
+                                   jnp.bfloat16)
+
+                def ein(x, disp, wg, wu, wd, layer):
+                    at = lambda a: jax.lax.dynamic_index_in_dim(
+                        a, layer, 0, keepdims=False)
+                    return moe_expert_compute(x, disp, disp, at(wg), at(wu),
+                                              at(wd))
+
+                runs.append(({"bench": "held_sweep", "label": label,
+                              "cell": cell, "tick": kind, "rows": rows,
+                              "impl": "einsum", "experts": E,
+                              "experts_touched": E,
+                              "bytes": E * 3 * D * F * 2,
+                              "timing_honest": on_tpu},
+                             jax.jit(ein), None,
+                             (x, disp, wg, wu, wd, layer)))
+        for row, fn, blocks, args in runs:
+            G.held_tile_n = (auto_tile_n if blocks is None else
+                             lambda K, N, *a, b=blocks: b[(K, N)])
+            try:
+                jax.block_until_ready(fn(*args))    # compile outside
+                if on_tpu:
+                    tdir = tempfile.mkdtemp(prefix=f"kb_held_{cell}_")
+                    with jax.profiler.trace(tdir):
+                        for _ in range(iters):
+                            y = fn(*args)
+                        jax.block_until_ready(y)
+                    ms = _busy_ms(tdir) / iters
+                    kern = _kernel_ms(tdir, prefix="held_experts_matmul")
+                    row = dict(row, kernel_ms=round(sum(kern) / iters, 5))
+                else:
+                    ms = _walltime(fn, args, n=iters)
+                results.append(dict(
+                    row, ms=round(ms, 5),
+                    gbps=round(row["bytes"] / ms / 1e6, 1)))
+            except Exception as e:   # a block the compiler refuses is a row
+                results.append(dict(row, error=f"{type(e).__name__}: "
+                                    f"{str(e)[:300]}"))
+            finally:
+                G.held_tile_n = auto_tile_n
+    return _emit(results, out)
+
+
 def block_sweep(out=None, iters=3):
     """Block-shape sweeps for the swept Pallas entry points (module
     docstring): time every candidate, record the winner per geometry
@@ -834,7 +1014,7 @@ if __name__ == "__main__":
     from paddle_tpu.compile_cache import enable_compile_cache
     enable_compile_cache()
     if {"--block-sweep", "--ragged-sweep", "--ssd-sweep",
-            "--mla-sweep"} & set(sys.argv):
+            "--mla-sweep", "--held-sweep"} & set(sys.argv):
         opt = {a.split("=", 1)[0]: a.split("=", 1)[1] for a in sys.argv
                if a.startswith("--") and "=" in a}
         path = opt.get("--out")
@@ -850,6 +1030,16 @@ if __name__ == "__main__":
             ssd_sweep(out=path, label=opt.get("--label", ""),
                       cells=(opt["--cells"].split(",") if "--cells" in opt
                              else None))
+        elif "--held-sweep" in sys.argv:
+            held_sweep(
+                out=path, label=opt.get("--label", ""),
+                cells=(opt["--cells"].split(",") if "--cells" in opt
+                       else None),
+                tile_ms=tuple(None if t == "auto" else int(t) for t in
+                              opt.get("--tile-ms", "auto").split(",")),
+                tile_ns=tuple(
+                    None if t == "auto" else tuple(map(int, t.split("x")))
+                    for t in opt.get("--tile-ns", "auto").split(",")))
         else:
             ragged_sweep(
                 out=path, label=opt.get("--label", ""),
