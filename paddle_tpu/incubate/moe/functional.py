@@ -264,6 +264,33 @@ def moe_ffn(
     return y.reshape(orig_shape), aux.astype(jnp.float32)
 
 
+# rows an expert (the call's rows x top_k over the router's outputs)
+# from which the grouped matmul walks row tiles of ROW_TILE_M rows: a
+# full MXU pass of rows an expert, where a pipelined tile pays and the
+# expert walk's one synchronous copy a 16-row tile no longer does
+ROW_TILE_WALK_FROM = 128
+ROW_TILE_M = 128
+# pairs under which the sorted buffer is sized for the worst case (every
+# pair held): at 2048 columns 16 384 rows are 64 MiB, and a bound would
+# buy nothing
+HELD_PAIRS_UNBOUNDED_TO = 16384
+
+
+def held_pairs_bound(rows: int, top_k: int, held: int, outputs: int) -> int:
+    """The static bound on the (row, choice) pairs that land on the
+    ``held`` experts of a router with ``outputs`` outputs, which sizes
+    the row-tile walk's sorted buffer: TWICE the expectation under a
+    balanced router (``rows x top_k x held / outputs``), never under
+    ``HELD_PAIRS_UNBOUNDED_TO`` and never over the worst case (every
+    pair: ``rows x top_k``). A step whose pairs exceed it is computed
+    exactly all the same (``grouped_experts_swiglu``: further passes
+    over the same buffer), at the cost of sorting again and reading the
+    weights once a pass."""
+    worst = rows * top_k
+    return min(worst, max(2 * worst * held // outputs,
+                          HELD_PAIRS_UNBOUNDED_TO))
+
+
 def moe_ffn_share(
     x: jax.Array,
     router_w: jax.Array,
@@ -281,13 +308,25 @@ def moe_ffn_share(
     normalize_topk: bool = False,
     impl: str = "auto",
     tile_m: int = 16,
+    row_stats: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """The SERVING expert layer, dropless: the experts a chip HOLDS of
-    an expert-parallel deployment's (``held = (0, num_routed)``: all of
-    them, a chip that serves the whole layer). It routes over every
+    """The expert layer of a chip that HOLDS a share of an expert-
+    parallel deployment's experts (``held = (0, num_routed)``: all of
+    them, a chip that has the whole layer), dropless, for a serving
+    tick and a training step alike. It routes over every
     router output, computes the part of the experts it holds and the
     identity experts' part, and reads the weights of the held experts
     that took a row and of no other.
+
+    DIFFERENTIABLE in ``x``, ``router_w`` and the experts' stacks: the
+    router's gradient comes through the combine weights (the scores of
+    the chosen, renormalised where asked), none through the choice and
+    none to ``select_bias``. On the TPU the grouped matmul goes by what
+    the call observes: a handful of rows an expert
+    (a serving tick) walks the EXPERTS, forward only; from
+    ``ROW_TILE_WALK_FROM`` rows an expert on (a training step) it walks
+    ROW TILES, which has a backward, over a sorted buffer sized by
+    ``held_pairs_bound``, exact past it too (further passes).
 
     ``x [N, D]``; ``router_w [D, num_routed + zero_experts]`` (float32
     router; ``score_fn``, ``select_bias`` and ``normalize_topk`` as
@@ -312,7 +351,10 @@ def moe_ffn_share(
 
     Returns ``(y [N, D] in x.dtype, counts [4] int32)``: ``counts =
     [pairs_held, pairs_zero, pairs_absent, experts_touched]``, with
-    ``pairs_held + pairs_zero + pairs_absent = top_k x rows``.
+    ``pairs_held + pairs_zero + pairs_absent = top_k x rows``. With
+    ``row_stats`` two more follow: ``[rows_padded, bound_fallbacks]``,
+    the dead rows of the sorted buffer's live tiles and whether this
+    call took the fall-back (both 0 where no buffer is sorted).
     """
     from ...ops.pallas import grouped_matmul as _gmm
     lo, n = int(held[0]), int(held[1])
@@ -333,14 +375,24 @@ def moe_ffn_share(
     with jax.named_scope("moe.experts"):
         local = jnp.where(here, local, n).astype(jnp.int32)
         w_here = jnp.where(here, w, 0.0)
-        if impl == "pallas" or (impl == "auto" and _gmm._on_tpu()):
+        grouped = impl == "pallas" or (impl == "auto" and _gmm._on_tpu())
+        by_row_tiles = grouped and (
+            N * top_k // (num_routed + zero_experts) >= ROW_TILE_WALK_FROM)
+        stats = None
+        if not grouped or by_row_tiles:
+            ex = experts if layer is None else {
+                k: lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
+                for k, v in experts.items()}
+        if by_row_tiles:
+            y, rows, stats = _gmm.grouped_experts_swiglu(
+                x, local, w_here, ex["w_gate"], ex["w_up"], ex["w_down"],
+                tile_m=ROW_TILE_M, max_pairs=held_pairs_bound(
+                    N, top_k, n, num_routed + zero_experts))
+        elif grouped:
             y, rows = _gmm.held_experts_swiglu(
                 x, local, w_here, experts["w_gate"], experts["w_up"],
                 experts["w_down"], layer=layer, tile_m=tile_m)
         else:
-            ex = experts if layer is None else {
-                k: lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
-                for k, v in experts.items()}
             onehot = jax.nn.one_hot(local, n + 1, dtype=jnp.float32)[..., :n]
             per = jnp.einsum("nk,nke->ne", w_here, onehot)          # [N, n]
             rows = (onehot.sum((0, 1))).astype(jnp.int32)
@@ -360,4 +412,8 @@ def moe_ffn_share(
         counts = jnp.stack([pairs_held, pairs_zero,
                             real - pairs_held - pairs_zero,
                             (rows > 0).sum()]).astype(jnp.int32)
+        if row_stats:
+            counts = jnp.concatenate(
+                [counts, jnp.zeros((2,), jnp.int32) if stats is None
+                 else stats])
     return y, counts

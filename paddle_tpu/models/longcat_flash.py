@@ -92,6 +92,7 @@ from . import llama as _llama
 from .layer_walk import (COUNTS, Group, LayerKind, PagePoolSpec,
                          _layer_params)
 from .llama import _mm, rms_norm
+from .mla import mla_qkv as _mla_qkv, rope_interleaved  # noqa: F401
 
 MLA = "mla"
 POOL = "latent_pages"
@@ -243,47 +244,6 @@ def abstract_params(cfg: LongcatFlashConfig):
 
 
 # ------------------------------------------------------------ the layers ----
-
-def rope_interleaved(x, positions, theta: float):
-    """Rotary embedding on INTERLEAVED pairs ``(x[2i], x[2i+1])`` of the
-    last axis; ``x [..., T, (heads,) R]`` with ``positions`` broadcast
-    over the heads."""
-    R = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, R, 2, dtype=jnp.float32) / R))
-    ang = positions.astype(jnp.float32)[..., None] * inv        # [..., T, R/2]
-    if x.ndim == ang.ndim + 1:                                  # a head axis
-        ang = ang[..., None, :]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], R // 2, 2)
-    x0, x1 = xf[..., 0], xf[..., 1]
-    out = jnp.stack([x0 * cos - x1 * sin, x1 * cos + x0 * sin], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
-def _mla_qkv(lp, h, positions, cfg: LongcatFlashConfig):
-    """The sublayer's projections: ``(q_n [.., T, H, nope], q_r [.., T,
-    H, rope], c_kv [.., T, Rkv], k_r [.., T, rope])``."""
-    H, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
-    a = rms_norm(h, lp["input_norm"], cfg.rms_norm_eps)
-    with jax.named_scope("attn.mla.q"):
-        c_q = rms_norm(_mm(a, lp["wq_a"]), lp["q_a_norm"], cfg.rms_norm_eps)
-        q_n = jnp.einsum("...r,nr->...n", c_q, lp["wq_nope"]).reshape(
-            *h.shape[:-1], H, nope)
-        q_r = jnp.einsum("...r,nr->...n", c_q, lp["wq_rope"]).reshape(
-            *h.shape[:-1], H, cfg.qk_rope_head_dim)
-        if cfg.q_scale != 1.0:
-            q_n = q_n * jnp.asarray(cfg.q_scale, q_n.dtype)
-            q_r = q_r * jnp.asarray(cfg.q_scale, q_r.dtype)
-        q_r = rope_interleaved(q_r, positions, cfg.rope_theta)
-    with jax.named_scope("attn.mla.kv"):
-        c_kv = rms_norm(_mm(a, lp["wkv_a"]), lp["kv_a_norm"],
-                        cfg.rms_norm_eps)
-        if cfg.kv_scale != 1.0:
-            c_kv = c_kv * jnp.asarray(cfg.kv_scale, c_kv.dtype)
-        k_r = rope_interleaved(_mm(a, lp["wk_rope"]), positions,
-                               cfg.rope_theta)
-    return q_n, q_r, c_kv, k_r
-
 
 def _attn_out(lp, h, o):
     with jax.named_scope("attn.out"):

@@ -23,7 +23,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..incubate.moe.functional import moe_ffn, moe_ffn_share
-from ..observability import in_setup_span, setup_span
 from .layer_walk import (COUNTS, EXPERT_COUNTERS, expert_counts,
                          with_tick_counts)
 from .llama import _mm, rms_norm, rope
@@ -252,30 +251,11 @@ def loss_fn(params, batch, cfg: Qwen2MoeConfig, mesh=None):
 def make_train_step(cfg: Qwen2MoeConfig, mesh: Mesh, optimizer=None):
     """Jitted SPMD train step; optimizer state inherits param sharding
     (ZeRO-style, like models/llama.py make_train_step)."""
-    with setup_span("train.setup.build"):   # no frame added: llama.py
-        import optax
-        if optimizer is None:
-            optimizer = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
-
-        @in_setup_span("train.setup.init", ready=True)
-        def init_fn(key):
-            params = init_params(cfg, key)
-            params = shard_params(params, cfg, mesh)
-            opt_state = optimizer.init(params)
-            return {"params": params, "opt": opt_state,
-                    "step": jnp.zeros((), jnp.int32)}
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def step_fn(state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(
-                state["params"], batch, cfg, mesh)
-            updates, opt = optimizer.update(grads, state["opt"],
-                                            state["params"])
-            params = optax.apply_updates(state["params"], updates)
-            return {"params": params, "opt": opt,
-                    "step": state["step"] + 1}, loss
-
-    return step_fn, init_fn
+    from .train_step import make_family_train_step
+    return make_family_train_step(
+        lambda key: shard_params(init_params(cfg, key), cfg, mesh),
+        lambda params, batch: (loss_fn(params, batch, cfg, mesh), None),
+        optimizer)
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,10 @@ anomaly instead of reconstructed after it.
                        or read from the persistent cache, by name
                        (sentinel.py: compile_ledger, compile_totals,
                        ledger_health)
+    step_counters    — what a compiled step counts on the device and
+                       sends out by one unordered host callback (a
+                       train step's expert pairs): the process's
+                       registry (counters.py: emit, step_counters)
     setup_report     — time to ready: the set-up spans of the engine
                        and the trainer on the process tracer, joined
                        to the ledger (setup.py)
@@ -37,6 +41,7 @@ Wired through ``serving.ServingEngine`` (``trace=``, ``flight_ticks=``,
 ``graph_lint --json``'s ``observability`` block. See
 docs/OBSERVABILITY.md.
 """
+from .counters import StepCounters, emit, step_counters  # noqa: F401
 from .flight import FlightRecorder, default_flight_dir  # noqa: F401
 from .sentinel import (COMPILE_EVENT, RECOMPILES_METRIC,  # noqa: F401
                        RecompileSentinel, RecompileWarning,
@@ -49,4 +54,5 @@ __all__ = ["SpanTracer", "Span", "current_span", "process_tracer",
            "FlightRecorder", "default_flight_dir", "RecompileSentinel",
            "RecompileWarning", "COMPILE_EVENT", "RECOMPILES_METRIC",
            "compile_ledger", "compile_totals", "ledger_health", "setup_span",
-           "in_setup_span", "setup_report"]
+           "in_setup_span", "setup_report", "StepCounters", "step_counters",
+           "emit"]

@@ -19,7 +19,9 @@ Elsewhere (the 8-device CPU test mesh) a dense XLA path with identical
 semantics runs instead.
 
 Layout contract: q/k/v are [B, T, H, Dh] (time-major like the reference's
-python API); GQA passes k/v as [B, T, Hkv, Dh] with H % Hkv == 0.
+python API); GQA passes k/v as [B, T, Hkv, Dh] with H % Hkv == 0. ``v``
+may have a head size of its own (``head_dim_v``: latent attention's
+expanded form has q / k at 192 and v at 128); the result has v's.
 """
 from __future__ import annotations
 
@@ -71,10 +73,19 @@ def _splash_kernel(n_heads: int, t_q: int, t_kv: int, causal: bool,
                                   block_sizes=bs)
 
 
+def _block_for(t_q: int, t_kv: int) -> int:
+    """The splash block: 512 (the sweep in the module docstring, 2048
+    tokens), 1024 from 8192 tokens on (forward + backward of 2 x 32
+    heads x 8192 at head sizes 192 / 128 on a v5e: 67.3 ms at 512, 59.3
+    at 1024, out of VMEM at 2048: PERF.md §6, PR 44)."""
+    return min(1024 if min(t_q, t_kv) >= 8192 else 512, t_q, t_kv)
+
+
 def _splash(q, k, v, causal, sm_scale):
-    """[B, T, H, Dh] x [B, S, Hkv, Dh] -> [B, T, H, Dh] via splash."""
+    """[B, T, H, Dh] x [B, S, Hkv, Dh] (v: [B, S, Hkv, Dv]) -> [B, T, H,
+    Dv] via splash."""
     H, T, S = q.shape[2], q.shape[1], k.shape[1]
-    kernel = _splash_kernel(H, T, S, causal, min(512, T, S))
+    kernel = _splash_kernel(H, T, S, causal, _block_for(T, S))
     qt = (q * sm_scale).astype(q.dtype).transpose(0, 2, 1, 3)  # [B,H,T,Dh]
     kt = k.transpose(0, 2, 1, 3)                               # [B,Hkv,S,Dh]
     vt = v.transpose(0, 2, 1, 3)
@@ -84,7 +95,8 @@ def _splash(q, k, v, causal, sm_scale):
 
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
                     impl: str = "auto"):
-    """[B, T, H, Dh] attention; returns [B, T, H, Dh].
+    """[B, T, H, Dh] attention; returns [B, T, H, Dv] (``Dv`` is v's
+    head size, ``Dh`` unless v brings its own).
 
     impl: "auto" (pallas splash on TPU when shapes allow, dense
     otherwise), "pallas" (splash whatever the shapes or backend —
@@ -100,7 +112,10 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(Dh)
 
-    pallas_ok = (_on_tpu() and Dh % 128 == 0 and q.shape[1] % 128 == 0
+    # q / k at a whole number of lane tiles, or at one and a half (192:
+    # the chip's compiler takes it as it is, tests/test_chip_compile.py)
+    pallas_ok = (_on_tpu() and (Dh % 128 == 0 or Dh == 192)
+                 and v.shape[3] % 128 == 0 and q.shape[1] % 128 == 0
                  and k.shape[1] % 128 == 0 and H % Hkv == 0)
     if impl == "pallas" or (impl == "auto" and pallas_ok):
         return _splash(q, k, v, causal, sm_scale)

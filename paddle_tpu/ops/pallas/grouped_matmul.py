@@ -51,35 +51,74 @@ def _fit_tile_n(K: int, tile_m: int, tile_n: int, N: int,
     return tn if N % tn == 0 else N
 
 
-def _gmm_kernel(tile_expert_ref, lhs_ref, rhs_ref, out_ref):
+# what the compiler keeps beside a call's own blocks, and the most a
+# call asks for (a v5e core has 128 MiB of VMEM, the compiler's default
+# scope is 16)
+GMM_VMEM_SLACK = 8 << 20
+GMM_VMEM_MOST = 96 << 20
+
+
+def _vmem_limit(*block_bytes: int) -> int:
+    """Two buffers a block plus slack, stated: a whole expert's weights
+    a step pass the default scope."""
+    return min(2 * sum(block_bytes) + GMM_VMEM_SLACK, GMM_VMEM_MOST)
+
+
+def _live_row_tile(i, live):
+    """The row tile grid step ``i`` names: its own while it is live,
+    the last live one after (so a dead step fetches nothing)."""
+    return jnp.minimum(i, jnp.maximum(live[0] - 1, 0))
+
+
+def _gmm_kernel(tile_expert_ref, live_ref, lhs_ref, rhs_ref, out_ref):
     del tile_expert_ref  # consumed by the index maps
-    out_ref[...] = jnp.dot(
-        lhs_ref[...], rhs_ref[0],
-        preferred_element_type=jnp.float32).astype(out_ref.dtype)
+    i = pl.program_id(0)
+
+    @pl.when(i < live_ref[0])
+    def _():
+        out_ref[...] = jnp.dot(
+            lhs_ref[...], rhs_ref[0],
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+    @pl.when(i >= live_ref[0])
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_n",
                                              "interpret"))
-def _gmm_call(lhs, rhs, tile_expert, tile_m, tile_n, interpret):
+def _gmm_call(lhs, rhs, tile_expert, live, tile_m, tile_n, interpret):
+    """``live [1]`` i32: row tiles ``>= live[0]`` hold no row (the
+    static buffer's tail): they cost no matmul and no fetch, and their
+    rows of the result are zeros."""
     M, K = lhs.shape
     E, K2, N = rhs.shape
     assert K == K2 and M % tile_m == 0 and N % tile_n == 0
     grid = (M // tile_m, N // tile_n)
+    item = lhs.dtype.itemsize
     return pl.pallas_call(
         _gmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((tile_m, K), lambda i, j, te: (i, 0)),
-                pl.BlockSpec((1, K, tile_n), lambda i, j, te: (te[i], 0, j)),
+                pl.BlockSpec((tile_m, K),
+                             lambda i, j, te, lv: (_live_row_tile(i, lv), 0)),
+                pl.BlockSpec((1, K, tile_n),
+                             lambda i, j, te, lv: (te[i], 0, j)),
             ],
             out_specs=pl.BlockSpec((tile_m, tile_n),
-                                   lambda i, j, te: (i, j)),
+                                   lambda i, j, te, lv: (i, j)),
         ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(tile_m * K * item,
+                                         K * tile_n * item,
+                                         tile_m * tile_n * item)),
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
         interpret=interpret,
-    )(tile_expert, lhs, rhs)
+        name="grouped_matmul",
+    )(tile_expert, live, lhs, rhs)
 
 
 # ---------------------------------------------------------------------------
@@ -89,45 +128,55 @@ def _gmm_call(lhs, rhs, tile_expert, tile_m, tile_n, interpret):
 # output block is visited in one contiguous run)
 # ---------------------------------------------------------------------------
 
-def _tgmm_kernel(tile_expert_ref, lhs_ref, g_ref, out_ref):
-    j = pl.program_id(0)  # n tile (outer)
+def _tgmm_kernel(tile_expert_ref, live_ref, lhs_ref, g_ref, out_ref):
     i = pl.program_id(1)  # m tile (inner, sequential over experts)
     e = tile_expert_ref[i]
     first_of_expert = jnp.logical_or(
         i == 0, tile_expert_ref[jnp.maximum(i - 1, 0)] != e)
-    del j
 
     @pl.when(first_of_expert)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += jnp.dot(
-        lhs_ref[...].T, g_ref[...],
-        preferred_element_type=jnp.float32)[None]
+    @pl.when(i < live_ref[0])
+    def _():
+        out_ref[...] += jax.lax.dot_general(
+            lhs_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("num_experts", "tile_m",
                                              "tile_n", "interpret"))
-def _tgmm_call(lhs, g, tile_expert, num_experts, tile_m, tile_n, interpret):
+def _tgmm_call(lhs, g, tile_expert, live, num_experts, tile_m, tile_n,
+               interpret):
     M, K = lhs.shape
     M2, N = g.shape
     assert M == M2 and M % tile_m == 0 and N % tile_n == 0
     grid = (N // tile_n, M // tile_m)
+    item = lhs.dtype.itemsize
     out = pl.pallas_call(
         _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((tile_m, K), lambda j, i, te: (i, 0)),
-                pl.BlockSpec((tile_m, tile_n), lambda j, i, te: (i, j)),
+                pl.BlockSpec((tile_m, K),
+                             lambda j, i, te, lv: (_live_row_tile(i, lv), 0)),
+                pl.BlockSpec((tile_m, tile_n),
+                             lambda j, i, te, lv: (_live_row_tile(i, lv), j)),
             ],
             out_specs=pl.BlockSpec((1, K, tile_n),
-                                   lambda j, i, te: (te[i], 0, j)),
+                                   lambda j, i, te, lv: (te[i], 0, j)),
         ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(tile_m * K * item,
+                                         tile_m * tile_n * item,
+                                         K * tile_n * 4)),
         out_shape=jax.ShapeDtypeStruct((num_experts, K, N), jnp.float32),
         interpret=interpret,
-    )(tile_expert, lhs, g)
+        name="grouped_matmul_dw",
+    )(tile_expert, live, lhs, g)
     # experts owning no row tile never have their output block written —
     # zero them instead of returning uninitialised memory
     present = jnp.zeros((num_experts,), jnp.bool_).at[tile_expert].set(True)
@@ -138,8 +187,8 @@ def _tgmm_call(lhs, g, tile_expert, num_experts, tile_m, tile_n, interpret):
 # public op with custom VJP
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def gmm(lhs, rhs, tile_expert, tile_m: int = 128, tile_n: int = 128):
+def gmm(lhs, rhs, tile_expert, tile_m: int = 128, tile_n: int = 128,
+        live_tiles=None):
     """Grouped matmul: rows are token tiles, each tile owned by one expert.
 
     lhs: ``[M, K]`` token-sorted activations, M % tile_m == 0; every row
@@ -154,13 +203,25 @@ def gmm(lhs, rhs, tile_expert, tile_m: int = 128, tile_n: int = 128):
       [0, 1, 0]) silently drops earlier contributions.
       ``sort_and_pad_by_expert`` always produces a sorted layout; the
       precondition is checked here when the value is concrete.
+    live_tiles: i32 scalar, the row tiles that hold a row (the first
+      ``live_tiles`` of them; default: all). The rest of a static
+      worst-case buffer costs no matmul, and its rows of the result and
+      of ``dlhs`` are zeros.
 
     Returns ``[M, N]`` in lhs dtype.
     """
     _check_sorted_tiles(tile_expert)
+    if live_tiles is None:
+        live_tiles = lhs.shape[0] // tile_m
+    live = jnp.asarray(live_tiles, jnp.int32).reshape(1)
+    return _gmm(lhs, rhs, tile_expert, live, tile_m, tile_n)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _gmm(lhs, rhs, tile_expert, live, tile_m, tile_n):
     tn = _fit_tile_n(lhs.shape[1], tile_m, tile_n, rhs.shape[2],
                      lhs.dtype.itemsize)
-    return _gmm_call(lhs, rhs, tile_expert, tile_m, tn,
+    return _gmm_call(lhs, rhs, tile_expert, live, tile_m, tn,
                      interpret=not _on_tpu())
 
 
@@ -178,33 +239,29 @@ def _check_sorted_tiles(tile_expert):
             "for correct weight gradients; use sort_and_pad_by_expert")
 
 
-def _gmm_fwd(lhs, rhs, tile_expert, tile_m, tile_n):
-    _check_sorted_tiles(tile_expert)
-    tn = _fit_tile_n(lhs.shape[1], tile_m, tile_n, rhs.shape[2],
-                     lhs.dtype.itemsize)
-    out = _gmm_call(lhs, rhs, tile_expert, tile_m, tn,
-                    interpret=not _on_tpu())
-    return out, (lhs, rhs, tile_expert)
+def _gmm_fwd(lhs, rhs, tile_expert, live, tile_m, tile_n):
+    return (_gmm(lhs, rhs, tile_expert, live, tile_m, tile_n),
+            (lhs, rhs, tile_expert, live))
 
 
 def _gmm_bwd(tile_m, tile_n, res, g):
-    lhs, rhs, tile_expert = res
+    lhs, rhs, tile_expert, live = res
     interp = not _on_tpu()
     g = g.astype(lhs.dtype)
     # dlhs = g @ rhs[e]^T — same kernel with swapped weight dims (the
     # output dim is K here, re-fitted to VMEM by _fit_tile_n)
     tn_k = _fit_tile_n(rhs.shape[2], tile_m, tile_n, rhs.shape[1],
                        g.dtype.itemsize)
-    dlhs = _gmm_call(g, jnp.swapaxes(rhs, 1, 2), tile_expert, tile_m,
+    dlhs = _gmm_call(g, jnp.swapaxes(rhs, 1, 2), tile_expert, live, tile_m,
                      tn_k, interpret=interp)
     tn_d = _fit_tile_n(rhs.shape[1], tile_m, tile_n, rhs.shape[2],
                        g.dtype.itemsize)
-    drhs = _tgmm_call(lhs, g, tile_expert, rhs.shape[0], tile_m, tn_d,
+    drhs = _tgmm_call(lhs, g, tile_expert, live, rhs.shape[0], tile_m, tn_d,
                       interpret=interp).astype(rhs.dtype)
-    return dlhs, drhs, None
+    return dlhs, drhs, None, None
 
 
-gmm.defvjp(_gmm_fwd, _gmm_bwd)
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -410,17 +467,20 @@ def held_tile_n(K: int, N: int, itemsize: int = 2, whole: int = 8 << 20,
     return under[-1] if under else (legal[0] if legal else N)
 
 
-def sort_rows_by_held_expert(local_ids, num_held: int, tile_m: int):
+def sort_rows_by_held_expert(local_ids, num_held: int, tile_m: int,
+                             max_pairs: int = None):
     """``local_ids [A]``: each assignment's expert among the ``num_held``
     held ones, or ``num_held`` for an assignment that lands elsewhere.
     Returns ``(dest [A], first [E], tiles [E], counts [E], m_pad)``:
     ``dest`` the row of an assignment in the sorted buffer (``m_pad``
     for one that lands elsewhere: past the buffer), each expert's rows
     from ``first[e] * tile_m`` on, in ``tiles[e]`` tiles. A counting
-    sort, as ``sort_and_pad_by_expert``."""
+    sort, as ``sort_and_pad_by_expert``. The buffer holds every
+    assignment (``A`` of them could be held) unless the caller KNOWS
+    that at most ``max_pairs`` are."""
     A = local_ids.shape[0]
     E = int(num_held)
-    m_pad = (-(-A // tile_m) + E) * tile_m
+    m_pad = (-(-min(A, max_pairs or A) // tile_m) + E) * tile_m
     onehot = local_ids[:, None] == jnp.arange(E, dtype=local_ids.dtype)
     incl = jnp.cumsum(onehot.astype(jnp.int32), axis=0)            # [A, E]
     counts = incl[-1]
@@ -485,6 +545,150 @@ def held_experts_swiglu(x, local_ids, weights, w_gate, w_up, w_down, *,
 
 
 # ---------------------------------------------------------------------------
+# the same share over MANY rows an expert, differentiable
+# ---------------------------------------------------------------------------
+# ``held_experts_swiglu`` walks EXPERTS and loops a data-dependent number
+# of 16-row tiles inside a step, one synchronous copy a tile: right for a
+# serving tick's handful of rows an expert, and forward only. With
+# hundreds of rows an expert (a training step) the ROW-TILE walk ``gmm``
+# is the one to use: its tiles are pipelined, an expert's weights stay in
+# VMEM across its consecutive tiles (a whole expert a block), it has a
+# backward (``dX`` the same kernel over the transposed weights, ``dW``
+# ``_tgmm_call``), and the static buffer's dead tail costs neither a
+# matmul nor a fetch (``live_tiles``).
+
+def _share_rows(x, local_ids, weights, w_gate, w_up, w_down, *, tile_m,
+                max_pairs):
+    """One pass over rows ``x [N, D]`` whose held pairs number at
+    most ``max_pairs`` (the caller's promise). ``(y [N, D] f32, counts
+    [E], rows_padded)``."""
+    N, D = x.shape
+    k = local_ids.shape[1]
+    E, F = w_gate.shape[0], w_gate.shape[2]
+    flat = local_ids.reshape(-1).astype(jnp.int32)
+    dest, first, tiles, counts, m_pad = sort_rows_by_held_expert(
+        flat, E, tile_m, max_pairs)
+    n_tiles = m_pad // tile_m
+    live = tiles.sum()
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(jnp.cumsum(tiles), jnp.arange(n_tiles),
+                         side="right"), E - 1).astype(jnp.int32)
+    # the sorted buffer's rows as pairs (-1: a row nothing lands on,
+    # which is ZERO, gives zeros all the way and takes no gradient)
+    row_pair = jnp.full((m_pad,), -1, jnp.int32).at[dest].set(
+        jnp.arange(N * k, dtype=jnp.int32), mode="drop")
+    row_live = row_pair >= 0
+    row_token = jnp.maximum(row_pair, 0) // k
+    xs = jnp.where(row_live[:, None], x[row_token], 0)
+    grouped = functools.partial(gmm, tile_expert=tile_expert, tile_m=tile_m,
+                                live_tiles=live)
+    h = (jax.nn.silu(grouped(xs, w_gate, tile_n=F))
+         * grouped(xs, w_up, tile_n=F))
+    ys = grouped(h.astype(x.dtype), w_down, tile_n=D)
+    # the combine over the SORTED rows (the bound's many, not the
+    # pairs' N x k): each row's weight gathered, the weighted rows
+    # scatter-added onto their tokens in float32 (a dead row past them)
+    row_w = weights.reshape(-1).astype(jnp.float32)[jnp.maximum(row_pair, 0)]
+    y = jnp.zeros((N, D), jnp.float32).at[
+        jnp.where(row_live, row_token, N)].add(
+        ys.astype(jnp.float32) * row_w[:, None], mode="drop")
+    return y, counts, live * tile_m - counts.sum()
+
+
+def _pass_ids(local_ids, num_held: int, max_pairs: int):
+    """``(ids_of(j), needed)``: the held pairs ``max_pairs`` at a time,
+    in pair order. ``ids_of(j)`` is ``local_ids`` with every pair that
+    is not pass ``j``'s sent elsewhere; ``needed`` the passes that hold
+    a pair."""
+    held = local_ids < num_held
+    place = (jnp.cumsum(held.reshape(-1)) - 1).reshape(held.shape)
+    needed = -(-held.sum() // max_pairs)
+    return (lambda j: jnp.where(held & (place // max_pairs == j),
+                                local_ids, num_held)), needed
+
+
+def _later_passes(first, one, needed, most: int):
+    """``first`` (pass 0's results) plus those of the passes ``1 ..
+    needed - 1`` (``one(j)``, the same pytree), at most ``most``
+    passes: nothing runs where pass 0 held every pair."""
+    def rest(acc):
+        def body(acc, j):
+            return jax.lax.cond(
+                j < needed,
+                lambda a: jax.tree_util.tree_map(jnp.add, a, one(j)),
+                lambda a: a, acc), None
+        return jax.lax.scan(body, acc,
+                            jnp.arange(1, most, dtype=jnp.int32))[0]
+    return jax.lax.cond(needed > 1, rest, lambda a: a, first)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _share_in_passes(x, local_ids, weights, w_gate, w_up, w_down, tile_m,
+                     max_pairs):
+    ids_of, needed = _pass_ids(local_ids, w_gate.shape[0], max_pairs)
+    one = lambda j: _share_rows(x, ids_of(j), weights, w_gate, w_up, w_down,
+                                tile_m=tile_m, max_pairs=max_pairs)
+    y, counts, padded = _later_passes(
+        one(0), one, needed, -(-local_ids.size // max_pairs))
+    return y, counts, jnp.stack([padded, needed > 1]).astype(jnp.int32)
+
+
+def _share_in_passes_fwd(x, local_ids, weights, w_gate, w_up, w_down, tile_m,
+                         max_pairs):
+    return (_share_in_passes(x, local_ids, weights, w_gate, w_up, w_down,
+                             tile_m, max_pairs),
+            (x, local_ids, weights, w_gate, w_up, w_down))
+
+
+def _share_in_passes_bwd(tile_m, max_pairs, res, g):
+    """A pass's gradients are made where its rows are sorted again
+    (nothing of a pass is kept from the forward), and summed over the
+    passes."""
+    x, local_ids, weights, w_gate, w_up, w_down = res
+    dy = g[0]
+    ids_of, needed = _pass_ids(local_ids, w_gate.shape[0], max_pairs)
+
+    def one(j):
+        _, vjp = jax.vjp(
+            lambda *a: _share_rows(a[0], ids_of(j), *a[1:], tile_m=tile_m,
+                                   max_pairs=max_pairs)[0],
+            x, weights, w_gate, w_up, w_down)
+        return vjp(dy)
+
+    dx, dw, dg, du, dd = _later_passes(
+        one(0), one, needed, -(-local_ids.size // max_pairs))
+    return dx, None, dw, dg, du, dd
+
+
+_share_in_passes.defvjp(_share_in_passes_fwd, _share_in_passes_bwd)
+
+
+def grouped_experts_swiglu(x, local_ids, weights, w_gate, w_up, w_down, *,
+                           tile_m: int = 128, max_pairs: int = None):
+    """``held_experts_swiglu``'s contract (``w_*`` one layer's ``[E,
+    ...]``) by the row-tile walk, DIFFERENTIABLE in ``x``, ``weights``
+    and the three stacks. ``max_pairs``: a static bound on the held
+    (row, choice) pairs that sizes the sorted buffer (default: every
+    pair could be held). Held pairs past the bound are NOT dropped: the
+    held pairs go through the one buffer ``max_pairs`` at a time, in as
+    many passes as they need, so a call within the bound runs one pass
+    and a call past it is exact at the cost of sorting again and
+    reading the weights once a pass.
+
+    Returns ``(y [N, D] float32, counts [E], stats [2] int32)``:
+    ``stats = [rows_padded, fell_back]``, the dead rows of the live
+    tiles and whether the call needed more than one pass."""
+    A = local_ids.size
+    if max_pairs is None or max_pairs >= A:
+        y, counts, padded = _share_rows(
+            x, local_ids, weights, w_gate, w_up, w_down, tile_m=tile_m,
+            max_pairs=A)
+        return y, counts, jnp.stack([padded, 0]).astype(jnp.int32)
+    return _share_in_passes(x, local_ids, weights, w_gate, w_up, w_down,
+                            tile_m, max_pairs)
+
+
+# ---------------------------------------------------------------------------
 # kernel-audit registration (analysis/kernel_audit.py)
 # ---------------------------------------------------------------------------
 # Geometry keys match moe_mlp_dropless's autotune lookup kwargs, so
@@ -516,6 +720,7 @@ def audit_launches(geom, config=None):
     # sorted-precondition check and tgmm's contiguous-run accumulation
     # rely on
     te = np.sort(np.arange(n_tiles, dtype=np.int32) % E)
+    live = np.asarray([n_tiles], np.int32)
     xs = jax.ShapeDtypeStruct((m_pad, D), dt)
     hs = jax.ShapeDtypeStruct((m_pad, F), dt)
     w_gate = jax.ShapeDtypeStruct((E, D, F), dt)
@@ -542,13 +747,13 @@ def audit_launches(geom, config=None):
         (f"gmm_gate[{tile_m}x{tn_gate}]",
          functools.partial(_gmm_call, tile_m=tile_m, tile_n=tn_gate,
                            interpret=False),
-         (xs, w_gate, te)),
+         (xs, w_gate, te, live)),
         (f"gmm_down[{tile_m}x{tn_down}]",
          functools.partial(_gmm_call, tile_m=tile_m, tile_n=tn_down,
                            interpret=False),
-         (hs, w_down, te)),
+         (hs, w_down, te, live)),
         (f"tgmm_dw[{tile_m}x{tn_grad}]",
          functools.partial(_tgmm_call, num_experts=E, tile_m=tile_m,
                            tile_n=tn_grad, interpret=False),
-         (xs, hs, te)),
+         (xs, hs, te, live)),
     ]

@@ -852,3 +852,119 @@ def test_int8_matmul(chip):
     (_, fn, args), = I.audit_launches(
         {"M": SLOTS, "K": D, "N": 14336, "dtype": "bfloat16"})
     chip(fn, *args)
+
+
+# the training cell ``joyai-train-8k-ep8`` (JoyAI-LLM-Flash as one chip
+# of an EP-8 job: benchmark/workloads/joyai-train-8k-ep8.json): latent
+# attention EXPANDED through splash at q / k 192 and v 128, the held
+# experts' grouped matmuls each way, the whole step
+
+
+def _joyai_cell():
+    from tools.kernel_bench import train_gmm_cells
+    return train_gmm_cells()["joyai-train-8k-ep8"]
+
+
+def test_splash_takes_head_size_192_beside_v_128(chip):
+    """q and k at one and a half lane tiles, v at one, forward and
+    backward at the cell's batch and sequence: the chip's compiler takes
+    192 as it is, so ``flash_attention`` pads nothing."""
+    from paddle_tpu.ops.pallas.flash_attention import _splash
+    c = _joyai_cell()
+    assert (c["qk"], c["dv"]) == (192, 128)
+    B, T, H = c["batch"], c["seq_len"], c["heads"]
+
+    def loss(q, k, v):
+        o = _splash(q, k, v, True, c["qk"] ** -0.5)
+        assert o.shape == (B, T, H, c["dv"])
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    text = chip(jax.grad(loss, argnums=(0, 1, 2)), sds((B, T, H, c["qk"])),
+                sds((B, T, H, c["qk"])), sds((B, T, H, c["dv"])))
+    assert text.compiled.count("splash_mha") >= 3       # fwd, dq, dkv
+
+
+def test_grouped_matmuls_each_way_at_the_train_cell_s_geometry(chip):
+    """``[rows, 2048] @ [32, 2048, 768]`` forward, ``dX`` (the same
+    kernel over the transposed stack) and ``dW`` (``_tgmm_call``: a whole
+    expert's float32 gradient block a step, 6 MiB, which the stated VMEM
+    limit allows) over the sorted buffer the step sizes (twice the
+    balanced rows, the tail dead)."""
+    from paddle_tpu.incubate.moe.functional import (ROW_TILE_M,
+                                                    held_pairs_bound)
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+    c = _joyai_cell()
+    D, F, E = c["hidden"], c["width"], c["held"]
+    bound = held_pairs_bound(c["rows"], c["top_k"], E, c["routed"])
+    assert bound == 2 * c["rows"]
+    tile_m = ROW_TILE_M
+    tiles = -(-bound // tile_m) + E
+    M = tiles * tile_m
+    i32 = functools.partial(sds, dtype=jnp.int32)
+
+    def each_way(x, g, w, te, live):
+        fwd = G._gmm_call(x, w, te, live, tile_m, F, interpret=False)
+        dx = G._gmm_call(g, jnp.swapaxes(w, 1, 2), te, live, tile_m, D,
+                         interpret=False)
+        dw = G._tgmm_call(x, g, te, live, E, tile_m, F, interpret=False)
+        return fwd, dx, dw
+
+    text = chip(each_way, sds((M, D)), sds((M, F)), sds((E, D, F)),
+                i32((tiles,)), i32((1,)))
+    assert text.compiled.count("grouped_matmul_dw") >= 1
+    assert text.compiled.count("grouped_matmul") >= 3
+
+
+def test_joyai_train_step_fits_the_chip(topo, chip, monkeypatch):
+    """The cell's WHOLE step (dense + 5 expert layers, 2 x 8192 tokens,
+    strict kernels) for the described chip: splash, the fused norm and
+    the grouped matmuls each way are in the compiled text, the expert
+    walk is not, and ``memory_analysis`` stays under the chip's 15.75
+    GiB (13.9 at PR 44: 5.88 of state, 8.0 of temporaries)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    import os
+    import sys
+    bench_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench_dir not in sys.path:
+        sys.path.insert(0, bench_dir)
+    from harness import manifest
+    from paddle_tpu.ops.pallas import flash_attention as FA
+    from paddle_tpu.ops.pallas import fused_norm_rope as FN
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+    from paddle_tpu.parallel import init_hybrid_mesh
+    for mod in (FA, FN, G):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    cell = manifest.Cell(manifest.load_manifest(), "joyai-train-8k-ep8")
+    tr = cell.workload["trainer"]
+    B, T = tr["batch"], tr["seq_len"]
+    cfg, L = cell.family.program_config(
+        dict(cell.model), max_position_embeddings=T,
+        use_flash_attention="pallas", use_fused_norm_rope="pallas")
+    mesh = init_hybrid_mesh(dp=1, pp=1, tp=1, set_global=False,
+                            devices=topo.devices[:1]).mesh
+    with mesh:
+        step, init = L.make_train_step(cfg, mesh)
+        state = jax.tree.map(
+            lambda a, sp: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, sp)),
+            jax.eval_shape(init, jax.random.PRNGKey(0)),
+            L.train_state_specs(cfg, mesh))
+        batch = {k: jax.ShapeDtypeStruct(
+            (B, T), jnp.int32, sharding=NamedSharding(mesh, P("dp", None)))
+            for k in ("tokens", "labels")}
+        compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    count = lambda mark: sum(mark in ln for ln in calls)
+    assert count("splash_mha") >= 6             # two groups x fwd, dq, dkv
+    assert count("_rms_fwd_call") and count("_rms_bwd_call")
+    assert count("grouped_matmul_dw") >= 3 and count("grouped_matmul") >= 12
+    assert count("held_experts_matmul") == 0
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    state_bytes = 6 * cell.family.param_count(cell.model)
+    assert abs(mem.argument_size_in_bytes - state_bytes) < 0.01 * state_bytes
+    assert mem.alias_size_in_bytes >= 0.99 * mem.argument_size_in_bytes
+    assert 0.80 * 15.75 * 2 ** 30 < live < 0.93 * 15.75 * 2 ** 30, live

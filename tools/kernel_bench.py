@@ -209,11 +209,10 @@ def _walltime(f, args, n=3):
     return best * 1e3
 
 
-def _serving_cells():
-    """``(workloads entry, model, engine geometry, slots x table)`` of
-    every serving cell of ``BENCHMARK.json``, READ from the files the
-    cell runs from: its workload file and its configuration with the
-    workload's overrides."""
+def _cells():
+    """``(workloads entry, workload file, model)`` of every cell of
+    ``BENCHMARK.json``, READ from the files the cell runs from: its
+    workload file and its configuration with the workload's overrides."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
     def read(*path):
@@ -224,9 +223,16 @@ def _serving_cells():
     files = {c["name"]: c["file"] for c in bench["configs"]}
     for w in bench["workloads"]:
         work = read("benchmark", "workloads", w["name"] + ".json")
+        yield w, work, {**read(files[w["config"]]),
+                        **work.get("overrides", {})}
+
+
+def _serving_cells():
+    """``(workloads entry, model, engine geometry, slots x table)`` of
+    every serving cell."""
+    for w, work, model in _cells():
         if not work.get("mode", "").startswith("serve"):
             continue
-        model = {**read(files[w["config"]]), **work.get("overrides", {})}
         eng = work["engine"]
         ps, slots = eng["page_size"], eng["max_batch"]
         longest = max(eng.get("prompt_buckets") or [eng["max_prompt_len"]])
@@ -855,6 +861,166 @@ def held_sweep(out=None, iters=5, cells=None, label="", tile_ms=(None,),
     return _emit(results, out)
 
 
+def train_gmm_cells():
+    """The held experts' grouped matmul of every TRAINING cell whose
+    configuration holds a share of routed experts, keyed by the cell's
+    name, READ from the files the cell runs from: ``rows`` a step,
+    ``hidden`` x ``width`` an expert, ``held`` experts of ``routed``
+    router outputs, ``top_k`` choices a row. The one copy of these
+    numbers: ``train_gmm_sweep`` and tests/test_chip_compile.py read
+    it."""
+    cells = {}
+    for w, work, m in _cells():
+        if work.get("mode") != "train" or "moe_intermediate_size" not in m:
+            continue
+        t = work["trainer"]
+        cells[w["name"]] = dict(
+            rows=t["batch"] * t["seq_len"], batch=t["batch"],
+            seq_len=t["seq_len"], hidden=m["hidden_size"],
+            width=m["moe_intermediate_size"], held=m["n_routed_experts"],
+            routed=m.get("router_experts", m["n_routed_experts"]),
+            top_k=m["num_experts_per_tok"],
+            heads=m["num_attention_heads"],
+            qk=m["qk_nope_head_dim"] + m["qk_rope_head_dim"],
+            dv=m["v_head_dim"])
+    return cells
+
+
+def train_gmm_sweep(out=None, iters=5, cells=None, label="",
+                    tile_ms=(128, 256, 512)):
+    """ONE grouped matmul of a training cell's held experts, ``[rows,
+    hidden] @ [held, hidden, width]``, alone, each way: ``forward``
+    (``_gmm_call``), ``dX`` (the same kernel over the transposed
+    stack; ``dX+T`` with the transpose in front of it) and ``dW``
+    (``_tgmm_call``), by rows an expert (256 / 512 / 1024), uniform and
+    skewed counts (loads from a quarter to seven quarters of the mean)
+    and ``tile_m``; the sorted buffer as the step sizes it (twice the
+    rows, the tail dead). Beside them the EXPERT walk's forward
+    (``_held_gmm_call``, one stack) at the same rows, and splash's
+    forward + backward at the cell's q / k head size as it is and
+    padded to the next lane tile. ``ms`` is the device's busy time a
+    call, ``tflops`` the real rows' arithmetic over it."""
+    import tempfile
+    from paddle_tpu.ops.pallas import flash_attention as FA
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+    on_tpu = jax.default_backend() == "tpu"
+    table = train_gmm_cells() if on_tpu else {
+        "tiny": dict(rows=256, batch=1, seq_len=256, hidden=128, width=128,
+                     held=4, routed=16, top_k=4, heads=2, qk=192, dv=128)}
+    if not on_tpu:
+        tile_ms = (16, 32)
+    results = []
+
+    def timed(row, fn, args, flops):
+        try:
+            jax.block_until_ready(fn(*args))
+            if on_tpu:
+                tdir = tempfile.mkdtemp(prefix="kb_tgmm_")
+                with jax.profiler.trace(tdir):
+                    for _ in range(iters):
+                        y = fn(*args)
+                    jax.block_until_ready(y)
+                ms = _busy_ms(tdir) / iters
+            else:
+                ms = _walltime(fn, args, n=iters)
+            results.append(dict(row, ms=round(ms, 5),
+                                tflops=round(flops / ms / 1e9, 2),
+                                timing_honest=on_tpu))
+        except Exception as e:      # a block the compiler refuses is a row
+            results.append(dict(row, error=f"{type(e).__name__}: "
+                                f"{str(e)[:300]}"))
+
+    for name in cells or table:
+        c = table[name]
+        D, F, E = c["hidden"], c["width"], c["held"]
+        rng = np.random.RandomState(0)
+        keys = jax.random.split(jax.random.PRNGKey(0), 2)
+        w = (jax.random.normal(keys[0], (E, D, F), jnp.float32)
+             * 0.02).astype(jnp.bfloat16)
+        mean_rows = c["rows"] * c["top_k"] // c["routed"]
+        for per in (mean_rows // 2, mean_rows, mean_rows * 2):
+            for load in ("uniform", "skewed"):
+                share = (np.ones(E) if load == "uniform"
+                         else np.linspace(0.25, 1.75, E))
+                counts = np.maximum((share * per).astype(np.int64), 1)
+                real = int(counts.sum())
+                for tm in tile_ms:
+                    tiles = -(-counts // tm)
+                    n_tiles = -(-2 * real // tm) + E
+                    M = n_tiles * tm
+                    te = np.minimum(np.searchsorted(
+                        np.cumsum(tiles), np.arange(n_tiles), side="right"),
+                        E - 1).astype(np.int32)
+                    live = np.asarray([tiles.sum()], np.int32)
+                    x = jnp.asarray(rng.randn(M, D) * 0.1, jnp.bfloat16)
+                    g = jnp.asarray(rng.randn(M, F) * 0.1, jnp.bfloat16)
+                    row = {"bench": "train_gmm_sweep", "label": label,
+                           "cell": name, "rows_an_expert": int(per),
+                           "load": load, "rows": real, "buffer_rows": M,
+                           "tile_m": tm}
+                    flops = 2.0 * real * D * F
+                    interp = not on_tpu
+                    timed(dict(row, op="forward", impl="row_tiles"),
+                          jax.jit(lambda x, w, te, live, tm=tm: G._gmm_call(
+                              x, w, te, live, tm, F, interpret=interp)),
+                          (x, w, te, live), flops)
+                    wt = jnp.swapaxes(w, 1, 2)
+                    timed(dict(row, op="dX", impl="row_tiles"),
+                          jax.jit(lambda g, wt, te, live, tm=tm: G._gmm_call(
+                              g, wt, te, live, tm, D, interpret=interp)),
+                          (g, wt, te, live), flops)
+                    timed(dict(row, op="dX+T", impl="row_tiles"),
+                          jax.jit(lambda g, w, te, live, tm=tm: G._gmm_call(
+                              g, jnp.swapaxes(w, 1, 2), te, live, tm, D,
+                              interpret=interp)),
+                          (g, w, te, live), flops)
+                    timed(dict(row, op="dW", impl="row_tiles"),
+                          jax.jit(lambda x, g, te, live, tm=tm: G._tgmm_call(
+                              x, g, te, live, E, tm, F, interpret=interp)),
+                          (x, g, te, live), flops)
+                for tm in ((16, 128, 256) if on_tpu else (8,)):
+                    tiles = (-(-counts // tm)).astype(np.int32)
+                    first = (np.cumsum(tiles) - tiles).astype(np.int32)
+                    M = (int(tiles.sum()) + E) * tm
+                    x = jnp.asarray(rng.randn(M, D) * 0.1, jnp.bfloat16)
+                    blk = np.arange(E, dtype=np.int32)
+                    timed({"bench": "train_gmm_sweep", "label": label,
+                           "cell": name, "rows_an_expert": int(per),
+                           "load": load, "rows": real, "buffer_rows": M,
+                           "tile_m": tm, "op": "forward",
+                           "impl": "expert_walk"},
+                          jax.jit(lambda x, w, blk, first, tiles, tm=tm:
+                                  G._held_gmm_call(
+                                      x, (w[None],), jnp.zeros((1,), jnp.int32),
+                                      blk, first, tiles, tile_m=tm, tile_n=F,
+                                      interpret=not on_tpu)),
+                          (x, w, blk, first, tiles), 2.0 * real * D * F)
+        # splash at the cell's q / k head size, as it is and padded to
+        # the next lane tile, by block size (the program's is 512)
+        B, T, H = c["batch"], c["seq_len"], c["heads"]
+        for dq in sorted({c["qk"], -(-c["qk"] // 128) * 128}):
+            for block in ((512, 1024, 2048) if on_tpu else ()):
+                q = jnp.asarray(rng.randn(B, T, H, dq) * 0.1, jnp.bfloat16)
+                v = jnp.asarray(rng.randn(B, T, H, c["dv"]) * 0.1,
+                                jnp.bfloat16)
+                kernel = FA._splash_kernel(H, T, T, True, block)
+
+                def loss(q, k, v, kernel=kernel):
+                    t = lambda a: a.transpose(0, 2, 1, 3)
+                    o = jax.vmap(kernel)(
+                        t((q * c["qk"] ** -0.5).astype(q.dtype)), t(k), t(v))
+                    return (o.astype(jnp.float32) ** 2).sum()
+
+                sq = 2.0 * B * H * T * T / 2.0
+                timed({"bench": "train_gmm_sweep", "label": label,
+                       "cell": name, "op": "splash fwd+bwd", "head_qk": dq,
+                       "head_v": c["dv"], "block": block},
+                      jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (q, q, v),
+                      sq * (c["qk"] + c["dv"])
+                      + sq * (3 * c["qk"] + 2 * c["dv"]))
+    return _emit(results, out)
+
+
 def block_sweep(out=None, iters=3):
     """Block-shape sweeps for the swept Pallas entry points (module
     docstring): time every candidate, record the winner per geometry
@@ -1014,7 +1180,8 @@ if __name__ == "__main__":
     from paddle_tpu.compile_cache import enable_compile_cache
     enable_compile_cache()
     if {"--block-sweep", "--ragged-sweep", "--ssd-sweep",
-            "--mla-sweep", "--held-sweep"} & set(sys.argv):
+            "--mla-sweep", "--held-sweep", "--train-gmm-sweep"} & set(
+                sys.argv):
         opt = {a.split("=", 1)[0]: a.split("=", 1)[1] for a in sys.argv
                if a.startswith("--") and "=" in a}
         path = opt.get("--out")
@@ -1030,6 +1197,13 @@ if __name__ == "__main__":
             ssd_sweep(out=path, label=opt.get("--label", ""),
                       cells=(opt["--cells"].split(",") if "--cells" in opt
                              else None))
+        elif "--train-gmm-sweep" in sys.argv:
+            train_gmm_sweep(
+                out=path, label=opt.get("--label", ""),
+                cells=(opt["--cells"].split(",") if "--cells" in opt
+                       else None),
+                tile_ms=tuple(int(t) for t in
+                              opt.get("--tile-ms", "128,256,512").split(",")))
         elif "--held-sweep" in sys.argv:
             held_sweep(
                 out=path, label=opt.get("--label", ""),
