@@ -67,6 +67,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..incubate.moe.functional import moe_ffn_share
+from ..ops.pallas.flash_attention import flash_attention, remat_layer
 from .layer_walk import layer_groups as _groups, layer_kinds
 from .llama import _fused_nr_on, _norm_fn
 from .mla import mla_qkv
@@ -249,7 +250,6 @@ def shard_params(params, cfg: JoyAIFlashConfig, mesh: Mesh):
 
 def _attention(lp, h, positions, cfg: JoyAIFlashConfig, norm):
     """``h + MLA(rms(h))`` in the expanded form."""
-    from ..ops.pallas.flash_attention import flash_attention
     B, T, _ = h.shape
     H = cfg.num_attention_heads
     q_n, q_r, c_kv, k_r = mla_qkv(lp, h, positions, cfg, norm)
@@ -323,7 +323,7 @@ def _trunk(params, tokens, cfg: JoyAIFlashConfig, mesh):
         kind = group.layers[0][1]
         body = dense if kind == DENSE else moe
         if cfg.remat:
-            body = jax.checkpoint(body)
+            body = remat_layer(body)
         with jax.named_scope("layers"):
             h, per_layer = lax.scan(body, h, params[kind])
         if per_layer is not None:
@@ -355,7 +355,7 @@ def _mtp_logits(params, h, next_tokens, cfg: JoyAIFlashConfig, norm):
                              norm(h, mp["hidden_norm"])], axis=-1)
         layer = lambda x: expert_layer(mp["layer"], x @ mp["proj"],
                                        positions, cfg, norm)
-        x, counts = (jax.checkpoint(layer) if cfg.remat else layer)(x)
+        x, counts = (remat_layer(layer) if cfg.remat else layer)(x)
         return _head(x, mp["final_norm"], params["lm_head"], norm), counts
 
 

@@ -28,10 +28,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..observability import in_setup_span, setup_span
+from ..ops.pallas.flash_attention import remat_layer
 from ..parallel.pipeline_spmd import pipeline_spmd, microbatch
 
 
@@ -363,10 +363,6 @@ def _block(lp, h, positions, cfg: LlamaConfig, attn_fn, sp_spec=None,
         v = _mm(x, lp["wv"]).reshape(B, T, Hkv, Dh)
         q, k = rope_fn(q, k)
     o = attn_fn(q, k, v)
-    # tag for remat policies: lets a save_only_these_names policy keep the
-    # kernel output so backward recompute skips the flash forward (the
-    # default bench path uses plain per-layer remat, measured faster)
-    o = checkpoint_name(o, "attn_out")
     with jax.named_scope("attn.out"):
         h = h + _mm(o.reshape(B, T, H * Dh), lp["wo"])
         if sp_spec is not None:
@@ -477,10 +473,11 @@ def _scan_layers(layer_params, h, cfg: LlamaConfig, sp_spec=None, remat=False,
     fn = partial(decoder_layer, cfg=cfg, sp_spec=sp_spec, mesh=mesh,
                  positions=positions)
     if remat:
-        # measured on-chip: plain full per-layer remat beats
-        # save_only_these_names("attn_out") by ~2% step time at bench
-        # shapes (the saved flash recompute is outweighed by HBM pressure)
-        fn = jax.checkpoint(fn)
+        # all of the layer is rebuilt in the backward pass but splash's
+        # out and logsumexp, named inside the kernel's own forward rule
+        # (the backward kernels read THAT rule's residuals: a name put
+        # on the layer's attention output saves nothing they read)
+        fn = remat_layer(fn)
     if grad_specs is not None:
         own, sharded = (_layer_specs(param_specs(cfg)["layers"]),
                         _layer_specs(grad_specs))
@@ -714,7 +711,7 @@ def _async_stage_head_fns(cfg: LlamaConfig, mesh: Mesh):
         fn = lambda lp, hh: _tp_local_block(lp, hh, positions, cfg,
                                             attn_fn)
         if cfg.remat:
-            fn = jax.checkpoint(fn)
+            fn = remat_layer(fn)
 
         def body(carry, lp):
             return fn(lp, carry), None

@@ -23,6 +23,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..incubate.moe.functional import moe_ffn, moe_ffn_share
+from ..ops.pallas.flash_attention import remat_layer
 from .layer_walk import (COUNTS, EXPERT_COUNTERS, expert_counts,
                          with_tick_counts)
 from .llama import _mm, rms_norm, rope
@@ -226,7 +227,7 @@ def forward(params, tokens, cfg: Qwen2MoeConfig,
     fn = partial(decoder_layer, cfg=cfg, ep_axis=ep_axis,
                  use_dropless=use_dropless)
     if cfg.remat:
-        fn = jax.checkpoint(fn)
+        fn = remat_layer(fn)
 
     def body(carry, lp):
         h, aux = carry

@@ -32,6 +32,23 @@ import jax
 import jax.numpy as jnp
 from . import on_tpu as _on_tpu
 
+# The name the splash forward rule gives its residuals ``out`` and
+# ``logsumexp``. It has to come from INSIDE the kernel's ``custom_vjp``
+# forward rule: the backward kernels read that rule's own residuals, so
+# an output named outside the call is another variable, brings no
+# log-sum-exp and saves nothing. Under no policy the name is an identity.
+SPLASH_RESIDUALS = "splash_residuals"
+
+
+def remat_layer(layer):
+    """``jax.checkpoint(layer)`` that keeps splash's two residuals (128
+    + 2 MiB a layer at 2 x 32 heads x 8192 x 128) and rebuilds everything
+    else: the backward pass then runs no second forward of the kernel.
+    The dense path carries no name, so this is plain remat there."""
+    return jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(
+            SPLASH_RESIDUALS))
+
 
 def _dense_reference(q, k, v, causal, sm_scale):
     B, T, H, Dh = q.shape
@@ -69,8 +86,9 @@ def _splash_kernel(n_heads: int, t_q: int, t_kv: int, causal: bool,
     # cached and reused across traces — a tracer leaking into it would
     # poison later calls)
     with jax.ensure_compile_time_eval():
-        return sk.make_splash_mha(mask=mask, head_shards=1, q_seq_shards=1,
-                                  block_sizes=bs)
+        return sk.make_splash_mha(
+            mask=mask, head_shards=1, q_seq_shards=1, block_sizes=bs,
+            residual_checkpoint_name=SPLASH_RESIDUALS)
 
 
 def _block_for(t_q: int, t_kv: int) -> int:

@@ -80,6 +80,27 @@ def pytest_collection_modifyitems(config, items):
 
 
 @pytest.fixture(autouse=True)
+def _bounded_memory_maps():
+    """Every compiled program maps a few regions of memory and a worker
+    keeps the programs of every file it has run: five files ahead of
+    ``test_joyai_flash.py`` on one worker left 61 784 mappings of the
+    kernel's 65 530 (``vm.max_map_count``), the next large compile's
+    ``mmap`` failed and the worker died of a segmentation fault inside
+    XLA (PR 45; whichever test compiles then, on whichever schedule).
+    Past 40 000, drop JAX's caches before the test: 973 are left."""
+    try:
+        with open("/proc/self/maps") as f:
+            maps = sum(1 for _ in f)
+    except OSError:         # no procfs: nothing to count, nothing to do
+        maps = 0
+    if maps > 40000:
+        import gc
+        jax.clear_caches()
+        gc.collect()
+    yield
+
+
+@pytest.fixture(autouse=True)
 def _seed_all():
     import paddle_tpu as pt
     pt.seed(2024)
@@ -117,3 +138,19 @@ def _span_tick(mod, params, cfg, cache, tables, slot, toks, pos0, width,
 @pytest.fixture(scope="session")
 def span_tick():
     return _span_tick
+
+
+@pytest.fixture
+def splash_interpreted(monkeypatch):
+    """``flash_attention(..., impl="pallas")`` off the chip: the splash
+    kernel built with the LIBRARY's ``interpret=True`` (the program has
+    no such argument; strict pallas off the chip raises otherwise)."""
+    import functools
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setattr(sk, "make_splash_mha", functools.partial(
+        sk.make_splash_mha, interpret=True))
+    fa._splash_kernel.cache_clear()
+    yield
+    fa._splash_kernel.cache_clear()

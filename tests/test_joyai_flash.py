@@ -164,6 +164,37 @@ def test_loss_and_every_leafs_gradient_equal_the_reference(nextn):
         close(g, w, tol=5 * TOL, what=name)
 
 
+@pytest.mark.parametrize("against", ["plain_remat", "no_remat"])
+@pytest.mark.parametrize("attn", ["pallas", "dense"])
+def test_keeping_splash_s_residuals_changes_no_gradient(
+        attn, against, splash_interpreted, monkeypatch):
+    """A dense and an expert layer with the module on, rematerialised
+    but for splash's ``out`` and ``logsumexp`` (``remat_layer``), against
+    plain ``jax.checkpoint`` and against no remat: the loss and every
+    leaf's gradient EXACTLY, through the kernel (interpret mode; the
+    backward reads what the forward pass wrote instead of a second
+    evaluation) and on the dense path (nothing carries the name)."""
+    def value_and_grads(remat):
+        cfg = M.JoyAIFlashConfig.tiny(
+            num_hidden_layers=2, num_nextn_predict_layers=1,
+            use_flash_attention=attn, remat=remat)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0,
+                                  cfg.vocab_size)
+        batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+        return jax.jit(jax.value_and_grad(
+            lambda p: M.loss_fn(p, batch, cfg)))(
+                M.init_params(cfg, jax.random.PRNGKey(0)))
+
+    kept = value_and_grads(True)
+    if against == "plain_remat":
+        monkeypatch.setattr(M, "remat_layer", jax.checkpoint)
+    other = value_and_grads(against == "plain_remat")
+    assert np.abs(np.asarray(jax.tree.leaves(kept[1])[0])).max() > 0
+    for name, a, b in zip(train._leaf_names(kept), jax.tree.leaves(kept),
+                          jax.tree.leaves(other)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+
+
 # ------------------------------------------------------- the expert share ----
 
 def _layer_inputs(seed=2, n_rows=96):

@@ -4,6 +4,8 @@ Mirrors the reference's hybrid-strategy integration tests
 (test/auto_parallel/hybrid_strategy/semi_auto_llama.py — dp/mp/pp Llama on
 multi-GPU): here the mesh is virtual, the parallelism is real.
 """
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -49,6 +51,35 @@ def test_fused_norm_rope_path_matches_unfused():
     for a, b in zip(flat_f, flat_u):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-5)
+
+
+@pytest.mark.parametrize("against", ["plain_remat", "no_remat"])
+@pytest.mark.parametrize("attn", ["pallas", False], ids=["pallas", "dense"])
+def test_keeping_splash_s_residuals_changes_no_gradient(
+        attn, against, splash_interpreted, monkeypatch):
+    """Two layers rematerialised but for splash's ``out`` and
+    ``logsumexp`` (``remat_layer``), against plain ``jax.checkpoint`` and
+    against no remat: the loss and every leaf's gradient EXACTLY, through
+    the kernel (interpret mode) and on the dense path, where nothing
+    carries the name and the policy is plain remat."""
+    def value_and_grads(remat):
+        cfg = dataclasses.replace(
+            _cfg(use_flash_attention=attn, use_fused_norm_rope=False,
+                 remat=remat), num_hidden_layers=2)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0,
+                                  cfg.vocab_size)
+        batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+        return jax.jit(jax.value_and_grad(
+            lambda p: L.loss_fn(p, batch, cfg)))(
+                L.init_params(cfg, jax.random.PRNGKey(0)))
+
+    kept = value_and_grads(True)
+    if against == "plain_remat":
+        monkeypatch.setattr(L, "remat_layer", jax.checkpoint)
+    other = value_and_grads(against == "plain_remat")
+    assert np.abs(np.asarray(kept[1]["layers"]["wq"])).max() > 0
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(other)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_pipeline_matches_single_stage():
