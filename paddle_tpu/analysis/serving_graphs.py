@@ -20,7 +20,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional
 
 from .framework import GraphTarget, trace_graph
-from .recompile import ServingGeometry
+from .recompile import ServingGeometry, tick_budget
 
 __all__ = ["engine_geometry", "serving_targets", "pp_stage_targets",
            "rewrite_targets", "ragged_walk_model", "FLAGSHIP_MODELS"]
@@ -113,12 +113,15 @@ def _get_model(name: str):
                                 if k in fields})
 
 
-def _abstract_cache(mod, cfg, slots: int, pps: int, page_size: int):
+def _abstract_cache(mod, cfg, slots: int, pps: int, page_size: int,
+                    max_span: int = 1):
     """The family's cache pytree as the engine has ``init_serving_pages``
-    build it (``slots * pps`` pages and the trash page), abstractly."""
+    build it (``slots * pps`` pages and the trash page; ``max_span``
+    rows a slot a tick for a family with window rings), abstractly."""
     import jax
-    return jax.eval_shape(lambda: mod.init_serving_pages(
-        cfg, slots * pps + 1, page_size, max_batch=slots))
+    from ..serving.engine import init_cache
+    return jax.eval_shape(lambda: init_cache(
+        mod, cfg, slots * pps + 1, page_size, slots, max_span))
 
 
 def _donated(cache, first: int):
@@ -205,7 +208,8 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
             lambda lhs, rhs: rhs.shape and rhs.shape[-1] == n_e)
 
     params = mod.abstract_params(cfg)
-    cache = _abstract_cache(mod, cfg, slots, pps, page_size)
+    cache = _abstract_cache(mod, cfg, slots, pps, page_size,
+                            tick_budget(geom))
     # a family that hands counts back beside its tokens (TICK_COUNTERS:
     # one more small result in front of the slots' tokens)
     counts = 1 if getattr(mod, "TICK_COUNTERS", ()) else 0
@@ -222,7 +226,6 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
     # stays False so the host-pull budget (whose hot-path guard is the
     # block program below) does not charge it per step; the engine
     # pulls only the [S(,1+tail)] i32 token block whoever samples.
-    from .recompile import tick_budget
     budget = tick_budget(geom)
     T = slots + budget
     targets.append(trace_graph(
@@ -239,7 +242,8 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
     # this target, so graph_lint proves the draft/verify program set
     # stays within the per-bucket bound (emitted as
     # serving_programs_spec in --json)
-    if not any(k.cache == "slot_rows" for k in _cache_kinds(mod, cfg)):
+    if not any(k.cache in ("slot_rows", "window_pages")
+               for k in _cache_kinds(mod, cfg)):
         spec_geom = engine_geometry(
             page_size=page_size, max_prompt_len=max_prompt_len,
             max_new_tokens_cap=max_new_tokens_cap,

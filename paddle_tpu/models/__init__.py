@@ -39,7 +39,8 @@ SERVING_FAMILIES = {"llama": "LlamaConfig",
                     "qwen2_moe": "Qwen2MoeConfig",
                     "lfm2_moe": "Lfm2MoeConfig",
                     "granite_hybrid": "GraniteHybridConfig",
-                    "longcat_flash": "LongcatFlashConfig"}
+                    "longcat_flash": "LongcatFlashConfig",
+                    "mimo_v2_flash": "MimoV2FlashConfig"}
 
 
 def resolve_family(model, cfg=None):

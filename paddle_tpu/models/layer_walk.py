@@ -23,9 +23,13 @@ from jax import lax
 
 class LayerKind(NamedTuple):
     """An operator kind and what it keeps between ticks: ``pages`` (K
-    and V in the paged pool, rebuilt from a prefix's pages) or
+    and V in the paged pool, rebuilt from a prefix's pages),
     ``slot_rows`` (a fixed row a slot, which nothing but the tokens
-    themselves can rebuild)."""
+    themselves can rebuild) or ``window_pages`` (K and V of the last
+    tokens of a window in a ring of pages a slot, in a pool of its own
+    that the allocator does not count and the context never grows:
+    ``models/mimo_v2_flash.py``; a prefix's pages do not rebuild it
+    either)."""
     name: str
     cache: str
 

@@ -199,21 +199,25 @@ def tiled_ulp_error(got, ref) -> float:
 
 
 def _step_vmem_bytes(heads: int, tile_pages: int, page_size: int,
-                     head_dim: int, rows: int, itemsize: int) -> int:
+                     head_dim: int, rows: int, itemsize: int,
+                     v_head_dim=None) -> int:
     """What one grid step of ``heads`` KV heads pins of VMEM as the
     chip's compiler counts it: the double-buffered K and V tiles, the
     float32 flash state (each ``(rows, 1)`` column of running max and
     denominator fills whole 128-lane rows there), and the q and o
-    blocks, which the pipeline double-buffers."""
+    blocks, which the pipeline double-buffers. ``v_head_dim``: the
+    values' (and the result's) head size where it is not the keys'."""
+    dv = head_dim if v_head_dim is None else int(v_head_dim)
     rows = -(-rows // ROW_BLOCK) * ROW_BLOCK if rows > ROW_BLOCK else rows
-    kv = 2 * 2 * heads * tile_pages * page_size * head_dim * itemsize
-    state = heads * rows * (head_dim + 2 * LANES) * 4
-    blocks = 2 * 2 * heads * rows * head_dim * itemsize
+    kv = 2 * heads * tile_pages * page_size * (head_dim + dv) * itemsize
+    state = heads * rows * (dv + 2 * LANES) * 4
+    blocks = 2 * heads * rows * (head_dim + dv) * itemsize
     return kv + state + blocks
 
 
 def heads_per_step(kv_heads: int, tile_pages: int, page_size: int,
-                   head_dim: int, dtype=jnp.bfloat16, rows: int = 0) -> int:
+                   head_dim: int, dtype=jnp.bfloat16, rows: int = 0,
+                   v_head_dim=None) -> int:
     """KV heads one grid step holds at a ``tile_pages`` tile and
     ``rows`` query rows a head: all of them where ``STEP_VMEM_BUDGET``
     allows (a page then moves with one copy a pool), else the largest
@@ -224,7 +228,7 @@ def heads_per_step(kv_heads: int, tile_pages: int, page_size: int,
     for heads in range(int(kv_heads), 1, -1):
         if kv_heads % heads == 0 and _step_vmem_bytes(
                 heads, tile_pages, page_size, head_dim, int(rows),
-                item) <= STEP_VMEM_BUDGET:
+                item, v_head_dim) <= STEP_VMEM_BUDGET:
             return heads
     return 1
 
@@ -244,14 +248,14 @@ def default_kv_tile_pages(pages_per_slot: int, page_size: int,
 
 def page_copies(kv_heads: int, pages_per_slot: int, page_size: int,
                 head_dim: int, dtype=jnp.bfloat16, rows: int = 0,
-                kv_tile_pages=None) -> int:
+                kv_tile_pages=None, v_head_dim=None) -> int:
     """Copies a launch of ``rows`` query rows a (slot, kv head) starts
     for ONE live page of a layer: one a pool a grid step that walks it
     (2 where a step holds every head)."""
     tile = _tile_pages(pages_per_slot, page_size, head_dim, dtype,
                        kv_tile_pages)
     return 2 * (int(kv_heads) // heads_per_step(
-        kv_heads, tile, page_size, head_dim, dtype, rows))
+        kv_heads, tile, page_size, head_dim, dtype, rows, v_head_dim))
 
 
 def _tile_pages(pages_per_slot, page_size, head_dim, dtype,
@@ -322,21 +326,44 @@ def _mxu_dot(a, b, dims):
         preferred_element_type=jnp.float32).astype(a.dtype)
 
 
-def _row_mask(r0, shape, k0, q_len, kv_len, g: int):
+def _row_mask(r0, shape, k0, q_len, kv_len, g: int, window: int = 0):
     """Bottom-right causal mask ``[rows, keys]`` (broadcast from a
     column of tokens and a row of key positions) of (token, group)-
     ordered query rows ``r0 ..`` against key positions ``k0 ..``: row
     ``r`` is token ``t = r // g`` and sees keys
     ``0 .. (kv_len - q_len) + t``; rows past ``g·q_len`` (span padding)
-    are fully masked."""
+    are fully masked. With ``window`` a row sees the LAST ``window`` of
+    those keys only (its own position and the ``window - 1`` before)."""
     t = jax.lax.div(
         r0 + jax.lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0),
         jnp.int32(g))
     k_idx = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1)
-    return (t < q_len) & (k_idx <= (kv_len - q_len) + t)
+    if not window:
+        return (t < q_len) & (k_idx <= (kv_len - q_len) + t)
+    hi = (kv_len - q_len) + t
+    return (t < q_len) & (k_idx <= hi) & (k_idx > hi - window)
 
 
-def _attend(qs, ks, vs, q_len, kv_len, g: int):
+def _first_key(q_len, kv_len, window: int):
+    """The first key position ANY row of a slot sees under ``window``
+    (its first row's): what lies before it is skipped by the walk."""
+    return jnp.maximum(kv_len - q_len - (window - 1), 0)
+
+
+def _sink_rows(sinks, r0, rows: int, g: int):
+    """``[rows, 1]`` float32: the sink logit of each of the (token,
+    group)-ordered query rows ``r0 ..`` of one KV head, ``sinks [G]``
+    its query heads' (row ``r`` is query head ``r % g`` of the group)."""
+    gi = jax.lax.rem(
+        r0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), jnp.int32(g))
+    col = jnp.zeros((rows, 1), jnp.float32)
+    for j in range(g):
+        col = jnp.where(gi == j, sinks[j], col)
+    return col
+
+
+def _attend(qs, ks, vs, q_len, kv_len, g: int, window: int = 0,
+            sink=None):
     """One (slot, kv-head) attention block in ONE softmax over the
     whole padded context: the one-shot dense reference the flash walk
     is held to under ``TILED_ULP_BOUND``.
@@ -352,18 +379,24 @@ def _attend(qs, ks, vs, q_len, kv_len, g: int):
     ks = jnp.where(kmask, ks, 0)
     vs = jnp.where(kmask, vs, 0)
     s = _mxu_dot(qs, ks, (((1,), (1,)), ((), ()))).astype(jnp.float32)
-    mask = _row_mask(0, s.shape, 0, q_len, kv_len, g)
+    mask = _row_mask(0, s.shape, 0, q_len, kv_len, g, window)
     s = jnp.where(mask, s, _MASK)
     m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        # the sink ``[rows, 1]``: a logit in the denominator, no value
+        m = jnp.maximum(m, sink)
     p = jnp.exp(s - m)
     p = jnp.where(mask, p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
+    if sink is not None:
+        l = l + jnp.exp(sink - m)
     o = _mxu_dot(p.astype(vs.dtype), vs, (((1,), (0,)), ((), ())))
     # fully-masked rows (padding, empty slots): l == 0 -> emit 0, not NaN
     return (o / jnp.where(l > 0, l, 1.0).astype(o.dtype)).astype(vs.dtype)
 
 
-def _flash_tile(qs, ks_t, vs_t, k0, r0, q_len, kv_len, g: int, m, l, acc):
+def _flash_tile(qs, ks_t, vs_t, k0, r0, q_len, kv_len, g: int, m, l, acc,
+                window: int = 0, k_lo=None):
     """One (row block, KV tile) step of the online-softmax (flash-
     combine) walk — the single source of the walk's math, shared
     verbatim by the kernel body and its dense twin (the bitwise pin
@@ -381,11 +414,15 @@ def _flash_tile(qs, ks_t, vs_t, k0, r0, q_len, kv_len, g: int, m, l, acc):
     the twin may walk a static tile count while the kernel walks only
     live tiles and the two stay bitwise-equal."""
     tile_kv = ks_t.shape[0]
-    vmask = (k0 + jax.lax.broadcasted_iota(jnp.int32, (tile_kv, 1), 0)
-             < kv_len)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (tile_kv, 1), 0)
+    vmask = k_pos < kv_len
+    if k_lo is not None:
+        # keys before the slot's first visible one: their pages were
+        # not copied (the windowed walk starts at ``k_lo``'s page)
+        vmask = vmask & (k_pos >= k_lo)
     vs_t = jnp.where(vmask, vs_t, 0)
     s = _mxu_dot(qs, ks_t, (((1,), (1,)), ((), ()))).astype(jnp.float32)
-    mask = _row_mask(r0, s.shape, k0, q_len, kv_len, g)
+    mask = _row_mask(r0, s.shape, k0, q_len, kv_len, g, window)
     s = jnp.where(mask, s, _MASK)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -398,11 +435,17 @@ def _flash_tile(qs, ks_t, vs_t, k0, r0, q_len, kv_len, g: int, m, l, acc):
     return m_new, l_new, acc_new
 
 
-def _flash_init(rows: int, dh: int):
+def _flash_init(rows: int, dh: int, sink=None):
     """Flash-combine state init: running max starts at the MASK value
     (not -inf — ``exp(_MASK - _MASK)`` must be a defined 1.0 for rows
     that never see a live key, so fully-masked rows emit 0, not NaN —
-    the same dead-row contract as ``_attend``)."""
+    the same dead-row contract as ``_attend``). With a SINK (``[rows,
+    1]`` float32 logits in the kernel's own scaling) the state starts
+    as if one key of that score and no value had been seen: ``m =
+    sink``, ``l = 1``, ``acc = 0``."""
+    if sink is not None:
+        return (sink, jnp.ones((rows, 1), jnp.float32),
+                jnp.zeros((rows, dh), jnp.float32))
     return (jnp.full((rows, 1), _MASK, jnp.float32),
             jnp.zeros((rows, 1), jnp.float32),
             jnp.zeros((rows, dh), jnp.float32))
@@ -413,7 +456,8 @@ def _flash_final(l, acc, dtype):
     return (acc / jnp.where(l > 0, l, 1.0)).astype(dtype)
 
 
-def _attend_tiled(qs, ks, vs, r0, q_len, kv_len, g: int, tile_kv: int):
+def _attend_tiled(qs, ks, vs, r0, q_len, kv_len, g: int, tile_kv: int,
+                  window: int = 0, sink=None):
     """One row block of the kernel's DENSE TWIN: query rows
     ``r0 .. r0+rows-1`` over the KV axis walked in ``tile_kv``-sized
     tiles through ``_flash_tile``. Walks every tile of the padded
@@ -421,29 +465,31 @@ def _attend_tiled(qs, ks, vs, r0, q_len, kv_len, g: int, tile_kv: int):
     ``_flash_tile``), and a row block past the slot's real rows comes
     out zero."""
     kv_max, dh = ks.shape
+    dv = vs.shape[1]
     n_tiles = -(-kv_max // tile_kv)
     pad = n_tiles * tile_kv - kv_max
     if pad:
         ks = jnp.concatenate(
             [ks, jnp.zeros((pad, dh), ks.dtype)], axis=0)
         vs = jnp.concatenate(
-            [vs, jnp.zeros((pad, dh), vs.dtype)], axis=0)
+            [vs, jnp.zeros((pad, dv), vs.dtype)], axis=0)
+    k_lo = _first_key(q_len, kv_len, window) if window else None
 
     def body(t, carry):
         k0 = t * tile_kv
         ks_t = jax.lax.dynamic_slice_in_dim(ks, k0, tile_kv)
         vs_t = jax.lax.dynamic_slice_in_dim(vs, k0, tile_kv)
         return _flash_tile(qs, ks_t, vs_t, k0, r0, q_len, kv_len, g,
-                           *carry)
+                           *carry, window=window, k_lo=k_lo)
 
     _, l, acc = jax.lax.fori_loop(0, n_tiles, body,
-                                  _flash_init(qs.shape[0], dh))
+                                  _flash_init(qs.shape[0], dv, sink))
     return _flash_final(l, acc, vs.dtype)
 
 
-def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
-            o_ref, k_scr, v_scr, m_scr, l_scr, acc_scr, sems, nxt_ref, *,
-            pps: int, page_size: int, g: int, tile_pages: int, rb: int):
+def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, *refs,
+            pps: int, page_size: int, g: int, tile_pages: int, rb: int,
+            window: int = 0, sinks: bool = False):
     """One slot, and the step's KV heads of it (all of them on a grid
     over slots alone): see the module docstring's loop nest. The K/V
     scratch is ``(2, tile_pages, heads, page_size, Dh)`` a pool — two
@@ -452,11 +498,22 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
     (head, row block)'s slice loaded and stored around each
     ``_flash_tile``. ``nxt_ref`` (SMEM) hands the next live step the
     first tile this one started for it: the step's number + 1 (0:
-    none), and the buffer it lands in."""
+    none), and the buffer it lands in.
+
+    ``window`` (static): a row sees its last ``window`` keys only; the
+    walk starts at the tile, and the copies at the page, of the slot's
+    first visible key (``_first_key``), so what lies behind the window
+    costs nothing. ``sinks``: one more scalar-prefetch operand, ``[H]``
+    float32 sink logits (``_flash_init``). K and V may differ in head
+    size (``q_ref`` the keys', ``o_ref`` the values')."""
+    sink_ref, refs = (refs[0], refs[1:]) if sinks else (None, refs)
+    (q_ref, kp_ref, vp_ref, o_ref, k_scr, v_scr, m_scr, l_scr, acc_scr,
+     sems, nxt_ref) = refs
     s, j = pl.program_id(0), pl.program_id(1)
     n_slots, head_steps = pl.num_programs(0), pl.num_programs(1)
     step = s * head_steps + j
     heads, n_rows, dh = o_ref.shape
+    dk = q_ref.shape[-1]
     qn = qlen_ref[s]
     tile_kv = tile_pages * page_size
 
@@ -469,6 +526,19 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
 
     def tile_live_pages(n_pages, t):
         return jnp.minimum(tile_pages, n_pages - t * tile_pages)
+
+    def first_key(slot):
+        # the first key the slot's rows see under the window
+        return _first_key(qlen_ref[slot],
+                          jnp.minimum(kvlen_ref[slot], pps * page_size),
+                          window)
+
+    def tile_first_page(slot, t, n):
+        # pages of tile t in front of the slot's first visible key:
+        # never copied (0 without a window)
+        if not window:
+            return 0
+        return jnp.clip(first_key(slot) // page_size - t * tile_pages, 0, n)
 
     def start_tile(slot, jh, n_pages, t, buf):
         # start the K and V copies of the live pages of tile t of
@@ -489,19 +559,27 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
                 vp_ref.at[layer, pl.ds(jh * heads, heads), page],
                 v_scr.at[buf, p], sems.at[1, buf]).start()
 
+        n = tile_live_pages(n_pages, t)
+        lo = tile_first_page(slot, t, n)
+
         def chunk(c, carry):
             for i in range(PAGE_UNROLL):
-                start_page(c * PAGE_UNROLL + i)
+                start_page(c * PAGE_UNROLL + i if not window
+                           else lo + c * PAGE_UNROLL + i)
             return carry
 
         def rest(p, carry):
             start_page(p)
             return carry
 
-        n = tile_live_pages(n_pages, t)
-        whole = n // PAGE_UNROLL
-        jax.lax.fori_loop(0, whole, chunk, 0)
-        jax.lax.fori_loop(whole * PAGE_UNROLL, n, rest, 0)
+        if not window:
+            whole = n // PAGE_UNROLL
+            jax.lax.fori_loop(0, whole, chunk, 0)
+            jax.lax.fori_loop(whole * PAGE_UNROLL, n, rest, 0)
+        else:
+            whole = (n - lo) // PAGE_UNROLL
+            jax.lax.fori_loop(0, whole, chunk, 0)
+            jax.lax.fori_loop(lo + whole * PAGE_UNROLL, n, rest, 0)
 
     def start_next_step(buf):
         # the slot's last tile computes: start the first tile of the
@@ -519,7 +597,8 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
 
         @pl.when(s2 < n_slots)
         def _():
-            start_tile(s2, j2, live_pages(s2)[1], 0, buf)
+            start_tile(s2, j2, live_pages(s2)[1],
+                       first_key(s2) // tile_kv if window else 0, buf)
             nxt_ref[0] = s2 * head_steps + j2 + 1
             nxt_ref[1] = buf
 
@@ -538,6 +617,9 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
         kn, n_pages = live_pages(s)
         n_tiles = pl.cdiv(kn, tile_kv)
         n_blocks = pl.cdiv(g * qn, rb)
+        # the walk's first tile: the slot's first visible key's
+        k_lo = first_key(s) if window else None
+        t_lo = k_lo // tile_kv if window else 0
 
         def wait_tile(t, buf):
             # a DMA semaphore counts bytes, so ONE wait a pool awaits a
@@ -549,20 +631,22 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
                 pltpu.make_async_copy(dst, dst, sems.at[lane, buf]).wait()
 
             n = tile_live_pages(n_pages, t)
+            lo = tile_first_page(s, t, n)
+            copied = n - lo if window else n
 
-            @pl.when(n == tile_pages)
+            @pl.when(copied == tile_pages)
             def _():
                 wait(k_scr, 0)
                 wait(v_scr, 1)
 
-            @pl.when(n < tile_pages)
+            @pl.when(copied < tile_pages)
             def _():
                 def wait_page(p, carry):
                     wait(k_scr, 0, p)
                     wait(v_scr, 1, p)
                     return carry
 
-                jax.lax.fori_loop(0, n, wait_page, 0)
+                jax.lax.fori_loop(lo, n, wait_page, 0)
 
         def rows(b):
             # a launch of one block (fewer rows than ROW_BLOCK, which
@@ -600,7 +684,13 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
             each_head(head)
 
         def init_block(h, b, r):
-            m_scr[h, r], l_scr[h, r], acc_scr[h, r] = _flash_init(rb, dh)
+            sink = None
+            if sinks:
+                h0 = (j * heads + h) * g
+                sink = _sink_rows([sink_ref[h0 + i] for i in range(g)],
+                                  b * rb, rb, g)
+            m_scr[h, r], l_scr[h, r], acc_scr[h, r] = _flash_init(
+                rb, dh, sink)
 
         each_block(0, n_blocks, init_block)
         # the step before may have started this one's first tile
@@ -609,10 +699,11 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
 
         @pl.when(jnp.logical_not(started))
         def _():
-            start_tile(s, j, n_pages, 0, buf0)
+            start_tile(s, j, n_pages, t_lo, buf0)
 
         def tile_body(t, carry):
-            buf = jax.lax.rem(buf0 + t, 2)
+            buf = jax.lax.rem(buf0 + t if not window else buf0 + t - t_lo,
+                              2)
 
             @pl.when(t + 1 < n_tiles)
             def _():
@@ -628,15 +719,15 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
                 # the head's tile is loaded inside the row-block loop:
                 # a span's row blocks are few and the load is VMEM's
                 m_scr[h, r], l_scr[h, r], acc_scr[h, r] = _flash_tile(
-                    q_ref[h, r], k_scr[buf, :, h].reshape(tile_kv, dh),
+                    q_ref[h, r], k_scr[buf, :, h].reshape(tile_kv, dk),
                     v_scr[buf, :, h].reshape(tile_kv, dh), t * tile_kv,
                     b * rb, qn, kn, g, m_scr[h, r], l_scr[h, r],
-                    acc_scr[h, r])
+                    acc_scr[h, r], window=window, k_lo=k_lo)
 
             each_block(0, n_blocks, flash_block)
             return carry
 
-        jax.lax.fori_loop(0, n_tiles, tile_body, 0)
+        jax.lax.fori_loop(t_lo, n_tiles, tile_body, 0)
 
         def final_block(h, b, r):
             o_ref[h, r] = _flash_final(l_scr[h, r], acc_scr[h, r],
@@ -652,9 +743,11 @@ def _kernel(layer_ref, qlen_ref, kvlen_ref, tab_ref, q_ref, kp_ref, vp_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("g", "tile_pages", "interpret"))
+                   static_argnames=("g", "tile_pages", "interpret",
+                                    "window", "head_major"))
 def _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables, g,
-                 tile_pages, interpret):
+                 tile_pages, interpret, window=0, sinks=None,
+                 head_major=False):
     """qs ``[S, Hkv, R, Dh]`` pre-scaled, rows (t, g)-ordered, ``R`` a
     multiple of its row block; returns the same shape. k_pages/v_pages
     are the STACKED pools ``[L, Hkv, P, ps, Dh]`` and ``layer`` ``[1]``
@@ -662,51 +755,74 @@ def _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables, g,
     HBM (``pl.ANY``) whole, nothing is sliced out. The grid is the
     slots, times the steps a slot's heads take (one wherever
     ``heads_per_step`` holds them all). The scratch shapes are the
-    whole VMEM story — O(tile) and O(rows) a head, never O(pps)."""
-    S, Hkv, R, Dh = qs.shape
+    whole VMEM story — O(tile) and O(rows) a head, never O(pps).
+    ``v_pages`` may hold another head size than ``k_pages`` (the
+    result's); ``window`` / ``sinks [H]`` f32 as ``_kernel`` takes them
+    (the sinks are one more scalar-prefetch operand). ``head_major``:
+    ``qs`` and the result are ``[Hkv, S, R, D]`` (what a gather of the
+    packed stream's rows a KV head gives: ``_blocked_launch``); a grid
+    step's blocks are the same."""
+    if head_major:
+        Hkv, S, R, Dh = qs.shape
+    else:
+        S, Hkv, R, Dh = qs.shape
+    Dv = v_pages.shape[-1]
     pps = tables.shape[1]
     page_size = k_pages.shape[3]
     tile_pages = min(int(tile_pages), pps)
-    heads = heads_per_step(Hkv, tile_pages, page_size, Dh, k_pages.dtype, R)
+    heads = heads_per_step(Hkv, tile_pages, page_size, Dh, k_pages.dtype, R,
+                           None if Dv == Dh else Dv)
     kernel = functools.partial(_kernel, pps=pps, page_size=page_size, g=g,
-                               tile_pages=tile_pages, rb=_row_block(R))
+                               tile_pages=tile_pages, rb=_row_block(R),
+                               window=int(window), sinks=sinks is not None)
     block = pl.BlockSpec((None, heads, R, Dh),
                          lambda s, h, *_: (s, h, 0, 0))
+    out_block = block if Dv == Dh else pl.BlockSpec(
+        (None, heads, R, Dv), lambda s, h, *_: (s, h, 0, 0))
+    out_shape = (S, Hkv, R, Dv)
+    if head_major:
+        block, out_block = (pl.BlockSpec(
+            (heads, None, R, d), lambda s, h, *_: (h, s, 0, 0))
+            for d in (Dh, Dv))
+        out_shape = (Hkv, S, R, Dv)
+    prefetch = (layer, q_len, kv_len, tables.reshape(-1))
+    if sinks is not None:
+        prefetch += (sinks.astype(jnp.float32),)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetch),
             grid=(S, Hkv // heads),
             in_specs=[
                 block,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=block,
+            out_specs=out_block,
             scratch_shapes=[
                 pltpu.VMEM((2, tile_pages, heads, page_size, Dh),
                            k_pages.dtype),
-                pltpu.VMEM((2, tile_pages, heads, page_size, Dh),
+                pltpu.VMEM((2, tile_pages, heads, page_size, Dv),
                            v_pages.dtype),
                 pltpu.VMEM((heads, R, 1), jnp.float32),
                 pltpu.VMEM((heads, R, 1), jnp.float32),
-                pltpu.VMEM((heads, R, Dh), jnp.float32),
+                pltpu.VMEM((heads, R, Dv), jnp.float32),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((2,), jnp.int32),
             ]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        out_shape=jax.ShapeDtypeStruct(qs.shape, k_pages.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, k_pages.dtype),
         interpret=interpret,
         # a stable name: how the kernel shows in lowered and compiled
         # program text (chip_smoke.py) and in a device trace (the
         # benchmark's reducer reads ``^ragged_paged_attention``)
         name="ragged_paged_attention",
-    )(layer, q_len, kv_len, tables.reshape(-1), qs, k_pages, v_pages)
+    )(*prefetch, qs, k_pages, v_pages)
 
 
 def _reference_impl(qs, k_pages, v_pages, q_len, kv_len, tables, g,
-                    tile_pages: int = 0):
+                    tile_pages: int = 0, window: int = 0, sinks=None):
     """Dense-gather reference with identical semantics: per slot,
     gather the table's pages and attend per kv head.
 
@@ -719,20 +835,29 @@ def _reference_impl(qs, k_pages, v_pages, q_len, kv_len, tables, g,
     head, row block) steps one after another like the kernel's grid,
     not batched: a batched dot is another XLA program than the
     kernel's, and at some shapes rounds differently in the last
-    place."""
+    place. ``window`` / ``sinks [H]`` as the kernel takes them; V may
+    hold another head size than K (the result's)."""
     S, Hkv, R, Dh = qs.shape
+    Dv = v_pages.shape[-1]
     pps = tables.shape[1]
     ps = k_pages.shape[2]
     kv_len = jnp.minimum(kv_len, pps * ps)     # the kernel's clamp
 
     def gathered(pages, h, tab):
-        return pages[h][tab].reshape(pps * ps, Dh)
+        return pages[h][tab].reshape(pps * ps, pages.shape[-1])
+
+    def sink_rows(h, r0, rows):
+        if sinks is None:
+            return None
+        return _sink_rows(sinks.astype(jnp.float32).reshape(Hkv, g)[h],
+                          r0, rows, g)
 
     if not tile_pages:
         def per_slot(q_s, qn, kn, tab):
             return jax.vmap(lambda qh, h: _attend(
                 qh, gathered(k_pages, h, tab), gathered(v_pages, h, tab),
-                qn, kn, g))(q_s, jnp.arange(Hkv))
+                qn, kn, g, window, sink_rows(h, 0, R)))(
+                    q_s, jnp.arange(Hkv))
 
         return jax.vmap(per_slot)(qs, q_len, kv_len, tables)
 
@@ -744,12 +869,13 @@ def _reference_impl(qs, k_pages, v_pages, q_len, kv_len, tables, g,
         qb = jax.lax.dynamic_slice_in_dim(qs[s, h], b * rb, rb)
         return _attend_tiled(qb, gathered(k_pages, h, tables[s]),
                              gathered(v_pages, h, tables[s]), b * rb,
-                             q_len[s], kv_len[s], g, tile_kv)
+                             q_len[s], kv_len[s], g, tile_kv, window,
+                             sink_rows(h, b * rb, rb))
 
     steps = jnp.stack(jnp.meshgrid(
         jnp.arange(S), jnp.arange(Hkv), jnp.arange(R // rb),
         indexing="ij"), -1).reshape(-1, 3)
-    return jax.lax.map(step, steps).reshape(S, Hkv, R, Dh)
+    return jax.lax.map(step, steps).reshape(S, Hkv, R, Dv)
 
 
 def _layer_pages(pages, layer):
@@ -763,7 +889,8 @@ def _layer_pages(pages, layer):
 
 def ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len, tables,
                            sm_scale=None, impl: str = "auto",
-                           kv_tile_pages=None, layer=None):
+                           kv_tile_pages=None, layer=None, window: int = 0,
+                           sinks=None):
     """One-launch attention for a mixed ragged batch over paged KV.
 
     q: ``[S, Tq, H, Dh]`` slot-major query spans (see module
@@ -787,11 +914,22 @@ def ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len, tables,
     (dense included — the kernel's bitwise twin); 0 = one softmax
     over the whole context: the one-shot reference on the dense path,
     the whole table in one tile on the kernel's.
+
+    Three arguments that default to "none" (a launch without them is
+    the program it always was): ``v_pages`` of ANOTHER HEAD SIZE than
+    ``k_pages`` (``q`` carries the keys', the result the values');
+    ``window``: a query sees its own position and the ``window - 1``
+    before it, and the walk skips the tiles (and the copies the pages)
+    wholly behind a slot's first visible key; ``sinks [H]``: a learned
+    logit a head (in the scaled scores' units) that joins the softmax's
+    denominator and takes no value.
     """
     if impl not in ("auto", "pallas", "dense"):
         raise ValueError(f"impl must be auto|pallas|dense, got {impl!r}")
     S, Tq, H, Dh = q.shape
     Hkv, _, page_size, _ = k_pages.shape[-4:]
+    Dv = v_pages.shape[-1]
+    extra = dict(window=int(window), sinks=sinks)
     if H % Hkv:
         raise ValueError(f"H={H} not a multiple of Hkv={Hkv}")
     G = H // Hkv
@@ -827,25 +965,27 @@ def ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len, tables,
         layer = jnp.asarray(layer, jnp.int32).reshape(1)
         out = _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len,
                            tables, g=G, tile_pages=tile,
-                           interpret=not _on_tpu())
+                           interpret=not _on_tpu(), **extra)
     else:
         out = _reference_impl(qs, _layer_pages(k_pages, layer),
                               _layer_pages(v_pages, layer), q_len, kv_len,
-                              tables, g=G, tile_pages=tile)
-    out = out[:, :, :rows].reshape(S, Hkv, Tq, G, Dh)
-    return out.transpose(0, 2, 1, 3, 4).reshape(S, Tq, H, Dh).astype(q.dtype)
+                              tables, g=G, tile_pages=tile, **extra)
+    out = out[:, :, :rows].reshape(S, Hkv, Tq, G, Dv)
+    return out.transpose(0, 2, 1, 3, 4).reshape(S, Tq, H, Dv).astype(q.dtype)
 
 
 def ragged_paged_attention_reference(q, k_pages, v_pages, q_len, kv_len,
-                                     tables, sm_scale=None):
+                                     tables, sm_scale=None, window: int = 0,
+                                     sinks=None):
     """The dense-gather formulation, directly (tests reach it via
     ``impl="dense"`` too)."""
     return ragged_paged_attention(q, k_pages, v_pages, q_len, kv_len,
-                                  tables, sm_scale=sm_scale, impl="dense")
+                                  tables, sm_scale=sm_scale, impl="dense",
+                                  window=window, sinks=sinks)
 
 
 def _packed_impl(q, k_pages, v_pages, tok_slot, tok_qoff, q_len, kv_len,
-                 tables, sm_scale):
+                 tables, sm_scale, window: int = 0, sinks=None):
     """Work-proportional PACKED formulation: attention computed
     directly on the tick's token stream — score work scales with the
     ``T`` real rows, not the ``S × Tq`` slot-major padding the kernel's
@@ -864,10 +1004,11 @@ def _packed_impl(q, k_pages, v_pages, tok_slot, tok_qoff, q_len, kv_len,
     # [:, tok_slot] would copy the gathered block a second time
     # (padding rows — slot sentinel S — clamp to slot 0 and are fully
     # masked below)
+    Dv = v_pages.shape[-1]
     sl = jnp.minimum(tok_slot, S - 1)
     tabs_t = tables[sl]                                     # [T, pps]
     ks = k_pages[:, tabs_t].reshape(Hkv, T, KV, Dh)
-    vs = v_pages[:, tabs_t].reshape(Hkv, T, KV, Dh)
+    vs = v_pages[:, tabs_t].reshape(Hkv, T, KV, Dv)
     kmask = (jax.lax.broadcasted_iota(jnp.int32, (T, KV), 1)
              < kv_len[sl][:, None])                         # [T, KV]
     # K needs no pre-zeroing: every garbage position's score is
@@ -884,15 +1025,22 @@ def _packed_impl(q, k_pages, v_pages, tok_slot, tok_qoff, q_len, kv_len,
     hi = (kv_len[sl] - q_len[sl] + tok_qoff)[:, None]
     mask = ((tok_slot < S)[:, None] & (tok_qoff < q_len[sl])[:, None]
             & (k_idx <= hi))                                # [T, KV]
+    if window:
+        mask = mask & (k_idx > hi - window)
     m4 = mask[:, None, None, :]
     s = jnp.where(m4, s, _MASK)
     m = jnp.max(s, axis=-1, keepdims=True)
+    if sinks is not None:
+        sink = sinks.astype(jnp.float32).reshape(1, Hkv, G, 1)
+        m = jnp.maximum(m, sink)
     p = jnp.exp(s - m)
     p = jnp.where(m4, p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
+    if sinks is not None:
+        l = l + jnp.exp(sink - m)
     o = jnp.einsum("tkgs,ktsd->tkgd", p.astype(vs.dtype), vs)
     o = o / jnp.where(l > 0, l, 1.0).astype(o.dtype)
-    return o.reshape(T, H, Dh).astype(q.dtype)
+    return o.reshape(T, H, Dv).astype(q.dtype)
 
 
 # A pool whose rows are narrower than the chip's 128 lanes cannot be
@@ -950,10 +1098,91 @@ def _lane_narrow(o, member, f):
                                member[None, :, None, None], axis=2)[:, :, 0]
 
 
+def _span_blocks(start, q_len, kv_len, tables, tok_slot, tok_qoff, T: int,
+                 bt: int):
+    """A tick's spans cut into VIRTUAL SLOTS of at most ``bt`` tokens
+    each, for the slot-major kernel: ``(q_len_v, kv_len_v, tables_v, idx
+    [NV, bt], inv [T])``. Slot ``s`` (rows ``start[s] .. start[s] +
+    q_len[s] - 1`` of the packed stream of ``T`` rows) becomes
+    ``ceil(q_len[s] / bt)`` of them, in order; block ``b`` of it holds
+    its tokens ``b·bt ..`` and sees the keys up to its own last token
+    (``kv_len_v``: bottom-right causal makes a block of a span exactly a
+    span of its own), over the slot's table row. ``NV = S + ceil(T /
+    bt)`` bounds their number; the rest are dead (``q_len_v`` 0).
+    ``idx`` is each virtual row's place in the stream (``T``: no row),
+    ``inv`` each stream row's place among the ``NV·bt`` virtual rows.
+
+    WHY: the kernel holds a slot's rows whole in one VMEM block, which a
+    512-token span of 64 heads is 32 times too large for
+    (``mla_paged_attention.py``'s docstring has the arithmetic); ``bt``
+    tokens are not, and a span re-walks its pages once a block as that
+    kernel's does."""
+    S = q_len.shape[0]
+    nv = S + -(-T // bt)
+    nb = (q_len + bt - 1) // bt                         # blocks a slot
+    ends = jnp.cumsum(nb)
+    first = ends - nb                                   # a slot's first
+    v = jnp.arange(nv, dtype=jnp.int32)
+    owner = jnp.searchsorted(ends, v, side="right").astype(jnp.int32)
+    o = jnp.minimum(owner, S - 1)
+    sub = (v - first[o]) * bt                           # tokens before it
+    qv = jnp.where(owner < S, jnp.clip(q_len[o] - sub, 0, bt), 0)
+    kvv = kv_len[o] - q_len[o] + sub + qv
+    j = jnp.arange(bt, dtype=jnp.int32)[None]
+    idx = jnp.where(j < qv[:, None], (start[o] + sub)[:, None] + j, T)
+    sl = jnp.minimum(tok_slot, S - 1)
+    inv = (first[sl] + tok_qoff // bt) * bt + tok_qoff % bt
+    return (qv.astype(jnp.int32), kvv.astype(jnp.int32), tables[o], idx,
+            inv.astype(jnp.int32))
+
+
+def _blocked_launch(q, k_pages, v_pages, tok_slot, tok_qoff, start, q_len,
+                    kv_len, tables, bt: int, sm_scale, impl, kv_tile_pages,
+                    layer, extra):
+    """``q [T, H, Dk]`` (the packed stream) through the kernel over
+    ``_span_blocks``' virtual slots: ``[T, H, Dv]``, padding rows zero.
+    The queries are gathered ONCE, a KV head at a time out of ``[Hkv, T,
+    G·Dk]``, straight into the (token, group)-ordered rows the kernel
+    walks (``[Hkv, NV, bt·G, Dk]``: ``head_major``), and the results
+    gathered back by ``inv``: two passes over the virtual rows, which
+    are 2.4 x the stream's at 48 slots and a 512-row span."""
+    T, H, Dk = q.shape
+    S = q_len.shape[0]
+    Hkv, _, page_size, _ = k_pages.shape[-4:]
+    G, Dv = H // Hkv, v_pages.shape[-1]
+    qv, kvv, tabs, idx, inv = _span_blocks(
+        start, q_len, kv_len, tables, tok_slot, tok_qoff, T, bt)
+    nv = idx.shape[0]
+    qh = (q * sm_scale).astype(q.dtype).reshape(T, Hkv, G * Dk)
+    qh = jnp.concatenate(
+        [qh.transpose(1, 0, 2), jnp.zeros((Hkv, 1, G * Dk), q.dtype)], 1)
+    qs = qh[:, idx].reshape(Hkv, nv, bt * G, Dk)    # rows (t, g)-ordered
+    use_pallas = impl == "pallas" or (impl == "auto" and _on_tpu())
+    if use_pallas:
+        tile = _tile_pages(tables.shape[1], page_size, Dk, k_pages.dtype,
+                           kv_tile_pages)
+        if layer is None:
+            k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+        out = _pallas_impl(
+            qs, k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1),
+            qv, kvv, tabs, g=G, tile_pages=tile, interpret=not _on_tpu(),
+            head_major=True, **extra)
+    else:
+        out = _reference_impl(
+            qs.transpose(1, 0, 2, 3), _layer_pages(k_pages, layer),
+            _layer_pages(v_pages, layer), qv, kvv, tabs, g=G,
+            tile_pages=int(kv_tile_pages or 0), **extra).transpose(1, 0, 2, 3)
+    o = out.reshape(Hkv, nv * bt, G * Dv)[:, inv]       # [Hkv, T, G·Dv]
+    o = jnp.where((tok_slot < S)[None, :, None], o, 0)
+    return o.transpose(1, 0, 2).reshape(T, H, Dv).astype(q.dtype)
+
+
 def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
                                   q_len, kv_len, tables, tq: int,
                                   sm_scale=None, impl: str = "auto",
-                                  kv_tile_pages=None, layer=None):
+                                  kv_tile_pages=None, layer=None,
+                                  window: int = 0, sinks=None,
+                                  block_tokens: int = 0, start=None):
     """Packed-layout entry for the serving tick: ``q [T, H, Dh]`` is
     the tick's token stream with per-token owner/offset metadata
     (``tok_slot [T]`` — ``S`` = padding sentinel; ``tok_qoff [T]``).
@@ -969,11 +1198,21 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
     ``layer`` as in ``ragged_paged_attention``: with it the pools are
     the stacked ``[L, Hkv, P, page_size, Dh]``. Pools with rows wider
     than ``Dh`` are lane-packed (``lane_pack_factor``).
+
+    ``window`` / ``sinks`` / a ``v_pages`` of another head size as
+    ``ragged_paged_attention`` takes them (with any of them the pools
+    are never lane-packed: ``q`` brings the keys' head size).
+    ``block_tokens`` (with ``start [S]``, each slot's first row in the
+    stream; a slot's rows are contiguous there): on the kernel's path a
+    launch of more than ``block_tokens`` query rows a slot is cut into
+    virtual slots of that many (``_span_blocks``), so the kernel's VMEM
+    does not grow with the chunk.
     """
     if impl not in ("auto", "pallas", "dense", "packed"):
         raise ValueError(
             f"impl must be auto|pallas|dense|packed, got {impl!r}")
     T, H, Dh = q.shape
+    extra = dict(window=int(window), sinks=sinks)
     if k_pages.shape[-1] != Dh:
         member, f = _lane_member(q, k_pages)
         o = ragged_paged_attention_packed(
@@ -993,7 +1232,13 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
     if impl == "packed" or (impl == "auto" and not _on_tpu()):
         return _packed_impl(q, _layer_pages(k_pages, layer),
                             _layer_pages(v_pages, layer), tok_slot,
-                            tok_qoff, q_len, kv_len, tables, sm_scale)
+                            tok_qoff, q_len, kv_len, tables, sm_scale,
+                            **extra)
+    if block_tokens and int(tq) > int(block_tokens):
+        return _blocked_launch(
+            q, k_pages, v_pages, tok_slot, tok_qoff,
+            jnp.asarray(start, jnp.int32), q_len, kv_len, tables,
+            int(block_tokens), sm_scale, impl, kv_tile_pages, layer, extra)
     # slot-major boundary: scatter the stream into the kernel's
     # [S, Tq] layout (row S+1 absorbs padding tokens), run the kernel,
     # gather back (padding reads the zero row)
@@ -1001,7 +1246,8 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
     qs = qs.at[tok_slot, tok_qoff].set(q)
     o = ragged_paged_attention(qs[:S], k_pages, v_pages, q_len, kv_len,
                                tables, sm_scale=sm_scale, impl=impl,
-                               kv_tile_pages=kv_tile_pages, layer=layer)
+                               kv_tile_pages=kv_tile_pages, layer=layer,
+                               **extra)
     o = jnp.concatenate([o, jnp.zeros((1,) + o.shape[1:], o.dtype)],
                         axis=0)
     return o[tok_slot, tok_qoff].astype(q.dtype)

@@ -109,6 +109,18 @@ def _page_pools(mod, cfg) -> tuple:
     return KV_POOLS if pools is None else tuple(pools(cfg))
 
 
+def init_cache(mod, cfg, total_pages: int, page_size: int, max_batch: int,
+               max_span: int):
+    """The family's cache pytree (``init_serving_pages``). A family
+    whose window layers keep RINGS is also told the most query rows a
+    slot brings to one tick (the engine's per-tick prefill budget):
+    with the config's window that sizes a ring, and nothing else does."""
+    kw = {"max_span": int(max_span)} if any(
+        k.cache == "window_pages" for k in _cache_kinds(mod, cfg)) else {}
+    return mod.init_serving_pages(cfg, total_pages, page_size,
+                                  max_batch=max_batch, **kw)
+
+
 from collections import OrderedDict
 
 # LRU-bounded: each entry pins a config + three jitted fns (and their
@@ -431,12 +443,18 @@ class ServingEngine:
         # yet, so what attaches, moves or rolls back pages is off
         kinds = _cache_kinds(self._mod, cfg)
         self._stateful = [k.name for k in kinds if k.cache == "slot_rows"]
-        if self._stateful:
+        # a kind that keeps the last tokens of a WINDOW in a ring of
+        # pages a slot (sized by the config's window and the chunk, not
+        # by the context): a prefix's pages cannot rebuild it either
+        self._windowed = [k.name for k in kinds
+                          if k.cache == "window_pages"]
+        if self._stateful or self._windowed:
             if speculative is not None:
                 raise ValueError(
                     f"speculative decoding is not available for a model "
-                    f"with per-slot state ({self._stateful} layers): a "
-                    f"rejected draft's state cannot be rolled back")
+                    f"with per-slot state ({self._stateful + self._windowed}"
+                    f" layers): a rejected draft's state cannot be rolled "
+                    f"back")
             prefix_cache = False    # so: no chains, no cold tier
         self._attn_impl = attn_impl
         self._max_new_cap = int(max_new_tokens_cap)
@@ -553,8 +571,8 @@ class ServingEngine:
         import jax
         with setup_span("serving.setup.init.cache"):
             self._cache = jax.block_until_ready(dict(  # noqa: PT002 — the set-up span holds the allocation, once an engine
-                self._mod.init_serving_pages(
-                    cfg, total_pages, page_size, max_batch=max_batch)))
+                init_cache(self._mod, cfg, total_pages, page_size,
+                           max_batch, self._budget)))
         # its page pools by name, and whether they are the K and V pools
         # that chain export / adopt and the cold tier carry
         self._pools = _page_pools(self._mod, cfg)
@@ -570,6 +588,15 @@ class ServingEngine:
                 state_layers=len(self._stateful),
                 attn_layers=sum(k.cache == "pages" for k in kinds))
             pool_names = {p.name for p in self._pools}
+            if self._windowed:
+                self._window = int(cfg.sliding_window)
+                self._tick_layers["window_layers"] = len(self._windowed)
+                # the rings, by the names the family gives them: they
+                # come with the slots and no allocator counts them
+                rings = set(self._mod.cache_window_pools(cfg))
+                self._window_pool_bytes = sum(
+                    int(self._cache[name].nbytes) for name in rings)
+                pool_names |= rings
             self._slot_state_bytes = sum(
                 int(a.nbytes) for name, a in self._cache.items()
                 if name not in pool_names)
@@ -712,6 +739,13 @@ class ServingEngine:
                 f"{what} is not available for a model with per-slot "
                 f"state ({self._stateful} layers): a chain of pages does "
                 f"not carry the state its prefix left behind")
+        if self._windowed:
+            self.metrics.inc_labeled("chain_refused", pool="window_pages")
+            raise RuntimeError(
+                f"{what} is not available for a model with window rings "
+                f"({len(self._windowed)} window layers): a chain of the "
+                f"full layers' pages does not carry the window layers' "
+                f"last tokens")
         if not self._kv_pools:
             # the chain blob and the cold tier's entries are K and V
             # pages; a family with another pool is refused by name
@@ -897,6 +931,8 @@ class ServingEngine:
             g["cold_tier"] = self._cold.stats()
         if self._tick_layers:
             g["slot_state_bytes"] = self._slot_state_bytes
+        if self._windowed:
+            g["window_pool_bytes"] = self._window_pool_bytes
         return g
 
     def snapshot(self) -> dict:
@@ -1574,8 +1610,30 @@ class ServingEngine:
                 rows=tq * (self._cfg.num_attention_heads // kv_heads))
         return self._page_copies[tq]
 
+    def _window_counts(self, launches) -> dict:
+        """What the WINDOW layers' launches read and score, one layer's
+        worth (a reader multiplies by ``window_layers``): over
+        ``launches`` (``[(q_len, kv_len)]`` arrays of the slots with a
+        query row), ``window_kv_tokens`` the keys a launch must read,
+        ``min(kv_len, W - 1 + q_len)`` a slot, and ``window_attn_pairs``
+        the (query token, key) pairs it scores, ``min(position + 1, W)``
+        a row."""
+        W = self._window
+        tokens = pairs = 0
+        for q, kv in launches:
+            q, kv = q.astype(np.int64), kv.astype(np.int64)
+            tokens += int(np.minimum(kv, W - 1 + q).sum())
+            # rows whose position lies under the window see it all
+            short = np.clip(W - 1 - (kv - q), 0, q)
+            pairs += int((short * (kv - q + 1) + short * (short - 1) // 2
+                          + (q - short) * W).sum())
+        self.metrics.inc("window_kv_tokens", tokens)
+        self.metrics.inc("window_attn_pairs", pairs)
+        return dict(window_kv_tokens=tokens, window_attn_pairs=pairs)
+
     def _count_tick(self, rows: int, rows_real: int, kv_tokens: int,
-                    walks, tq: int = 1, attn_pairs: int = 0) -> dict:
+                    walks, tq: int = 1, attn_pairs: int = 0,
+                    q_lens=None) -> dict:
         """What a tick launches against what it needs, counted where
         the tick's arrays are built: into the counters (operators) and,
         returned, into the ``serving.tick`` span's args (the profiler's
@@ -1610,11 +1668,18 @@ class ServingEngine:
         if self._stateful:
             self.metrics.inc("slot_state_bytes_moved",
                              2 * live_slots * self._state_bytes_per_slot)
+        window = {}
+        if self._windowed:
+            # ``q_lens``: the main launch's query rows a live slot; a
+            # fused step after it brings one
+            window = self._window_counts(
+                [(np.ones_like(w) if i or q_lens is None else q_lens, w)
+                 for i, w in enumerate(walks)])
         return dict(rows=rows, rows_real=rows_real, kv_tokens=kv_tokens,
                     attn_pairs=attn_pairs,
                     live_slots=live_slots, kv_pages=kv_pages,
                     kv_pages_table=table, kv_page_copies=copies,
-                    **self._tick_layers)
+                    **window, **self._tick_layers)
 
     def _record_tick(self, tk: _Tick, t1: float) -> None:
         """Per-tick evidence, at the tick's completion (caller holds the
@@ -1804,6 +1869,8 @@ class ServingEngine:
             self.metrics.inc("prefix_misses")
         elif self._stateful:
             self.metrics.inc("prefix_bypassed_stateful")
+        elif self._windowed:
+            self.metrics.inc("prefix_bypassed_window")
         req.prefilling = True
         req.chunk_done = 0
         req.table_row = self.scheduler.tables[slot].copy()
@@ -2113,7 +2180,7 @@ class ServingEngine:
                                    for j in range(1, tail + 1)], tq=tq,
             attn_pairs=int((q_len.astype(np.int64) * (
                 2 * kv_len.astype(np.int64) - q_len + 1) // 2).sum())
-            + tail_tokens)
+            + tail_tokens, q_lens=q_len[q_len > 0])
         # the state the NEXT build reads, advanced before the launch
         for slot, req in live:
             if slot not in drafts:

@@ -135,7 +135,14 @@ class ServingMetrics:
     needed; kv_page_copies — the copies the attention kernel starts to
     walk those pages, all attention layers counted: over
     kv_pages_walked x those layers, the copies a page (one a pool
-    where a grid step holds every KV head); slot_state_bytes_moved — for a model whose layers keep
+    where a grid step holds every KV head); window_kv_tokens,
+    window_attn_pairs — for a model with WINDOW attention layers, one
+    such layer's worth: the keys its launches must read (``min(kv_len,
+    window - 1 + q_len)`` a slot) and the pairs they score; the
+    window layers' rings are the gauge ``window_pool_bytes``;
+    prefix_bypassed_window — requests admitted where prefix reuse is
+    off because a prefix's pages cannot rebuild a window ring;
+    slot_state_bytes_moved — for a model whose layers keep
     per-slot state, live slots x the state bytes a slot x 2: what the
     launches had to read once and write once of it), and the tick the
     engine keeps in flight ahead of the host
@@ -200,6 +207,8 @@ class ServingMetrics:
                 "moe_pairs_absent", "moe_experts_touched",
                 "moe_experts_held", "tick_live_slots", "kv_pages_walked", "kv_pages_table",
                 "kv_page_copies", "slot_state_bytes_moved", "prefix_bypassed_stateful",
+                "prefix_bypassed_window", "window_kv_tokens",
+                "window_attn_pairs",
                 "ticks_ahead",
                 "inflight_drains", "overrun_slot_ticks")
     HISTOGRAMS = ("queue_wait_s", "ttft_s", "decode_step_s",

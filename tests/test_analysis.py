@@ -133,7 +133,7 @@ def test_serving_targets_trace_a_family_through_its_three_functions(
     # a verify target only where the family can verify: what its layer
     # kinds keep tells, not its name
     cfg_cls = getattr(mod, SERVING_FAMILIES[model])
-    stateful = any(k.cache == "slot_rows"
+    stateful = any(k.cache in ("slot_rows", "window_pages")
                    for k in _cache_kinds(mod, cfg_cls.tiny()))
     assert any("[verify" in n for n in targets) == (not stateful)
 

@@ -987,3 +987,128 @@ def test_joyai_train_step_fits_the_chip(topo, chip, monkeypatch):
         f"splash residuals remat keeps, out [{B}, {H}, {T}, {Dv}] bf16 + "
         f"logsumexp [{B}, {H}, {T}] f32 x {cfg.num_hidden_layers} layers "
         f"= {kept / 2 ** 30:.2f} GiB, are new with PR 45 (13.89 before)")
+
+
+# MiMo-V2-Flash's serving cell (``mimov2-serve-mixedlen``): window layers
+# in a ring a slot beside full layers in the paged pool, k rows of 192
+# (held at 256 lanes) beside v rows of 128, the ragged kernel with a
+# window, a sink and a span in virtual slots of 16 tokens
+
+def _mimo_cell():
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if os.path.join(root, "benchmark") not in sys.path:
+        sys.path.insert(0, os.path.join(root, "benchmark"))
+    from harness import manifest
+    cell = manifest.Cell(manifest.load_manifest(), "mimov2-serve-mixedlen")
+    cfg, mod = cell.family.program_config(cell.model)
+    return cell, cfg, mod
+
+
+def test_mimo_window_pool_has_the_bytes_the_configuration_states():
+    """``init_serving_pages``' two kinds of pool at the cell's geometry:
+    the rings' bytes follow the slots, the window and the chunk (11
+    pages a slot) and are what the configuration file's ``sizes``
+    states; so are the paged pool's."""
+    cell, cfg, mod = _mimo_cell()
+    geo, sizes = cell.workload["engine"], cell.config["sizes"]
+    cache = jax.eval_shape(lambda: mod.init_serving_pages(
+        cfg, geo["total_pages"], geo["page_size"],
+        max_batch=geo["max_batch"], max_span=geo["prefill_chunk"]))
+    ring = mod.window_ring_pages(cfg, geo["page_size"], geo["prefill_chunk"])
+    assert ring == sizes["window_ring_pages_per_slot"] == 11
+    assert cache[mod.K_WINDOW].shape == (5, 8, 48 * 11 + 1, 64, 256)
+    assert cache[mod.V_WINDOW].shape == (5, 8, 48 * 11 + 1, 64, 128)
+    assert cache[mod.K_FULL].shape == (2, 4, 13057, 64, 256)
+    assert cache[mod.V_FULL].shape == (2, 4, 13057, 64, 128)
+
+    def nbytes(*names):
+        return sum(math.prod(cache[n].shape) * 2 for n in names)
+
+    assert nbytes(mod.K_WINDOW, mod.V_WINDOW) == sizes[
+        "window_pool_bytes_48_slots_in_pool"]
+    assert nbytes(mod.K_FULL, mod.V_FULL) == sizes[
+        "full_pool_bytes_13057_pages_in_pool"]
+    # counted at the published 192-wide key row
+    assert sizes["window_pool_bytes_48_slots_published"] == (
+        nbytes(mod.K_WINDOW, mod.V_WINDOW) - 2 * math.prod(
+            cache[mod.K_WINDOW].shape) // 4 - 5 * 8 * 64 * 320 * 2)
+
+
+@pytest.mark.parametrize("program", ["tick", "tick_tail3", "block4"])
+def test_mimo_cell_programs_hold_both_pools_once(topo, chip, monkeypatch,
+                                                 program):
+    """The engine's jitted tick (48 slots + one 512-row chunk, plain and
+    with a fused tail of 3) and fused block of 4 at the cell's geometry
+    and published widths, all seven layers: they compile for the
+    described chip (k rows of 192 enter the kernel padded to 256 lanes,
+    v rows at 128; the sinks as a float32 scalar-prefetch operand; a
+    span in virtual slots of 16 tokens), both kinds of pool are aliased
+    (held once), the tick holds at least 60 % of the chip's 15.75 GiB
+    and fits it, and the share's counts come back as one more ``s32[4]``
+    result beside the tokens."""
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    from paddle_tpu.serving import engine as E
+    monkeypatch.setattr(R, "_on_tpu", lambda: True)
+    monkeypatch.setattr(G, "_on_tpu", lambda: True)
+    cell, cfg, mod = _mimo_cell()
+    geo = cell.workload["engine"]
+    S, chunk, ps = geo["max_batch"], geo["prefill_chunk"], geo["page_size"]
+    pps = -(-(geo["max_prompt_len"] + geo["max_new_tokens_cap"] - 1) // ps)
+    params = jax.eval_shape(lambda: cell.family.make_params(cell.model, 0))
+    cache = jax.eval_shape(lambda: mod.init_serving_pages(
+        cfg, geo["total_pages"], ps, max_batch=S, max_span=chunk))
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    f32 = functools.partial(sds, dtype=jnp.float32)
+    samp = dict(temp=f32((S,)), top_p=f32((S,)), top_k=i32((S,)),
+                key=sds((S, 2), jnp.uint32), produced=i32((S,)))
+    E._JIT_CACHE.clear()        # jit objects of THIS precision context
+    tick, block = E._jit_step_fns(mod, cfg, "auto")
+    if program.startswith("tick"):
+        T = S + chunk
+        meta = dict(tok_slot=i32((T,)), tok_pos=i32((T,)),
+                    tok_page=i32((T,)), tok_off=i32((T,)),
+                    tok_qoff=i32((T,)), q_len=i32((S,)), kv_len=i32((S,)),
+                    last=i32((S,)), tables=i32((S, pps)),
+                    tail_live=sds((S,), jnp.bool_), cur_tok=i32((S,)),
+                    **samp)
+        compiled = tick.lower(
+            *on_chip((params, i32((T,)), meta, cache)), tq=chunk,
+            decode_tail=3 if program == "tick_tail3" else 0).compile()
+        results = 4             # toks, logits, counts, cur_tok'
+    else:
+        compiled = block.lower(
+            *on_chip((params, i32((S,)), i32((S,)), i32((S, pps)), cache)),
+            num_steps=4, sampling=on_chip(samp)).compile()
+        results = 3             # toks, counts, cur_tok'
+    E._JIT_CACHE.clear()
+    text = compiled.as_text()
+    assert "ragged_paged_attention" in text and "held_experts_matmul" in text
+    outs = jax.tree.leaves(compiled.out_info)
+    leaves = jax.tree.leaves(cache)
+    assert len(outs) == results + len(leaves)
+    counts, nxt = outs[results - 2:results]
+    assert (counts.shape, counts.dtype) == ((4,), jnp.int32)
+    assert (nxt.shape, nxt.dtype) == ((S,), jnp.int32)
+    assert [(o.shape, o.dtype) for o in outs[results:]] == [
+        (a.shape, a.dtype) for a in leaves]
+    mem = compiled.memory_analysis()
+    pools = sum(math.prod(a.shape) * a.dtype.itemsize for a in leaves)
+    assert mem.alias_size_in_bytes >= pools
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"[mimo {program}] arguments {mem.argument_size_in_bytes} "
+          f"alias {mem.alias_size_in_bytes} temp {mem.temp_size_in_bytes} "
+          f"live {live} = {live / 2 ** 30:.2f} GiB")
+    assert 0.60 * 15.75 * 2 ** 30 <= live < 15.75 * 2 ** 30
+    # no pool is copied around a layer: temporaries far under one pool
+    assert mem.temp_size_in_bytes < math.prod(
+        cache[mod.K_WINDOW].shape) * 2

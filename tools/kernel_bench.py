@@ -266,7 +266,12 @@ def ragged_cells():
 
     cells = {}
     for w, model, geo in _serving_cells():
-        if "kv_lora_rank" in model:
+        if "kv_lora_rank" in model or "hybrid_layer_pattern" in model:
+            # latent pages are ``mla_cells()``'s; a cell whose window
+            # and full layers launch the kernel at two geometries (k / v
+            # head sizes 192 / 128, 8 and 4 KV heads, a span in virtual
+            # slots) has its described-chip compile of the whole tick
+            # (tests/test_chip_compile.py) and no sweep here yet
             continue
         heads, kv = model["num_attention_heads"], model["num_key_value_heads"]
         dh = model.get("head_dim") or model["hidden_size"] // heads
@@ -314,11 +319,17 @@ def moe_cells():
     for w, model, geo in _serving_cells():
         if "n_routed_experts" in model:             # a share of the experts
             held = model["n_routed_experts"]
-            moe = dict(held=held, top_k=model["moe_topk"],
+            # (the two families that hold a share name these three
+            # differently: LongCat's keys, then MiMo-V2's)
+            freq = model.get("moe_layer_freq")
+            moe = dict(held=held,
+                       top_k=model.get("moe_topk")
+                       or model["num_experts_per_tok"],
                        routed=(model.get("router_experts", held)
                                + model.get("zero_expert_num", 0)),
-                       width=model["expert_ffn_hidden_size"],
-                       layers=model["num_layers"])
+                       width=model.get("expert_ffn_hidden_size")
+                       or model["moe_intermediate_size"],
+                       layers=sum(freq) if freq else model["num_layers"])
         elif model.get("num_experts"):
             held = model["num_experts"]
             moe = dict(held=held, routed=held,

@@ -123,8 +123,12 @@ def main(names) -> dict:
         pages = eng.get("total_pages") or S * pps + 1
         params = jax.eval_shape(
             lambda: mod.init_params(cfg, jax.random.PRNGKey(0)))
+        # a family with window rings sizes them by the chunk
+        # (``init_cache``; a parent from before it has no such family)
+        init = getattr(E, "init_cache", None)
         cache = jax.eval_shape(
-            lambda: mod.init_serving_pages(cfg, pages, ps, max_batch=S))
+            lambda: init(mod, cfg, pages, ps, S, chunk) if init
+            else mod.init_serving_pages(cfg, pages, ps, max_batch=S))
         i32 = functools.partial(sds, dtype=jnp.int32)
         f32 = functools.partial(sds, dtype=jnp.float32)
         samp = dict(temp=f32((S,)), top_p=f32((S,)), top_k=i32((S,)),
