@@ -571,6 +571,8 @@ def _walk(params, h, cache, meta, cfg: GraniteHybridConfig, tq, attn_impl):
     tok_page = meta["tok_page"][:, None]                            # [T, 1]
     tok_off = meta["tok_off"][:, None]
     q_len, last = meta["q_len"], meta["last"]
+    plan = _llama.tick_plan(meta, tq, cfg.num_attention_heads,
+                            cache["k_pages"])
     ssd_impl = attn_impl if attn_impl in ("pallas", "dense") else "auto"
     # what the Mamba layers need of the packing, once for all of them
     plans = ssd_plan(tok_slot, tok_pos, q_len.shape[0], cfg.mamba_chunk_size)
@@ -590,7 +592,7 @@ def _walk(params, h, cache, meta, cfg: GraniteHybridConfig, tq, attn_impl):
                     q[0], kp2, vp2, tok_slot, tok_qoff, q_len,
                     meta["kv_len"], meta["tables"], tq=tq,
                     sm_scale=cfg.attention_multiplier, impl=attn_impl,
-                    layer=layer)
+                    layer=layer, plan=plan)
             return o[None].astype(q.dtype)
 
         h = _attn_op(lp, h, cfg, attn_fn)

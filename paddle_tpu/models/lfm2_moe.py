@@ -420,6 +420,8 @@ def _walk(params, h, cache, meta, cfg: Lfm2MoeConfig, tq, attn_impl):
     tok_page = meta["tok_page"][:, None]                            # [T, 1]
     tok_off = meta["tok_off"][:, None]
     q_len, last = meta["q_len"], meta["last"]
+    plan = _llama.tick_plan(meta, tq, cfg.num_attention_heads,
+                            cache["k_pages"])
 
     def attn_layer(lp, h, kp, vp, layer):
         cell = {}
@@ -435,7 +437,7 @@ def _walk(params, h, cache, meta, cfg: Lfm2MoeConfig, tq, attn_impl):
                 o = ragged_paged_attention_packed(
                     q[0], kp2, vp2, tok_slot, tok_qoff, q_len,
                     meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl,
-                    layer=layer)
+                    layer=layer, plan=plan)
             return o[None].astype(q.dtype)
 
         h = _attn_op(lp, h, positions, cfg, attn_fn)

@@ -1429,6 +1429,20 @@ def _one_kind_walk(block_fn=None):
                    block_fn=block_fn if block_fn is not None else _block)
 
 
+def tick_plan(meta, tq, heads: int, pool, tables=None, block_tokens: int = 0):
+    """What the ragged kernel's path needs of a tick's packing
+    (``ops/pallas/ragged_paged_attention.py: stream_plan``) for launches
+    of ``heads`` query heads over ``pool``: every walk makes it ONCE a
+    tick outside its layers and hands it to each launch. A slot's rows
+    are contiguous in the stream, up to ``meta['last']``. ``tables``: a
+    launch's own page table (None: ``meta['tables']``)."""
+    from ..ops.pallas.ragged_paged_attention import stream_plan
+    return stream_plan(
+        meta["tok_slot"], meta["tok_qoff"], meta["q_len"], meta["kv_len"],
+        meta["tables"] if tables is None else tables, tq, heads, pool,
+        start=meta["last"] - meta["q_len"] + 1, block_tokens=block_tokens)
+
+
 def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl, block_fn):
     """The layer walk of a model whose layers are ONE kind, each holding
     K and V: ``params['layers']`` scanned, the two stacked pools in the
@@ -1442,6 +1456,7 @@ def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl, block_fn):
     k_pages, v_pages = cache["k_pages"], cache["v_pages"]
     tok_slot = meta["tok_slot"]
     tok_qoff = meta["tok_qoff"]
+    plan = tick_plan(meta, tq, cfg.num_attention_heads, k_pages)
     positions = meta["tok_pos"][None]
     heads = jnp.arange(k_pages.shape[1], dtype=jnp.int32)[None, :]  # [1, Hkv]
     tok_page = meta["tok_page"][:, None]                            # [T, 1]
@@ -1467,14 +1482,14 @@ def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl, block_fn):
             cell["kp"], cell["vp"] = kp2, vp2
             # 2) one ragged launch over the pages (span KV included):
             # the packed entry keeps score work proportional to the T
-            # real rows off-TPU and scatters to the kernel's slot-major
-            # layout on TPU; the kernel reads the layer's pages where
-            # they lie in the stacked pool
+            # real rows off-TPU and copies each slot's rows straight
+            # into the kernel's blocks on TPU; the kernel reads the
+            # layer's pages where they lie in the stacked pool
             with jax.named_scope("ragged_attn"):
                 o = ragged_paged_attention_packed(
                     q[0], kp2, vp2, tok_slot, tok_qoff, meta["q_len"],
                     meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl,
-                    layer=layer)
+                    layer=layer, plan=plan)
             return o[None].astype(q.dtype)
 
         h = block_fn(lp, h, positions, cfg, attn_fn)

@@ -519,7 +519,6 @@ def _walk(params, h, cache, meta, cfg: MimoV2FlashConfig, tq, attn_impl):
     tok_pos = meta["tok_pos"]
     positions = tok_pos[None]
     q_len, kv_len = meta["q_len"], meta["kv_len"]
-    start = meta["last"] - q_len + 1
     real = tok_slot < S
     ps = cache[K_FULL].shape[-2]
     pps = meta["tables"].shape[1]
@@ -545,6 +544,12 @@ def _walk(params, h, cache, meta, cfg: MimoV2FlashConfig, tq, attn_impl):
               WINDOW: (jnp.arange(S, dtype=jnp.int32)[:, None] * ring
                        + jnp.arange(pps, dtype=jnp.int32)[None] % ring)}
 
+    # what the kernel's path needs of the packing, once a kind for all
+    # its layers (a span enters as virtual slots of BLOCK_TOKENS tokens)
+    plans = {kind: _llama.tick_plan(
+        meta, tq, cfg.num_attention_heads, cache[pool], tables[kind],
+        BLOCK_TOKENS) for kind, pool in ((FULL, K_FULL), (WINDOW, K_WINDOW))}
+
     def attention(kind, lp, h, kp, vp, layer):
         heads = jnp.arange(cfg.kv_heads(kind), dtype=jnp.int32)[None]
         q, k, v = _qkv(lp, h, positions, cfg, kind, pad)
@@ -559,7 +564,7 @@ def _walk(params, h, cache, meta, cfg: MimoV2FlashConfig, tq, attn_impl):
             sm_scale=cfg.sm_scale, impl=attn_impl,
             kv_tile_pages=tiles[kind], layer=layer,
             window=W if kind == WINDOW else 0, sinks=lp.get("sinks"),
-            block_tokens=BLOCK_TOKENS, start=start)
+            plan=plans[kind])
         return _attn_out(lp, h, o[None].astype(h.dtype), cfg), kp, vp
 
     def run(group, carry, i):

@@ -26,7 +26,7 @@ from ..incubate.moe.functional import moe_ffn, moe_ffn_share
 from ..ops.pallas.flash_attention import remat_layer
 from .layer_walk import (COUNTS, EXPERT_COUNTERS, expert_counts,
                          with_tick_counts)
-from .llama import _mm, rms_norm, rope
+from .llama import _mm, rms_norm, rope, tick_plan
 
 # what a serving tick hands back beside its tokens
 TICK_COUNTERS = EXPERT_COUNTERS
@@ -425,6 +425,7 @@ def _walk(params, h, cache, meta, cfg: Qwen2MoeConfig, tq, attn_impl):
     k_pages, v_pages = cache["k_pages"], cache["v_pages"]
     E, top_k = cfg.num_experts, cfg.num_experts_per_tok
     tok_slot, tok_qoff = meta["tok_slot"], meta["tok_qoff"]
+    plan = tick_plan(meta, tq, cfg.num_attention_heads, k_pages)
     positions = meta["tok_pos"][None]
     real = tok_slot < meta["q_len"].shape[0]
     heads = jnp.arange(k_pages.shape[1], dtype=jnp.int32)[None, :]  # [1, Hkv]
@@ -455,7 +456,7 @@ def _walk(params, h, cache, meta, cfg: Qwen2MoeConfig, tq, attn_impl):
                 o = ragged_paged_attention_packed(
                     q[0], kp2, vp2, tok_slot, tok_qoff, meta["q_len"],
                     meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl,
-                    layer=layer)
+                    layer=layer, plan=plan)
             return o[None].astype(q.dtype)
 
         def routed_fn(lp, x, cfg):

@@ -113,11 +113,45 @@ Off-TPU the kernel runs in interpreter mode (CPU-testable, like the
 int8/flash kernels); ``impl="dense"`` selects the reference gather
 formulation with identical semantics — ``impl="auto"`` uses the kernel
 on TPU and the reference elsewhere.
+
+**The serving tick's way in** (``ragged_paged_attention_packed``): the
+tick's queries arrive PACKED, ``[T, H, Dh]``, one row a token, a slot's
+rows contiguous. On the kernel's path ONE function lays them out
+(``_stream_launch``), for whole slots and for spans cut into virtual
+slots (``_span_blocks``) alike, from a plan of the packing that a walk
+of many layers makes once a tick (``stream_plan``: index arrays from
+the tick's metadata and the pool's KV heads alone; ``models/llama.py:
+tick_plan`` is the one place the families' walks make it; where a
+slot's rows start and whether spans are cut are the PLAN's to say, not
+the entry's). Every (virtual) slot's FIRST token goes
+into its block of the kernel's input ``[S, Hkv, R, Dh]`` by one row
+gather, padded out to the block's ``R`` rows in the same write: all a
+decode slot needs. A slot that holds a SPAN gets its block as one
+window of the stream, re-laid at ITS size a KV head at a time with rows
+(token, group)-ordered, copied in place from the slot's first row on:
+a loop over the spans alone: one or two a tick, or every drafting slot
+of a speculative tick (rows past a slot's own are its neighbours':
+finite, masked, never read back). Behind the kernel the stream's rows
+are gathered straight out of its result by the inverse map (``(slot ·
+Hkv + head) · R + offset · G + g``), a row a (token, head): nothing of
+the buffer's size stands between. A decode launch's block is one
+stream row: no pad, no loop. A span CUT into virtual slots (a buffer
+about the stream's size) is one token gather a KV head into head-major
+blocks and one back, as it was before PR 48: alone the loop and the
+row gather are faster there too, in MiMo's whole tick 1.5 ms a span
+tick slower (PERF.md section 6). The buffer of whole slots is ``S · tq``
+tokens for a stream of ``S + tq``: 26 times the data in the chat
+cell's span tick, where the slot-major scatter, the scale, four
+transposing copies and a pad that stood here until PR 48 were eight
+passes over it a layer, 8 of a span tick's 21 ms (PERF.md section 6).
+The slot-major API (``ragged_paged_attention``) keeps its own layout
+for its callers and tests.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple, Optional
 
 import numpy as np
 import jax
@@ -127,7 +161,8 @@ from jax.experimental.pallas import tpu as pltpu
 from . import on_tpu as _on_tpu
 
 __all__ = ["ragged_paged_attention", "ragged_paged_attention_reference",
-           "ragged_paged_attention_packed", "default_kv_tile_pages",
+           "ragged_paged_attention_packed", "stream_plan", "StreamPlan",
+           "default_kv_tile_pages",
            "vmem_scratch_bytes", "ROW_BLOCK", "TILED_ULP_BOUND",
            "tiled_ulp_error"]
 
@@ -760,8 +795,8 @@ def _pallas_impl(qs, k_pages, v_pages, layer, q_len, kv_len, tables, g,
     result's); ``window`` / ``sinks [H]`` f32 as ``_kernel`` takes them
     (the sinks are one more scalar-prefetch operand). ``head_major``:
     ``qs`` and the result are ``[Hkv, S, R, D]`` (what a gather of the
-    packed stream's rows a KV head gives: ``_blocked_launch``); a grid
-    step's blocks are the same."""
+    packed stream's rows a KV head gives: a span cut into virtual
+    slots, ``_stream_launch``); a grid step's blocks are the same."""
     if head_major:
         Hkv, S, R, Dh = qs.shape
     else:
@@ -1099,18 +1134,20 @@ def _lane_narrow(o, member, f):
 
 
 def _span_blocks(start, q_len, kv_len, tables, tok_slot, tok_qoff, T: int,
-                 bt: int):
-    """A tick's spans cut into VIRTUAL SLOTS of at most ``bt`` tokens
-    each, for the slot-major kernel: ``(q_len_v, kv_len_v, tables_v, idx
-    [NV, bt], inv [T])``. Slot ``s`` (rows ``start[s] .. start[s] +
-    q_len[s] - 1`` of the packed stream of ``T`` rows) becomes
-    ``ceil(q_len[s] / bt)`` of them, in order; block ``b`` of it holds
-    its tokens ``b·bt ..`` and sees the keys up to its own last token
-    (``kv_len_v``: bottom-right causal makes a block of a span exactly a
-    span of its own), over the slot's table row. ``NV = S + ceil(T /
-    bt)`` bounds their number; the rest are dead (``q_len_v`` 0).
-    ``idx`` is each virtual row's place in the stream (``T``: no row),
-    ``inv`` each stream row's place among the ``NV·bt`` virtual rows.
+                 bt: int, tq: int):
+    """A tick's spans as the VIRTUAL SLOTS the kernel is launched over,
+    at most ``bt`` tokens each: ``(q_len_v, kv_len_v, tables_v, start_v
+    [NV], slot_v [T], off_v [T])``. Where ``bt >= tq`` nothing is cut:
+    the virtual slots ARE the slots. Else slot ``s`` (rows ``start[s] ..
+    start[s] + q_len[s] - 1`` of the packed stream of ``T`` rows)
+    becomes ``ceil(q_len[s] / bt)`` of them, in order; block ``b`` of it
+    holds its tokens ``b·bt ..`` and sees the keys up to its own last
+    token (``kv_len_v``: bottom-right causal makes a block of a span
+    exactly a span of its own), over the slot's table row. ``NV = S +
+    ceil(T / bt)`` bounds their number; the rest are dead (``q_len_v``
+    0). ``start_v`` is the stream row of each virtual slot's first token
+    (``T``: none), ``slot_v`` / ``off_v`` each stream row's virtual slot
+    (``NV``: a padding row) and its place in it.
 
     WHY: the kernel holds a slot's rows whole in one VMEM block, which a
     512-token span of 64 heads is 32 times too large for
@@ -1118,6 +1155,9 @@ def _span_blocks(start, q_len, kv_len, tables, tok_slot, tok_qoff, T: int,
     tokens are not, and a span re-walks its pages once a block as that
     kernel's does."""
     S = q_len.shape[0]
+    if bt >= tq:
+        return (q_len, kv_len, tables, jnp.where(q_len > 0, start, T),
+                tok_slot, tok_qoff)
     nv = S + -(-T // bt)
     nb = (q_len + bt - 1) // bt                         # blocks a slot
     ends = jnp.cumsum(nb)
@@ -1128,70 +1168,218 @@ def _span_blocks(start, q_len, kv_len, tables, tok_slot, tok_qoff, T: int,
     sub = (v - first[o]) * bt                           # tokens before it
     qv = jnp.where(owner < S, jnp.clip(q_len[o] - sub, 0, bt), 0)
     kvv = kv_len[o] - q_len[o] + sub + qv
-    j = jnp.arange(bt, dtype=jnp.int32)[None]
-    idx = jnp.where(j < qv[:, None], (start[o] + sub)[:, None] + j, T)
     sl = jnp.minimum(tok_slot, S - 1)
-    inv = (first[sl] + tok_qoff // bt) * bt + tok_qoff % bt
-    return (qv.astype(jnp.int32), kvv.astype(jnp.int32), tables[o], idx,
-            inv.astype(jnp.int32))
+    return (qv.astype(jnp.int32), kvv.astype(jnp.int32), tables[o],
+            jnp.where(qv > 0, start[o] + sub, T).astype(jnp.int32),
+            jnp.where(tok_slot < S, first[sl] + tok_qoff // bt,
+                      nv).astype(jnp.int32),
+            (tok_qoff % bt).astype(jnp.int32))
 
 
-def _blocked_launch(q, k_pages, v_pages, tok_slot, tok_qoff, start, q_len,
-                    kv_len, tables, bt: int, sm_scale, impl, kv_tile_pages,
-                    layer, extra):
-    """``q [T, H, Dk]`` (the packed stream) through the kernel over
-    ``_span_blocks``' virtual slots: ``[T, H, Dv]``, padding rows zero.
-    The queries are gathered ONCE, a KV head at a time out of ``[Hkv, T,
-    G·Dk]``, straight into the (token, group)-ordered rows the kernel
-    walks (``[Hkv, NV, bt·G, Dk]``: ``head_major``), and the results
-    gathered back by ``inv``: two passes over the virtual rows, which
-    are 2.4 x the stream's at 48 slots and a 512-row span."""
+class StreamPlan(NamedTuple):
+    """What the kernel's path needs of a tick's packing, from its
+    metadata and the launch's head counts alone, so a walk of many
+    layers makes it ONCE a tick (``stream_plan``): the (virtual) slots
+    the kernel is launched over (``_span_blocks``) and where their rows
+    lie in the stream and in the kernel's result. WHOLE slots:
+    ``rows_in [NV]`` the stream row of each slot's first token, ``spans
+    [NV]`` the slots that hold MORE than one token, compacted in front,
+    ``n_spans`` their count, ``rows_out`` each stream row's slot
+    (``[T]``: a decode launch) or its rows of the flat result (``[T,
+    Hkv, G]``). A span CUT into virtual slots (``cut``): ``rows_in [NV,
+    bt]`` every virtual row's stream row (``T``: none), ``rows_out [T]``
+    each stream row's place among the ``NV·bt`` virtual rows.
+    ``real [T, 1, 1]`` the rows that are tokens, ``bt`` the tokens a
+    slot's block holds, ``heads`` the ``(kv heads, query heads a kv
+    head)`` it was made for."""
+    q_len: jax.Array
+    kv_len: jax.Array
+    tables: jax.Array
+    rows_in: jax.Array
+    spans: Optional[jax.Array]
+    n_spans: Optional[jax.Array]
+    rows_out: jax.Array
+    real: jax.Array
+    bt: int
+    heads: tuple
+    cut: bool
+
+
+def stream_plan(tok_slot, tok_qoff, q_len, kv_len, tables, tq: int,
+                heads: int, pool, start=None,
+                block_tokens: int = 0) -> StreamPlan:
+    """The ``StreamPlan`` of a tick (metadata as
+    ``ragged_paged_attention_packed`` takes it) for launches of
+    ``heads`` query heads over ``pool``, the K pool as the kernel reads
+    it (``[..., Hkv, P, page_size, D]``; lane-packed or not: its own KV
+    heads are the kernel's). ``start [S]``: each slot's first row in
+    the stream (a slot without a row: anything); without it, read from
+    ``tok_slot``. ``block_tokens``: a launch of more query rows a slot
+    is cut into virtual slots of that many (``_span_blocks``), so the
+    kernel's VMEM does not grow with the chunk."""
+    tok_slot = jnp.asarray(tok_slot, jnp.int32)
+    tok_qoff = jnp.asarray(tok_qoff, jnp.int32)
+    q_len = jnp.asarray(q_len, jnp.int32)
+    kv_len = jnp.asarray(kv_len, jnp.int32)
+    tables = jnp.asarray(tables, jnp.int32)
+    T, S = tok_slot.shape[0], tables.shape[0]
+    Hkv = int(pool.shape[-4])
+    G = int(heads) // Hkv
+    if start is None:
+        start = jnp.full((S + 1,), T, jnp.int32).at[tok_slot].min(
+            jnp.arange(T, dtype=jnp.int32))[:S]
+    start = jnp.clip(jnp.asarray(start, jnp.int32), 0, T)
+    bt = min(int(block_tokens) or int(tq), int(tq))
+    qv, kvv, tabs, start_v, slot_v, off_v = _span_blocks(
+        start, q_len, kv_len, tables, tok_slot, tok_qoff, T, bt, int(tq))
+    nv, R = qv.shape[0], _block_rows(bt * G)
+    real = (slot_v < nv)[:, None, None]                 # over [T, H, Dv]
+    slot_c = jnp.minimum(slot_v, nv - 1)
+    if bt < int(tq):
+        j = jnp.arange(bt, dtype=jnp.int32)[None]
+        rows_in = jnp.where(j < qv[:, None], start_v[:, None] + j, T)
+        return StreamPlan(qv, kvv, tabs, rows_in, None, None,
+                          slot_c * bt + off_v, real, bt, (Hkv, G), True)
+    if bt == 1:
+        rows_out = slot_c
+    else:
+        rows_out = ((slot_c * (Hkv * R) + off_v * G)[:, None, None]
+                    + jnp.arange(Hkv, dtype=jnp.int32)[None, :, None] * R
+                    + jnp.arange(G, dtype=jnp.int32)[None, None, :])
+    span = qv > 1
+    at = jnp.where(span, jnp.cumsum(span) - 1, nv)
+    spans = jnp.zeros((nv,), jnp.int32).at[at].set(
+        jnp.arange(nv, dtype=jnp.int32), mode="drop")
+    return StreamPlan(qv, kvv, tabs, jnp.minimum(start_v, T - 1), spans,
+                      jnp.sum(span).astype(jnp.int32), rows_out, real, bt,
+                      (Hkv, G), False)
+
+
+def _rows(x, at):
+    """``x[at]`` along axis 0 for indices that ARE in bounds: the bare
+    gather, none of the wrap-around and clamp arithmetic indexing puts
+    in front of it (a layer loop would repeat it a layer)."""
+    return jax.lax.gather(
+        x, at[..., None], jax.lax.GatherDimensionNumbers(
+            offset_dims=tuple(range(at.ndim, at.ndim + x.ndim - 1)),
+            collapsed_slice_dims=(0,), start_index_map=(0,)),
+        (1,) + x.shape[1:], mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def _block_rows(rows: int) -> int:
+    """``rows`` query rows a (slot, kv head) as whole row blocks."""
+    return rows + -rows % _row_block(rows)
+
+
+def _stream_launch(q, k_pages, v_pages, plan: StreamPlan, sm_scale, impl,
+                   kv_tile_pages, layer, extra):
+    """``q [T, H, Dk]`` (the packed stream) through the kernel, or its
+    dense twin, over the plan's (virtual) slots: ``[T, H, Dv]``, padding
+    rows zero. WHOLE slots, where the buffer is many times the stream
+    (``S · tq`` tokens for ``S + tq``): ONE write of the kernel's query
+    buffer in front of it (``[NV, Hkv, R, Dk]``): every slot's first
+    token, a row gather of the stream, padded out to the block's ``R``
+    rows; then the slots that hold a SPAN, and they alone, get their
+    block (``[Hkv, R, Dk]``) as ONE window of the stream re-laid at ITS
+    size, a KV head at a time with rows (token, group)-ordered (``[Hkv,
+    T·G, Dk]``), from the slot's first row on (a slot's rows are
+    contiguous in the stream), copied in place. What a window holds past
+    its slot's rows are the next slots' (finite, masked by the kernel,
+    never read back). Behind the kernel the stream's rows are gathered
+    straight out of its result by the inverse map, a row a (token,
+    head): nothing of the buffer's size stands between. A decode
+    launch's block IS a stream row: no pad, no span, and its results are
+    put back by slot. A span CUT into virtual slots, where the buffer
+    is about the stream's size: one token gather a KV head out of
+    ``[Hkv, T + 1, G·Dk]`` straight into head-major blocks and one back
+    (in MiMo's whole tick that launch is 1.5 ms a span tick FASTER than
+    the loop and the row gather, which win when timed alone: PERF.md
+    section 6, PR 48)."""
     T, H, Dk = q.shape
-    S = q_len.shape[0]
     Hkv, _, page_size, _ = k_pages.shape[-4:]
     G, Dv = H // Hkv, v_pages.shape[-1]
-    qv, kvv, tabs, idx, inv = _span_blocks(
-        start, q_len, kv_len, tables, tok_slot, tok_qoff, T, bt)
-    nv = idx.shape[0]
-    qh = (q * sm_scale).astype(q.dtype).reshape(T, Hkv, G * Dk)
-    qh = jnp.concatenate(
-        [qh.transpose(1, 0, 2), jnp.zeros((Hkv, 1, G * Dk), q.dtype)], 1)
-    qs = qh[:, idx].reshape(Hkv, nv, bt * G, Dk)    # rows (t, g)-ordered
+    nv, bt = plan.q_len.shape[0], plan.bt
+    R = _block_rows(bt * G)
+    if plan.heads != (Hkv, G) or plan.rows_out.shape[0] != T:
+        raise ValueError(
+            f"a plan for {plan.rows_out.shape[0]} rows of {plan.heads} "
+            f"(kv heads, group) cannot place a stream of {T} rows of "
+            f"{(Hkv, G)}")
+    if plan.cut:
+        qh = (q * sm_scale).astype(q.dtype).reshape(T, Hkv, G * Dk)
+        qh = jnp.concatenate(
+            [qh.transpose(1, 0, 2), jnp.zeros((Hkv, 1, G * Dk), q.dtype)], 1)
+        qs = qh[:, plan.rows_in].reshape(Hkv, nv, bt * G, Dk)
+        qs = jnp.pad(qs, ((0, 0), (0, 0), (0, R - bt * G), (0, 0)))
+    else:
+        # every slot's FIRST token, one row gather; the kernel's block
+        # holds it in front of R - G rows that only a span fills
+        qs = (_rows(q, plan.rows_in) * sm_scale).astype(q.dtype)
+        qs = qs.reshape(nv, Hkv, G, Dk)
+        if bt > 1:
+            qs = jnp.pad(qs, ((0, 0), (0, 0), (0, R - G), (0, 0)))
+            qh = (q * sm_scale).astype(q.dtype).reshape(T, Hkv, G, Dk)
+            qh = qh.transpose(1, 0, 2, 3).reshape(Hkv, T * G, Dk)
+            qh = jnp.pad(qh, ((0, 0), (0, R), (0, 0)))
+
+            def copy_span(i, qs):
+                # a span's block is ONE window of the head-major stream
+                v = plan.spans[i]
+                rows = jax.lax.dynamic_slice(
+                    qh, (0, plan.rows_in[v] * G, 0), (Hkv, R, Dk))
+                return jax.lax.dynamic_update_slice(qs, rows[None],
+                                                    (v, 0, 0, 0))
+
+            qs = jax.lax.fori_loop(0, plan.n_spans, copy_span, qs)
     use_pallas = impl == "pallas" or (impl == "auto" and _on_tpu())
     if use_pallas:
-        tile = _tile_pages(tables.shape[1], page_size, Dk, k_pages.dtype,
-                           kv_tile_pages)
+        tile = _tile_pages(plan.tables.shape[1], page_size, Dk,
+                           k_pages.dtype, kv_tile_pages)
+        # one kernel body: a single layer's 4-D pool enters as a
+        # one-layer stack (a bitcast) read at layer 0
         if layer is None:
             k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
         out = _pallas_impl(
             qs, k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1),
-            qv, kvv, tabs, g=G, tile_pages=tile, interpret=not _on_tpu(),
-            head_major=True, **extra)
+            plan.q_len, plan.kv_len, plan.tables, g=G, tile_pages=tile,
+            interpret=not _on_tpu(), head_major=plan.cut, **extra)
     else:
+        major = (1, 0, 2, 3) if plan.cut else (0, 1, 2, 3)
         out = _reference_impl(
-            qs.transpose(1, 0, 2, 3), _layer_pages(k_pages, layer),
-            _layer_pages(v_pages, layer), qv, kvv, tabs, g=G,
-            tile_pages=int(kv_tile_pages or 0), **extra).transpose(1, 0, 2, 3)
-    o = out.reshape(Hkv, nv * bt, G * Dv)[:, inv]       # [Hkv, T, G·Dv]
-    o = jnp.where((tok_slot < S)[None, :, None], o, 0)
-    return o.transpose(1, 0, 2).reshape(T, H, Dv).astype(q.dtype)
+            qs.transpose(major), _layer_pages(k_pages, layer),
+            _layer_pages(v_pages, layer), plan.q_len, plan.kv_len,
+            plan.tables, g=G, tile_pages=int(kv_tile_pages or 0),
+            **extra).transpose(major)
+    # the way back is the plan's: a cut span's virtual rows a KV head, a
+    # decode launch's slots, else the flat result's rows
+    if plan.cut:
+        o = out[:, :, :bt * G].reshape(Hkv, nv * bt, G * Dv)
+        o = o[:, plan.rows_out].transpose(1, 0, 2)
+    else:
+        o = _rows(
+            out.reshape((nv, H, Dv) if bt == 1 else (nv * Hkv * R, Dv)),
+            plan.rows_out)
+    return jnp.where(plan.real, o.reshape(T, H, Dv), 0).astype(q.dtype)
 
 
 def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
                                   q_len, kv_len, tables, tq: int,
                                   sm_scale=None, impl: str = "auto",
                                   kv_tile_pages=None, layer=None,
-                                  window: int = 0, sinks=None,
-                                  block_tokens: int = 0, start=None):
+                                  window: int = 0, sinks=None, plan=None):
     """Packed-layout entry for the serving tick: ``q [T, H, Dh]`` is
     the tick's token stream with per-token owner/offset metadata
-    (``tok_slot [T]`` — ``S`` = padding sentinel; ``tok_qoff [T]``).
-    Returns ``[T, H, Dh]`` (padding rows zero).
+    (``tok_slot [T]`` — ``S`` = padding sentinel; ``tok_qoff [T]``); a
+    slot's rows are CONTIGUOUS in it, in ``tok_qoff`` order, as the
+    engine packs them (padding rows anywhere between slots). Returns
+    ``[T, H, Dh]`` (padding rows zero).
 
     impl: "auto" — the work-proportional packed formulation off-TPU,
-    the Pallas kernel (scatter to the slot-major layout at the
-    boundary) on TPU; "pallas"/"dense" force the slot-major kernel /
-    reference; "packed" forces the packed formulation.
+    the Pallas kernel on TPU (``_stream_launch``: each slot's rows
+    copied straight into the kernel's block, its results gathered
+    straight back); "pallas"/"dense" force the kernel / its
+    dense reference over that same boundary; "packed" forces the packed
+    formulation.
     ``kv_tile_pages`` rides through to the slot-major walk selection
     (None = geometry auto — the serving tick passes nothing and a
     100k-token table picks the tiled walk by itself on TPU).
@@ -1202,11 +1390,12 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
     ``window`` / ``sinks`` / a ``v_pages`` of another head size as
     ``ragged_paged_attention`` takes them (with any of them the pools
     are never lane-packed: ``q`` brings the keys' head size).
-    ``block_tokens`` (with ``start [S]``, each slot's first row in the
-    stream; a slot's rows are contiguous there): on the kernel's path a
-    launch of more than ``block_tokens`` query rows a slot is cut into
-    virtual slots of that many (``_span_blocks``), so the kernel's VMEM
-    does not grow with the chunk.
+    ``plan``: the kernel's path reads the packing from it alone
+    (``stream_plan`` of the same metadata and ``tq`` over ``k_pages``,
+    which also says where a slot's rows start and whether a span is cut
+    into virtual slots): a walk of many layers makes it once a tick
+    outside them, where the index arithmetic is then not repeated a
+    layer. Without one it is made here, from the metadata, un-cut.
     """
     if impl not in ("auto", "pallas", "dense", "packed"):
         raise ValueError(
@@ -1219,38 +1408,21 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, tok_slot, tok_qoff,
             _lane_widen(q, member, f), k_pages, v_pages, tok_slot, tok_qoff,
             q_len, kv_len, tables, tq,
             sm_scale=sm_scale or 1.0 / float(np.sqrt(Dh)), impl=impl,
-            kv_tile_pages=kv_tile_pages, layer=layer)
+            kv_tile_pages=kv_tile_pages, layer=layer, plan=plan)
         return _lane_narrow(o, member, f)
-    S = tables.shape[0]
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(Dh))
-    tok_slot = jnp.asarray(tok_slot, jnp.int32)
-    tok_qoff = jnp.asarray(tok_qoff, jnp.int32)
-    q_len = jnp.asarray(q_len, jnp.int32)
-    kv_len = jnp.asarray(kv_len, jnp.int32)
-    tables = jnp.asarray(tables, jnp.int32)
     if impl == "packed" or (impl == "auto" and not _on_tpu()):
-        return _packed_impl(q, _layer_pages(k_pages, layer),
-                            _layer_pages(v_pages, layer), tok_slot,
-                            tok_qoff, q_len, kv_len, tables, sm_scale,
-                            **extra)
-    if block_tokens and int(tq) > int(block_tokens):
-        return _blocked_launch(
-            q, k_pages, v_pages, tok_slot, tok_qoff,
-            jnp.asarray(start, jnp.int32), q_len, kv_len, tables,
-            int(block_tokens), sm_scale, impl, kv_tile_pages, layer, extra)
-    # slot-major boundary: scatter the stream into the kernel's
-    # [S, Tq] layout (row S+1 absorbs padding tokens), run the kernel,
-    # gather back (padding reads the zero row)
-    qs = jnp.zeros((S + 1, int(tq), H, Dh), q.dtype)
-    qs = qs.at[tok_slot, tok_qoff].set(q)
-    o = ragged_paged_attention(qs[:S], k_pages, v_pages, q_len, kv_len,
-                               tables, sm_scale=sm_scale, impl=impl,
-                               kv_tile_pages=kv_tile_pages, layer=layer,
-                               **extra)
-    o = jnp.concatenate([o, jnp.zeros((1,) + o.shape[1:], o.dtype)],
-                        axis=0)
-    return o[tok_slot, tok_qoff].astype(q.dtype)
+        return _packed_impl(
+            q, _layer_pages(k_pages, layer), _layer_pages(v_pages, layer),
+            jnp.asarray(tok_slot, jnp.int32), jnp.asarray(tok_qoff, jnp.int32),
+            jnp.asarray(q_len, jnp.int32), jnp.asarray(kv_len, jnp.int32),
+            jnp.asarray(tables, jnp.int32), sm_scale, **extra)
+    if plan is None:
+        plan = stream_plan(tok_slot, tok_qoff, q_len, kv_len, tables, tq, H,
+                           k_pages)
+    return _stream_launch(q, k_pages, v_pages, plan, sm_scale, impl,
+                          kv_tile_pages, layer, extra)
 
 
 # ---------------------------------------------------------------------------

@@ -280,6 +280,32 @@ def _scatters_in_place(compiled_text, line):
             and '"aliasing_operands":{"lists":[]}' not in line)
 
 
+_TICK_TEXTS = {}
+
+
+def _mistral_tick_text(chip, monkeypatch, tq):
+    """The chat cell's whole tick at ``tq`` query rows a slot (two
+    layers, the cell's own pool), compiled for the described chip once
+    a module."""
+    from paddle_tpu.models import llama as L
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    # the packed entry asks the backend, and sees the CPU here
+    monkeypatch.setattr(R, "_on_tpu", lambda: True)
+    if tq not in _TICK_TEXTS:
+        # the cell's own pool: a layer's pages (112 MiB) are then larger
+        # than the kernel's query rows (32 x 128 tokens, 32 MiB)
+        cfg, shapes = _mistral_tick_shapes(tq, 2, CHAT["pages"])
+
+        # the pool as ONE donated pytree, as the engine's jitted wrapper
+        # has it
+        def serving_tick(params, tokens, meta, cache):
+            return L.serving_tick_cache(params, tokens, meta, cache, cfg,
+                                        tq=tq)
+
+        _TICK_TEXTS[tq] = chip(serving_tick, *shapes, donate=(3,))
+    return _TICK_TEXTS[tq]
+
+
 @pytest.mark.parametrize("tq", [1, 128])
 def test_serving_tick_holds_the_pool_once(chip, monkeypatch, tq):
     """The whole tick, compiled for the described chip: the KV pool is
@@ -290,20 +316,8 @@ def test_serving_tick_holds_the_pool_once(chip, monkeypatch, tq):
     ``dynamic-update-slice`` of a layer's pages, no relayout in front
     of the kernel, no whole-pool copy into the donated buffers at the
     end; and the program's temporaries stay under one pool."""
-    from paddle_tpu.models import llama as L
-    from paddle_tpu.ops.pallas import ragged_paged_attention as R
-    # the packed entry asks the backend, and sees the CPU here
-    monkeypatch.setattr(R, "_on_tpu", lambda: True)
-    # the cell's own pool: a layer's pages (112 MiB) are then larger
-    # than the kernel's slot-major queries (32 x 128 rows, 64 MiB in f32)
     layers, pages = 2, CHAT["pages"]
-    cfg, shapes = _mistral_tick_shapes(tq, layers, pages)
-
-    # the pool as ONE donated pytree, as the engine's jitted wrapper has it
-    def serving_tick(params, tokens, meta, cache):
-        return L.serving_tick_cache(params, tokens, meta, cache, cfg, tq=tq)
-
-    text = chip(serving_tick, *shapes, donate=(3,))
+    text = _mistral_tick_text(chip, monkeypatch, tq)
     from chip_smoke import kernels_in
     assert kernels_in(text.compiled)["ragged_paged_attention"] == 1
     layer_pages = HKV * pages * PAGE * DH * 2     # bytes of one layer's K
@@ -318,6 +332,51 @@ def test_serving_tick_holds_the_pool_once(chip, monkeypatch, tq):
     assert not moved, f"the tick moves a layer's pages or more: {moved}"
     assert text.memory.alias_size_in_bytes >= 2 * layers * layer_pages
     assert text.memory.temp_size_in_bytes < layers * layer_pages
+
+
+def _written_results(compiled_text, nbytes, scope):
+    """``opcode name`` of every instruction OUTSIDE fused computations
+    and outside the Mosaic kernels whose ``op_name`` holds ``scope`` and
+    whose result holds an array of ``nbytes`` or more that it WRITES (a
+    tuple, its elements and a bitcast move nothing; a gather the
+    compiler expands into a loop of in-place updates is that ``while``,
+    one pass)."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", compiled_text))
+    out, skip = [], False
+    for line in compiled_text.splitlines():
+        if line and not line[0].isspace():      # a computation's header
+            skip = line.split(" ", 1)[0].lstrip("%") in fused
+        m = _HLO_RESULT.match(line)
+        if (skip or not m or scope not in line
+                or m.group(3) in ("custom-call", "tuple", "bitcast",
+                                  "get-tuple-element", "parameter")):
+            continue
+        if any(_ITEMSIZE[d] * math.prod(int(x) for x in dims.split(","))
+               >= nbytes for d, dims in _HLO_ARRAY.findall(m.group(2))):
+            out.append(f"{m.group(3)} {m.group(1)}")
+    return out
+
+
+def test_span_tick_passes_over_the_query_buffer_at_most_three_times(
+        chip, monkeypatch):
+    """The chat cell's span tick (``T = 32 + 128``, ``tq = 128``): the
+    kernel's query buffer (32 slots x 128 tokens x 32 heads x 128, 33.6
+    MB for a stream of 1.3) is written ONCE a layer in front of the
+    kernel (every slot's first token, padded out to its block), a
+    span's rows copied into it in place (the update and the loop that
+    holds it are the other two names), and nothing of its size is
+    written behind it. Until PR 48 eight results of that size a layer
+    stood under ``ragged_attn`` outside the kernel (a zero fill, a
+    scatter, the scale, four transposing copies and a pad: 8 of a span
+    tick's ~21 ms); three is where this guard stands."""
+    tq = CHAT["span"]
+    text = _mistral_tick_text(chip, monkeypatch, tq)
+    nbytes = CHAT["slots"] * tq * H * DH * 2
+    passes = _written_results(text.compiled, nbytes, "ragged_attn")
+    assert 1 <= len(passes) <= 3, passes
+    # the yardstick finds what it is to find: the kernel's own result is
+    # of that size, under that scope, and not counted
+    assert re.search(r"custom-call\(.*ragged_attn", text.compiled)
 
 
 # the three serving cells' tick programs as the ENGINE jits them

@@ -417,11 +417,14 @@ def test_the_packed_entry_cuts_a_span_into_blocks(impl, bt):
     real = tok_slot < S_
     qp[real] = np.asarray(q)[tok_slot[real], tok_qoff[real]]
     want = _brute(args, 6, sinks)
+    meta = (jnp.asarray(tok_slot), jnp.asarray(tok_qoff), q_len, kv_len,
+            tables)
+    plan = R.stream_plan(*meta, Tq, q.shape[2], kp, start=jnp.asarray(start),
+                         block_tokens=bt)
     got = np.asarray(R.ragged_paged_attention_packed(
-        jnp.asarray(qp), kp, vp, jnp.asarray(tok_slot),
-        jnp.asarray(tok_qoff), q_len, kv_len, tables, tq=Tq, impl=impl,
+        jnp.asarray(qp), kp, vp, *meta, tq=Tq, impl=impl,
         kv_tile_pages=None if impl == "packed" else 2, window=6,
-        sinks=sinks, block_tokens=bt, start=jnp.asarray(start)))
+        sinks=sinks, plan=plan))
     assert np.abs(got[real] - want[tok_slot[real], tok_qoff[real]]
                   ).max() < 1e-5
     assert np.abs(got[~real]).max() == 0

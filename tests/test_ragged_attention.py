@@ -313,6 +313,147 @@ def test_packed_matches_slot_major():
     np.testing.assert_array_equal(np.asarray(out_l), np.asarray(out_p))
 
 
+def _mixed_tick(seed, S, tq, H, Hkv, Dh, Dv=None, layers=None, ps=4,
+                pps=6):
+    """A tick's packed stream as the engine packs it, every kind of row
+    in it: spans of 1, 7 and ``tq`` rows (slots 0, 1, 2), a second
+    decode row in the LAST slot, every other slot dead, and padding
+    rows in front of the first slot, between slots and behind the last.
+    ``(q [T, H, Dh], kp, vp, tok_slot, tok_qoff, q_len, kv_len, tables,
+    start)``; ``layers``: stacked pools."""
+    rng = np.random.RandomState(seed)
+    ql = np.zeros((S,), np.int32)
+    ql[[0, 1, 2, S - 1]] = 1, 7, tq, 1
+    kl = np.where(ql > 0, ql + rng.randint(0, pps * ps - tq, (S,)), 0)
+    tok_slot, tok_qoff, start = [S], [0], np.zeros((S,), np.int32)
+    for s in range(S):
+        start[s] = len(tok_slot)
+        tok_slot += [s] * int(ql[s]) + [S] * (s in (0, 2))
+        tok_qoff += list(range(int(ql[s]))) + [0] * (s in (0, 2))
+    tok_slot, tok_qoff = tok_slot + [S, S], tok_qoff + [0, 0]
+    P = S * pps + 1
+    tables = rng.permutation(P - 1)[: S * pps].reshape(S, pps) + 1
+    for s in range(S):
+        tables[s, -(-int(kl[s]) // ps):] = 0            # TRASH past the span
+    lead = () if layers is None else (layers,)
+    draw = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.randn(*shape).astype(np.float32))
+    i32 = lambda a: jnp.asarray(np.asarray(a, np.int32))  # noqa: E731
+    return (draw(len(tok_slot), H, Dh), draw(*lead, Hkv, P, ps, Dh),
+            draw(*lead, Hkv, P, ps, Dv or Dh), i32(tok_slot), i32(tok_qoff),
+            i32(ql), i32(kl), i32(tables), i32(start))
+
+
+_STREAM_CASES = {
+    # G = 1 and 4; 5 slots: a buffer about the stream's size; 24 slots,
+    # 20 of them dead: a buffer that is mostly padding
+    "g1": dict(S=5, H=2, Hkv=2),
+    "g4": dict(S=5, H=8, Hkv=2),
+    "g1-mostly-padding": dict(S=24, H=2, Hkv=2),
+    "g4-mostly-padding": dict(S=24, H=8, Hkv=2),
+    # the serving tick's way in: stacked pools and a layer index
+    "stacked-layer": dict(S=5, H=8, Hkv=2, layers=3, kw=dict(layer=2)),
+    # a window, sinks and values of another head size than the keys'
+    "window-sinks-dv": dict(S=5, H=8, Hkv=2, Dv=16, sinks=True,
+                            kw=dict(window=5)),
+    "window-sinks-dv-mostly-padding": dict(S=24, H=8, Hkv=2, Dv=16,
+                                           sinks=True, kw=dict(window=5)),
+    # no plan from the caller: the entry makes it from the metadata,
+    # each slot's first row read from ``tok_slot``
+    "no-plan": dict(S=5, H=8, Hkv=2, plan=None),
+    "no-plan-mostly-padding": dict(S=24, H=8, Hkv=2, plan=None),
+    # a span cut into virtual slots of 3 tokens, with and without the
+    # slots' first rows from the caller
+    "blocks-of-3": dict(S=5, H=8, Hkv=2, plan=dict(block_tokens=3)),
+    "blocks-of-3-no-start": dict(S=5, H=8, Hkv=2,
+                                 plan=dict(block_tokens=3, start=None)),
+    "blocks-of-3-mostly-padding": dict(S=24, H=8, Hkv=2,
+                                       plan=dict(block_tokens=3)),
+    # head size 64 over a LANE-PACKED pool (two KV heads a 128-lane
+    # row, the queries widened with zeros), stacked
+    "lane-packed": dict(S=5, H=8, Hkv=4, Dh=64, layers=2, kw=dict(layer=1)),
+    "lane-packed-mostly-padding": dict(S=24, H=8, Hkv=4, Dh=64, layers=2,
+                                       kw=dict(layer=1)),
+}
+
+
+@pytest.mark.parametrize("impl", ["pallas", "dense"])
+@pytest.mark.parametrize("name", list(_STREAM_CASES))
+def test_stream_launch_matches_the_packed_formulation(name, impl):
+    """The packed entry's way to the kernel (``_stream_launch``: a
+    slot's rows copied straight into the kernel's head-major block, the
+    results gathered straight back) against the packed formulation a
+    CPU tick takes, on a tick with every kind of row (``_mixed_tick``):
+    the contract's bound at the row's scale for two orders of the same
+    reductions, padding rows exactly zero; the kernel and its dense
+    twin over the same boundary BITWISE."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    c = dict(_STREAM_CASES[name])
+    kw, sinks = dict(c.pop("kw", {})), c.pop("sinks", False)
+    # the packing's plan made by the caller, as a walk of many layers
+    # makes it once a tick
+    plan = c.pop("plan", {})
+    tq, Dh = 9, c.pop("Dh", 8)
+    *args, start = _mixed_tick(3, tq=tq, Dh=Dh, **c)
+    q, tok_slot = args[0], np.asarray(args[3])
+    T, G, S = q.shape[0], c["H"] // c["Hkv"], c["S"]
+    if name.startswith("lane-packed"):
+        # [L, Hkv, P, ps, Dh] -> [L, Hkv/f, P, ps, f*Dh]
+        f = R.lane_pack_factor(Dh, c["Hkv"])
+        assert f == 2
+        args[1:3] = [R.lane_pack_heads(x.transpose(0, 2, 3, 1, 4),
+                                       f).transpose(0, 3, 1, 2, 4)
+                     for x in args[1:3]]
+        G *= f
+    if sinks:
+        kw["sinks"] = jnp.asarray(
+            np.random.RandomState(4).randn(c["H"]).astype(np.float32))
+    if plan is not None:
+        kw["plan"] = R.stream_plan(*args[3:], tq, c["H"], args[1],
+                                   **{"start": start, **plan})
+    run = functools.partial(ragged_paged_attention_packed, *args, tq=tq,
+                            **kw)
+    want = run(impl="packed")
+    got = run(impl=impl, kv_tile_pages=2)
+    assert got.shape == want.shape == (T, c["H"], c.get("Dv", Dh))
+    assert tiled_ulp_error(got, want) <= TILED_ULP_BOUND
+    assert not np.asarray(got)[tok_slot == S].any()     # padding: zero
+    assert np.asarray(got)[tok_slot < S].all()
+    if impl == "pallas":
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(run(impl="dense", kv_tile_pages=2)))
+
+
+def test_a_plan_for_other_heads_is_refused():
+    """A plan places the rows of ONE head geometry: handed a stream of
+    another, the entry raises at trace time where a gather would have
+    read other rows in silence."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    *args, start = _mixed_tick(3, S=24, tq=9, H=8, Hkv=2, Dh=8)
+    plan = R.stream_plan(*args[3:], 9, 4, args[1], start=start)
+    with pytest.raises(ValueError, match="cannot place"):
+        ragged_paged_attention_packed(*args, tq=9, impl="dense", plan=plan)
+
+
+def test_packed_sweep_times_the_one_entry_off_the_chip():
+    """``tools/kernel_bench.py --packed-sweep`` off the chip: a decode,
+    a span and a verify tick of the tiny cells (whole slots; a window
+    launch whose span is cut into virtual slots) through the packed
+    entry's kernel path, a quarter and all of the slots live, wall-clock
+    rows that say they are no device timing."""
+    from tools.kernel_bench import VERIFY_ROWS, packed_sweep
+    rows = packed_sweep(iters=1)
+    assert [(r["cell"], r["block_tokens"]) for r in rows[::6]] == [
+        ("tiny", 0), ("tiny.blocked", 2)]
+    assert [(r["tick"], r["slots_live"]) for r in rows] == 2 * [
+        ("decode", 0.25), ("decode", 1.0), ("span", 0.25), ("span", 1.0),
+        ("verify", 0.25), ("verify", 1.0)]
+    assert all(r["busy_ms"] > 0 and not r["timing_honest"] for r in rows)
+    assert all(r["stream_rows"] == (
+        r["slots"] * VERIFY_ROWS if r["tick"] == "verify"
+        else r["slots"] + (r["tq"] > 1) * r["tq"]) for r in rows)
+
+
 def test_bottom_right_causal_prefill_equals_whole():
     """Chunked-prefill exactness at the kernel level: running a prompt
     as two ragged spans (KV written first, bottom-right causal) gives

@@ -24,6 +24,16 @@ geometry. On TPU it reads the kernel's device events from a profiler
 trace; off TPU it still runs end-to-end in interpreter mode at a tiny
 size (wall-clock, ``timing_honest: false`` — the smoke path).
 
+``--packed-sweep`` times the PACKED entry (``ragged_paged_attention_
+packed``: each slot's rows copied into the kernel's blocks, the
+kernel, its results gathered back) at the same geometries and at the
+two launches of a cell whose spans enter as virtual slots
+(``blocked_cells()``): a decode, a span and a verify tick a cell, a
+quarter of the slots and all of them live; ``busy_ms`` a call, the
+kernel's own ``kernel_ms``, what lies around it (``boundary_ms``) and
+the packing plan's own ``plan_ms``. A boundary that wins here has still
+to win in its tick (PERF.md section 6, PR 48).
+
 ``--mla-sweep`` times attention over latent pages (``ops/pallas/
 mla_paged_attention.py``) ALONE at the geometry of every cell with a
 latent cache (``mla_cells()``), and the expanded form of a span as plain
@@ -514,6 +524,183 @@ def ragged_sweep(out=None, iters=5, cells=None, tiles=(None,), label=""):
         results.append({"bench": "ragged_sweep", "cell": cell,
                         "resolution": True, **ageom, **(win or {}),
                         "tiling_source": "swept" if win else "default"})
+    return _emit(results, out)
+
+
+def blocked_cells():
+    """The ragged kernel's launches in a serving cell whose full and
+    window layers take it at two geometries (``models/mimo_v2_flash.py``),
+    keyed ``<traffic>.full`` / ``<traffic>.window``: ``ragged_cells()``'s
+    fields, READ from the same files, and what such a launch adds: a key
+    row as the pool holds it (192 -> 256 lanes, the queries padded
+    alike), ``v_dim``, ``window`` and ``sinks``, a window layer's ring
+    of pages a slot, and ``block_tokens``: a span enters as virtual
+    slots of that many tokens."""
+    from paddle_tpu.models.mimo_v2_flash import BLOCK_TOKENS
+
+    cells = {}
+    for w, model, geo in _serving_cells():
+        if "hybrid_layer_pattern" not in model:
+            continue
+        heads, ps = model["num_attention_heads"], geo["page_size"]
+        dk = -(-model["head_dim"] // 128) * 128
+        W = model["sliding_window"]
+        ring = -(-(W - 1 + geo["span"]) // ps) + 1
+        for kind, kv, win in (
+                ("full", model["num_key_value_heads"], 0),
+                ("window", model["swa_num_key_value_heads"], W)):
+            cells[f"{w['traffic']}.{kind}"] = dict(
+                geo, kv_heads=kv, group=heads // kv, head_dim=dk,
+                v_dim=model["v_head_dim"], window=win, sinks=bool(win),
+                ring=ring if win else 0, block_tokens=BLOCK_TOKENS,
+                pages=geo["slots"] * ring + 1 if win else geo["pages"],
+                layers=sum(bool(x) == bool(win)
+                           for x in model["hybrid_layer_pattern"]))
+    return cells
+
+
+BLOCKED_CELLS_TINY = {
+    "tiny.blocked": dict(slots=4, kv_heads=2, group=2, head_dim=8, v_dim=4,
+                         page_size=4, pps=8, pages=33, span=8, window=5,
+                         sinks=True, ring=0, block_tokens=2),
+}
+
+
+def _packed_stream(S, tq, n_live, ctx, pps, ps, spans=1):
+    """A tick's metadata as the engine packs it: ``n_live`` slots spread
+    over the table's rows hold one decode row each over ``ctx`` tokens
+    of context, the first ``spans`` of them (None: every one, as a
+    speculative tick's verify rows are) a span of ``tq`` rows where
+    ``tq > 1``; ``T = S + tq`` stream rows (``S`` at ``tq`` 1, ``S * tq``
+    where every slot may hold a span), the padding behind the slots.
+    ``(tok_slot, tok_qoff, q_len, kv_len, start)``."""
+    T = S * tq if spans is None else S + (tq if tq > 1 else 0)
+    ql, kl = np.zeros((S,), np.int32), np.zeros((S,), np.int32)
+    live = (np.arange(n_live) * S) // max(n_live, 1)
+    ql[live], kl[live] = 1, ctx
+    if tq > 1 and n_live:
+        wide = live[:spans]
+        ql[wide], kl[wide] = tq, min(ctx + tq, pps * ps)
+    start = np.concatenate([[0], np.cumsum(ql)[:-1]]).astype(np.int32)
+    tok_slot = np.full((T,), S, np.int32)
+    tok_qoff = np.zeros((T,), np.int32)
+    for s in np.flatnonzero(ql):
+        tok_slot[start[s]:start[s] + ql[s]] = s
+        tok_qoff[start[s]:start[s] + ql[s]] = np.arange(ql[s])
+    return tok_slot, tok_qoff, ql, kl, start
+
+
+VERIFY_ROWS = 5     # a speculative tick's rows a drafting slot (1 + k)
+
+
+def packed_sweep(out=None, iters=5, cells=None, label=""):
+    """The packed entry ALONE at the serving cells' geometries
+    (``ragged_cells()`` and ``blocked_cells()``), as a layer of the tick
+    calls it (stacked pools, a layer index, the packing's plan made
+    outside it): one row for each (cell, tick kind, share of the slots
+    live), with the device's ``busy_ms`` a call, the kernel's own
+    ``kernel_ms``, ``boundary_ms`` = what the entry spends around the
+    kernel (laying the stream into the kernel's blocks, gathering the
+    results back) and ``plan_ms``, the plan's own time, once a tick. A
+    tick kind is ``decode`` (one row a live slot), ``span`` (the cell's
+    prefill chunk in the first live slot beside the decode rows) or
+    ``verify`` (``VERIFY_ROWS`` rows in EVERY live slot, as a
+    speculative tick packs its drafts). Only the public entry is
+    called, so the same file times another checkout's boundary (copy it
+    into that tree; a checkout from before ``stream_plan`` takes the
+    metadata alone, and its ``plan_ms`` is inside ``boundary_ms``). Off
+    the chip: the interpreter's wall clock at a tiny size
+    (``timing_honest: false``)."""
+    import tempfile
+    from paddle_tpu.ops.pallas import ragged_paged_attention as R
+    on_tpu = jax.default_backend() == "tpu"
+    table = ({**ragged_cells(), **blocked_cells()} if on_tpu
+             else {**RAGGED_CELLS_TINY, **BLOCKED_CELLS_TINY})
+    dt = jnp.bfloat16 if on_tpu else jnp.float32
+    make_plan = getattr(R, "stream_plan", None)
+
+    def device_ms(fn, args):
+        """(busy, the kernel's own) ms a call of ``fn``."""
+        tdir = tempfile.mkdtemp(prefix="kb_packed_")
+        with jax.profiler.trace(tdir):
+            for _ in range(iters):
+                y = fn(*args)
+            jax.block_until_ready(y)
+        return _busy_ms(tdir) / iters, sum(_kernel_ms(tdir)) / iters
+
+    results = []
+    for cell in cells or table:
+        c = table[cell]
+        S, ps, pps, P = c["slots"], c["page_size"], c["pps"], c["pages"]
+        bt = c.get("block_tokens", 0)
+        # the stream's heads are the MODEL's; a lane-packed pool holds
+        # the kernel's (fewer, wider)
+        m = c.get("model") or dict(heads=c["kv_heads"] * c["group"],
+                                   head_dim=c["head_dim"])
+        rng = np.random.RandomState(0)
+        kk, kv_ = jax.random.split(jax.random.PRNGKey(0))
+        pool = (2, c["kv_heads"], P, ps)
+        kp = jax.random.normal(kk, pool + (c["head_dim"],), dt)
+        vp = jax.random.normal(kv_, pool + (c.get("v_dim", c["head_dim"]),),
+                               dt)
+        if c.get("ring"):       # a window layer's ring of pages a slot
+            tabs = jnp.asarray(np.arange(S)[:, None] * c["ring"]
+                               + np.arange(pps)[None] % c["ring"], jnp.int32)
+        else:
+            tabs = jnp.asarray(1 + rng.randint(0, P - 1, (S, pps)),
+                               jnp.int32)
+        opts = dict(window=c["window"]) if c.get("window") else {}
+        if c.get("sinks"):
+            opts["sinks"] = jnp.asarray(rng.randn(m["heads"]), jnp.float32)
+        runs = []
+        for kind, tq, spans in (("decode", 1, 1), ("span", c["span"], 1),
+                                ("verify", VERIFY_ROWS, None)):
+            for slots_live in (0.25, 1.0):
+                n_live = max(1, int(round(slots_live * S)))
+                ctx = min(pps * ps // 4, pps * ps - tq)
+                *meta, start = map(jnp.asarray, _packed_stream(
+                    S, tq, n_live, ctx, pps, ps, spans))
+                T = meta[0].shape[0]
+                q = jnp.asarray(rng.randn(T, m["heads"], m["head_dim"]), dt)
+                plan_fn = None
+                if make_plan:
+                    # a walk makes the plan once a tick, outside its
+                    # layers: here outside the timed call
+                    mk = functools.partial(
+                        make_plan, tq=tq, heads=m["heads"],
+                        pool=jax.ShapeDtypeStruct(kp.shape, kp.dtype),
+                        block_tokens=bt)
+                    kw = dict(plan=mk(*meta, tabs, start=start))
+                    plan_fn = jax.jit(lambda *a, mk=mk: [
+                        x for x in mk(*a[:-1], start=a[-1])
+                        if isinstance(x, jax.Array)])
+                else:
+                    kw = dict(block_tokens=bt, start=start) if bt else {}
+                fn = jax.jit(functools.partial(
+                    R.ragged_paged_attention_packed, tq=tq, layer=1,
+                    impl="pallas", **opts, **kw))
+                row = {"bench": "packed_sweep", "label": label,
+                       "cell": cell, "tick": kind, "tq": tq, "slots": S,
+                       "stream_rows": T, "heads": m["heads"],
+                       "kv_heads": c["kv_heads"], "head_dim": m["head_dim"],
+                       "block_tokens": bt,
+                       "slots_live": slots_live, "live_slots": n_live,
+                       "kv_len": ctx, "timing_honest": on_tpu}
+                runs.append((row, fn, (q, kp, vp, *meta, tabs),
+                             plan_fn, (*meta, tabs, start)))
+        for row, fn, args, plan_fn, plan_args in runs:
+            jax.block_until_ready(fn(*args))        # compile outside it
+            if not on_tpu:
+                results.append(dict(row, busy_ms=round(
+                    _walltime(fn, args, n=iters), 4)))
+                continue
+            busy, kern = device_ms(fn, args)
+            row = dict(row, busy_ms=round(busy, 5), kernel_ms=round(kern, 5),
+                       boundary_ms=round(busy - kern, 5))
+            if plan_fn:
+                jax.block_until_ready(plan_fn(*plan_args))
+                row["plan_ms"] = round(device_ms(plan_fn, plan_args)[0], 5)
+            results.append(row)
     return _emit(results, out)
 
 
@@ -1190,7 +1377,7 @@ def block_sweep(out=None, iters=3):
 if __name__ == "__main__":
     from paddle_tpu.compile_cache import enable_compile_cache
     enable_compile_cache()
-    if {"--block-sweep", "--ragged-sweep", "--ssd-sweep",
+    if {"--block-sweep", "--ragged-sweep", "--ssd-sweep", "--packed-sweep",
             "--mla-sweep", "--held-sweep", "--train-gmm-sweep"} & set(
                 sys.argv):
         opt = {a.split("=", 1)[0]: a.split("=", 1)[1] for a in sys.argv
@@ -1198,6 +1385,10 @@ if __name__ == "__main__":
         path = opt.get("--out")
         if "--block-sweep" in sys.argv:
             block_sweep(out=path)
+        elif "--packed-sweep" in sys.argv:
+            packed_sweep(out=path, label=opt.get("--label", ""),
+                         cells=(opt["--cells"].split(",") if "--cells" in opt
+                                else None))
         elif "--mla-sweep" in sys.argv:
             mla_sweep(out=path, label=opt.get("--label", ""),
                       cells=(opt["--cells"].split(",") if "--cells" in opt
