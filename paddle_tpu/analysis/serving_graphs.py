@@ -207,7 +207,11 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
         meta["wide_dot_ok"] = (
             lambda lhs, rhs: rhs.shape and rhs.shape[-1] == n_e)
 
-    params = mod.abstract_params(cfg)
+    given = mod.abstract_params(cfg)        # generate_paged's tree
+    # the tree an ENGINE holds: the family's own where it brings one
+    serving = getattr(mod, "serving_params", None)
+    params = given if serving is None else jax.eval_shape(
+        lambda p: serving(p, cfg), given)
     cache = _abstract_cache(mod, cfg, slots, pps, page_size,
                             tick_budget(geom))
     # a family that hands counts back beside its tokens (TICK_COUNTERS:
@@ -290,7 +294,7 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
         targets.append(trace_graph(
             f"{model}.generate_paged[B={B}]",
             mod.generate_paged,
-            (params, sds((B, T0), i32), sds((B,), i32)),
+            (given, sds((B, T0), i32), sds((B,), i32)),
             static_kwargs=dict(cfg=cfg, max_new_tokens=mnt,
                                page_size=page_size, attn_impl="dense"),
             compute_dtype=cfg.dtype, slots=B, steps_per_call=mnt,

@@ -358,9 +358,9 @@ def _block(lp, h, positions, cfg: LlamaConfig, attn_fn, sp_spec=None,
     # operation is
     with jax.named_scope("attn.qkv_rope"):
         x = norm(h, lp["attn_norm"])
-        q = _mm(x, lp["wq"]).reshape(B, T, H, Dh)
-        k = _mm(x, lp["wk"]).reshape(B, T, Hkv, Dh)
-        v = _mm(x, lp["wv"]).reshape(B, T, Hkv, Dh)
+        q = _proj(x, lp, "wq").reshape(B, T, H, Dh)
+        k = _proj(x, lp, "wk").reshape(B, T, Hkv, Dh)
+        v = _proj(x, lp, "wv").reshape(B, T, Hkv, Dh)
         q, k = rope_fn(q, k)
     o = attn_fn(q, k, v)
     with jax.named_scope("attn.out"):
@@ -1863,3 +1863,53 @@ def make_batch(cfg: LlamaConfig, batch_size: int, seq_len: int, mesh: Mesh,
     sh = NamedSharding(mesh, P("dp", cp))
     return {"tokens": jax.device_put(toks[:, :-1], sh),
             "labels": jax.device_put(toks[:, 1:], sh)}
+
+
+# ---------------------------------------------------------------------------
+# the tree a serving engine holds
+# ---------------------------------------------------------------------------
+# Kept at the END of the file: a Mosaic kernel's compile-cache key holds
+# its call site's line numbers, so a line added above a call site costs
+# every cell that runs it one cold start.
+
+# a layer's q / k / v projection held OUTPUT-MAJOR ``[L, O, D]`` stands
+# under its name with this suffix (``wq_om``), in a serving tree only
+OUTPUT_MAJOR = "_om"
+_RELAID = ("wq", "wk", "wv")
+
+
+def _proj(x, lp, name):
+    """``x @ W`` of the layer's projection ``name``, as the tree holds
+    it: output-major (``lp[name + OUTPUT_MAJOR]``, ``[O, D]``) it is
+    contracted over its LAST axis, where the chip's compiler reads the
+    layer's slice of the stack as it lies; input-major or int8
+    (``lp[name]``: ``init_params``' tree, the trainers', an ``Int8Weight``)
+    it is ``_mm``. In front of a product of a tick's handful of rows the
+    compiler wants the weight the other way round: held ``[D, O]`` it
+    copied every layer's three slices in every tick (``PERF.md`` §6,
+    PR 49)."""
+    w = lp.get(name + OUTPUT_MAJOR)
+    if w is None:
+        return _mm(x, lp[name])
+    return jnp.einsum("btd,od->bto", x, w)
+
+
+@jax.jit
+def _output_major(w):
+    return jnp.swapaxes(w, -1, -2)
+
+
+def serving_params(params, cfg):
+    """The tree a serving engine holds for ``params`` (an optional
+    function of a family's module; ``ServingEngine`` calls it once, at
+    construction): a NEW tree that shares every leaf with the caller's
+    but the dense ``layers.wq`` / ``.wk`` / ``.wv`` stacks, which it
+    holds output-major ``[L, O, D]`` under ``wq_om`` / ``wk_om`` /
+    ``wv_om`` (one transpose a stack, made here). ``_proj`` reads
+    either. The caller's tree is not touched; a stack that is no dense
+    array (``Int8Weight``) stays as and where it is."""
+    layers = dict(params["layers"])
+    for name in _RELAID:
+        if not hasattr(layers[name], "dequant_matmul"):
+            layers[name + OUTPUT_MAJOR] = _output_major(layers.pop(name))
+    return dict(params, layers=layers)

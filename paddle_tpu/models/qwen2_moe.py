@@ -26,7 +26,7 @@ from ..incubate.moe.functional import moe_ffn, moe_ffn_share
 from ..ops.pallas.flash_attention import remat_layer
 from .layer_walk import (COUNTS, EXPERT_COUNTERS, expert_counts,
                          with_tick_counts)
-from .llama import _mm, rms_norm, rope, tick_plan
+from .llama import _mm, _proj, rms_norm, rope, tick_plan
 
 # what a serving tick hands back beside its tokens
 TICK_COUNTERS = EXPERT_COUNTERS
@@ -312,9 +312,9 @@ def _decode_block(lp, h, positions, cfg: Qwen2MoeConfig, attn_fn,
     # brings ``moe.router`` and ``moe.experts``
     with jax.named_scope("attn.qkv_rope"):
         x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
-        q = _mm(x, lp["wq"]).reshape(B, T, H, Dh)
-        k = _mm(x, lp["wk"]).reshape(B, T, Hkv, Dh)
-        v = _mm(x, lp["wv"]).reshape(B, T, Hkv, Dh)
+        q = _proj(x, lp, "wq").reshape(B, T, H, Dh)
+        k = _proj(x, lp, "wk").reshape(B, T, Hkv, Dh)
+        v = _proj(x, lp, "wv").reshape(B, T, Hkv, Dh)
         q, k = rope(q, k, positions, cfg.rope_theta, Dh)
     o = attn_fn(q, k, v)
     with jax.named_scope("attn.out"):
@@ -508,3 +508,11 @@ def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
         lambda c: _impl(params, tok, lengths, tables, c, cfg, num_steps,
                         walk=_walk, **kw),
         cache, len(TICK_COUNTERS), True)
+
+
+def serving_params(params, cfg: Qwen2MoeConfig):
+    """The tree a serving engine holds (``models/llama.py
+    serving_params``): q / k / v stacks output-major, read by
+    ``_decode_block`` through ``_proj``."""
+    from .llama import serving_params as _impl
+    return _impl(params, cfg)

@@ -434,10 +434,10 @@ class ServingEngine:
         if decode_block_size < 1:
             raise ValueError("decode_block_size must be >= 1")
         self._decode_block = int(decode_block_size)
-        self._params = params
         self._cfg = cfg
         from ..models import resolve_family
         self._mod = resolve_family(model, cfg)
+        self._params = self._serving_tree(params)
         # a layer kind that keeps a fixed row a slot (not pages) holds
         # state that a prefix's pages cannot rebuild: no snapshots exist
         # yet, so what attaches, moves or rolls back pages is off
@@ -948,16 +948,16 @@ class ServingEngine:
 
     def stats(self) -> dict:
         """:meth:`snapshot` plus ``setup``: where the time to ready
-        went (``observability.setup_report``: the set-up spans, the
-        compile ledger's totals and slowest programs, the rows that
-        partition it), as of the first tick that carried a request;
-        until then (``ready`` False) as of now."""
+        went (``observability.setup_report``: spans, the compile
+        ledger's totals and slowest programs, the rows that partition
+        it) as of the first tick that carried a request (until then,
+        ``ready`` False, as of now), and ``_serving_tree``'s cost."""
         snap = self.snapshot()
         with self._tick_lock:
             ready_t = self._ready_t
         snap["setup"] = dict(
             setup_report(since=self._setup_t0, until=ready_t),
-            ready=ready_t is not None,
+            ready=ready_t is not None, **self._relaid,
             time_to_ready_s=(None if ready_t is None
                              else ready_t - self._setup_t0))
         return snap
@@ -2617,3 +2617,33 @@ class ServingEngine:
         for slot, req in self.scheduler.occupied():
             req.error = e
             self.scheduler.retire(slot, CANCELLED)
+
+    def _serving_tree(self, params):
+        """The tree this engine's programs read. A family may bring a
+        layout of its own for serving (``serving_params(params, cfg)``,
+        an optional function of its module, as ``TICK_COUNTERS`` is: a
+        NEW tree that shares with the caller's every leaf it does not
+        re-lay); a family without one serves the tree it was given. Made
+        once, here, under a set-up span of its own; the caller's tree is
+        not touched. ``stats()["setup"]`` says what it cost:
+        ``weights_relaid_bytes`` (the leaves of the engine's tree that
+        are not the caller's own: 0 where nothing was re-laid) and
+        ``weights_relay_s``. (At the end of the class: a line added
+        above ``warm_programs`` would shift the frames a Mosaic
+        kernel's compile-cache key holds.)"""
+        import jax
+        relay = getattr(self._mod, "serving_params", None)
+        if relay is None:
+            self._relaid = dict(weights_relaid_bytes=0, weights_relay_s=0.0)
+            return params
+        t0 = time.monotonic()
+        with setup_span("serving.setup.init.relay"):
+            tree = relay(params, self._cfg)
+            given = {id(x) for x in jax.tree_util.tree_leaves(params)}
+            new = jax.block_until_ready(  # noqa: PT002 — the set-up span holds the transposes, once an engine
+                [x for x in jax.tree_util.tree_leaves(tree)
+                 if id(x) not in given])
+        self._relaid = dict(
+            weights_relaid_bytes=sum(int(x.nbytes) for x in new),
+            weights_relay_s=time.monotonic() - t0)
+        return tree

@@ -94,7 +94,9 @@ def test_serving_targets_trace_a_family_through_its_three_functions(
     mod = resolve_family(model)
     public = {n for n in dir(mod) if "serving" in n
               and not n.startswith("_") and callable(getattr(mod, n))}
-    assert public - {"serving_cache_kinds"} == set(_THREE)
+    # what a family DECLARES beside them, each optional: its layer
+    # kinds, and the tree an engine holds (made once, at construction)
+    assert public - {"serving_cache_kinds", "serving_params"} == set(_THREE)
     calls = dict.fromkeys(_THREE, 0)
     caches = []
 

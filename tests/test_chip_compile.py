@@ -226,8 +226,10 @@ def _mistral_tick_shapes(tq, layers, pages):
         vocab_size=32768, hidden_size=D, intermediate_size=14336,
         num_hidden_layers=layers, num_attention_heads=H,
         num_key_value_heads=HKV, rope_theta=1e6, dtype=jnp.bfloat16)
-    params = jax.eval_shape(
-        lambda: L.init_params(cfg, jax.random.PRNGKey(0)))
+    # the tree an ENGINE holds (``serving_params``: the q / k / v stacks
+    # output-major), not ``init_params``' own
+    params = jax.eval_shape(lambda: L.serving_params(
+        L.init_params(cfg, jax.random.PRNGKey(0)), cfg))
     params = jax.tree.map(lambda a: sds(a.shape), params)
     S, T = CHAT["slots"], CHAT["slots"] + (tq if tq > 1 else 0)
     i32 = functools.partial(sds, dtype=jnp.int32)
@@ -379,6 +381,50 @@ def test_span_tick_passes_over_the_query_buffer_at_most_three_times(
     assert re.search(r"custom-call\(.*ragged_attn", text.compiled)
 
 
+def _layer_weight_results(compiled_text, layers, opcode):
+    """``(name, dims)`` of every ``opcode`` instruction of the compiled
+    program, fused computations included, whose result is ONE LAYER of a
+    stacked matrix of ``layers`` (``[1, a, b]`` or ``[a, b]``, either
+    way round)."""
+    mats = {tuple(sorted(a.shape[1:])) for a in jax.tree.leaves(layers)
+            if len(a.shape) == 3}
+    out = []
+    for line in compiled_text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m or m.group(3) != opcode:
+            continue
+        for _, dims in _HLO_ARRAY.findall(m.group(2)):
+            dims = tuple(int(d) for d in dims.split(","))
+            if tuple(sorted(dims[-2:])) in mats and set(dims[:-2]) <= {1}:
+                out.append((m.group(1), dims))
+    return out
+
+
+@pytest.mark.parametrize("tq", [1, 128])
+def test_serving_tick_reads_the_qkv_stacks_as_they_lie(chip, monkeypatch,
+                                                       tq):
+    """The chat cell's tick on the tree an engine holds (``llama.
+    serving_params``: ``wq`` / ``wk`` / ``wv`` output-major, contracted
+    over their last axis): a layer's three slices leave their stacks
+    (``[1, 4096, 4096]`` and two ``[1, 1024, 4096]``) and NO ``copy``
+    re-lays a layer of any weight in front of its product. Held
+    ``[L, D, O]`` the compiler put one behind each of the three slices
+    (``copy.64`` / ``.66`` / ``.67``, ``{2,1,0}`` to ``{1,2,0}``: ~0.75
+    ms of every chat tick's 12.4; ``PERF.md`` section 6, PR 49)."""
+    text = _mistral_tick_text(chip, monkeypatch, tq)
+    cfg, (params, *_) = _mistral_tick_shapes(tq, 2, CHAT["pages"])
+    layers = params["layers"]
+    assert layers["wq_om"].shape == (2, H * DH, D)
+    assert layers["wk_om"].shape == layers["wv_om"].shape == (2, HKV * DH, D)
+    copies = _layer_weight_results(text.compiled, layers, "copy")
+    assert not copies, f"a layer's weight is re-laid: {copies}"
+    sliced = [dims for _, dims in _layer_weight_results(
+        text.compiled, layers, "dynamic-slice")]
+    assert sliced.count((1, HKV * DH, D)) == 2          # wk, wv
+    assert sliced.count((1, H * DH, D)) >= 1            # wq (wo is square too)
+    assert (1, D, HKV * DH) not in sliced
+
+
 # the three serving cells' tick programs as the ENGINE jits them
 # (``serving/engine.py: _jit_step_fns``: the family's own walk, the
 # slots' current tokens in and their successor out, the cache donated),
@@ -422,8 +468,10 @@ def _cell_program_args(traffic):
     model.update(_TWO_LAYERS[model["family"]])
     cfg, mod = manifest.load_family(model["family"]).program_config(model)
     g = CELLS[traffic]
-    params = jax.eval_shape(
-        lambda: mod.init_params(cfg, jax.random.PRNGKey(0)))
+    # the tree the engine holds: the family's own where it brings one
+    serving = getattr(mod, "serving_params", lambda p, cfg: p)
+    params = jax.eval_shape(lambda: serving(
+        mod.init_params(cfg, jax.random.PRNGKey(0)), cfg))
     cache = jax.eval_shape(lambda: mod.init_serving_pages(
         cfg, g["pages"], g["page_size"], max_batch=g["slots"]))
     return mod, cfg, g["slots"], g["pps"], g["span"], params, cache
@@ -484,6 +532,13 @@ def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
     E._JIT_CACHE.clear()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel"
+    if hasattr(mod, "serving_params"):
+        # q / k / v read as their stacks hold them (the batch cell's
+        # three are ``[1, 2048, 2048]``): no layer of them re-laid
+        qkv = [a for name, a in params["layers"].items()
+               if name.endswith("_om")]
+        assert len(qkv) == 3
+        assert not _layer_weight_results(text, qkv, "copy")
     outs = jax.tree.leaves(compiled.out_info)
     leaves = jax.tree.leaves(cache)
     assert len(outs) == results + len(leaves)

@@ -930,7 +930,9 @@ def test_engine_setup_spans_and_stats_block(params):
     (init,), (warm,) = spans["serving.setup.init"], spans["serving.setup.warm"]
     assert init["parent"] is None and warm["parent"] is None
     kids = spans["serving.setup.init.inventory"] \
-        + spans["serving.setup.init.cache"]
+        + spans["serving.setup.init.cache"] \
+        + spans["serving.setup.init.relay"]     # llama's serving tree
+    assert len(kids) == 3
     assert all(k["parent"] == "serving.setup.init" for k in kids)
     assert sum(k["dur_s"] for k in kids) <= init["dur_s"]
     assert init["self_s"] == pytest.approx(

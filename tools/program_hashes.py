@@ -123,6 +123,11 @@ def main(names) -> dict:
         pages = eng.get("total_pages") or S * pps + 1
         params = jax.eval_shape(
             lambda: mod.init_params(cfg, jax.random.PRNGKey(0)))
+        # the tree an engine holds: the family's own where it brings one
+        # (``serving_params``; a parent from before it serves the given)
+        serving = getattr(mod, "serving_params", None)
+        if serving is not None:
+            params = jax.eval_shape(lambda p: serving(p, cfg), params)
         # a family with window rings sizes them by the chunk
         # (``init_cache``; a parent from before it has no such family)
         init = getattr(E, "init_cache", None)
