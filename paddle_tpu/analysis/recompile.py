@@ -3,8 +3,8 @@ serving call site can produce.
 
 The engine's only step functions are ``serving_tick`` (decode tokens +
 prompt spans as one program; geometry rides in device arrays) and
-``serving_tick_block`` (the fused decode block), jitted over a family's
-``serving_tick_cache`` / ``serving_tick_block_cache``. The
+``serving_tick_block`` (the fused decode block), jitted over
+``models/serving_tick.py``'s two functions and a family's record. The
 compiled-program key is the packed token width, and the reachable set
 is fixed by construction: mixed widths run the tail/no-tail tick pair,
 width ``S`` exactly ONE program (the fused block — sampling rides it as

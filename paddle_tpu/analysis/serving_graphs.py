@@ -3,10 +3,10 @@
 One place that knows how to hand each flagship program to the
 analysers: abstract-trace (``jax.make_jaxpr`` over ShapeDtypeStructs —
 nothing allocates, nothing compiles) the serving step functions of a
-model family exactly as the engine jits them (the family's
-``init_serving_pages`` cache pytree through ``serving_tick_cache`` /
-``serving_tick_block_cache``), tagged with the
-call-site facts the passes need (compute dtype, donated pool outputs,
+model family exactly as the engine jits them (the cache pytree of the
+family's ``SERVING.init_pages`` through ``models/serving_tick.py``'s
+``serving_tick`` / ``serving_tick_block``), tagged with the call-site
+facts the passes need (compute dtype, donated pool outputs,
 slot/step counts, engine geometry for the recompile pass, pp stage
 grouping for the collective pass).
 
@@ -115,13 +115,13 @@ def _get_model(name: str):
 
 def _abstract_cache(mod, cfg, slots: int, pps: int, page_size: int,
                     max_span: int = 1):
-    """The family's cache pytree as the engine has ``init_serving_pages``
-    build it (``slots * pps`` pages and the trash page; ``max_span``
-    rows a slot a tick for a family with window rings), abstractly."""
+    """The family's cache pytree as the engine has its record's
+    ``init_pages`` build it (``slots * pps`` pages and the trash page;
+    ``max_span`` rows a slot a tick, which sizes a window ring),
+    abstractly."""
     import jax
-    from ..serving.engine import init_cache
-    return jax.eval_shape(lambda: init_cache(
-        mod, cfg, slots * pps + 1, page_size, slots, max_span))
+    return jax.eval_shape(lambda: mod.SERVING.init_pages(
+        cfg, slots * pps + 1, page_size, slots, max_span))
 
 
 def _donated(cache, first: int):
@@ -149,7 +149,7 @@ def _sampling_meta(slots: int) -> Dict[str, Any]:
 
 
 def _tick_meta(T: int, slots: int, pps: int) -> Dict[str, Any]:
-    """``serving_tick_cache``'s ``meta`` at packed width ``T``, abstractly."""
+    """``serving_tick``'s ``meta`` at packed width ``T``, abstractly."""
     import jax
     import jax.numpy as jnp
     sds, i32 = jax.ShapeDtypeStruct, jnp.int32
@@ -169,9 +169,10 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
                     decode_block: int = 4,
                     spec_k: int = 3) -> List[GraphTarget]:
     """GraphTargets for one family's flagship serving programs, traced
-    through the THREE functions the engine calls (the cache pytree of
-    ``init_serving_pages`` abstractly, then ``serving_tick_cache`` /
-    ``serving_tick_block_cache``): the tick at the mixed packed width,
+    through what the engine calls (the cache pytree of the family's
+    ``SERVING.init_pages`` abstractly, then ``models/serving_tick.py``'s
+    ``serving_tick`` / ``serving_tick_block`` over the family's
+    record): the tick at the mixed packed width,
     the fused decode block (the ONLY pure-decode program: sampling
     slots ride it through the fused in-graph sampler, whose per-slot
     temperature/top-k/top-p/key/produced state is traced here exactly
@@ -188,9 +189,10 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
     import jax
     import jax.numpy as jnp
 
-    from ..serving.engine import _cache_kinds
+    from ..models.serving_tick import serving_tick, serving_tick_block
 
     mod, cfg = _get_model(model)
+    family = mod.SERVING
     geom = engine_geometry(
         page_size=page_size, max_prompt_len=max_prompt_len,
         max_new_tokens_cap=max_new_tokens_cap,
@@ -209,14 +211,13 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
 
     given = mod.abstract_params(cfg)        # generate_paged's tree
     # the tree an ENGINE holds: the family's own where it brings one
-    serving = getattr(mod, "serving_params", None)
-    params = given if serving is None else jax.eval_shape(
-        lambda p: serving(p, cfg), given)
+    params = given if family.params is None else jax.eval_shape(
+        lambda p: family.params(p, cfg), given)
     cache = _abstract_cache(mod, cfg, slots, pps, page_size,
                             tick_budget(geom))
-    # a family that hands counts back beside its tokens (TICK_COUNTERS:
+    # a family that hands counts back beside its tokens (``counters``:
     # one more small result in front of the slots' tokens)
-    counts = 1 if getattr(mod, "TICK_COUNTERS", ()) else 0
+    counts = 1 if family.counters else 0
 
     sds = jax.ShapeDtypeStruct
     i32 = jnp.int32
@@ -234,9 +235,10 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
     T = slots + budget
     targets.append(trace_graph(
         f"{model}.serving_tick[mixed]",
-        mod.serving_tick_cache,
+        serving_tick,
         (params, sds((T,), i32), _tick_meta(T, slots, pps), cache),
-        static_kwargs=dict(cfg=cfg, tq=budget, attn_impl="dense"),
+        static_kwargs=dict(cfg=cfg, family=family, tq=budget,
+                           attn_impl="dense"),
         compute_dtype=cfg.dtype, slots=slots,
         donated_outputs=_donated(cache, 2 + counts), meta=dict(meta)))
 
@@ -246,8 +248,7 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
     # this target, so graph_lint proves the draft/verify program set
     # stays within the per-bucket bound (emitted as
     # serving_programs_spec in --json)
-    if not any(k.cache in ("slot_rows", "window_pages")
-               for k in _cache_kinds(mod, cfg)):
+    if all(k.cache == "pages" for k in family.kinds(cfg)):
         spec_geom = engine_geometry(
             page_size=page_size, max_prompt_len=max_prompt_len,
             max_new_tokens_cap=max_new_tokens_cap,
@@ -261,10 +262,11 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
             draft_len=sds((slots,), i32))
         targets.append(trace_graph(
             f"{model}.serving_tick[verify,spec_k={spec_k}]",
-            mod.serving_tick_cache,
+            serving_tick,
             (params, sds((Tv,), i32), ver_meta, cache),
-            static_kwargs=dict(cfg=cfg, tq=slots * (1 + spec_k),
-                               spec_k=spec_k, attn_impl="dense"),
+            static_kwargs=dict(cfg=cfg, family=family,
+                               tq=slots * (1 + spec_k), spec_k=spec_k,
+                               attn_impl="dense"),
             compute_dtype=cfg.dtype, slots=slots,
             donated_outputs=_donated(cache, 3 + counts),
             meta=dict(meta, geometry=spec_geom)))
@@ -273,8 +275,8 @@ def serving_targets(model: str = "llama", *, slots: int = 4,
     # sampling slots — the sampling state is a traced arg, exactly as
     # the engine passes it) --------------------------------------------
     def _block_with_sampling(p, tok, lens, tabs, cache_, samp):
-        return mod.serving_tick_block_cache(
-            p, tok, lens, tabs, cache_, cfg, decode_block,
+        return serving_tick_block(
+            p, tok, lens, tabs, cache_, cfg, family, decode_block,
             attn_impl="dense", sampling=samp)
 
     targets.append(trace_graph(
@@ -355,6 +357,7 @@ def rewrite_targets(models=("llama",), *, slots: int = 4,
     # flagship — skipped when the caller excluded llama) --------------
     if "llama" not in models:
         return targets
+    from ..models.serving_tick import serving_tick
     from ..quantization.decode import quantize_for_decode
     mod, cfg = _get_model("llama")
     geom = engine_geometry(
@@ -369,10 +372,11 @@ def rewrite_targets(models=("llama",), *, slots: int = 4,
     try:
         t = trace_graph(
             "llama.serving_tick[int8-unfused]",
-            mod.serving_tick_cache,
+            serving_tick,
             (qparams, jax.ShapeDtypeStruct((slots,), jnp.int32),
              _tick_meta(slots, slots, pps), cache),
-            static_kwargs=dict(cfg=cfg, tq=1, attn_impl="dense"),
+            static_kwargs=dict(cfg=cfg, family=mod.SERVING, tq=1,
+                               attn_impl="dense"),
             compute_dtype=cfg.dtype, slots=slots, in_decode_loop=True,
             donated_outputs=_donated(cache, 2))
     finally:

@@ -30,11 +30,11 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
                     ErnieForPretraining)
 
 
-# A serving family is a module that brings the THREE functions the
-# engine calls: ``init_serving_pages`` / ``serving_tick_cache`` /
-# ``serving_tick_block_cache`` (contracts: models/llama.py). Family
-# (module) name -> its config class; this is the one place a name
-# becomes a module.
+# A serving family is a module that exposes ONE record, ``SERVING``
+# (``models/layer_walk.py: ServingFamily``: its layer walk, its cache
+# and what the engine may do with it); the tick around the walk is
+# ``models/serving_tick.py``'s, for every family. Family (module) name
+# -> its config class; this is the one place a name becomes a module.
 SERVING_FAMILIES = {"llama": "LlamaConfig",
                     "qwen2_moe": "Qwen2MoeConfig",
                     "lfm2_moe": "Lfm2MoeConfig",
@@ -55,5 +55,5 @@ def resolve_family(model, cfg=None):
             return importlib.import_module(f"{__name__}.{family}")
     raise ValueError(
         f"cannot infer serving model from {name!r}; pass one of "
-        f"{sorted(SERVING_FAMILIES)} or a module exposing "
-        "init_serving_pages/serving_tick_cache/serving_tick_block_cache")
+        f"{sorted(SERVING_FAMILIES)} or a module exposing SERVING "
+        "(a models.layer_walk.ServingFamily)")

@@ -24,7 +24,7 @@ h + residual_multiplier * MLP(RMSNorm(h))``.
 * feed-forward: ``[g, u] = split2(a W_in)``, ``(silu(g) * u) W_out``;
 * model: ``h_0 = embedding_multiplier * E[token]``; final RMSNorm;
   ``logits = (h W_head) / logits_scaling`` (both applied by
-  ``models/llama.py: serving_tick_cache`` from this config's fields).
+  ``models/serving_tick.py`` from this config's fields).
 
 Parameters are stacked BY KIND, each kind's layers in model order::
 
@@ -93,11 +93,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.pallas.ragged_paged_attention import (lane_pack_factor,
-                                                 lane_pack_heads)
+from ..ops.pallas.ragged_paged_attention import lane_pack_factor
 from ..ops.pallas.ssd_update import Walk, live_walk, ssd_update
 from . import layer_walk as _lw
-from . import llama as _llama
 from .layer_walk import LayerKind, _layer_params
 from .llama import _mm, rms_norm
 
@@ -533,7 +531,7 @@ def serving_cache_kinds(cfg: GraniteHybridConfig):
 
 
 def init_serving_pages(cfg: GraniteHybridConfig, total_pages: int,
-                       page_size: int, max_batch: int):
+                       page_size: int, max_batch: int, max_span: int = 1):
     """The model's cache, ONE pytree built from its kinds: ``k_pages`` /
     ``v_pages`` over the attention layers only (page 0 = trash; lane-
     packed where the head size is under the chip's 128 lanes), and the
@@ -561,18 +559,13 @@ def _walk(params, h, cache, meta, cfg: GraniteHybridConfig, tq, attn_impl):
     contract) over ``layer_groups``: one scan over the periods, inside
     it one loop a run of Mamba layers, the pools and both states in
     every loop's carry (the state is never copied: module docstring)."""
-    from ..ops.pallas.ragged_paged_attention import (
-        ragged_paged_attention_packed)
     K = cfg.mamba_d_conv
     tok_slot, tok_qoff, tok_pos = (meta["tok_slot"], meta["tok_qoff"],
                                    meta["tok_pos"])
-    f = lane_pack_factor(cfg.head_dim, cfg.num_key_value_heads)
-    heads = jnp.arange(cfg.num_key_value_heads // f, dtype=jnp.int32)[None]
-    tok_page = meta["tok_page"][:, None]                            # [T, 1]
-    tok_off = meta["tok_off"][:, None]
+    at = _lw.kv_rows(meta, cache["k_pages"])
     q_len, last = meta["q_len"], meta["last"]
-    plan = _llama.tick_plan(meta, tq, cfg.num_attention_heads,
-                            cache["k_pages"])
+    plan = _lw.tick_plan(meta, tq, cfg.num_attention_heads,
+                         cache["k_pages"])
     ssd_impl = attn_impl if attn_impl in ("pallas", "dense") else "auto"
     # what the Mamba layers need of the packing, once for all of them
     plans = ssd_plan(tok_slot, tok_pos, q_len.shape[0], cfg.mamba_chunk_size)
@@ -581,19 +574,10 @@ def _walk(params, h, cache, meta, cfg: GraniteHybridConfig, tq, attn_impl):
         cell = {}
 
         def attn_fn(q, k, v):
-            with jax.named_scope("kv_pool.write"):
-                kp2 = kp.at[layer, heads, tok_page, tok_off].set(
-                    lane_pack_heads(k[0], f).astype(kp.dtype))
-                vp2 = vp.at[layer, heads, tok_page, tok_off].set(
-                    lane_pack_heads(v[0], f).astype(vp.dtype))
-            cell["kp"], cell["vp"] = kp2, vp2
-            with jax.named_scope("ragged_attn"):
-                o = ragged_paged_attention_packed(
-                    q[0], kp2, vp2, tok_slot, tok_qoff, q_len,
-                    meta["kv_len"], meta["tables"], tq=tq,
-                    sm_scale=cfg.attention_multiplier, impl=attn_impl,
-                    layer=layer, plan=plan)
-            return o[None].astype(q.dtype)
+            o, cell["kp"], cell["vp"] = _lw.paged_kv_attend(
+                q, k, v, kp, vp, layer, meta, at, plan, tq, attn_impl,
+                sm_scale=cfg.attention_multiplier)
+            return o
 
         h = _attn_op(lp, h, cfg, attn_fn)
         return h, cell["kp"], cell["vp"]
@@ -654,27 +638,5 @@ def _walk(params, h, cache, meta, cfg: GraniteHybridConfig, tq, attn_impl):
                "ssm_state": ss}
 
 
-def serving_tick_cache(params, tokens, meta, cache, cfg: GraniteHybridConfig,
-                       tq: int = 1, decode_tail: int = 0, spec_k: int = 0,
-                       attn_impl: str = "auto"):
-    """ONE ragged serving tick (``models/llama.py serving_tick_cache``
-    with this model's walk) over this model's cache pytree: returns
-    ``(toks, logits, cache')``, with ``meta['cur_tok']``
-    ``(toks, logits, cur_tok', cache')``."""
-    if spec_k:
-        raise ValueError("no speculative verify for a model with per-slot "
-                         "state: a rejected draft's state cannot be rolled "
-                         "back")
-    return _llama.serving_tick_cache(
-        params, tokens, meta, cache, cfg, tq=tq, decode_tail=decode_tail,
-        attn_impl=attn_impl, walk=_walk)
-
-
-def serving_tick_block_cache(params, tok, lengths, tables, cache,
-                             cfg: GraniteHybridConfig, num_steps: int,
-                             attn_impl: str = "auto", sampling=None):
-    """``num_steps`` fused decode ticks: ``(toks [S, num_steps], tok'
-    [S], cache')``."""
-    return _llama.serving_tick_block_cache(
-        params, tok, lengths, tables, cache, cfg, num_steps,
-        attn_impl=attn_impl, sampling=sampling, walk=_walk)
+SERVING = _lw.ServingFamily(walk=_walk, init_pages=init_serving_pages,
+                            kinds=serving_cache_kinds)

@@ -1,11 +1,13 @@
-"""What the families whose layers follow a PATTERN OF KINDS share
-(ROADMAP D1; ``models/lfm2_moe.py`` began these, ``models/
-granite_hybrid.py`` is their second user): what a kind keeps between
-ticks (``LayerKind``), the stack cut into a handful of groups
+"""What the serving families share below the tick (ROADMAP D1): the ONE
+record a family's module hands the engine and the shared tick
+(``ServingFamily``, as ``SERVING``), what a kind keeps between ticks
+(``LayerKind``), a cache's page pools by name (``PagePoolSpec``), how a
+tick's K and V land in a stacked pool and are attended
+(``tick_plan`` / ``paged_kv_attend``), and for the families whose
+layers follow a PATTERN OF KINDS the stack cut into a handful of groups
 (``layer_groups``), one layer's parameters out of a kind's stack
-(``_layer_params``), the loop over the groups (``walk_groups``), the
-counts a tick hands back beside its tokens (``with_tick_counts``) and
-the per-slot window of a causal depthwise convolution in a ragged tick
+(``_layer_params``), the loop over the groups (``walk_groups``) and the
+per-slot window of a causal depthwise convolution in a ragged tick
 (``earlier_rows`` / ``window_rows``).
 
 A layer is ``(operator, feed-forward, operator's ordinal, feed-
@@ -14,11 +16,14 @@ in model order, so a layer is found by its ordinals.
 """
 from __future__ import annotations
 
-from typing import Dict, NamedTuple
+from typing import Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops.pallas.ragged_paged_attention import (
+    lane_pack_heads, ragged_paged_attention_packed, stream_plan)
 
 
 class LayerKind(NamedTuple):
@@ -35,8 +40,8 @@ class LayerKind(NamedTuple):
 
 
 class PagePoolSpec(NamedTuple):
-    """One page pool of a family's cache pytree, as its
-    ``cache_page_pools(cfg)`` declares it: the leaf's ``name`` and the
+    """One page pool of a family's cache pytree, as its record's
+    ``page_pools(cfg)`` declares it: the leaf's ``name`` and the
     axis its pages lie on. What moves pages (defrag, the KV auditor's
     gathers, chain export and the cold tier where they carry the pool)
     goes by these, not by the names ``k_pages`` / ``v_pages``."""
@@ -49,8 +54,70 @@ class PagePoolSpec(NamedTuple):
 KV_POOLS = (PagePoolSpec("k_pages", 2), PagePoolSpec("v_pages", 2))
 
 
-# a tick's per-launch counts (a family's ``TICK_COUNTERS``), carried
-# through its walk beside the pools under this key of the cache pytree
+def _one_kind(cfg):
+    return ()
+
+
+def _kv_pools(cfg):
+    return KV_POOLS
+
+
+def _no_rings(cfg):
+    return ()
+
+
+class ServingFamily(NamedTuple):
+    """Everything the engine (``serving/engine.py``) and the shared tick
+    (``models/serving_tick.py``) ask of a serving family: its module
+    exposes exactly one, as ``SERVING``. The record points at the
+    module's plain functions; every field but the first two has the
+    default a model of one kind, each layer holding K and V, needs.
+
+    * ``walk(params, h [1, T, D], cache, meta, cfg, tq, attn_impl) ->
+      (h, cache')``: the layers, over the model's WHOLE cache pytree;
+      the tick owns everything around them.
+    * ``init_pages(cfg, total_pages, page_size, max_batch, max_span) ->
+      cache``: the cache pytree (page 0 of a pool = trash).
+      ``max_batch`` (the slots) sizes what a kind keeps a slot,
+      ``max_span`` (the most query rows a slot brings to one tick: the
+      engine's prefill budget) a window layer's ring; a family with no
+      use for either ignores it.
+    * ``kinds(cfg)``: a ``LayerKind`` a layer, in order (``()``: one
+      kind, pages of K and V). A kind whose cache is not ``"pages"``
+      keeps what a prefix's pages cannot rebuild: the engine then
+      attaches, moves and rolls back nothing, and the tick refuses
+      ``spec_k``.
+    * ``page_pools(cfg)``: the pools whose pages the engine's allocator
+      hands out (``PagePoolSpec``s; ``KV_POOLS``).
+    * ``window_pools(cfg)``: the leaves that are window rings, by name
+      (``()``): they come with the slots and no allocator counts them.
+    * ``tick_pool``: a pool whose second-to-last axis is a page's
+      tokens; all the tick reads of it is that size.
+    * ``counters``: names of the ``[n]`` i32 counts a tick hands back
+      beside its tokens (``()``: none, and no such result); the walk
+      adds to ``cache[COUNTS]``.
+    * ``page_copies(cfg, cache, pages_per_slot, tq) -> int``: copies a
+      tick's launches of ``tq`` rows a slot start for ONE live page, for
+      a kernel other than the K / V one (None: the engine reckons from
+      ``k_pages``).
+    * ``params(params, cfg)``: the tree a serving engine holds, made
+      once an engine: a NEW tree sharing every leaf it does not re-lay
+      (None: the engine serves the tree it was given)."""
+    walk: Callable
+    init_pages: Callable
+    kinds: Callable = _one_kind
+    page_pools: Callable = _kv_pools
+    window_pools: Callable = _no_rings
+    tick_pool: str = "k_pages"
+    counters: tuple = ()
+    page_copies: Optional[Callable] = None
+    params: Optional[Callable] = None
+
+
+# a tick's per-launch counts (a family's ``counters``), carried through
+# its walk beside the pools under this key of the cache pytree: the
+# shared tick puts the zeros there and hands the sums back beside the
+# tokens; a walk adds to ``cache[COUNTS]`` where it finds it
 COUNTS = "tick_counts"
 
 
@@ -71,20 +138,63 @@ def expert_counts(share_counts, num_experts: int):
                       num_experts]).astype(jnp.int32)
 
 
-def with_tick_counts(fn, cache, n: int, has_cur: bool):
-    """Run a tick entry point (``fn(cache) -> (..., [cur_tok',]
-    cache')``) with ``n`` counts carried in the cache under ``COUNTS``,
-    and hand them back BESIDE the tokens: ``(..., counts [n] i32,
-    [cur_tok',] cache')``. The family's walk adds to ``cache[COUNTS]``
-    where it finds it; the engine adds the counts to its counters when
-    the tick completes (no pull of their own)."""
-    *out, new = fn({**cache, COUNTS: jnp.zeros((n,), jnp.int32)})
-    new = dict(new)
-    counts = new.pop(COUNTS)
-    if has_cur:
-        *out, nxt = out
-        return (*out, counts, nxt, new)
-    return (*out, counts, new)
+def tick_plan(meta, tq, heads: int, pool, tables=None, block_tokens: int = 0):
+    """What the ragged kernel's path needs of a tick's packing
+    (``ops/pallas/ragged_paged_attention.py: stream_plan``) for launches
+    of ``heads`` query heads over ``pool``: every walk makes it ONCE a
+    tick outside its layers and hands it to each launch. A slot's rows
+    are contiguous in the stream, up to ``meta['last']``. ``tables``: a
+    launch's own page table (None: ``meta['tables']``)."""
+    return stream_plan(
+        meta["tok_slot"], meta["tok_qoff"], meta["q_len"], meta["kv_len"],
+        meta["tables"] if tables is None else tables, tq, heads, pool,
+        start=meta["last"] - meta["q_len"] + 1, block_tokens=block_tokens)
+
+
+def kv_rows(meta, pool):
+    """Where a tick's K and V rows land in a stacked pool ``[L, Hkv, P,
+    ps, Dh]``: ``(heads [1, Hkv], page [T, 1], offset [T, 1])``. Like
+    the plan, made once a tick OUTSIDE the layers (the compiler does
+    not hoist index arithmetic out of a loop)."""
+    heads = jnp.arange(pool.shape[1], dtype=jnp.int32)[None, :]
+    return heads, meta["tok_page"][:, None], meta["tok_off"][:, None]
+
+
+def paged_kv_attend(q, k, v, kp, vp, layer, meta, at, plan, tq, attn_impl,
+                    sm_scale=None):
+    """One attention layer of a tick over the stacked K / V pools: ``q
+    [1, T, H, Dh]`` and the span's ``k`` / ``v [1, T, Hkv, Dh]`` ->
+    ``(o [1, T, H, Dh], kp', vp')``. The pools are ``[L, Hkv, P, ps,
+    Dh]``, or lane-packed ``[L, Hkv / f, P, ps, f * Dh]``
+    (``lane_pack_factor``), which their row width says; ``at`` /
+    ``plan``: ``kv_rows`` / ``tick_plan`` over ``kp``."""
+    heads, tok_page, tok_off = at
+    pack = kp.shape[-1] // k.shape[-1]
+
+    def landed(pool, x):
+        x = x[0] if pack == 1 else lane_pack_heads(x[0], pack)
+        return pool.at[layer, heads, tok_page, tok_off].set(
+            x.astype(pool.dtype))
+
+    # 1) land the span's KV in the layer's pages, in place on the
+    # carried pool (padding -> trash page). One scattered row per
+    # (token, kv head), the window the head size alone: a window over
+    # the heads (``kp.at[layer, :, page, off]``) makes the chip's
+    # compiler re-lay the WHOLE pool out, heads next to the head size,
+    # around every use of it
+    with jax.named_scope("kv_pool.write"):
+        kp, vp = landed(kp, k), landed(vp, v)
+    # 2) one ragged launch over the pages (span KV included): the packed
+    # entry keeps score work proportional to the T real rows off-TPU and
+    # copies each slot's rows straight into the kernel's blocks on TPU;
+    # the kernel reads the layer's pages where they lie in the stacked
+    # pool
+    with jax.named_scope("ragged_attn"):
+        o = ragged_paged_attention_packed(
+            q[0], kp, vp, meta["tok_slot"], meta["tok_qoff"], meta["q_len"],
+            meta["kv_len"], meta["tables"], tq=tq, sm_scale=sm_scale,
+            impl=attn_impl, layer=layer, plan=plan)
+    return o[None].astype(q.dtype), kp, vp
 
 
 class Group(NamedTuple):

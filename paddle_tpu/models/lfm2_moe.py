@@ -44,14 +44,15 @@ what it keeps between ticks (``KINDS``): pages of K and V
 ``k_pages`` / ``v_pages`` over the attention layers only, ``conv_state``
 ``[Lc, S + 1, K - 1, D]`` over the conv layers (row ``S`` is the trash
 row padding tokens read) — and the serving entry points
-(``serving_tick_cache``, ``serving_tick_block_cache``, every model's)
-take and return that pytree whole; ``serving_cache_kinds`` tells the
-engine what the kinds keep. A kind whose state is a row a slot cannot be rebuilt from a prefix's pages, so
-the engine serves such a model without the prefix cache, chain
-migration, the cold tier and speculation (``serving/engine.py``).
+(``models/serving_tick.py``, every model's) take and return that pytree
+whole; ``serving_cache_kinds`` (the record's ``kinds``) tells the engine
+what the kinds keep. A kind whose state is a row a slot cannot be
+rebuilt from a prefix's pages, so the engine serves such a model without
+the prefix cache, chain migration, the cold tier and speculation
+(``serving/engine.py``).
 
 THE TICK: embedding, final norm, head, fused sampler and the fused
-decode tail are ``models/llama.py: serving_tick_cache``'s; this module
+decode tail are ``models/serving_tick.py``'s; this module
 brings the layer WALK (``_walk``): the leading dense layers, then ONE
 scan over the periods of the layer pattern whose body is the period's
 layers, then the trailing part of a period (``layer_groups``) — a
@@ -72,10 +73,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..incubate.moe.functional import moe_ffn
-from ..ops.pallas.ragged_paged_attention import (lane_pack_factor,
-                                                 lane_pack_heads)
+from ..ops.pallas.ragged_paged_attention import lane_pack_factor
 from . import layer_walk as _lw
-from . import llama as _llama
 from .layer_walk import (Group, LayerKind,  # noqa: F401  (this module's names)
                          _layer_params)
 from .llama import _mm, rms_norm, rope
@@ -375,7 +374,7 @@ def serving_cache_kinds(cfg: Lfm2MoeConfig):
 
 
 def init_serving_pages(cfg: Lfm2MoeConfig, total_pages: int, page_size: int,
-                       max_batch: int):
+                       max_batch: int, max_span: int = 1):
     """The model's cache, ONE pytree built from its kinds: ``k_pages`` /
     ``v_pages`` over the attention layers only (page 0 = trash), lane-
     packed where the head size is under the chip's 128 lanes (``[La,
@@ -410,35 +409,21 @@ def _walk(params, h, cache, meta, cfg: Lfm2MoeConfig, tq, attn_impl):
     in front of them when the span is shorter than the row); idle
     slots, padding tokens and slots dead in a fused tail step (``q_len``
     0 there: mid-prefill) leave every row as it was."""
-    from ..ops.pallas.ragged_paged_attention import (
-        ragged_paged_attention_packed)
     K = cfg.conv_L_cache
     tok_slot, tok_qoff = meta["tok_slot"], meta["tok_qoff"]
     positions = meta["tok_pos"][None]
-    f = lane_pack_factor(cfg.head_dim, cfg.num_key_value_heads)
-    heads = jnp.arange(cfg.num_key_value_heads // f, dtype=jnp.int32)[None]
-    tok_page = meta["tok_page"][:, None]                            # [T, 1]
-    tok_off = meta["tok_off"][:, None]
+    at = _lw.kv_rows(meta, cache["k_pages"])
     q_len, last = meta["q_len"], meta["last"]
-    plan = _llama.tick_plan(meta, tq, cfg.num_attention_heads,
-                            cache["k_pages"])
+    plan = _lw.tick_plan(meta, tq, cfg.num_attention_heads,
+                         cache["k_pages"])
 
     def attn_layer(lp, h, kp, vp, layer):
         cell = {}
 
         def attn_fn(q, k, v):
-            with jax.named_scope("kv_pool.write"):
-                kp2 = kp.at[layer, heads, tok_page, tok_off].set(
-                    lane_pack_heads(k[0], f).astype(kp.dtype))
-                vp2 = vp.at[layer, heads, tok_page, tok_off].set(
-                    lane_pack_heads(v[0], f).astype(vp.dtype))
-            cell["kp"], cell["vp"] = kp2, vp2
-            with jax.named_scope("ragged_attn"):
-                o = ragged_paged_attention_packed(
-                    q[0], kp2, vp2, tok_slot, tok_qoff, q_len,
-                    meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl,
-                    layer=layer, plan=plan)
-            return o[None].astype(q.dtype)
+            o, cell["kp"], cell["vp"] = _lw.paged_kv_attend(
+                q, k, v, kp, vp, layer, meta, at, plan, tq, attn_impl)
+            return o
 
         h = _attn_op(lp, h, positions, cfg, attn_fn)
         return h, cell["kp"], cell["vp"]
@@ -485,27 +470,5 @@ def _walk(params, h, cache, meta, cfg: Lfm2MoeConfig, tq, attn_impl):
     return h, {"k_pages": kp, "v_pages": vp, "conv_state": cs}
 
 
-def serving_tick_cache(params, tokens, meta, cache, cfg: Lfm2MoeConfig,
-                       tq: int = 1, decode_tail: int = 0, spec_k: int = 0,
-                       attn_impl: str = "auto"):
-    """ONE ragged serving tick (``models/llama.py serving_tick_cache``
-    with this model's walk) over this model's cache pytree: returns
-    ``(toks, logits, cache')``, with ``meta['cur_tok']``
-    ``(toks, logits, cur_tok', cache')``."""
-    if spec_k:
-        raise ValueError("no speculative verify for a model with per-slot "
-                         "state: a rejected draft's state cannot be rolled "
-                         "back")
-    return _llama.serving_tick_cache(
-        params, tokens, meta, cache, cfg, tq=tq, decode_tail=decode_tail,
-        attn_impl=attn_impl, walk=_walk)
-
-
-def serving_tick_block_cache(params, tok, lengths, tables, cache,
-                             cfg: Lfm2MoeConfig, num_steps: int,
-                             attn_impl: str = "auto", sampling=None):
-    """``num_steps`` fused decode ticks: ``(toks [S, num_steps], tok'
-    [S], cache')``."""
-    return _llama.serving_tick_block_cache(
-        params, tok, lengths, tables, cache, cfg, num_steps,
-        attn_impl=attn_impl, sampling=sampling, walk=_walk)
+SERVING = _lw.ServingFamily(walk=_walk, init_pages=init_serving_pages,
+                            kinds=serving_cache_kinds)

@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..observability import in_setup_span, setup_span
 from ..ops.pallas.flash_attention import remat_layer
 from ..parallel.pipeline_spmd import pipeline_spmd, microbatch
+from .layer_walk import ServingFamily, kv_rows, paged_kv_attend, tick_plan
 
 
 @dataclasses.dataclass
@@ -1134,71 +1135,6 @@ def sample_logits(logits, key, temperature: float = 1.0,
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
-def _fused_sample(logits, temp, top_p, top_k, key, idx):
-    """In-graph per-row sampling head of the serving tick (r16): the
-    generalization of the fused argmax that lets SAMPLING requests
-    ride the same programs as greedy ones. Greedy rows (temp == 0)
-    take ``jnp.argmax`` — BITWISE the pre-r16 fused path, so every
-    greedy==generate() pin survives; sampling rows apply temperature →
-    top-k → top-p masking (``sample_logits`` semantics, but per-row
-    DATA instead of static kwargs) and draw one gumbel/categorical
-    token.
-
-    Determinism discipline: the draw for a slot's token at
-    continuation index ``idx[s]`` uses ``fold_in(key[s], idx[s])`` —
-    the token INDEX keys the draw, not a split chain advanced per
-    device step. A fixed seed therefore emits one token stream
-    whatever the batch composition, fused-block boundaries or
-    speculation around it: tokens a fused block computed past EOS, or
-    drafts a verify rejected, burn no key state — the next launch
-    re-draws the same index with the same key.
-
-    logits ``[S, V]`` f32; temp/top_p ``[S]`` f32; top_k ``[S]`` i32
-    (0 = filter off); key ``[S, 2]`` u32 raw per-slot PRNG keys; idx
-    ``[S]`` i32. Returns ``[S]`` i32.
-
-    Cost discipline: the whole sampling branch (sort, cumsum,
-    categorical) sits behind a ``lax.cond`` on ``any(temp > 0)`` —
-    still ONE program (the predicate is data), but an all-greedy tick
-    executes only the argmax at runtime, so folding sampling into
-    every program does not tax greedy traffic (measured: the sort is
-    the dominant cost on the CPU mesh)."""
-    V = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def _draw(_):
-        l = logits / jnp.maximum(temp, 1e-6)[:, None]
-        # top-k with k as data: cutoff at the k-th largest (k=0/off ->
-        # the smallest value, masking nothing; ties at the cutoff
-        # survive, matching sample_logits)
-        srt = jnp.sort(l, axis=-1)[:, ::-1]
-        k_eff = jnp.where(top_k > 0, jnp.minimum(top_k, V), V)
-        kth = jnp.take_along_axis(srt, (k_eff - 1)[:, None], axis=-1)
-        # top-p over the top-k-masked logits (sample_logits order).
-        # ONE sort suffices: the masked row's descending sort is the
-        # original sort with sub-cutoff positions replaced (ties at
-        # the cutoff survive masking in both views). The top-1 token
-        # is always kept so top_p=0 degrades to greedy, and cutoff is
-        # the SMALLEST kept logit.
-        srt2 = jnp.where(srt >= kth, srt, -1e30)
-        probs = jax.nn.softmax(srt2, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        keep = (cum - probs) < top_p[:, None]
-        keep = keep.at[:, 0].set(True)
-        cutoff = jnp.min(jnp.where(keep, srt2, jnp.inf), axis=-1)
-        masked = jnp.where(l < kth, -1e30, l)
-        masked = jnp.where(masked < cutoff[:, None], -1e30, masked)
-
-        def draw(k, n, row):
-            return jax.random.categorical(jax.random.fold_in(k, n), row)
-
-        return jax.vmap(draw)(key, idx, masked).astype(jnp.int32)
-
-    sampled = jax.lax.cond(jnp.any(temp > 0.0), _draw,
-                           lambda _: greedy, None)
-    return jnp.where(temp <= 0.0, greedy, sampled)
-
-
 def _decode_loop(fwd_cache_fn, init_cache_fn, params, prompt,
                  max_new_tokens: int, temperature, top_p, top_k, key,
                  eos_token_id):
@@ -1395,26 +1331,21 @@ def generate_paged(params, prompt, lengths, cfg: LlamaConfig,
 
 
 # ---------------------------------------------------------------------------
-# serving: the tick over a SHARED page pool
+# serving: this family's cache and layer walk
 # ---------------------------------------------------------------------------
-# The continuous-batching engine (paddle_tpu/serving/) calls a model
-# through THREE functions, once per tick against a persistent cache
-# pytree: ``init_serving_pages`` builds the cache, ``serving_tick_cache``
-# runs one ragged tick over it and ``serving_tick_block_cache`` a fused
-# block of decode ticks. (generate_paged, by contrast, builds its cache
-# fresh per batch and fuses its decode loop into one scan.) Pages are
-# allocated per REQUEST by the host-side PagePool (serving/scheduler.py)
-# and freed the moment a sequence retires, so a long generation never
-# holds cache capacity hostage for the whole batch. The block math is
+# What the engine and the shared tick (``models/serving_tick.py``) ask of
+# a family is ONE record, ``SERVING`` (``models/layer_walk.py:
+# ServingFamily``; this module's stands at its end). The block math is
 # _block — the same single source of truth the training and fused-scan
 # decode paths use.
 
 
 def init_serving_pages(cfg, total_pages: int, page_size: int,
-                       max_batch: int = 0):
+                       max_batch: int = 0, max_span: int = 1):
     """The serving cache pytree: layer-stacked page pools ``[L, Hkv, P,
     ps, Dh]`` (page 0 = trash). ``max_batch`` (the slots) sizes what a
-    layer kind keeps a slot; a model of pages alone has no use for it."""
+    layer kind keeps a slot and ``max_span`` a window layer's ring; a
+    model of pages alone has no use for either."""
     L, Hkv, Dh = (cfg.num_hidden_layers, cfg.num_key_value_heads,
                   cfg.head_dim)
     shape = (L, Hkv, total_pages, page_size, Dh)
@@ -1422,45 +1353,18 @@ def init_serving_pages(cfg, total_pages: int, page_size: int,
             "v_pages": jnp.zeros(shape, cfg.dtype)}
 
 
-def _one_kind_walk(block_fn=None):
-    """The walk of a model whose layers are all ``block_fn`` (None:
-    ``_block``)."""
-    return partial(_walk_one_kind,
-                   block_fn=block_fn if block_fn is not None else _block)
-
-
-def tick_plan(meta, tq, heads: int, pool, tables=None, block_tokens: int = 0):
-    """What the ragged kernel's path needs of a tick's packing
-    (``ops/pallas/ragged_paged_attention.py: stream_plan``) for launches
-    of ``heads`` query heads over ``pool``: every walk makes it ONCE a
-    tick outside its layers and hands it to each launch. A slot's rows
-    are contiguous in the stream, up to ``meta['last']``. ``tables``: a
-    launch's own page table (None: ``meta['tables']``)."""
-    from ..ops.pallas.ragged_paged_attention import stream_plan
-    return stream_plan(
-        meta["tok_slot"], meta["tok_qoff"], meta["q_len"], meta["kv_len"],
-        meta["tables"] if tables is None else tables, tq, heads, pool,
-        start=meta["last"] - meta["q_len"] + 1, block_tokens=block_tokens)
-
-
-def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl, block_fn):
+def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl):
     """The layer walk of a model whose layers are ONE kind, each holding
     K and V: ``params['layers']`` scanned, the two stacked pools in the
     carry. A walk is ``walk(params, h [1, T, D], cache, meta, cfg, tq,
     attn_impl) -> (h, cache)`` over the model's WHOLE cache pytree;
-    ``serving_tick_cache`` owns everything around it. A model whose
+    ``models/serving_tick.py`` owns everything around it. A model whose
     layers follow a pattern of kinds brings its own
     (``models/lfm2_moe.py``)."""
-    from ..ops.pallas.ragged_paged_attention import (
-        ragged_paged_attention_packed)
     k_pages, v_pages = cache["k_pages"], cache["v_pages"]
-    tok_slot = meta["tok_slot"]
-    tok_qoff = meta["tok_qoff"]
     plan = tick_plan(meta, tq, cfg.num_attention_heads, k_pages)
     positions = meta["tok_pos"][None]
-    heads = jnp.arange(k_pages.shape[1], dtype=jnp.int32)[None, :]  # [1, Hkv]
-    tok_page = meta["tok_page"][:, None]                            # [T, 1]
-    tok_off = meta["tok_off"][:, None]
+    at = kv_rows(meta, k_pages)
 
     def body(carry, xs):
         h, kp, vp = carry
@@ -1468,31 +1372,11 @@ def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl, block_fn):
         cell = {}
 
         def attn_fn(q, k, v):
-            # 1) land the span's KV in the layer's pages, in place on
-            # the carried pool (padding -> trash page). One scattered
-            # row per (token, kv head), the window the head size alone:
-            # a window over the heads (``kp.at[layer, :, page, off]``)
-            # makes the chip's compiler re-lay the WHOLE pool out,
-            # heads next to the head size, around every use of it
-            with jax.named_scope("kv_pool.write"):
-                kp2 = kp.at[layer, heads, tok_page, tok_off].set(
-                    k[0].astype(kp.dtype))
-                vp2 = vp.at[layer, heads, tok_page, tok_off].set(
-                    v[0].astype(vp.dtype))
-            cell["kp"], cell["vp"] = kp2, vp2
-            # 2) one ragged launch over the pages (span KV included):
-            # the packed entry keeps score work proportional to the T
-            # real rows off-TPU and copies each slot's rows straight
-            # into the kernel's blocks on TPU; the kernel reads the
-            # layer's pages where they lie in the stacked pool
-            with jax.named_scope("ragged_attn"):
-                o = ragged_paged_attention_packed(
-                    q[0], kp2, vp2, tok_slot, tok_qoff, meta["q_len"],
-                    meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl,
-                    layer=layer, plan=plan)
-            return o[None].astype(q.dtype)
+            o, cell["kp"], cell["vp"] = paged_kv_attend(
+                q, k, v, kp, vp, layer, meta, at, plan, tq, attn_impl)
+            return o
 
-        h = block_fn(lp, h, positions, cfg, attn_fn)
+        h = _block(lp, h, positions, cfg, attn_fn)
         return (h, cell["kp"], cell["vp"]), None
 
     # the scan CARRIES the stacked pools: the donated parameter is the
@@ -1506,351 +1390,6 @@ def _walk_one_kind(params, h, cache, meta, cfg, tq, attn_impl, block_fn):
             (params["layers"],
              jnp.arange(k_pages.shape[0], dtype=jnp.int32)))
     return h, {"k_pages": kp_new, "v_pages": vp_new}
-
-
-def serving_tick_cache(params, tokens, meta, cache, cfg, tq: int = 1,
-                       decode_tail: int = 0, spec_k: int = 0,
-                       attn_impl: str = "auto", walk=None,
-                       page_pool: str = "k_pages"):
-    """ONE ragged serving tick over a model's whole cache pytree: any mix
-    of chunked prefills, warm-prefix attaches and decode steps as a
-    single static program. Sequence geometry rides in ``meta`` as DEVICE
-    ARRAYS, so XLA compiles exactly one program per packed width:
-    prompt length, chunk position and attached-prefix size are data.
-
-    This is how the engine calls every model: the embedding, the final
-    norm, the head, the fused sampler, the verify pass and the fused
-    decode tail are here, once; the layers are ``walk``'s (None: this
-    model's, see ``_walk_one_kind``; another model's module hands its
-    own). ``cache`` is what the model's ``init_serving_pages`` built and
-    is DONATED by the engine — its page pools (``k_pages`` / ``v_pages``
-    ``[L_attn, Hkv, P, ps, Dh]``, or what the family declares:
-    ``page_pool`` names one whose second-to-last axis is the page's
-    tokens, which is all this function reads of it), and whatever else
-    its layer kinds keep (a fixed row a slot, ...); the new cache is the
-    last result.
-
-    TWO SCALARS OF THE CONFIG, read with ``getattr`` as trace-time
-    facts: ``embedding_multiplier`` (the embedding lookup times it) and
-    ``logits_scaling`` (the float32 logits over it, before the sampler
-    and the verify pass). A config without them, or with 1.0, emits no
-    operation (``models/granite_hybrid.py`` sets both).
-
-    tokens ``[T]`` i32 — the tick's packed token stream: each live
-    slot's current decode token and/or a span of some prompt's next
-    uncached tokens, concatenated (padding tokens allowed anywhere).
-    meta — a dict of device arrays describing the packing:
-
-    * ``tok_slot [T]``: owning slot of each packed token (``S`` = a
-      padding token that must touch nothing real);
-    * ``tok_pos [T]``: the token's absolute sequence position;
-    * ``tok_page [T]`` / ``tok_off [T]``: the page id and in-page
-      offset its KV lands at (TRASH page for padding);
-    * ``tok_qoff [T]``: offset of the token inside its slot's span;
-    * ``q_len [S]``: span length per slot (0 = slot idle this tick);
-    * ``kv_len [S]``: keys visible at the END of the span (context +
-      the span itself);
-    * ``last [S]``: packed index of each slot's LAST span token — its
-      hidden state feeds that slot's logits row (idle slots may point
-      anywhere; their row is junk the host discards);
-    * ``tables [S, pps]``: the page-table rows.
-
-    THE SLOTS' CURRENT TOKENS — optional ``meta['cur_tok'] [S]`` i32 (a
-    trace-time fact, like the sampling state; the engine ALWAYS passes
-    it): the token each slot produced last, kept on the device from one
-    tick to the next, so the host need not read tick N's tokens back
-    before it launches tick N+1. A packed token ``< 0`` takes its value
-    from ``cur_tok[tok_slot]`` in-graph (a decode row, the first token
-    of a drafted span; prompt-span tokens come from the host as they
-    are), and the successor is returned just before the cache: for
-    every slot that produced a token this tick (``meta['tail_live']``:
-    a decode row, a span completing its prompt) its LAST token (the
-    last fused tail step's; with ``spec_k`` the bonus/correction token
-    ``toks[s, accept[s]]``), for every other slot the old value.
-
-    FUSED SAMPLING — five more optional meta arrays, all DATA, turn
-    every token selection in the tick (last-position pick, fused tail
-    steps, speculative verify) into a per-slot temperature/top-k/top-p
-    gumbel draw via ``_fused_sample``: ``temp [S]`` f32 / ``top_p [S]``
-    f32 / ``top_k [S]`` i32 (0 = off) / ``key [S, 2]`` u32 raw per-slot
-    PRNG keys / ``produced [S]`` i32 — the continuation index of the
-    token this launch emits; token ``n`` is always drawn with
-    ``fold_in(key, n)``, so a fixed seed yields one stream whatever the
-    batch composition, block fusion or speculation (see
-    ``_fused_sample``). Greedy rows (temp == 0) keep the bitwise
-    argmax. The engine ALWAYS passes these (presence is a trace-time
-    fact): sampling slots ride the same programs as greedy ones.
-
-    THREE MODES, chosen by two STATIC arguments (one compile per value):
-
-    * plain (``decode_tail == spec_k == 0``): the ragged pass alone;
-    * ``decode_tail`` fuses that many extra decode steps after the
-      ragged pass — the multi-step scheduling lever that keeps an
-      admission tick producing a full decode block for in-flight
-      streams, in the SAME program. ``meta['tail_live'] [S]`` bool
-      gates it: only tail-live slots (decoding slots, plus spans that
-      complete their prompt this tick) advance — mid-prefill slots stay
-      dead through the tail (q_len 0, KV writes to the trash page);
-    * ``spec_k`` (the engine's draft-length cap; a speculative engine
-      uses exactly one) turns the tick into the speculative VERIFY
-      program: speculating slots submitted their current token plus up
-      to ``spec_k`` draft tokens as an ordinary ragged span (the same
-      packed stream, mixed with prefill spans and plain decode slots),
-      and the tick additionally computes the target model's token at
-      EVERY span position plus the in-graph longest-prefix acceptance
-      against the drafts. Three extra ``meta`` arrays carry the
-      (per-slot, DATA-not-shape) speculation geometry: ``ver_idx [S,
-      1+spec_k]``, the packed index of each slot's span token ``j``
-      (position ``j``'s hidden state predicts span position ``j+1``;
-      non-speculating slots point every entry at their ``last`` token,
-      so their row 0 reproduces the plain tick's logits exactly), and
-      ``draft_tok [S, spec_k]`` / ``draft_len [S]``, the draft tokens
-      and each slot's actual draft count ``k_s <= spec_k`` (0 for
-      non-speculating slots — adaptive k is data, the cap is the only
-      shape). ``spec_k`` and ``decode_tail`` are mutually exclusive
-      (speculation IS the multi-token lever on a speculative engine).
-
-    ``tq`` (STATIC; the engine passes the span width of the tick's entry
-    in its width grid) is the maximum span length, sizing the kernel's
-    slot-major query layout.
-
-    Returns ``(toks, logits [S, V] f32, cache')`` (with ``cur_tok``:
-    ``(toks, logits, cur_tok', cache')``; with ``spec_k`` too:
-    ``(toks, accept, logits, cur_tok', cache')``): ``toks`` is each
-    slot's in-graph token pick at its last position (argmax, or the
-    fused sampler's draw) — ``[S]`` i32 when ``decode_tail == 0``, else
-    ``[S, 1+decode_tail]`` (the host pulls only these ints); ``logits``
-    is the RAGGED pass's (first step's) logits, kept for callers that
-    sample their own way — the engine never reads it, it stays on
-    device and is dropped. With ``spec_k > 0`` the return is ``(toks
-    [S, 1+spec_k], accept [S], logits [S, V] f32, cache')``: ``toks[s,
-    j]`` is the target's token after consuming span tokens ``0..j``,
-    ``accept[s]`` the number of leading drafts matching it (``toks[s,
-    :accept[s]]`` equal the drafts token-for-token and ``toks[s,
-    accept[s]]`` is the bonus/correction token — ``1 + accept`` emitted
-    tokens from ONE target launch), and ``logits`` is row 0's logits.
-    Rejected draft KV needs no device-side rollback: the stale rows sit
-    past the slot's advanced length, masked by ``kv_len`` until the
-    sequence's real tokens overwrite them positionally — the same
-    trash-row discipline retiring overruns already rely on.
-
-    Exactness: the span's KV is scattered into the pages FIRST, then
-    the ragged kernel attends over pages only, bottom-right causal —
-    so a prefix's KV is a function of the prefix tokens alone and
-    chunked/whole/warm prefills all produce the bits a single
-    whole-prompt pass would (tests pin greedy equality to
-    ``generate()`` in every cache state).
-    """
-    if walk is None:
-        walk = _one_kind_walk()
-    tq = int(tq)
-    spec_k = int(spec_k)
-    decode_tail = int(decode_tail)
-    if spec_k and decode_tail:
-        raise ValueError("spec_k and decode_tail are mutually "
-                         "exclusive (speculation replaces the "
-                         "fused greedy tail)")
-    S = meta["q_len"].shape[0]
-    cur = meta.get("cur_tok")
-    with jax.named_scope("embed"):
-        if cur is not None:
-            tokens = jnp.where(
-                tokens < 0, cur[jnp.minimum(meta["tok_slot"], S - 1)],
-                tokens)
-        h = params["embed"].astype(cfg.dtype)[tokens[None]]    # [1, T, D]
-        # a family that scales its embedding (trace-time facts of the
-        # config, like the divisor of the logits below: 1.0 emits
-        # nothing)
-        if getattr(cfg, "embedding_multiplier", 1.0) != 1.0:
-            h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
-    logits_scaling = float(getattr(cfg, "logits_scaling", 1.0))
-    h, cache_new = walk(params, h, cache, meta, cfg, tq, attn_impl)
-    with jax.named_scope("lm_head"):
-        h = rms_norm(h[0], params["final_norm"], cfg.rms_norm_eps)  # [T, D]
-    # fused sampling (r16): when the meta carries per-slot sampling
-    # state — temp/top_p [S] f32, top_k [S] i32, key [S, 2] u32 raw
-    # PRNG keys, produced [S] i32 (the continuation index of the token
-    # this launch emits) — every token selection below goes through
-    # _fused_sample instead of bare argmax, so SAMPLING slots ride the
-    # same program as greedy ones (the engine always passes the
-    # fields; presence is a trace-time fact, not a per-tick branch).
-    # Greedy rows still take the bitwise argmax path inside.
-    samp = "temp" in meta
-
-    def pick(logits, idx):
-        if samp:
-            return _fused_sample(logits, meta["temp"], meta["top_p"],
-                                 meta["top_k"], meta["key"], idx)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def verify(logits_ver):
-        """The verify pass's token at every span position and the
-        longest accepted draft prefix, ``(toks [S, 1+spec_k],
-        accept [S])``."""
-        if samp:
-            # SAMPLED acceptance (spec_k is no longer greedy-only):
-            # span position j draws the token for continuation index
-            # produced+j — the same fold_in key a plain tick would
-            # use at that index, and conditioning over the accepted
-            # prefix is exact by construction, so the emitted stream
-            # is bitwise the non-speculative engine's whatever the
-            # drafter proposed. Greedy slots still argmax (temp==0).
-            kk = 1 + spec_k
-            idx = (meta["produced"][:, None]
-                   + jnp.arange(kk, dtype=jnp.int32)[None]).reshape(-1)
-            toks = _fused_sample(
-                logits_ver.reshape(S * kk, -1),
-                jnp.repeat(meta["temp"], kk),
-                jnp.repeat(meta["top_p"], kk),
-                jnp.repeat(meta["top_k"], kk),
-                jnp.repeat(meta["key"], kk, axis=0),
-                idx).reshape(S, kk)
-        else:
-            toks = jnp.argmax(logits_ver, axis=-1).astype(jnp.int32)
-        # longest-prefix acceptance: draft j is accepted iff every
-        # draft 0..j matched the target's token (sampled or argmax) at
-        # its span position (cumprod zeroes everything after the first
-        # mismatch) and j is a real draft (j < draft_len — adaptive k
-        # is data)
-        j = jnp.arange(spec_k)
-        match = ((toks[:, :spec_k] == meta["draft_tok"])
-                 & (j[None, :] < meta["draft_len"][:, None]))
-        accept = jnp.cumprod(match.astype(jnp.int32), axis=1) \
-                    .sum(axis=1).astype(jnp.int32)
-        return toks, accept
-
-    def result(last, *rest):
-        """The tick's results; with ``cur_tok`` its successor (``last``
-        ``[S]`` where the slot produced a token) before the cache."""
-        if cur is None:
-            return (*rest, cache_new)
-        with jax.named_scope("sampler"):
-            nxt = jnp.where(meta["tail_live"], last, cur)
-        return (*rest, nxt, cache_new)
-
-    if spec_k:
-        # logits at EVERY span position of every slot — the verify
-        # pass's whole point: one launch prices 1+spec_k predictions
-        with jax.named_scope("lm_head"):
-            h_ver = h[meta["ver_idx"]]              # [S, 1+spec_k, D]
-            logits_ver = _mm(h_ver, params["lm_head"]).astype(jnp.float32)
-            if logits_scaling != 1.0:
-                logits_ver = logits_ver / logits_scaling
-        with jax.named_scope("sampler"):
-            toks, accept = verify(logits_ver)
-        # row 0 == the plain tick's logits for every non-speculating
-        # slot (ver_idx[:, 0] = last there)
-        return result(toks[jnp.arange(S), accept], toks, accept,
-                      logits_ver[:, 0])
-    with jax.named_scope("lm_head"):
-        h_last = h[meta["last"]]                                # [S, D]
-        logits = _mm(h_last, params["lm_head"]).astype(jnp.float32)
-        if logits_scaling != 1.0:
-            logits = logits / logits_scaling
-    with jax.named_scope("sampler"):
-        toks = pick(logits, meta["produced"] if samp else None)
-    if not decode_tail:
-        return result(toks, toks, logits)
-
-    ps = cache[page_pool].shape[-2]
-    pps = meta["tables"].shape[1]
-    b_idx = jnp.arange(S, dtype=jnp.int32)
-    zeros = jnp.zeros((S,), jnp.int32)
-    live = meta["tail_live"].astype(jnp.bool_)
-
-    def step(carry, _):
-        tok, lens, idx, cache_t = carry
-        slot = lens // ps
-        # rows out of pages (retiring overruns), dead all-TRASH rows
-        # and tail-dead (mid-prefill) slots land on the trash page
-        # (page 0, offset 0), which nothing reads
-        ok = live & (slot < pps)
-        page = jnp.where(
-            ok, meta["tables"][b_idx, jnp.minimum(slot, pps - 1)], 0)
-        m = dict(tok_slot=jnp.where(live, b_idx, S).astype(jnp.int32),
-                 tok_pos=lens, tok_page=page.astype(jnp.int32),
-                 tok_off=jnp.where(ok, lens % ps, 0).astype(jnp.int32),
-                 tok_qoff=zeros, q_len=live.astype(jnp.int32),
-                 kv_len=lens + 1, last=b_idx, tables=meta["tables"])
-        if samp:
-            # step j of the tail samples continuation index
-            # produced + j: the fold_in discipline, not a split chain
-            m.update(temp=meta["temp"], top_p=meta["top_p"],
-                     top_k=meta["top_k"], key=meta["key"],
-                     produced=idx)
-        nxt, _, cache_t = serving_tick_cache(
-            params, tok, m, cache_t, cfg, tq=1, attn_impl=attn_impl,
-            walk=walk, page_pool=page_pool)
-        return (nxt, lens + 1, idx + 1, cache_t), nxt
-
-    idx0 = (meta["produced"] + 1) if samp else zeros
-    (_, _, _, cache_new), tail = lax.scan(
-        step, (toks, meta["kv_len"], idx0, cache_new), None,
-        length=decode_tail)
-    toks = jnp.concatenate([toks[:, None], jnp.moveaxis(tail, 0, 1)],
-                           axis=1)                    # [S, 1+tail]
-    return result(toks[:, -1], toks, logits)
-
-
-def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
-                             num_steps: int, attn_impl: str = "auto",
-                             sampling=None, walk=None,
-                             page_pool: str = "k_pages"):
-    """``num_steps`` fused decode ticks built on the ragged tick (the
-    multi-step scheduling lever: per-call dispatch + host bookkeeping
-    amortize over the block) over a model's whole cache pytree and its
-    layer ``walk`` (see ``serving_tick_cache``). Greedy slots are
-    in-graph argmax and match single-step decode exactly. tok/lengths
-    ``[S]`` i32, tables ``[S, pps]``. ``tok`` is the slots' current
-    tokens as the engine keeps them on the device (``meta['cur_tok']``
-    of the tick), and its successor is returned: a live slot's last
-    token of the block, a dead slot's old value.
-
-    A slot with ``lengths == 0`` (free, or admitted and not yet
-    prefilled) is DEAD to the block: it enters the tick with no query
-    row (``q_len`` 0, the slot sentinel for its token), attends
-    nothing, writes to the trash page, and its returned tokens mean
-    nothing. The host truncates a sequence's tokens at
-    EOS/max_new_tokens; positions a retiring sequence wrote past its
-    pages land on the trash page, so neighbours never see them.
-
-    ``sampling``: a dict of the fused-sampling meta arrays —
-    ``temp``/``top_p`` f32 [S], ``top_k`` i32 [S], ``key`` u32 [S, 2],
-    ``produced`` i32 [S] — letting SAMPLING slots ride the fused block
-    too (step ``j`` draws continuation index ``produced + j`` via the
-    fold_in discipline); None keeps the all-greedy block. Returns
-    ``(toks [S, num_steps] i32, tok' [S] i32, cache')``."""
-    S = tok.shape[0]
-    pps = tables.shape[1]
-    ps = cache[page_pool].shape[-2]
-    b_idx = jnp.arange(S, dtype=jnp.int32)
-    slot = lengths // ps
-    # a slot that holds no context (free, or admitted and not yet
-    # prefilled: the scheduler keeps its length 0) is DEAD to the step,
-    # as a tail-dead slot is to the tail's: no query row, so the ragged
-    # kernel's walk skips it, where a row of its own would walk the
-    # trash page in every layer
-    live = lengths > 0
-    # rows out of pages (retiring overruns) and dead all-TRASH rows
-    # land on the trash page (page 0, offset 0), which nothing reads
-    ok = live & (slot < pps)
-    page = jnp.where(ok, tables[b_idx, jnp.minimum(slot, pps - 1)], 0)
-    meta = dict(tok_slot=jnp.where(live, b_idx, S).astype(jnp.int32),
-                tok_pos=lengths, tok_page=page,
-                tok_off=jnp.where(ok, lengths % ps, 0),
-                tok_qoff=jnp.zeros((S,), jnp.int32),
-                q_len=live.astype(jnp.int32), kv_len=lengths + 1,
-                last=b_idx, tables=tables, tail_live=live, cur_tok=tok)
-    if sampling is not None:
-        meta.update(temp=sampling["temp"], top_p=sampling["top_p"],
-                    top_k=sampling["top_k"], key=sampling["key"],
-                    produced=sampling["produced"])
-    toks, _, nxt, cache = serving_tick_cache(
-        params, tok, meta, cache, cfg, tq=1, decode_tail=num_steps - 1,
-        attn_impl=attn_impl, walk=walk, page_pool=page_pool)
-    if num_steps == 1:
-        toks = toks[:, None]
-    return toks, nxt, cache
 
 
 def make_batch(cfg: LlamaConfig, batch_size: int, seq_len: int, mesh: Mesh,
@@ -1900,11 +1439,11 @@ def _output_major(w):
 
 
 def serving_params(params, cfg):
-    """The tree a serving engine holds for ``params`` (an optional
-    function of a family's module; ``ServingEngine`` calls it once, at
-    construction): a NEW tree that shares every leaf with the caller's
-    but the dense ``layers.wq`` / ``.wk`` / ``.wv`` stacks, which it
-    holds output-major ``[L, O, D]`` under ``wq_om`` / ``wk_om`` /
+    """The tree a serving engine holds for ``params`` (the record's
+    ``params``; ``ServingEngine`` calls it once, at construction): a
+    NEW tree that shares every leaf with the caller's but the dense
+    ``layers.wq`` / ``.wk`` / ``.wv`` stacks, which it holds
+    output-major ``[L, O, D]`` under ``wq_om`` / ``wk_om`` /
     ``wv_om`` (one transpose a stack, made here). ``_proj`` reads
     either. The caller's tree is not touched; a stack that is no dense
     array (``Int8Weight``) stays as and where it is."""
@@ -1913,3 +1452,7 @@ def serving_params(params, cfg):
         if not hasattr(layers[name], "dequant_matmul"):
             layers[name + OUTPUT_MAJOR] = _output_major(layers.pop(name))
     return dict(params, layers=layers)
+
+
+SERVING = ServingFamily(walk=_walk_one_kind, init_pages=init_serving_pages,
+                        params=serving_params)

@@ -88,7 +88,6 @@ from ..incubate.moe.functional import moe_ffn_share
 from ..ops.pallas.mla_paged_attention import (latent_row_width,
                                               mla_paged_attention)
 from . import layer_walk as _lw
-from . import llama as _llama
 from .layer_walk import (COUNTS, Group, LayerKind, PagePoolSpec,
                          _layer_params)
 from .llama import _mm, rms_norm
@@ -98,8 +97,8 @@ MLA = "mla"
 POOL = "latent_pages"
 # the tick's per-launch counts, carried through the walk beside the pool
 # (``layer_walk.COUNTS``) and handed back BESIDE the tokens
-# (``TICK_COUNTERS`` names them for the engine, which adds them when the
-# tick completes)
+# (the record's ``counters`` names them for the engine, which adds them
+# when the tick completes)
 TICK_COUNTERS = ("moe_pairs_held", "moe_pairs_zero", "moe_pairs_absent",
                  "moe_experts_touched")
 
@@ -372,10 +371,10 @@ def cache_page_pools(cfg: LongcatFlashConfig):
 
 
 def init_serving_pages(cfg: LongcatFlashConfig, total_pages: int,
-                       page_size: int, max_batch: int):
+                       page_size: int, max_batch: int, max_span: int = 1):
     """The model's cache: one latent pool over the ``2L`` attention
     sublayers (page 0 = trash), a row ``[c_kv | k_r | 0]``."""
-    del max_batch
+    del max_batch, max_span
     return {POOL: jnp.zeros((2 * cfg.num_layers, total_pages, page_size,
                              cfg.row_width), cfg.dtype)}
 
@@ -453,30 +452,7 @@ def _walk(params, h, cache, meta, cfg: LongcatFlashConfig, tq, attn_impl):
     return h, new
 
 
-def serving_tick_cache(params, tokens, meta, cache, cfg: LongcatFlashConfig,
-                       tq: int = 1, decode_tail: int = 0, spec_k: int = 0,
-                       attn_impl: str = "auto"):
-    """ONE ragged serving tick (``models/llama.py serving_tick_cache``
-    with this model's walk) over this model's cache pytree: ``(toks,
-    logits, counts, cache')``, with ``meta['cur_tok']`` ``(toks, logits,
-    counts, cur_tok', cache')`` (with ``spec_k``: ``toks, accept,
-    logits, counts, ...``); ``counts [4]`` i32 are the tick's
-    ``TICK_COUNTERS`` over its launches."""
-    return _lw.with_tick_counts(
-        lambda c: _llama.serving_tick_cache(
-            params, tokens, meta, c, cfg, tq=tq, decode_tail=decode_tail,
-            spec_k=spec_k, attn_impl=attn_impl, walk=_walk, page_pool=POOL),
-        cache, len(TICK_COUNTERS), "cur_tok" in meta)
-
-
-def serving_tick_block_cache(params, tok, lengths, tables, cache,
-                             cfg: LongcatFlashConfig, num_steps: int,
-                             attn_impl: str = "auto", sampling=None):
-    """``num_steps`` fused decode ticks: ``(toks [S, num_steps], counts
-    [4], tok' [S], cache')``."""
-    return _lw.with_tick_counts(
-        lambda c: _llama.serving_tick_block_cache(
-            params, tok, lengths, tables, c, cfg, num_steps,
-            attn_impl=attn_impl, sampling=sampling, walk=_walk,
-            page_pool=POOL),
-        cache, len(TICK_COUNTERS), True)
+SERVING = _lw.ServingFamily(
+    walk=_walk, init_pages=init_serving_pages, kinds=serving_cache_kinds,
+    page_pools=cache_page_pools, tick_pool=POOL, counters=TICK_COUNTERS,
+    page_copies=cache_page_copies)

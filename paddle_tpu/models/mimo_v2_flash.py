@@ -102,7 +102,6 @@ import jax.numpy as jnp
 from ..incubate.moe.functional import moe_ffn_share
 from ..ops.pallas import ragged_paged_attention as _rpa
 from . import layer_walk as _lw
-from . import llama as _llama
 from .layer_walk import COUNTS, LayerKind, PagePoolSpec, _layer_params
 from .llama import _mm, rms_norm
 
@@ -546,7 +545,7 @@ def _walk(params, h, cache, meta, cfg: MimoV2FlashConfig, tq, attn_impl):
 
     # what the kernel's path needs of the packing, once a kind for all
     # its layers (a span enters as virtual slots of BLOCK_TOKENS tokens)
-    plans = {kind: _llama.tick_plan(
+    plans = {kind: _lw.tick_plan(
         meta, tq, cfg.num_attention_heads, cache[pool], tables[kind],
         BLOCK_TOKENS) for kind, pool in ((FULL, K_FULL), (WINDOW, K_WINDOW))}
 
@@ -595,33 +594,7 @@ def _walk(params, h, cache, meta, cfg: MimoV2FlashConfig, tq, attn_impl):
     return h, new
 
 
-def serving_tick_cache(params, tokens, meta, cache, cfg: MimoV2FlashConfig,
-                       tq: int = 1, decode_tail: int = 0, spec_k: int = 0,
-                       attn_impl: str = "auto"):
-    """ONE ragged serving tick (``models/llama.py serving_tick_cache``
-    with this model's walk) over this model's cache pytree: ``(toks,
-    logits, counts, cache')``, with ``meta['cur_tok']`` ``(toks, logits,
-    counts, cur_tok', cache')``; ``counts [4]`` i32 are the tick's
-    ``TICK_COUNTERS`` over its launches."""
-    if spec_k:
-        raise ValueError("no speculative verify for a model with window "
-                         "rings: a rejected draft's rows have overwritten "
-                         "the ring")
-    return _lw.with_tick_counts(
-        lambda c: _llama.serving_tick_cache(
-            params, tokens, meta, c, cfg, tq=tq, decode_tail=decode_tail,
-            attn_impl=attn_impl, walk=_walk, page_pool=K_FULL),
-        cache, len(TICK_COUNTERS), "cur_tok" in meta)
-
-
-def serving_tick_block_cache(params, tok, lengths, tables, cache,
-                             cfg: MimoV2FlashConfig, num_steps: int,
-                             attn_impl: str = "auto", sampling=None):
-    """``num_steps`` fused decode ticks: ``(toks [S, num_steps], counts
-    [4], tok' [S], cache')``."""
-    return _lw.with_tick_counts(
-        lambda c: _llama.serving_tick_block_cache(
-            params, tok, lengths, tables, c, cfg, num_steps,
-            attn_impl=attn_impl, sampling=sampling, walk=_walk,
-            page_pool=K_FULL),
-        cache, len(TICK_COUNTERS), True)
+SERVING = _lw.ServingFamily(
+    walk=_walk, init_pages=init_serving_pages, kinds=serving_cache_kinds,
+    page_pools=cache_page_pools, window_pools=cache_window_pools,
+    tick_pool=K_FULL, counters=TICK_COUNTERS, page_copies=cache_page_copies)

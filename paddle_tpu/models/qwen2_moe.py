@@ -24,12 +24,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..incubate.moe.functional import moe_ffn, moe_ffn_share
 from ..ops.pallas.flash_attention import remat_layer
-from .layer_walk import (COUNTS, EXPERT_COUNTERS, expert_counts,
-                         with_tick_counts)
-from .llama import _mm, _proj, rms_norm, rope, tick_plan
-
-# what a serving tick hands back beside its tokens
-TICK_COUNTERS = EXPERT_COUNTERS
+from .layer_walk import (COUNTS, EXPERT_COUNTERS, ServingFamily,
+                         expert_counts, kv_rows, paged_kv_attend, tick_plan)
+from .llama import (_mm, _proj, init_serving_pages, rms_norm, rope,
+                    serving_params)
 
 
 def _dense_w(w, dtype):
@@ -391,11 +389,11 @@ def make_batch(cfg: Qwen2MoeConfig, batch_size: int, seq_len: int,
 # ---------------------------------------------------------------------------
 # serving: the tick over a shared page pool
 # ---------------------------------------------------------------------------
-# The three functions the engine calls (models/llama.py has the
-# contracts): the tick is llama's, over this model's walk. A tick's
-# routed experts go through the held-experts grouped matmul at the share
-# ``(0, E)`` (``incubate/moe/functional.py: moe_ffn_share``): it fetches
-# the weights of the experts that took a row and of no other.
+# The family's record (``SERVING``, at the end: ``models/layer_walk.py:
+# ServingFamily``): llama's cache and serving tree, this model's walk. A
+# tick's routed experts go through the held-experts grouped matmul at
+# the share ``(0, E)`` (``incubate/moe/functional.py: moe_ffn_share``):
+# it fetches the weights of the experts that took a row and of no other.
 
 
 def abstract_params(cfg: Qwen2MoeConfig):
@@ -404,33 +402,22 @@ def abstract_params(cfg: Qwen2MoeConfig):
     return jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
 
 
-def init_serving_pages(cfg: Qwen2MoeConfig, total_pages: int,
-                       page_size: int, max_batch: int = 0):
-    from .llama import init_serving_pages as _impl
-    return _impl(cfg, total_pages, page_size)
-
-
 def _walk(params, h, cache, meta, cfg: Qwen2MoeConfig, tq, attn_impl):
     """The tick's layer walk (``models/llama.py _walk_one_kind``'s
     contract and its scan: the stacked pools in the carry, a layer's
-    KV scattered in place, one ragged launch over the pages), with the
+    KV through ``paged_kv_attend``), with the
     expert STACKS kept out of the scanned ``xs``: a Pallas call cannot
     take a scanned slice without the compiler copying the layer's
     experts (1.04 GB) in front of it, so the block gets the stacks and
     the layer index. Rows that are no token (``tok_slot == S``: padding
-    and dead slots) route nowhere. The tick's counts (``TICK_COUNTERS``)
+    and dead slots) route nowhere. The tick's counts (``EXPERT_COUNTERS``)
     ride the carry where the caller carries them (``cache[COUNTS]``)."""
-    from ..ops.pallas.ragged_paged_attention import (
-        ragged_paged_attention_packed)
     k_pages, v_pages = cache["k_pages"], cache["v_pages"]
     E, top_k = cfg.num_experts, cfg.num_experts_per_tok
-    tok_slot, tok_qoff = meta["tok_slot"], meta["tok_qoff"]
     plan = tick_plan(meta, tq, cfg.num_attention_heads, k_pages)
     positions = meta["tok_pos"][None]
-    real = tok_slot < meta["q_len"].shape[0]
-    heads = jnp.arange(k_pages.shape[1], dtype=jnp.int32)[None, :]  # [1, Hkv]
-    tok_page = meta["tok_page"][:, None]                            # [T, 1]
-    tok_off = meta["tok_off"][:, None]
+    real = meta["tok_slot"] < meta["q_len"].shape[0]
+    at = kv_rows(meta, k_pages)
     layers = dict(params["layers"])
     experts = layers["experts"]
     # what the code observes in its input: bfloat16 stacks go to the
@@ -446,18 +433,9 @@ def _walk(params, h, cache, meta, cfg: Qwen2MoeConfig, tq, attn_impl):
         cell = {}
 
         def attn_fn(q, k, v):
-            with jax.named_scope("kv_pool.write"):
-                kp2 = kp.at[layer, heads, tok_page, tok_off].set(
-                    k[0].astype(kp.dtype))
-                vp2 = vp.at[layer, heads, tok_page, tok_off].set(
-                    v[0].astype(vp.dtype))
-            cell["kp"], cell["vp"] = kp2, vp2
-            with jax.named_scope("ragged_attn"):
-                o = ragged_paged_attention_packed(
-                    q[0], kp2, vp2, tok_slot, tok_qoff, meta["q_len"],
-                    meta["kv_len"], meta["tables"], tq=tq, impl=attn_impl,
-                    layer=layer, plan=plan)
-            return o[None].astype(q.dtype)
+            o, cell["kp"], cell["vp"] = paged_kv_attend(
+                q, k, v, kp, vp, layer, meta, at, plan, tq, attn_impl)
+            return o
 
         def routed_fn(lp, x, cfg):
             if not held:
@@ -473,7 +451,7 @@ def _walk(params, h, cache, meta, cfg: Qwen2MoeConfig, tq, attn_impl):
         h = _decode_block(lp, h, positions, cfg, attn_fn, routed_fn)
         return (h, cell["kp"], cell["vp"], counts + cell["counts"]), None
 
-    counts = cache.get(COUNTS, jnp.zeros((len(TICK_COUNTERS),), jnp.int32))
+    counts = cache.get(COUNTS, jnp.zeros((len(EXPERT_COUNTERS),), jnp.int32))
     # an operation under bare ``layers`` is the scan's own: the slicing
     # of a layer's weights
     with jax.named_scope("layers"):
@@ -486,33 +464,7 @@ def _walk(params, h, cache, meta, cfg: Qwen2MoeConfig, tq, attn_impl):
     return h, new
 
 
-def serving_tick_cache(params, tokens, meta, cache, cfg, **kw):
-    """The tick over the cache pytree, as the engine calls it
-    (``models/llama.py serving_tick_cache``), walking this model's
-    block: ``(toks, logits, counts, cache')``, with ``meta['cur_tok']``
-    ``(toks, logits, counts, cur_tok', cache')`` (with ``spec_k``:
-    ``toks, accept, logits, counts, ...``); ``counts [3]`` i32 are the
-    tick's ``TICK_COUNTERS`` over its launches."""
-    from .llama import serving_tick_cache as _impl
-    return with_tick_counts(
-        lambda c: _impl(params, tokens, meta, c, cfg, walk=_walk, **kw),
-        cache, len(TICK_COUNTERS), "cur_tok" in meta)
-
-
-def serving_tick_block_cache(params, tok, lengths, tables, cache, cfg,
-                             num_steps: int, **kw):
-    """``num_steps`` fused decode ticks: ``(toks [S, num_steps], counts
-    [3], tok' [S], cache')``."""
-    from .llama import serving_tick_block_cache as _impl
-    return with_tick_counts(
-        lambda c: _impl(params, tok, lengths, tables, c, cfg, num_steps,
-                        walk=_walk, **kw),
-        cache, len(TICK_COUNTERS), True)
-
-
-def serving_params(params, cfg: Qwen2MoeConfig):
-    """The tree a serving engine holds (``models/llama.py
-    serving_params``): q / k / v stacks output-major, read by
-    ``_decode_block`` through ``_proj``."""
-    from .llama import serving_params as _impl
-    return _impl(params, cfg)
+# what a serving tick hands back beside its tokens: ``counts [3]`` i32,
+# ``EXPERT_COUNTERS`` over the tick's launches
+SERVING = ServingFamily(walk=_walk, init_pages=init_serving_pages,
+                        counters=EXPERT_COUNTERS, params=serving_params)

@@ -9,10 +9,10 @@ so the span holds the device's part too):
     serving.setup.init              ServingEngine.__init__
     serving.setup.init.inventory      the static program inventory, the
                                       geometry checks
-    serving.setup.init.cache          init_serving_pages: pools and slot
-                                      state allocated on the device
-    serving.setup.init.relay          the family's ``serving_params``: the
-                                      weights it re-lays for serving
+    serving.setup.init.cache          the family's ``init_pages``: pools and
+                                      slot state allocated on the device
+    serving.setup.init.relay          the family's ``params``: the weights
+                                      it re-lays for serving
     serving.setup.warm              warm_programs()
     serving.setup.warm.program        one call of a tick program (args
                                       tq, decode_tail, spec_k; the fused

@@ -21,8 +21,8 @@ Layout contract:
   into real outputs.
 * ``k_pages``/``v_pages``: ``[Hkv, total_pages, page_size, Dh]`` — the
   shared serving pools. The span's OWN fresh KV must already be
-  written into the pages (``serving_tick_cache`` scatters before
-  attending), so the kernel is purely paged: no separate current-chunk
+  written into the pages (``models/layer_walk.py: paged_kv_attend``
+  scatters before attending), so the kernel is purely paged: no separate current-chunk
   operand, no gathered-prefix concat.
 * ``layer`` (optional): with it the pools are the serving tick's
   STACKED ones, ``[L, Hkv, total_pages, page_size, Dh]``, and the
@@ -120,8 +120,8 @@ rows contiguous. On the kernel's path ONE function lays them out
 (``_stream_launch``), for whole slots and for spans cut into virtual
 slots (``_span_blocks``) alike, from a plan of the packing that a walk
 of many layers makes once a tick (``stream_plan``: index arrays from
-the tick's metadata and the pool's KV heads alone; ``models/llama.py:
-tick_plan`` is the one place the families' walks make it; where a
+the tick's metadata and the pool's KV heads alone; ``models/
+layer_walk.py: tick_plan`` is the one place the families' walks make it; where a
 slot's rows start and whether spans are cut are the PLAN's to say, not
 the entry's). Every (virtual) slot's FIRST token goes
 into its block of the kernel's input ``[S, Hkv, R, Dh]`` by one row
@@ -273,7 +273,7 @@ def default_kv_tile_pages(pages_per_slot: int, page_size: int,
     """Geometry selection of the KV walk's tile, in pages: what
     ``DEFAULT_TILE_BYTES`` holds of this geometry's rows a head, and
     never more than the table (a table that fits one tile is walked in
-    one trip). The engine never chooses: ``serving_tick_cache`` passes
+    one trip). The engine never chooses: the serving tick passes
     geometry through and this picks per (pages_per_slot, page_size,
     Dh, dtype); ``heads_per_step`` then fits the step to VMEM."""
     tokens = DEFAULT_TILE_BYTES // (int(head_dim)

@@ -26,8 +26,8 @@ Deliberately NOT quantized:
   * qwen's shared-expert sigmoid gate — O(D·1).
 
 The quantized pytree drops into every decode entry point unchanged —
-``generate``, ``generate_paged``, ``serving_tick_cache`` /
-``serving_tick_block_cache`` — because the model bodies dispatch
+``generate``, ``generate_paged``, ``serving_tick`` /
+``serving_tick_block`` — because the model bodies dispatch
 matmuls through ``_mm`` (dense array or Int8Weight).
 Training paths are out of scope: quantize AFTER training, for serving.
 """

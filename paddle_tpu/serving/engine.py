@@ -15,12 +15,12 @@ behind it); this engine batches per STEP:
     prefix_cache.py — refcounted KV page reuse across requests:
     system prompts and few-shot headers are computed once) — and only
     the uncached suffix is ever computed;
-  - every engine tick is ONE jitted ragged program (the model
-    family's ``serving_tick_cache`` over the ragged-paged-attention
-    Pallas kernel): each live slot's decode token AND up to a per-tick
-    token budget of pending prompt spans run in the same launch, with
-    sequence geometry (span lengths, cache lengths, page tables)
-    carried as device arrays. Prompt length, chunk position and
+  - every engine tick is ONE jitted ragged program (``models/
+    serving_tick.py`` around the model family's layer walk, over the
+    ragged-paged-attention Pallas kernel): each live slot's decode
+    token AND up to a per-tick token budget of pending prompt spans
+    run in the same launch, with sequence geometry (span lengths,
+    cache lengths, page tables) carried as device arrays. Prompt length, chunk position and
     attached-prefix size are DATA, not compile shapes, and the
     recompile-hazard pass proves the whole engine compiles 1-2
     programs per packed width;
@@ -91,34 +91,15 @@ def _env_flag(name: str, default: bool) -> bool:
     return raw.strip().lower() in ("1", "true", "yes", "on")
 
 
-def _cache_kinds(mod, cfg) -> tuple:
-    """What the model's layer kinds keep between ticks
-    (``serving_cache_kinds(cfg)``); a model that declares none is of
-    one kind, each layer holding pages of K and V."""
-    kinds = getattr(mod, "serving_cache_kinds", None)
-    return () if kinds is None else tuple(kinds(cfg))
-
-
-def _page_pools(mod, cfg) -> tuple:
-    """The page pools of the model's cache pytree
-    (``cache_page_pools(cfg)``: name and page axis of each); a model
-    that declares none keeps K and V a head in ``k_pages`` /
-    ``v_pages``."""
-    from ..models.layer_walk import KV_POOLS
-    pools = getattr(mod, "cache_page_pools", None)
-    return KV_POOLS if pools is None else tuple(pools(cfg))
-
-
-def init_cache(mod, cfg, total_pages: int, page_size: int, max_batch: int,
-               max_span: int):
-    """The family's cache pytree (``init_serving_pages``). A family
-    whose window layers keep RINGS is also told the most query rows a
-    slot brings to one tick (the engine's per-tick prefill budget):
-    with the config's window that sizes a ring, and nothing else does."""
-    kw = {"max_span": int(max_span)} if any(
-        k.cache == "window_pages" for k in _cache_kinds(mod, cfg)) else {}
-    return mod.init_serving_pages(cfg, total_pages, page_size,
-                                  max_batch=max_batch, **kw)
+def _family(mod):
+    """The module's ``SERVING`` record: the one place the engine learns
+    a family (``models/layer_walk.py: ServingFamily``)."""
+    try:
+        return mod.SERVING
+    except AttributeError:
+        raise TypeError(
+            f"{mod!r} is no serving family: it exposes no SERVING (a "
+            f"models.layer_walk.ServingFamily)") from None
 
 
 from collections import OrderedDict
@@ -137,13 +118,13 @@ def _jit_step_fns(mod, cfg, attn_impl: str, rewrites: bool = False):
     jit objects, so XLA's executable cache carries across instances.
 
     Exactly TWO step functions serve everything, one over each of the
-    family's two step entry points (``init_serving_pages`` built the
-    cache they take): ``serving_tick`` (``mod.serving_tick_cache``) —
-    any mix of decode tokens and prompt spans as one ragged program
-    (one compile per packed width; widths come from the engine's small
-    width grid — see ``ServingEngine._w_grid``) — and
-    ``serving_tick_block`` (``mod.serving_tick_block_cache``) — the
-    fused multi-step decode path.
+    shared tick's two entry points (``models/serving_tick.py``, around
+    the walk of ``mod.SERVING``, whose ``init_pages`` built the cache
+    they take): ``serving_tick`` — any mix of decode tokens and prompt
+    spans as one ragged program (one compile per packed width; widths
+    come from the engine's small width grid — see
+    ``ServingEngine._w_grid``) — and ``serving_tick_block`` — the fused
+    multi-step decode path.
 
     ``rewrites=True`` routes every step function through the analysis
     subsystem's verified rewrite passes (analysis/rewrite.py) before
@@ -152,6 +133,8 @@ def _jit_step_fns(mod, cfg, attn_impl: str, rewrites: bool = False):
     pin in tests/test_rewrite.py proves greedy outputs stay
     byte-identical to the unrewritten engine)."""
     import jax
+    from ..models import serving_tick as shared
+    family = _family(mod)
     # content key (repr of a dataclass config is deterministic and
     # covers every field): benches and tests that rebuild an identical
     # config per run — the common restart shape — reuse the traced jit
@@ -177,16 +160,16 @@ def _jit_step_fns(mod, cfg, attn_impl: str, rewrites: bool = False):
     # trace tells the tick programs from everything else on the chip
     def serving_tick(params, tokens, meta, cache, tq=1, decode_tail=0,
                      spec_k=0):
-        return mod.serving_tick_cache(params, tokens, meta, cache, cfg,
-                                      tq=tq, decode_tail=decode_tail,
-                                      spec_k=spec_k, attn_impl=attn_impl)
+        return shared.serving_tick(params, tokens, meta, cache, cfg, family,
+                                   tq=tq, decode_tail=decode_tail,
+                                   spec_k=spec_k, attn_impl=attn_impl)
 
     def serving_tick_block(params, tok, lengths, tables, cache, num_steps,
                            sampling=None):
-        return mod.serving_tick_block_cache(params, tok, lengths, tables,
-                                            cache, cfg, num_steps,
-                                            attn_impl=attn_impl,
-                                            sampling=sampling)
+        return shared.serving_tick_block(params, tok, lengths, tables, cache,
+                                         cfg, family, num_steps,
+                                         attn_impl=attn_impl,
+                                         sampling=sampling)
 
     tick = jax.jit(_rw(serving_tick), donate_argnums=(3,),
                    static_argnames=("tq", "decode_tail", "spec_k"))
@@ -209,7 +192,7 @@ class _Tick:
     def __init__(self, no, live, spans, drafts, tail, ahead):
         self.no = no                # the tick's number
         self.outs = ()              # (toks_d,) or (toks_d, accept_d)
-        self.counts = None          # the family's TICK_COUNTERS, [n] i32
+        self.counts = None          # the family's counters, [n] i32
         self.live = live            # decode rows [(slot, req)]
         self.spans = spans          # [(slot, req, start, take)]
         self.drafts = drafts        # {slot: draft tokens} (verify tick)
@@ -437,11 +420,12 @@ class ServingEngine:
         self._cfg = cfg
         from ..models import resolve_family
         self._mod = resolve_family(model, cfg)
+        self._family = _family(self._mod)
         self._params = self._serving_tree(params)
         # a layer kind that keeps a fixed row a slot (not pages) holds
         # state that a prefix's pages cannot rebuild: no snapshots exist
         # yet, so what attaches, moves or rolls back pages is off
-        kinds = _cache_kinds(self._mod, cfg)
+        kinds = tuple(self._family.kinds(cfg))
         self._stateful = [k.name for k in kinds if k.cache == "slot_rows"]
         # a kind that keeps the last tokens of a WINDOW in a ring of
         # pages a slot (sized by the config's window and the chunk, not
@@ -571,16 +555,16 @@ class ServingEngine:
         import jax
         with setup_span("serving.setup.init.cache"):
             self._cache = jax.block_until_ready(dict(  # noqa: PT002 — the set-up span holds the allocation, once an engine
-                init_cache(self._mod, cfg, total_pages, page_size,
-                           max_batch, self._budget)))
+                self._family.init_pages(cfg, total_pages, page_size,
+                                        max_batch, self._budget)))
         # its page pools by name, and whether they are the K and V pools
         # that chain export / adopt and the cold tier carry
-        self._pools = _page_pools(self._mod, cfg)
+        self._pools = tuple(self._family.page_pools(cfg))
         self._kv_pools = tuple(p.name for p in self._pools) == (
             "k_pages", "v_pages")
         # counts a family's tick programs return beside their tokens
-        # (``TICK_COUNTERS``): added when the tick completes
-        self._tick_counters = tuple(getattr(self._mod, "TICK_COUNTERS", ()))
+        # (the record's ``counters``): added when the tick completes
+        self._tick_counters = tuple(self._family.counters)
         self._page_copies: Dict[int, int] = {}   # by query rows a slot
         self._tick_layers = {}
         if kinds:
@@ -593,7 +577,7 @@ class ServingEngine:
                 self._tick_layers["window_layers"] = len(self._windowed)
                 # the rings, by the names the family gives them: they
                 # come with the slots and no allocator counts them
-                rings = set(self._mod.cache_window_pools(cfg))
+                rings = set(self._family.window_pools(cfg))
                 self._window_pool_bytes = sum(
                     int(self._cache[name].nbytes) for name in rings)
                 pool_names |= rings
@@ -709,7 +693,7 @@ class ServingEngine:
         return toks_d, counts_d
 
     def _split_counts(self, out):
-        """``(results, counts)``: a family with ``TICK_COUNTERS`` hands
+        """``(results, counts)``: a family with ``counters`` hands
         its counts back as the last result before the slots' tokens."""
         if self._tick_counters:
             return out[:-1], out[-1]
@@ -1597,9 +1581,9 @@ class ServingEngine:
         slot start for ONE live page, over the attention layers: from
         the geometry the kernel is launched with (the pool as it
         stands, lane-packed or not)."""
-        if tq not in self._page_copies and not self._kv_pools:
+        if tq not in self._page_copies and self._family.page_copies:
             # another pool's kernel: the family says what it starts
-            self._page_copies[tq] = int(self._mod.cache_page_copies(
+            self._page_copies[tq] = int(self._family.page_copies(
                 self._cfg, self._cache, self.scheduler.pages_per_slot, tq))
         if tq not in self._page_copies:
             from ..ops.pallas.ragged_paged_attention import page_copies
@@ -2620,19 +2604,18 @@ class ServingEngine:
 
     def _serving_tree(self, params):
         """The tree this engine's programs read. A family may bring a
-        layout of its own for serving (``serving_params(params, cfg)``,
-        an optional function of its module, as ``TICK_COUNTERS`` is: a
-        NEW tree that shares with the caller's every leaf it does not
-        re-lay); a family without one serves the tree it was given. Made
-        once, here, under a set-up span of its own; the caller's tree is
-        not touched. ``stats()["setup"]`` says what it cost:
+        layout of its own for serving (its record's ``params(params,
+        cfg)``: a NEW tree that shares with the caller's every leaf it
+        does not re-lay); a family without one serves the tree it was
+        given. Made once, here, under a set-up span of its own; the
+        caller's tree is not touched. ``stats()["setup"]`` says what it cost:
         ``weights_relaid_bytes`` (the leaves of the engine's tree that
         are not the caller's own: 0 where nothing was re-laid) and
         ``weights_relay_s``. (At the end of the class: a line added
         above ``warm_programs`` would shift the frames a Mosaic
         kernel's compile-cache key holds.)"""
         import jax
-        relay = getattr(self._mod, "serving_params", None)
+        relay = self._family.params
         if relay is None:
             self._relaid = dict(weights_relaid_bytes=0, weights_relay_s=0.0)
             return params

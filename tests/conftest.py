@@ -110,13 +110,14 @@ def _seed_all():
 
 def _span_tick(mod, params, cfg, cache, tables, slot, toks, pos0, width,
                **kw):
-    """ONE ``serving_tick_cache`` call of family ``mod`` whose only query
+    """ONE ``serving_tick`` call of family ``mod`` whose only query
     rows are ``slot``'s span ``toks`` at positions ``pos0..``, packed at
     the front of a ``width``-token stream (the rest is padding): what
     the engine sends for one chunk of one prompt. ``tables [S, pps]``
     and the cache's page size place the rows. Returns the tick's
     results."""
     import jax.numpy as jnp
+    from paddle_tpu.models.serving_tick import serving_tick
     S, ps, n = tables.shape[0], cache["k_pages"].shape[-2], len(toks)
     tok = np.zeros((width,), np.int32)
     tok[:n] = toks
@@ -131,8 +132,8 @@ def _span_tick(mod, params, cfg, cache, tables, slot, toks, pos0, width,
         kv_len=np.where(np.arange(S) == slot, pos0 + n, 0),
         last=np.full((S,), n - 1), tables=tables)
     meta = {k: jnp.asarray(v, jnp.int32) for k, v in meta.items()}
-    return mod.serving_tick_cache(params, jnp.asarray(tok), meta, cache,
-                                  cfg, tq=width, **kw)
+    return serving_tick(params, jnp.asarray(tok), meta, cache, cfg,
+                        mod.SERVING, tq=width, **kw)
 
 
 @pytest.fixture(scope="session")

@@ -75,44 +75,33 @@ def test_flagship_serving_graphs_lint_clean(model):
                for f in report.findings)
 
 
-_THREE = ("init_serving_pages", "serving_tick_cache",
-          "serving_tick_block_cache")
-
-
 @pytest.mark.parametrize("program", ["serving_tick[mixed]",
                                      "serving_tick_block[k=4]"])
 @pytest.mark.parametrize("model", sorted(SERVING_FAMILIES))
-def test_serving_targets_trace_a_family_through_its_three_functions(
+def test_serving_targets_trace_a_family_through_its_record(
         model, program, monkeypatch):
     """Analysis reaches a family as the engine does: the cache pytree of
-    ``init_serving_pages`` through ``serving_tick_cache`` /
-    ``serving_tick_block_cache``, abstractly (zero compiles), with the
-    whole cache donated back. Fails when a family grows a fourth
-    serving entry point or analysis a private one."""
+    its record's ``init_pages`` through the shared tick around its
+    record's ``walk``, abstractly (zero compiles), with the whole cache
+    donated back."""
     from paddle_tpu.observability import RecompileSentinel
-    from paddle_tpu.serving.engine import _cache_kinds
     mod = resolve_family(model)
-    public = {n for n in dir(mod) if "serving" in n
-              and not n.startswith("_") and callable(getattr(mod, n))}
-    # what a family DECLARES beside them, each optional: its layer
-    # kinds, and the tree an engine holds (made once, at construction)
-    assert public - {"serving_cache_kinds", "serving_params"} == set(_THREE)
-    calls = dict.fromkeys(_THREE, 0)
+    calls = dict(walk=0, init_pages=0)
     caches = []
 
     def spy(name):
-        fn = getattr(mod, name)
+        fn = getattr(mod.SERVING, name)
 
         def wrapped(*a, **kw):
             calls[name] += 1
             out = fn(*a, **kw)
-            if name == "init_serving_pages":
+            if name == "init_pages":
                 caches.append(out)
             return out
         return wrapped
 
-    for name in _THREE:
-        monkeypatch.setattr(mod, name, spy(name))
+    monkeypatch.setattr(mod, "SERVING", mod.SERVING._replace(
+        **{name: spy(name) for name in calls}))
     sentinel = RecompileSentinel()
     try:
         targets = {t.name: t for t in serving_targets(model)}
@@ -135,9 +124,101 @@ def test_serving_targets_trace_a_family_through_its_three_functions(
     # a verify target only where the family can verify: what its layer
     # kinds keep tells, not its name
     cfg_cls = getattr(mod, SERVING_FAMILIES[model])
-    stateful = any(k.cache in ("slot_rows", "window_pages")
-                   for k in _cache_kinds(mod, cfg_cls.tiny()))
-    assert any("[verify" in n for n in targets) == (not stateful)
+    pages_only = all(k.cache == "pages"
+                     for k in mod.SERVING.kinds(cfg_cls.tiny()))
+    assert any("[verify" in n for n in targets) == pages_only
+
+
+@pytest.mark.parametrize("model", sorted(SERVING_FAMILIES))
+def test_a_serving_family_is_one_record(model):
+    """Everything the engine and the shared tick ask of a family stands
+    in its module's ``SERVING``: every field of its type, ``init_pages``
+    of the ONE signature, the kinds and pools it declares true of the
+    cache it builds, and as many counts out of a tick as it names
+    counters (abstractly: zero compiles)."""
+    import inspect
+
+    from paddle_tpu.analysis.serving_graphs import _tick_meta
+    from paddle_tpu.models.layer_walk import (LayerKind, PagePoolSpec,
+                                              ServingFamily)
+    from paddle_tpu.models.serving_tick import (serving_tick,
+                                                serving_tick_block)
+    from paddle_tpu.observability import RecompileSentinel
+    mod = resolve_family(model)
+    family = mod.SERVING
+    assert isinstance(family, ServingFamily)
+    for name in ("walk", "init_pages", "kinds", "page_pools",
+                 "window_pools"):
+        assert callable(getattr(family, name)), name
+    for name in ("page_copies", "params"):
+        assert getattr(family, name) is None \
+            or callable(getattr(family, name)), name
+    assert isinstance(family.tick_pool, str)
+    assert isinstance(family.counters, tuple) \
+        and all(isinstance(c, str) for c in family.counters)
+    assert list(inspect.signature(family.init_pages).parameters) == [
+        "cfg", "total_pages", "page_size", "max_batch", "max_span"]
+    cfg = getattr(mod, SERVING_FAMILIES[model]).tiny()
+    S, ps, pps, T = 2, 4, 3, 6
+    sentinel = RecompileSentinel()
+    try:
+        cache = jax.eval_shape(
+            lambda: family.init_pages(cfg, 1 + S * pps, ps, S, T))
+        kinds, pools = family.kinds(cfg), family.page_pools(cfg)
+        rings = family.window_pools(cfg)
+        assert all(isinstance(k, LayerKind) for k in kinds)
+        assert all(isinstance(p, PagePoolSpec) for p in pools)
+        # the allocator's pools hold its pages on the axis they say; a
+        # ring is a leaf no allocator counts; the tick's pool has a
+        # page's tokens second to last
+        for p in pools:
+            assert cache[p.name].shape[p.page_axis] == 1 + S * pps
+        assert set(rings) <= set(cache) - {p.name for p in pools}
+        assert bool(rings) == any(k.cache == "window_pages" for k in kinds)
+        assert cache[family.tick_pool].shape[-2] == ps
+        # a kind that keeps rows a slot has a leaf that is no pool
+        assert (set(cache) > {p.name for p in pools} | set(rings)) == any(
+            k.cache == "slot_rows" for k in kinds)
+        params = mod.abstract_params(cfg)
+        if family.params is not None:
+            params = jax.eval_shape(lambda p: family.params(p, cfg), params)
+        sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+        meta = _tick_meta(T, S, pps)
+        tick = jax.eval_shape(
+            lambda p, t, m, c: serving_tick(p, t, m, c, cfg, family, tq=T,
+                                            decode_tail=1,
+                                            attn_impl="dense"),
+            params, sds((T,), i32), meta, cache)
+        block = jax.eval_shape(
+            lambda p, t, n, tab, c: serving_tick_block(
+                p, t, n, tab, c, cfg, family, 2, attn_impl="dense"),
+            params, sds((S,), i32), sds((S,), i32), sds((S, pps), i32),
+            cache)
+    finally:
+        sentinel.close()
+    assert sentinel.warmup_compiles == 0
+    # (toks, logits, [counts,] cur_tok', cache') / (toks, [counts,]
+    # tok', cache'): the cache comes back as it went in
+    n = len(family.counters)
+    assert len(tick) == 4 + bool(n) and len(block) == 3 + bool(n)
+    for out in (tick, block):
+        assert jax.tree.map(lambda a: (a.shape, a.dtype), out[-1]) == \
+            jax.tree.map(lambda a: (a.shape, a.dtype), cache)
+        if n:
+            assert (out[-3].shape, out[-3].dtype) == ((n,), jnp.int32)
+
+
+def test_a_module_without_the_record_is_no_serving_family():
+    """The engine reads ``SERVING`` and nothing else of a module: one
+    that lacks it is refused by name, not served with defaults."""
+    import types
+    mod = types.SimpleNamespace(
+        __name__="no_record", init_serving_pages=L.init_serving_pages,
+        serving_params=L.serving_params)
+    cfg = L.LlamaConfig.tiny()
+    with pytest.raises(TypeError, match="exposes no SERVING"):
+        ServingEngine(L.abstract_params(cfg), cfg, model=mod, max_batch=2,
+                      page_size=4, max_prompt_len=8, max_new_tokens_cap=4)
 
 
 def test_pp_stage_chunks_consistent():

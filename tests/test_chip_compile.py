@@ -217,7 +217,7 @@ def test_ragged_head_size_64_enters_lane_packed(chip, monkeypatch, tq):
 
 
 def _mistral_tick_shapes(tq, layers, pages):
-    """``serving_tick_cache``'s operands at Mistral-7B-v0.3 widths (the
+    """``serving_tick``'s operands at Mistral-7B-v0.3 widths (the
     Llama-3-8B ones above but for the vocabulary) and the chat cell's
     slots and table width, cut to ``layers`` layers and ``pages``
     pages."""
@@ -290,6 +290,7 @@ def _mistral_tick_text(chip, monkeypatch, tq):
     layers, the cell's own pool), compiled for the described chip once
     a module."""
     from paddle_tpu.models import llama as L
+    from paddle_tpu.models import serving_tick as T
     from paddle_tpu.ops.pallas import ragged_paged_attention as R
     # the packed entry asks the backend, and sees the CPU here
     monkeypatch.setattr(R, "_on_tpu", lambda: True)
@@ -301,8 +302,8 @@ def _mistral_tick_text(chip, monkeypatch, tq):
         # the pool as ONE donated pytree, as the engine's jitted wrapper
         # has it
         def serving_tick(params, tokens, meta, cache):
-            return L.serving_tick_cache(params, tokens, meta, cache, cfg,
-                                        tq=tq)
+            return T.serving_tick(params, tokens, meta, cache, cfg,
+                                  L.SERVING, tq=tq)
 
         _TICK_TEXTS[tq] = chip(serving_tick, *shapes, donate=(3,))
     return _TICK_TEXTS[tq]
@@ -497,7 +498,7 @@ def test_cell_tick_programs_keep_the_slots_tokens_on_the_device(
     monkeypatch.setattr(R, "_on_tpu", lambda: True)
     monkeypatch.setattr(G, "_on_tpu", lambda: True)
     mod, cfg, S, pps, chunk, params, cache = _cell_program_args(traffic)
-    counters = getattr(mod, "TICK_COUNTERS", ())
+    counters = mod.SERVING.counters
     one = SingleDeviceSharding(topo.devices[0])
 
     def on_chip(tree):

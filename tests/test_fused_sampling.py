@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.models import llama as L
+from paddle_tpu.models.serving_tick import _fused_sample
 from paddle_tpu.serving import ServingEngine
 
 CFG = L.LlamaConfig.tiny(dtype=jnp.float32, use_flash_attention=False,
@@ -223,17 +224,15 @@ def test_fused_sample_unit_masks():
     ones = jnp.ones((4,), jnp.float32)
     zi = jnp.zeros((4,), jnp.int32)
     greedy = np.asarray(jnp.argmax(logits, axis=-1))
-    out = np.asarray(L._fused_sample(logits, zeros, ones, zi, keys,
-                                     idx))
+    out = np.asarray(_fused_sample(logits, zeros, ones, zi, keys, idx))
     np.testing.assert_array_equal(out, greedy)
-    out = np.asarray(L._fused_sample(logits, ones, ones,
-                                     jnp.full((4,), 1, jnp.int32),
-                                     keys, idx))
+    out = np.asarray(_fused_sample(logits, ones, ones,
+                                   jnp.full((4,), 1, jnp.int32), keys, idx))
     np.testing.assert_array_equal(out, greedy)       # top_k=1
     # row independence: permuting OTHER rows does not change row 0
-    a = np.asarray(L._fused_sample(logits, ones, ones, zi, keys, idx))
+    a = np.asarray(_fused_sample(logits, ones, ones, zi, keys, idx))
     perm = jnp.asarray([0, 3, 2, 1])
-    b = np.asarray(L._fused_sample(logits[perm], ones, ones, zi,
-                                   keys[perm], idx[perm]))
+    b = np.asarray(_fused_sample(logits[perm], ones, ones, zi, keys[perm],
+                                 idx[perm]))
     assert a[0] == b[0]
     assert a[3] == b[1]

@@ -35,6 +35,8 @@ from harness import manifest, reference  # noqa: E402
 
 from paddle_tpu.models import granite_hybrid as M  # noqa: E402
 from paddle_tpu.models import layer_walk  # noqa: E402
+from paddle_tpu.models.serving_tick import (  # noqa: E402
+    serving_tick, serving_tick_block)
 from paddle_tpu.ops.pallas import ssd_update as K  # noqa: E402
 from paddle_tpu.serving import ServingEngine  # noqa: E402
 
@@ -268,9 +270,9 @@ class Ticks:
                     tok_qoff=tok_qoff, q_len=q_len, kv_len=kv_len, last=last,
                     tables=self.tables, tail_live=live)
         meta = {k: jnp.asarray(v) for k, v in meta.items()}
-        toks, logits, self.cache = M.serving_tick_cache(
+        toks, logits, self.cache = serving_tick(
             self.params, jnp.asarray(tok), meta, self.cache, self.cfg,
-            tq=width, decode_tail=decode_tail)
+            M.SERVING, tq=width, decode_tail=decode_tail)
         self.lens[list(tail_live)] += decode_tail
         return np.asarray(toks), np.asarray(logits)
 
@@ -338,9 +340,10 @@ def test_fused_block_against_the_reference():
     t = Ticks(cfg, params)
     first, _ = t.run({1: b})
     before = np.asarray(t.cache["ssm_state"])
-    toks, _, t.cache = M.serving_tick_block_cache(
+    toks, _, t.cache = serving_tick_block(
         params, jnp.asarray(np.array([0, first[1], 0], np.int32)),
-        jnp.asarray(t.lens), jnp.asarray(t.tables), t.cache, cfg, 3)
+        jnp.asarray(t.lens), jnp.asarray(t.tables), t.cache, cfg, M.SERVING,
+        3)
     cont = np.concatenate([b, first[1:2], np.asarray(toks)[1]])
     want = ref_logits(params, model, cont[:-1], rows=np.arange(5, 9))
     assert (want.argmax(-1) == cont[6:]).all()
@@ -554,7 +557,7 @@ def test_engine_refuses_speculation_for_a_stateful_model():
     with pytest.raises(ValueError, match=r"per-slot state \(\['mamba'"):
         engine(cfg, params, speculative="ngram")
     with pytest.raises(ValueError, match="rolled back"):
-        M.serving_tick_cache(params, None, {}, {}, cfg, spec_k=2)
+        serving_tick(params, None, {}, {}, cfg, M.SERVING, spec_k=2)
 
 
 @pytest.mark.parametrize("call", ["export_chain", "export_chain_begin",

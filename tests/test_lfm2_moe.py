@@ -30,6 +30,8 @@ from harness import manifest, reference  # noqa: E402
 
 from paddle_tpu.incubate.moe.functional import top_k_gating  # noqa: E402
 from paddle_tpu.models import lfm2_moe as M  # noqa: E402
+from paddle_tpu.models.serving_tick import (  # noqa: E402
+    serving_tick, serving_tick_block)
 from paddle_tpu.ops.pallas import ragged_paged_attention as R  # noqa: E402
 from paddle_tpu.serving import ServingEngine  # noqa: E402
 
@@ -167,9 +169,9 @@ class Ticks:
                     tok_qoff=tok_qoff, q_len=q_len, kv_len=kv_len, last=last,
                     tables=self.tables, tail_live=live)
         meta = {k: jnp.asarray(v) for k, v in meta.items()}
-        toks, logits, self.cache = M.serving_tick_cache(
+        toks, logits, self.cache = serving_tick(
             self.params, jnp.asarray(tok), meta, self.cache, self.cfg,
-            tq=width, decode_tail=decode_tail)
+            M.SERVING, tq=width, decode_tail=decode_tail)
         self.lens[list(tail_live)] += decode_tail
         return np.asarray(toks), np.asarray(logits)
 
@@ -235,9 +237,10 @@ def test_fused_block_against_the_reference():
     b = seq(6, 5, 1)
     t = Ticks(cfg, params)
     first, _ = t.run({1: b})
-    toks, _, t.cache = M.serving_tick_block_cache(
+    toks, _, t.cache = serving_tick_block(
         params, jnp.asarray(np.array([0, first[1], 0], np.int32)),
-        jnp.asarray(t.lens), jnp.asarray(t.tables), t.cache, cfg, 3)
+        jnp.asarray(t.lens), jnp.asarray(t.tables), t.cache, cfg, M.SERVING,
+        3)
     cont = np.concatenate([b, first[1:2], np.asarray(toks)[1]])
     want = ref_logits(params, model, cont[:-1], rows=np.arange(5, 9))
     assert (want.argmax(-1) == cont[6:]).all()
@@ -420,7 +423,7 @@ def test_engine_resolves_the_model_by_name_and_by_config():
     assert resolve_family("lfm2_moe") is M
     assert resolve_family(None, M.Lfm2MoeConfig.tiny()) is M
     assert resolve_family(M) is M
-    with pytest.raises(ValueError, match="serving_tick_block_cache"):
+    with pytest.raises(ValueError, match="exposing SERVING"):
         resolve_family("no_such_family")
 
 

@@ -28,6 +28,8 @@ from harness import manifest, reference  # noqa: E402
 from paddle_tpu.incubate.moe.functional import moe_ffn_share  # noqa: E402
 from paddle_tpu.models import layer_walk  # noqa: E402
 from paddle_tpu.models import longcat_flash as M  # noqa: E402
+from paddle_tpu.models.serving_tick import (  # noqa: E402
+    serving_tick, serving_tick_block)
 from paddle_tpu.ops.pallas import mla_paged_attention as K  # noqa: E402
 from paddle_tpu.serving import ServingEngine  # noqa: E402
 
@@ -162,9 +164,10 @@ class Ticks:
                     tok_qoff=tok_qoff, q_len=q_len, kv_len=kv_len, last=last,
                     tables=self.tables, tail_live=live)
         meta = {k: jnp.asarray(v) for k, v in meta.items()}
-        toks, logits, counts, self.cache = M.serving_tick_cache(
+        toks, logits, counts, self.cache = serving_tick(
             self.params, jnp.asarray(tok), meta, self.cache, self.cfg,
-            tq=width, decode_tail=decode_tail, attn_impl=self.impl)
+            M.SERVING, tq=width, decode_tail=decode_tail,
+            attn_impl=self.impl)
         self.lens[list(tail_live)] += decode_tail
         return np.asarray(toks), np.asarray(logits), np.asarray(counts)
 
@@ -234,9 +237,9 @@ def test_fused_block_against_the_reference():
     lengths = np.array([0, 5, 0], np.int32)
     tok = jnp.asarray(np.array([0, b[5], 0], np.int32))
     before = np.asarray(t.cache[M.POOL])
-    toks, counts, nxt, cache = M.serving_tick_block_cache(
+    toks, counts, nxt, cache = serving_tick_block(
         params, tok, jnp.asarray(lengths), jnp.asarray(t.tables), t.cache,
-        cfg, num_steps=3)
+        cfg, M.SERVING, num_steps=3)
     toks = np.asarray(toks)
     cont = np.concatenate([b, toks[1]])
     want = ref_logits(params, model, cont[:-1], rows=np.arange(5, 8))
@@ -504,10 +507,9 @@ def test_the_older_families_keep_their_two_pools(family):
     ``qwen2_moe`` hands three counts back beside the tokens (its routed
     experts go through the share ``(0, E)``), the others none."""
     import importlib
-    from paddle_tpu.serving.engine import _page_pools
     mod = importlib.import_module(f"paddle_tpu.models.{family}")
-    assert _page_pools(mod, None) == layer_walk.KV_POOLS
-    assert getattr(mod, "TICK_COUNTERS", ()) == (
+    assert mod.SERVING.page_pools(None) == layer_walk.KV_POOLS
+    assert mod.SERVING.counters == (
         ("moe_pairs_held", "moe_experts_touched", "moe_experts_held")
         if family == "qwen2_moe" else ())
 

@@ -29,6 +29,8 @@ from harness import manifest, reference  # noqa: E402
 
 from paddle_tpu.incubate.moe.functional import moe_ffn_share  # noqa: E402
 from paddle_tpu.models import mimo_v2_flash as M  # noqa: E402
+from paddle_tpu.models.serving_tick import (  # noqa: E402
+    serving_tick, serving_tick_block)
 from paddle_tpu.ops.pallas import ragged_paged_attention as R  # noqa: E402
 from paddle_tpu.serving import ServingEngine  # noqa: E402
 
@@ -191,9 +193,10 @@ class Ticks:
                     tok_qoff=tok_qoff, q_len=q_len, kv_len=kv_len, last=last,
                     tables=self.tables, tail_live=live)
         meta = {k: jnp.asarray(v) for k, v in meta.items()}
-        toks, logits, counts, self.cache = M.serving_tick_cache(
+        toks, logits, counts, self.cache = serving_tick(
             self.params, jnp.asarray(tok), meta, self.cache, self.cfg,
-            tq=width, decode_tail=decode_tail, attn_impl=self.impl)
+            M.SERVING, tq=width, decode_tail=decode_tail,
+            attn_impl=self.impl)
         self.lens[list(tail_live)] += decode_tail
         return np.asarray(toks), np.asarray(logits), np.asarray(counts)
 
@@ -267,9 +270,9 @@ def test_fused_tail_and_block_against_the_reference():
     # the fused block from where slot 1 stands
     lengths = np.array([0, t.lens[1], 0], np.int32)
     tok = jnp.asarray(np.array([0, toks[1, -1], 0], np.int32))
-    blk, counts, nxt, _ = M.serving_tick_block_cache(
+    blk, counts, nxt, _ = serving_tick_block(
         params, tok, jnp.asarray(lengths), jnp.asarray(t.tables), t.cache,
-        cfg, num_steps=3)
+        cfg, M.SERVING, num_steps=3)
     blk = np.asarray(blk)
     cont = np.concatenate([cont, blk[1]])
     want = ref_logits(params, model, cont[:-1], rows=np.arange(32, 35))
@@ -284,7 +287,7 @@ def test_a_span_wider_than_the_ring_was_sized_for_is_refused():
     with pytest.raises(ValueError, match="ring"):
         t.run({0: seq(20)}, width=32)
     with pytest.raises(ValueError, match="specul"):
-        M.serving_tick_cache(params, None, {}, t.cache, cfg, spec_k=2)
+        serving_tick(params, None, {}, t.cache, cfg, M.SERVING, spec_k=2)
 
 
 # ---------------------------------------------------------------- kernel ----

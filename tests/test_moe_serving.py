@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.incubate.moe import functional as MF
 from paddle_tpu.models.llama import rms_norm
+from paddle_tpu.models.serving_tick import serving_tick
 from paddle_tpu.ops.pallas import grouped_matmul as G
 from paddle_tpu.serving import ServingEngine
 
@@ -229,9 +230,9 @@ def test_a_tick_equals_forward_at_every_real_row(monkeypatch, impl):
         got = logits[i:i + len(toks)]
         assert np.abs(got - want).max() < 2e-4 * max(1, np.abs(want).max())
         i += len(toks) + 1
-    toks, _, counts, _ = M.serving_tick_cache(
+    toks, _, counts, _ = serving_tick(
         params, tok, {**meta, "tail_live": jnp.zeros((S_SLOTS,), bool)},
-        cache, cfg, tq=width)
+        cache, cfg, M.SERVING, tq=width)
     layers, n_e = cfg.num_hidden_layers, cfg.num_experts
     pairs, touched, held = np.asarray(counts)
     assert pairs == cfg.num_experts_per_tok * int(real.sum()) * layers
@@ -275,10 +276,11 @@ def test_int8_experts_keep_the_capacity_einsum():
     tok, meta, real = _stream(cfg, {1: np.arange(1, 7)}, 12)
     cache = M.init_serving_pages(cfg, 1 + S_SLOTS * PPS, PS)
     meta = {**meta, "tail_live": jnp.zeros((S_SLOTS,), bool)}
-    jaxpr = str(jax.make_jaxpr(lambda p, c: M.serving_tick_cache(
-        p, tok, meta, c, cfg, tq=12))(q, cache))
+    jaxpr = str(jax.make_jaxpr(lambda p, c: serving_tick(
+        p, tok, meta, c, cfg, M.SERVING, tq=12))(q, cache))
     assert "held_experts_matmul" not in jaxpr
-    _, _, counts, _ = M.serving_tick_cache(q, tok, meta, cache, cfg, tq=12)
+    _, _, counts, _ = serving_tick(q, tok, meta, cache, cfg, M.SERVING,
+                                   tq=12)
     layers = cfg.num_hidden_layers
     np.testing.assert_array_equal(
         np.asarray(counts),

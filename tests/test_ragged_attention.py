@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.models import llama as L
+from paddle_tpu.models import serving_tick as T
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     ragged_paged_attention, ragged_paged_attention_packed, tiled_ulp_error)
 from paddle_tpu.serving import ServingEngine
@@ -651,8 +652,9 @@ def test_block_tick_free_slots_are_dead_and_live_ones_decode(
     # single-step greedy decode: K blocks of one step each
     want, cur, lens, c = [], jnp.asarray(tok), lengths.copy(), cache
     for _ in range(K):
-        t, nxt, c = L.serving_tick_block_cache(
-            params, cur, jnp.asarray(lens), jnp.asarray(tables), c, CFG, 1)
+        t, nxt, c = T.serving_tick_block(
+            params, cur, jnp.asarray(lens), jnp.asarray(tables), c, CFG,
+            L.SERVING, 1)
         want.append(np.asarray(t)[:, 0])
         # the successor of the slots' current tokens: a live slot's new
         # token, a dead slot's old value
@@ -662,17 +664,17 @@ def test_block_tick_free_slots_are_dead_and_live_ones_decode(
         cur, lens = nxt, lens + live
     want = np.stack(want, axis=1)
     seen = {}
-    tick = L.serving_tick_cache
+    tick = T.serving_tick
 
     def spy(params, tokens, meta, *a, **kw):
         if not seen:            # the block's own call, not the tail's
             seen.update(meta)
         return tick(params, tokens, meta, *a, **kw)
 
-    monkeypatch.setattr(L, "serving_tick_cache", spy)
-    got, nxt, _ = L.serving_tick_block_cache(
+    monkeypatch.setattr(T, "serving_tick", spy)
+    got, nxt, _ = T.serving_tick_block(
         params, jnp.asarray(tok), jnp.asarray(lengths),
-        jnp.asarray(tables), cache, CFG, K, attn_impl=attn_impl)
+        jnp.asarray(tables), cache, CFG, L.SERVING, K, attn_impl=attn_impl)
     np.testing.assert_array_equal(np.asarray(got)[live], want[live])
     np.testing.assert_array_equal(np.asarray(nxt),
                                   np.where(live, want[:, -1], tok))

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.models.llama import OUTPUT_MAJOR
+from paddle_tpu.models.serving_tick import serving_tick, serving_tick_block
 from paddle_tpu.serving import ServingEngine
 
 FAMILIES = ("llama", "qwen2_moe")
@@ -71,12 +72,13 @@ def _run(mod, cfg, params, program):
     if program == "block":
         lengths = jnp.asarray([5, 3, 0], jnp.int32)
         tables = 1 + jnp.arange(S * PPS, dtype=jnp.int32).reshape(S, PPS)
-        return mod.serving_tick_block_cache(
+        return serving_tick_block(
             params, jnp.asarray([3, 7, 0], jnp.int32), lengths, tables,
-            cache, cfg, num_steps=3, attn_impl="dense")
+            cache, cfg, mod.SERVING, num_steps=3, attn_impl="dense")
     tok, meta, width = _tick_meta()
-    return mod.serving_tick_cache(
-        params, tok, meta, cache, cfg, tq=width - S, attn_impl="dense",
+    return serving_tick(
+        params, tok, meta, cache, cfg, mod.SERVING, tq=width - S,
+        attn_impl="dense",
         decode_tail={"plain": 0, "tail": 2}[program])
 
 
@@ -124,7 +126,7 @@ def test_an_engines_tokens_are_those_of_the_given_tree(family, monkeypatch):
     got = _serve(eng, cfg)
     assert [(id(x), x.shape) for x in jax.tree.leaves(params)] == before
     assert set(params["layers"]) == keys
-    monkeypatch.delattr(mod, "serving_params")
+    monkeypatch.setattr(mod, "SERVING", mod.SERVING._replace(params=None))
     plain = _engine(params, cfg)
     assert plain._params is params
     for g, w in zip(got, _serve(plain, cfg)):
@@ -192,7 +194,7 @@ def test_an_int8_tree_passes_through(family, monkeypatch):
         assert name + OUTPUT_MAJOR not in layers
     assert eng.stats()["setup"]["weights_relaid_bytes"] == 0
     got = _serve(eng, cfg)
-    monkeypatch.delattr(mod, "serving_params")
+    monkeypatch.setattr(mod, "SERVING", mod.SERVING._replace(params=None))
     for g, w in zip(got, _serve(_engine(quant, cfg), cfg)):
         np.testing.assert_array_equal(g, w)
 
@@ -202,7 +204,7 @@ def test_a_family_without_the_function_serves_the_given_tree(family):
     """LFM2's two and granite's four attention layers keep ``[D, O]``
     (``PERF.md`` section 7): the engine's tree IS the caller's."""
     mod = importlib.import_module(f"paddle_tpu.models.{family}")
-    assert not hasattr(mod, "serving_params")
+    assert mod.SERVING.params is None
     cls = {"lfm2_moe": "Lfm2MoeConfig",
            "granite_hybrid": "GraniteHybridConfig"}[family]
     cfg = getattr(mod, cls).tiny()

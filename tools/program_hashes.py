@@ -123,17 +123,18 @@ def main(names) -> dict:
         pages = eng.get("total_pages") or S * pps + 1
         params = jax.eval_shape(
             lambda: mod.init_params(cfg, jax.random.PRNGKey(0)))
-        # the tree an engine holds: the family's own where it brings one
-        # (``serving_params``; a parent from before it serves the given)
-        serving = getattr(mod, "serving_params", None)
+        # the tree an engine holds and the cache its family builds, the
+        # rings sized by the chunk: the record's (``mod.SERVING``); for a
+        # parent from before the record, the engine's ``init_cache`` and
+        # the module's ``serving_params``
+        family = getattr(mod, "SERVING", None)
+        serving = family.params if family else getattr(
+            mod, "serving_params", None)
         if serving is not None:
             params = jax.eval_shape(lambda p: serving(p, cfg), params)
-        # a family with window rings sizes them by the chunk
-        # (``init_cache``; a parent from before it has no such family)
-        init = getattr(E, "init_cache", None)
         cache = jax.eval_shape(
-            lambda: init(mod, cfg, pages, ps, S, chunk) if init
-            else mod.init_serving_pages(cfg, pages, ps, max_batch=S))
+            lambda: family.init_pages(cfg, pages, ps, S, chunk) if family
+            else E.init_cache(mod, cfg, pages, ps, S, chunk))
         i32 = functools.partial(sds, dtype=jnp.int32)
         f32 = functools.partial(sds, dtype=jnp.float32)
         samp = dict(temp=f32((S,)), top_p=f32((S,)), top_k=i32((S,)),

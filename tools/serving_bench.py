@@ -15,8 +15,8 @@ strategies over the SAME model params:
   (c) engine        — `serving.ServingEngine` continuous batching:
                       per-step admission/retirement over the shared
                       page pool, tokens streamed as decoded (the model
-                      family's `init_serving_pages` /
-                      `serving_tick_cache` / `serving_tick_block_cache`).
+                      family's `SERVING` record under
+                      `models/serving_tick.py`).
 
 Reported per mode: wall_s, useful tok/s (only each request's OWN
 requested tokens count), time-to-first-token p50/p99 (ms), and mean
